@@ -178,11 +178,11 @@ def _consensus_stage_kwargs(args):
 
 def _print_stats(stats, wall_s=None):
     """--stats output: per-stage busy/blocked table + queue occupancy,
-    peak RSS, the device-boundary accounting (dispatches, fetch-wait,
-    GFLOP/s, MFU estimate, device fraction of wall) and the per-dispatch
-    device timeline when any kernel dispatched this run (the
-    PipelineStats::format_summary analog, reference base.rs:3379-3947;
-    VERDICT r4 item 9)."""
+    peak RSS, which platform did the consensus work (jax's devices, or the
+    native host engine) with the device-boundary accounting (dispatches,
+    fetch-wait, GFLOP/s, MFU estimate) and the per-dispatch device
+    timeline when any kernel dispatched this run (the
+    PipelineStats::format_summary analog, reference base.rs:3379-3947)."""
     print(stats.format_table())
     try:
         with open("/proc/self/status") as f:
@@ -194,8 +194,8 @@ def _print_stats(stats, wall_s=None):
         pass
     from .ops.kernel import DEVICE_STATS
 
+    print(DEVICE_STATS.format_summary(wall_s))
     if DEVICE_STATS.dispatches:
-        print(DEVICE_STATS.format_summary(wall_s))
         tl = DEVICE_STATS.timeline_snapshot()
         done = [t for t in tl if "t_fetched" in t]
         if done:
@@ -251,23 +251,25 @@ def _build_dp_mesh(devices_arg, mesh_spec=None):
     :class:`~fgumi_tpu.parallel.mesh.MeshConfigError` on an unsatisfiable
     shape; commands map it to exit 2.
     """
-    from .parallel.mesh import parse_mesh_spec, publish_mesh, resolve_mesh
-
-    spec = parse_mesh_spec(mesh_spec if mesh_spec is not None
-                           else os.environ.get("FGUMI_TPU_MESH"))
-    explicit_off = ((mesh_spec is not None
-                     or os.environ.get("FGUMI_TPU_MESH") is not None)
-                    and spec is None)
-    if explicit_off:
-        return None
+    raw_spec = (mesh_spec if mesh_spec is not None
+                else os.environ.get("FGUMI_TPU_MESH"))
     # CPU pinned without a forced virtual device count => exactly one device:
     # skip the jax import/backend init entirely (host-engine cold-start
-    # path) — unless an explicit mesh shape demands validation
-    if (spec is None
+    # path) — unless an explicit mesh shape demands validation. Decided
+    # before parallel.mesh is touched: it imports jax at module scope.
+    if (raw_spec is None
             and os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu"
             and "host_platform_device_count"
             not in os.environ.get("XLA_FLAGS", "")
             and not os.environ.get("FGUMI_TPU_COORDINATOR")):
+        return None
+    from .ops.kernel import _ensure_jax
+
+    _ensure_jax()  # the guarded first import (stage threads race here)
+    from .parallel.mesh import parse_mesh_spec, publish_mesh, resolve_mesh
+
+    spec = parse_mesh_spec(raw_spec)
+    if raw_spec is not None and spec is None:  # explicit "off"
         return None
     # multi-host: join the process group BEFORE the first backend touch so
     # jax.devices() below is the global device list (parallel/distributed.py)
@@ -3406,8 +3408,9 @@ def _add_serve(sub):
                         "on-request traces here (created if missing)")
     p.add_argument("--compile-cache", default=None, metavar="DIR",
                    help="persistent XLA compile-cache directory for warm "
-                        "serving (default: the standard cache under "
-                        "~/.cache/fgumi_tpu)")
+                        "serving (default: <checkout>/.jax_cache; ignored "
+                        "when JAX_COMPILATION_CACHE_DIR is set, which then "
+                        "names the directory)")
     p.add_argument("--max-frame-bytes", type=int, default=None,
                    help="protocol frame size cap (default 1 MiB); larger "
                         "frames are rejected and the connection closed")
@@ -3603,8 +3606,15 @@ def cmd_serve(args):
                       args.metrics_port, e)
         service.close()
         return 2
-    service.warm_up(compile_cache_dir=args.compile_cache,
-                    touch_device=not args.no_warmup)
+    try:
+        service.warm_up(compile_cache_dir=args.compile_cache,
+                        touch_device=not args.no_warmup)
+    except RuntimeError as e:
+        # jax found no usable backend (a chip held by another process, a
+        # missing runtime): a failed start, not a daemon on the host engine
+        log.error("serve: device warm-up failed: %s", e)
+        service.close()
+        return 1
     service.start()
 
     def _on_signal(signum, frame):

@@ -2,10 +2,11 @@
 
 The shared library is built lazily from the bundled source on first use
 (g++ -O3 against the system libdeflate) and cached next to this module;
-every consumer degrades gracefully to the pure-Python/zlib path when the
-toolchain or libdeflate is unavailable (set FGUMI_TPU_NO_NATIVE=1 to force
-the fallback). Mirrors the reference's native layering (SURVEY.md §2 intro:
-C++ equivalents for the L1-L4 hot paths).
+every consumer degrades to the pure-Python/zlib path when the toolchain or
+libdeflate is unavailable — with a warning that carries g++'s stderr (set
+FGUMI_TPU_NO_NATIVE=1 to force the fallback). Mirrors the reference's
+native layering (SURVEY.md §2 intro: C++ equivalents for the L1-L4 hot
+paths).
 """
 
 import ctypes
@@ -27,17 +28,32 @@ _lib_failed = False
 _ABI_VERSION = 14
 
 
-def _build() -> bool:
-    cmd = ["g++", "-O3", "-shared", "-fPIC", "-pthread", "-o", _SO_PATH,
+def build() -> bool:
+    """Compile the bundled source into ``libfgumi_native.so``.
+
+    g++ writes to a name private to this process and the result is
+    renamed into place, so several processes that reach first use
+    together (a fleet start, a test run) each load a complete library:
+    nobody can dlopen a half-written file. A failure is a warning with
+    the compiler's own words — the pure-Python fallback is an order of
+    magnitude slower and must not be entered silently."""
+    tmp = f"{_SO_PATH}.{os.getpid()}.tmp"
+    cmd = ["g++", "-O3", "-shared", "-fPIC", "-pthread", "-o", tmp,
            _SRC_PATH, "-ldeflate"]
     try:
         proc = subprocess.run(cmd, capture_output=True, text=True, timeout=120)
+        if proc.returncode != 0:
+            log.warning("native build failed (%s); using the pure-Python "
+                        "fallbacks:\n%s", " ".join(cmd), proc.stderr)
+            return False
+        os.replace(tmp, _SO_PATH)
     except (OSError, subprocess.TimeoutExpired) as e:
-        log.debug("native build failed to launch: %s", e)
+        log.warning("native build failed (%s); using the pure-Python "
+                    "fallbacks", e)
         return False
-    if proc.returncode != 0:
-        log.debug("native build failed:\n%s", proc.stderr)
-        return False
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
     return True
 
 
@@ -249,26 +265,26 @@ def get_lib():
         if not os.path.exists(_SO_PATH) or (
                 os.path.exists(_SRC_PATH)
                 and os.path.getmtime(_SRC_PATH) > os.path.getmtime(_SO_PATH)):
-            if not _build():
+            if not build():
                 _lib_failed = True
                 return None
         try:
             lib = ctypes.CDLL(_SO_PATH)
         except OSError as e:
-            log.debug("native library load failed: %s", e)
+            log.warning("native library load failed: %s", e)
             _lib_failed = True
             return None
         # stale-.so guard: a cached build whose mtime ties the source (e.g.
         # archive extraction) passes the rebuild check but may predate newer
         # symbols OR carry old signatures; rebuild on ABI mismatch
         if not _abi_ok(lib):
-            if not _build():
+            if not build():
                 _lib_failed = True
                 return None
             try:
                 lib = ctypes.CDLL(_SO_PATH)
             except OSError as e:
-                log.debug("native library reload failed: %s", e)
+                log.warning("native library reload failed: %s", e)
                 _lib_failed = True
                 return None
             if not _abi_ok(lib):

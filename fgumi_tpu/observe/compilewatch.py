@@ -2,15 +2,20 @@
 
 The serve daemon's whole value proposition is that the second job on a warm
 process *does not compile anything* — but "it felt faster" is not evidence.
-jax publishes monitoring events for exactly this: every real backend compile
-records ``/jax/core/compile/backend_compile_duration`` and every persistent
-compile-cache load records ``/jax/compilation_cache/cache_hits``; an
-in-memory jit cache hit records neither. A process-wide listener (installed
-once, at first jax use) forwards those events into ``METRICS`` under::
+jax publishes monitoring events for exactly this: every request that gets
+past the in-memory jit cache records
+``/jax/core/compile/backend_compile_duration`` when it returns — whether
+the executable was compiled or loaded from the persistent cache — and a
+persistent-cache load records ``/jax/compilation_cache/cache_hits`` first,
+on the same thread. A process-wide listener (installed once, at first jax
+use) pairs the two and forwards them into ``METRICS`` under::
 
     device.backend_compiles      count of real XLA compilations
     device.backend_compile_s     seconds spent in them
     device.compile_cache_hits    executables loaded from the persistent cache
+
+so a fresh process that found every executable on disk reports
+``backend_compiles == 0`` (the chip smoke's second leg asserts exactly that).
 
 ``METRICS`` is the scope-resolving proxy, and the listener fires on the
 thread that triggered the compile (the job thread or its context-carrying
@@ -18,16 +23,14 @@ device feeder), so in the daemon these counters land in the *owning job's*
 registry — ``tools/serve_smoke.py`` and the run reports assert warm-kernel
 behaviour from them: job 1 reports ``backend_compiles > 0``, the identical
 job 2 reports none.
-
-Failure tolerant by design: an old jax without ``jax.monitoring`` simply
-means no compile telemetry.
 """
 
-import logging
-
-log = logging.getLogger("fgumi_tpu")
+import threading
 
 _installed = False
+#: set by a cache-hit event, consumed by the duration event that closes the
+#: same compile request (both fire on the requesting thread)
+_tls = threading.local()
 
 _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
@@ -35,6 +38,9 @@ _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 def _on_duration(event: str, duration: float, **_kw):
     if event == _BACKEND_COMPILE_EVENT:
+        if getattr(_tls, "cache_hit", False):
+            _tls.cache_hit = False  # a disk load, not a compile
+            return
         from .metrics import METRICS
 
         METRICS.inc("device.backend_compiles")
@@ -55,6 +61,7 @@ def _on_duration(event: str, duration: float, **_kw):
 
 def _on_event(event: str, **_kw):
     if event == _CACHE_HIT_EVENT:
+        _tls.cache_hit = True
         from .metrics import METRICS
 
         METRICS.inc("device.compile_cache_hits")
@@ -68,16 +75,9 @@ def install() -> bool:
     global _installed
     if _installed:
         return True
-    try:
-        from jax import monitoring
-    except Exception as e:  # pragma: no cover - jax without monitoring
-        log.debug("compile watch unavailable: %s", e)
-        return False
-    try:
-        monitoring.register_event_duration_secs_listener(_on_duration)
-        monitoring.register_event_listener(_on_event)
-    except Exception as e:  # pragma: no cover - API drift tolerated
-        log.debug("compile watch not installed: %s", e)
-        return False
+    from jax import monitoring
+
+    monitoring.register_event_duration_secs_listener(_on_duration)
+    monitoring.register_event_listener(_on_event)
     _installed = True
     return True

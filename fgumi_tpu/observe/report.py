@@ -58,7 +58,16 @@ import time
 #: section records the applied deployment profile (path, knobs applied /
 #: skipped by explicit overrides, fingerprint mismatches, whether router
 #: priors were seeded — tune/profile.py).
-SCHEMA_VERSION = 7
+#: v8 (ISSUE 21): the ``device`` section is present whenever the run loaded
+#: the consensus kernel module, and always names which platform did the
+#: work: ``platform``, ``device_kind``, ``device_count`` as jax reports
+#: them in the process that ran (``cpu`` / "native f64 host engine" / 0
+#: when the run never initialised jax). ``metrics.device.backend_compiles``
+#: no longer counts executables loaded from the persistent compile cache.
+#: ``device.shapes`` lists the bucketed dispatch shapes the process has
+#: seen (``kind:dims``), and ``metrics.device.route.why.<reason>`` counts
+#: why the router sent each batch where it did.
+SCHEMA_VERSION = 8
 
 
 def _device_stats():
@@ -89,7 +98,8 @@ _REQUIRED = {
 _OPTIONAL = {
     "stages": dict,     # stage -> {"busy_s": f, "blocked_s": f}
     "queues": dict,     # {"in_mean","in_max","out_mean","out_max","samples"}
-    "device": dict,     # DeviceStats.snapshot()
+    "device": dict,     # platform/device_kind/device_count of the process
+                        # that ran (v8) + DeviceStats.snapshot()
     "io": dict,         # {"bytes_read","bytes_written"}
     "records": dict,    # progress label -> count
     "faults": dict,     # fault point -> fired count
@@ -151,6 +161,12 @@ def validate_report(obj) -> list:
             and obj["schema_version"] != SCHEMA_VERSION:
         errors.append(f"schema_version {obj['schema_version']} != "
                       f"{SCHEMA_VERSION}")
+    if isinstance(obj.get("device"), dict):
+        for key, typ in (("platform", str), ("device_kind", str),
+                         ("device_count", int)):
+            if not isinstance(obj["device"].get(key), typ):
+                errors.append(f"device.{key} is missing or not "
+                              f"{typ.__name__}")
     if isinstance(obj.get("metrics"), dict):
         for k in obj["metrics"]:
             if not isinstance(k, str) or not k:
@@ -372,8 +388,12 @@ def build_report(command: str, argv, started_unix: float, wall_s: float,
         if bsnap["transitions"] or bsnap["state"] != "closed" \
                 or bsnap["deadline_overruns"]:
             dev["breaker"] = bsnap
-    if dev.get("dispatches") or dev.get("route_host") \
-            or dev.get("breaker") or dev.get("mesh") or dev.get("routing"):
+    if stats is not None:
+        kern = sys.modules["fgumi_tpu.ops.kernel"]
+        dev.update(kern.device_identity())
+        shapes = kern.SHAPE_REGISTRY.shape_keys()
+        if shapes:
+            dev["shapes"] = shapes
         report["device"] = dev
     io_sec = {k.split(".", 1)[1]: v for k, v in metrics.items()
               if k.startswith("io.")}

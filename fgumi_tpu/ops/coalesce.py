@@ -302,6 +302,10 @@ class _MergeGroup:
             # before this point are queue-shaped time observe_device's
             # contract excludes
             t_disp = time.monotonic()
+            if deadline is not None and self.feeder_ticket.new_shape:
+                # a merged shape seen for the first time compiles inside
+                # the dispatch: wait to the ceiling (K.ticket_deadline_s)
+                deadline = max(deadline, K.dispatch_deadline_s() or 0.0)
             left = None if deadline is None else \
                 max(deadline - (time.monotonic() - t0), 0.1)
             dev = self.feeder_ticket.wait(left)
@@ -341,7 +345,8 @@ class _MergeGroup:
         leader = self.partners[0]
         tl = leader.ctx.run(
             lambda: K.DEVICE_STATS.timeline_entry(leader.slot))
-        if tl is not None:
+        # a first-sight merged shape compiled inside `wall`: not a sample
+        if tl is not None and not self.feeder_ticket.new_shape:
             from .router import ROUTER
 
             up_s = tl.get("upload_s", 0.0)

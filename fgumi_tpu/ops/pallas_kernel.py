@@ -72,9 +72,14 @@ log = logging.getLogger("fgumi_tpu")
 #: row-tile (matmul contraction dim) and segment-tile (output sublanes)
 R_TILE = 128
 S_TILE = 8
+#: lane width: the kernel's position axis is padded to a multiple of it on
+#: the device (a 100-column matmul operand is not lane-aligned)
+LANE = 128
+#: f32 exponent field — all ones means inf/NaN
+_F32_EXP_MASK = 0x7F800000
 
 _IMPORT_OK = None  # cached pallas-import probe
-_WARNED = set()    # loud-once keys (bad env value / forced-but-unavailable)
+_WARNED = set()    # loud-once keys (bad env value)
 
 
 # ---------------------------------------------------------------- selection
@@ -101,8 +106,8 @@ def kernel_backend() -> str:
 def available() -> bool:
     """Whether the Pallas lowering can be used in this process.
 
-    ``FGUMI_TPU_PALLAS_UNAVAILABLE=1`` forces False (the fallback-path
-    test hook — simulates a jaxlib built without Mosaic support)."""
+    ``FGUMI_TPU_PALLAS_UNAVAILABLE=1`` forces False (the test hook that
+    simulates a jaxlib built without Mosaic support)."""
     if os.environ.get("FGUMI_TPU_PALLAS_UNAVAILABLE", "").strip().lower() \
             in ("1", "true", "on"):
         return False
@@ -135,7 +140,8 @@ def selected_backend() -> str:
 
     - ``xla`` forced: XLA.
     - ``pallas`` forced: Pallas (interpret mode off-TPU — the test
-      path); if Pallas is unavailable, a loud error + XLA fallback.
+      path). A forced kernel that cannot run ends the run: it raises
+      rather than logging and quietly running something else.
     - ``auto``: Pallas only on a real TPU backend; CPU/GPU hosts keep
       the XLA path so production latency never pays interpret mode.
     """
@@ -143,14 +149,11 @@ def selected_backend() -> str:
     if mode == "xla":
         return "xla"
     if mode == "pallas":
-        if available():
-            return "pallas"
-        if "forced-unavailable" not in _WARNED:
-            _WARNED.add("forced-unavailable")
-            log.error("FGUMI_TPU_KERNEL=pallas but the Pallas lowering is "
-                      "unavailable in this jax install; falling back to "
-                      "the XLA kernels (parity is unaffected)")
-        return "xla"
+        if not available():
+            raise RuntimeError(
+                "FGUMI_TPU_KERNEL=pallas but the Pallas lowering is "
+                "unavailable in this jax install")
+        return "pallas"
     # auto
     return "pallas" if (available() and not interpreted()) else "xla"
 
@@ -164,49 +167,33 @@ def _bucket_pow2(n: int) -> int:
     return v
 
 
-class _Prepared:
-    """Host-side layout of one Pallas wire dispatch (window metadata +
-    row-tile-padded arrays), plus the device handles after upload."""
+class Windows:
+    """Per-segment-tile row-tile windows of one Pallas wire dispatch.
 
-    __slots__ = ("wire_p", "seg2d", "base", "cnt", "dictbits", "s_tiles",
-                 "w_tiles", "dev")
+    Computed at plan time, on the processing thread, because ``w_tiles``
+    is part of what gets compiled: the jitted wrappers are keyed by it, so
+    the shape registry has to see it before the dispatch is submitted — a
+    batch of a known (rows, L, segments) shape whose window bucket is new
+    still compiles."""
 
-    def __init__(self, wire_p, seg2d, base, cnt, dictbits, s_tiles,
-                 w_tiles):
-        self.wire_p = wire_p
-        self.seg2d = seg2d
+    __slots__ = ("base", "cnt", "s_tiles", "w_tiles", "n_rt", "dev")
+
+    def __init__(self, base, cnt, s_tiles, w_tiles, n_rt):
         self.base = base
         self.cnt = cnt
-        self.dictbits = dictbits
         self.s_tiles = s_tiles
         self.w_tiles = w_tiles
-        self.dev = None
+        self.n_rt = n_rt
+        self.dev = None  # device handles, set by upload()
 
 
-def _prepare(wire: np.ndarray, seg_ids: np.ndarray, dict32: np.ndarray,
-             num_segments: int) -> _Prepared:
-    """Row-tile padding + per-segment-tile window computation (numpy).
-
-    Pad rows carry seg id ``s_pad`` (outside every tile's range) and
-    WIRE_INVALID bytes — double-masked no-ops. Windows: seg_ids are
-    sorted, so segment tile s's rows span
-    ``searchsorted(s*S_TILE) .. searchsorted((s+1)*S_TILE)``."""
-    n_rows, L = wire.shape
+def plan_windows(seg_ids: np.ndarray, num_segments: int) -> Windows:
+    """seg_ids are sorted, so segment tile s's rows span
+    ``searchsorted(s*S_TILE) .. searchsorted((s+1)*S_TILE)``; the widest
+    span, bucketed to a power of two, is the grid's window axis."""
+    n_rows = len(seg_ids)
     s_tiles = -(-int(num_segments) // S_TILE)
-    s_pad = s_tiles * S_TILE
     n_rt = max(-(-n_rows // R_TILE), 1)
-    n_full = n_rt * R_TILE
-    if n_full != n_rows:
-        from .kernel import WIRE_INVALID
-
-        wire_p = np.full((n_full, L), WIRE_INVALID, dtype=np.uint8)
-        wire_p[:n_rows] = wire
-        segp = np.full(n_full, s_pad, dtype=np.int32)
-        segp[:n_rows] = seg_ids
-    else:
-        wire_p = wire
-        segp = np.ascontiguousarray(seg_ids, dtype=np.int32)
-    seg2d = segp.reshape(n_rt, R_TILE)
     edges = np.arange(s_tiles + 1, dtype=np.int64) * S_TILE
     bounds = np.searchsorted(seg_ids, edges, side="left")
     lo, hi = bounds[:-1], bounds[1:]
@@ -216,23 +203,40 @@ def _prepare(wire: np.ndarray, seg_ids: np.ndarray, dict32: np.ndarray,
     base = np.clip(base, 0, n_rt - 1).astype(np.int32)
     w_tiles = min(_bucket_pow2(int(cnt.max()) if len(cnt) else 1) or 1,
                   n_rt)
-    w_tiles = max(w_tiles, 1)
-    dictbits = np.ascontiguousarray(dict32, dtype=np.float32).view(np.int32)
-    return _Prepared(wire_p, seg2d, base, cnt, dictbits, s_tiles, w_tiles)
+    return Windows(base, cnt, s_tiles, max(w_tiles, 1), n_rt)
 
 
 def upload(wire: np.ndarray, seg_ids: np.ndarray, dict32: np.ndarray,
-           num_segments: int) -> _Prepared:
-    """Prepare + device_put everything a Pallas wire dispatch uploads
-    (called on the feeder thread inside the upload-timing window)."""
+           win: Windows) -> Windows:
+    """Row-tile padding + device_put of everything a Pallas wire dispatch
+    uploads (called on the feeder thread inside the upload-timing window).
+
+    Pad rows carry a seg id outside every tile's range and WIRE_INVALID
+    bytes — double-masked no-ops."""
     from .kernel import _ensure_jax
 
     jax = _ensure_jax()
-    prep = _prepare(wire, seg_ids, dict32, num_segments)
-    prep.dev = (jax.device_put(prep.wire_p), jax.device_put(prep.seg2d),
-                jax.device_put(prep.base), jax.device_put(prep.cnt),
-                jax.device_put(prep.dictbits))
-    return prep
+    n_rows, L = wire.shape
+    n_full = win.n_rt * R_TILE
+    if n_full != n_rows:
+        from .kernel import WIRE_INVALID
+
+        wire_p = np.full((n_full, L), WIRE_INVALID, dtype=np.uint8)
+        wire_p[:n_rows] = wire
+        segp = np.full(n_full, win.s_tiles * S_TILE, dtype=np.int32)
+        segp[:n_rows] = seg_ids
+    else:
+        wire_p = wire
+        segp = np.ascontiguousarray(seg_ids, dtype=np.int32)
+    # (n_rt, 1, R_TILE): the block's last two dims equal the array's, so
+    # one row tile of segment ids is a legal Mosaic block (a (1, R_TILE)
+    # block of a 2-D (n_rt, R_TILE) array breaks the (8, 128) rule)
+    seg3d = segp.reshape(win.n_rt, 1, R_TILE)
+    dictbits = np.ascontiguousarray(dict32, dtype=np.float32).view(np.int32)
+    win.dev = (jax.device_put(wire_p), jax.device_put(seg3d),
+               jax.device_put(win.base), jax.device_put(win.cnt),
+               jax.device_put(dictbits))
+    return win
 
 
 # ------------------------------------------------------------ kernel proper
@@ -268,42 +272,46 @@ def _consensus_kernel(s_tiles: int, w_tiles: int, last_w: int):
 
         @pl.when(w < cnt_ref[s])
         def _accumulate():
-            wire = wire_ref[...]  # (R_TILE, L) u8
-            qidx = (wire >> 2).astype(jnp.int32)
-            code = (wire & 3).astype(jnp.int32)
+            # widen once: Mosaic has no 8-bit vector shifts
+            wire = wire_ref[...].astype(jnp.int32)  # (R_TILE, L)
+            qidx = wire >> 2
+            code = wire & 3
             valid = qidx != 63
             # dictionary select off the SMEM scalar channel: 63 unrolled
-            # compare-selects (entry 63 is the invalid sentinel == 0).
+            # compare-selects (entry 63 is the invalid sentinel == 0) over
+            # the f32 BIT PATTERNS — Mosaic bitcasts vectors, not scalars,
+            # so the select runs in int32 and one vector bitcast follows.
             # Nonfinite (Q0-class) entries are zeroed per observation and
             # tracked in `pois` — 0 * inf through the matmul would NaN
             # the whole segment tile, where XLA's segment_sum NaNs only
             # the observation's own segment.
             L = wire.shape[1]
-            delta = jnp.zeros((R_TILE, L), jnp.float32)
+            bits = jnp.zeros((R_TILE, L), jnp.int32)
             pois = jnp.zeros((R_TILE, L), jnp.float32)
             for k in range(63):
-                tab_k = jax.lax.bitcast_convert_type(
-                    dictbits_ref[k], jnp.float32)
-                fin_k = jnp.isfinite(tab_k)
+                bits_k = dictbits_ref[k]
+                fin_k = (bits_k & _F32_EXP_MASK) != _F32_EXP_MASK
                 sel = qidx == k
-                delta = jnp.where(sel, jnp.where(fin_k, tab_k, 0.0), delta)
-                pois = jnp.where(sel & ~fin_k, 1.0, pois)
+                bits = jnp.where(sel, jnp.where(fin_k, bits_k, 0), bits)
+                pois = jnp.where(sel, jnp.where(fin_k, 0.0, 1.0), pois)
+            delta = jax.lax.bitcast_convert_type(bits, jnp.float32)
             # local segment one-hot: A[t, r] = [seg[r] == s*S_TILE + t]
-            s_local = seg_ref[...].astype(jnp.int32) - s * S_TILE  # (1, R)
+            s_local = seg_ref[...] - s * S_TILE  # (1, R_TILE)
             iota_t = jax.lax.broadcasted_iota(jnp.int32,
                                               (S_TILE, R_TILE), 0)
-            a = (iota_t == s_local).astype(jnp.float32)
+            a = jnp.where(iota_t == s_local, 1.0, 0.0)
             for b in range(4):
-                hot = ((code == b) & valid).astype(jnp.float32)
+                hot = jnp.where((code == b) & valid, 1.0, 0.0)
                 contrib_ref[b] += dot(a, delta * hot)
                 obs_ref[b] += dot(a, hot)
             poison_ref[...] += dot(a, pois)
 
         @pl.when(w == last_w)
         def _epilogue():
-            pre = jax.lax.bitcast_convert_type(prebits_ref[0], jnp.float32)
-            c = [contrib_ref[b][...] for b in range(4)]
-            o = [obs_ref[b][...] for b in range(4)]
+            c = [contrib_ref[b] for b in range(4)]
+            o = [obs_ref[b] for b in range(4)]
+            pre = jax.lax.bitcast_convert_type(
+                jnp.full(c[0].shape, prebits_ref[0], jnp.int32), jnp.float32)
             depth_f = o[0] + o[1] + o[2] + o[3]
             depth = depth_f.astype(jnp.int32)
             max_c = jnp.maximum(jnp.maximum(c[0], c[1]),
@@ -372,12 +380,12 @@ def _consensus_kernel(s_tiles: int, w_tiles: int, last_w: int):
             qual_ref[...] = qual
             dep_ref[...] = depth
             err_ref[...] = errors
-            sus_ref[...] = suspect.astype(jnp.int32)
+            sus_ref[...] = jnp.where(suspect, 1, 0)
 
     return kernel
 
 
-def _pallas_consensus(wire_p, seg2d, base, cnt, dictbits, prebits,
+def _pallas_consensus(wire_p, seg3d, base, cnt, dictbits, prebits,
                       s_tiles: int, w_tiles: int, interpret: bool):
     """pallas_call plumbing: grid/specs/scratch for the windowed kernel.
     Traced inside the jit wrappers below."""
@@ -388,30 +396,42 @@ def _pallas_consensus(wire_p, seg2d, base, cnt, dictbits, prebits,
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    n_rt, _ = seg2d.shape
+    from .kernel import WIRE_INVALID
+
+    n_rt = seg3d.shape[0]
     L = wire_p.shape[1]
+    Lp = -(-L // LANE) * LANE
+    if Lp != L:
+        # pad columns are invalid observations: depth 0, no-call, sliced
+        # off below — padded here, on the device, so the upload stays L
+        wire_p = jnp.pad(wire_p, ((0, 0), (0, Lp - L)),
+                         constant_values=WIRE_INVALID)
     s_pad = s_tiles * S_TILE
 
-    def _row_tile(s, w, base_ref, cnt_ref, _db, _pb):
+    def _row_tile(s, w, base_ref, cnt_ref):
         wc = jnp.minimum(w, jnp.maximum(cnt_ref[s] - 1, 0))
-        return (jnp.minimum(base_ref[s] + wc, n_rt - 1), 0)
+        return jnp.minimum(base_ref[s] + wc, n_rt - 1)
 
-    out_shape = [jax.ShapeDtypeStruct((s_pad, L), jnp.int32)
+    out_shape = [jax.ShapeDtypeStruct((s_pad, Lp), jnp.int32)
                  for _ in range(5)]
-    out_specs = [pl.BlockSpec((S_TILE, L), lambda s, w, *_: (s, 0))
+    out_specs = [pl.BlockSpec((S_TILE, Lp), lambda s, w, *_: (s, 0))
                  for _ in range(5)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(s_tiles, w_tiles),
         in_specs=[
-            pl.BlockSpec((1, R_TILE), _row_tile),   # seg2d
-            pl.BlockSpec((R_TILE, L), _row_tile),   # wire
+            pl.BlockSpec((None, 1, R_TILE),           # seg ids
+                         lambda s, w, b, c, _db, _pb:
+                         (_row_tile(s, w, b, c), 0, 0)),
+            pl.BlockSpec((R_TILE, Lp),                # wire
+                         lambda s, w, b, c, _db, _pb:
+                         (_row_tile(s, w, b, c), 0)),
         ],
         out_specs=out_specs,
         scratch_shapes=[
-            pltpu.VMEM((4, S_TILE, L), jnp.float32),  # contrib
-            pltpu.VMEM((4, S_TILE, L), jnp.float32),  # obs
-            pltpu.VMEM((S_TILE, L), jnp.float32),     # poison
+            pltpu.VMEM((4, S_TILE, Lp), jnp.float32),  # contrib
+            pltpu.VMEM((4, S_TILE, Lp), jnp.float32),  # obs
+            pltpu.VMEM((S_TILE, Lp), jnp.float32),     # poison
         ],
     )
     fn = pl.pallas_call(
@@ -420,7 +440,8 @@ def _pallas_consensus(wire_p, seg2d, base, cnt, dictbits, prebits,
         out_shape=out_shape,
         interpret=interpret,
     )
-    return fn(base, cnt, dictbits, prebits, seg2d, wire_p)
+    outs = fn(base, cnt, dictbits, prebits, seg3d, wire_p)
+    return [o[:, :L] for o in outs] if Lp != L else outs
 
 
 # --------------------------------------------------- jitted entry wrappers
@@ -445,9 +466,9 @@ def _full_jit(out_segments: int, s_tiles: int, w_tiles: int,
     jax = _ensure_jax()
     import jax.numpy as jnp
 
-    def fn(wire_p, seg2d, base, cnt, dictbits, prebits):
+    def fn(wire_p, seg3d, base, cnt, dictbits, prebits):
         win, qual, dep, err, sus = _pallas_consensus(
-            wire_p, seg2d, base, cnt, dictbits, prebits, s_tiles, w_tiles,
+            wire_p, seg3d, base, cnt, dictbits, prebits, s_tiles, w_tiles,
             interpret)
         qs, wp = _pack_split(win, qual, sus, out_segments)
         return (qs, wp, dep[:out_segments].astype(jnp.uint16),
@@ -464,11 +485,11 @@ def _filter_jit(out_segments: int, s_tiles: int, w_tiles: int,
     jax = _ensure_jax()
     import jax.numpy as jnp
 
-    def fn(wire_p, seg2d, base, cnt, dictbits, prebits, min_reads_c,
+    def fn(wire_p, seg3d, base, cnt, dictbits, prebits, min_reads_c,
            min_qual_c, lens, f_min_reads, f_emin_tab, f_min_base_q,
            f_per_base):
         win, qual, dep, err, sus = _pallas_consensus(
-            wire_p, seg2d, base, cnt, dictbits, prebits, s_tiles, w_tiles,
+            wire_p, seg3d, base, cnt, dictbits, prebits, s_tiles, w_tiles,
             interpret)
         qs, wp = _pack_split(win, qual, sus, out_segments)
         # filter epilogue — _wire_filter_fn twin over the kernel's
@@ -517,7 +538,7 @@ def _prebits(ln_error_pre_umi) -> np.ndarray:
                       dtype=np.float32).view(np.int32)
 
 
-def call_full(prep: _Prepared, ln_error_pre_umi, out_segments: int):
+def call_full(prep: Windows, ln_error_pre_umi, out_segments: int):
     """Full-column Pallas dispatch: the _wire_full_fn contract —
     (qs u8, wp u8, depth u16, errors u16), sliced to out_segments."""
     fn = _full_jit(int(out_segments), prep.s_tiles, prep.w_tiles,
@@ -525,7 +546,7 @@ def call_full(prep: _Prepared, ln_error_pre_umi, out_segments: int):
     return fn(*prep.dev, _prebits(ln_error_pre_umi))
 
 
-def call_filter(prep: _Prepared, ln_error_pre_umi, min_reads_c, min_qual_c,
+def call_filter(prep: Windows, ln_error_pre_umi, min_reads_c, min_qual_c,
                 lens_pad: np.ndarray, fparams, out_segments: int):
     """Fused consensus→filter Pallas dispatch: the
     ``_consensus_segments_wire_filter_jit`` contract —

@@ -22,8 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..ops.kernel import (_call_epilogue, _reduce_contributions,
-                          shard_map_compat)
+from ..ops.kernel import _call_epilogue, _reduce_contributions
 
 from . import MeshConfigError
 
@@ -154,7 +153,7 @@ def sharded_consensus_fn(mesh: Mesh, correct_tab, err_tab, ln_error_pre_umi):
         obs = jax.lax.psum(obs, "sp")
         return _call_epilogue(contrib, obs, pre)
 
-    mapped = shard_map_compat(
+    mapped = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P("dp", "sp", None), P("dp", "sp", None)),

@@ -191,7 +191,10 @@ class JobService:
 
         Enables the persistent XLA compile cache (optionally at an explicit
         directory), imports jax, and touches the backend so device
-        discovery/claiming happens now — not inside job 1's latency."""
+        discovery/claiming happens now — not inside job 1's latency.
+        A backend that cannot be reached raises: a daemon that was not
+        started for the CPU (``JAX_PLATFORMS=cpu``) and cannot claim its
+        chip must fail its start, not serve every job from the host."""
         from ..utils.compile_cache import enable_persistent_cache
 
         cache = enable_persistent_cache(compile_cache_dir)
@@ -199,18 +202,14 @@ class JobService:
             log.info("serve: persistent compile cache at %s", cache)
         if not touch_device:
             return
-        try:
-            t0 = time.monotonic()
-            from ..ops.kernel import _ensure_jax
+        t0 = time.monotonic()
+        from ..ops.kernel import _ensure_jax
 
-            jax = _ensure_jax()
-            devs = jax.devices()
-            log.info("serve: warm backend %s (%d device(s)) in %.2fs",
-                     devs[0].platform if devs else "none", len(devs),
-                     time.monotonic() - t0)
-        except Exception as e:  # noqa: BLE001 - serving still works cold
-            log.warning("serve: device warm-up failed (%s); jobs will pay "
-                        "cold start", e)
+        jax = _ensure_jax()
+        devs = jax.devices()
+        log.info("serve: warm backend %s (%s x%d) in %.2fs",
+                 devs[0].platform, devs[0].device_kind, len(devs),
+                 time.monotonic() - t0)
 
     # -- job execution ------------------------------------------------------
 

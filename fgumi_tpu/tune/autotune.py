@@ -191,11 +191,11 @@ def _bench_choosers(quick):
     better than the cold alternating probe."""
     import numpy as np
 
-    try:
-        import jax
-        import jax.numpy as jnp
-    except Exception:
-        return {}
+    from ..ops.kernel import _ensure_jax
+
+    jax = _ensure_jax()
+    import jax.numpy as jnp
+
     n, L = (512, 100) if quick else (4096, 150)
     cells = n * L
     a = np.random.default_rng(5).integers(0, 41, size=(n, L),

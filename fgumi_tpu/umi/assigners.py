@@ -300,19 +300,14 @@ def _get_dist_jit():
     call — measured at ~0.5s per group)."""
     global _dist_jit
     if _dist_jit is None:
-        import jax
+        # the one guarded first import of jax (a fused chain's simplex
+        # stage may be importing it on another thread right now); it also
+        # enables the persistent compile cache, which group/dedup runs
+        # reach only through this kernel
+        from ..ops.kernel import _ensure_jax
+
+        jax = _ensure_jax()
         import jax.numpy as jnp
-
-        from ..utils.compile_cache import enable_persistent_cache
-
-        enable_persistent_cache()  # cross-process reuse of the compiles
-
-        # group/dedup runs reach the device only through this kernel, so the
-        # persistent XLA cache must be enabled here too (first 16k-UMI group
-        # otherwise pays the ~2s compile in every CLI invocation)
-        from ..ops.kernel import _enable_persistent_compile_cache
-
-        _enable_persistent_compile_cache()
 
         @jax.jit
         def dist(a, b):
@@ -329,9 +324,8 @@ def _get_dist_jit():
 
 
 def _device_pairwise(mat_a: np.ndarray, mat_b: np.ndarray) -> np.ndarray:
-    import jax.numpy as jnp
-
     dist = _get_dist_jit()
+    import jax.numpy as jnp
 
     from ..ops.kernel import DEVICE_STATS
 

@@ -71,14 +71,13 @@ def test_invalid_kernel_value_is_loud_once(monkeypatch, caplog):
     assert len(errs) == 1  # loud, but once per distinct bad value
 
 
-def test_forced_pallas_unavailable_falls_back_loudly(monkeypatch, caplog):
-    monkeypatch.setattr(pk, "_WARNED", set())
+def test_forced_pallas_unavailable_raises(monkeypatch):
+    """A forced kernel that cannot run ends the run — no log-and-use-XLA."""
     monkeypatch.setenv("FGUMI_TPU_KERNEL", "pallas")
     monkeypatch.setenv("FGUMI_TPU_PALLAS_UNAVAILABLE", "1")
     assert pk.available() is False
-    with caplog.at_level(logging.ERROR, logger="fgumi_tpu"):
-        assert pk.selected_backend() == "xla"
-    assert any("falling back" in r.message for r in caplog.records)
+    with pytest.raises(RuntimeError, match="unavailable"):
+        pk.selected_backend()
 
 
 def test_auto_keeps_xla_off_tpu(monkeypatch):

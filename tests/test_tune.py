@@ -202,11 +202,11 @@ def _auto_kernel():
     return K()
 
 
-def test_seeded_router_routes_measured_side_first_batch():
-    """The cold static priors (10 MB/s link, 20 Mcells/s host) price every
-    first batch onto the host; a profile recording this host's measured
-    fast link and slow host engine flips the very first fam-3 batch onto
-    the device — the whole point of atlas-seeded priors."""
+def test_cold_and_seeded_router_first_batch():
+    """A cold router has never timed the device, so its static priors do
+    not get to price the device out: the first batch goes to the device
+    as an unmeasured-side probe. A profile-seeded router counts as
+    measured and decides its first batch on cost, either way."""
     pytest.importorskip("fgumi_tpu.native.batch")
     from fgumi_tpu.native import batch as nb
 
@@ -215,8 +215,13 @@ def test_seeded_router_routes_measured_side_first_batch():
     cold = OffloadRouter()
     # fam-3 shape: 4000 families x 3 reads x L=100
     shape = dict(n_rows=12000, n_segments=4000, L=100)
-    assert cold.decide_batch(_auto_kernel(), **shape) == "host"
-    assert cold.snapshot()["prior_source"] == "cold"
+    assert cold.decide_batch(_auto_kernel(), **shape) == "device"
+    snap = cold.snapshot()
+    assert snap["prior_source"] == "cold"
+    assert snap["last_decision"]["why"] == "probe-unmeasured"
+    # on the priors alone the model would have said host
+    pred = snap["last_decision"]
+    assert pred["pred_device_s"] > pred["pred_host_s"]
 
     seeded = OffloadRouter()
     assert seeded.seed_priors({
@@ -226,6 +231,13 @@ def test_seeded_router_routes_measured_side_first_batch():
     snap = seeded.snapshot()
     assert snap["prior_source"] == "profile"
     assert snap["last_decision"]["why"] == "cost"
+
+    slow = OffloadRouter()
+    assert slow.seed_priors({
+        "link_mbps": 1.0, "overhead_s": 1.0, "dispatch_wall_s": 1.0,
+        "host_mcells_per_s": 500.0}, source="profile")
+    assert slow.decide_batch(_auto_kernel(), **shape) == "host"
+    assert slow.snapshot()["last_decision"]["why"] == "cost"
 
 
 def test_seeding_is_cold_only():

@@ -1,0 +1,8 @@
+"""Seconds a job's ``filter`` stage thread works (``chain.filter``: wall minus
+the waits below it on that thread), mean of the traced jobs."""
+
+import spans
+
+
+def read(run):
+    return spans.stage_busy_s(run, "filter")

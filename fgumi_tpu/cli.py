@@ -1718,11 +1718,17 @@ def _cmd_sort_chain(args, source, sink, budget):
             if not isinstance(sorter, NativeExternalSorter):
                 log.error("sort: fused chain requires the native sorter")
                 return 2
-            sorter.ingest_batches(iter(source), batch_keys_fn, progress.add)
+            from .observe.trace import span, spanned_iter
+
+            with span("sort.ingest", rusage=True):
+                sorter.ingest_batches(iter(source), batch_keys_fn,
+                                      progress.add)
             progress.finish()
             wprogress = ProgressTracker("sort-write")
             with sink(out_header) as writer:
-                for arr in sorter.iter_sorted_wire():
+                for arr in spanned_iter("sort.merge",
+                                        sorter.iter_sorted_wire(),
+                                        rusage=True):
                     writer.write_serialized(arr)
                     wprogress.add()
             wprogress.finish()
@@ -3124,6 +3130,7 @@ def _pipeline_fused(args):
     from .observe import heartbeat as _hb
     from .observe.metrics import METRICS
     from .observe.scope import spawn_thread
+    from .observe.trace import span
     from .pipeline_chain import (ChainAborted, ChainChannel,
                                  ChannelBamWriter, ChannelBatchReader)
 
@@ -3186,6 +3193,11 @@ def _pipeline_fused(args):
         aborted = False
         with lock:
             active[name] = True
+        # one span for the stage thread's life: its waits (chain.put/get,
+        # queue waits) are children, so wall_s - wait_s is the stage's own
+        # work on this thread, with the thread's CPU seconds and faults
+        stage_span = span(f"chain.{name}", rusage=True)
+        stage_span.__enter__()
         try:
             # per-stage compat mapping (BGZF level contextvar etc.) runs in
             # this thread's context copy, so stages stay isolated exactly
@@ -3199,6 +3211,7 @@ def _pipeline_fused(args):
         except BaseException as e:  # noqa: BLE001 - relayed to the driver
             err = e
         finally:
+            stage_span.__exit__(None, None, None)
             wall = time.monotonic() - t0
             with lock:
                 active.pop(name, None)
@@ -4436,6 +4449,9 @@ def _telemetry_config(args):
 
 
 def main(argv=None):
+    from .observe import process as _process
+
+    _process.note_main()
     parser = build_parser()
     args = parser.parse_args(argv)
     from .observe.logs import setup_logging
@@ -4551,6 +4567,14 @@ def _main_scoped(args, argv):
             nth = 1
         xprof.configure(xla_dir, nth)
     tracer = hb = None
+    armed = bool(trace_path or report_path)
+    if armed:
+        # spans are live under either flag: the aggregate (the report's
+        # `spans` section) and the mirror onto the profiler's clock; the
+        # Chrome tracer below only under --trace
+        from .observe.trace import arm_spans
+
+        arm_spans()
     if trace_path:
         from .observe.scope import current_scope
         from .observe.trace import start_trace
@@ -4588,9 +4612,8 @@ def _main_scoped(args, argv):
         if hb is not None:
             hb.stop()
         if tracer is not None:
-            from .observe.trace import stop_trace, write_trace
+            from .observe.trace import write_trace
 
-            stop_trace()
             try:
                 write_trace(trace_path, tracer)
                 log.info("trace: %d spans -> %s (open in "
@@ -4617,6 +4640,10 @@ def _main_scoped(args, argv):
                           t0_unix, time.monotonic() - t0, rc, trace_path)
             if report is not None:
                 log.info("run report -> %s", report_path)
+        if armed:
+            from .observe.trace import stop_trace
+
+            stop_trace()  # after the report, which reads the aggregate
 
 
 if __name__ == "__main__":

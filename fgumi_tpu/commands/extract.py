@@ -311,6 +311,7 @@ def _run_extract_fast(inputs, output, structures, opts, offset, header,
     supported option surface (tests/test_extract_fast.py)."""
     from ..io.fastq import FastqBatchReader
     from ..native import batch as nb
+    from ..observe.trace import span
 
     segments = []
     for k, rs in enumerate(structures):
@@ -333,7 +334,8 @@ def _run_extract_fast(inputs, output, structures, opts, offset, header,
             while True:
                 for i, it in enumerate(iters):
                     if cur[i] is None or cur[i][1] >= len(cur[i][0][1]):
-                        nxt = next(it, None)
+                        with span("extract.read", rusage=True):
+                            nxt = next(it, None)  # gunzip + FASTQ lexing
                         cur[i] = (nxt, 0) if nxt is not None else None
                 if all(c is None for c in cur):
                     break
@@ -359,11 +361,12 @@ def _run_extract_fast(inputs, output, structures, opts, offset, header,
                     qual_off.append(qo[pos:pos + take])
                     cur[i] = (batch, pos + take)
                 try:
-                    blob = nb.extract_records(
-                        bufs, np.stack(name_off), np.stack(name_len),
-                        np.stack(seq_off), np.stack(seq_len),
-                        np.stack(qual_off), segments, offset, rg,
-                        opts.store_umi_quals)
+                    with span("extract.records", rusage=True):
+                        blob = nb.extract_records(
+                            bufs, np.stack(name_off), np.stack(name_len),
+                            np.stack(seq_off), np.stack(seq_len),
+                            np.stack(qual_off), segments, offset, rg,
+                            opts.store_umi_quals)
                 except nb.NativeExtractError as e:
                     # canonical error path: rebuild the offending record as
                     # FastqReads and let make_records raise its ExtractError

@@ -25,6 +25,7 @@ from ..consensus.filter import (EXCESSIVE_ERROR_RATE, INSUFFICIENT_READS,
                                 simplex_base_mask_arrays)
 from ..io.bam import FLAG_SECONDARY, FLAG_SUPPLEMENTARY, FLAG_UNMAPPED
 from ..native import batch as nb
+from ..observe.trace import spanned
 from .filter import FilterStats, _process_one
 
 _R_PASS, _R_INSUF, _R_ERR, _R_LOWQ, _R_NOCALL = 0, 1, 2, 3, 4
@@ -82,6 +83,7 @@ class FastFilter:
         self.stats = FilterStats()
         self._carry = []        # (record bytes,) of the open name group
 
+    @spanned("filter.batch", rusage=True)
     def process_batch(self, batch, emit, emit_reject):
         """Filter one batch; emit(buf_slice_bytes) per kept wire chunk."""
         n = batch.n

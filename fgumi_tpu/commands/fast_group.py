@@ -24,6 +24,7 @@ from ..io.bam import (FLAG_FIRST, FLAG_LAST, FLAG_MATE_UNMAPPED, FLAG_PAIRED,
                       FLAG_QC_FAIL, FLAG_REVERSE, FLAG_SECONDARY,
                       FLAG_SUPPLEMENTARY, FLAG_UNMAPPED)
 from ..native import batch as nb
+from ..observe.trace import spanned
 from .group import (FilterMetrics, append_mi_tag, assign_group, extract_umi,
                     filter_template, pair_orientation)
 
@@ -298,6 +299,7 @@ class FastGrouper:
         self._tail = self._materialize(batch, tbounds, nT - 1)
         return out
 
+    @spanned("group.defer", rusage=True)
     def _defer_templates(self, batch, tbounds, ts):
         """Append templates of the open group to the carry: filter + tally
         now (vectorized), carry only the kept templates' UMI strings and
@@ -422,6 +424,7 @@ class FastGrouper:
 
     # ------------------------------------------------------------------- keys
 
+    @spanned("group.keys", rusage=True)
     def _template_keys(self, batch, tbounds, nT):
         """Per-template position-key fields, (nT, 7) int64:
         lib_ord, a_tid, a_pos, a_strand, b_tid, b_pos, b_strand."""
@@ -705,6 +708,7 @@ class FastGrouper:
             self.family_sizes[int(size)] = \
                 self.family_sizes.get(int(size), 0) + int(cnt)
 
+    @spanned("group.rewrite", rusage=True)
     def _flush_pending(self, batch, rows, values):
         if len(rows) == 0:
             return []
@@ -730,6 +734,7 @@ class FastGrouper:
         umis, okeys = self._umi_strings(batch, kept_t)
         return self._assign_umis(umis, okeys)
 
+    @spanned("group.assign", rusage=True)
     def _assign_umis(self, umis, okeys):
         """assign_group's subgroup/truncate/assign tail over prepared UMI
         strings; returns MoleculeIds in entry order."""

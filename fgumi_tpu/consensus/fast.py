@@ -20,6 +20,7 @@ from ..io.bam import (FLAG_FIRST, FLAG_LAST, FLAG_MATE_UNMAPPED, FLAG_PAIRED,
                       FLAG_REVERSE, FLAG_SECONDARY, FLAG_SUPPLEMENTARY,
                       FLAG_UNMAPPED)
 from ..native import batch as nb
+from ..observe.trace import span as _span
 from ..ops import oracle
 from .overlapping import (AGREEMENT_CODES, DISAGREEMENT_CODES,
                           add_native_overlap_stats)
@@ -240,7 +241,8 @@ class _PendingChunk:
             winner, qual, depth, errors = kernel.resolve_segments_wire(
                 ticket, codes_d, quals_d, starts)
             self._assign(idxs, winner, qual, depth, errors)
-        return fast._serialize_jobs(self.batch, self.jobs, self.blocks)
+        with _span("resolve.serialize", rusage=True):
+            return fast._serialize_jobs(self.batch, self.jobs, self.blocks)
 
     def _assign(self, idxs, winner, qual, depth, errors):
         """Thresholds in one vectorized pass; rows are handed to the
@@ -303,56 +305,66 @@ class FastSimplexCaller:
         processed via the slow path, with overlap correction applied there so
         pairs split across batches are still corrected.
         """
-        flag = batch.flag
-        keep = (flag & (FLAG_SECONDARY | FLAG_SUPPLEMENTARY)) == 0
-        if not allow_unmapped:
-            is_mapped = (flag & FLAG_UNMAPPED) == 0
-            mapped_mate = ((flag & FLAG_PAIRED) != 0) \
-                & ((flag & FLAG_MATE_UNMAPPED) == 0)
-            keep &= is_mapped | mapped_mate
-        idx = np.nonzero(keep)[0]
+        with _span("process.decode", rusage=True):
+            flag = batch.flag
+            keep = (flag & (FLAG_SECONDARY | FLAG_SUPPLEMENTARY)) == 0
+            if not allow_unmapped:
+                is_mapped = (flag & FLAG_UNMAPPED) == 0
+                mapped_mate = ((flag & FLAG_PAIRED) != 0) \
+                    & ((flag & FLAG_MATE_UNMAPPED) == 0)
+                keep &= is_mapped | mapped_mate
+            idx = np.nonzero(keep)[0]
+            if len(idx):
+                # every tag this engine reads for the batch, one native aux
+                # scan
+                batch.prefetch_tags([self.tag, b"MC", b"RX"])
         if len(idx) == 0:
             return self.flush() if final else []
 
-        # every tag this engine reads for the batch, one native aux scan
-        batch.prefetch_tags([self.tag, b"MC", b"RX"])
-        mi_off, mi_len, _ = batch.tag_locs(self.tag)
-        starts = nb.group_starts(batch.buf, np.ascontiguousarray(mi_off[idx]),
-                                 mi_len[idx])
-        bounds = np.append(starts, len(idx))
-        n_total = len(bounds) - 1
+        with _span("process.group", rusage=True):
+            mi_off, mi_len, _ = batch.tag_locs(self.tag)
+            starts = nb.group_starts(
+                batch.buf, np.ascontiguousarray(mi_off[idx]), mi_len[idx])
+            bounds = np.append(starts, len(idx))
+            n_total = len(bounds) - 1
 
-        # does the first group continue the carried group?
-        first_mi = batch.tag_bytes(self.tag, int(idx[bounds[0]]))
-        merge_carry = self._carry is not None and self._carry[0] == first_mi
-        if merge_carry:
-            # materialize before any in-place correction of this batch
-            self._carry[1].extend(batch.raw_records(idx[bounds[0]:bounds[1]]))
+            # does the first group continue the carried group?
+            first_mi = batch.tag_bytes(self.tag, int(idx[bounds[0]]))
+            merge_carry = self._carry is not None \
+                and self._carry[0] == first_mi
+            if merge_carry:
+                # materialize before any in-place correction of this batch
+                self._carry[1].extend(
+                    batch.raw_records(idx[bounds[0]:bounds[1]]))
 
-        # groups [g0, g1) run the vectorized path this call; the last group of
-        # a non-final batch is deferred (it may continue into the next batch)
-        g0 = 1 if merge_carry else 0
-        g1 = n_total if final else max(n_total - 1, g0)
-        deferred = None
-        if not final and n_total - 1 >= g0:
-            last = idx[bounds[n_total - 1]:bounds[n_total]]
-            # materialize before in-place correction: the deferred group is
-            # corrected exactly once, on the slow path, when it completes
-            deferred = (batch.tag_bytes(self.tag, int(last[0])),
-                        batch.raw_records(last))
+            # groups [g0, g1) run the vectorized path this call; the last
+            # group of a non-final batch is deferred (it may continue into
+            # the next batch)
+            g0 = 1 if merge_carry else 0
+            g1 = n_total if final else max(n_total - 1, g0)
+            deferred = None
+            if not final and n_total - 1 >= g0:
+                last = idx[bounds[n_total - 1]:bounds[n_total]]
+                # materialize before in-place correction: the deferred group
+                # is corrected exactly once, on the slow path, when it
+                # completes
+                deferred = (batch.tag_bytes(self.tag, int(last[0])),
+                            batch.raw_records(last))
 
-        out = []
-        if self._carry is not None:
-            # the carry completes unless the merged group is still the open
-            # tail of a non-final batch (merge_carry and no group follows)
-            if (not merge_carry) or final or n_total >= 2:
-                out.extend(self._call_slow_group(*self._carry))
-                self._carry = None
+            out = []
+            if self._carry is not None:
+                # the carry completes unless the merged group is still the
+                # open tail of a non-final batch (merge_carry and no group
+                # follows)
+                if (not merge_carry) or final or n_total >= 2:
+                    out.extend(self._call_slow_group(*self._carry))
+                    self._carry = None
 
         if g1 > g0:
             # native in-place overlap correction only for the complete groups
             if self.overlap_caller is not None:
-                self._overlap_correct(batch, idx, bounds, g0, g1)
+                with _span("process.overlap", rusage=True):
+                    self._overlap_correct(batch, idx, bounds, g0, g1)
             out.extend(self._process_groups(batch, idx, bounds, g0, g1))
 
         if deferred is not None:
@@ -414,6 +426,19 @@ class FastSimplexCaller:
             return self._post_slow(
                 [b"".join(len(r).to_bytes(4, "little") + r for r in recs)])
 
+        with _span("process.prep", rusage=True):
+            codes, quals, table = self._prepare_jobs(batch, idx, bounds, g0,
+                                                     g1)
+        if len(table) == 0:
+            return []
+        pending, blocks0 = self._dispatch_jobs(codes, quals, table)
+        return [_PendingChunk(self, batch, table, pending, blocks0)]
+
+    def _prepare_jobs(self, batch, idx, bounds, g0, g1):
+        """Native prep of groups [g0, g1): mate clips, packed reads, and the
+        job table. Returns (codes, quals, table)."""
+        caller = self.caller
+        opts = caller.options
         # batch-wide native prep over the kept records of the processed groups
         span = idx[bounds[g0]:bounds[g1]]
         mc_off, mc_len, _ = batch.tag_locs_str(b"MC")
@@ -472,11 +497,7 @@ class FastSimplexCaller:
             gb = rel_bounds[g0:g1 + 1]
             table = self._prepare_groups_vec(batch, span, gb, rtype,
                                              final_len, group_uniform)
-
-        if len(table) == 0:
-            return []
-        pending, blocks0 = self._dispatch_jobs(codes, quals, table)
-        return [_PendingChunk(self, batch, table, pending, blocks0)]
+        return codes, quals, table
 
     def _prepare_groups_vec(self, batch, span, gb, rtype, final_len,
                             group_uniform):
@@ -852,28 +873,31 @@ class FastSimplexCaller:
         count = table.count
         blocks0 = []
 
-        single = np.nonzero(count == 1)[0]
-        if len(single):
-            rows1 = table.pool_rows[table.vlo[single]]
-            Lm = int(table.cons_len[single].max())
-            b, q, d, e = oracle.single_read_consensus(
-                codes[rows1, :Lm], quals[rows1, :Lm], caller.tables,
-                opts.min_consensus_base_quality)
-            blocks0.append((single, np.ascontiguousarray(b),
-                            np.ascontiguousarray(q),
-                            np.ascontiguousarray(d.astype(np.int32)),
-                            np.ascontiguousarray(e.astype(np.int32))))
+        with _span("process.prep", rusage=True):
+            # single-read jobs on the host, and the multi-read jobs' rows
+            single = np.nonzero(count == 1)[0]
+            if len(single):
+                rows1 = table.pool_rows[table.vlo[single]]
+                Lm = int(table.cons_len[single].max())
+                b, q, d, e = oracle.single_read_consensus(
+                    codes[rows1, :Lm], quals[rows1, :Lm], caller.tables,
+                    opts.min_consensus_base_quality)
+                blocks0.append((single, np.ascontiguousarray(b),
+                                np.ascontiguousarray(q),
+                                np.ascontiguousarray(d.astype(np.int32)),
+                                np.ascontiguousarray(e.astype(np.int32))))
 
-        multi = np.nonzero(count > 1)[0]
+            multi = np.nonzero(count > 1)[0]
+            if len(multi):
+                counts = count[multi]
+                rows_all = table.pool_rows[_ranges(table.vlo[multi], counts)]
+                # 4-multiple L >= every job's consensus length (<= the pack
+                # stride); 4 (not 16) because every padded position is an
+                # uploaded wire byte and the 2-bit winner output packs 4
+                # positions per byte
+                L_max = -(-int(table.cons_len[multi].max()) // 4) * 4
         if len(multi) == 0:
             return None, blocks0
-
-        counts = count[multi]
-        rows_all = table.pool_rows[_ranges(table.vlo[multi], counts)]
-        # 4-multiple L >= every job's consensus length (<= the pack stride);
-        # 4 (not 16) because every padded position is an uploaded wire byte
-        # and the 2-bit winner output packs 4 positions per byte
-        L_max = -(-int(table.cons_len[multi].max()) // 4) * 4
 
         from ..ops.kernel import HOST_DISPATCH, device_path
         from ..ops.router import ROUTER
@@ -908,11 +932,12 @@ class FastSimplexCaller:
             # CONCURRENTLY on the resolve pool, so e2e throughput is
             # device + host, not min of the two. No pad, no device layout:
             # the native engine consumes ragged rows.
-            starts = np.concatenate(([0], np.cumsum(counts)))
-            return ("seg", multi, starts,
-                    np.ascontiguousarray(codes[rows_all, :L_max]),
-                    np.ascontiguousarray(quals[rows_all, :L_max]),
-                    HOST_DISPATCH), blocks0
+            with _span("engine.host_gather", rusage=True):
+                starts = np.concatenate(([0], np.cumsum(counts)))
+                return ("seg", multi, starts,
+                        np.ascontiguousarray(codes[rows_all, :L_max]),
+                        np.ascontiguousarray(quals[rows_all, :L_max]),
+                        HOST_DISPATCH), blocks0
 
         if device_path() == "columns":
             # round-5 comparison route (FGUMI_TPU_DEVICE_PATH=columns):
@@ -935,9 +960,26 @@ class FastSimplexCaller:
         # byte-identity with the single-device path is the test oracle.
         import time
 
-        from ..ops.kernel import pad_segments_gather, pad_segments_mesh
+        # gather + pad + wire build == this batch's pack: the span ends with
+        # the dispatch handed to the feeder, a few microseconds after the
+        # timeline's pack_s stamp (begin_in_flight), which it must agree with
+        with _span("engine.pack", rusage=True):
+            t_pack0 = time.monotonic()
+            return self._pack_and_dispatch(
+                codes, quals, table, multi, counts, rows_all, L_max, full,
+                fused_filter, t_pack0), blocks0
 
-        t_pack0 = time.monotonic()  # gather+pad+wire == this batch's pack
+    def _pack_and_dispatch(self, codes, quals, table, multi, counts, rows_all,
+                           L_max, full, fused_filter, t_pack0):
+        """The device route of one batch: gather + pad into the device
+        layout, wire build and hand-off to the feeder. Returns the pending
+        tuple ``_PendingChunk.resolve`` completes."""
+        from ..ops.kernel import pad_segments_gather, pad_segments_mesh
+        from ..ops.router import ROUTER
+
+        kernel = self.caller.kernel
+        opts = self.caller.options
+        mesh = self.mesh
         pred = ROUTER.last_prediction()
         if mesh is not None:
             codes_d = np.ascontiguousarray(codes[rows_all, :L_max])
@@ -949,8 +991,7 @@ class FastSimplexCaller:
                 pack_t0=t_pack0, full=full,
                 pred_s=pred[0] if pred else None, mesh=mesh,
                 mesh_gather=gather)
-            return ("segw", multi, starts_p, codes_d, quals_d,
-                    ticket), blocks0
+            return ("segw", multi, starts_p, codes_d, quals_d, ticket)
         codes_dev, quals_dev, seg_ids, starts_p, F_pad, N_real = \
             pad_segments_gather(codes, quals, rows_all, L_max, counts)
         if fused_filter:
@@ -967,13 +1008,13 @@ class FastSimplexCaller:
                     table.cons_len[multi].astype(np.int32),
                     self.filter_stage.dev_params))
             return ("segwf", multi, starts_p, codes_dev[:N_real],
-                    quals_dev[:N_real], ticket), blocks0
+                    quals_dev[:N_real], ticket)
         ticket = kernel.device_call_segments_wire(
             codes_dev, quals_dev, seg_ids, F_pad, len(multi),
             pack_t0=t_pack0, full=full,
             pred_s=pred[0] if pred else None)
         return ("segw", multi, starts_p, codes_dev[:N_real],
-                quals_dev[:N_real], ticket), blocks0
+                quals_dev[:N_real], ticket)
 
     # ------------------------------------------------------------------ output
 

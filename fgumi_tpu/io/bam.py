@@ -843,9 +843,12 @@ class BamWriter:
     def write_serialized(self, blob: bytes):
         """Append records already carrying their block_size prefixes
         (the native batch serializer's output)."""
-        if self._audit is not None:
-            self._audit.add_serialized(blob)
-        self._w.write(blob)
+        from ..observe.trace import span
+
+        with span("sink.write", bytes=len(blob)):
+            if self._audit is not None:
+                self._audit.add_serialized(blob)
+            self._w.write(blob)
 
     def write_indexed(self, blob, starts):
         """Append a prefix-framed record blob and return the BGZF virtual

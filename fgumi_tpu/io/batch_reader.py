@@ -162,29 +162,44 @@ class _BatchAssembler:
 
     def __iter__(self):
         while True:
-            self._fill()
-            if not self._parts_len:
+            batch = self._next_batch()
+            if batch is None:
                 return
-            buf = (self._parts[0] if len(self._parts) == 1
-                   else np.concatenate(self._parts))
-            max_records = len(buf) // _MIN_RECORD_WIRE + 1
-            offsets, scanned = nb.find_boundaries(buf, max_records)
-            if len(offsets) == 0:
-                if self._eof:
-                    raise EOFError("truncated BAM record at end of stream")
-                # a single record larger than the accumulated bytes: grow
-                self._target *= 2
-                self._parts = [buf]
-                self._parts_len = len(buf)
-                continue
-            # tail: copy the (at most one partial record) remainder so the
-            # next batch doesn't pin this batch's full buffer
-            tail = buf[scanned:].copy()
-            self._parts = [tail] if len(tail) else []
-            self._parts_len = len(tail)
-            # a trailing partial record at EOF surfaces as an empty scan on the
-            # next iteration and raises there, after this chunk is consumed
-            yield RecordBatch(buf[:scanned], offsets.copy())
+            yield batch
+
+    def _next_batch(self):
+        """The next RecordBatch, or None at end of stream (one
+        ``reader.decode`` span: chunk reads, concatenation, boundary scan)."""
+        from ..observe.trace import span
+
+        with span("reader.decode", rusage=True):
+            while True:
+                self._fill()
+                if not self._parts_len:
+                    return None
+                buf = (self._parts[0] if len(self._parts) == 1
+                       else np.concatenate(self._parts))
+                max_records = len(buf) // _MIN_RECORD_WIRE + 1
+                offsets, scanned = nb.find_boundaries(buf, max_records)
+                if len(offsets) == 0:
+                    if self._eof:
+                        raise EOFError(
+                            "truncated BAM record at end of stream")
+                    # a single record larger than the accumulated bytes:
+                    # grow
+                    self._target *= 2
+                    self._parts = [buf]
+                    self._parts_len = len(buf)
+                    continue
+                # tail: copy the (at most one partial record) remainder so
+                # the next batch doesn't pin this batch's full buffer
+                tail = buf[scanned:].copy()
+                self._parts = [tail] if len(tail) else []
+                self._parts_len = len(tail)
+                # a trailing partial record at EOF surfaces as an empty scan
+                # on the next call and raises there, after this chunk is
+                # consumed
+                return RecordBatch(buf[:scanned], offsets.copy())
 
 
 class BamBatchReader:

@@ -228,7 +228,9 @@ def get_lib():
     global _lib, _lib_failed
     if _lib is not None or _lib_failed:
         return _lib
-    with _lock:
+    from ..observe.process import startup_span
+
+    with _lock, startup_span("startup.native_load"):
         if _lib is not None or _lib_failed:
             return _lib
         if os.environ.get("FGUMI_TPU_NO_NATIVE"):

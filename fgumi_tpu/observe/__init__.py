@@ -6,10 +6,12 @@ heartbeats, per-command metric files. This package is that discipline for
 fgumi-tpu, as one layer with a zero-overhead-when-disabled contract:
 
 - :mod:`.trace` — thread-aware ``span("name", **attrs)`` context manager
-  recording begin/end events across the pipeline stages, BGZF/prefetch
-  workers, external-sort spills, and device dispatch/fetch; exported as
-  Chrome trace-event JSON loadable in Perfetto (``--trace`` /
-  ``FGUMI_TPU_TRACE``).
+  at every layer boundary (start-up, host stages, chain stages, batch
+  engines, router, feeder, resolve, sink), live under ``--trace`` or
+  ``--run-report``; each span knows its parent and goes to the run
+  report's aggregate, onto the profiler's clock
+  (``jax.profiler.TraceAnnotation``) and, under ``--trace``, into Chrome
+  trace-event JSON loadable in Perfetto.
 - :mod:`.metrics` — a process-wide :class:`MetricsRegistry` aggregating the
   scattered ``DeviceStats``, ``StageTimes``, fault/retry counters, and I/O
   byte counts under stable dotted names.
@@ -27,6 +29,8 @@ fgumi-tpu, as one layer with a zero-overhead-when-disabled contract:
 - :mod:`.compilewatch` — folds jax compile/cache-hit monitoring events
   into the owning scope's metrics (``device.backend_compiles``), the
   warm-kernel evidence the serve smoke gate asserts on.
+- :mod:`.process` — the process-level record every run report carries:
+  start-up spans and each compile / cache load of the process so far.
 
 Disabled is the default and costs nothing on the hot path: ``span`` returns
 a shared no-op context manager, metric folding happens once per command at
@@ -34,5 +38,5 @@ report time, and no background thread starts unless asked for.
 """
 
 from .metrics import METRICS, MetricsRegistry  # noqa: F401
-from .trace import (NULL_SPAN, instant, span, start_trace, stop_trace,  # noqa: F401
-                    tracing_enabled, write_trace)
+from .trace import (NULL_SPAN, arm_spans, instant, span, start_trace,  # noqa: F401
+                    stop_trace, tracing_enabled, write_trace)

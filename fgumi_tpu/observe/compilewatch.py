@@ -13,6 +13,11 @@ use) pairs the two and forwards them into ``METRICS`` under::
     device.backend_compiles      count of real XLA compilations
     device.backend_compile_s     seconds spent in them
     device.compile_cache_hits    executables loaded from the persistent cache
+    device.compile_cache_load_s  seconds spent loading them
+
+and keeps one record of each in the process-level record
+(``observe/process.py``: kind, seconds, the bucketed shape key of the open
+dispatch, seconds since process start), which every run report carries.
 
 so a fresh process that found every executable on disk reports
 ``backend_compiles == 0`` (the chip smoke's second leg asserts exactly that).
@@ -36,27 +41,34 @@ _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 
 
-def _on_duration(event: str, duration: float, **_kw):
+def _on_duration(event: str, duration: float, fun_name=None, **_kw):
     if event == _BACKEND_COMPILE_EVENT:
-        if getattr(_tls, "cache_hit", False):
-            _tls.cache_hit = False  # a disk load, not a compile
-            return
+        from . import process
         from .metrics import METRICS
 
-        METRICS.inc("device.backend_compiles")
-        METRICS.inc("device.backend_compile_s", round(duration, 4))
         # shape-bucket attribution: the dispatch machinery flags (via a
         # contextvar that rides the feeder's context copy) dispatches
-        # whose bucketed shape is new this process; a real backend
-        # compile landing inside one is a shape-ladder recompile, which
-        # is what device.shape_bucket.recompiles counts (ops/datapath.py)
+        # whose bucketed shape is new this process, with the shape's key
+        shape = None
         try:
-            from ..ops.datapath import compile_is_shape_miss
+            from ..ops.datapath import compile_shape_key
 
-            if compile_is_shape_miss():
-                METRICS.inc("device.shape_bucket.recompiles")
+            shape = compile_shape_key()
         except Exception:  # pragma: no cover - attribution is best-effort
             pass
+        if getattr(_tls, "cache_hit", False):
+            _tls.cache_hit = False  # a disk load, not a compile
+            METRICS.inc("device.compile_cache_load_s", round(duration, 4))
+            process.note_compile("cache_load", duration, shape, fun_name)
+            return
+        METRICS.inc("device.backend_compiles")
+        METRICS.inc("device.backend_compile_s", round(duration, 4))
+        process.note_compile("compile", duration, shape, fun_name)
+        # a real backend compile landing inside a shape-miss dispatch is a
+        # shape-ladder recompile, which is what
+        # device.shape_bucket.recompiles counts (ops/datapath.py)
+        if shape:
+            METRICS.inc("device.shape_bucket.recompiles")
 
 
 def _on_event(event: str, **_kw):

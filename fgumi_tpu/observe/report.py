@@ -67,7 +67,18 @@ import time
 #: ``device.shapes`` lists the bucketed dispatch shapes the process has
 #: seen (``kind:dims``), and ``metrics.device.route.why.<reason>`` counts
 #: why the router sent each batch where it did.
-SCHEMA_VERSION = 8
+#: v9 (ISSUE 25): optional ``spans`` section — the span aggregate of this
+#: run (``{"job", "by_name": {name: {count, wall_s, self_s, wait_s, p50_s,
+#: max_s, threads[, utime_s, stime_s, minflt, majflt, nvcsw, nivcsw]
+#: [, counters]}}}``; present whenever spans were live, which ``--trace`` or
+#: ``--run-report`` makes them) — and the ``process`` section, in every
+#: report: ``start_unix``, ``first_main_s`` (process start to the first
+#: ``cli.main``), the once-per-process ``startup.*`` spans, and one entry
+#: per backend compile and persistent-cache load of the whole process so
+#: far (``kind``, ``s``, ``at_s`` since process start, ``shape``, ``fun``;
+#: observe/process.py). ``metrics.device.compile_cache_load_s`` holds the
+#: seconds of this run's cache loads.
+SCHEMA_VERSION = 9
 
 
 def _device_stats():
@@ -123,6 +134,9 @@ _OPTIONAL = {
     "profile": dict,  # applied deployment profile: path, knobs applied/
                       # skipped_explicit, fingerprint mismatches, whether
                       # router priors were seeded (tune/profile.py; v7)
+    "spans": dict,    # span aggregate by name (observe/trace.py; v9)
+    "process": dict,  # process-level record: start-up spans and every
+                      # compile / cache load so far (observe/process.py; v9)
 }
 
 #: Components a ``latency_decomposition`` section may carry besides
@@ -139,6 +153,61 @@ _LATENCY_FIELDS = ("count", "sum", "p50", "p90", "p99", "max")
 
 #: Required integer counters of the ``audit`` section (v4).
 _AUDIT_COUNTERS = ("sampled", "clean", "divergent", "dropped")
+
+#: Required numeric fields of one ``spans.by_name`` record (v9).
+_SPAN_FIELDS = ("count", "wall_s", "self_s", "wait_s", "p50_s", "max_s")
+
+
+def _is_number(v) -> bool:
+    return isinstance(v, (int, float)) and not isinstance(v, bool)
+
+
+def _validate_spans(spans: dict, errors: list):
+    if not isinstance(spans.get("job"), (str, int)):
+        errors.append("spans.job is not a job id or an ordinal")
+    by_name = spans.get("by_name")
+    if not isinstance(by_name, dict):
+        errors.append("spans.by_name is not an object")
+        return
+    for name, rec in by_name.items():
+        if not isinstance(rec, dict):
+            errors.append(f"spans entry {name!r} is not an object")
+            continue
+        missing = [f for f in _SPAN_FIELDS if not _is_number(rec.get(f))]
+        if missing:
+            errors.append(f"spans entry {name!r} missing numeric fields "
+                          f"{missing}")
+            continue
+        # a span's own time and its waits are parts of its wall
+        if rec["self_s"] > rec["wall_s"] + 1e-6 \
+                or rec["wait_s"] > rec["wall_s"] + 1e-6:
+            errors.append(f"spans entry {name!r}: self_s or wait_s "
+                          "exceeds wall_s")
+        if not isinstance(rec.get("threads"), list):
+            errors.append(f"spans entry {name!r} has no threads list")
+
+
+def _validate_process(proc: dict, errors: list):
+    if not _is_number(proc.get("start_unix")):
+        errors.append("process.start_unix is not a number")
+    if "first_main_s" in proc and not _is_number(proc["first_main_s"]):
+        errors.append("process.first_main_s is not a number")
+    spans = proc.get("spans")
+    if not isinstance(spans, dict) or not all(
+            isinstance(v, dict) and _is_number(v.get("s"))
+            and _is_number(v.get("at_s")) for v in spans.values()):
+        errors.append("process.spans is not {name: {s, at_s}}")
+    compiles = proc.get("compiles")
+    if not isinstance(compiles, list):
+        errors.append("process.compiles is not a list")
+        return
+    for rec in compiles:
+        if not (isinstance(rec, dict)
+                and rec.get("kind") in ("compile", "cache_load")
+                and _is_number(rec.get("s"))
+                and _is_number(rec.get("at_s"))):
+            errors.append(f"process.compiles entry {rec!r} is not "
+                          "{kind, s, at_s}")
 
 
 def validate_report(obj) -> list:
@@ -234,6 +303,10 @@ def validate_report(obj) -> list:
         if total is not None and comp_sum > total + 0.005:
             errors.append("latency_decomposition components sum "
                           f"{comp_sum:.6f} past total_s {total:.6f}")
+    if isinstance(obj.get("spans"), dict):
+        _validate_spans(obj["spans"], errors)
+    if isinstance(obj.get("process"), dict):
+        _validate_process(obj["process"], errors)
     return errors
 
 
@@ -481,6 +554,21 @@ def build_report(command: str, argv, started_unix: float, wall_s: float,
                 "seeded_router": bool(applied["seeded_router"]),
                 "seeded_choosers": list(applied["seeded_choosers"]),
             }
+    # span aggregate (v9): per-name count / wall / self time of every layer
+    # span of this run, whenever spans were live
+    from .trace import current_aggregate
+
+    agg = current_aggregate()
+    if agg is not None:
+        spans = agg.snapshot()
+        if spans["by_name"]:
+            report["spans"] = spans
+    # process-level record (v9): what this process paid once — start-up
+    # spans, every compile and cache load so far — in every report, since
+    # the commands that paid may have written none
+    from . import process
+
+    report["process"] = process.snapshot()
     return report
 
 

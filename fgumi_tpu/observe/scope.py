@@ -24,7 +24,12 @@ thread.
 """
 
 import contextvars
+import itertools
 import threading
+
+#: ordinals of the scopes created in this process: what a span's ``job`` is
+#: when no serve job id names it (the Nth top-level invocation)
+_ORDINALS = itertools.count(1)
 
 _SCOPE = contextvars.ContextVar("fgumi_tpu_telemetry_scope", default=None)
 #: Effective command line (argv list) override for output provenance (@PG
@@ -46,8 +51,9 @@ class TelemetryScope:
     in ``ops.kernel`` and is only materialized when a kernel actually
     touches it, so numpy-free commands never pay that import."""
 
-    __slots__ = ("label", "metrics", "tracer", "_device_stats", "_lock",
-                 "trace_id", "parent_span_id", "job_id", "hops")
+    __slots__ = ("label", "metrics", "tracer", "spans", "ordinal",
+                 "_device_stats", "_lock", "trace_id", "parent_span_id",
+                 "job_id", "hops")
 
     def __init__(self, label: str = None):
         from .metrics import MetricsRegistry
@@ -55,6 +61,8 @@ class TelemetryScope:
         self.label = label
         self.metrics = MetricsRegistry()
         self.tracer = None  # set by trace.start_trace inside the scope
+        self.spans = None   # SpanAggregate, set by trace.arm_spans
+        self.ordinal = next(_ORDINALS)
         self._device_stats = None
         self._lock = threading.Lock()
         #: fleet trace context (W3C-style ids propagated over the serve
@@ -208,10 +216,43 @@ def publish_to_global(scope: TelemetryScope):
             kern._GLOBAL_DEVICE_STATS.reset()
 
 
+_setname = None  # libc's pthread_setname_np; False once known missing
+
+
+def name_os_thread(name: str):
+    """Give the calling thread its OS name (``pthread_setname_np``; the
+    kernel keeps 15 bytes), which is what a profiler's host plane and
+    ``top -H`` show: Python 3.12 names only its own Thread objects, so
+    every thread of the process otherwise reads ``python3``. Best-effort;
+    a platform without the call keeps the old names."""
+    global _setname
+    if _setname is None:
+        try:
+            import ctypes
+
+            fn = ctypes.CDLL(None, use_errno=True).pthread_setname_np
+            fn.argtypes = [ctypes.c_void_p, ctypes.c_char_p]
+            fn.restype = ctypes.c_int
+            _setname = fn
+        except (OSError, AttributeError):
+            _setname = False
+    if _setname:
+        if len(name) > 15 and name.startswith("fgumi-"):
+            name = name[6:]  # fgumi-device-feeder -> device-feeder
+        # CPython's thread ident is the pthread_t on POSIX
+        _setname(threading.get_ident(), name.encode()[:15])
+
+
 def spawn_thread(target, *, name=None, daemon=True, args=()):
     """A ``threading.Thread`` whose target runs in a copy of the caller's
     context — the one-line way to keep a job's telemetry scope attached to
-    its helper threads. Returned un-started (call ``.start()``)."""
+    its helper threads — under its OS thread name. Returned un-started
+    (call ``.start()``)."""
     ctx = contextvars.copy_context()
-    return threading.Thread(target=lambda: ctx.run(target, *args),
-                            name=name, daemon=daemon)
+
+    def run():
+        if name:
+            name_os_thread(name)
+        ctx.run(target, *args)
+
+    return threading.Thread(target=run, name=name, daemon=daemon)

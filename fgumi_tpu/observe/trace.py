@@ -1,18 +1,32 @@
-"""Thread-aware span tracing with Chrome trace-event JSON export.
+"""Thread-aware span tracing: one span call, three sinks.
 
-``span("stage.process", batch=3)`` times a region of one thread and records
-it as a Chrome trace-event *complete* event (``ph: "X"``), so a run traced
-with ``--trace out.json`` opens directly in Perfetto (or
-``chrome://tracing``) with one timeline row per thread — a pipeline stall
-is visible as a gap, a device round trip as a block on the feeder row.
+``span("engine.pack", batch=3)`` times a region of one thread. Spans are
+live when the invocation has ``--trace`` **or** ``--run-report`` (armed in
+``cli._main_scoped``); a live span knows the span that was open on the same
+thread when it began (its parent, from a thread-local stack) and goes to:
+
+- **the aggregate** (:class:`SpanAggregate`, always when armed): per scope
+  and span name ``count``, ``wall_s``, ``self_s`` (duration minus the
+  direct children's cover), ``wait_s`` (cover of the ``wait=True`` spans
+  below it on the same thread) and the 50th percentile; for spans opened
+  with ``rusage=True`` also the thread's ``RUSAGE_THREAD`` deltas. It
+  becomes the run report's ``spans`` section.
+- **the profiler's clock**: once ``jax`` is imported a live span also
+  enters a ``jax.profiler.TraceAnnotation`` of the same name, so while a
+  profiler session is open (``--xla-profile``, or a harness's
+  ``jax.profiler.start_trace``) it lands in the xplane's host plane on the
+  thread that ran it, beside the device operations. With no session open
+  the annotation costs a flag test. jax is never imported for this.
+- **the Chrome trace** under ``--trace``: a trace-event *complete* event
+  (``ph: "X"``) that opens in Perfetto (or ``chrome://tracing``) with one
+  timeline row per thread, the parent's name in ``args``.
 
 Design constraints (the acceptance contract of the telemetry layer):
 
-- **Zero overhead when disabled.** ``span()`` with tracing off returns one
-  shared no-op context manager — no allocation, no lock, no time call.
-  Hot loops that want even the dict-build of attrs gone should hoist
-  ``tracing_enabled()`` once and skip their span calls entirely (the
-  pipeline does this).
+- **Zero overhead when disabled.** ``span()`` with neither flag returns one
+  shared no-op context manager — no allocation, no lock, no time call, no
+  ``getrusage``, no jax. Per-record loops that want even the dict-build of
+  attrs gone hoist ``tracing_enabled()`` once and skip their span calls.
 - **Thread attribution.** Events carry the OS thread id and the trace
   names each thread once via ``thread_name`` metadata events, so the
   fgumi-reader / fgumi-writer / fgumi-worker-N / fgumi-device-feeder rows
@@ -31,10 +45,14 @@ Design constraints (the acceptance contract of the telemetry layer):
   timelines on these anchors (docs/observability.md "Fleet tracing").
 """
 
+import functools
 import json
 import os
+import sys
 import threading
 import time
+
+from .scope import current_scope
 
 # ---------------------------------------------------------------------------
 # W3C-style trace context (trace-id + parent-span-id)
@@ -113,42 +131,99 @@ class _NullSpan:
 
 NULL_SPAN = _NullSpan()
 
-_tracer = None  # process-global _Tracer, or None (used when no scope active)
+# process-global sinks, used when no telemetry scope is active
+_tracer = None     # _Tracer (the Chrome trace), or None
+_aggregate = None  # SpanAggregate: spans are live iff this is set
+
+
+def _current_sinks():
+    """``(aggregate, tracer)`` spans should record into: the active
+    telemetry scope's (one per daemon job) when inside one, else the
+    process-global pair. A scope with spans off shades the global sinks on
+    purpose — job A tracing must not collect job B's spans. The aggregate
+    is set whenever spans are live; the tracer only under ``--trace``."""
+    scope = current_scope()
+    if scope is not None:
+        return scope.spans, scope.tracer
+    return _aggregate, _tracer
 
 
 def _current_tracer():
-    """The tracer spans should record into: the active telemetry scope's
-    (one per daemon job) when inside one, else the process-global tracer.
-    A scope with tracing off shades the global tracer on purpose — job A
-    tracing must not collect job B's spans."""
-    from .scope import current_scope
-
-    scope = current_scope()
-    if scope is not None:
-        return scope.tracer
-    return _tracer
+    return _current_sinks()[1]
 
 
 def tracing_enabled() -> bool:
-    return _current_tracer() is not None
+    """True when spans are live (``--trace`` or ``--run-report``)."""
+    return _current_sinks()[0] is not None
+
+
+def current_aggregate():
+    """The live :class:`SpanAggregate`, or None (the run report reads it)."""
+    return _current_sinks()[0]
 
 
 # ---------------------------------------------------------------------------
-# live tracer
+# live spans
 
 MAX_EVENTS = 500_000
 
+#: spans whose names start so are per-block I/O: they never reach the
+#: flight ring, which is for dispatches and breaker transitions
+_PER_BLOCK_PREFIXES = ("bgzf.", "io.")
+
+#: per-thread stack of the open live spans, outermost first
+_tls = threading.local()
+
+try:
+    import resource as _resource
+
+    _RUSAGE_THREAD = getattr(_resource, "RUSAGE_THREAD", None)
+except ImportError:  # pragma: no cover - non-POSIX
+    _resource = None
+    _RUSAGE_THREAD = None
+
+#: ``getrusage`` fields a ``rusage=True`` span records, as report keys
+_RUSAGE_FIELDS = (("utime_s", "ru_utime"), ("stime_s", "ru_stime"),
+                  ("minflt", "ru_minflt"), ("majflt", "ru_majflt"),
+                  ("nvcsw", "ru_nvcsw"), ("nivcsw", "ru_nivcsw"))
+
+_annotation = None  # jax.profiler.TraceAnnotation once jax is imported
+
+
+def _annotation_cls():
+    """``jax.profiler.TraceAnnotation`` if jax is already imported, else
+    None: the mirror onto the profiler's clock never imports jax itself
+    (and tolerates a jax another thread is still half-way through
+    importing)."""
+    global _annotation
+    if _annotation is None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return None
+        _annotation = getattr(getattr(jax, "profiler", None),
+                              "TraceAnnotation", None)
+    return _annotation
+
 
 class _Span:
-    """One in-flight span: records a complete ("X") event on exit."""
+    """One in-flight span: recorded in every armed sink on exit."""
 
-    __slots__ = ("_tracer", "name", "_t0", "args")
+    __slots__ = ("_agg", "_tracer", "name", "args", "_t0", "_parent",
+                 "_child_s", "_wait_s", "_wait", "_ru0", "_annot", "_counts")
 
-    def __init__(self, tracer, name, args):
+    def __init__(self, agg, tracer, name, args, rusage, wait):
+        self._agg = agg
         self._tracer = tracer
         self.name = name
         self.args = args
-        self._t0 = time.monotonic()
+        self._parent = None
+        self._child_s = 0.0  # cover of the direct children
+        self._wait_s = 0.0   # cover of the wait spans anywhere below
+        self._wait = wait
+        self._ru0 = rusage   # True until __enter__ reads the counters
+        self._annot = None
+        self._counts = None
+        self._t0 = None
 
     def set(self, **attrs):
         """Attach attrs discovered mid-span (recorded at exit)."""
@@ -158,13 +233,137 @@ class _Span:
             self.args.update(attrs)
 
     def __enter__(self):
+        # few Python-level calls on purpose: a profiler's Python tracer
+        # charges every one of them to the span's parent
+        try:
+            stack = _tls.stack
+        except AttributeError:
+            stack = _tls.stack = []
+            _tls.name = threading.current_thread().name
+        if stack:
+            self._parent = stack[-1]
+        stack.append(self)
+        cls = _annotation or _annotation_cls()
+        if cls is not None:
+            kw = {"job": self._agg.job}
+            if self.args and "batch" in self.args:
+                kw["batch"] = self.args["batch"]
+            self._annot = cls(self.name, **kw)
+            self._annot.__enter__()
+        if self._ru0:
+            self._ru0 = (_resource.getrusage(_RUSAGE_THREAD)
+                         if _RUSAGE_THREAD is not None else None)
+        self._t0 = time.monotonic()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.monotonic()
-        self._tracer._complete(self.name, self._t0, t1, self.args,
-                               error=exc_type.__name__ if exc_type else None)
+        ru = None
+        if self._ru0:
+            ru1 = _resource.getrusage(_RUSAGE_THREAD)
+            ru = [getattr(ru1, f) - getattr(self._ru0, f)
+                  for _key, f in _RUSAGE_FIELDS]
+        if self._annot is not None:
+            self._annot.__exit__(exc_type, exc, tb)
+        stack = _tls.stack
+        if stack[-1] is self:
+            stack.pop()
+        else:  # a generator suspended inside a span
+            stack.remove(self)
+        dur = t1 - self._t0
+        waited = dur if self._wait else self._wait_s
+        parent = self._parent
+        if parent is not None:
+            parent._child_s += dur
+            parent._wait_s += waited
+        self._agg.record(self.name, dur, max(dur - self._child_s, 0.0),
+                         waited, ru, self._counts, _tls.name)
+        if self._tracer is not None:
+            args = self.args
+            if parent is not None:
+                args = dict(args or ())
+                args["parent"] = parent.name
+            self._tracer._complete(
+                self.name, self._t0, t1, args,
+                error=exc_type.__name__ if exc_type else None)
         return False
+
+
+class _SpanStat:
+    __slots__ = ("count", "wall_s", "self_s", "wait_s", "hist", "rusage",
+                 "counts", "threads")
+
+    def __init__(self):
+        from .metrics import Histogram
+
+        self.count = 0
+        self.wall_s = 0.0
+        self.self_s = 0.0
+        self.wait_s = 0.0
+        self.hist = Histogram()
+        self.rusage = None
+        self.counts = None
+        self.threads = set()
+
+
+class SpanAggregate:
+    """Per-scope totals by span name: the run report's ``spans`` section.
+
+    ``job`` names whose spans these are: the serve job id, else the
+    ordinal of the top-level invocation in this process (0 with no scope).
+    """
+
+    def __init__(self, job=0):
+        self.job = job
+        self._lock = threading.Lock()
+        self._by_name = {}
+
+    def record(self, name, dur, self_s, wait_s=0.0, rusage=None,
+               counts=None, thread=None):
+        if thread is None:
+            thread = threading.current_thread().name
+        with self._lock:
+            st = self._by_name.get(name)
+            if st is None:
+                st = self._by_name[name] = _SpanStat()
+            st.count += 1
+            st.wall_s += dur
+            st.self_s += self_s
+            st.wait_s += wait_s
+            st.hist.observe(dur)
+            if len(st.threads) < 8:
+                st.threads.add(thread)
+            if rusage is not None:
+                if st.rusage is None:
+                    st.rusage = [0] * len(_RUSAGE_FIELDS)
+                for i, v in enumerate(rusage):
+                    st.rusage[i] += v
+            if counts:
+                if st.counts is None:
+                    st.counts = {}
+                for k, v in counts.items():
+                    st.counts[k] = st.counts.get(k, 0) + v
+
+    def snapshot(self) -> dict:
+        """``{"job": ..., "by_name": {name: record}}``, names sorted."""
+        by_name = {}
+        with self._lock:
+            for name in sorted(self._by_name):
+                st = self._by_name[name]
+                rec = {"count": st.count, "wall_s": round(st.wall_s, 6),
+                       "self_s": round(st.self_s, 6),
+                       "wait_s": round(st.wait_s, 6),
+                       "p50_s": round(st.hist.quantile(0.50), 6),
+                       "max_s": round(st.hist.max, 6),
+                       "threads": sorted(st.threads)}
+                if st.rusage is not None:
+                    for (key, _f), v in zip(_RUSAGE_FIELDS, st.rusage):
+                        rec[key] = round(v, 6) if key.endswith("_s") \
+                            else int(v)
+                if st.counts:
+                    rec.update(st.counts)
+                by_name[name] = rec
+        return {"job": self.job, "by_name": by_name}
 
 
 class _Tracer:
@@ -223,13 +422,17 @@ class _Tracer:
         return tid
 
     def _complete(self, name, t0, t1, args, error=None):
-        # span ends also feed the always-on flight recorder's ring (the
-        # black box shows the last few hundred spans even when the trace
-        # buffer overflowed or was never exported)
-        from .flight import FLIGHT
+        # span ends of a --trace run also feed the flight recorder's ring
+        # (the black box shows the last spans even when the trace buffer
+        # overflowed or was never exported); per-block I/O spans do not:
+        # they would evict the dispatches and breaker transitions the
+        # 512-entry ring is for
+        if not name.startswith(_PER_BLOCK_PREFIXES):
+            from .flight import FLIGHT
 
-        FLIGHT.note("span", name=name, dur_ms=round((t1 - t0) * 1e3, 3),
-                    **({"error": error} if error else {}))
+            FLIGHT.note("span", name=name,
+                        dur_ms=round((t1 - t0) * 1e3, 3),
+                        **({"error": error} if error else {}))
         ev = {"name": name, "ph": "X", "pid": os.getpid(),
               "ts": round((t0 - self.t_zero) * 1e6, 1),
               "dur": round((t1 - t0) * 1e6, 1)}
@@ -299,17 +502,73 @@ class _Tracer:
 # module API
 
 
-def span(name: str, **attrs):
-    """Time a region of the current thread as a named trace span.
+def span(name: str, *, rusage: bool = False, wait: bool = False, **attrs):
+    """Time a region of the current thread as a named span.
 
-    With tracing disabled this returns the shared :data:`NULL_SPAN` (no
-    allocation); enabled, a complete event is recorded when the context
-    exits, tagged with ``attrs`` and the thread's id/name. Exceptions
-    propagate (the span records ``error: <type>``)."""
-    t = _current_tracer()
-    if t is None:
+    With neither ``--trace`` nor ``--run-report`` this returns the shared
+    :data:`NULL_SPAN` (no allocation, no clock read); armed, the span is
+    recorded in every sink when the context exits, with its parent (the
+    span open on this thread when it began). ``rusage=True`` (layer-level
+    spans, not per-block ones) adds the thread's ``getrusage`` deltas;
+    ``wait=True`` marks a span in which the thread only waits, so that an
+    enclosing span's ``wall_s - wait_s`` is its own work. Never leave a
+    span open across a ``yield``. Exceptions propagate (the Chrome event
+    records ``error: <type>``)."""
+    agg, tracer = _current_sinks()
+    if agg is None:
         return NULL_SPAN
-    return _Span(t, name, attrs or None)
+    return _Span(agg, tracer, name, attrs or None, rusage, wait)
+
+
+def spanned(name: str, **span_kw):
+    """Decorator: the whole call of a (non-generator) function is one span."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapper(*args, **kwargs):
+            with span(name, **span_kw):
+                return fn(*args, **kwargs)
+        return wrapper
+    return deco
+
+
+def spanned_iter(name: str, iterable, **span_kw):
+    """Yield the items of ``iterable``, each pull inside a span of its own
+    (never open across the ``yield``): the work of a generator stage happens
+    in its pulls, on whichever thread drives them."""
+    it = iter(iterable)
+    while True:
+        with span(name, **span_kw):
+            try:
+                item = next(it)
+            except StopIteration:
+                return
+        yield item
+
+
+def record_interval(name: str, t0: float, t1: float, **attrs):
+    """Record a span that began on another thread (``time.monotonic()``
+    stamps; e.g. a queue wait from submit to pick-up), on the thread that
+    ends it. It has no parent and cannot be mirrored onto the profiler's
+    clock, which takes no back-dated events."""
+    agg, tracer = _current_sinks()
+    if agg is None:
+        return
+    dur = max(t1 - t0, 0.0)
+    agg.record(name, dur, dur)
+    if tracer is not None:
+        tracer._complete(name, t0, t1, attrs or None)
+
+
+def count(span_name: str, key: str, n=1):
+    """Add ``n`` to counter ``key`` of the innermost open span called
+    ``span_name`` on this thread (folded into its aggregate record); a
+    no-op when there is none."""
+    for sp in reversed(getattr(_tls, "stack", ())):
+        if sp.name == span_name:
+            if sp._counts is None:
+                sp._counts = {}
+            sp._counts[key] = sp._counts.get(key, 0) + n
+            return
 
 
 def instant(name: str, **attrs):
@@ -336,13 +595,28 @@ def set_clock_offset(offset_s: float):
         t.clock_offset_s = float(offset_s)
 
 
-def start_trace(max_events: int = None):
-    """Enable tracing for the active telemetry scope (one per daemon job),
-    or process-wide when no scope is entered. Idempotent (keeps the active
-    tracer)."""
-    global _tracer
-    from .scope import current_scope
+def arm_spans():
+    """Make spans live for the active telemetry scope (one per daemon job),
+    or process-wide when no scope is entered: the aggregate and the
+    profiler-clock mirror, no Chrome trace. Idempotent; returns the
+    :class:`SpanAggregate`."""
+    global _aggregate
+    scope = current_scope()
+    if scope is not None:
+        if scope.spans is None:
+            scope.spans = SpanAggregate(scope.job_id or scope.ordinal)
+        return scope.spans
+    if _aggregate is None:
+        _aggregate = SpanAggregate()
+    return _aggregate
 
+
+def start_trace(max_events: int = None):
+    """Arm spans and attach a Chrome tracer (``--trace``), for the active
+    telemetry scope or process-wide when no scope is entered. Idempotent
+    (keeps the active tracer)."""
+    global _tracer
+    arm_spans()
     scope = current_scope()
     if scope is not None:
         if scope.tracer is None:
@@ -354,16 +628,14 @@ def start_trace(max_events: int = None):
 
 
 def stop_trace():
-    """Disable tracing (scope-local when inside a scope) and return the
-    tracer (caller may still export it)."""
-    global _tracer
-    from .scope import current_scope
-
+    """Disarm spans (scope-local when inside a scope) and return the Chrome
+    tracer, if one was attached (caller may still export it)."""
+    global _tracer, _aggregate
     scope = current_scope()
     if scope is not None:
-        t, scope.tracer = scope.tracer, None
+        t, scope.tracer, scope.spans = scope.tracer, None, None
         return t
-    t, _tracer = _tracer, None
+    t, _tracer, _aggregate = _tracer, None, None
     return t
 
 

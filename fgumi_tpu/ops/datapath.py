@@ -93,16 +93,30 @@ def parse_shape_buckets(spec):
 
 
 # set while a dispatch whose bucketed shape is NEW this process is being
-# built/submitted; it rides contextvars.copy_context() into the device
-# feeder thread, so a jax backend-compile event fired there can be
-# attributed to the shape miss (device.shape_bucket.recompiles).
+# built/submitted, to that shape's key; it rides contextvars.copy_context()
+# into the device feeder thread, so a jax backend-compile event fired there
+# can be attributed to the shape miss (device.shape_bucket.recompiles, and
+# the shape named in the process record's compile entries).
 _MISS_FLAG = contextvars.ContextVar("fgumi_tpu_shape_miss", default=False)
+#: the key of the last shape each thread observed as new (observe() and
+#: attribute_compiles() run on the thread that builds the dispatch)
+_last_miss = threading.local()
 
 
 def compile_is_shape_miss() -> bool:
-    """True when the current (context-carried) dispatch was a shape miss
-    — called by observe/compilewatch on every backend-compile event."""
-    return _MISS_FLAG.get()
+    """True when the current (context-carried) dispatch was a shape miss."""
+    return bool(_MISS_FLAG.get())
+
+
+def compile_shape_key():
+    """The ``kind:dims`` key of the shape-miss dispatch open in this
+    context, or None — called by observe/compilewatch on every
+    backend-compile event."""
+    return _MISS_FLAG.get() or None
+
+
+def _key_str(key) -> str:
+    return f"{key[0]}:{'x'.join(map(str, key[1:]))}"
 
 
 class ShapeBucketRegistry:
@@ -173,8 +187,7 @@ class ShapeBucketRegistry:
         compared shape for shape even when the router split their batches
         differently."""
         with self._lock:
-            keys = sorted(f"{k[0]}:{'x'.join(map(str, k[1:]))}"
-                          for k in self._seen)
+            keys = sorted(_key_str(k) for k in self._seen)
         return keys[:limit]
 
     # ------------------------------------------------------------ ladder
@@ -241,6 +254,7 @@ class ShapeBucketRegistry:
             if new:
                 self._seen.add(key)
                 self.misses += 1
+                _last_miss.key = _key_str(key)
             else:
                 self.hits += 1
             n_shapes = len(self._seen)
@@ -260,7 +274,7 @@ class ShapeBucketRegistry:
         if not is_miss:
             yield
             return
-        token = _MISS_FLAG.set(True)
+        token = _MISS_FLAG.set(getattr(_last_miss, "key", None) or "new")
         try:
             yield
         finally:
@@ -452,13 +466,17 @@ class HostStagingPool:
                 self._order.remove(key)
                 self.reuses += 1
                 from ..observe.metrics import METRICS
+                from ..observe.trace import count
 
                 METRICS.inc("device.staging.reuses")
+                count("engine.pack", "staging_reuses")
                 return arr
             self.allocs += 1
         from ..observe.metrics import METRICS
+        from ..observe.trace import count
 
         METRICS.inc("device.staging.allocs")
+        count("engine.pack", "staging_allocs")
         return np.empty(shape, dtype=dtype)
 
     def acquire_filled(self, shape, dtype, fill) -> np.ndarray:

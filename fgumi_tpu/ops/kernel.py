@@ -77,18 +77,20 @@ def _ensure_jax():
         with _jax_import_lock:
             if _jax_ready:
                 return jax
-            import jax as _jax
-            import jax.numpy as _jnp
+            from ..observe import compilewatch, process
 
-            jax = _jax
-            jnp = _jnp
-            # before the first jit compile so device executables land on
-            # disk and compile events are counted (device.backend_compiles
-            # — the warm-kernel evidence the serve smoke gate asserts on)
-            _enable_persistent_compile_cache()
-            from ..observe import compilewatch
+            with process.startup_span("startup.jax_import"):
+                import jax as _jax
+                import jax.numpy as _jnp
 
-            compilewatch.install()
+                jax = _jax
+                jnp = _jnp
+                # before the first jit compile so device executables land
+                # on disk and compile events are counted
+                # (device.backend_compiles — the warm-kernel evidence the
+                # serve smoke gate asserts on)
+                _enable_persistent_compile_cache()
+                compilewatch.install()
             _jax_ready = True
     return jax
 
@@ -138,6 +140,7 @@ def upload_donation_enabled() -> bool:
     return jax.default_backend() != "cpu"
 
 from ..constants import MAX_PHRED, MIN_PHRED, N_CODE
+from ..observe.trace import record_interval, span
 from .datapath import CONST_CACHE, SHAPE_REGISTRY, as_device_operand
 from .tables import QualityTables
 
@@ -620,8 +623,6 @@ class DeviceStats:
         """Timed jax.device_get — route every device->host fetch through
         here so fetch_wait_s captures all host time blocked on the device.
         Accepts a single array or a tuple (fetched in one device_get)."""
-        from ..observe.trace import span
-
         _ensure_jax()
         t0 = time.monotonic()
         with span("device.fetch") as sp:
@@ -868,7 +869,7 @@ class DispatchTicket:
     __slots__ = ("_event", "_result", "_exc", "slot", "upload_bytes",
                  "_released", "_abandoned", "mesh_gather", "mesh_devices",
                  "mesh_f_loc", "staging", "filter_mode", "filter_ctx",
-                 "new_shape")
+                 "new_shape", "t_submit")
 
     def __init__(self):
         self._event = threading.Event()
@@ -876,6 +877,7 @@ class DispatchTicket:
         self._exc = None
         self.slot = -1
         self.upload_bytes = 0
+        self.t_submit = 0.0  # monotonic stamp of DeviceFeeder.submit
         self._released = False
         self._abandoned = False
         # first sight of this bucketed shape in the process: the dispatch
@@ -1054,6 +1056,7 @@ class DeviceFeeder:
         ticket = DispatchTicket()
         ticket.upload_bytes = int(upload_bytes)
         ticket.slot = slot
+        ticket.t_submit = time.monotonic()
         # submit sites run under SHAPE_REGISTRY.attribute_compiles(new)
         ticket.new_shape = compile_is_shape_miss()
         ctx = contextvars.copy_context()
@@ -1158,7 +1161,11 @@ class DeviceFeeder:
 
     def _run_item(self, fn, ticket, overlapped, t0):
         """Execute one work item inside the submitter's context (so
-        DEVICE_STATS / METRICS resolve the submitting job's scope)."""
+        DEVICE_STATS / METRICS / spans resolve the submitting job's
+        scope)."""
+        # submit -> this thread taking the ticket: queue + depth/byte gate
+        record_interval("feeder.queue_wait", ticket.t_submit, t0,
+                        slot=ticket.slot)
         result = fn()
         dt = time.monotonic() - t0
         if overlapped:
@@ -1190,6 +1197,9 @@ class DeviceFeeder:
         return result
 
     def _loop(self):
+        from ..observe.scope import name_os_thread
+
+        name_os_thread("fgumi-device-feeder")
         while True:
             with self._cv:
                 self._active = False
@@ -1444,7 +1454,6 @@ def device_retry_call(fn, what: str = "dispatch"):
     immediately (OOM is handled by batch splitting at resolve time). The
     device.dispatch fault point fires on every attempt, so chaos tests
     exercise exactly this loop."""
-    from ..observe.trace import span
     from ..utils import faults
 
     # chaos point for the wedge class of failure (kind `hang`, stall via
@@ -1510,9 +1519,9 @@ class _DeadlineRunner:
             else:
                 q = _queue.SimpleQueue()
                 self._seq += 1
-                threading.Thread(target=self._loop, args=(q,),
-                                 name=f"{self._name}-{self._seq}",
-                                 daemon=True).start()
+                name = f"{self._name}-{self._seq}"
+                threading.Thread(target=self._loop, args=(q, name),
+                                 name=name, daemon=True).start()
         q.put((ctx, fn, box, done))
         if not done.wait(deadline_s):
             # wedged: the worker is abandoned with its call (never reused;
@@ -1526,7 +1535,10 @@ class _DeadlineRunner:
         return box["result"]
 
     @staticmethod
-    def _loop(q):
+    def _loop(q, name):
+        from ..observe.scope import name_os_thread
+
+        name_os_thread(name)
         while True:
             ctx, fn, box, done = q.get()
             try:
@@ -2549,19 +2561,20 @@ def pad_segments_gather(codes: np.ndarray, quals: np.ndarray,
     pad_segments). Returns (codes_dev, quals_dev, seg_ids, starts, F_pad, N);
     codes_dev[:N] / quals_dev[:N] are the dense views resolve_segments needs.
     """
-    counts = np.asarray(counts, dtype=np.int64)
-    starts = np.concatenate(([0], np.cumsum(counts)))
-    N = int(starts[-1])
-    J = len(counts)
-    N_pad = _pad_rows(N)
-    F_pad = SHAPE_REGISTRY.bucket_segments(J)
-    DEVICE_STATS.add_pad(N, N_pad)
-    codes_dev = np.full((N_pad, L_max), N_CODE, dtype=np.uint8)
-    quals_dev = np.zeros((N_pad, L_max), dtype=np.uint8)
-    codes_dev[:N] = codes[rows, :L_max]
-    quals_dev[:N] = quals[rows, :L_max]
-    seg_ids = np.full(N_pad, max(J - 1, 0), dtype=np.int32)
-    seg_ids[:N] = np.repeat(np.arange(J, dtype=np.int32), counts)
+    with span("engine.pack.gather"):
+        counts = np.asarray(counts, dtype=np.int64)
+        starts = np.concatenate(([0], np.cumsum(counts)))
+        N = int(starts[-1])
+        J = len(counts)
+        N_pad = _pad_rows(N)
+        F_pad = SHAPE_REGISTRY.bucket_segments(J)
+        DEVICE_STATS.add_pad(N, N_pad)
+        codes_dev = np.full((N_pad, L_max), N_CODE, dtype=np.uint8)
+        quals_dev = np.zeros((N_pad, L_max), dtype=np.uint8)
+        codes_dev[:N] = codes[rows, :L_max]
+        quals_dev[:N] = quals[rows, :L_max]
+        seg_ids = np.full(N_pad, max(J - 1, 0), dtype=np.int32)
+        seg_ids[:N] = np.repeat(np.arange(J, dtype=np.int32), counts)
     return codes_dev, quals_dev, seg_ids, starts, F_pad, N
 
 
@@ -3062,9 +3075,10 @@ class ConsensusKernel:
         out_segments = _pad_out_segments(J, num_segments)
         from .datapath import STAGING_POOL
 
-        staging = [STAGING_POOL.acquire(codes2d_padded.shape, np.uint8)]
-        w = build_wire(codes2d_padded, quals2d_padded, self._delta94,
-                       out=staging[0])
+        with span("engine.pack.wire"):
+            staging = [STAGING_POOL.acquire(codes2d_padded.shape, np.uint8)]
+            w = build_wire(codes2d_padded, quals2d_padded, self._delta94,
+                           out=staging[0])
         pre = self._pre
         tables_dev = self._tables_dev
         filt = filter_params is not None
@@ -3108,7 +3122,8 @@ class ConsensusKernel:
                     # rides the kernel's scalar-prefetch channel (256 B)
                     # instead of the constant cache.
                     t0 = time.monotonic()
-                    prep = _pk.upload(wire, seg_ids, dict32, windows)
+                    with span("feeder.upload", bytes=upload):
+                        prep = _pk.upload(wire, seg_ids, dict32, windows)
                     DEVICE_STATS.note_upload(slot, time.monotonic() - t0)
                     DEVICE_STATS.add_kernel_backend(slot, "pallas")
                     if filt:
@@ -3118,9 +3133,10 @@ class ConsensusKernel:
                     return _pk.call_full(prep, pre, out_segments)
                 donate = upload_donation_enabled()
                 t0 = time.monotonic()
-                wd = jax.device_put(wire)
-                sd = jax.device_put(seg_ids)
-                dtab = CONST_CACHE.put("dict_tab", dict32)
+                with span("feeder.upload", bytes=upload):
+                    wd = jax.device_put(wire)
+                    sd = jax.device_put(seg_ids)
+                    dtab = CONST_CACHE.put("dict_tab", dict32)
                 DEVICE_STATS.note_upload(slot, time.monotonic() - t0)
                 DEVICE_STATS.add_kernel_backend(slot, "xla")
                 if donate:
@@ -3151,7 +3167,8 @@ class ConsensusKernel:
                 return fn(wd, sd, dtab, pre, num_segments, out_segments)
         else:
             STAGING_POOL.release(staging.pop())
-            cp, qsent = pack_codes2(codes2d_padded, quals2d_padded)
+            with span("engine.pack.wire", layout="packed2"):
+                cp, qsent = pack_codes2(codes2d_padded, quals2d_padded)
             upload = cp.nbytes + qsent.nbytes + seg_ids.nbytes
             new = SHAPE_REGISTRY.observe(
                 "segp2f" if full else "segp2", cp.shape[0], cp.shape[1],
@@ -3161,10 +3178,11 @@ class ConsensusKernel:
                 _ensure_jax()
                 donate = upload_donation_enabled()
                 t0 = time.monotonic()
-                cd = jax.device_put(cp)
-                qd = jax.device_put(qsent)
-                sd = jax.device_put(seg_ids)
-                ct, et = tables_dev()
+                with span("feeder.upload", bytes=upload):
+                    cd = jax.device_put(cp)
+                    qd = jax.device_put(qsent)
+                    sd = jax.device_put(seg_ids)
+                    ct, et = tables_dev()
                 DEVICE_STATS.note_upload(slot, time.monotonic() - t0)
                 DEVICE_STATS.add_kernel_backend(slot, "xla")
                 if donate:
@@ -3194,7 +3212,8 @@ class ConsensusKernel:
         dp = int(mesh.shape["dp"])
         sp = int(dict(mesh.shape).get("sp", 1))
         pre = self._pre
-        w = build_wire(codes_g, quals_g, self._delta94)
+        with span("engine.pack.wire"):
+            w = build_wire(codes_g, quals_g, self._delta94)
         if w is not None:
             wire, dict32 = w
             upload = wire.nbytes + seg_g.nbytes
@@ -3209,10 +3228,11 @@ class ConsensusKernel:
             def _dispatch(slot):
                 _ensure_jax()
                 t0 = time.monotonic()
-                wd = jax.device_put(wire, rows_sh)
-                sd = jax.device_put(seg_g, rows_sh)
-                dtab = CONST_CACHE.put("dict_tab", dict32,
-                                       sharding=repl_sh)
+                with span("feeder.upload", bytes=upload):
+                    wd = jax.device_put(wire, rows_sh)
+                    sd = jax.device_put(seg_g, rows_sh)
+                    dtab = CONST_CACHE.put("dict_tab", dict32,
+                                           sharding=repl_sh)
                 DEVICE_STATS.note_upload(slot, time.monotonic() - t0)
                 DEVICE_STATS.add_kernel_backend(slot, "xla")
                 if resident:
@@ -3222,7 +3242,8 @@ class ConsensusKernel:
                 return _consensus_segments_wire_mesh_jit(
                     wd, sd, dtab, pre, F_loc, mesh, full)
         else:
-            cp, qsent = pack_codes2(codes_g, quals_g)
+            with span("engine.pack.wire", layout="packed2"):
+                cp, qsent = pack_codes2(codes_g, quals_g)
             upload = cp.nbytes + qsent.nbytes + seg_g.nbytes
             tables_dev = self._tables_dev
             new = SHAPE_REGISTRY.observe(
@@ -3232,13 +3253,14 @@ class ConsensusKernel:
             def _dispatch(slot):
                 _ensure_jax()
                 t0 = time.monotonic()
-                cd = jax.device_put(cp, rows_sh)
-                qd = jax.device_put(qsent, rows_sh)
-                sd = jax.device_put(seg_g, rows_sh)
-                ct = CONST_CACHE.put("correct_tab", self._correct_f32,
-                                     sharding=repl_sh)
-                et = CONST_CACHE.put("err_tab", self._err_f32,
-                                     sharding=repl_sh)
+                with span("feeder.upload", bytes=upload):
+                    cd = jax.device_put(cp, rows_sh)
+                    qd = jax.device_put(qsent, rows_sh)
+                    sd = jax.device_put(seg_g, rows_sh)
+                    ct = CONST_CACHE.put("correct_tab", self._correct_f32,
+                                         sharding=repl_sh)
+                    et = CONST_CACHE.put("err_tab", self._err_f32,
+                                         sharding=repl_sh)
                 DEVICE_STATS.note_upload(slot, time.monotonic() - t0)
                 DEVICE_STATS.add_kernel_backend(slot, "xla")
                 return _consensus_segments_packed2_mesh_jit(
@@ -3295,7 +3317,8 @@ class ConsensusKernel:
         d16 = e16 = resident = None
         deadline = ticket_deadline_s(ticket)
         try:
-            dev = ticket.wait(deadline)
+            with span("resolve.wait", wait=True):
+                dev = ticket.wait(deadline)
             if isinstance(dev[-1], ResidentHandles):
                 resident = dev[-1]
                 dev = dev[:-1]
@@ -3354,11 +3377,12 @@ class ConsensusKernel:
 
         BREAKER.record_success()
         _feed_router(ticket, fetched)
-        return self._complete_wire_columns(
-            qs, wp, d16, e16, codes2d, quals2d, starts,
-            want_extras=want_extras, resident=resident,
-            gather=ticket.mesh_gather, devices=ticket.mesh_devices,
-            f_loc=ticket.mesh_f_loc, slot=ticket.slot)
+        with span("resolve.unpack", rusage=True):
+            return self._complete_wire_columns(
+                qs, wp, d16, e16, codes2d, quals2d, starts,
+                want_extras=want_extras, resident=resident,
+                gather=ticket.mesh_gather, devices=ticket.mesh_devices,
+                f_loc=ticket.mesh_f_loc, slot=ticket.slot)
 
     def _complete_wire_columns(self, qs, wp, d16, e16,
                                codes2d: np.ndarray, quals2d: np.ndarray,
@@ -3992,8 +4016,10 @@ class ConsensusKernel:
         if dev is HOST_DISPATCH:
             engine = self._host()
             t0 = time.monotonic()
-            winner, qual, depth, errors, n_slow = engine.call_segments_counted(
-                codes2d, quals2d, np.asarray(starts, dtype=np.int64))
+            with span("resolve.host_engine", rusage=True):
+                winner, qual, depth, errors, n_slow = \
+                    engine.call_segments_counted(
+                        codes2d, quals2d, np.asarray(starts, dtype=np.int64))
             from .router import ROUTER
 
             ROUTER.observe_host(codes2d.size, time.monotonic() - t0)

@@ -113,9 +113,18 @@ def available() -> bool:
         return False
     global _IMPORT_OK
     if _IMPORT_OK is None:
+        from ..observe.process import startup_span
+        from .kernel import _ensure_jax
+
+        # the first jax import of a process goes through _ensure_jax (one
+        # thread at a time, cache placed, compiles watched): the first wire
+        # dispatch of a CLI run asks this before anything else has
+        _ensure_jax()
         try:
-            from jax.experimental import pallas as _pl  # noqa: F401
-            from jax.experimental.pallas import tpu as _pltpu  # noqa: F401
+            with startup_span("startup.pallas_import"):
+                from jax.experimental import pallas as _pl  # noqa: F401
+                from jax.experimental.pallas import (  # noqa: F401
+                    tpu as _pltpu)
 
             _IMPORT_OK = True
         except Exception as exc:  # noqa: BLE001 - any import failure

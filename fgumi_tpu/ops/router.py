@@ -237,14 +237,19 @@ class OffloadRouter:
         consensus→filter route's fetch instead — a 28 B/read stats row
         plus the survivors' 6 B/position masked columns, scaled by the
         measured keep-rate EWMA (prior 0.5)."""
-        if filtered:
-            with self._lock:
-                keep = self._filter_keep.get(0.5)
-            down = 28 * n_segments + int(keep * 6 * n_segments * L)
-        else:
-            down = (21 * n_segments * L) // 4
-        return self.decide(kernel, n_rows * L + 4 * n_rows, down,
-                           n_rows * L, devices=devices)
+        from ..observe.trace import span
+
+        with span("router.decide") as sp:
+            if filtered:
+                with self._lock:
+                    keep = self._filter_keep.get(0.5)
+                down = 28 * n_segments + int(keep * 6 * n_segments * L)
+            else:
+                down = (21 * n_segments * L) // 4
+            side = self.decide(kernel, n_rows * L + 4 * n_rows, down,
+                               n_rows * L, devices=devices)
+            sp.set(side=side, why=self._last.get("why", ""))
+        return side
 
     def decide(self, kernel, up_bytes: int, down_bytes: int,
                cells: int, devices: int = 1) -> str:

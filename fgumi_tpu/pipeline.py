@@ -172,33 +172,45 @@ def _traced_source(source_iter):
     """Wrap a source iterator so each pull is a pipeline.read span (runs on
     whichever thread drives the iterator — the reader thread when threaded,
     the caller inline — so thread attribution is automatic)."""
+    from .observe.trace import spanned_iter
+
+    return spanned_iter("pipeline.read", source_iter)
+
+
+def _traced_stage(name, fn):
+    """Wrap a stage callable in a named span."""
     from .observe.trace import span
 
-    it = iter(source_iter)
-    while True:
-        with span("pipeline.read"):
-            try:
-                item = next(it)
-            except StopIteration:
-                return
-        yield item
+    def wrapped(item):
+        with span(name):
+            return fn(item)
+    return wrapped
 
 
-def _traced_stage(name, fn, materialize=False):
-    """Wrap a stage callable in a named span. ``materialize`` forces lazy
-    process outputs into a list so the span covers the actual work, not
-    just generator construction (tracing is opt-in diagnostics; the small
-    buffering change is acceptable there)."""
-    from .observe.trace import span
+def _traced_process(name, fn):
+    """Wrap the processing callable so that its work is spanned where it
+    happens and nowhere else is changed: a stage that returns a list works
+    inside the call, a generator stage on each pull, so each pull is a span
+    (as ``_traced_source`` does for the reader) and outputs still reach the
+    next stage one by one."""
+    from .observe.trace import span, spanned_iter
 
-    if materialize:
-        def wrapped(item):
-            with span(name):
-                return list(fn(item))
-    else:
-        def wrapped(item):
-            with span(name):
-                return fn(item)
+    def wrapped(item):
+        with span(name):
+            out = fn(item)
+            if isinstance(out, (list, tuple)):
+                it = None
+            else:
+                it = iter(out)
+                try:
+                    first = next(it)  # the call and the first pull: one span
+                except StopIteration:
+                    return
+        if it is None:
+            yield from out
+            return
+        yield first
+        yield from spanned_iter(name, it)
     return wrapped
 
 
@@ -243,12 +255,11 @@ def run_stages(source_iter, process_fn, sink_fn, threads: int = 0,
     from .observe import trace as _trace
 
     if _trace.tracing_enabled():
-        # wrap only when tracing is on: with flags off the hot path runs
+        # wrap only when spans are live: with flags off the hot path runs
         # the caller's bare callables (zero telemetry overhead, no new
         # per-item allocations — the acceptance contract of observe/)
         source_iter = _traced_source(source_iter)
-        process_fn = _traced_stage("pipeline.process", process_fn,
-                                   materialize=True)
+        process_fn = _traced_process("pipeline.process", process_fn)
         if resolve_fn is not None:
             resolve_fn = _traced_stage("pipeline.resolve", resolve_fn)
         sink_fn = _traced_stage("pipeline.sink", sink_fn)
@@ -513,10 +524,13 @@ def _run_stages_impl(source_iter, process_fn, sink_fn, threads, queue_items,
     for t in wts:
         t.start()
     serial = 0
+    from .observe.trace import span
+
     try:
         while True:
             t0 = time.monotonic()
-            item = q_in.get()
+            with span("pipeline.wait_in", wait=True):
+                item = q_in.get()
             now = time.monotonic()
             stats.add_blocked("process", now - t0)
             if item is _DONE:
@@ -526,11 +540,12 @@ def _run_stages_impl(source_iter, process_fn, sink_fn, threads, queue_items,
             nb, item = item
             try:
                 for out in process_fn(item):
-                    if n_workers:
-                        q_out.put((serial, out))
-                        serial += 1
-                    else:
-                        q_out.put(out)
+                    with span("pipeline.wait_out", wait=True):
+                        if n_workers:
+                            q_out.put((serial, out))
+                            serial += 1
+                        else:
+                            q_out.put(out)
             finally:
                 if nb:
                     budget.release(nb)

@@ -198,7 +198,7 @@ class ChainChannel:
         if self._trace_on:
             from .observe.trace import span
 
-            with span("chain.put", channel=self.name, bytes=n):
+            with span("chain.put", wait=True, channel=self.name, bytes=n):
                 self._put(blob, n)
         else:
             self._put(blob, n)
@@ -267,7 +267,9 @@ class ChainChannel:
     def header(self):
         """The stream's ``BamHeader`` (blocks until the producer publishes;
         raises :class:`ChainAborted` if it never will)."""
-        with self._cv:
+        from .observe.trace import span
+
+        with span("chain.header", wait=True, channel=self.name), self._cv:
             while not self._have_header:
                 if self._abort_reason is not None or self._cancelled:
                     raise ChainAborted(self._reason_locked())
@@ -279,6 +281,14 @@ class ChainChannel:
 
     def get(self):
         """Next blob, or None at end of stream."""
+        if self._trace_on:
+            from .observe.trace import span
+
+            with span("chain.get", wait=True, channel=self.name):
+                return self._get()
+        return self._get()
+
+    def _get(self):
         from .observe.metrics import METRICS
 
         t0 = time.monotonic()

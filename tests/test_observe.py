@@ -510,3 +510,382 @@ def test_concurrent_goal_holders_do_not_clobber():
         a.finish()
     assert hb_mod._goal_total() is None
     METRICS.reset()
+
+
+# ---------------------------------------------------------------------------
+# one span system, three sinks (ISSUE 25)
+
+
+def _spans():
+    return trace.current_aggregate().snapshot()["by_name"]
+
+
+def test_span_unarmed_reads_no_clock_no_rusage_no_jax(monkeypatch):
+    """With neither --trace nor --run-report a span is the shared no-op:
+    nothing is read, nothing allocated, jax is not looked for."""
+    def boom(*a, **k):
+        raise AssertionError("an unarmed span touched a sink")
+
+    monkeypatch.setattr(trace._resource, "getrusage", boom)
+    monkeypatch.setattr(trace, "_annotation_cls", boom)
+    monkeypatch.setattr(trace._Span, "__init__", boom)
+    monkeypatch.setattr(trace.time, "monotonic", boom)
+    assert trace.span("engine.pack", rusage=True, batch=1) is trace.NULL_SPAN
+    assert trace.span("chain.get", wait=True) is trace.NULL_SPAN
+    trace.record_interval("feeder.queue_wait", 0.0, 1.0)
+    trace.count("engine.pack", "staging_allocs")
+    assert list(trace.spanned_iter("sort.merge", [1, 2])) == [1, 2]
+    assert trace.spanned("group.assign")(lambda x: x + 1)(1) == 2
+    assert trace.current_aggregate() is None
+
+
+def test_arm_spans_without_chrome_tracer():
+    """--run-report alone arms the aggregate, not the Chrome trace."""
+    agg = trace.arm_spans()
+    assert trace.tracing_enabled() and trace.arm_spans() is agg
+    assert trace._current_tracer() is None
+    with trace.span("a"):
+        pass
+    assert _spans()["a"]["count"] == 1
+    trace.instant("marker")  # needs the Chrome tracer: a no-op here
+    assert trace.stop_trace() is None
+    assert not trace.tracing_enabled()
+
+
+def test_span_parent_and_self_time_nested_and_siblings_two_threads():
+    import time as _time
+
+    t = trace.start_trace()
+
+    def work():
+        with trace.span("outer"):
+            with trace.span("child"):
+                _time.sleep(0.02)
+            with trace.span("child"):
+                _time.sleep(0.02)
+            _time.sleep(0.01)
+
+    th = threading.Thread(target=work, name="obs-other")
+    with trace.span("main-top"):
+        th.start()
+        th.join()  # the other thread's spans are no children of this one
+    work()
+    by = _spans()
+    outer, child, top = by["outer"], by["child"], by["main-top"]
+    assert outer["count"] == 2 and child["count"] == 4
+    assert set(outer["threads"]) == {"obs-other",
+                                     threading.current_thread().name}
+    # self time = duration minus the children's cover, per thread
+    assert child["self_s"] == pytest.approx(child["wall_s"])
+    assert outer["self_s"] == pytest.approx(
+        outer["wall_s"] - child["wall_s"], abs=1e-4)
+    assert 0.015 <= outer["self_s"] <= outer["wall_s"] - 0.07
+    assert top["self_s"] == pytest.approx(top["wall_s"])
+    assert child["p50_s"] <= child["max_s"]
+    # the Chrome events name their parent
+    parents = {(e["name"], (e.get("args") or {}).get("parent"))
+               for e in t.snapshot() if e["ph"] == "X"}
+    assert parents == {("outer", None), ("child", "outer"),
+                       ("main-top", None)}
+
+
+def test_span_wait_cover_propagates_to_every_ancestor():
+    import time as _time
+
+    trace.arm_spans()
+    with trace.span("stage"):
+        with trace.span("pull"):
+            with trace.span("q.get", wait=True):
+                _time.sleep(0.02)
+        _time.sleep(0.005)
+    by = _spans()
+    assert by["q.get"]["wait_s"] == pytest.approx(by["q.get"]["wall_s"])
+    assert by["pull"]["wait_s"] == pytest.approx(by["q.get"]["wall_s"])
+    assert by["stage"]["wait_s"] == pytest.approx(by["q.get"]["wall_s"])
+    own = by["stage"]["wall_s"] - by["stage"]["wait_s"]
+    assert 0.004 <= own < by["stage"]["wall_s"]
+
+
+def test_span_rusage_fields_only_when_asked():
+    trace.arm_spans()
+    with trace.span("plain"):
+        pass
+    import mmap
+
+    with trace.span("layer", rusage=True):
+        # a fresh anonymous mapping faults on first touch whatever state the
+        # heap is in (a bytearray may come from resident pages and fault 0)
+        with mmap.mmap(-1, 4 << 20) as m:
+            m.write(b"x" * (4 << 20))
+        sum(range(200000))
+    by = _spans()
+    assert "minflt" not in by["plain"]
+    rec = by["layer"]
+    for key in ("utime_s", "stime_s", "minflt", "majflt", "nvcsw", "nivcsw"):
+        assert key in rec and rec[key] >= 0
+    assert isinstance(rec["minflt"], int) and rec["minflt"] >= 1024
+    assert rec["utime_s"] + rec["stime_s"] > 0
+
+
+def test_span_counters_and_record_interval():
+    import time as _time
+
+    t = trace.start_trace()
+    with trace.span("engine.pack"):
+        with trace.span("engine.pack.wire"):
+            trace.count("engine.pack", "staging_reuses")
+            trace.count("engine.pack", "staging_reuses")
+            trace.count("no.such.span", "x")
+    t0 = _time.monotonic()
+    trace.record_interval("feeder.queue_wait", t0 - 0.5, t0, slot=3)
+    by = _spans()
+    assert by["engine.pack"]["staging_reuses"] == 2
+    assert "staging_reuses" not in by["engine.pack.wire"]
+    assert by["feeder.queue_wait"]["wall_s"] == pytest.approx(0.5)
+    (ev,) = [e for e in t.snapshot() if e["name"] == "feeder.queue_wait"]
+    assert ev["dur"] == pytest.approx(0.5e6, rel=1e-3)
+    assert ev["args"] == {"slot": 3}
+
+
+def test_spanned_iter_spans_each_pull_not_the_consumer():
+    import time as _time
+
+    trace.arm_spans()
+
+    def gen():
+        for i in range(3):
+            _time.sleep(0.01)
+            yield i
+
+    for _ in trace.spanned_iter("merge", gen()):
+        _time.sleep(0.02)  # the consumer's time is nobody's pull
+    rec = _spans()["merge"]
+    assert rec["count"] == 4  # three items and the pull that ends it
+    assert 0.03 <= rec["wall_s"] < 0.06
+
+
+def test_run_stages_generator_stage_yields_item_by_item_when_tracing():
+    """The `materialize` repair: tracing must not change when outputs reach
+    the next stage. A generator stage's second output is produced only
+    after the first was sunk, exactly as with tracing off."""
+    from fgumi_tpu.pipeline import run_stages
+
+    def run(tracing):
+        order = []
+
+        def process(item):
+            for k in range(3):
+                order.append(("made", item, k))
+                yield (item, k)
+
+        if tracing:
+            trace.start_trace()
+        run_stages(iter([0, 1]), process,
+                   lambda out: order.append(("sunk",) + out), threads=0)
+        trace.stop_trace()
+        return order
+
+    plain, traced = run(False), run(True)
+    assert traced == plain
+    assert traced[:4] == [("made", 0, 0), ("sunk", 0, 0),
+                          ("made", 0, 1), ("sunk", 0, 1)]
+
+
+def test_run_stages_spans_list_stage_once_and_waits_when_threaded():
+    from fgumi_tpu.pipeline import run_stages
+
+    trace.arm_spans()
+    sunk = []
+    run_stages(iter(range(4)), lambda x: [x, x], sunk.append, threads=2)
+    by = _spans()
+    assert by["pipeline.process"]["count"] == 4  # per item, not per output
+    assert by["pipeline.wait_in"]["count"] == 5  # four items and the end
+    assert by["pipeline.wait_out"]["count"] == 8
+    assert by["pipeline.wait_in"]["wait_s"] == \
+        pytest.approx(by["pipeline.wait_in"]["wall_s"])
+
+
+def test_flight_ring_takes_no_per_block_span_and_none_without_trace():
+    from fgumi_tpu.observe.flight import FLIGHT
+
+    def ring_spans():
+        return [e.get("name") for e in FLIGHT.events()
+                if e.get("kind") == "span"]
+
+    FLIGHT.reset()
+    trace.arm_spans()  # --run-report alone: the ring hears nothing
+    with trace.span("device.dispatch"):
+        pass
+    assert ring_spans() == []
+    trace.stop_trace()
+    trace.start_trace()  # --trace: layer spans, never per-block I/O
+    for name in ("bgzf.compress", "bgzf.decompress", "io.prefetch.read",
+                 "device.dispatch"):
+        with trace.span(name):
+            pass
+    assert ring_spans() == ["device.dispatch"]
+    FLIGHT.reset()
+
+
+def test_span_mirrors_onto_profiler_clock(tmp_path):
+    """Sink B: with a profiler session open, a spanned dispatch lands in the
+    xplane's host plane under its own name, on the named thread, through
+    the benchmark's own trace loader."""
+    import os
+    import sys
+
+    import jax
+    import jax.numpy as jnp
+
+    from fgumi_tpu.observe.scope import spawn_thread
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    sys.path.insert(0, bench)
+    try:
+        import tracered
+    finally:
+        sys.path.remove(bench)
+
+    trace.arm_spans()
+
+    def dispatch():
+        with trace.span("engine.pack", rusage=True, batch=7):
+            with trace.span("device.dispatch"):
+                jnp.arange(8).sum().block_until_ready()
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        th = spawn_thread(dispatch, name="fgumi-process")
+        th.start()
+        th.join()
+    finally:
+        jax.profiler.stop_trace()
+    _device, host = tracered.load(tracered.find_xplane(str(tmp_path)))
+    names = {name for name, _s, _d, _m in host.get("fgumi-process", [])}
+    assert {"engine.pack", "device.dispatch"} <= names
+    pack = [(s, d) for n, s, d, _m in host["fgumi-process"]
+            if n == "engine.pack"]
+    inner = [(s, d) for n, s, d, _m in host["fgumi-process"]
+             if n == "device.dispatch"]
+    assert pack[0][0] <= inner[0][0]
+    assert inner[0][0] + inner[0][1] <= pack[0][0] + pack[0][1] + 1e-6
+
+
+def test_name_os_thread_sets_comm():
+    from fgumi_tpu.observe.scope import name_os_thread, spawn_thread
+
+    seen = {}
+
+    def comm():
+        with open("/proc/thread-self/comm") as f:
+            return f.read().strip()
+
+    def work():
+        seen["before"] = comm()
+        name_os_thread("fgumi-device-feeder")
+        seen["after"] = comm()
+
+    th = spawn_thread(work, name="fgumi-worker-0")
+    th.start()
+    th.join()
+    assert seen["before"] == "fgumi-worker-0"
+    assert seen["after"] == "device-feeder"  # 15 bytes: the prefix goes
+
+
+def test_compilewatch_keeps_cache_load_seconds_and_process_records():
+    from fgumi_tpu.observe import compilewatch, process
+    from fgumi_tpu.ops.datapath import SHAPE_REGISTRY
+
+    METRICS.reset()
+    before = len(process.snapshot()["compiles"])
+    new = SHAPE_REGISTRY.observe("obs-test", 7, 9)
+    with SHAPE_REGISTRY.attribute_compiles(new):
+        compilewatch._on_event(compilewatch._CACHE_HIT_EVENT)
+        compilewatch._on_duration(compilewatch._BACKEND_COMPILE_EVENT, 0.25,
+                                  fun_name="jit(fn)")
+        compilewatch._on_duration(compilewatch._BACKEND_COMPILE_EVENT, 1.5,
+                                  fun_name="jit(fn)")
+    try:
+        assert METRICS.get("device.compile_cache_hits") == 1
+        assert METRICS.get("device.compile_cache_load_s") == 0.25
+        assert METRICS.get("device.backend_compiles") == 1
+        assert METRICS.get("device.backend_compile_s") == 1.5
+        assert METRICS.get("device.shape_bucket.recompiles") == 1
+        recs = process.snapshot()["compiles"][before:]
+        if recs:  # the bounded list may be full in a long test process
+            assert [r["kind"] for r in recs] == ["cache_load", "compile"]
+            assert recs[0]["shape"] == "obs-test:7x9"
+            assert recs[0]["s"] == 0.25 and recs[0]["fun"] == "jit(fn)"
+            assert recs[0]["at_s"] <= recs[1]["at_s"]
+    finally:
+        METRICS.reset()
+
+
+def test_process_record_bounded_oldest_kept(monkeypatch):
+    from fgumi_tpu.observe import process
+
+    monkeypatch.setattr(process, "_compiles", [])
+    monkeypatch.setattr(process, "_compiles_dropped", 0)
+    for i in range(process.MAX_COMPILE_RECORDS + 5):
+        process.note_compile("compile", float(i))
+    snap = process.snapshot()
+    assert len(snap["compiles"]) == process.MAX_COMPILE_RECORDS
+    assert snap["compiles"][0]["s"] == 0.0  # the oldest stay
+    assert snap["compiles_dropped"] == 5
+
+
+def test_startup_span_records_first_occurrence_only(monkeypatch):
+    from fgumi_tpu.observe import process
+
+    monkeypatch.setattr(process, "_spans", {})
+    trace.arm_spans()
+    with process.startup_span("startup.test"):
+        pass
+    first = process.snapshot()["spans"]["startup.test"]
+    with process.startup_span("startup.test"):
+        pass
+    assert process.snapshot()["spans"]["startup.test"] == first
+    assert first["s"] >= 0 and first["at_s"] > 0
+    assert _spans()["startup.test"]["count"] == 2
+
+
+def test_span_aggregate_loses_no_update_under_thread_contention():
+    """More threads than cores, a shortened switch interval: every span of
+    every thread is in the aggregate, and each thread's parents saw exactly
+    their own children."""
+    import sys
+
+    trace.arm_spans()
+    n_threads, n_spans = 12, 300
+    errors = []
+
+    def work():
+        try:
+            for _ in range(n_spans):
+                with trace.span("outer") as outer:
+                    with trace.span("inner", wait=True):
+                        pass
+                    trace.count("outer", "hits")
+                assert outer._parent is None
+        except BaseException as e:  # noqa: BLE001 - relayed to the assert
+            errors.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=work) for _ in range(n_threads)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=60)
+            assert not th.is_alive()
+    finally:
+        sys.setswitchinterval(old)
+    assert errors == []
+    by = _spans()
+    total = n_threads * n_spans
+    assert by["outer"]["count"] == by["inner"]["count"] == total
+    assert by["outer"]["hits"] == total
+    assert by["outer"]["wait_s"] == pytest.approx(by["inner"]["wall_s"])
+    assert by["outer"]["self_s"] <= by["outer"]["wall_s"] + 1e-6

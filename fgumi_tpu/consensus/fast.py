@@ -974,7 +974,7 @@ class FastSimplexCaller:
         """The device route of one batch: gather + pad into the device
         layout, wire build and hand-off to the feeder. Returns the pending
         tuple ``_PendingChunk.resolve`` completes."""
-        from ..ops.kernel import pad_segments_gather, pad_segments_mesh
+        from ..ops.kernel import pad_segments_mesh
         from ..ops.router import ROUTER
 
         kernel = self.caller.kernel
@@ -992,8 +992,8 @@ class FastSimplexCaller:
                 pred_s=pred[0] if pred else None, mesh=mesh,
                 mesh_gather=gather)
             return ("segw", multi, starts_p, codes_d, quals_d, ticket)
-        codes_dev, quals_dev, seg_ids, starts_p, F_pad, N_real = \
-            pad_segments_gather(codes, quals, rows_all, L_max, counts)
+        codes_dev, quals_dev, seg_ids, starts_p, F_pad, N_real, prebuilt = \
+            kernel.pack_segments_wire(codes, quals, rows_all, L_max, counts)
         if fused_filter:
             # fused consensus→filter dispatch: per-read stats fetch +
             # device-resident masked columns (survivors gathered at
@@ -1001,7 +1001,7 @@ class FastSimplexCaller:
             ticket = kernel.device_call_segments_wire(
                 codes_dev, quals_dev, seg_ids, F_pad, len(multi),
                 pack_t0=t_pack0, full=True,
-                pred_s=pred[0] if pred else None,
+                pred_s=pred[0] if pred else None, prebuilt=prebuilt,
                 filter_params=(
                     np.int32(opts.min_reads),
                     np.int32(opts.min_consensus_base_quality),
@@ -1012,7 +1012,7 @@ class FastSimplexCaller:
         ticket = kernel.device_call_segments_wire(
             codes_dev, quals_dev, seg_ids, F_pad, len(multi),
             pack_t0=t_pack0, full=full,
-            pred_s=pred[0] if pred else None)
+            pred_s=pred[0] if pred else None, prebuilt=prebuilt)
         return ("segw", multi, starts_p, codes_dev[:N_real],
                 quals_dev[:N_real], ticket)
 

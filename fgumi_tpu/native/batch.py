@@ -421,6 +421,66 @@ def segment_depth_errors(codes2d: np.ndarray, winner: np.ndarray,
     return depth, errors
 
 
+def wire_inputs_ok(codes: np.ndarray, quals: np.ndarray, rows=None,
+                   L: int = None) -> bool:
+    """Whether :func:`build_wire` can take these inputs: the library is
+    loaded, codes/quals are uint8 matrices of one shape whose rows are
+    contiguous at one stride and at least ``L`` wide, and ``rows`` (when
+    given) is an int64 vector of in-range row numbers. Anything else is
+    the numpy path's to handle (or to refuse, as it always did)."""
+    if get_lib() is None:
+        return False
+    for a in (codes, quals):
+        if not (isinstance(a, np.ndarray) and a.dtype == np.uint8
+                and a.ndim == 2):
+            return False
+    if codes.shape != quals.shape or codes.strides != quals.strides:
+        return False
+    R, width = codes.shape
+    if (R > 1 and codes.strides[0] < width) or \
+            (width > 1 and codes.strides[1] != 1):
+        return False
+    if L is not None and not 0 <= L <= width:
+        return False
+    if rows is None:
+        return True
+    if not (isinstance(rows, np.ndarray) and rows.dtype == np.int64
+            and rows.ndim == 1 and rows.flags.c_contiguous):
+        return False
+    return not len(rows) or (int(rows.min()) >= 0 and int(rows.max()) < R)
+
+
+def build_wire(codes: np.ndarray, quals: np.ndarray, rows, n_pad: int,
+               L: int, wire: np.ndarray, codes_dev: np.ndarray = None,
+               quals_dev: np.ndarray = None):
+    """The device layout of one wire dispatch in one native pass
+    (fgumi_build_wire; inputs vetted by :func:`wire_inputs_ok`).
+
+    Row i of the layout is ``codes[rows[i], :L]`` (``rows=None``: row i of
+    the already dense input); rows up to ``n_pad`` are pad. Fills ``wire``
+    and, when given, the dense ``codes_dev`` / ``quals_dev`` (all
+    C-contiguous (n_pad, L) uint8). Returns the batch's distinct quals in
+    ascending order (uint8[n], n <= 63: the wire's dictionary order), or
+    None when there are more, with nothing written."""
+    n = len(codes) if rows is None else len(rows)
+    outs = [a for a in (wire, codes_dev, quals_dev) if a is not None]
+    if not all(a.dtype == np.uint8 and a.shape == (n_pad, L)
+               and a.flags.c_contiguous and a.flags.writeable for a in outs) \
+            or n > n_pad:
+        raise ValueError("build_wire: outputs must be writable C-contiguous "
+                         f"({n_pad}, {L}) uint8 arrays holding {n} rows")
+    vals = np.empty(64, dtype=np.uint8)
+    k = get_lib().fgumi_build_wire(
+        codes.ctypes.data, quals.ctypes.data,
+        codes.strides[0] if len(codes) > 1 else codes.shape[1],
+        None if rows is None else rows.ctypes.data, n, n_pad, L,
+        wire.ctypes.data,
+        None if codes_dev is None else codes_dev.ctypes.data,
+        None if quals_dev is None else quals_dev.ctypes.data,
+        vals.ctypes.data)
+    return None if k < 0 else vals[:k]
+
+
 def segment_depth_errors_ranges(codes2d: np.ndarray, winner: np.ndarray,
                                 lo, hi):
     """segment_depth_errors over explicit [lo[j], hi[j]) row ranges."""

@@ -92,7 +92,7 @@ extern "C" {
 // ABI version for the stale-.so guard in __init__.py: bump whenever any
 // exported signature changes (a symbol probe alone cannot detect an
 // argument-list change in an existing function).
-long fgumi_abi_version() { return 14; }
+long fgumi_abi_version() { return 15; }
 
 // Candidate UMI pairs with hamming(A[i], B[j]) <= d over (n, L)/(m, L) byte
 // matrices, via the d+1-part pigeonhole (umi/assigners.py
@@ -3135,6 +3135,67 @@ void fgumi_merge_close(void* handle) {
     if (r.f != nullptr) fclose(r.f);
   }
   delete st;
+}
+
+// Device layout of one wire dispatch in one pass and no temporaries
+// (ops/kernel.py build_wire, which stays the numpy oracle, and the gather +
+// pad of pad_segments_gather). Row i of the layout is row rows[i] of the
+// packed (R, stride) codes/quals, or row i itself when rows is NULL (the
+// input is already dense); rows [N, N_pad) are pad. First pass: which qual
+// values occur in the first L columns of the N rows (a pad row counts as
+// qual 0, as the padded numpy layout does). More than 63 distinct values:
+// returns -1 and writes nothing (the caller takes the packed-codes layout).
+// Second pass: wire (N_pad, L) = index of the qual in the sorted distinct
+// values << 2 | min(code, 3), 0xFC (WIRE_INVALID) where code == 4 and in
+// pad rows; codes_dev / quals_dev (N_pad, L), when not NULL, receive the
+// gathered rows with pad rows 4 / 0. vals[0..n) are the distinct values in
+// ascending order; returns n. No output overlaps an input or another output.
+long fgumi_build_wire(const uint8_t* codes, const uint8_t* quals, long stride,
+                      const int64_t* rows, long N, long N_pad, long L,
+                      uint8_t* wire, uint8_t* codes_dev, uint8_t* quals_dev,
+                      uint8_t* vals) {
+  uint8_t seen[256] = {0};
+  for (long i = 0; i < N; ++i) {
+    const uint8_t* q = quals + (rows ? rows[i] : i) * stride;
+    long k = 0;
+    for (; k + 8 <= L; k += 8) {
+      uint64_t x;
+      std::memcpy(&x, q + k, 8);
+      for (int b = 0; b < 64; b += 8) seen[(x >> b) & 0xFF] = 1;
+    }
+    for (; k < L; ++k) seen[q[k]] = 1;
+  }
+  if (N_pad > N && L > 0) seen[0] = 1;
+  uint8_t lut[256];
+  long n = 0;
+  for (int v = 0; v < 256; ++v) {
+    if (!seen[v]) continue;
+    if (n == 63) return -1;
+    vals[n] = static_cast<uint8_t>(v);
+    lut[v] = static_cast<uint8_t>(n++ << 2);
+  }
+  const size_t row_bytes = static_cast<size_t>(L);
+  for (long i = 0; i < N; ++i) {
+    const int64_t r = rows ? rows[i] : i;
+    const uint8_t* __restrict c = codes + r * stride;
+    const uint8_t* __restrict q = quals + r * stride;
+    uint8_t* __restrict w = wire + i * L;
+    // the table lookups on their own, so that the compiler vectorises the
+    // branch-free arithmetic on the codes (a fused loop runs three times
+    // as long, more where Ns are frequent and mispredicted)
+    for (long k = 0; k < L; ++k) w[k] = lut[q[k]];
+    for (long k = 0; k < L; ++k) {
+      const uint8_t ck = c[k];
+      w[k] = ck == 4 ? 0xFC : static_cast<uint8_t>(w[k] | (ck < 3 ? ck : 3));
+    }
+    if (codes_dev) std::memcpy(codes_dev + i * L, c, row_bytes);
+    if (quals_dev) std::memcpy(quals_dev + i * L, q, row_bytes);
+  }
+  const size_t pad_bytes = static_cast<size_t>(N_pad - N) * row_bytes;
+  std::memset(wire + N * L, 0xFC, pad_bytes);
+  if (codes_dev) std::memset(codes_dev + N * L, 4, pad_bytes);
+  if (quals_dev) std::memset(quals_dev + N * L, 0, pad_bytes);
+  return n;
 }
 
 // ---------------------------------------------------------------------------

@@ -140,7 +140,7 @@ def upload_donation_enabled() -> bool:
     return jax.default_backend() != "cpu"
 
 from ..constants import MAX_PHRED, MIN_PHRED, N_CODE
-from ..observe.trace import record_interval, span
+from ..observe.trace import count, record_interval, span
 from .datapath import CONST_CACHE, SHAPE_REGISTRY, as_device_operand
 from .tables import QualityTables
 
@@ -2181,6 +2181,14 @@ _consensus_segments_packed2_full_donated_jit = _lazy_jit(
     static_argnames=_W_STATIC, donate_argnums=(0, 1))(_packed2_full_fn)
 
 
+def _wire_dict(vals: np.ndarray, delta94: np.ndarray) -> np.ndarray:
+    """The wire's 64-entry dictionary: delta of each distinct qual, in
+    ascending qual order; unused entries (and qidx 63) are 0."""
+    dict64 = np.zeros(64, dtype=np.float32)
+    dict64[: len(vals)] = delta94[np.minimum(vals, MAX_PHRED)]
+    return dict64
+
+
 def build_wire(codes2d: np.ndarray, quals2d: np.ndarray, delta94: np.ndarray,
                out: np.ndarray = None):
     """Host-side wire build: (wire (N, L) uint8, dict64 (64,) f32) or None
@@ -2188,7 +2196,20 @@ def build_wire(codes2d: np.ndarray, quals2d: np.ndarray, delta94: np.ndarray,
     the packed-codes layout). delta94 = correct_f32 - err_f32 per Phred.
     ``out``: optional preallocated (N, L) uint8 staging buffer (the
     feeder's recycled pool) filled in place instead of minting a fresh
-    array per dispatch."""
+    array per dispatch.
+
+    One native pass that mints nothing where the library is loaded
+    (native/batch.build_wire); the numpy body below returns the same bytes
+    on a host without it and is the tests' oracle. ``engine.pack``'s
+    counters ``wire_native`` / ``wire_numpy`` say which one ran."""
+    from ..native import batch as nb
+
+    if nb.wire_inputs_ok(codes2d, quals2d):
+        count("engine.pack", "wire_native")
+        wire = out if out is not None else np.empty(codes2d.shape, np.uint8)
+        vals = nb.build_wire(codes2d, quals2d, None, *codes2d.shape, wire)
+        return None if vals is None else (wire, _wire_dict(vals, delta94))
+    count("engine.pack", "wire_numpy")
     hist = np.bincount(quals2d.ravel(), minlength=256)
     vals = np.nonzero(hist)[0]
     if len(vals) > 63:
@@ -2203,9 +2224,7 @@ def build_wire(codes2d: np.ndarray, quals2d: np.ndarray, delta94: np.ndarray,
     else:
         wire = (lut[quals2d] << 2) | np.minimum(codes2d, 3)
     wire[codes2d == N_CODE] = WIRE_INVALID
-    dict64 = np.zeros(64, dtype=np.float32)
-    dict64[: len(vals)] = delta94[np.minimum(vals, MAX_PHRED)]
-    return wire, dict64
+    return wire, _wire_dict(vals, delta94)
 
 
 def pack_codes2(codes2d: np.ndarray, quals2d: np.ndarray):
@@ -2552,6 +2571,31 @@ def pad_segments(codes2d: np.ndarray, quals2d: np.ndarray,
     return codes_dev, quals_dev, seg_ids, starts, F_pad
 
 
+def _gather_layout(counts: np.ndarray):
+    """Row bookkeeping of the gathered device layout (pad_segments'
+    invariants): (seg_ids (N_pad,), starts (J+1,), F_pad, N, N_pad)."""
+    counts = np.asarray(counts, dtype=np.int64)
+    starts = np.concatenate(([0], np.cumsum(counts)))
+    N = int(starts[-1])
+    J = len(counts)
+    N_pad = _pad_rows(N)
+    F_pad = SHAPE_REGISTRY.bucket_segments(J)
+    DEVICE_STATS.add_pad(N, N_pad)
+    seg_ids = np.full(N_pad, max(J - 1, 0), dtype=np.int32)
+    seg_ids[:N] = np.repeat(np.arange(J, dtype=np.int32), counts)
+    return seg_ids, starts, F_pad, N, N_pad
+
+
+def _gather_rows(codes, quals, rows, L_max: int, N_pad: int):
+    """numpy gather of ``rows`` into fresh padded (N_pad, L_max) dense views
+    (pad rows all-N / qual 0)."""
+    codes_dev = np.full((N_pad, L_max), N_CODE, dtype=np.uint8)
+    quals_dev = np.zeros((N_pad, L_max), dtype=np.uint8)
+    codes_dev[:len(rows)] = codes[rows, :L_max]
+    quals_dev[:len(rows)] = quals[rows, :L_max]
+    return codes_dev, quals_dev
+
+
 def pad_segments_gather(codes: np.ndarray, quals: np.ndarray,
                         rows: np.ndarray, L_max: int, counts: np.ndarray):
     """Fused gather + bucket-pad: one copy instead of pad_segments' two.
@@ -2560,21 +2604,12 @@ def pad_segments_gather(codes: np.ndarray, quals: np.ndarray,
     padded (N_pad, L_max) device layout (same pad invariants as
     pad_segments). Returns (codes_dev, quals_dev, seg_ids, starts, F_pad, N);
     codes_dev[:N] / quals_dev[:N] are the dense views resolve_segments needs.
+    The numpy form of ConsensusKernel.pack_segments_wire's layout: what a
+    host without the native library runs, and the tests' oracle.
     """
     with span("engine.pack.gather"):
-        counts = np.asarray(counts, dtype=np.int64)
-        starts = np.concatenate(([0], np.cumsum(counts)))
-        N = int(starts[-1])
-        J = len(counts)
-        N_pad = _pad_rows(N)
-        F_pad = SHAPE_REGISTRY.bucket_segments(J)
-        DEVICE_STATS.add_pad(N, N_pad)
-        codes_dev = np.full((N_pad, L_max), N_CODE, dtype=np.uint8)
-        quals_dev = np.zeros((N_pad, L_max), dtype=np.uint8)
-        codes_dev[:N] = codes[rows, :L_max]
-        quals_dev[:N] = quals[rows, :L_max]
-        seg_ids = np.full(N_pad, max(J - 1, 0), dtype=np.int32)
-        seg_ids[:N] = np.repeat(np.arange(J, dtype=np.int32), counts)
+        seg_ids, starts, F_pad, N, N_pad = _gather_layout(counts)
+        codes_dev, quals_dev = _gather_rows(codes, quals, rows, L_max, N_pad)
     return codes_dev, quals_dev, seg_ids, starts, F_pad, N
 
 
@@ -2965,12 +3000,48 @@ class ConsensusKernel:
         return (self.device_call_segments(codes_dev, quals_dev, seg_ids,
                                           F_pad), starts)
 
+    def pack_segments_wire(self, codes, quals, rows, L_max: int, counts):
+        """pad_segments_gather plus the wire, in one native pass over the
+        ragged rows: the single-device route's whole pack.
+
+        Returns pad_segments_gather's tuple plus ``prebuilt`` for
+        :meth:`device_call_segments_wire`: the finished (wire, dict64) in a
+        pooled staging buffer, or None where that call still has the wire
+        to build (no native library: the numpy gather ran; or more than
+        63 distinct quals: the packed-codes layout). The pass fills fresh
+        ``np.empty`` dense views and mints nothing else."""
+        from ..native import batch as nb
+
+        if not nb.wire_inputs_ok(codes, quals, rows, L_max):
+            return pad_segments_gather(codes, quals, rows, L_max,
+                                       counts) + (None,)
+        from .datapath import STAGING_POOL
+
+        with span("engine.pack.gather"):
+            seg_ids, starts, F_pad, N, N_pad = _gather_layout(counts)
+            codes_dev = np.empty((N_pad, L_max), dtype=np.uint8)
+            quals_dev = np.empty((N_pad, L_max), dtype=np.uint8)
+        with span("engine.pack.wire"):
+            wire = STAGING_POOL.acquire((N_pad, L_max), np.uint8)
+            vals = nb.build_wire(codes, quals, rows, N_pad, L_max, wire,
+                                 codes_dev, quals_dev)
+            if vals is not None:
+                count("engine.pack", "wire_native")
+                return (codes_dev, quals_dev, seg_ids, starts, F_pad, N,
+                        (wire, _wire_dict(vals, self._delta94)))
+            STAGING_POOL.release(wire)
+        with span("engine.pack.gather", layout="packed2"):
+            codes_dev, quals_dev = _gather_rows(codes, quals, rows, L_max,
+                                                N_pad)
+        return codes_dev, quals_dev, seg_ids, starts, F_pad, N, None
+
     def device_call_segments_wire(self, codes2d_padded, quals2d_padded,
                                   seg_ids, num_segments: int, J: int,
                                   pack_t0: float = None, full: bool = False,
                                   resident_thresholds=None,
                                   pred_s: float = None, mesh=None,
-                                  mesh_gather=None, filter_params=None):
+                                  mesh_gather=None, filter_params=None,
+                                  prebuilt=None):
         """Async wire-format dispatch via the feeder pipeline.
 
         codes2d_padded/quals2d_padded: the full padded (N_pad, L) row layout
@@ -3014,7 +3085,11 @@ class ConsensusKernel:
         copies concurrently, and the device output is the shard-ordered
         (dp * F_loc, ...) global that resolve_segments_wire re-gathers.
         A 1-device (or None) mesh is exactly the legacy single-device
-        path — bit-for-bit, including the compiled executables."""
+        path — bit-for-bit, including the compiled executables.
+
+        ``prebuilt``: pack_segments_wire's finished (wire, dict64) for
+        these rows (single-device route only); the wire build is skipped
+        and its pooled buffer recycles with the ticket."""
         t_pack0 = pack_t0 if pack_t0 is not None else time.monotonic()
         mesh_active = mesh is not None and mesh.size > 1
         if mesh_active:
@@ -3034,11 +3109,17 @@ class ConsensusKernel:
                 self, codes2d_padded, quals2d_padded, seg_ids,
                 num_segments, J, full=full, pack_t0=t_pack0, pred_s=pred_s)
             if merged is not None:
+                if prebuilt is not None:
+                    # the merged launch builds its own wire over every
+                    # partner's rows: this one goes back to the pool unsent
+                    from .datapath import STAGING_POOL
+
+                    STAGING_POOL.release(prebuilt[0])
                 return merged
         plan = self._wire_dispatch_plan(
             codes2d_padded, quals2d_padded, seg_ids, num_segments, J,
             full=full, resident_thresholds=resident_thresholds,
-            filter_params=filter_params)
+            filter_params=filter_params, prebuilt=prebuilt)
         DEVICE_STATS.add_dispatch(segments_flops(
             codes2d_padded.shape[0], codes2d_padded.shape[1], num_segments))
         slot = DEVICE_STATS.begin_in_flight(
@@ -3061,7 +3142,8 @@ class ConsensusKernel:
 
     def _wire_dispatch_plan(self, codes2d_padded, quals2d_padded, seg_ids,
                             num_segments: int, J: int, full: bool = False,
-                            resident_thresholds=None, filter_params=None):
+                            resident_thresholds=None, filter_params=None,
+                            prebuilt=None):
         """Build — but do not submit — one wire-layout dispatch.
 
         The shared dispatch seam of the solo path and the cross-job
@@ -3071,14 +3153,21 @@ class ConsensusKernel:
         closure (runs on the feeder thread), the upload byte count for
         the feeder's governed budget, the shape-registry new-shape flag,
         the pooled staging buffers to recycle at resolve, and whether the
-        fused-filter kernel was actually selected."""
+        fused-filter kernel was actually selected. ``prebuilt``: the
+        (wire, dict64) that pack_segments_wire already built for these
+        rows in a pooled buffer — taken as is."""
         out_segments = _pad_out_segments(J, num_segments)
         from .datapath import STAGING_POOL
 
-        with span("engine.pack.wire"):
-            staging = [STAGING_POOL.acquire(codes2d_padded.shape, np.uint8)]
-            w = build_wire(codes2d_padded, quals2d_padded, self._delta94,
-                           out=staging[0])
+        if prebuilt is not None:
+            w = prebuilt
+            staging = [w[0]]
+        else:
+            with span("engine.pack.wire"):
+                staging = [STAGING_POOL.acquire(codes2d_padded.shape,
+                                                np.uint8)]
+                w = build_wire(codes2d_padded, quals2d_padded, self._delta94,
+                               out=staging[0])
         pre = self._pre
         tables_dev = self._tables_dev
         filt = filter_params is not None

@@ -116,6 +116,162 @@ def test_build_wire_declines_wide_qual_sets():
     assert build_wire(codes, quals, np.zeros(94, np.float32)) is None
 
 
+def _numpy_build_wire(codes2d, quals2d, delta94):
+    """build_wire's numpy body (the oracle): the library looks absent."""
+    from unittest import mock
+
+    from fgumi_tpu.native import batch as nb
+
+    with mock.patch.object(nb, "get_lib", return_value=None):
+        return build_wire(codes2d, quals2d, delta94)
+
+
+def _wire_case(name):
+    """(codes (R, stride), quals, rows or None, L_max, counts) of one
+    layout the native pass must reproduce byte for byte. Quals are never
+    0 unless the case says so: a 0 in the dictionary comes from pad rows."""
+    rng = np.random.default_rng(11)
+    R, stride = 64, 24
+    codes = rng.integers(0, 5, size=(R, stride)).astype(np.uint8)
+    quals = rng.choice(np.array([2, 11, 25, 37, 40], np.uint8),
+                       size=(R, stride))
+    L = stride
+    rows = np.sort(rng.choice(R, 27, replace=False)).astype(np.int64)
+    counts = [5, 1, 9, 2, 10]
+    if name in ("exact_bucket", "63_quals", "64_quals"):
+        rows = np.arange(16, 48, dtype=np.int64)     # _pad_rows(32) == 32
+        counts = [7, 25]
+    if name == "short_L":
+        L = 16
+    elif name == "unsorted_rows":
+        rng.shuffle(rows)
+    elif name == "repeated_rows":
+        rows[5:9] = rows[4]
+        rows[20] = rows[0]
+    elif name == "all_N":
+        codes[:] = 4
+    elif name == "one_row":
+        rows, counts = np.array([5], dtype=np.int64), [1]
+    elif name in ("63_quals", "64_quals", "63_quals_plus_pad"):
+        n = 64 if name == "64_quals" else 63
+        quals[rows] = (1 + np.arange(len(rows) * stride) % n).reshape(
+            len(rows), stride)
+    elif name == "dense":
+        rows = None
+    return codes, quals, rows, L, counts
+
+
+@pytest.mark.parametrize("name", [
+    "padded", "exact_bucket", "short_L", "unsorted_rows", "repeated_rows",
+    "all_N", "one_row", "63_quals", "64_quals", "63_quals_plus_pad",
+    "dense"])
+def test_native_wire_pass_equals_numpy(name):
+    """The one native pass (gather + pad + histogram + LUT + wire) against
+    numpy's pad_segments_gather + build_wire, byte for byte: the fused
+    ragged form of the single-device route, and the dense form every other
+    caller of build_wire takes."""
+    from fgumi_tpu.native import batch as nb
+    from fgumi_tpu.ops.datapath import STAGING_POOL
+
+    if not nb.available():
+        pytest.skip("no native library on this host")
+    kernel = ConsensusKernel(TABLES)
+    delta94 = kernel._delta94
+    codes, quals, rows, L, counts = _wire_case(name)
+    if rows is None:
+        # already the dense (N_pad, L) layout (mesh route, coalescer)
+        cd, qd = codes, quals
+        N = N_pad = len(codes)
+    else:
+        cd, qd, seg, starts, F_pad, N = pad_segments_gather(
+            codes, quals, rows, L, counts)
+        N_pad = _pad_rows(N)
+        held0 = STAGING_POOL.snapshot()["held_bytes"]
+        got = kernel.pack_segments_wire(codes, quals, rows, L, counts)
+    want = _numpy_build_wire(cd, qd, delta94)
+    declines = name in ("64_quals", "63_quals_plus_pad")
+    assert (want is None) == declines
+    assert (N == N_pad) == (name in ("exact_bucket", "63_quals",
+                                     "64_quals", "dense"))
+    if name == "padded":
+        assert want[1][0] == delta94[0] and (want[0][N:] == WIRE_INVALID).all()
+    if name == "63_quals":
+        assert np.count_nonzero(want[1]) == 63
+
+    # dense form, into a caller's buffer and into a fresh one
+    out = np.full(cd.shape, 0xA5, dtype=np.uint8)
+    for w in (build_wire(cd, qd, delta94, out=out),
+              build_wire(cd, qd, delta94)):
+        if declines:
+            assert w is None
+        else:
+            np.testing.assert_array_equal(w[0], want[0])
+            np.testing.assert_array_equal(w[1], want[1])
+            assert w[0].dtype == np.uint8 and w[1].dtype == np.float32
+    assert declines == bool((out == 0xA5).all())  # declined: nothing written
+    if rows is None:
+        return
+
+    # ragged form: the dense views, the bookkeeping and the wire in one pass
+    cd2, qd2, seg2, starts2, F_pad2, N2, prebuilt = got
+    np.testing.assert_array_equal(cd2, cd)
+    np.testing.assert_array_equal(qd2, qd)
+    np.testing.assert_array_equal(seg2, seg)
+    np.testing.assert_array_equal(starts2, starts)
+    assert (F_pad2, N2, seg2.dtype) == (F_pad, N, seg.dtype)
+    if declines:
+        # the packed-codes layout takes over, and the staging buffer the
+        # pass had taken is back in the pool for it
+        assert prebuilt is None
+        assert STAGING_POOL.snapshot()["held_bytes"] >= held0
+        return
+    np.testing.assert_array_equal(prebuilt[0], want[0])
+    np.testing.assert_array_equal(prebuilt[1], want[1])
+    STAGING_POOL.release(prebuilt[0])
+
+
+@pytest.mark.parametrize("native", [True, False])
+def test_wire_counters_say_which_build_ran(device_kernel, monkeypatch,
+                                           native):
+    """``engine.pack`` counts one ``wire_native`` a dispatch on a host with
+    the library and one ``wire_numpy`` under FGUMI_TPU_NO_NATIVE=1, and
+    the results are the oracle's either way."""
+    import fgumi_tpu.native as native_mod
+    from fgumi_tpu.observe import trace
+
+    if not native:
+        # what a fresh process does with the variable set
+        monkeypatch.setenv("FGUMI_TPU_NO_NATIVE", "1")
+        monkeypatch.setattr(native_mod, "_lib", None)
+        monkeypatch.setattr(native_mod, "_lib_failed", False)
+    elif native_mod.get_lib() is None:
+        pytest.skip("no native library on this host")
+    rng = np.random.default_rng(6)
+    trace.stop_trace()
+    trace.arm_spans()
+    try:
+        for _ in range(2):
+            codes, quals, counts, starts = make_ragged(rng, J=24, L=16)
+            rows = np.arange(len(codes), dtype=np.int64)
+            with trace.span("engine.pack"):
+                cd, qd, seg, st, F_pad, N, prebuilt = \
+                    device_kernel.pack_segments_wire(codes, quals, rows, 16,
+                                                     counts)
+                assert (prebuilt is not None) == native
+                ticket = device_kernel.device_call_segments_wire(
+                    cd, qd, seg, F_pad, len(counts), prebuilt=prebuilt)
+            w, q, d, e = device_kernel.resolve_segments_wire(
+                ticket, cd[:N], qd[:N], st)
+            assert_oracle_parity(codes, quals, starts, w, q, d, e)
+        pack = trace.current_aggregate().snapshot()["by_name"]["engine.pack"]
+    finally:
+        trace.stop_trace()
+    ran, other = (("wire_native", "wire_numpy") if native
+                  else ("wire_numpy", "wire_native"))
+    assert pack[ran] == pack["count"] == 2
+    assert other not in pack
+
+
 def test_pack_codes2_roundtrip():
     from fgumi_tpu.ops.kernel import QUAL_INVALID, pack_codes2
 

@@ -21,6 +21,8 @@ from ..io.bam import (FLAG_FIRST, FLAG_LAST, FLAG_MATE_UNMAPPED, FLAG_PAIRED,
                       FLAG_REVERSE, FLAG_SECONDARY, FLAG_SUPPLEMENTARY,
                       FLAG_UNMAPPED)
 from ..native import batch as nb
+from ..observe.metrics import METRICS
+from ..observe.trace import span as _span
 from ..ops import oracle
 from .fast import overlap_correct_span
 from .simple_umi import _ACGTN_UPPER, consensus_umis_batch
@@ -89,17 +91,75 @@ class FastDuplexCaller:
         """Consume one RecordBatch -> list of wire chunks (block_size-prefixed
         record runs). The molecule spanning the batch boundary is carried as
         RawRecords and processed via the slow path when it completes."""
-        flag = batch.flag
-        keep = (flag & (FLAG_SECONDARY | FLAG_SUPPLEMENTARY)) == 0
-        if not allow_unmapped:
-            is_mapped = (flag & FLAG_UNMAPPED) == 0
-            mapped_mate = ((flag & FLAG_PAIRED) != 0) \
-                & ((flag & FLAG_MATE_UNMAPPED) == 0)
-            keep &= is_mapped | mapped_mate
-        idx = np.nonzero(keep)[0]
+        with _span("process.decode", rusage=True):
+            flag = batch.flag
+            keep = (flag & (FLAG_SECONDARY | FLAG_SUPPLEMENTARY)) == 0
+            if not allow_unmapped:
+                is_mapped = (flag & FLAG_UNMAPPED) == 0
+                mapped_mate = ((flag & FLAG_PAIRED) != 0) \
+                    & ((flag & FLAG_MATE_UNMAPPED) == 0)
+                keep &= is_mapped | mapped_mate
+            idx = np.nonzero(keep)[0]
+            if len(idx):
+                mo, ml = self._parse_mi(batch, idx)
         if len(idx) == 0:
             return self.flush() if final else []
 
+        with _span("process.group", rusage=True):
+            buf = batch.buf
+            starts = nb.group_starts(buf, np.ascontiguousarray(mo),
+                                     (ml - 2).astype(np.int32))
+            bounds = np.append(starts, len(idx))
+            n_total = len(bounds) - 1
+            strand_b = buf[mo + ml - 1] == ord("B")  # per kept row
+
+            def materialize(lo, hi):
+                rows = idx[lo:hi]
+                a = batch.raw_records(rows[~strand_b[lo:hi]])
+                b = batch.raw_records(rows[strand_b[lo:hi]])
+                return a, b
+
+            first_base = self._base_mi(batch, int(idx[bounds[0]]))
+            merge_carry = self._carry is not None \
+                and self._carry[0] == first_base
+            if merge_carry:
+                a, b = materialize(bounds[0], bounds[1])
+                self._carry[1].extend(a)
+                self._carry[2].extend(b)
+
+            g0 = 1 if merge_carry else 0
+            g1 = n_total if final else max(n_total - 1, g0)
+            deferred = None
+            if not final and n_total - 1 >= g0:
+                a, b = materialize(bounds[n_total - 1], bounds[n_total])
+                deferred = (
+                    self._base_mi(batch, int(idx[bounds[n_total - 1]])),
+                    a, b)
+
+            out = []
+            if self._carry is not None:
+                if (not merge_carry) or final or n_total >= 2:
+                    out.extend(self._call_slow_molecule(*self._carry))
+                    self._carry = None
+
+        if g1 > g0:
+            if self.overlap_caller is not None:
+                with _span("process.overlap", rusage=True):
+                    self._overlap_correct(batch, idx, bounds, strand_b, g0,
+                                          g1)
+            out.extend(self._process_molecules(batch, idx, bounds, strand_b,
+                                               g0, g1))
+
+        if deferred is not None:
+            self._carry = deferred
+        if final:
+            out.extend(self.flush())
+        return out
+
+    def _parse_mi(self, batch, idx):
+        """One native aux scan for the tags this engine reads, then the MI
+        value's place in the buffer for every kept row, each checked for its
+        ``/A`` or ``/B`` suffix."""
         batch.prefetch_tags([self.tag, b"MC", b"RX"])
         mi_off, mi_len, _ = batch.tag_locs(self.tag)
         mo, ml = mi_off[idx], mi_len[idx]
@@ -117,51 +177,7 @@ class FastDuplexCaller:
                 f"Read has MI tag {mi!r} without /A or /B suffix. Duplex "
                 "consensus requires input from `group --strategy paired`, "
                 "which marks the source strand.")
-
-        starts = nb.group_starts(buf, np.ascontiguousarray(mo),
-                                 (ml - 2).astype(np.int32))
-        bounds = np.append(starts, len(idx))
-        n_total = len(bounds) - 1
-        strand_b = buf[mo + ml - 1] == ord("B")  # per kept row
-
-        def materialize(lo, hi):
-            rows = idx[lo:hi]
-            a = batch.raw_records(rows[~strand_b[lo:hi]])
-            b = batch.raw_records(rows[strand_b[lo:hi]])
-            return a, b
-
-        first_base = self._base_mi(batch, int(idx[bounds[0]]))
-        merge_carry = self._carry is not None and self._carry[0] == first_base
-        if merge_carry:
-            a, b = materialize(bounds[0], bounds[1])
-            self._carry[1].extend(a)
-            self._carry[2].extend(b)
-
-        g0 = 1 if merge_carry else 0
-        g1 = n_total if final else max(n_total - 1, g0)
-        deferred = None
-        if not final and n_total - 1 >= g0:
-            a, b = materialize(bounds[n_total - 1], bounds[n_total])
-            deferred = (self._base_mi(batch, int(idx[bounds[n_total - 1]])),
-                        a, b)
-
-        out = []
-        if self._carry is not None:
-            if (not merge_carry) or final or n_total >= 2:
-                out.extend(self._call_slow_molecule(*self._carry))
-                self._carry = None
-
-        if g1 > g0:
-            if self.overlap_caller is not None:
-                self._overlap_correct(batch, idx, bounds, strand_b, g0, g1)
-            out.extend(self._process_molecules(batch, idx, bounds, strand_b,
-                                               g0, g1))
-
-        if deferred is not None:
-            self._carry = deferred
-        if final:
-            out.extend(self.flush())
-        return out
+        return mo, ml
 
     def flush(self):
         if self._carry is None:
@@ -180,15 +196,22 @@ class FastDuplexCaller:
         """One molecule through DuplexConsensusCaller (the semantic
         reference). Overlap correction applies here unless the records were
         already corrected in place natively."""
-        if self.overlap_caller is not None and not corrected \
-                and a_records and b_records:
-            from .overlapping import apply_overlapping_consensus
+        # corrected=True marks a molecule of the vectorized span (stage 2's
+        # fallback set, already counted there); the others were carried
+        # across a batch boundary and are seen here for the first time
+        METRICS.inc("duplex.slow_molecules")
+        if not corrected:
+            METRICS.inc("duplex.molecules")
+        with _span("engine.duplex.slow_molecule", rusage=True):
+            if self.overlap_caller is not None and not corrected \
+                    and a_records and b_records:
+                from .overlapping import apply_overlapping_consensus
 
-            a_records = apply_overlapping_consensus(a_records,
-                                                    self.overlap_caller)
-            b_records = apply_overlapping_consensus(b_records,
-                                                    self.overlap_caller)
-        recs = self.caller.call_groups([(base_mi, a_records, b_records)])
+                a_records = apply_overlapping_consensus(a_records,
+                                                        self.overlap_caller)
+                b_records = apply_overlapping_consensus(b_records,
+                                                        self.overlap_caller)
+            recs = self.caller.call_groups([(base_mi, a_records, b_records)])
         if not recs:
             return []
         return [b"".join(len(r).to_bytes(4, "little") + r for r in recs)]
@@ -221,146 +244,159 @@ class FastDuplexCaller:
     # ------------------------------------------------------------- stage 1 + 2
 
     def _process_molecules(self, batch, idx, bounds, strand_b, g0, g1):
-        caller = self.caller
-        stats = caller.stats
-        span = idx[bounds[g0]:bounds[g1]]
-        nG = g1 - g0
-        gb = bounds[g0:g1 + 1] - bounds[g0]
-        sizes = np.diff(gb)
-        g_of_row = np.repeat(np.arange(nG), sizes)
-        sb = strand_b[bounds[g0]:bounds[g1]]
+        with _span("process.prep", rusage=True):
+            caller = self.caller
+            stats = caller.stats
+            span = idx[bounds[g0]:bounds[g1]]
+            nG = g1 - g0
+            gb = bounds[g0:g1 + 1] - bounds[g0]
+            sizes = np.diff(gb)
+            g_of_row = np.repeat(np.arange(nG), sizes)
+            sb = strand_b[bounds[g0]:bounds[g1]]
 
-        flag_s = batch.flag[span]
-        paired = (flag_s & FLAG_PAIRED) != 0
-        first = (flag_s & FLAG_FIRST) != 0
-        last = (flag_s & FLAG_LAST) != 0
+            flag_s = batch.flag[span]
+            paired = (flag_s & FLAG_PAIRED) != 0
+            first = (flag_s & FLAG_FIRST) != 0
+            last = (flag_s & FLAG_LAST) != 0
 
-        # molecule-level fallback: FIRST|LAST reads (belong to both X and Y
-        # sets) and per-strand downsampling
-        fallback = np.zeros(nG, dtype=bool)
-        fl_both = paired & first & last
-        fallback[g_of_row[fl_both]] = True
-        max_rs = self.ss.options.max_reads
-        if self.caller.track_rejects or self.ss.options.methylation_mode:
-            # methylation needs each read's CIGAR/position context for the
-            # reference annotation — the packed batch path strips it, so
-            # every molecule runs the classic per-molecule path (the same
-            # engineering choice as the simplex engine's _vector_ok gate)
-            fallback[:] = True
+            # molecule-level fallback: FIRST|LAST reads (belong to both X and Y
+            # sets) and per-strand downsampling
+            fallback = np.zeros(nG, dtype=bool)
+            fl_both = paired & first & last
+            fallback[g_of_row[fl_both]] = True
+            max_rs = self.ss.options.max_reads
+            if self.caller.track_rejects or self.ss.options.methylation_mode:
+                # methylation needs each read's CIGAR/position context for the
+                # reference annotation — the packed batch path strips it, so
+                # every molecule runs the classic per-molecule path (the same
+                # engineering choice as the simplex engine's _vector_ok gate)
+                fallback[:] = True
 
-        # per-row seg type (AB_R1..BA_R2); fragments and paired-but-neither
-        # get -1
-        t = np.full(len(span), -1, dtype=np.int8)
-        r1 = paired & first
-        r2 = paired & last & ~first
-        t[~sb & r1] = AB_R1
-        t[~sb & r2] = AB_R2
-        t[sb & r1] = BA_R1
-        t[sb & r2] = BA_R2
+            # per-row seg type (AB_R1..BA_R2); fragments and paired-but-neither
+            # get -1
+            t = np.full(len(span), -1, dtype=np.int8)
+            r1 = paired & first
+            r2 = paired & last & ~first
+            t[~sb & r1] = AB_R1
+            t[~sb & r2] = AB_R2
+            t[sb & r1] = BA_R1
+            t[sb & r2] = BA_R2
 
-        frag = ~paired
-        n_frag = np.bincount(g_of_row[frag], minlength=nG)
-        n_paired = sizes - n_frag
-        num_a_r1 = np.bincount(g_of_row[~sb & r1], minlength=nG)
-        num_b_r1 = np.bincount(g_of_row[sb & r1], minlength=nG)
-        num_xy = np.maximum(num_a_r1, num_b_r1)
-        num_yx = np.minimum(num_a_r1, num_b_r1)
-        gate_ok = (caller.min_total <= num_xy + num_yx) \
-            & (caller.min_xy <= num_xy) & (caller.min_yx <= num_yx)
+            frag = ~paired
+            n_frag = np.bincount(g_of_row[frag], minlength=nG)
+            n_paired = sizes - n_frag
+            num_a_r1 = np.bincount(g_of_row[~sb & r1], minlength=nG)
+            num_b_r1 = np.bincount(g_of_row[sb & r1], minlength=nG)
+            num_xy = np.maximum(num_a_r1, num_b_r1)
+            num_yx = np.minimum(num_a_r1, num_b_r1)
+            gate_ok = (caller.min_total <= num_xy + num_yx) \
+                & (caller.min_xy <= num_xy) & (caller.min_yx <= num_yx)
 
-        # strand-orientation validation (duplex_caller.rs:1830-1860): only for
-        # molecules with paired rows on both strands; X = AB-R1 + BA-R2 and
-        # Y = AB-R2 + BA-R1 must each be strand-uniform
-        n_pa = np.bincount(g_of_row[~sb & paired], minlength=nG)
-        n_pb = np.bincount(g_of_row[sb & paired], minlength=nG)
-        both_strands = (n_pa > 0) & (n_pb > 0)
-        rev = (flag_s & FLAG_REVERSE) != 0
-        is_x = (t == AB_R1) | (t == BA_R2)
-        is_y = (t == AB_R2) | (t == BA_R1)
-        coll = np.zeros(nG, dtype=bool)
-        for setm in (is_x, is_y):
-            gr = g_of_row[setm]
-            rv = rev[setm]
-            mn = np.full(nG, 2, dtype=np.int8)
-            mx = np.full(nG, -1, dtype=np.int8)
-            np.minimum.at(mn, gr, rv.astype(np.int8))
-            np.maximum.at(mx, gr, rv.astype(np.int8))
-            coll |= (mx - mn) > 0
-        coll &= both_strands
+            # strand-orientation validation (duplex_caller.rs:1830-1860):
+            # only for molecules with paired rows on both strands; X = AB-R1 +
+            # BA-R2 and Y = AB-R2 + BA-R1 must each be strand-uniform
+            n_pa = np.bincount(g_of_row[~sb & paired], minlength=nG)
+            n_pb = np.bincount(g_of_row[sb & paired], minlength=nG)
+            both_strands = (n_pa > 0) & (n_pb > 0)
+            rev = (flag_s & FLAG_REVERSE) != 0
+            is_x = (t == AB_R1) | (t == BA_R2)
+            is_y = (t == AB_R2) | (t == BA_R1)
+            coll = np.zeros(nG, dtype=bool)
+            for setm in (is_x, is_y):
+                gr = g_of_row[setm]
+                rv = rev[setm]
+                mn = np.full(nG, 2, dtype=np.int8)
+                mx = np.full(nG, -1, dtype=np.int8)
+                np.minimum.at(mn, gr, rv.astype(np.int8))
+                np.maximum.at(mx, gr, rv.astype(np.int8))
+                coll |= (mx - mn) > 0
+            coll &= both_strands
 
-        # native pack over all rows (clip/trim/RC/mask; fast.py discipline)
-        mc_off, mc_len, _ = batch.tag_locs_str(b"MC")
-        clips = nb.mate_clips(
-            batch.buf, np.ascontiguousarray(batch.cigar_off[span]),
-            batch.n_cigar[span], batch.flag[span], batch.ref_id[span],
-            batch.pos[span], batch.next_ref_id[span], batch.next_pos[span],
-            batch.tlen[span], np.ascontiguousarray(mc_off[span]),
-            mc_len[span])
-        stride = max(-(-int(batch.l_seq[span].max()) // 32) * 32, 32)
-        codes, quals, final_len = nb.pack_reads(
-            batch.buf, np.ascontiguousarray(batch.seq_off[span]),
-            np.ascontiguousarray(batch.qual_off[span]), batch.l_seq[span],
-            rev.astype(np.uint8), clips,
-            self.ss.options.min_input_base_quality, stride)
+            # native pack over all rows (clip/trim/RC/mask; fast.py discipline)
+            mc_off, mc_len, _ = batch.tag_locs_str(b"MC")
+            clips = nb.mate_clips(
+                batch.buf, np.ascontiguousarray(batch.cigar_off[span]),
+                batch.n_cigar[span], batch.flag[span], batch.ref_id[span],
+                batch.pos[span], batch.next_ref_id[span], batch.next_pos[span],
+                batch.tlen[span], np.ascontiguousarray(mc_off[span]),
+                mc_len[span])
+            stride = max(-(-int(batch.l_seq[span].max()) // 32) * 32, 32)
+            codes, quals, final_len = nb.pack_reads(
+                batch.buf, np.ascontiguousarray(batch.seq_off[span]),
+                np.ascontiguousarray(batch.qual_off[span]), batch.l_seq[span],
+                rev.astype(np.uint8), clips,
+                self.ss.options.min_input_base_quality, stride)
 
-        # seg construction over valid rows of live molecules (dead molecules
-        # -- failed gates/validation -- need no conversion at all)
-        live_mol = gate_ok & ~coll & (n_paired > 0) & ~fallback
-        valid = (final_len > 0) & (t >= 0) & live_mol[g_of_row]
-        er = np.nonzero(valid)[0]
-        key = g_of_row[er] * 4 + t[er]
-        order = np.argsort(key, kind="stable")
-        vrows = er[order]
-        skey = key[order]
-        seg_first = np.concatenate(([True], skey[1:] != skey[:-1])) \
-            if len(skey) else np.empty(0, dtype=bool)
-        seg_of_row = (np.cumsum(seg_first) - 1) if len(skey) else skey
-        seg_key = skey[seg_first] if len(skey) else skey
-        nseg = len(seg_key)
-        seg_g = seg_key >> 2
-        seg_t = (seg_key & 3).astype(np.int8)
-        c1 = np.bincount(seg_of_row, minlength=nseg).astype(np.int64)
-        vstarts = np.concatenate(([0], np.cumsum(c1))).astype(np.int64)
-        if max_rs is not None and nseg and (c1 > max_rs).any():
-            fallback[seg_g[c1 > max_rs]] = True
+            # seg construction over valid rows of live molecules (dead
+            # molecules -- failed gates/validation -- need no conversion)
+            live_mol = gate_ok & ~coll & (n_paired > 0) & ~fallback
+            valid = (final_len > 0) & (t >= 0) & live_mol[g_of_row]
+            er = np.nonzero(valid)[0]
+            key = g_of_row[er] * 4 + t[er]
+            order = np.argsort(key, kind="stable")
+            vrows = er[order]
+            skey = key[order]
+            seg_first = np.concatenate(([True], skey[1:] != skey[:-1])) \
+                if len(skey) else np.empty(0, dtype=bool)
+            seg_of_row = (np.cumsum(seg_first) - 1) if len(skey) else skey
+            seg_key = skey[seg_first] if len(skey) else skey
+            nseg = len(seg_key)
+            seg_g = seg_key >> 2
+            seg_t = (seg_key & 3).astype(np.int8)
+            c1 = np.bincount(seg_of_row, minlength=nseg).astype(np.int64)
+            vstarts = np.concatenate(([0], np.cumsum(c1))).astype(np.int64)
+            if max_rs is not None and nseg and (c1 > max_rs).any():
+                fallback[seg_g[c1 > max_rs]] = True
 
-        # alignment-filter analysis per X/Y set of each live molecule:
-        # uniform CIGARs over the set's valid rows, with the mixed-strand
-        # palindrome rule (fast.py _prepare_groups_vec)
-        if nseg:
-            self._need_filter_fallback(batch, span, vrows, g_of_row, t,
-                                       fallback, nG)
-        live_mol &= ~fallback
+            # alignment-filter analysis per X/Y set of each live molecule:
+            # uniform CIGARs over the set's valid rows, with the mixed-strand
+            # palindrome rule (fast.py _prepare_groups_vec)
+            if nseg:
+                self._need_filter_fallback(batch, span, vrows, g_of_row, t,
+                                           fallback, nG)
+            live_mol &= ~fallback
 
-        # rejection tallies for non-fallback molecules
-        vec = ~fallback
-        stats.input_reads += int(sizes[vec].sum())
-        n_fr = int(n_frag[vec].sum())
-        if n_fr:
-            stats.reject("FragmentRead", n_fr)
-        gate_dead = vec & ~gate_ok & (n_paired > 0)
-        if gate_dead.any():
-            stats.reject("InsufficientReads", int(n_paired[gate_dead].sum()))
-        coll_dead = vec & gate_ok & coll
-        if coll_dead.any():
-            stats.reject("PotentialCollision", int(n_paired[coll_dead].sum()))
+            # rejection tallies for non-fallback molecules
+            vec = ~fallback
+            stats.input_reads += int(sizes[vec].sum())
+            n_fr = int(n_frag[vec].sum())
+            if n_fr:
+                stats.reject("FragmentRead", n_fr)
+            gate_dead = vec & ~gate_ok & (n_paired > 0)
+            if gate_dead.any():
+                stats.reject("InsufficientReads",
+                             int(n_paired[gate_dead].sum()))
+            coll_dead = vec & gate_ok & coll
+            if coll_dead.any():
+                stats.reject("PotentialCollision",
+                             int(n_paired[coll_dead].sum()))
+            # the same tallies by molecule (run report `duplex.*`); stage 2
+            # adds what it emits and what it drops
+            METRICS.inc("duplex.molecules", nG)
+            for reason, sel in (("fragments_only", vec & (n_paired == 0)),
+                                ("insufficient_reads", gate_dead),
+                                ("collision", coll_dead)):
+                if sel.any():
+                    METRICS.inc("duplex.rejected." + reason, int(sel.sum()))
+                    METRICS.inc("duplex.rejected", int(sel.sum()))
 
-        # molecule -> seg map for live molecules
-        seg_map = np.full((nG, 4), -1, dtype=np.int64)
-        if nseg:
-            lm = live_mol[seg_g]
-            seg_map[seg_g[lm], seg_t[lm]] = np.nonzero(lm)[0]
+            # molecule -> seg map for live molecules
+            seg_map = np.full((nG, 4), -1, dtype=np.int64)
+            if nseg:
+                lm = live_mol[seg_g]
+                seg_map[seg_g[lm], seg_t[lm]] = np.nonzero(lm)[0]
 
-        # reserve this span's ordinal range NOW (stream order), so deferred
-        # stage-2 resolution cannot shift the classic fallback numbering —
-        # the simplex engine's _group_ordinal discipline (fast.py:499)
-        ord0 = caller._ordinal
-        caller._ordinal = ord0 + nG
+            # reserve this span's ordinal range NOW (stream order), so
+            # deferred stage-2 resolution cannot shift the classic fallback
+            # numbering — the simplex engine's _group_ordinal discipline
+            # (fast.py:499)
+            ord0 = caller._ordinal
+            caller._ordinal = ord0 + nG
 
-        seg_len = np.zeros(nseg, dtype=np.int64)
-        if nseg:
-            fl = final_len[vrows]
-            np.maximum.at(seg_len, seg_of_row, fl)
+            seg_len = np.zeros(nseg, dtype=np.int64)
+            if nseg:
+                fl = final_len[vrows]
+                np.maximum.at(seg_len, seg_of_row, fl)
 
         # SS consensus for every seg: one kernel dispatch for multi-read
         # segs, one vectorized host pass for single-read segs
@@ -452,36 +488,46 @@ class FastDuplexCaller:
         if not nseg:
             return tb, tq, d16, e16, np.zeros((0, L_max), dtype=np.uint8), \
                 None
-        codes2d = np.ascontiguousarray(codes[vrows])
-        quals2d = np.ascontiguousarray(quals[vrows])
 
         single = c1 == 1
-        if single.any():
-            rows = vrows[vstarts[:-1][single]]
-            b, q, d, e = oracle.single_read_consensus(
-                codes[rows], quals[rows], self.ss.tables,
-                opts.min_consensus_base_quality)
-            tb[single] = b
-            tq[single] = q
-            d16[single] = np.minimum(d, I16_MAX).astype(np.int32)
-            # errors are zero for single-read consensus
         multi = np.nonzero(~single)[0]
+        METRICS.inc("duplex.single_segments", nseg - len(multi))
+        METRICS.inc("duplex.multi_segments", len(multi))
+        if single.any():
+            with _span("engine.duplex.single", rusage=True):
+                rows = vrows[vstarts[:-1][single]]
+                b, q, d, e = oracle.single_read_consensus(
+                    codes[rows], quals[rows], self.ss.tables,
+                    opts.min_consensus_base_quality)
+                tb[single] = b
+                tq[single] = q
+                d16[single] = np.minimum(d, I16_MAX).astype(np.int32)
+                # errors are zero for single-read consensus
         if not len(multi):
+            with _span("engine.host_gather", rusage=True):
+                codes2d = np.ascontiguousarray(codes[vrows])
             return tb, tq, d16, e16, codes2d, None
-        rows_m = np.concatenate(
-            [np.arange(vstarts[s], vstarts[s + 1]) for s in multi])
-        cm = np.ascontiguousarray(codes2d[rows_m])
-        qm = np.ascontiguousarray(quals2d[rows_m])
         counts_m = c1[multi]
         starts_m = np.concatenate(([0], np.cumsum(counts_m)))
 
+        def gather():
+            """The valid rows in seg order (stage 2 recounts errors over
+            them) and, out of those, the multi-read segs' rows, dense."""
+            codes2d = np.ascontiguousarray(codes[vrows])
+            quals2d = np.ascontiguousarray(quals[vrows])
+            rows_m = np.concatenate(
+                [np.arange(vstarts[s], vstarts[s + 1]) for s in multi])
+            return (codes2d, np.ascontiguousarray(codes2d[rows_m]),
+                    np.ascontiguousarray(quals2d[rows_m]))
+
         def finish_with(w, q_, d, e, ctx):
-            b_m, q_m = oracle.apply_consensus_thresholds(
-                w, q_, d, opts.min_reads, opts.min_consensus_base_quality)
-            tb[multi] = b_m
-            tq[multi] = q_m
-            d16[multi] = np.minimum(d, I16_MAX).astype(np.int32)
-            e16[multi] = np.minimum(e, I16_MAX).astype(np.int32)
+            with _span("resolve.unpack", rusage=True):
+                b_m, q_m = oracle.apply_consensus_thresholds(
+                    w, q_, d, opts.min_reads, opts.min_consensus_base_quality)
+                tb[multi] = b_m
+                tq[multi] = q_m
+                d16[multi] = np.minimum(d, I16_MAX).astype(np.int32)
+                e16[multi] = np.minimum(e, I16_MAX).astype(np.int32)
             return tb, tq, d16, e16, codes2d, ctx
 
         route = "host"
@@ -491,13 +537,15 @@ class FastDuplexCaller:
             from ..ops.router import ROUTER
 
             route = ROUTER.decide_batch(
-                self.kernel, cm.shape[0], len(multi), L_max,
+                self.kernel, int(starts_m[-1]), len(multi), L_max,
                 devices=self.mesh.size if self.mesh is not None else 1)
         if route == "host":
             # no device, or the cost model priced this batch host-side:
             # the native f64 engine absorbs it concurrently
             from ..ops.kernel import HOST_DISPATCH
 
+            with _span("engine.host_gather", rusage=True):
+                codes2d, cm, qm = gather()
             w, q_, d, e = self.kernel.resolve_segments(HOST_DISPATCH, cm,
                                                        qm, starts_m)
             return finish_with(w, q_, d, e, None)
@@ -506,7 +554,10 @@ class FastDuplexCaller:
         if device_path() == "columns":
             # round-5 comparison route: classify + compact hard-column
             # export (FGUMI_TPU_DEVICE_PATH=columns)
-            pending = self.kernel.dispatch_hard_columns(cm, qm, starts_m)
+            with _span("engine.pack", rusage=True):
+                with _span("engine.pack.gather"):
+                    codes2d, cm, qm = gather()
+                pending = self.kernel.dispatch_hard_columns(cm, qm, starts_m)
 
             def resolve_cols():
                 w, q_, d, e = self.kernel.resolve_hard_columns(pending)
@@ -530,26 +581,30 @@ class FastDuplexCaller:
                                   "auto").strip().lower()
         full_ok = bool(counts_m.max() < 65536)
         want_res = full_ok and comb_env != "host"
-        t_pack0 = _time.monotonic()
         pred = ROUTER.last_prediction()
         res_thresholds = (opts.min_reads,
                           opts.min_consensus_base_quality) \
             if want_res else None
         mesh = self.mesh
-        if mesh is not None:
-            cg, qg, seg_g, _st, F_loc, gather = pad_segments_mesh(
-                cm, qm, counts_m, mesh)
-            ticket = self.kernel.device_call_segments_wire(
-                cg, qg, seg_g, F_loc, len(multi), pack_t0=t_pack0,
-                full=full_ok, resident_thresholds=res_thresholds,
-                pred_s=pred[0] if pred else None, mesh=mesh,
-                mesh_gather=gather)
-        else:
-            cd, qd, seg_ids, _sp, F_pad = pad_segments(cm, qm, counts_m)
+        # row copies + pad + wire build == this batch's pack: the span ends
+        # with the dispatch handed to the feeder, as the simplex engine's
+        # does, and the timeline's pack_s starts where it starts
+        with _span("engine.pack", rusage=True):
+            t_pack0 = _time.monotonic()
+            with _span("engine.pack.gather"):
+                codes2d, cm, qm = gather()
+                if mesh is not None:
+                    cd, qd, seg_ids, _st, F_pad, gather_idx = \
+                        pad_segments_mesh(cm, qm, counts_m, mesh)
+                    on_mesh = {"mesh": mesh, "mesh_gather": gather_idx}
+                else:
+                    cd, qd, seg_ids, _sp, F_pad = pad_segments(cm, qm,
+                                                               counts_m)
+                    on_mesh = {}
             ticket = self.kernel.device_call_segments_wire(
                 cd, qd, seg_ids, F_pad, len(multi), pack_t0=t_pack0,
                 full=full_ok, resident_thresholds=res_thresholds,
-                pred_s=pred[0] if pred else None)
+                pred_s=pred[0] if pred else None, **on_mesh)
 
         def resolve_wire():
             w, q_, d, e, extras = self.kernel.resolve_segments_wire(
@@ -584,99 +639,111 @@ class FastDuplexCaller:
         stats = caller.stats
         nG = len(sizes)
 
-        p = seg_map >= 0
-        full = p.all(axis=1) & live_mol
-        ab_only = p[:, AB_R1] & p[:, AB_R2] & ~p[:, BA_R1] & ~p[:, BA_R2] \
-            & live_mol & (caller.min_yx == 0)
-        ba_only = ~p[:, AB_R1] & ~p[:, AB_R2] & p[:, BA_R1] & p[:, BA_R2] \
-            & live_mol & (caller.min_yx == 0)
+        with _span("engine.duplex.classify", rusage=True):
+            p = seg_map >= 0
+            full = p.all(axis=1) & live_mol
+            ab_only = p[:, AB_R1] & p[:, AB_R2] & ~p[:, BA_R1] & ~p[:, BA_R2] \
+                & live_mol & (caller.min_yx == 0)
+            ba_only = ~p[:, AB_R1] & ~p[:, AB_R2] & p[:, BA_R1] & p[:, BA_R2] \
+                & live_mol & (caller.min_yx == 0)
 
-        # per-seg aliveness: any positive depth within a length limit.
-        # One vector pass finds each seg's first positive-depth column;
-        # the per-output-read check (lengths differ per pairing) is then a
-        # scalar compare instead of a numpy any() per molecule
-        pos_depth = d16 > 0
-        has_depth = pos_depth.any(axis=1)
-        first_nz = np.where(has_depth, np.argmax(pos_depth, axis=1), 1 << 30)
+            # per-seg aliveness: any positive depth within a length limit.
+            # One vector pass finds each seg's first positive-depth column;
+            # the per-output-read check (lengths differ per pairing) is then a
+            # scalar compare instead of a numpy any() per molecule
+            pos_depth = d16 > 0
+            has_depth = pos_depth.any(axis=1)
+            first_nz = np.where(has_depth, np.argmax(pos_depth, axis=1),
+                                1 << 30)
 
-        def seg_alive(s, limit):
-            return first_nz[s] < limit
+            def seg_alive(s, limit):
+                return first_nz[s] < limit
 
-        # build output reads in molecule order: 2 per emitted molecule
-        out_specs = []   # (mol, flags, aseg, bseg, kind) kind: 2=combined,
-        #                   1=a-passthrough, 0=b-passthrough(is_ba_only)
-        emitted = np.zeros(nG, dtype=bool)
-        col = np.arange(L_max)
+            # build output reads in molecule order: 2 per emitted molecule
+            out_specs = []   # (mol, flags, aseg, bseg, kind) kind: 2=combined,
+            #                   1=a-passthrough, 0=b-passthrough(is_ba_only)
+            emitted = np.zeros(nG, dtype=bool)
+            col = np.arange(L_max)
 
-        def classify(mol, a_s, b_s):
-            """One output read's effective sides; None = dead molecule."""
-            La, Lb = int(seg_len[a_s]) if a_s >= 0 else 0, \
-                int(seg_len[b_s]) if b_s >= 0 else 0
-            if a_s >= 0 and b_s >= 0:
-                length = min(La, Lb)
-                aa = seg_alive(a_s, length)
-                ba = seg_alive(b_s, length)
-                if aa and ba:
-                    return (2, a_s, b_s, length)
-                if aa:
-                    return (1, a_s, -1, La)
-                if ba:
-                    return (0, b_s, -1, Lb)
+            def classify(mol, a_s, b_s):
+                """One output read's effective sides; None = dead molecule."""
+                La, Lb = int(seg_len[a_s]) if a_s >= 0 else 0, \
+                    int(seg_len[b_s]) if b_s >= 0 else 0
+                if a_s >= 0 and b_s >= 0:
+                    length = min(La, Lb)
+                    aa = seg_alive(a_s, length)
+                    ba = seg_alive(b_s, length)
+                    if aa and ba:
+                        return (2, a_s, b_s, length)
+                    if aa:
+                        return (1, a_s, -1, La)
+                    if ba:
+                        return (0, b_s, -1, Lb)
+                    return None
+                if a_s >= 0:
+                    return (1, a_s, -1, La) if seg_alive(a_s, La) else None
+                if b_s >= 0:
+                    return (0, b_s, -1, Lb) if seg_alive(b_s, Lb) else None
                 return None
-            if a_s >= 0:
-                return (1, a_s, -1, La) if seg_alive(a_s, La) else None
-            if b_s >= 0:
-                return (0, b_s, -1, Lb) if seg_alive(b_s, Lb) else None
-            return None
 
-        for g in np.nonzero(full | ab_only | ba_only)[0]:
-            # rx1/rx2: the AB and BA segs contributing RX values per output
-            # read — the reference folds in raws of BOTH segs even when one
-            # strand's consensus is depth-dead (duplex.py:421-434 iterates
-            # raws_a + raws_b of the branch taken)
-            if full[g]:
-                spec1 = classify(g, seg_map[g, AB_R1], seg_map[g, BA_R2])
-                spec2 = classify(g, seg_map[g, AB_R2], seg_map[g, BA_R1])
-                rx1 = (seg_map[g, AB_R1], seg_map[g, BA_R2])
-                rx2 = (seg_map[g, AB_R2], seg_map[g, BA_R1])
-                if spec1 is None or spec2 is None:
-                    continue
-                # _has_min_reads on both output reads (duplex.py:304-308)
-                okmin = True
-                for spec in (spec1, spec2):
-                    kind, s1, s2, length = spec
-                    na = int(d16[s1, :length].max()) if length else 0
-                    nb_ = int(d16[s2, :length].max()) if kind == 2 and length \
-                        else 0
-                    xy, yx = max(na, nb_), min(na, nb_)
-                    if not (caller.min_total <= xy + yx
-                            and caller.min_xy <= xy and caller.min_yx <= yx):
-                        okmin = False
-                if not okmin:
-                    continue
-            elif ab_only[g]:
-                spec1 = classify(g, seg_map[g, AB_R1], -1)
-                spec2 = classify(g, seg_map[g, AB_R2], -1)
-                rx1 = (seg_map[g, AB_R1], -1)
-                rx2 = (seg_map[g, AB_R2], -1)
-                if spec1 is None or spec2 is None:
-                    continue
-            else:
-                spec1 = classify(g, -1, seg_map[g, BA_R2])
-                spec2 = classify(g, -1, seg_map[g, BA_R1])
-                rx1 = (-1, seg_map[g, BA_R2])
-                rx2 = (-1, seg_map[g, BA_R1])
-                if spec1 is None or spec2 is None:
-                    continue
-            emitted[g] = True
-            out_specs.append((g, _TYPE_FLAGS[R1]) + spec1 + rx1)
-            out_specs.append((g, _TYPE_FLAGS[R2]) + spec2 + rx2)
+            for g in np.nonzero(full | ab_only | ba_only)[0]:
+                # rx1/rx2: the AB and BA segs contributing RX values per
+                # output read — the reference folds in raws of BOTH segs even
+                # when one strand's consensus is depth-dead (duplex.py:421-434
+                # iterates raws_a + raws_b of the branch taken)
+                if full[g]:
+                    spec1 = classify(g, seg_map[g, AB_R1], seg_map[g, BA_R2])
+                    spec2 = classify(g, seg_map[g, AB_R2], seg_map[g, BA_R1])
+                    rx1 = (seg_map[g, AB_R1], seg_map[g, BA_R2])
+                    rx2 = (seg_map[g, AB_R2], seg_map[g, BA_R1])
+                    if spec1 is None or spec2 is None:
+                        continue
+                    # _has_min_reads on both output reads (duplex.py:304-308)
+                    okmin = True
+                    for spec in (spec1, spec2):
+                        kind, s1, s2, length = spec
+                        na = int(d16[s1, :length].max()) if length else 0
+                        nb_ = int(d16[s2, :length].max()) \
+                            if kind == 2 and length else 0
+                        xy, yx = max(na, nb_), min(na, nb_)
+                        if not (caller.min_total <= xy + yx
+                                and caller.min_xy <= xy
+                                and caller.min_yx <= yx):
+                            okmin = False
+                    if not okmin:
+                        continue
+                elif ab_only[g]:
+                    spec1 = classify(g, seg_map[g, AB_R1], -1)
+                    spec2 = classify(g, seg_map[g, AB_R2], -1)
+                    rx1 = (seg_map[g, AB_R1], -1)
+                    rx2 = (seg_map[g, AB_R2], -1)
+                    if spec1 is None or spec2 is None:
+                        continue
+                else:
+                    spec1 = classify(g, -1, seg_map[g, BA_R2])
+                    spec2 = classify(g, -1, seg_map[g, BA_R1])
+                    rx1 = (-1, seg_map[g, BA_R2])
+                    rx2 = (-1, seg_map[g, BA_R1])
+                    if spec1 is None or spec2 is None:
+                        continue
+                emitted[g] = True
+                out_specs.append((g, _TYPE_FLAGS[R1]) + spec1 + rx1)
+                out_specs.append((g, _TYPE_FLAGS[R2]) + spec2 + rx2)
 
-        # InsufficientReads for live-but-unemitted molecules (the fallthrough
-        # reject in _combine_molecule, duplex.py:361-363)
-        dead = live_mol & ~emitted
-        if dead.any():
-            stats.reject("InsufficientReads", int(n_paired[dead].sum()))
+            # InsufficientReads for live-but-unemitted molecules (the
+            # fallthrough reject in _combine_molecule, duplex.py:361-363)
+            dead = live_mol & ~emitted
+            if dead.any():
+                stats.reject("InsufficientReads", int(n_paired[dead].sum()))
+
+            # what became of this span's molecules (run report `duplex.*`;
+            # the fallback set is counted where the slow caller takes it)
+            for name, sel in (("full", full), ("ab_only", ab_only),
+                              ("ba_only", ba_only)):
+                METRICS.inc("duplex." + name, int((emitted & sel).sum()))
+            if dead.any():
+                METRICS.inc("duplex.rejected.no_consensus", int(dead.sum()))
+                METRICS.inc("duplex.rejected", int(dead.sum()))
 
         K = len(out_specs)
         chunks = []
@@ -731,6 +798,54 @@ class FastDuplexCaller:
         the host combine: the resident arrays are pre-patch."""
         caller = self.caller
         K = len(out_specs)
+        with _span("engine.duplex.combine", rusage=True) as sp:
+            mols, flags, kinds, aseg, bseg, lens, out_b, out_q, out_e = \
+                self._combine_outputs(out_specs, tb, tq, e16, codes2d,
+                                      vstarts, L_max, col, combine_ctx, sp)
+
+        # serializer strand inputs: 'a' side = dup.ab_consensus (the alive /
+        # AB side, truncated to the combined length), 'b' side =
+        # ba_consensus (combined case only)
+        a_rows = aseg
+        a_len = lens.astype(np.int32)
+        b_present = (kinds == 2).astype(np.uint8)
+        b_rows = np.where(kinds == 2, bseg, 0)
+        b_len = np.where(kinds == 2, lens, 0).astype(np.int32)
+
+        def row_addrs(arr, rows):
+            return arr.ctypes.data + rows * arr.shape[1] * arr.itemsize
+
+        # RX per output read (strand-reoriented consensus, duplex.py:421-434)
+        with _span("engine.duplex.rx", rusage=True):
+            rx_addr, rx_len, keep_alive = self._output_rx(
+                batch, span, out_specs, seg_map, vrows, vstarts)
+
+        with _span("resolve.serialize", rusage=True):
+            mi_off, mi_len, _ = batch.tag_locs(self.tag)
+            first_rows = span[gb[mols]]
+            mi_addr = batch.buf.ctypes.data + mi_off[first_rows]
+            # base MI, no /A|/B
+            mi_l = (mi_len[first_rows] - 2).astype(np.int32)
+
+            blob, rec_end = nb.build_duplex_records(
+                row_addrs(out_b, np.arange(K)), row_addrs(out_q, np.arange(K)),
+                row_addrs(out_e, np.arange(K)), lens, flags,
+                caller.prefix.encode(), mi_addr, mi_l,
+                row_addrs(tb, a_rows), row_addrs(tq, a_rows),
+                row_addrs(d16, a_rows), row_addrs(e16, a_rows), a_len,
+                row_addrs(tb, b_rows), row_addrs(tq, b_rows),
+                row_addrs(d16, b_rows), row_addrs(e16, b_rows), b_len,
+                b_present, rx_addr, rx_len, caller.read_group_id.encode(),
+                caller.produce_per_base_tags)
+        del keep_alive
+        return blob, rec_end
+
+    def _combine_outputs(self, out_specs, tb, tq, e16, codes2d, vstarts,
+                         L_max, col, combine_ctx, sp):
+        """The K output reads' arrays: the specs as columns, and the reads'
+        bases, quals and errors, strand-combined or passed through. ``sp``
+        is the ``engine.duplex.combine`` span this runs in."""
+        K = len(out_specs)
         mols = np.array([s[0] for s in out_specs], dtype=np.int64)
         flags = np.array([s[1] for s in out_specs], dtype=np.int32)
         kinds = np.array([s[2] for s in out_specs], dtype=np.int8)
@@ -784,6 +899,7 @@ class FastDuplexCaller:
             out_e[sel] = np.minimum(errs, I16_MAX)
 
         done_rows = np.empty(0, dtype=np.int64)
+        side = "host"
         if len(comb) and combine_ctx is not None:
             s2m = combine_ctx["seg_to_multi"]
             ma = s2m[aseg[comb]]
@@ -795,8 +911,10 @@ class FastDuplexCaller:
                 # whole output row to the host combine (resident arrays
                 # are pre-patch; conservative over the full row width)
                 sus_row = sus.any(axis=1)
-                eligible &= ~(sus_row[np.maximum(ma, 0)]
-                              | sus_row[np.maximum(mb, 0)])
+                touched = eligible & (sus_row[np.maximum(ma, 0)]
+                                      | sus_row[np.maximum(mb, 0)])
+                METRICS.inc("duplex.suspect_rows", int(touched.sum()))
+                eligible &= ~touched
             cand = comb[eligible]
             if len(cand):
                 from ..ops.kernel import duplex_combine_device
@@ -821,11 +939,15 @@ class FastDuplexCaller:
                     out_q[cand] = oq
                     out_e[cand] = oe
 
-                run_adaptive_stage(DUPLEX_COMBINE, len(cand) * L_max,
-                                   combine_ctx.get("override", "auto"),
-                                   _device_combine,
-                                   lambda: combine_host(cand))
+                _, side = run_adaptive_stage(
+                    DUPLEX_COMBINE, len(cand) * L_max,
+                    combine_ctx.get("override", "auto"),
+                    _device_combine, lambda: combine_host(cand))
                 done_rows = cand
+        on_device = len(done_rows) if side == "device" else 0
+        sp.set(side=side)
+        METRICS.inc("duplex.combine_rows_device", on_device)
+        METRICS.inc("duplex.combine_rows_host", len(comb) - on_device)
         rest = np.setdiff1d(comb, done_rows)
         if len(rest):
             # suspect-touched / single-seg / no-resident rows: always the
@@ -844,40 +966,7 @@ class FastDuplexCaller:
             out_b[k, :L] = tb[s, :L]
             out_q[k, :L] = tq[s, :L]
             out_e[k, :L] = e16[s, :L]
-
-        # serializer strand inputs: 'a' side = dup.ab_consensus (the alive /
-        # AB side, truncated to the combined length), 'b' side =
-        # ba_consensus (combined case only)
-        a_rows = aseg
-        a_len = lens.astype(np.int32)
-        b_present = (kinds == 2).astype(np.uint8)
-        b_rows = np.where(kinds == 2, bseg, 0)
-        b_len = np.where(kinds == 2, lens, 0).astype(np.int32)
-
-        def row_addrs(arr, rows):
-            return arr.ctypes.data + rows * arr.shape[1] * arr.itemsize
-
-        # RX per output read (strand-reoriented consensus, duplex.py:421-434)
-        rx_addr, rx_len, keep_alive = self._output_rx(
-            batch, span, out_specs, seg_map, vrows, vstarts)
-
-        mi_off, mi_len, _ = batch.tag_locs(self.tag)
-        first_rows = span[gb[mols]]
-        mi_addr = batch.buf.ctypes.data + mi_off[first_rows]
-        mi_l = (mi_len[first_rows] - 2).astype(np.int32)  # base MI, no /A|/B
-
-        blob, rec_end = nb.build_duplex_records(
-            row_addrs(out_b, np.arange(K)), row_addrs(out_q, np.arange(K)),
-            row_addrs(out_e, np.arange(K)), lens, flags,
-            caller.prefix.encode(), mi_addr, mi_l,
-            row_addrs(tb, a_rows), row_addrs(tq, a_rows),
-            row_addrs(d16, a_rows), row_addrs(e16, a_rows), a_len,
-            row_addrs(tb, b_rows), row_addrs(tq, b_rows),
-            row_addrs(d16, b_rows), row_addrs(e16, b_rows), b_len, b_present,
-            rx_addr, rx_len, caller.read_group_id.encode(),
-            caller.produce_per_base_tags)
-        del keep_alive
-        return blob, rec_end
+        return mols, flags, kinds, aseg, bseg, lens, out_b, out_q, out_e
 
     def _output_rx(self, batch, span, out_specs, seg_map, vrows, vstarts):
         """RX tag per output read: a-side values verbatim, b-side values

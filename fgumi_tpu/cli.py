@@ -840,11 +840,6 @@ def cmd_duplex(args):
                               getattr(args, "mesh", None))
         fast = FastDuplexCaller(caller, b"MI", overlap_caller=oc_caller,
                                 mesh=mesh)
-        # inline mode: resolve_chunk runs on this same thread in FIFO order,
-        # so the SS device round trip can defer into the double-buffer
-        # window (threaded modes run resolve on another thread and stage-2
-        # mutates shared stats/ordinals — keep those synchronous)
-        fast.defer_device = args.threads <= 1
         progress = ProgressTracker("duplex")
         with BamBatchReader(args.input,
                             target_bytes=args.batch_bytes) as reader:

@@ -375,7 +375,7 @@ class DuplexConsensusCaller(RejectTracking):
                         self._build_record(dr1, R1, base_mi, raws["ab_r1"], raws["ba_r2"]),
                         self._build_record(dr2, R2, base_mi, raws["ab_r2"], raws["ba_r1"]),
                     ]
-                    self.stats.consensus_reads += 2
+                    self.stats.add_consensus_reads(2)
                     return recs
                 self.stats.reject("InsufficientReads", mol["n_records"])
                 self._reject_records(mol.get("all_records", ()))
@@ -390,7 +390,7 @@ class DuplexConsensusCaller(RejectTracking):
                         self._build_record(dr1, R1, base_mi, raws["ab_r1"], []),
                         self._build_record(dr2, R2, base_mi, raws["ab_r2"], []),
                     ]
-                    self.stats.consensus_reads += 2
+                    self.stats.add_consensus_reads(2)
                     return recs
         elif ab_r1 is None and ab_r2 is None and ba_r1 is not None \
                 and ba_r2 is not None:
@@ -403,7 +403,7 @@ class DuplexConsensusCaller(RejectTracking):
                         self._build_record(dr1, R1, base_mi, [], raws["ba_r2"]),
                         self._build_record(dr2, R2, base_mi, [], raws["ba_r1"]),
                     ]
-                    self.stats.consensus_reads += 2
+                    self.stats.add_consensus_reads(2)
                     return recs
         self.stats.reject("InsufficientReads", mol["n_records"])
         self._reject_records(mol.get("all_records", ()))

@@ -14,6 +14,8 @@ downsampling, most-common-alignment filtering) fall back to the slow caller
 per molecule, in stream order.
 """
 
+import threading
+
 import numpy as np
 
 from ..constants import MAX_PHRED, MIN_PHRED, N_CODE
@@ -38,17 +40,34 @@ def _flip_umi(value: str) -> str:
 
 
 class _DuplexPending:
-    """Deferred half of a duplex batch: the SS device fetch + stage-2
-    combine + serialization run at resolve time (pipeline.resolve_chunk),
-    after the NEXT batch's dispatch is in flight."""
+    """One span of a duplex batch between its dispatch and its bytes.
 
-    __slots__ = ("_fn",)
+    process_batch returns it as soon as the batch is packed and handed to
+    the feeder (or kept for the host engine); resolve() does the rest on
+    whichever thread run_stages resolves on: the SS fetch and unpack, the
+    thresholds, stage 2 and the serialization. Stage 2 is a function of
+    its batch: ordinals were reserved and the fallback molecules called at
+    process time, and its tallies go through CallerStats' locked methods.
+    A chunk dropped unresolved (a failed run) hands its dispatch back, so
+    the feeder slot and the resident-byte accounting are not leaked."""
 
-    def __init__(self, fn):
-        self._fn = fn
+    __slots__ = ("_finish", "_discard", "_made_on")
+
+    def __init__(self, finish, discard=None):
+        self._finish = finish
+        self._discard = discard  # None: nothing in flight on the device
+        self._made_on = threading.get_ident()
 
     def resolve(self) -> bytes:
-        return self._fn()
+        finish, self._finish, self._discard = self._finish, None, None
+        METRICS.inc("duplex.stage2_batches")
+        if threading.get_ident() != self._made_on:
+            METRICS.inc("duplex.stage2_off_thread")
+        return finish()
+
+    def __del__(self):
+        if self._discard is not None:
+            self._discard()
 
 
 class FastDuplexCaller:
@@ -76,21 +95,16 @@ class FastDuplexCaller:
         # (ops/router.py; FGUMI_TPU_ROUTE / FGUMI_TPU_MAX_INFLIGHT handled
         # inside ROUTER.decide)
         self._carry = None  # (base_mi, [RawRecord] a, [RawRecord] b)
-        # With threads<=1 the CLI sets this True: the SS device round trip is
-        # then deferred into a pending chunk resolved AFTER the next batch's
-        # dispatch (pipeline.run_stages double buffering), hiding the fetch
-        # behind host prep. Ordinals are pre-reserved at process time so MI
-        # numbering is identical either way. Must stay False when resolve_fn
-        # runs on another thread: stage-2 mutates shared stats/ordinals.
-        self.defer_device = False
 
     # ------------------------------------------------------------------ driver
 
     def process_batch(self, batch, allow_unmapped: bool = False,
                       final: bool = False):
-        """Consume one RecordBatch -> list of wire chunks (block_size-prefixed
-        record runs). The molecule spanning the batch boundary is carried as
-        RawRecords and processed via the slow path when it completes."""
+        """Consume one RecordBatch -> list of output items for
+        fast.resolve_chunk: wire bytes (block_size-prefixed record runs) of
+        a carried molecule, and the span's pending chunk. The molecule
+        spanning the batch boundary is carried as RawRecords and processed
+        via the slow path when it completes."""
         with _span("process.decode", rusage=True):
             flag = batch.flag
             keep = (flag & (FLAG_SECONDARY | FLAG_SUPPLEMENTARY)) == 0
@@ -386,12 +400,10 @@ class FastDuplexCaller:
                 lm = live_mol[seg_g]
                 seg_map[seg_g[lm], seg_t[lm]] = np.nonzero(lm)[0]
 
-            # reserve this span's ordinal range NOW (stream order), so
-            # deferred stage-2 resolution cannot shift the classic fallback
-            # numbering — the simplex engine's _group_ordinal discipline
-            # (fast.py:499)
+            # this span's ordinal range, one a molecule in stream order (the
+            # simplex engine's _group_ordinal discipline): the fallback
+            # calls below number themselves from it
             ord0 = caller._ordinal
-            caller._ordinal = ord0 + nG
 
             seg_len = np.zeros(nseg, dtype=np.int64)
             if nseg:
@@ -401,23 +413,36 @@ class FastDuplexCaller:
         # SS consensus for every seg: one kernel dispatch for multi-read
         # segs, one vectorized host pass for single-read segs
         L_max = stride
-        ss_res = self._ss_consensus(codes, quals, vrows, c1, vstarts, nseg,
-                                    L_max, defer=self.defer_device)
-        if len(ss_res) == 2 and ss_res[0] == "defer":
-            finish_ss = ss_res[1]
+        finish_ss, discard = self._ss_consensus(codes, quals, vrows, c1,
+                                                vstarts, nseg, L_max)
+        slow = {}  # fallback molecule -> its wire bytes, filled below
 
-            def _finish():
-                tb, tq, d16, e16, codes2d, ctx = finish_ss()
-                return b"".join(self._stage2(
-                    batch, span, gb, sizes, n_paired, fallback, sb,
-                    live_mol, seg_map, seg_len, tb, tq, d16, e16,
-                    codes2d, vrows, vstarts, L_max, ord0, ctx))
+        def finish():
+            tb, tq, d16, e16, codes2d, ctx = finish_ss()
+            try:
+                return self._stage2(
+                    batch, span, gb, sizes, n_paired, slow, live_mol,
+                    seg_map, seg_len, tb, tq, d16, e16, codes2d, vrows,
+                    vstarts, L_max, ctx)
+            finally:
+                if ctx is not None:
+                    ctx["resident"].release()
 
-            return [_DuplexPending(_finish)]
-        tb, tq, d16, e16, codes2d, ctx = ss_res
-        return self._stage2(batch, span, gb, sizes, n_paired, fallback, sb,
-                            live_mol, seg_map, seg_len, tb, tq, d16, e16,
-                            codes2d, vrows, vstarts, L_max, ord0, ctx)
+        chunk = _DuplexPending(finish, discard)
+        # the per-molecule caller carries stats, the ordinal and the slow
+        # path's kernel calls, none of it safe on two threads: the fallback
+        # set (final before the dispatch) goes through it here, in stream
+        # order, while the device works on the rest
+        for g in np.nonzero(fallback)[0].tolist():
+            rows = span[gb[g]:gb[g + 1]]
+            sb_g = sb[gb[g]:gb[g + 1]]
+            caller._ordinal = ord0 + g
+            slow[g] = b"".join(self._call_slow_molecule(
+                self._base_mi(batch, int(rows[0])),
+                batch.raw_records(rows[~sb_g]), batch.raw_records(rows[sb_g]),
+                corrected=True))
+        caller._ordinal = ord0 + nG
+        return [chunk]
 
     def _need_filter_fallback(self, batch, span, vrows, g_of_row, t, fallback,
                               nG):
@@ -469,25 +494,26 @@ class FastDuplexCaller:
                 need[s] = True
         fallback[set_g[need]] = True
 
-    def _ss_consensus(self, codes, quals, vrows, c1, vstarts, nseg, L_max,
-                      defer=False):
-        """All segs' single-strand consensus: thresholded bases/quals and
-        i16-clamped depth/error arrays, (nseg, L_max) each, plus the fused
-        strand-combine context (None unless the full-column device route
-        kept stage-1 outputs resident).
+    def _ss_consensus(self, codes, quals, vrows, c1, vstarts, nseg, L_max):
+        """Start all segs' single-strand consensus: the single-read host
+        pass, the row gather, and the multi-read segs' dispatch (handed to
+        the feeder, or kept for the native host engine when there is no
+        device or the router prices the batch host-side).
 
-        defer=True + a device route: returns ("defer", finish) right after
-        the dispatch; finish() -> the 6-tuple. Every other path stays
-        synchronous (host compute has nothing to overlap; the sharded path
-        fetches per shard)."""
+        Returns (finish, discard). finish() completes it on the resolving
+        thread: thresholded bases/quals and i16-clamped depth/error arrays,
+        (nseg, L_max) each, the valid rows' codes, and the fused
+        strand-combine context (None unless the full-column device route
+        kept stage-1 outputs resident). discard() hands a wire dispatch
+        back when finish() will never run; None when there is none."""
         opts = self.ss.options
         tb = np.zeros((nseg, L_max), dtype=np.uint8)
         tq = np.zeros((nseg, L_max), dtype=np.uint8)
         d16 = np.zeros((nseg, L_max), dtype=np.int32)
         e16 = np.zeros((nseg, L_max), dtype=np.int32)
         if not nseg:
-            return tb, tq, d16, e16, np.zeros((0, L_max), dtype=np.uint8), \
-                None
+            codes2d = np.zeros((0, L_max), dtype=np.uint8)
+            return (lambda: (tb, tq, d16, e16, codes2d, None)), None
 
         single = c1 == 1
         multi = np.nonzero(~single)[0]
@@ -506,7 +532,7 @@ class FastDuplexCaller:
         if not len(multi):
             with _span("engine.host_gather", rusage=True):
                 codes2d = np.ascontiguousarray(codes[vrows])
-            return tb, tq, d16, e16, codes2d, None
+            return (lambda: (tb, tq, d16, e16, codes2d, None)), None
         counts_m = c1[multi]
         starts_m = np.concatenate(([0], np.cumsum(counts_m)))
 
@@ -541,14 +567,17 @@ class FastDuplexCaller:
                 devices=self.mesh.size if self.mesh is not None else 1)
         if route == "host":
             # no device, or the cost model priced this batch host-side:
-            # the native f64 engine absorbs it concurrently
+            # the native f64 engine computes it at resolve time
             from ..ops.kernel import HOST_DISPATCH
 
             with _span("engine.host_gather", rusage=True):
                 codes2d, cm, qm = gather()
-            w, q_, d, e = self.kernel.resolve_segments(HOST_DISPATCH, cm,
-                                                       qm, starts_m)
-            return finish_with(w, q_, d, e, None)
+
+            def resolve_host():
+                return finish_with(*self.kernel.resolve_segments(
+                    HOST_DISPATCH, cm, qm, starts_m), None)
+
+            return resolve_host, None
         from ..ops.kernel import device_path
 
         if device_path() == "columns":
@@ -560,10 +589,10 @@ class FastDuplexCaller:
                 pending = self.kernel.dispatch_hard_columns(cm, qm, starts_m)
 
             def resolve_cols():
-                w, q_, d, e = self.kernel.resolve_hard_columns(pending)
-                return finish_with(w, q_, d, e, None)
+                return finish_with(
+                    *self.kernel.resolve_hard_columns(pending), None)
 
-            return ("defer", resolve_cols) if defer else resolve_cols()
+            return resolve_cols, None
         # full-column wire route (round-6 default): the whole multi-seg
         # pileup crosses the link once; with the resident variant the
         # thresholded outputs stay on device for the fused strand combine.
@@ -622,19 +651,38 @@ class FastDuplexCaller:
                        "gather": extras.get("gather")}
             return finish_with(w, q_, d, e, ctx)
 
-        return ("defer", resolve_wire) if defer else resolve_wire()
+        def discard_wire():
+            # nobody will fetch this dispatch: hand it back as a resolver
+            # that ran out of time does, without waiting for the device
+            from ..ops.kernel import (DEVICE_FEEDER, DEVICE_STATS,
+                                      ResidentHandles)
+
+            DEVICE_STATS.end_in_flight(ticket.slot, 0, 0.0)
+            DEVICE_FEEDER.abandon(ticket)
+            try:
+                dev = ticket.wait(0)
+            except Exception:  # noqa: BLE001 - nothing of it to release
+                # still queued or running (the feeder discards it when it
+                # ends), or the dispatch itself failed
+                return
+            if isinstance(dev[-1], ResidentHandles):
+                dev[-1].release()
+
+        return resolve_wire, discard_wire
 
     # ---------------------------------------------------------------- stage 2
 
-    def _stage2(self, batch, span, gb, sizes, n_paired, fallback, sb,
-                live_mol, seg_map, seg_len, tb, tq, d16, e16, codes2d,
-                vrows, vstarts, L_max, ord0, combine_ctx=None):
+    def _stage2(self, batch, span, gb, sizes, n_paired, slow, live_mol,
+                seg_map, seg_len, tb, tq, d16, e16, codes2d, vrows, vstarts,
+                L_max, combine_ctx=None) -> bytes:
         """Strand combination + serialization, molecule order preserved.
 
-        ord0: the first ordinal of this span's pre-reserved range (set in
-        _process_molecules before any deferral) — the global counter may
-        already be past ord0 + nG when resolution is deferred, so it is
-        save/restored around the classic fallback calls, never rewound."""
+        Runs on whichever thread resolves the span's chunk, several spans
+        at once on a resolve pool: everything it writes is its own batch's,
+        but the tallies (CallerStats' locked methods, METRICS) and the
+        locked DUPLEX_COMBINE chooser. ``slow``: the wire bytes of the
+        span's fallback molecules by molecule index, called at process
+        time; they are only interleaved here."""
         caller = self.caller
         stats = caller.stats
         nG = len(sizes)
@@ -746,44 +794,28 @@ class FastDuplexCaller:
                 METRICS.inc("duplex.rejected", int(dead.sum()))
 
         K = len(out_specs)
-        chunks = []
         fast_blob = b""
         rec_end = np.zeros(0, dtype=np.int64)
         if K:
             fast_blob, rec_end = self._serialize_outputs(
                 batch, span, gb, out_specs, seg_map, seg_len, tb, tq, d16,
                 e16, codes2d, vrows, vstarts, L_max, col, combine_ctx)
-            stats.consensus_reads += K
-        elif combine_ctx is not None:
-            # nothing to combine this span: drop the resident accounting
-            combine_ctx["resident"].release()
-
-        # assemble in molecule order, interleaving fallback molecules
-        fb_set = set(np.nonzero(fallback)[0].tolist())
-        if not fb_set:
-            return [fast_blob] if fast_blob else []
+            stats.add_consensus_reads(K)
+        if not slow:
+            return fast_blob
+        # molecule order: each fallback molecule's bytes go in after the
+        # fast records of the molecules before it
+        parts = []
         out_i = 0
-        pending_fast_start = 0
-        saved_ordinal = caller._ordinal
-        for g in sorted(fb_set):
-            # flush the fast run before this molecule
-            while out_i < len(out_specs) and out_specs[out_i][0] < g:
+        start = 0
+        for g, blob in slow.items():  # ascending by construction
+            while out_i < K and out_specs[out_i][0] < g:
                 out_i += 2
-            run_end = int(rec_end[out_i - 1]) if out_i else 0
-            if run_end > pending_fast_start:
-                chunks.append(fast_blob[pending_fast_start:run_end])
-                pending_fast_start = run_end
-            rows = span[gb[g]:gb[g + 1]]
-            sb_g = sb[gb[g]:gb[g + 1]]
-            a = batch.raw_records(rows[~sb_g])
-            b = batch.raw_records(rows[sb_g])
-            caller._ordinal = ord0 + g
-            chunks.extend(self._call_slow_molecule(
-                self._base_mi(batch, int(rows[0])), a, b, corrected=True))
-        caller._ordinal = saved_ordinal
-        if len(fast_blob) > pending_fast_start:
-            chunks.append(fast_blob[pending_fast_start:])
-        return chunks
+            end = int(rec_end[out_i - 1]) if out_i else 0
+            parts += (fast_blob[start:end], blob)
+            start = end
+        parts.append(fast_blob[start:])
+        return b"".join(parts)
 
     def _serialize_outputs(self, batch, span, gb, out_specs, seg_map, seg_len,
                            tb, tq, d16, e16, codes2d, vrows, vstarts, L_max,

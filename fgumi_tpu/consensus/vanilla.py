@@ -66,9 +66,10 @@ class VanillaOptions:
 class CallerStats:
     """Aggregate statistics (ConsensusCallingStats analog).
 
-    `add_consensus_reads` takes the lock because that counter is bumped from
-    whichever thread resolves a deferred batch (the pipeline's writer stage)
-    while input_reads/rejected stay on the processing thread.
+    `add_consensus_reads` and `reject` take the lock because whichever
+    thread resolves a deferred batch (a resolve worker, the writer stage)
+    bumps them while the processing thread does too; input_reads stays on
+    the processing thread.
     """
 
     input_reads: int = 0
@@ -81,7 +82,8 @@ class CallerStats:
             self.consensus_reads += count
 
     def reject(self, reason: str, count: int):
-        self.rejected[reason] = self.rejected.get(reason, 0) + count
+        with self.lock:
+            self.rejected[reason] = self.rejected.get(reason, 0) + count
 
     def merge(self, other: "CallerStats"):
         self.input_reads += other.input_reads

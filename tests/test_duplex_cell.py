@@ -60,7 +60,8 @@ COUNTERS = (
     "duplex.molecules", "duplex.full", "duplex.ab_only", "duplex.ba_only",
     "duplex.slow_molecules", "duplex.single_segments",
     "duplex.multi_segments", "duplex.combine_rows_device",
-    "duplex.combine_rows_host")
+    "duplex.combine_rows_host", "duplex.stage2_batches",
+    "duplex.stage2_off_thread")
 
 
 @functools.lru_cache(maxsize=None)
@@ -153,7 +154,17 @@ def test_run_report_names_what_duplex_does(route):
     assert [n for n in COUNTERS if n not in report["metrics"]] == []
     side = "device" if route == "fast-device" else "host"
     assert report["metrics"]["duplex.combine_rows_" + side] > 0
-    assert by_name["engine.duplex.combine"]["threads"] == ["MainThread"]
+    # the configuration runs --threads 4: stage 2 and the completion of
+    # stage 1 belong to the resolve workers, every span of them
+    for name in ("resolve.unpack", "engine.duplex.classify",
+                 "engine.duplex.combine", "engine.duplex.rx",
+                 "resolve.serialize"):
+        assert all(t.startswith("fgumi-worker-")
+                   for t in by_name[name]["threads"]), name
+    m = report["metrics"]
+    assert m["duplex.stage2_off_thread"] == m["duplex.stage2_batches"] > 0
+    # and the per-molecule caller to the processing thread
+    assert by_name["engine.duplex.slow_molecule"]["threads"] == ["MainThread"]
 
 
 def test_wire_counters_match_the_dispatches():

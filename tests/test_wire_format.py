@@ -527,11 +527,12 @@ print("INFLIGHT-OK")
 
 
 def test_duplex_deferred_hybrid_cli_bytes(tmp_path):
-    """Duplex inline (threads 0) defers its SS device round trip into the
-    double-buffer window (fast_duplex._DuplexPending); threaded mode stays
-    synchronous. All hybrid configurations must produce byte-identical
-    output — including the MI/ordinal numbering of classic-fallback
-    molecules, whose range is pre-reserved at process time."""
+    """Every duplex span is a pending chunk (fast_duplex._DuplexPending):
+    inline (threads 0) it resolves in the double-buffer window, behind the
+    next batch's dispatch or (FGUMI_TPU_INLINE_FLIGHT=1) at once; at
+    threads 4 on the resolve workers. All hybrid configurations must
+    produce byte-identical output — including the MI/ordinal numbering of
+    classic-fallback molecules, called and numbered at process time."""
     import subprocess
     import sys
 
@@ -554,7 +555,7 @@ def test_duplex_deferred_hybrid_cli_bytes(tmp_path):
             ("inline_deferred", "0", {"FGUMI_TPU_HOST_ENGINE": "0"}),
             ("inline_serial", "0", {"FGUMI_TPU_HOST_ENGINE": "0",
                                     "FGUMI_TPU_INLINE_FLIGHT": "1"}),
-            ("threaded_sync", "4", {"FGUMI_TPU_HOST_ENGINE": "0"}),
+            ("threaded_pool", "4", {"FGUMI_TPU_HOST_ENGINE": "0"}),
             ("host_engine", "0", {"FGUMI_TPU_HOST_ENGINE": "1"})):
         d = tmp_path / label
         d.mkdir()
@@ -588,5 +589,5 @@ def test_duplex_deferred_hybrid_cli_bytes(tmp_path):
     # threaded mode delivers different chunk sizes to the writer (BGZF
     # framing differs) and a different @PG CL — the record stream itself
     # must still be byte-identical
-    assert records(outs["inline_deferred"]) == records(outs["threaded_sync"])
+    assert records(outs["inline_deferred"]) == records(outs["threaded_pool"])
     assert records(outs["inline_deferred"]) == records(outs["host_engine"])

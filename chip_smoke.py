@@ -42,8 +42,7 @@ CLI = [sys.executable, "-m", "fgumi_tpu"]
 #: children that must never touch the chip (data, references, clients)
 OFF_CHIP = {"JAX_PLATFORMS": "cpu"}
 ROUTE_VARS = ("FGUMI_TPU_ROUTE", "FGUMI_TPU_KERNEL", "FGUMI_TPU_HOST_ENGINE",
-              "FGUMI_TPU_HYBRID", "FGUMI_TPU_DEVICE_PATH", "FGUMI_TPU_MESH",
-              "FGUMI_TPU_MAX_INFLIGHT", "FGUMI_TPU_DONATE")
+              "FGUMI_TPU_HYBRID", "FGUMI_TPU_MESH", "FGUMI_TPU_MAX_INFLIGHT")
 
 _PROBE = ("import json, jax; d = jax.devices(); print(json.dumps("
           "{'platform': d[0].platform, 'kind': d[0].device_kind, "

@@ -300,35 +300,18 @@ class SimplexFilterStage:
                            np.ascontiguousarray(e, dtype=np.int32)))
 
         fused = None  # (multi idxs, resident, fused stats rows)
-        pending = chunk.pending
-        if pending is None:
-            pass
-        elif pending[0] == "seg":
-            _, idxs, starts, codes_d, quals_d, dev = pending
-            w, q, d, e = kernel.resolve_segments(dev, codes_d, quals_d,
-                                                 starts)
-            add_full_columns(idxs, w, q, d, e)
-        elif pending[0] == "cols":
-            _, idxs, pend = pending
-            w, q, d, e = kernel.resolve_hard_columns(pend)
-            add_full_columns(idxs, w, q, d, e)
-        elif pending[0] == "segwf":
-            _, idxs, starts, codes_d, quals_d, ticket = pending
-            out = kernel.resolve_segments_wire_filtered(
-                ticket, codes_d, quals_d, starts)
+        pending = chunk.pending  # an ops/kernel.PendingSegments, or None
+        if pending is not None:
+            out = pending.resolve_filtered()
             if out[0] == "columns":
-                add_full_columns(idxs, *out[1:])
+                add_full_columns(chunk.idxs, *out[1:])
             else:
                 _, dev_stats, resident = out
-                fused = self._fused_rows(kernel, table, idxs, starts,
-                                         codes_d, quals_d, dev_stats,
+                fused = self._fused_rows(kernel, table, chunk.idxs,
+                                         pending.starts, pending.codes2d,
+                                         pending.quals2d, dev_stats,
                                          resident, stats_all, newly,
                                          add_full_columns)
-        else:  # "segw": standard wire ticket (mesh route etc.)
-            _, idxs, starts, codes_d, quals_d, ticket = pending
-            w, q, d, e = kernel.resolve_segments_wire(
-                ticket, codes_d, quals_d, starts)
-            add_full_columns(idxs, w, q, d, e)
 
         verdicts = self.read_verdicts(stats_all, table.cons_len)
         keep = self.template_keep(verdicts, table.mi_rec)

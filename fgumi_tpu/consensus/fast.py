@@ -38,26 +38,6 @@ def resolve_chunk(chunk) -> bytes:
     return chunk if isinstance(chunk, bytes) else chunk.resolve()
 
 
-def split_row_balanced(counts, dp):
-    """Job boundaries for dp contiguous row-balanced shards over segments of
-    `counts` rows each: (dp+1,) indices into the job list.
-
-    The target-crossing job goes to whichever side leaves the row split
-    closer to the target (plain searchsorted+1 can collapse a 2-job batch
-    onto one device). Shared by the simplex and duplex sharded dispatches.
-    """
-    n_jobs = len(counts)
-    cum = np.cumsum(counts)
-    total = int(cum[-1])
-    targets = (np.arange(1, dp) * total) // dp
-    i = np.searchsorted(cum, targets, side="left")
-    prev = np.where(i > 0, cum[np.maximum(i - 1, 0)], 0)
-    jb = i + ((cum[np.minimum(i, n_jobs - 1)] - targets)
-              <= (targets - prev))
-    jb = np.concatenate(([0], jb, [n_jobs]))
-    return np.minimum(np.maximum.accumulate(jb), n_jobs)
-
-
 def pack_shards(codes_d, quals_d, starts, jb, L_max):
     """Pack dense (rows, L) segment data into the (dp, N_max, L) sharded
     layout for device_call_segments_sharded.
@@ -204,12 +184,15 @@ class _PendingChunk:
     depth/errors on host, apply thresholds, serialize (SURVEY §7 step 4
     double-buffering: dispatch happens in process_batch, this completes it)."""
 
-    __slots__ = ("fast", "batch", "jobs", "pending", "blocks")
+    __slots__ = ("fast", "batch", "jobs", "idxs", "pending", "blocks")
 
-    def __init__(self, fast, batch, jobs, pending, blocks0=()):
+    def __init__(self, fast, batch, jobs, idxs, pending, blocks0=()):
         self.fast = fast
         self.batch = batch
         self.jobs = jobs  # a _JobTable
+        # the multi-read jobs' indices and their submitted segment batch
+        # (ops/kernel.PendingSegments); None when every job is single-read
+        self.idxs = idxs
         self.pending = pending
         # (job_idxs, bases, quals, depth32, errors32) row blocks; starts with
         # the host-path blocks (single-read jobs) from _dispatch_jobs
@@ -222,25 +205,8 @@ class _PendingChunk:
             # device stats fetch (or host columns), survivors-only gather,
             # survivors-only serialization — consensus/device_filter.py
             return fast.filter_stage.resolve_chunk(self)
-        caller = fast.caller
-        kernel = caller.kernel
-        if self.pending is None:
-            pass
-        elif self.pending[0] == "seg":
-            _, idxs, starts, codes_d, quals_d, dev = self.pending
-            winner, qual, depth, errors = kernel.resolve_segments(
-                dev, codes_d, quals_d, starts)
-            self._assign(idxs, winner, qual, depth, errors)
-        elif self.pending[0] == "cols":
-            _, idxs, pending = self.pending
-            winner, qual, depth, errors = kernel.resolve_hard_columns(
-                pending)
-            self._assign(idxs, winner, qual, depth, errors)
-        else:  # "segw": the wire ticket, single-device or mesh-sharded
-            _, idxs, starts, codes_d, quals_d, ticket = self.pending
-            winner, qual, depth, errors = kernel.resolve_segments_wire(
-                ticket, codes_d, quals_d, starts)
-            self._assign(idxs, winner, qual, depth, errors)
+        if self.pending is not None:
+            self._assign(self.idxs, *self.pending.resolve())
         with _span("resolve.serialize", rusage=True):
             return fast._serialize_jobs(self.batch, self.jobs, self.blocks)
 
@@ -431,8 +397,8 @@ class FastSimplexCaller:
                                                      g1)
         if len(table) == 0:
             return []
-        pending, blocks0 = self._dispatch_jobs(codes, quals, table)
-        return [_PendingChunk(self, batch, table, pending, blocks0)]
+        multi, pending, blocks0 = self._dispatch_jobs(codes, quals, table)
+        return [_PendingChunk(self, batch, table, multi, pending, blocks0)]
 
     def _prepare_jobs(self, batch, idx, bounds, g0, g1):
         """Native prep of groups [g0, g1): mate clips, packed reads, and the
@@ -865,7 +831,8 @@ class FastSimplexCaller:
         and one uint16 fetch per record batch, independent of family-size
         mix (the per-execution launch overhead is paid once). The fetch + threshold + serialize half runs
         in _PendingChunk.resolve() (SURVEY §7 step 4: host prep overlaps
-        device compute and transfer). Returns (pending-or-None, host_blocks).
+        device compute and transfer). Returns (the multi-read jobs' indices,
+        their submitted batch or None when there are none, host_blocks).
         """
         caller = self.caller
         opts = caller.options
@@ -897,22 +864,20 @@ class FastSimplexCaller:
                 # positions per byte
                 L_max = -(-int(table.cons_len[multi].max()) // 4) * 4
         if len(multi) == 0:
-            return None, blocks0
+            return multi, None, blocks0
 
-        from ..ops.kernel import HOST_DISPATCH, device_path
         from ..ops.router import ROUTER
 
-        N = len(rows_all)
         mesh = self.mesh
         # full-column gate (uint16 depth fetch) decided BEFORE routing so
         # the fused-filter pricing below can never be promised for a batch
         # that would actually dispatch the ordinary full-column kernel
-        full = bool(counts.max() < 65536)
         fused_filter = False
-        if self.filter_stage is not None and mesh is None and full:
+        if self.filter_stage is not None and mesh is None \
+                and counts.max() < 65536:
             from .device_filter import device_mask_enabled
 
-            fused_filter = device_mask_enabled() and device_path() == "full"
+            fused_filter = device_mask_enabled()
         if kernel.host_mode():
             side = "host"
         else:
@@ -923,98 +888,40 @@ class FastSimplexCaller:
             # batch is priced with its reduced fetch (stats row + keep-rate
             # scaled survivor columns) instead of the full-column fetch.
             side = ROUTER.decide_batch(
-                kernel, N, len(multi), L_max,
+                kernel, len(rows_all), len(multi), L_max,
                 devices=mesh.size if mesh is not None else 1,
                 filtered=fused_filter)
-        if side == "host":
-            # host f64 engine path: either no device at all, or the cost
-            # model priced this batch host-side — the native engine eats it
-            # CONCURRENTLY on the resolve pool, so e2e throughput is
-            # device + host, not min of the two. No pad, no device layout:
-            # the native engine consumes ragged rows.
-            with _span("engine.host_gather", rusage=True):
-                starts = np.concatenate(([0], np.cumsum(counts)))
-                return ("seg", multi, starts,
-                        np.ascontiguousarray(codes[rows_all, :L_max]),
-                        np.ascontiguousarray(quals[rows_all, :L_max]),
-                        HOST_DISPATCH), blocks0
-
-        if device_path() == "columns":
-            # round-5 comparison route (FGUMI_TPU_DEVICE_PATH=columns):
-            # native classify resolves the easy columns on host; only the
-            # hard few percent cross the link as a compact observation
-            # stream (ops/kernel.py dispatch_hard_columns)
-            starts = np.concatenate(([0], np.cumsum(counts)))
-            pending = kernel.dispatch_hard_columns(
-                np.ascontiguousarray(codes[rows_all, :L_max]),
-                np.ascontiguousarray(quals[rows_all, :L_max]), starts)
-            return ("cols", multi, pending), blocks0
-
-        # full-column device route (the round-6 default): the whole batch
-        # crosses the link once in the 1 B/position wire layout and the
-        # device resolves every column — winner/qual/depth/errors per
-        # position, no host re-walk of the dense rows at resolve time.
-        # With a > 1-device mesh the same wire kernels run shard_map-
-        # wrapped over (dp, sp) (ops/kernel.pad_segments_mesh +
-        # _dispatch_wire_mesh); resolve is the identical "segw" pending —
-        # byte-identity with the single-device path is the test oracle.
-        import time
-
-        # gather + pad + wire build == this batch's pack: the span ends with
-        # the dispatch handed to the feeder, a few microseconds after the
-        # timeline's pack_s stamp (begin_in_flight), which it must agree with
-        with _span("engine.pack", rusage=True):
-            t_pack0 = time.monotonic()
-            return self._pack_and_dispatch(
-                codes, quals, table, multi, counts, rows_all, L_max, full,
-                fused_filter, t_pack0), blocks0
+        return multi, self._pack_and_dispatch(
+            codes, quals, table, multi, counts, rows_all, L_max, side,
+            fused_filter), blocks0
 
     def _pack_and_dispatch(self, codes, quals, table, multi, counts, rows_all,
-                           L_max, full, fused_filter, t_pack0):
-        """The device route of one batch: gather + pad into the device
-        layout, wire build and hand-off to the feeder. Returns the pending
-        tuple ``_PendingChunk.resolve`` completes."""
-        from ..ops.kernel import pad_segments_mesh
-        from ..ops.router import ROUTER
-
+                           L_max, side, fused_filter):
+        """Submit one batch's multi-read jobs on the route the router chose:
+        the whole batch crosses the link once in the 1 B/position wire
+        layout and the device resolves every column, or the native f64
+        engine takes the rows at resolve time. One device packs the ragged
+        rows in one native pass; a > 1-device mesh runs the same wire
+        kernels shard_map-wrapped over (dp, sp) from dense rows —
+        byte-identity with the single-device path is the test oracle."""
         kernel = self.caller.kernel
-        opts = self.caller.options
-        mesh = self.mesh
-        pred = ROUTER.last_prediction()
-        if mesh is not None:
-            codes_d = np.ascontiguousarray(codes[rows_all, :L_max])
-            quals_d = np.ascontiguousarray(quals[rows_all, :L_max])
-            codes_g, quals_g, seg_g, starts_p, F_loc, gather = \
-                pad_segments_mesh(codes_d, quals_d, counts, mesh)
-            ticket = kernel.device_call_segments_wire(
-                codes_g, quals_g, seg_g, F_loc, len(multi),
-                pack_t0=t_pack0, full=full,
-                pred_s=pred[0] if pred else None, mesh=mesh,
-                mesh_gather=gather)
-            return ("segw", multi, starts_p, codes_d, quals_d, ticket)
-        codes_dev, quals_dev, seg_ids, starts_p, F_pad, N_real, prebuilt = \
-            kernel.pack_segments_wire(codes, quals, rows_all, L_max, counts)
-        if fused_filter:
+        if self.mesh is not None:
+            return kernel.submit_dense(
+                lambda: (np.ascontiguousarray(codes[rows_all, :L_max]),
+                         np.ascontiguousarray(quals[rows_all, :L_max])),
+                counts, side, mesh=self.mesh)
+        filter_params = None
+        if fused_filter and side != "host":
             # fused consensus→filter dispatch: per-read stats fetch +
             # device-resident masked columns (survivors gathered at
             # resolve time by the filter stage)
-            ticket = kernel.device_call_segments_wire(
-                codes_dev, quals_dev, seg_ids, F_pad, len(multi),
-                pack_t0=t_pack0, full=True,
-                pred_s=pred[0] if pred else None, prebuilt=prebuilt,
-                filter_params=(
-                    np.int32(opts.min_reads),
-                    np.int32(opts.min_consensus_base_quality),
-                    table.cons_len[multi].astype(np.int32),
-                    self.filter_stage.dev_params))
-            return ("segwf", multi, starts_p, codes_dev[:N_real],
-                    quals_dev[:N_real], ticket)
-        ticket = kernel.device_call_segments_wire(
-            codes_dev, quals_dev, seg_ids, F_pad, len(multi),
-            pack_t0=t_pack0, full=full,
-            pred_s=pred[0] if pred else None, prebuilt=prebuilt)
-        return ("segw", multi, starts_p, codes_dev[:N_real],
-                quals_dev[:N_real], ticket)
+            opts = self.caller.options
+            filter_params = (np.int32(opts.min_reads),
+                             np.int32(opts.min_consensus_base_quality),
+                             table.cons_len[multi].astype(np.int32),
+                             self.filter_stage.dev_params)
+        return kernel.submit_ragged(codes, quals, rows_all, L_max, counts,
+                                    side, filter_params=filter_params)
 
     # ------------------------------------------------------------------ output
 

@@ -114,7 +114,7 @@ class FastCodecCaller:
 
         Vec-prepared molecules (strand rows resident in the pack arrays)
         land in the dense layout via ONE gather from codes_pk/quals_pk —
-        the same pad_segments/device_call_segments/thresholds sequence as
+        the same route_and_call_segments/thresholds sequence as
         VanillaConsensusCaller._run_jobs, minus the per-read row repack.
         Classic-prepared molecules (carry/fallback ConsensusJobs) repack
         their few rows into the same layout, so every batch costs exactly
@@ -188,13 +188,12 @@ class FastCodecCaller:
                     codes2d[row, :k] = c[:k]
                     quals2d[row, :k] = q[:k]
                     row += 1
-            # adaptive offload: host f64 engine / hard-column export /
-            # full-column wire, decided per batch (ops/kernel helper)
+            # adaptive offload: host f64 engine or full-column wire,
+            # decided per batch (ops/kernel helper)
             from ..ops.kernel import route_and_call_segments
 
-            starts = np.concatenate(([0], np.cumsum(counts)))
             w, q_, d, e = route_and_call_segments(ss.kernel, codes2d,
-                                                  quals2d, counts, starts,
+                                                  quals2d, counts,
                                                   mesh=self.mesh)
             slots = [(v[0], v[1], v[4]) for v in vec_multi] \
                 + [(c[0], c[1], c[2]) for c in cls]

@@ -505,7 +505,8 @@ class FastDuplexCaller:
         (nseg, L_max) each, the valid rows' codes, and the fused
         strand-combine context (None unless the full-column device route
         kept stage-1 outputs resident). discard() hands a wire dispatch
-        back when finish() will never run; None when there is none."""
+        back when finish() will never run (None, or a no-op on the host
+        route, when there is none)."""
         opts = self.ss.options
         tb = np.zeros((nseg, L_max), dtype=np.uint8)
         tq = np.zeros((nseg, L_max), dtype=np.uint8)
@@ -534,16 +535,18 @@ class FastDuplexCaller:
                 codes2d = np.ascontiguousarray(codes[vrows])
             return (lambda: (tb, tq, d16, e16, codes2d, None)), None
         counts_m = c1[multi]
-        starts_m = np.concatenate(([0], np.cumsum(counts_m)))
+
+        codes2d = None
 
         def gather():
             """The valid rows in seg order (stage 2 recounts errors over
             them) and, out of those, the multi-read segs' rows, dense."""
+            nonlocal codes2d
             codes2d = np.ascontiguousarray(codes[vrows])
             quals2d = np.ascontiguousarray(quals[vrows])
             rows_m = np.concatenate(
                 [np.arange(vstarts[s], vstarts[s + 1]) for s in multi])
-            return (codes2d, np.ascontiguousarray(codes2d[rows_m]),
+            return (np.ascontiguousarray(codes2d[rows_m]),
                     np.ascontiguousarray(quals2d[rows_m]))
 
         def finish_with(w, q_, d, e, ctx):
@@ -563,81 +566,27 @@ class FastDuplexCaller:
             from ..ops.router import ROUTER
 
             route = ROUTER.decide_batch(
-                self.kernel, int(starts_m[-1]), len(multi), L_max,
+                self.kernel, int(counts_m.sum()), len(multi), L_max,
                 devices=self.mesh.size if self.mesh is not None else 1)
-        if route == "host":
-            # no device, or the cost model priced this batch host-side:
-            # the native f64 engine computes it at resolve time
-            from ..ops.kernel import HOST_DISPATCH
-
-            with _span("engine.host_gather", rusage=True):
-                codes2d, cm, qm = gather()
-
-            def resolve_host():
-                return finish_with(*self.kernel.resolve_segments(
-                    HOST_DISPATCH, cm, qm, starts_m), None)
-
-            return resolve_host, None
-        from ..ops.kernel import device_path
-
-        if device_path() == "columns":
-            # round-5 comparison route: classify + compact hard-column
-            # export (FGUMI_TPU_DEVICE_PATH=columns)
-            with _span("engine.pack", rusage=True):
-                with _span("engine.pack.gather"):
-                    codes2d, cm, qm = gather()
-                pending = self.kernel.dispatch_hard_columns(cm, qm, starts_m)
-
-            def resolve_cols():
-                return finish_with(
-                    *self.kernel.resolve_hard_columns(pending), None)
-
-            return resolve_cols, None
-        # full-column wire route (round-6 default): the whole multi-seg
-        # pileup crosses the link once; with the resident variant the
+        # host: the native f64 engine computes the batch at resolve time.
+        # Device: the whole multi-seg pileup crosses the link once in the
+        # full-column wire layout; with the resident variant the
         # thresholded outputs stay on device for the fused strand combine.
         # A > 1-device mesh runs the same kernels shard_map-wrapped
         # (families over dp, read rows over sp with one psum); the
         # resident arrays then live sharded along dp and the combine's
         # indices are mapped through the shard-order gather below.
         import os
-        import time as _time
-
-        from ..ops.kernel import pad_segments, pad_segments_mesh
-        from ..ops.router import ROUTER
 
         comb_env = os.environ.get("FGUMI_TPU_DUPLEX_COMBINE",
                                   "auto").strip().lower()
-        full_ok = bool(counts_m.max() < 65536)
-        want_res = full_ok and comb_env != "host"
-        pred = ROUTER.last_prediction()
-        res_thresholds = (opts.min_reads,
-                          opts.min_consensus_base_quality) \
-            if want_res else None
-        mesh = self.mesh
-        # row copies + pad + wire build == this batch's pack: the span ends
-        # with the dispatch handed to the feeder, as the simplex engine's
-        # does, and the timeline's pack_s starts where it starts
-        with _span("engine.pack", rusage=True):
-            t_pack0 = _time.monotonic()
-            with _span("engine.pack.gather"):
-                codes2d, cm, qm = gather()
-                if mesh is not None:
-                    cd, qd, seg_ids, _st, F_pad, gather_idx = \
-                        pad_segments_mesh(cm, qm, counts_m, mesh)
-                    on_mesh = {"mesh": mesh, "mesh_gather": gather_idx}
-                else:
-                    cd, qd, seg_ids, _sp, F_pad = pad_segments(cm, qm,
-                                                               counts_m)
-                    on_mesh = {}
-            ticket = self.kernel.device_call_segments_wire(
-                cd, qd, seg_ids, F_pad, len(multi), pack_t0=t_pack0,
-                full=full_ok, resident_thresholds=res_thresholds,
-                pred_s=pred[0] if pred else None, **on_mesh)
+        pending = self.kernel.submit_dense(
+            gather, counts_m, route, mesh=self.mesh,
+            resident_thresholds=None if comb_env == "host" else
+            (opts.min_reads, opts.min_consensus_base_quality))
 
-        def resolve_wire():
-            w, q_, d, e, extras = self.kernel.resolve_segments_wire(
-                ticket, cm, qm, starts_m, want_extras=True)
+        def resolve():
+            w, q_, d, e, extras = pending.resolve(want_extras=True)
             ctx = None
             if extras["resident"] is not None:
                 seg_to_multi = np.full(nseg, -1, dtype=np.int64)
@@ -648,27 +597,10 @@ class FastDuplexCaller:
                        "override": comb_env,
                        # mesh dispatches: multi index -> row of the
                        # shard-ordered resident arrays
-                       "gather": extras.get("gather")}
+                       "gather": extras["gather"]}
             return finish_with(w, q_, d, e, ctx)
 
-        def discard_wire():
-            # nobody will fetch this dispatch: hand it back as a resolver
-            # that ran out of time does, without waiting for the device
-            from ..ops.kernel import (DEVICE_FEEDER, DEVICE_STATS,
-                                      ResidentHandles)
-
-            DEVICE_STATS.end_in_flight(ticket.slot, 0, 0.0)
-            DEVICE_FEEDER.abandon(ticket)
-            try:
-                dev = ticket.wait(0)
-            except Exception:  # noqa: BLE001 - nothing of it to release
-                # still queued or running (the feeder discards it when it
-                # ends), or the dispatch itself failed
-                return
-            if isinstance(dev[-1], ResidentHandles):
-                dev[-1].release()
-
-        return resolve_wire, discard_wire
+        return resolve, pending.discard
 
     # ---------------------------------------------------------------- stage 2
 

@@ -434,7 +434,7 @@ class VanillaConsensusCaller(RejectTracking):
 
     def _run_jobs(self, jobs):
         """Execute jobs: single-read on host, multi-read via ONE ragged
-        segment-sum dispatch (kernel.device_call_segments) per call.
+        segment-sum dispatch (ops/kernel.route_and_call_segments) per call.
 
         One device execution per job batch regardless of family-size mix —
         the same dense layout the fast simplex engine uses (consensus/fast.py
@@ -497,9 +497,8 @@ class VanillaConsensusCaller(RejectTracking):
         # classic/--classic runs share their link economics
         from ..ops.kernel import route_and_call_segments
 
-        starts = np.concatenate(([0], np.cumsum(counts)))
         w, q_, d, e = route_and_call_segments(self.kernel, codes2d, quals2d,
-                                              counts, starts)
+                                              counts)
         for fi, j in enumerate(multi):
             L = jobs[j].consensus_len
             b_j, q_j = oracle.apply_consensus_thresholds(

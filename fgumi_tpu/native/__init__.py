@@ -25,7 +25,7 @@ _lock = threading.Lock()
 _lib = None
 _lib_failed = False
 # must equal fgumi_abi_version() in fgumi_native.cc (stale-.so guard)
-_ABI_VERSION = 15
+_ABI_VERSION = 16
 
 
 def build() -> bool:
@@ -147,11 +147,6 @@ def _declare(lib):
     lib.fgumi_consensus_segments.argtypes = (
         [p, p, p, ctypes.c_long, ctypes.c_long, p, p, ctypes.c_double,
          ctypes.c_int, ctypes.c_int] + [p] * 8 + [p, p, p, ctypes.c_long])
-    lib.fgumi_consensus_classify.restype = ctypes.c_long
-    lib.fgumi_consensus_classify.argtypes = (
-        [p, p, p, ctypes.c_long, ctypes.c_long, p, ctypes.c_double,
-         ctypes.c_int, ctypes.c_int] + [p] * 8
-        + [p, p, p, p, p, ctypes.c_long, ctypes.c_long, p])
     lib.fgumi_ranges_equal.restype = None
     lib.fgumi_ranges_equal.argtypes = [p] * 5 + [ctypes.c_long, p]
     lib.fgumi_hash_ranges.restype = None

@@ -290,59 +290,6 @@ def consensus_segments(codes2d: np.ndarray, quals2d: np.ndarray,
         cap = n_slow  # adversarial input: every position borderline
 
 
-def consensus_classify(codes2d: np.ndarray, quals2d: np.ndarray,
-                       starts: np.ndarray, delta_tab: np.ndarray,
-                       g_sat: float, qual_const: int, min_phred: int,
-                       tab1_winner: np.ndarray, tab1_qual: np.ndarray,
-                       tab2_winner: np.ndarray, tab2_qual: np.ndarray):
-    """Easy/hard column classification + hard export
-    (fgumi_consensus_classify; the host half of the hybrid device dispatch).
-
-    Returns (winner, qual, depth, errors, hard_idx, hard_depth,
-    hard_counts (K,4) i32, hard_codes (M,) u8, hard_quals (M,) u8): the
-    (J, L) outputs are written for EASY columns only; the K hard columns
-    (flat indices, ascending) carry their valid observations concatenated
-    in hard_codes/hard_quals (M = hard_depth.sum()).
-    """
-    faults.fire("native.batch")
-    lib = get_lib()
-    J = len(starts) - 1
-    L = codes2d.shape[1] if codes2d.ndim == 2 else 0
-    codes2d = _as_c(codes2d, np.uint8)
-    quals2d = _as_c(quals2d, np.uint8)
-    starts = _as_c(starts, np.int64)
-    delta_tab = _as_c(delta_tab, np.float64)
-    winner = np.empty((J, L), dtype=np.uint8)
-    qual = np.empty((J, L), dtype=np.uint8)
-    depth = np.empty((J, L), dtype=np.int32)
-    errors = np.empty((J, L), dtype=np.int32)
-    N = int(starts[-1]) if J else 0
-    cap = max(4096, (J * L) // 8)
-    obs_cap = max(16384, (N * L) // 8)
-    n_obs = np.zeros(1, dtype=np.int64)
-    while True:
-        hard_idx = np.empty(cap, dtype=np.int64)
-        hard_depth = np.empty(cap, dtype=np.int32)
-        hard_counts = np.empty((cap, 4), dtype=np.int32)
-        hard_codes = np.empty(obs_cap, dtype=np.uint8)
-        hard_quals = np.empty(obs_cap, dtype=np.uint8)
-        n_hard = lib.fgumi_consensus_classify(
-            _addr(codes2d), _addr(quals2d), _addr(starts), J, L,
-            _addr(delta_tab), float(g_sat), int(qual_const), int(min_phred),
-            _addr(tab1_winner), _addr(tab1_qual), _addr(tab2_winner),
-            _addr(tab2_qual), _addr(winner), _addr(qual), _addr(depth),
-            _addr(errors), _addr(hard_idx), _addr(hard_depth),
-            _addr(hard_counts), _addr(hard_codes), _addr(hard_quals),
-            cap, obs_cap, _addr(n_obs))
-        M = int(n_obs[0])
-        if n_hard <= cap and M <= obs_cap:
-            return (winner, qual, depth, errors, hard_idx[:n_hard],
-                    hard_depth[:n_hard], hard_counts[:n_hard],
-                    hard_codes[:M], hard_quals[:M])
-        cap = max(n_hard, cap)
-        obs_cap = max(M, obs_cap)
-
-
 def umi_neighbor_pairs(mat_a: np.ndarray, mat_b, d: int, index: str = "auto"):
     """Candidate (i, j) pairs with hamming <= d.
 

@@ -92,7 +92,7 @@ extern "C" {
 // ABI version for the stale-.so guard in __init__.py: bump whenever any
 // exported signature changes (a symbol probe alone cannot detect an
 // argument-list change in an existing function).
-long fgumi_abi_version() { return 15; }
+long fgumi_abi_version() { return 16; }
 
 // Candidate UMI pairs with hamming(A[i], B[j]) <= d over (n, L)/(m, L) byte
 // matrices, via the d+1-part pigeonhole (umi/assigners.py
@@ -383,7 +383,8 @@ long fgumi_gzip_decompress(const uint8_t* src, long n, uint8_t* dst,
 // Decompress as many complete BGZF blocks from src as fit in dst.
 // Returns bytes produced; sets *consumed to the input bytes consumed (whole
 // blocks only — a trailing partial block is left for the caller's next call).
-// Returns -1 on a malformed block, -2 when dst has no room for the next
+// Returns -1 on a malformed block (a payload that does not inflate to ISIZE
+// bytes with the trailer's CRC32), -2 when dst has no room for the next
 // block's payload (caller grows dst or flushes first).
 long fgumi_bgzf_decompress(const uint8_t* src, long src_len, uint8_t* dst,
                            long dst_cap, long* consumed) {
@@ -417,6 +418,12 @@ long fgumi_bgzf_decompress(const uint8_t* src, long src_len, uint8_t* dst,
         decompressor(), payload, static_cast<size_t>(payload_len),
         dst + out_off, static_cast<size_t>(isize), &actual);
     if (r != LIBDEFLATE_SUCCESS || actual != isize) return -1;
+    // the member's CRC32 (trailer, before ISIZE): flipped payload bytes can
+    // still inflate to ISIZE bytes, and only this check sees them
+    if (libdeflate_crc32(0, dst + out_off, actual) !=
+        read_u32(src + in_off + bsize - 8)) {
+      return -1;
+    }
     out_off += static_cast<long>(isize);
     in_off += bsize;
   }
@@ -3343,142 +3350,6 @@ long fgumi_consensus_segments(
     }
   }
   return n_slow;
-}
-
-// ---------------------------------------------------------------------------
-// Hybrid classify + hard-column export (round 5). Resolves the EASY columns
-// natively at byte-scan cost — depth-0 no-call, depth-1/2 oracle lookup
-// tables, and unanimous saturated columns (single observed base, no Q0, gap
-// = sum of per-obs deltas >= g_sat + slack, so the oracle's two-trials quick
-// path provably fires and the quality is the precomputed constant) — and
-// exports the remaining HARD columns as a compact column-major observation
-// stream for the accelerator. On UMI pileups the hard fraction is a few
-// percent of columns carrying most of the remaining likelihood compute, so
-// the device gets the compute-worthy work at ~2 orders of magnitude fewer
-// link bytes than shipping whole pileups (the ops/kernel.py hard-column
-// dispatch; reference semantics: base_builder.rs:186-301 unanimous fast
-// path generalized to an export boundary).
-//
-// Unlike fgumi_consensus_segments, no Kahan lane accumulation happens here:
-// per observation the work is one delta-table load + add and a few byte
-// ops. Correctness of the saturation test: the naive f64 sum of
-// nonnegative deltas differs from the engine's Kahan lane-sum gap by
-// <= n*eps*sum (~1e-9 at depth 1000), dwarfed by the 1e-6 slack; columns
-// failing the slack by less go hard and are resolved exactly downstream.
-//
-// Outputs: out_* (J, L) written for easy columns only; hard columns land in
-// hard_idx (flat j*L+i, ascending), hard_depth, hard_counts (4 per column),
-// and the concatenated hard_codes/hard_quals streams (valid obs only,
-// quals clamped to 93). Returns n_hard and writes the total obs count to
-// n_obs_out; if n_hard > hard_cap or obs > obs_cap the export is partial
-// and the caller must retry with the returned sizes.
-long fgumi_consensus_classify(
-    const uint8_t* codes, const uint8_t* quals, const int64_t* starts,
-    long J, long L, const double* delta_tab, double g_sat, int qual_const,
-    int min_phred, const uint8_t* tab1_winner, const uint8_t* tab1_qual,
-    const uint8_t* tab2_winner, const uint8_t* tab2_qual,
-    uint8_t* out_winner, uint8_t* out_qual, int32_t* out_depth,
-    int32_t* out_errors, int64_t* hard_idx, int32_t* hard_depth,
-    int32_t* hard_counts, uint8_t* hard_codes, uint8_t* hard_quals,
-    long hard_cap, long obs_cap, int64_t* n_obs_out) {
-  struct ColAcc {
-    double sum_delta;
-    int32_t obs[4];
-    uint8_t b0, q0, b1, q1;  // first two observations (depth-table keys)
-    uint8_t distinct;        // bitmask of observed bases
-    uint8_t has_q0;
-  };
-  std::vector<ColAcc> acc(static_cast<size_t>(L));
-  long n_hard = 0;
-  int64_t n_obs = 0;
-  for (long j = 0; j < J; ++j) {
-    std::memset(acc.data(), 0, sizeof(ColAcc) * static_cast<size_t>(L));
-    for (int64_t r = starts[j]; r < starts[j + 1]; ++r) {
-      const uint8_t* crow = codes + r * L;
-      const uint8_t* qrow = quals + r * L;
-      for (long i = 0; i < L; ++i) {
-        const uint8_t c = crow[i];
-        if (c >= 4) continue;
-        ColAcc& a = acc[static_cast<size_t>(i)];
-        const uint8_t q = qrow[i] > 93 ? 93 : qrow[i];
-        const int32_t n = a.obs[0] + a.obs[1] + a.obs[2] + a.obs[3];
-        if (n == 0) {
-          a.b0 = c;
-          a.q0 = q;
-        } else if (n == 1) {
-          a.b1 = c;
-          a.q1 = q;
-        }
-        a.sum_delta += delta_tab[q];
-        a.distinct |= static_cast<uint8_t>(1u << c);
-        a.has_q0 |= static_cast<uint8_t>(q == 0);
-        ++a.obs[c];
-      }
-    }
-    for (long i = 0; i < L; ++i) {
-      const ColAcc& a = acc[static_cast<size_t>(i)];
-      const int32_t depth = a.obs[0] + a.obs[1] + a.obs[2] + a.obs[3];
-      const long o = j * L + i;
-      if (depth == 0) {  // all-N column: no-observation no-call
-        out_winner[o] = 4;
-        out_qual[o] = static_cast<uint8_t>(min_phred);
-        out_depth[o] = 0;
-        out_errors[o] = 0;
-        continue;
-      }
-      if (depth == 1) {
-        const int k = a.b0 * 94 + a.q0;
-        const uint8_t w = tab1_winner[k];
-        out_winner[o] = w;
-        out_qual[o] = tab1_qual[k];
-        out_depth[o] = 1;
-        out_errors[o] = (w == a.b0) ? 0 : 1;
-        continue;
-      }
-      if (depth == 2 && a.q0 > 0 && a.q1 > 0) {
-        const long k = static_cast<long>(a.b0 * 94 + a.q0) * 376 +
-                       (a.b1 * 94 + a.q1);
-        const uint8_t w = tab2_winner[k];
-        out_winner[o] = w;
-        out_qual[o] = tab2_qual[k];
-        out_depth[o] = 2;
-        out_errors[o] = 2 - ((w < 4) ? ((w == a.b0) + (w == a.b1)) : 0);
-        continue;
-      }
-      const bool unanimous = (a.distinct & (a.distinct - 1)) == 0;
-      if (unanimous && !a.has_q0 && a.sum_delta >= g_sat + 1e-6) {
-        out_winner[o] = a.b0;
-        out_qual[o] = static_cast<uint8_t>(qual_const);
-        out_depth[o] = depth;
-        out_errors[o] = 0;
-        continue;
-      }
-      // hard: export the column's valid observations (column-major gather
-      // over the family's rows — the family block is cache-resident)
-      if (n_hard < hard_cap && n_obs + depth <= obs_cap) {
-        hard_idx[n_hard] = o;
-        hard_depth[n_hard] = depth;
-        for (int lane = 0; lane < 4; ++lane) {
-          hard_counts[n_hard * 4 + lane] = a.obs[lane];
-        }
-        int64_t w = n_obs;
-        for (int64_t r = starts[j]; r < starts[j + 1]; ++r) {
-          const uint8_t c = codes[r * L + i];
-          if (c >= 4) continue;
-          const uint8_t q = quals[r * L + i];
-          hard_codes[w] = c;
-          hard_quals[w] = q > 93 ? 93 : q;
-          ++w;
-        }
-        n_obs = w;
-      } else {
-        n_obs += depth;  // keep counting so the caller can size the retry
-      }
-      ++n_hard;
-    }
-  }
-  *n_obs_out = n_obs;
-  return n_hard;
 }
 
 // Elementwise CODEC duplex combine over the concatenated strand arrays —

@@ -26,8 +26,8 @@ import time
 #: histograms (observe/metrics.py) — and optional ``flight_dumps`` (paths
 #: of black boxes the flight recorder wrote during the run).
 #: v3 (ISSUE 11): the ``device`` section may carry the device-resident
-#: pipeline counters — ``donated_uploads``, ``resident_bytes_peak`` (+
-#: live ``resident_bytes`` when nonzero at exit), and the routing
+#: pipeline counters — ``resident_bytes_peak`` (+ live
+#: ``resident_bytes`` when nonzero at exit), and the routing
 #: snapshot's ``filter_keep_rate`` — and the latency section gains the
 #: ``device.dispatch.fetch_bytes`` histogram, making the fused-filter
 #: bytes-fetched claim machine-readable from any run.

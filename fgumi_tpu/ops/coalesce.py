@@ -717,10 +717,7 @@ class DispatchCoalescer:
                                                starts64, split_depth)
             else:
                 raise failure
-            if want_extras:
-                return out + ({"suspect": None, "resident": None,
-                               "gather": None},)
-            return out
+            return out + (K._no_extras(),) if want_extras else out
         base = group.seg_bases[ticket.index]
         j = partner.j
         if len(got) == 4:

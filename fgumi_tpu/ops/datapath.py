@@ -420,12 +420,13 @@ class HostStagingPool:
     The wire build used to mint a fresh (N_pad, L) array per dispatch; with
     the shape-bucket ladder bounding the vocabulary of padded shapes, a
     small keyed free-list turns that into zero per-dispatch staging
-    allocations after warm-up (the donation regression check in
-    microbench.py gates on exactly this). Buffers are released back at
-    dispatch *resolve* time — by then the device has consumed the upload
-    even on backends where ``device_put`` aliases host memory — via the
-    feeder's ``mark_resolved`` (an abandoned/wedged dispatch leaks its
-    buffer rather than risking a recycle under a still-running upload).
+    allocations after warm-up (tests/test_device_filter.py
+    test_staging_pool_reuses_after_warmup gates on exactly this). Buffers
+    are released back at dispatch *resolve* time — by then the device has
+    consumed the upload even on backends where ``device_put`` aliases host
+    memory — via the feeder's ``mark_resolved`` (an abandoned/wedged
+    dispatch leaks its buffer rather than risking a recycle under a
+    still-running upload).
 
     Bounded by ``FGUMI_TPU_STAGING_POOL`` bytes (default 64 MiB; ``0``
     disables pooling entirely): the free list evicts oldest-first, and a

@@ -95,13 +95,9 @@ def _ensure_jax():
     return jax
 
 
-def _lazy_jit(fn=None, *, static_argnames=(), donate_argnums=()):
+def _lazy_jit(fn=None, *, static_argnames=()):
     """@jax.jit that defers both the jax import and the jit wrapping to the
-    first call (same compiled-function caching afterwards).
-    ``donate_argnums``: forwarded to jax.jit — the upload-donation variants
-    of the wire kernels pass their input-buffer argnums so XLA may reuse
-    the uploaded pages for outputs/temporaries instead of allocating fresh
-    device memory per dispatch (SNIPPETS [1]/[3] pattern)."""
+    first call (same compiled-function caching afterwards)."""
     def deco(f):
         box = []
 
@@ -112,8 +108,6 @@ def _lazy_jit(fn=None, *, static_argnames=(), donate_argnums=()):
                 kwargs = {}
                 if static_argnames:
                     kwargs["static_argnames"] = static_argnames
-                if donate_argnums:
-                    kwargs["donate_argnums"] = donate_argnums
                 box.append(jax.jit(f, **kwargs))
             return box[0](*a, **k)
 
@@ -121,23 +115,6 @@ def _lazy_jit(fn=None, *, static_argnames=(), donate_argnums=()):
 
     return deco(fn) if fn is not None else deco
 
-
-def upload_donation_enabled() -> bool:
-    """Whether wire-upload buffers are donated to the consensus jits.
-
-    ``FGUMI_TPU_DONATE=1/0`` forces; the default (``auto``) donates on any
-    non-CPU backend — the CPU backend ignores donation with a per-call
-    warning, so auto keeps host-only runs quiet. Read per dispatch (cheap)
-    so tests can flip it between in-process runs."""
-    import os
-
-    v = os.environ.get("FGUMI_TPU_DONATE", "auto").strip().lower()
-    if v in ("1", "true", "on", "force"):
-        return True
-    if v in ("0", "false", "off"):
-        return False
-    _ensure_jax()
-    return jax.default_backend() != "cpu"
 
 from ..constants import MAX_PHRED, MIN_PHRED, N_CODE
 from ..observe.trace import count, record_interval, span
@@ -317,17 +294,6 @@ def use_host_engine() -> bool:
     _ensure_jax()
     return jax.default_backend() == "cpu"
 
-def device_path() -> str:
-    """Which device route the engines use for whole batches: ``"full"``
-    (the 1-byte-wire full-column kernel; round-6 default) or ``"columns"``
-    (the round-5 classify-and-export-hard-columns path, kept for A/B
-    comparison via FGUMI_TPU_DEVICE_PATH=columns)."""
-    import os
-
-    v = os.environ.get("FGUMI_TPU_DEVICE_PATH", "full").strip().lower()
-    return v if v in ("full", "columns") else "full"
-
-
 # bf16 systolic peak FLOP/s and HBM bytes/s per chip, keyed by the exact
 # ``jax.devices()[0].device_kind`` — for the MFU estimate below. The
 # consensus kernel is VPU/elementwise-dominated, so low MFU is expected.
@@ -395,10 +361,8 @@ class DeviceStats:
         # the device vs the native f64 host engine
         self.route_device = 0
         self.route_host = 0
-        # device-resident pipeline accounting (ISSUE 11): dispatches whose
-        # upload buffers were donated to XLA, and the live/peak bytes of
-        # ResidentHandles arrays pinned on the device between stages
-        self.donated_uploads = 0
+        # device-resident pipeline accounting (ISSUE 11): the live/peak
+        # bytes of ResidentHandles arrays pinned on the device between stages
         self.resident_bytes = 0
         self.resident_bytes_peak = 0
         # kernel-backend accounting (ISSUE 19): wire dispatches executed
@@ -456,10 +420,6 @@ class DeviceStats:
     def add_const_hit(self):
         with self._lock:
             self.const_hits += 1
-
-    def add_donated_upload(self):
-        with self._lock:
-            self.donated_uploads += 1
 
     def add_kernel_backend(self, slot: int, backend: str):
         """Record which kernel backend ran a wire dispatch (ISSUE 19):
@@ -672,8 +632,6 @@ class DeviceStats:
             if self.route_device or self.route_host:
                 out["route_device"] = self.route_device
                 out["route_host"] = self.route_host
-            if self.donated_uploads:
-                out["donated_uploads"] = self.donated_uploads
             if self.kernel_pallas or self.kernel_xla:
                 out["kernel_pallas"] = self.kernel_pallas
                 out["kernel_xla"] = self.kernel_xla
@@ -708,7 +666,7 @@ class DeviceStats:
                 "deadline_fallbacks",
                 "upload_overlap_s", "feeder_queue_peak", "const_uploads",
                 "const_hits", "const_upload_bytes", "route_device",
-                "route_host", "donated_uploads", "resident_bytes",
+                "route_host", "resident_bytes",
                 "resident_bytes_peak", "kernel_pallas", "kernel_xla",
                 "_t0", "_next_slot")}
             timeline = [dict(t) for t in other.timeline]
@@ -1825,19 +1783,11 @@ def _wire_full_fn(wire, seg_ids, dict_tab, ln_error_pre_umi, num_segments,
             errors[:out_segments].astype(jnp.uint16))
 
 
-# plain + upload-donation compilations of each wire-layout kernel: the
-# donated variants let XLA alias the (wire, seg_ids) upload pages for
-# outputs/temporaries instead of allocating fresh device memory per
-# dispatch; chosen per dispatch by upload_donation_enabled().
 _W_STATIC = ("num_segments", "out_segments")
 _consensus_segments_wire_jit = _lazy_jit(
     static_argnames=_W_STATIC)(_wire_split_fn)
-_consensus_segments_wire_donated_jit = _lazy_jit(
-    static_argnames=_W_STATIC, donate_argnums=(0, 1))(_wire_split_fn)
 _consensus_segments_wire_full_jit = _lazy_jit(
     static_argnames=_W_STATIC)(_wire_full_fn)
-_consensus_segments_wire_full_donated_jit = _lazy_jit(
-    static_argnames=_W_STATIC, donate_argnums=(0, 1))(_wire_full_fn)
 
 
 _I16_MAX = 32767  # fgbio Short tag clamp (vanilla.py I16_MAX twin)
@@ -1921,8 +1871,6 @@ def _wire_resident_fn(wire, seg_ids, dict_tab, ln_error_pre_umi, min_reads,
 
 _consensus_segments_wire_resident_jit = _lazy_jit(
     static_argnames=_W_STATIC)(_wire_resident_fn)
-_consensus_segments_wire_resident_donated_jit = _lazy_jit(
-    static_argnames=_W_STATIC, donate_argnums=(0, 1))(_wire_resident_fn)
 
 
 def _wire_filter_fn(wire, seg_ids, dict_tab, ln_error_pre_umi, min_reads_c,
@@ -1985,8 +1933,6 @@ def _wire_filter_fn(wire, seg_ids, dict_tab, ln_error_pre_umi, min_reads_c,
 
 _consensus_segments_wire_filter_jit = _lazy_jit(
     static_argnames=_W_STATIC)(_wire_filter_fn)
-_consensus_segments_wire_filter_donated_jit = _lazy_jit(
-    static_argnames=_W_STATIC, donate_argnums=(0, 1))(_wire_filter_fn)
 
 
 @_lazy_jit(static_argnames=("out_rows",))
@@ -2173,12 +2119,8 @@ def _packed2_full_fn(codes_packed, quals, seg_ids, correct_tab, err_tab,
 
 _consensus_segments_packed2_jit = _lazy_jit(
     static_argnames=_W_STATIC)(_packed2_split_fn)
-_consensus_segments_packed2_donated_jit = _lazy_jit(
-    static_argnames=_W_STATIC, donate_argnums=(0, 1))(_packed2_split_fn)
 _consensus_segments_packed2_full_jit = _lazy_jit(
     static_argnames=_W_STATIC)(_packed2_full_fn)
-_consensus_segments_packed2_full_donated_jit = _lazy_jit(
-    static_argnames=_W_STATIC, donate_argnums=(0, 1))(_packed2_full_fn)
 
 
 def _wire_dict(vals: np.ndarray, delta94: np.ndarray) -> np.ndarray:
@@ -2238,58 +2180,6 @@ def pack_codes2(codes2d: np.ndarray, quals2d: np.ndarray):
           | (c4[..., 3] << 6))
     q = np.where(codes2d == N_CODE, QUAL_INVALID, quals2d).astype(np.uint8)
     return np.ascontiguousarray(cp), q
-
-
-def _columns_body(one_hot, delta, depths, ln_error_pre_umi, num_segments,
-                  out_segments):
-    """Shared hard-column reduction: per-observation (one_hot, delta) ->
-    sliced split-packed per-column result. Segment ids are reconstructed on
-    device from the depths (saves 4 B/obs of seg-id upload); the output
-    packing delegates to _pack_result_split so the suspect-bit/2-bit-winner
-    wire word has exactly one encoder."""
-    n_rows = one_hot.shape[0]
-    seg_ids = jnp.repeat(jnp.arange(num_segments, dtype=jnp.int32), depths,
-                         total_repeat_length=n_rows)
-    contrib = jax.ops.segment_sum(delta[:, None] * one_hot, seg_ids,
-                                  num_segments=num_segments,
-                                  indices_are_sorted=True)
-    obs = jax.ops.segment_sum(one_hot, seg_ids, num_segments=num_segments,
-                              indices_are_sorted=True).astype(jnp.int32)
-    winner, qual, _depth, _errors, suspect = _call_epilogue(
-        contrib, obs, ln_error_pre_umi)
-    # (C,) columns pack as one L=4-wide pseudo-row group: same wire word
-    qs, wp = _pack_result_split(winner.reshape(-1, 4),
-                                qual.reshape(-1, 4),
-                                suspect.reshape(-1, 4), out_segments // 4)
-    return qs.reshape(-1)[:out_segments], wp.reshape(-1)
-
-
-@_lazy_jit(static_argnames=("num_segments", "out_segments"))
-def _consensus_columns_wire_jit(wire_obs, depths, dict_tab, ln_error_pre_umi,
-                                num_segments, out_segments):
-    """Hard-column consensus: a flat wire-format observation stream with
-    per-column depths -> per-column (qual|suspect u8, 2-bit winner) packed.
-
-    The device never sees easy columns (the native classify resolved them
-    at byte-scan cost, fgumi_native.cc fgumi_consensus_classify); this
-    kernel gets only the compute-worthy pileup columns, so the upload is
-    ~1 byte per OBSERVATION of the hard few percent instead of 1 byte per
-    position of everything."""
-    one_hot, delta = _wire_terms(wire_obs, dict_tab)
-    return _columns_body(one_hot, delta, depths, ln_error_pre_umi,
-                         num_segments, out_segments)
-
-
-@_lazy_jit(static_argnames=("num_segments", "out_segments"))
-def _consensus_columns_raw_jit(codes_obs, quals_obs, depths, correct_tab,
-                               err_tab, ln_error_pre_umi, num_segments,
-                               out_segments):
-    """2 B/observation fallback of the hard-column kernel (>63 distinct
-    quals in the stream): raw codes+quals, N_CODE marks pad rows."""
-    one_hot, delta = _observation_terms(codes_obs, quals_obs, correct_tab,
-                                        err_tab)
-    return _columns_body(one_hot, delta, depths, ln_error_pre_umi,
-                         num_segments, out_segments)
 
 
 @_lazy_jit(static_argnames=("num_segments",))
@@ -2613,6 +2503,26 @@ def pad_segments_gather(codes: np.ndarray, quals: np.ndarray,
     return codes_dev, quals_dev, seg_ids, starts, F_pad, N
 
 
+def split_row_balanced(counts, dp):
+    """Job boundaries for dp contiguous row-balanced shards over segments of
+    `counts` rows each: (dp+1,) indices into the job list.
+
+    The target-crossing job goes to whichever side leaves the row split
+    closer to the target (plain searchsorted+1 can collapse a 2-job batch
+    onto one device).
+    """
+    n_jobs = len(counts)
+    cum = np.cumsum(counts)
+    total = int(cum[-1])
+    targets = (np.arange(1, dp) * total) // dp
+    i = np.searchsorted(cum, targets, side="left")
+    prev = np.where(i > 0, cum[np.maximum(i - 1, 0)], 0)
+    jb = i + ((cum[np.minimum(i, n_jobs - 1)] - targets)
+              <= (targets - prev))
+    jb = np.concatenate(([0], jb, [n_jobs]))
+    return np.minimum(np.maximum.accumulate(jb), n_jobs)
+
+
 def pad_segments_mesh(codes2d: np.ndarray, quals2d: np.ndarray,
                       counts: np.ndarray, mesh):
     """Chunked global row layout for the shard_map wire kernels.
@@ -2634,8 +2544,6 @@ def pad_segments_mesh(codes2d: np.ndarray, quals2d: np.ndarray,
     ``gather[j]`` is family j's row in the (dp * F_loc, ...) shard-ordered
     device output (resolve_segments_wire applies it).
     """
-    from ..consensus.fast import split_row_balanced
-
     counts = np.asarray(counts, dtype=np.int64)
     starts = np.concatenate(([0], np.cumsum(counts)))
     J = len(counts)
@@ -2697,6 +2605,87 @@ class _WirePlan:
         self.new = new
         self.staging = staging
         self.filter_mode = filter_mode
+
+
+def _full_column_ok(counts) -> bool:
+    """Whether the full-column kernels' uint16 depth fetch can hold every
+    family of the batch."""
+    return bool(np.max(counts) < 65536)
+
+
+def _predicted_s():
+    """The cost model's predicted dispatch seconds for this thread's latest
+    routing decision (stamped into the timeline), None when it was forced."""
+    from .router import ROUTER
+
+    pred = ROUTER.last_prediction()
+    return pred[0] if pred else None
+
+
+def _no_extras() -> dict:
+    """``want_extras``' fifth element where nothing stayed on the device."""
+    return {"suspect": None, "resident": None, "gather": None}
+
+
+class PendingSegments:
+    """One submitted segment batch between its dispatch and its columns:
+    what :meth:`ConsensusKernel.submit_ragged` / ``submit_dense`` return.
+
+    Holds the feeder ticket (``HOST_DISPATCH`` on the host route: the native
+    f64 engine then computes the batch at resolve time) and what resolve
+    needs of the batch: the unpadded dense row views and the (J+1,) segment
+    row boundaries. The padded device layout (segment ids, buckets, the
+    mesh's shard order) stays behind it."""
+
+    __slots__ = ("kernel", "ticket", "codes2d", "quals2d", "starts")
+
+    def __init__(self, kernel, ticket, codes2d, quals2d, starts):
+        self.kernel = kernel
+        self.ticket = ticket
+        self.codes2d = codes2d
+        self.quals2d = quals2d
+        self.starts = starts
+
+    def resolve(self, want_extras: bool = False):
+        """(winner, qual, depth, errors) (J, L) arrays, exact on every
+        route; ``want_extras`` appends resolve_segments_wire's dict (all
+        None unless a resident dispatch completed on the device)."""
+        k = self.kernel
+        if self.ticket is HOST_DISPATCH:
+            out = k.resolve_segments(HOST_DISPATCH, self.codes2d,
+                                     self.quals2d, self.starts)
+            return out + (_no_extras(),) if want_extras else out
+        return k.resolve_segments_wire(self.ticket, self.codes2d,
+                                       self.quals2d, self.starts,
+                                       want_extras=want_extras)
+
+    def resolve_filtered(self):
+        """The fused-filter resolve (consensus/device_filter.py):
+        resolve_segments_wire_filtered's ``("stats", stats, resident)``
+        where the fused kernel ran, ``("columns", winner, qual, depth,
+        errors)`` on every other route."""
+        if self.ticket is HOST_DISPATCH:
+            return ("columns",) + self.resolve()
+        return self.kernel.resolve_segments_wire_filtered(
+            self.ticket, self.codes2d, self.quals2d, self.starts)
+
+    def discard(self):
+        """Hand an unresolved dispatch back, as a resolver that ran out of
+        time does, without waiting for the device: the feeder slot and the
+        resident-byte accounting of a batch nobody will fetch."""
+        ticket = self.ticket
+        if ticket is HOST_DISPATCH:
+            return
+        DEVICE_STATS.end_in_flight(ticket.slot, 0, 0.0)
+        DEVICE_FEEDER.abandon(ticket)
+        try:
+            dev = ticket.wait(0)
+        except Exception:  # noqa: BLE001 - nothing of it to release
+            # still queued or running (the feeder discards it when it
+            # ends), or the dispatch itself failed
+            return
+        if isinstance(dev[-1], ResidentHandles):
+            dev[-1].release()
 
 
 def _unpack_device_result(packed: np.ndarray):
@@ -2984,22 +2973,6 @@ class ConsensusKernel:
             # on the unpadded rows it receives
             return HOST_DISPATCH
 
-    def dispatch_segments(self, codes2d, quals2d, counts):
-        """Pad + dispatch ragged segments, or skip both in host mode.
-
-        The one-stop shop for single-device callers holding dense (N, L)
-        rows and per-segment counts: returns (dev, starts) for the matching
-        resolve_segments(dev, codes2d, quals2d, starts) call. In host mode
-        no padded copies are built and no DEVICE_STATS pad rows are charged
-        — the native f64 engine reads the dense rows directly."""
-        if self.host_mode():
-            starts = np.concatenate(([0], np.cumsum(counts)))
-            return HOST_DISPATCH, starts
-        codes_dev, quals_dev, seg_ids, starts, F_pad = pad_segments(
-            codes2d, quals2d, counts)
-        return (self.device_call_segments(codes_dev, quals_dev, seg_ids,
-                                          F_pad), starts)
-
     def pack_segments_wire(self, codes, quals, rows, L_max: int, counts):
         """pad_segments_gather plus the wire, in one native pass over the
         ragged rows: the single-device route's whole pack.
@@ -3034,6 +3007,82 @@ class ConsensusKernel:
             codes_dev, quals_dev = _gather_rows(codes, quals, rows, L_max,
                                                 N_pad)
         return codes_dev, quals_dev, seg_ids, starts, F_pad, N, None
+
+    def submit_ragged(self, codes, quals, rows, L_max: int, counts,
+                      route: str, filter_params=None) -> PendingSegments:
+        """Turn ragged rows into a dispatched batch (single device).
+
+        ``rows`` index the batch's packed (R, L_stride) ``codes``/``quals``
+        in segment order, ``counts`` rows per segment, ``L_max`` the
+        4-multiple width; ``route`` is the caller's ROUTER.decide_batch
+        verdict (``"host"`` where there is no device). The device route is
+        pack_segments_wire + device_call_segments_wire under one
+        ``engine.pack`` span that ends with the dispatch handed to the
+        feeder, a few microseconds after the timeline's pack_s stamp
+        (begin_in_flight), which it must agree with. ``filter_params``:
+        device_call_segments_wire's fused consensus→filter selection (the
+        caller gates it on every family being under 65536 rows); resolve
+        such a batch with :meth:`PendingSegments.resolve_filtered`."""
+        if route == "host":
+            # the native engine eats the batch CONCURRENTLY on the resolve
+            # pool, so e2e throughput is device + host, not min of the two.
+            # No pad, no device layout: it consumes ragged rows.
+            with span("engine.host_gather", rusage=True):
+                starts = np.concatenate(([0], np.cumsum(counts)))
+                return PendingSegments(
+                    self, HOST_DISPATCH,
+                    np.ascontiguousarray(codes[rows, :L_max]),
+                    np.ascontiguousarray(quals[rows, :L_max]), starts)
+        with span("engine.pack", rusage=True):
+            t_pack0 = time.monotonic()
+            codes_dev, quals_dev, seg_ids, starts, F_pad, N, prebuilt = \
+                self.pack_segments_wire(codes, quals, rows, L_max, counts)
+            ticket = self.device_call_segments_wire(
+                codes_dev, quals_dev, seg_ids, F_pad, len(counts),
+                pack_t0=t_pack0, full=_full_column_ok(counts),
+                pred_s=_predicted_s(), filter_params=filter_params,
+                prebuilt=prebuilt)
+            return PendingSegments(self, ticket, codes_dev[:N],
+                                   quals_dev[:N], starts)
+
+    def submit_dense(self, gather, counts, route: str, mesh=None,
+                     resident_thresholds=None) -> PendingSegments:
+        """Turn dense rows into a dispatched batch (one device or a mesh).
+
+        ``gather()`` returns the batch's dense (N, L) ``(codes2d,
+        quals2d)`` in segment order (L % 4 == 0); it runs inside the pack
+        span, so a caller's row copies are charged to the batch they
+        belong to. ``counts``/``route`` as in :meth:`submit_ragged`. The
+        device route pads (pad_segments, or pad_segments_mesh's chunked
+        layout where ``mesh`` has more than one device) under
+        ``engine.pack.gather`` and dispatches through
+        device_call_segments_wire. ``resident_thresholds``: that call's
+        (min_reads, min_qual) for the fused duplex combine; dropped where
+        a family of 65536 rows or more rules the full-column kernel out."""
+        if route == "host":
+            with span("engine.host_gather", rusage=True):
+                codes2d, quals2d = gather()
+            starts = np.concatenate(([0], np.cumsum(counts)))
+            return PendingSegments(self, HOST_DISPATCH, codes2d, quals2d,
+                                   starts)
+        full = _full_column_ok(counts)
+        with span("engine.pack", rusage=True):
+            t_pack0 = time.monotonic()
+            with span("engine.pack.gather"):
+                codes2d, quals2d = gather()
+                if mesh is not None and mesh.size > 1:
+                    cd, qd, seg_ids, starts, F_pad, order = \
+                        pad_segments_mesh(codes2d, quals2d, counts, mesh)
+                else:
+                    cd, qd, seg_ids, starts, F_pad = pad_segments(
+                        codes2d, quals2d, counts)
+                    order = None
+            ticket = self.device_call_segments_wire(
+                cd, qd, seg_ids, F_pad, len(counts), pack_t0=t_pack0,
+                full=full,
+                resident_thresholds=resident_thresholds if full else None,
+                pred_s=_predicted_s(), mesh=mesh, mesh_gather=order)
+        return PendingSegments(self, ticket, codes2d, quals2d, starts)
 
     def device_call_segments_wire(self, codes2d_padded, quals2d_padded,
                                   seg_ids, num_segments: int, J: int,
@@ -3206,10 +3255,9 @@ class ConsensusKernel:
             def _dispatch(slot):
                 _ensure_jax()
                 if use_pallas:
-                    # Pallas manages its own blocks — upload donation is
-                    # a no-op here (not counted), and the wire dictionary
-                    # rides the kernel's scalar-prefetch channel (256 B)
-                    # instead of the constant cache.
+                    # the wire dictionary rides the kernel's
+                    # scalar-prefetch channel (256 B) instead of the
+                    # constant cache
                     t0 = time.monotonic()
                     with span("feeder.upload", bytes=upload):
                         prep = _pk.upload(wire, seg_ids, dict32, windows)
@@ -3220,7 +3268,6 @@ class ConsensusKernel:
                                               fparams, out_segments)
                         return (out[0], ResidentHandles(out[1:]))
                     return _pk.call_full(prep, pre, out_segments)
-                donate = upload_donation_enabled()
                 t0 = time.monotonic()
                 with span("feeder.upload", bytes=upload):
                     wd = jax.device_put(wire)
@@ -3228,30 +3275,21 @@ class ConsensusKernel:
                     dtab = CONST_CACHE.put("dict_tab", dict32)
                 DEVICE_STATS.note_upload(slot, time.monotonic() - t0)
                 DEVICE_STATS.add_kernel_backend(slot, "xla")
-                if donate:
-                    DEVICE_STATS.add_donated_upload()
                 if filt:
                     ld = jax.device_put(lens_pad)
                     etab = CONST_CACHE.put("filter_emin", fparams.emin_tab)
-                    fn = (_consensus_segments_wire_filter_donated_jit
-                          if donate else _consensus_segments_wire_filter_jit)
-                    out = fn(wd, sd, dtab, pre, mr, mq, ld,
-                             fparams.min_reads, etab, fparams.min_base_q,
-                             np.int32(1 if fparams.per_base else 0),
-                             num_segments, out_segments)
+                    out = _consensus_segments_wire_filter_jit(
+                        wd, sd, dtab, pre, mr, mq, ld,
+                        fparams.min_reads, etab, fparams.min_base_q,
+                        np.int32(1 if fparams.per_base else 0),
+                        num_segments, out_segments)
                     return (out[0], ResidentHandles(out[1:]))
                 if resident:
-                    fn = (_consensus_segments_wire_resident_donated_jit
-                          if donate
-                          else _consensus_segments_wire_resident_jit)
-                    out = fn(wd, sd, dtab, pre, mr, mq, num_segments,
-                             out_segments)
+                    out = _consensus_segments_wire_resident_jit(
+                        wd, sd, dtab, pre, mr, mq, num_segments,
+                        out_segments)
                     return out[:4] + (ResidentHandles(out[4:]),)
-                if full:
-                    fn = (_consensus_segments_wire_full_donated_jit
-                          if donate else _consensus_segments_wire_full_jit)
-                    return fn(wd, sd, dtab, pre, num_segments, out_segments)
-                fn = (_consensus_segments_wire_donated_jit if donate
+                fn = (_consensus_segments_wire_full_jit if full
                       else _consensus_segments_wire_jit)
                 return fn(wd, sd, dtab, pre, num_segments, out_segments)
         else:
@@ -3265,7 +3303,6 @@ class ConsensusKernel:
 
             def _dispatch(slot):
                 _ensure_jax()
-                donate = upload_donation_enabled()
                 t0 = time.monotonic()
                 with span("feeder.upload", bytes=upload):
                     cd = jax.device_put(cp)
@@ -3274,14 +3311,8 @@ class ConsensusKernel:
                     ct, et = tables_dev()
                 DEVICE_STATS.note_upload(slot, time.monotonic() - t0)
                 DEVICE_STATS.add_kernel_backend(slot, "xla")
-                if donate:
-                    DEVICE_STATS.add_donated_upload()
-                if full:
-                    fn = (_consensus_segments_packed2_full_donated_jit
-                          if donate else _consensus_segments_packed2_full_jit)
-                else:
-                    fn = (_consensus_segments_packed2_donated_jit
-                          if donate else _consensus_segments_packed2_jit)
+                fn = (_consensus_segments_packed2_full_jit if full
+                      else _consensus_segments_packed2_jit)
                 return fn(cd, qd, sd, ct, et, pre, num_segments,
                           out_segments)
         return _WirePlan(_dispatch, upload, new, staging,
@@ -3458,10 +3489,7 @@ class ConsensusKernel:
             else:
                 out = self._recover_segments(failure, codes2d, quals2d,
                                              starts, _split_depth)
-            if want_extras:
-                return out + ({"suspect": None, "resident": None,
-                               "gather": None},)
-            return out
+            return out + (_no_extras(),) if want_extras else out
         from .breaker import BREAKER
 
         BREAKER.record_success()
@@ -3564,8 +3592,7 @@ class ConsensusKernel:
                 resident.release()
                 resident = None
             if want_extras:
-                return winner, qual, depth, errors, {
-                    "suspect": None, "resident": None, "gather": None}
+                return winner, qual, depth, errors, _no_extras()
         if want_extras:
             return winner, qual, depth, errors, {"suspect": suspect,
                                                  "resident": resident,
@@ -3846,222 +3873,6 @@ class ConsensusKernel:
             exc, len(starts) - 1)
         return self._host_engine_complete(codes2d, quals2d, starts)
 
-    # --------------------------------------------------- hard-column hybrid
-
-    def dispatch_hard_columns(self, codes2d: np.ndarray, quals2d: np.ndarray,
-                              starts: np.ndarray):
-        """Classify + async-dispatch: the production device path (round 5).
-
-        The native classify (fgumi_consensus_classify) resolves easy
-        columns on host at byte-scan cost and exports the hard few percent
-        as a compact observation stream; only that stream crosses the link
-        (~2 orders of magnitude fewer bytes than whole pileups). Returns an
-        opaque pending resolved by resolve_hard_columns (possibly with no
-        device work at all when every column was easy)."""
-        from ..native import batch as nb
-
-        t_pack0 = time.monotonic()  # classify + wire build == pack time
-        host = self._host()
-        if host._tab1 is None:
-            host._build_tables()
-        t = self.tables
-        with np.errstate(invalid="ignore"):
-            delta64 = np.asarray(t.adjusted_correct, np.float64) - \
-                np.asarray(t.adjusted_error_per_alt, np.float64)
-        winner, qual, depth, errors, hard_idx, hard_depth, hard_counts, \
-            hc, hq = nb.consensus_classify(
-                codes2d, quals2d, starts, delta64, host.g_sat,
-                host.qual_const, MIN_PHRED, host._tab1[0], host._tab1[1],
-                host._tab2[0], host._tab2[1])
-        easy = (winner, qual, depth, errors)  # int32 end to end
-        C = len(hard_idx)
-        if C == 0:
-            with self._counter_lock:
-                self.total_positions += winner.size
-            return ("cols_done", easy)
-        M = len(hc)
-        N_pad = _pad_rows(M)
-        C_pad = max(8, SHAPE_REGISTRY.bucket_segments(C))
-        # fetch-slice step: a multiple of 4 (the 2-bit winner packs 4
-        # columns per byte) that divides the fetch into <= ~8 slice shapes
-        m_out = max(4 * (C_pad // 32), 4)
-        C_out = min(-(-C // m_out) * m_out, C_pad)
-        depths_dev = np.zeros(C_pad, dtype=np.int32)
-        depths_dev[:C] = hard_depth
-        depths_dev[C_pad - 1] += N_pad - M  # pad obs fold into the last id
-        DEVICE_STATS.add_dispatch(M * 16 + C_pad * 40)
-        DEVICE_STATS.add_pad(M, N_pad)
-        pre = self._pre
-        tables_dev = self._tables_dev
-        w = build_wire(hc.reshape(1, -1), hq.reshape(1, -1), self._delta94)
-        if w is not None:
-            wire, dict64 = w
-            wire_pad = np.full(N_pad, WIRE_INVALID, dtype=np.uint8)
-            wire_pad[:M] = wire.ravel()
-            upload = wire_pad.nbytes + depths_dev.nbytes
-            new = SHAPE_REGISTRY.observe("colsw", N_pad, C_pad, C_out)
-
-            def _dispatch(slot):
-                _ensure_jax()
-                t0 = time.monotonic()
-                wd = jax.device_put(wire_pad)
-                dd = jax.device_put(depths_dev)
-                dtab = CONST_CACHE.put("dict_tab", dict64)
-                DEVICE_STATS.note_upload(slot, time.monotonic() - t0)
-                return _consensus_columns_wire_jit(wd, dd, dtab, pre,
-                                                   C_pad, C_out)
-        else:
-            codes_pad = np.full(N_pad, N_CODE, dtype=np.uint8)
-            codes_pad[:M] = hc
-            quals_pad = np.zeros(N_pad, dtype=np.uint8)
-            quals_pad[:M] = hq
-            upload = codes_pad.nbytes + quals_pad.nbytes + depths_dev.nbytes
-            new = SHAPE_REGISTRY.observe("colsr", N_pad, C_pad, C_out)
-
-            def _dispatch(slot):
-                _ensure_jax()
-                t0 = time.monotonic()
-                cd = jax.device_put(codes_pad)
-                qd = jax.device_put(quals_pad)
-                dd = jax.device_put(depths_dev)
-                ct, et = tables_dev()
-                DEVICE_STATS.note_upload(slot, time.monotonic() - t0)
-                return _consensus_columns_raw_jit(cd, qd, dd, ct, et,
-                                                  pre, C_pad, C_out)
-        slot = DEVICE_STATS.begin_in_flight(
-            upload, pack_s=time.monotonic() - t_pack0)
-        with SHAPE_REGISTRY.attribute_compiles(new):
-            ticket = DEVICE_FEEDER.submit(
-                lambda: device_retry_call(lambda: _dispatch(slot),
-                                          "hard-column dispatch"),
-                upload_bytes=upload, slot=slot)
-        return ("cols_dev", easy, hard_idx, hard_depth, hard_counts, hc, hq,
-                ticket)
-
-    def resolve_hard_columns(self, pending):
-        """Fetch + scatter a dispatch_hard_columns pending.
-
-        Returns (winner, qual, depth, errors) (J, L) with hard columns
-        filled from the device result and suspects recomputed exactly by
-        the f64 oracle over the exported observation stream."""
-        if pending[0] == "cols_done":
-            return pending[1]
-        _, easy, hard_idx, hard_depth, hard_counts, hc, hq, ticket = pending
-        winner, qual, depth, errors = easy
-        C = len(hard_idx)
-        t0 = time.monotonic()
-        fetched = 0
-        failure = None
-        deadline = ticket_deadline_s(ticket)
-        try:
-            dev = ticket.wait(deadline)
-            left = None if deadline is None else \
-                max(deadline - (time.monotonic() - t0), 1.0)
-            qs, wp = _fetch_with_deadline(dev, left)
-            fetched = qs.nbytes + wp.nbytes
-        except BaseException as e:  # noqa: BLE001 - recovered below
-            failure = e
-        finally:
-            DEVICE_STATS.end_in_flight(ticket.slot, fetched,
-                                       time.monotonic() - t0)
-            if isinstance(failure, DeadlineExceeded):
-                DEVICE_FEEDER.abandon(ticket)
-            else:
-                DEVICE_FEEDER.mark_resolved(ticket)
-        if failure is not None:
-            from .breaker import BREAKER
-
-            overran = isinstance(failure, DeadlineExceeded)
-            if not overran and not (_is_oom(failure)
-                                    or _is_transient(failure)):
-                raise failure
-            # degrade: the exported observation stream is exactly what the
-            # host f64 patch path consumes — recompute every hard column
-            # there (native guaranteed: classify already required it)
-            if overran:
-                DEVICE_STATS.add_deadline_fallback()
-                BREAKER.record_deadline_overrun()
-            else:
-                DEVICE_STATS.add_host_fallback()
-                if not _is_oom(failure):
-                    BREAKER.record_transient_failure()
-            log.warning(
-                "device dispatch %s (%s: %s); resolving "
-                "%d hard columns on the native f64 host engine",
-                "overran its deadline" if overran
-                else "failed after retries",
-                type(failure).__name__, failure, C)
-            self._patch_hard_columns(
-                np.ones(C, dtype=bool), hard_idx, hard_depth, hc, hq,
-                winner.ravel(), qual.ravel(), depth.ravel(), errors.ravel())
-            with self._counter_lock:
-                self.total_positions += winner.size
-                self.fallback_positions += C
-            return winner, qual, depth, errors
-        from .breaker import BREAKER
-
-        BREAKER.record_success()
-        w_col, q_col, suspect = unpack_result_split(
-            qs.reshape(1, -1), wp.reshape(1, -1), 1)
-        w_col = w_col.ravel()[:C].astype(np.uint8)
-        q_col = q_col.ravel()[:C].astype(np.uint8)
-        suspect = suspect.ravel()[:C]
-        e_col = hard_depth - hard_counts[np.arange(C), w_col]
-        wf = winner.ravel()
-        qf = qual.ravel()
-        df = depth.ravel()
-        ef = errors.ravel()
-        wf[hard_idx] = w_col
-        qf[hard_idx] = q_col
-        df[hard_idx] = hard_depth
-        ef[hard_idx] = e_col
-        with self._counter_lock:
-            self.total_positions += winner.size
-            self.fallback_positions += int(suspect.sum())
-        if suspect.any():
-            self._patch_hard_columns(suspect, hard_idx, hard_depth, hc, hq,
-                                     wf, qf, df, ef)
-        return winner, qual, depth, errors
-
-    @staticmethod
-    def _concat_aranges(counts):
-        """Concatenated arange(0, c_i) for each count, no Python loop."""
-        counts = np.asarray(counts, dtype=np.int64)
-        total = int(counts.sum())
-        offs = np.repeat(np.concatenate(([0], np.cumsum(counts)[:-1])),
-                         counts)
-        return np.arange(total, dtype=np.int64) - offs
-
-    def _patch_hard_columns(self, suspect, hard_idx, hard_depth, hc, hq,
-                            wf, qf, df, ef):
-        """Exact f64 recompute of suspect hard columns from the exported
-        observation stream.
-
-        Each suspect column becomes one length-1 segment of the native f64
-        host engine (its observations are a run of depth-R "reads" of
-        length 1, in the original read order, so the Kahan accumulation
-        order matches the oracle exactly — the engine's bit-exactness
-        contract covers this shape like any other). The engine resolves
-        them in one native pass + one vectorized oracle epilogue for its
-        own borderline positions, replacing a per-read Python loop that
-        dominated the patch cost. The native library is guaranteed here:
-        every pending came from dispatch_hard_columns, whose classify pass
-        already required it."""
-        obs_starts = np.concatenate(([0], np.cumsum(hard_depth)))
-        sus = np.nonzero(suspect)[0]
-        lo = obs_starts[sus]
-        counts = obs_starts[sus + 1] - lo
-        total = int(counts.sum())
-        rows = np.repeat(lo, counts) + self._concat_aranges(counts)
-        starts = np.concatenate(([0], np.cumsum(counts)))
-        w, q, d, e = self._host().call_segments(
-            hc[rows].reshape(total, 1), hq[rows].reshape(total, 1), starts)
-        flat = hard_idx[sus]
-        wf[flat] = w.ravel()
-        qf[flat] = q.ravel()
-        df[flat] = d.ravel()
-        ef[flat] = e.ravel()
-
     def device_call_segments_sharded(self, codes3d, quals3d, seg_ids2d,
                                      num_segments: int, mesh):
         """Dispatch (dp, N, L) rows, one contiguous family shard per device.
@@ -4238,51 +4049,23 @@ class ConsensusKernel:
 
 
 def route_and_call_segments(kernel: "ConsensusKernel", codes2d, quals2d,
-                            counts, starts, mesh=None):
+                            counts, mesh=None):
     """Route one dense (N, L) segment batch through the adaptive offload
-    policy and resolve it synchronously: the host f64 engine, the round-5
-    hard-column export (FGUMI_TPU_DEVICE_PATH=columns), or the full-column
-    wire kernel (default device route; sharded over ``mesh`` when one with
-    > 1 device is passed). The one shared implementation of the decide ->
-    dispatch -> resolve sequence for the synchronous callers (fast_codec,
-    the classic vanilla path); the async engines (simplex pending chunks,
-    duplex defer/resident) keep their specialized flows but share
-    ROUTER.decide_batch and the same dispatch entry points."""
+    policy and resolve it synchronously: the host f64 engine or the
+    full-column wire kernel (sharded over ``mesh`` when one with > 1 device
+    is passed). Decide -> submit_dense -> resolve for the synchronous
+    callers (fast_codec, the classic vanilla path); the async engines
+    (simplex pending chunks, duplex defer/resident) call the same entries
+    and resolve later."""
     from .router import ROUTER
 
-    mesh_active = mesh is not None and mesh.size > 1
     route = "host"
     if not kernel.host_mode():
-        route = ROUTER.decide_batch(kernel, codes2d.shape[0], len(counts),
-                                    codes2d.shape[1],
-                                    devices=mesh.size if mesh_active else 1)
-    if route == "host":
-        return kernel.resolve_segments(HOST_DISPATCH, codes2d, quals2d,
-                                       starts)
-    if device_path() == "columns":
-        # the round-5 comparison route is single-device by design (the
-        # compact hard-column stream defeats the point of sharding); an
-        # explicit FGUMI_TPU_DEVICE_PATH=columns wins over the mesh
-        pending = kernel.dispatch_hard_columns(codes2d, quals2d, starts)
-        return kernel.resolve_hard_columns(pending)
-    t_pack0 = time.monotonic()
-    pred = ROUTER.last_prediction()
-    full = bool(np.max(counts) < 65536)
-    if mesh_active:
-        cg, qg, seg_g, _st, f_loc, gather = pad_segments_mesh(
-            codes2d, quals2d, counts, mesh)
-        ticket = kernel.device_call_segments_wire(
-            cg, qg, seg_g, f_loc, len(counts), pack_t0=t_pack0, full=full,
-            pred_s=pred[0] if pred else None, mesh=mesh,
-            mesh_gather=gather)
-        return kernel.resolve_segments_wire(ticket, codes2d, quals2d,
-                                            starts)
-    cd, qd, seg_ids, _sp, f_pad = pad_segments(codes2d, quals2d, counts)
-    ticket = kernel.device_call_segments_wire(
-        cd, qd, seg_ids, f_pad, len(counts), pack_t0=t_pack0,
-        full=full,
-        pred_s=pred[0] if pred else None)
-    return kernel.resolve_segments_wire(ticket, codes2d, quals2d, starts)
+        route = ROUTER.decide_batch(
+            kernel, codes2d.shape[0], len(counts), codes2d.shape[1],
+            devices=mesh.size if mesh is not None else 1)
+    return kernel.submit_dense(lambda: (codes2d, quals2d), counts, route,
+                               mesh=mesh).resolve()
 
 
 # ------------------------------------------------------ fused device stages

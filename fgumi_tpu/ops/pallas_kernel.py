@@ -55,8 +55,7 @@ CPU runs fall back to XLA so tier-1 latency is unchanged. The XLA kernels
 remain the permanent parity oracle. Covered dispatch kinds: the
 full-column wire kernel (``segwfp``) and the fused consensus→filter
 kernel (``segwxp``); resident/duplex, mesh, packed2-fallback, and gather
-dispatches stay XLA. Upload donation is a no-op here (Pallas manages its
-own blocks); the donation knob simply does not apply.
+dispatches stay XLA.
 """
 
 import functools

@@ -281,78 +281,6 @@ def bench_device_filter(out):
             full_bytes / max(fused_bytes, 1), 2)
 
 
-def bench_donation(out):
-    """Upload-donation regression check (ISSUE 11): after warm-up, the
-    donated wire route must mint ZERO new host staging buffers per
-    dispatch (the recycled pool serves every upload), and — on backends
-    that implement donation — the donated upload pages must be recycled
-    by XLA, observed as a stable ``unsafe_buffer_pointer`` across
-    back-to-back dispatches. The pointer check skips cleanly on the CPU
-    backend (XLA ignores donation there)."""
-    import os
-
-    import numpy as np
-
-    from fgumi_tpu.ops.datapath import STAGING_POOL
-    from fgumi_tpu.ops.kernel import ConsensusKernel, pad_segments
-    from fgumi_tpu.ops.tables import quality_tables
-
-    kernel = ConsensusKernel(quality_tables(45, 40))
-    kernel.set_force_device()
-    rng = np.random.default_rng(29)
-    codes, quals = _family_pileup(rng, 512, 4, 100)
-    counts = np.full(512, 4, dtype=np.int64)
-    starts = (np.arange(513) * 4).astype(np.int64)
-
-    os.environ["FGUMI_TPU_DONATE"] = "1"
-    try:
-        import warnings
-
-        def run_once():
-            cd, qd, seg, _st, F = pad_segments(codes, quals, counts)
-            t = kernel.device_call_segments_wire(cd, qd, seg, F, 512,
-                                                 full=True)
-            kernel.resolve_segments_wire(t, codes, quals, starts)
-
-        with warnings.catch_warnings():
-            # the cpu backend warns that donation is unimplemented —
-            # expected there; the staging-pool half still applies
-            warnings.simplefilter("ignore")
-            run_once()  # warm-up: pool + jit cache populated
-            allocs0 = STAGING_POOL.allocs
-            for _ in range(4):
-                run_once()
-            out["donation_staging_allocs_after_warmup"] = \
-                STAGING_POOL.allocs - allocs0  # acceptance: 0
-
-            import jax
-
-            if jax.default_backend() == "cpu":
-                out["donation_ptr_check"] = \
-                    "skipped (cpu backend does not implement donation)"
-            else:
-                from fgumi_tpu.ops.datapath import CONST_CACHE
-                from fgumi_tpu.ops.kernel import (
-                    _consensus_segments_wire_full_donated_jit, build_wire)
-
-                cd, qd, seg, _st, F = pad_segments(codes, quals, counts)
-                wire, dict32 = build_wire(cd, qd,
-                                          kernel._delta94)
-                dtab = CONST_CACHE.put("dict_tab", dict32)
-                ptrs = []
-                for _ in range(3):
-                    wd = jax.device_put(wire)
-                    sd = jax.device_put(seg)
-                    ptrs.append(wd.unsafe_buffer_pointer())
-                    r = _consensus_segments_wire_full_donated_jit(
-                        wd, sd, dtab, kernel._pre, F, F)
-                    jax.block_until_ready(r)
-                    del r, wd, sd
-                out["donation_ptr_stable"] = ptrs[1] == ptrs[2]
-    finally:
-        os.environ.pop("FGUMI_TPU_DONATE", None)
-
-
 def bench_datapath(out):
     """Dispatch-prep regression bench: operand preparation must be a no-op
     for the common already-contiguous case (the old unconditional
@@ -898,7 +826,6 @@ def main():
                         bench_full_column,
                         bench_pallas,
                         bench_device_filter,
-                        bench_donation,
                         bench_coalesce,
                         bench_sharded,
                         bench_datapath,

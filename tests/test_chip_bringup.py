@@ -105,8 +105,6 @@ def test_compile_failure_ends_the_dispatch(monkeypatch):
         raise boom
 
     monkeypatch.setattr(K, "_consensus_segments_wire_full_jit", _raise)
-    monkeypatch.setattr(K, "_consensus_segments_wire_full_donated_jit",
-                        _raise)
     monkeypatch.setenv("FGUMI_TPU_KERNEL", "xla")
     before = K.DEVICE_STATS.snapshot()
     cd, qd, seg, _st, f_pad = K.pad_segments(codes, quals, counts)

@@ -252,10 +252,12 @@ def test_sync_batch_dispatch_wedge_bounded(kernel, monkeypatch):
 
 
 def test_sync_segment_dispatch_wedge_bounded(kernel, monkeypatch):
-    """The classic-segments sync path (dispatch_segments/resolve_segments)
-    degrades a dispatch-time wedge to HOST_DISPATCH under the deadline."""
+    """The classic-segments sync path (pad_segments + device_call_segments
+    / resolve_segments) degrades a dispatch-time wedge to HOST_DISPATCH
+    under the deadline."""
     codes, quals, counts, starts = _batch(seed=9)
-    dev, st = kernel.dispatch_segments(codes, quals, counts)
+    cd, qd, seg, st, fpad = pad_segments(codes, quals, counts)
+    dev = kernel.device_call_segments(cd, qd, seg, fpad)
     ref = kernel.resolve_segments(dev, codes, quals, st)  # warm
 
     monkeypatch.setenv("FGUMI_TPU_DISPATCH_DEADLINE_S", "0.2:0.4")
@@ -264,7 +266,7 @@ def test_sync_segment_dispatch_wedge_bounded(kernel, monkeypatch):
     faults.reset()
     before = DEVICE_STATS.deadline_fallbacks
     t0 = time.monotonic()
-    dev, st = kernel.dispatch_segments(codes, quals, counts)
+    dev = kernel.device_call_segments(cd, qd, seg, fpad)
     out = kernel.resolve_segments(dev, codes, quals, st)
     assert time.monotonic() - t0 < 1.4
     for a, b in zip(ref, out):

@@ -3,7 +3,7 @@
 Covers: the exact integer reformulation of the per-base error-rate mask,
 fused-mask-kernel parity against the host twin at bucket-edge shapes,
 CLI forced-route parity for all three engines (`--device-filter` output
-record-identical to <engine> | filter), donation byte-identity under
+record-identical to <engine> | filter), upload byte-identity under
 retry and OOM batch-halving, staging-pool reuse, and resident-byte
 release on the deadline/abandon path (PR 7 wedge machinery).
 """
@@ -36,8 +36,8 @@ pytestmark = pytest.mark.skipif(not nb.available(),
 
 @pytest.fixture(autouse=True)
 def _clean(monkeypatch):
-    for var in ("FGUMI_TPU_FAULT", "FGUMI_TPU_DONATE",
-                "FGUMI_TPU_DEVICE_FILTER", "FGUMI_TPU_ROUTE"):
+    for var in ("FGUMI_TPU_FAULT", "FGUMI_TPU_DEVICE_FILTER",
+                "FGUMI_TPU_ROUTE"):
         monkeypatch.delenv(var, raising=False)
     faults.reset()
     from fgumi_tpu.ops import breaker as breaker_mod
@@ -267,17 +267,15 @@ def test_cli_codec_parity(tmp_path):
     assert _records(fused) == _records(ref)
 
 
-# --------------------------------------------- donation under retry/halving
+# ----------------------------------------------- upload under retry/halving
 
-def test_donation_identity_under_retry(grouped_bam, tmp_path, monkeypatch,
-                                       recwarn):
-    """A donated upload that fails transiently must be RE-UPLOADED on
-    retry (the donated device buffer died with the failed dispatch; the
-    host staging buffer survives) — output identical to a clean run."""
+def test_upload_identity_under_retry(grouped_bam, tmp_path, monkeypatch):
+    """An upload whose dispatch fails transiently must be RE-UPLOADED on
+    retry (the device buffers died with the failed dispatch; the host
+    staging buffer survives) — output identical to a clean run."""
     ref = _two_stage_simplex(grouped_bam, tmp_path)
     monkeypatch.setenv("FGUMI_TPU_ROUTE", "device")
     monkeypatch.setenv("FGUMI_TPU_HOST_ENGINE", "0")
-    monkeypatch.setenv("FGUMI_TPU_DONATE", "1")
     monkeypatch.setenv("FGUMI_TPU_DEVICE_BACKOFF_S", "0.01")
     monkeypatch.setenv("FGUMI_TPU_FAULT", "device.dispatch:raise:1.0:1")
     # deadlines off: on a slow shared-core host the deadline-abandon path
@@ -285,35 +283,24 @@ def test_donation_identity_under_retry(grouped_bam, tmp_path, monkeypatch,
     # completes via host fallback with retries == 0 — a different,
     # separately-tested degrade path)
     monkeypatch.setenv("FGUMI_TPU_DISPATCH_DEADLINE_S", "0")
-    import warnings
-
-    out = str(tmp_path / "donated_retry.bam")
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore")  # cpu backend ignores donation
-        assert cli_main(["simplex", "-i", grouped_bam, "-o", out,
-                         "--min-reads", "1", "--device-filter"]
-                        + _FILT) == 0
+    out = str(tmp_path / "upload_retry.bam")
+    assert cli_main(["simplex", "-i", grouped_bam, "-o", out,
+                     "--min-reads", "1", "--device-filter"] + _FILT) == 0
     assert _records(out) == _records(ref)
     assert DEVICE_STATS.retries >= 1
 
 
-def test_donation_identity_under_oom_halving(grouped_bam, tmp_path,
-                                             monkeypatch):
+def test_upload_identity_under_oom_halving(grouped_bam, tmp_path,
+                                           monkeypatch):
     """An injected RESOURCE_EXHAUSTED halves the batch and re-dispatches
-    both halves; donated or not, the output bytes cannot change."""
+    both halves; the output bytes cannot change."""
     ref = _two_stage_simplex(grouped_bam, tmp_path)
     monkeypatch.setenv("FGUMI_TPU_ROUTE", "device")
     monkeypatch.setenv("FGUMI_TPU_HOST_ENGINE", "0")
-    monkeypatch.setenv("FGUMI_TPU_DONATE", "1")
     monkeypatch.setenv("FGUMI_TPU_FAULT", "device.dispatch:oom:1.0:1")
-    import warnings
-
-    out = str(tmp_path / "donated_oom.bam")
-    with warnings.catch_warnings():
-        warnings.simplefilter("ignore")
-        assert cli_main(["simplex", "-i", grouped_bam, "-o", out,
-                         "--min-reads", "1", "--device-filter"]
-                        + _FILT) == 0
+    out = str(tmp_path / "upload_oom.bam")
+    assert cli_main(["simplex", "-i", grouped_bam, "-o", out,
+                     "--min-reads", "1", "--device-filter"] + _FILT) == 0
     assert _records(out) == _records(ref)
     assert DEVICE_STATS.batch_splits >= 1
 

@@ -54,7 +54,8 @@ def test_global_mesh_runs_the_kernel():
     from fgumi_tpu.ops.kernel import ConsensusKernel
     from fgumi_tpu.ops import oracle
     from fgumi_tpu.ops.tables import quality_tables
-    from fgumi_tpu.consensus.fast import pack_shards_sp, split_row_balanced
+    from fgumi_tpu.consensus.fast import pack_shards_sp
+    from fgumi_tpu.ops.kernel import split_row_balanced
 
     mesh = make_global_mesh(sp=2)
     t = quality_tables(45, 40)

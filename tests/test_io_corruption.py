@@ -194,3 +194,27 @@ def test_prefetch_close_surfaces_pending_error(tmp_path, caplog):
     with caplog.at_level(logging.WARNING, logger="fgumi_tpu"):
         pf.close()
     assert any("pending read error" in r.message for r in caplog.records)
+
+
+@pytest.mark.parametrize("path_kind", ["native", "zlib"])
+def test_member_crc32_checked(small_bam, tmp_path, monkeypatch, path_kind):
+    """A member whose payload inflates to ISIZE bytes but whose stored
+    CRC32 does not match them is a diagnosed input error on the native
+    inflate and on the zlib fallback alike (ROADMAP D1)."""
+    from fgumi_tpu import native
+
+    if path_kind == "zlib":
+        monkeypatch.setattr(native, "get_lib", lambda: None)
+    elif native.get_lib() is None:
+        pytest.skip("native library unavailable")
+    data = bytearray(open(small_bam, "rb").read())
+    bsize = int.from_bytes(data[16:18], "little") + 1  # first member
+    data[bsize - 8] ^= 0x01  # its CRC32, not its payload
+    bad = str(tmp_path / "crc.bam")
+    with open(bad, "wb") as f:
+        f.write(bytes(data))
+    r = BgzfReader(open(bad, "rb"), owns_fileobj=True, name=bad)
+    with pytest.raises(InputFormatError, match="crc.bam"):
+        while r.read(1 << 16):
+            pass
+    r.close()

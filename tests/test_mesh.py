@@ -137,8 +137,8 @@ def _ragged(seed, n_fam=37, L=24):
 
 @pytest.mark.parametrize("dp,sp", [(4, 2), (2, 4), (8, 1)])
 def test_segments_dp_sp_matches_single_device(tables, dp, sp):
-    from fgumi_tpu.consensus.fast import pack_shards_sp, split_row_balanced
-    from fgumi_tpu.ops.kernel import pad_segments
+    from fgumi_tpu.consensus.fast import pack_shards_sp
+    from fgumi_tpu.ops.kernel import pad_segments, split_row_balanced
 
     kernel = ConsensusKernel(tables)
     codes, quals, counts, starts = _ragged(91)

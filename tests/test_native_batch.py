@@ -431,52 +431,6 @@ def test_bktree_pairs_native():
         assert set(zip(i.tolist(), j.tolist())) == truth
 
 
-def test_consensus_classify_native_easy_hard():
-    """fgumi_consensus_classify under the sanitizer lane: easy columns match
-    the full native engine; hard export streams reconstruct the columns."""
-    nb = pytest.importorskip("fgumi_tpu.native.batch")
-    if not nb.available():
-        pytest.skip("native library unavailable")
-    from fgumi_tpu.constants import MIN_PHRED
-    from fgumi_tpu.ops.host_kernel import HostConsensusEngine
-    from fgumi_tpu.ops.tables import quality_tables
-
-    t = quality_tables(45, 40)
-    eng = HostConsensusEngine(t)
-    eng._build_tables()
-    rng = np.random.default_rng(7)
-    counts = rng.integers(1, 7, size=25)
-    starts = np.concatenate(([0], np.cumsum(counts))).astype(np.int64)
-    N = int(starts[-1])
-    L = 16
-    codes = rng.integers(0, 5, size=(N, L)).astype(np.uint8)
-    quals = rng.integers(0, 50, size=(N, L)).astype(np.uint8)
-    with np.errstate(invalid="ignore"):
-        delta = np.asarray(t.adjusted_correct) - \
-            np.asarray(t.adjusted_error_per_alt)
-    w, q, d, e, hidx, hdep, hcnt, hc, hq = nb.consensus_classify(
-        codes, quals, starts, delta, eng.g_sat, eng.qual_const, MIN_PHRED,
-        eng._tab1[0], eng._tab1[1], eng._tab2[0], eng._tab2[1])
-    fw, fq, fd, fe, _n = eng.call_segments_counted(codes, quals, starts)
-    easy = np.ones(w.size, bool)
-    easy[hidx] = False
-    em = easy.reshape(w.shape)
-    np.testing.assert_array_equal(w[em], fw[em])
-    np.testing.assert_array_equal(q[em], fq[em])
-    np.testing.assert_array_equal(d[em], fd[em].astype(np.int32))
-    np.testing.assert_array_equal(e[em], fe[em].astype(np.int32))
-    # hard streams: per-column valid observations in row order
-    os_ = np.concatenate(([0], np.cumsum(hdep)))
-    for k, o in enumerate(hidx):
-        jj, ii = divmod(int(o), L)
-        col = codes[starts[jj]:starts[jj + 1], ii]
-        cq = quals[starts[jj]:starts[jj + 1], ii]
-        v = col != 4
-        assert (hc[os_[k]:os_[k + 1]] == col[v]).all()
-        assert (hq[os_[k]:os_[k + 1]] == np.minimum(cq[v], 93)).all()
-        assert (hcnt[k] == np.bincount(col[v], minlength=4)[:4]).all()
-
-
 def test_codec_combine_matches_numpy_oracle():
     """fgumi_codec_combine must be bit-exact with consensus/codec.py
     combine_arrays (the classic-path oracle) across adversarial inputs:

@@ -34,7 +34,7 @@ needs_pallas = pytest.mark.skipif(not pk.available(),
 @pytest.fixture(autouse=True)
 def _clean(monkeypatch):
     for var in ("FGUMI_TPU_KERNEL", "FGUMI_TPU_PALLAS_UNAVAILABLE",
-                "FGUMI_TPU_AUDIT", "FGUMI_TPU_FAULT", "FGUMI_TPU_DONATE"):
+                "FGUMI_TPU_AUDIT", "FGUMI_TPU_FAULT"):
         monkeypatch.delenv(var, raising=False)
     monkeypatch.setenv("FGUMI_TPU_HOST_ENGINE", "0")
     monkeypatch.setenv("FGUMI_TPU_ROUTE", "device")
@@ -261,7 +261,7 @@ def test_filter_audit_divergence_repairs_and_trips(monkeypatch):
 
     # unfaulted full-column reference for the repair tuple
     from fgumi_tpu.ops.kernel import route_and_call_segments
-    ref = route_and_call_segments(kernel, codes, quals, counts, starts)
+    ref = route_and_call_segments(kernel, codes, quals, counts)
 
     base_resident = DEVICE_STATS.resident_bytes
     monkeypatch.setenv("FGUMI_TPU_AUDIT", "all")

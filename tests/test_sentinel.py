@@ -61,7 +61,7 @@ def _batch(seed=0, n_fam=4, fam=3, L=48):
 
 
 def _resolve(kern, codes, quals, counts, starts):
-    return K.route_and_call_segments(kern, codes, quals, counts, starts)
+    return K.route_and_call_segments(kern, codes, quals, counts)
 
 
 # ---------------------------------------------------------------------------

@@ -329,53 +329,86 @@ def test_pad_out_segments():
             assert out - j <= max(f_pad // 8, 1)
 
 
-def hard_roundtrip(kernel, codes, quals, starts):
-    pending = kernel.dispatch_hard_columns(codes, quals, starts)
-    return kernel.resolve_hard_columns(pending)
+def ragged_layout(rng, codes, quals, stride_extra=8, spare_rows=5):
+    """The rows of a dense (N, L) batch scattered over a wider, longer
+    packed array as the engines hold them: (codes_pk, quals_pk, rows, L)."""
+    N, L = codes.shape
+    rows = np.sort(rng.choice(N + spare_rows, N, replace=False)).astype(
+        np.int64)
+    codes_pk = rng.integers(0, 5, size=(N + spare_rows, L + stride_extra))
+    quals_pk = rng.integers(2, 40, size=codes_pk.shape)
+    codes_pk, quals_pk = codes_pk.astype(np.uint8), quals_pk.astype(np.uint8)
+    codes_pk[rows, :L] = codes
+    quals_pk[rows, :L] = quals
+    return codes_pk, quals_pk, rows, L
 
 
-@pytest.mark.parametrize("seed,err", [(0, 0.1), (1, 0.4), (2, 0.02)])
-def test_hard_columns_parity(device_kernel, seed, err):
-    """The classify+export device path must match the oracle exactly on
-    every column — easy (native tables/saturation) and hard (device f32 +
-    guard band + oracle patch) alike."""
-    rng = np.random.default_rng(seed)
-    codes, quals, counts, starts = make_ragged(rng, J=40, L=32, err=err)
-    w, q, d, e = hard_roundtrip(device_kernel, codes, quals, starts)
-    assert_oracle_parity(codes, quals, starts, w, q, d, e)
+def submit_and_resolve(kernel, entry, codes, quals, counts, route="device",
+                       rng=None, **dense_kwargs):
+    """One batch through ConsensusKernel.submit_ragged / submit_dense and
+    its PendingSegments.resolve()."""
+    if entry == "ragged":
+        codes_pk, quals_pk, rows, L = ragged_layout(
+            rng or np.random.default_rng(0), codes, quals)
+        pending = kernel.submit_ragged(codes_pk, quals_pk, rows, L, counts,
+                                       route)
+    else:
+        pending = kernel.submit_dense(lambda: (codes, quals), counts, route,
+                                      **dense_kwargs)
+    return pending.resolve(want_extras=bool(
+        dense_kwargs.get("resident_thresholds")))
 
 
-def test_hard_columns_parity_edge_quals(device_kernel):
-    """Q0 observations (NaN-poisoned lanes -> hard -> suspect -> oracle)."""
-    rng = np.random.default_rng(9)
-    codes, quals, counts, starts = make_ragged(rng, J=24, L=16, err=0.4,
-                                               qlo=0, qhi=8)
-    w, q, d, e = hard_roundtrip(device_kernel, codes, quals, starts)
-    assert_oracle_parity(codes, quals, starts, w, q, d, e)
+# every route a submitted batch can take x the entry that takes it there;
+# the pairs that do not exist (resident and mesh dispatches start from
+# dense rows only) are left out
+_SUBMIT_CASES = [
+    ("host", "ragged"), ("host", "dense"),
+    ("wire", "ragged"), ("wire", "dense"),
+    ("packed2", "ragged"), ("packed2", "dense"),
+    ("resident", "dense"), ("mesh", "dense"),
+]
 
 
-def test_hard_columns_all_easy(device_kernel):
-    """A clean unanimous pileup never dispatches (cols_done path)."""
-    rng = np.random.default_rng(2)
-    codes, quals, counts, starts = make_ragged(rng, J=16, L=20, err=0.0,
-                                               n_rate=0.0, qlo=30, qhi=40)
-    pending = device_kernel.dispatch_hard_columns(codes, quals, starts)
-    assert pending[0] == "cols_done"
-    w, q, d, e = device_kernel.resolve_hard_columns(pending)
-    assert_oracle_parity(codes, quals, starts, w, q, d, e)
+@pytest.mark.parametrize("route,entry", _SUBMIT_CASES)
+def test_submit_resolve_parity(device_kernel, route, entry):
+    """submit -> PendingSegments.resolve against the f64 oracle on the
+    host route, the XLA wire kernel, the packed2 fallback (> 63 distinct
+    quals), the resident (duplex) kernel and a mesh of the 8 virtual CPU
+    devices."""
+    rng = np.random.default_rng(13)
+    wide = route == "packed2"
+    codes, quals, counts, starts = make_ragged(
+        rng, J=37, L=32, err=0.15, qlo=2, qhi=88 if wide else 45)
+    assert (len(np.unique(quals)) > 63) == wide
+    kwargs = {}
+    if route == "resident":
+        kwargs["resident_thresholds"] = (1, 2)
+    if route == "mesh":
+        import jax
+
+        from fgumi_tpu.parallel.mesh import make_mesh
+
+        if len(jax.devices()) < 8:
+            pytest.skip("needs 8 virtual devices")
+        kwargs["mesh"] = make_mesh(jax.devices()[:8], dp=4, sp=2)
+    before = DEVICE_STATS.snapshot()["dispatches"]
+    out = submit_and_resolve(
+        device_kernel, entry, codes, quals, counts,
+        route="host" if route == "host" else "device", rng=rng, **kwargs)
+    assert DEVICE_STATS.snapshot()["dispatches"] - before == \
+        (0 if route == "host" else 1)
+    if route == "resident":
+        extras = out[4]
+        assert extras["resident"] is not None
+        assert extras["suspect"].shape == (len(counts), codes.shape[1])
+        extras["resident"].release()
+    assert_oracle_parity(codes, quals, starts, *out[:4])
+    assert DEVICE_STATS.in_flight_count() == 0
 
 
-def test_hard_columns_wide_qual_fallback(device_kernel):
-    """>63 distinct quals in the hard stream takes the raw 2 B/obs jit."""
-    rng = np.random.default_rng(7)
-    codes, quals, counts, starts = make_ragged(rng, J=40, L=16, err=0.5,
-                                               qlo=2, qhi=88)
-    assert len(np.unique(quals)) > 63
-    w, q, d, e = hard_roundtrip(device_kernel, codes, quals, starts)
-    assert_oracle_parity(codes, quals, starts, w, q, d, e)
-
-
-def test_hard_columns_deep_family(device_kernel):
+@pytest.mark.parametrize("entry", ["ragged", "dense"])
+def test_submit_deep_family(device_kernel, entry):
     """One deep family (256 reads) among shallow ones: depth-class
     bucketing in the suspect patch, saturation on the deep column."""
     rng = np.random.default_rng(5)
@@ -389,13 +422,29 @@ def test_hard_columns_deep_family(device_kernel):
     codes[errs] = rng.integers(0, 4, size=int(errs.sum()))
     codes = codes.astype(np.uint8)
     quals = rng.integers(5, 45, size=(N, L)).astype(np.uint8)
-    w, q, d, e = hard_roundtrip(device_kernel, codes, quals, starts)
+    w, q, d, e = submit_and_resolve(device_kernel, entry, codes, quals,
+                                    counts, rng=rng)
+    assert d[0].max() == 256
+    assert_oracle_parity(codes, quals, starts, w, q, d, e)
+
+
+@pytest.mark.parametrize("entry", ["ragged", "dense"])
+def test_submit_clean_unanimous(device_kernel, entry):
+    """A clean unanimous pileup (no errors, no N, high quals): every
+    column saturates, none is an error, and the device's answer stands."""
+    rng = np.random.default_rng(2)
+    codes, quals, counts, starts = make_ragged(rng, J=16, L=20, err=0.0,
+                                               n_rate=0.0, qlo=30, qhi=40)
+    w, q, d, e = submit_and_resolve(device_kernel, entry, codes, quals,
+                                    counts, rng=rng)
+    assert not e.any()
+    np.testing.assert_array_equal(d, np.repeat(counts, 20).reshape(-1, 20))
     assert_oracle_parity(codes, quals, starts, w, q, d, e)
 
 
 def test_hybrid_routes_overflow_to_host(monkeypatch):
     """When in-flight dispatches exceed the cap, _dispatch_jobs must route
-    the batch to the host f64 engine (HOST_DISPATCH pending)."""
+    the batch to the host f64 engine (a pending round HOST_DISPATCH)."""
     monkeypatch.setenv("FGUMI_TPU_HOST_ENGINE", "0")
     monkeypatch.setenv("FGUMI_TPU_HYBRID", "1")
     from fgumi_tpu.ops.kernel import HOST_DISPATCH
@@ -485,10 +534,7 @@ from fgumi_tpu.ops import kernel as K
 def boom(*a, **kw):
     raise RuntimeError("injected device failure")
 
-# break every whole-batch device kernel the engines can route to: the
-# full-column wire kernels (round-6 default) and the hard-column export
-K._consensus_columns_wire_jit = boom
-K._consensus_columns_raw_jit = boom
+# break every whole-batch device kernel the engines can route to
 K._consensus_segments_wire_jit = boom
 K._consensus_segments_wire_full_jit = boom
 K._consensus_segments_wire_resident_jit = boom

@@ -418,11 +418,11 @@ class FastDuplexCaller:
         slow = {}  # fallback molecule -> its wire bytes, filled below
 
         def finish():
-            tb, tq, d16, e16, codes2d, ctx = finish_ss()
+            tb, tq, d16, e16, ctx = finish_ss()
             try:
                 return self._stage2(
                     batch, span, gb, sizes, n_paired, slow, live_mol,
-                    seg_map, seg_len, tb, tq, d16, e16, codes2d, vrows,
+                    seg_map, seg_len, tb, tq, d16, e16, codes, vrows,
                     vstarts, L_max, ctx)
             finally:
                 if ctx is not None:
@@ -496,25 +496,25 @@ class FastDuplexCaller:
 
     def _ss_consensus(self, codes, quals, vrows, c1, vstarts, nseg, L_max):
         """Start all segs' single-strand consensus: the single-read host
-        pass, the row gather, and the multi-read segs' dispatch (handed to
-        the feeder, or kept for the native host engine when there is no
-        device or the router prices the batch host-side).
+        pass and the multi-read segs' dispatch, packed from their rows
+        where they lie in ``codes`` / ``quals`` (handed to the feeder, or
+        kept for the native host engine when there is no device or the
+        router prices the batch host-side).
 
         Returns (finish, discard). finish() completes it on the resolving
         thread: thresholded bases/quals and i16-clamped depth/error arrays,
-        (nseg, L_max) each, the valid rows' codes, and the fused
-        strand-combine context (None unless the full-column device route
-        kept stage-1 outputs resident). discard() hands a wire dispatch
-        back when finish() will never run (None, or a no-op on the host
-        route, when there is none)."""
+        (nseg, L_max) each, and the fused strand-combine context (None
+        unless the full-column device route kept stage-1 outputs
+        resident). discard() hands a wire dispatch back when finish() will
+        never run (None, or a no-op on the host route, when there is
+        none)."""
         opts = self.ss.options
         tb = np.zeros((nseg, L_max), dtype=np.uint8)
         tq = np.zeros((nseg, L_max), dtype=np.uint8)
         d16 = np.zeros((nseg, L_max), dtype=np.int32)
         e16 = np.zeros((nseg, L_max), dtype=np.int32)
         if not nseg:
-            codes2d = np.zeros((0, L_max), dtype=np.uint8)
-            return (lambda: (tb, tq, d16, e16, codes2d, None)), None
+            return (lambda: (tb, tq, d16, e16, None)), None
 
         single = c1 == 1
         multi = np.nonzero(~single)[0]
@@ -531,23 +531,10 @@ class FastDuplexCaller:
                 d16[single] = np.minimum(d, I16_MAX).astype(np.int32)
                 # errors are zero for single-read consensus
         if not len(multi):
-            with _span("engine.host_gather", rusage=True):
-                codes2d = np.ascontiguousarray(codes[vrows])
-            return (lambda: (tb, tq, d16, e16, codes2d, None)), None
+            return (lambda: (tb, tq, d16, e16, None)), None
         counts_m = c1[multi]
-
-        codes2d = None
-
-        def gather():
-            """The valid rows in seg order (stage 2 recounts errors over
-            them) and, out of those, the multi-read segs' rows, dense."""
-            nonlocal codes2d
-            codes2d = np.ascontiguousarray(codes[vrows])
-            quals2d = np.ascontiguousarray(quals[vrows])
-            rows_m = np.concatenate(
-                [np.arange(vstarts[s], vstarts[s + 1]) for s in multi])
-            return (np.ascontiguousarray(codes2d[rows_m]),
-                    np.ascontiguousarray(quals2d[rows_m]))
+        # vrows is in seg order: the multi-read segs' rows are a mask of it
+        rows_m = vrows[np.repeat(~single, c1)]
 
         def finish_with(w, q_, d, e, ctx):
             with _span("resolve.unpack", rusage=True):
@@ -557,7 +544,7 @@ class FastDuplexCaller:
                 tq[multi] = q_m
                 d16[multi] = np.minimum(d, I16_MAX).astype(np.int32)
                 e16[multi] = np.minimum(e, I16_MAX).astype(np.int32)
-            return tb, tq, d16, e16, codes2d, ctx
+            return tb, tq, d16, e16, ctx
 
         route = "host"
         if not self.kernel.host_mode():
@@ -572,18 +559,27 @@ class FastDuplexCaller:
         # Device: the whole multi-seg pileup crosses the link once in the
         # full-column wire layout; with the resident variant the
         # thresholded outputs stay on device for the fused strand combine.
-        # A > 1-device mesh runs the same kernels shard_map-wrapped
-        # (families over dp, read rows over sp with one psum); the
-        # resident arrays then live sharded along dp and the combine's
-        # indices are mapped through the shard-order gather below.
+        # One device packs the ragged rows in one native pass (fast.py
+        # _pack_and_dispatch's choice). A > 1-device mesh runs the same
+        # kernels shard_map-wrapped (families over dp, read rows over sp
+        # with one psum) from dense rows; the resident arrays then live
+        # sharded along dp and the combine's indices are mapped through
+        # the shard-order gather below.
         import os
 
         comb_env = os.environ.get("FGUMI_TPU_DUPLEX_COMBINE",
                                   "auto").strip().lower()
-        pending = self.kernel.submit_dense(
-            gather, counts_m, route, mesh=self.mesh,
-            resident_thresholds=None if comb_env == "host" else
-            (opts.min_reads, opts.min_consensus_base_quality))
+        resident = None if comb_env == "host" else \
+            (opts.min_reads, opts.min_consensus_base_quality)
+        if self.mesh is not None:
+            pending = self.kernel.submit_dense(
+                lambda: (np.ascontiguousarray(codes[rows_m]),
+                         np.ascontiguousarray(quals[rows_m])),
+                counts_m, route, mesh=self.mesh, resident_thresholds=resident)
+        else:
+            pending = self.kernel.submit_ragged(
+                codes, quals, rows_m, L_max, counts_m, route,
+                resident_thresholds=resident)
 
         def resolve():
             w, q_, d, e, extras = pending.resolve(want_extras=True)
@@ -605,7 +601,7 @@ class FastDuplexCaller:
     # ---------------------------------------------------------------- stage 2
 
     def _stage2(self, batch, span, gb, sizes, n_paired, slow, live_mol,
-                seg_map, seg_len, tb, tq, d16, e16, codes2d, vrows, vstarts,
+                seg_map, seg_len, tb, tq, d16, e16, codes, vrows, vstarts,
                 L_max, combine_ctx=None) -> bytes:
         """Strand combination + serialization, molecule order preserved.
 
@@ -731,7 +727,7 @@ class FastDuplexCaller:
         if K:
             fast_blob, rec_end = self._serialize_outputs(
                 batch, span, gb, out_specs, seg_map, seg_len, tb, tq, d16,
-                e16, codes2d, vrows, vstarts, L_max, col, combine_ctx)
+                e16, codes, vrows, vstarts, L_max, col, combine_ctx)
             stats.add_consensus_reads(K)
         if not slow:
             return fast_blob
@@ -750,7 +746,7 @@ class FastDuplexCaller:
         return b"".join(parts)
 
     def _serialize_outputs(self, batch, span, gb, out_specs, seg_map, seg_len,
-                           tb, tq, d16, e16, codes2d, vrows, vstarts, L_max,
+                           tb, tq, d16, e16, codes, vrows, vstarts, L_max,
                            col, combine_ctx=None):
         """Combine + native-serialize the K fast output reads (order kept).
 
@@ -764,7 +760,7 @@ class FastDuplexCaller:
         K = len(out_specs)
         with _span("engine.duplex.combine", rusage=True) as sp:
             mols, flags, kinds, aseg, bseg, lens, out_b, out_q, out_e = \
-                self._combine_outputs(out_specs, tb, tq, e16, codes2d,
+                self._combine_outputs(out_specs, tb, tq, e16, codes, vrows,
                                       vstarts, L_max, col, combine_ctx, sp)
 
         # serializer strand inputs: 'a' side = dup.ab_consensus (the alive /
@@ -804,11 +800,14 @@ class FastDuplexCaller:
         del keep_alive
         return blob, rec_end
 
-    def _combine_outputs(self, out_specs, tb, tq, e16, codes2d, vstarts,
+    def _combine_outputs(self, out_specs, tb, tq, e16, codes, vrows, vstarts,
                          L_max, col, combine_ctx, sp):
         """The K output reads' arrays: the specs as columns, and the reads'
-        bases, quals and errors, strand-combined or passed through. ``sp``
-        is the ``engine.duplex.combine`` span this runs in."""
+        bases, quals and errors, strand-combined or passed through.
+        ``codes`` is the batch's packed rows and ``vrows`` the valid ones
+        in seg order (seg s is ``vrows[vstarts[s]:vstarts[s + 1]]``): the
+        error recount reads them in place. ``sp`` is the
+        ``engine.duplex.combine`` span this runs in."""
         K = len(out_specs)
         mols = np.array([s[0] for s in out_specs], dtype=np.int64)
         flags = np.array([s[1] for s in out_specs], dtype=np.int32)
@@ -856,7 +855,7 @@ class FastDuplexCaller:
             for side in (ca, cb):
                 # one native pass per side over each output's seg row range
                 _, e_side = nb.segment_depth_errors_ranges(
-                    codes2d, rb8, vstarts[:-1][side], vstarts[1:][side])
+                    codes, vrows, rb8, vstarts[:-1][side], vstarts[1:][side])
                 errs += e_side
             errs[rb8 == N_CODE] = 0
             errs[~in_len] = 0

@@ -25,7 +25,7 @@ _lock = threading.Lock()
 _lib = None
 _lib_failed = False
 # must equal fgumi_abi_version() in fgumi_native.cc (stale-.so guard)
-_ABI_VERSION = 16
+_ABI_VERSION = 17
 
 
 def build() -> bool:
@@ -138,7 +138,7 @@ def _declare(lib):
         [p, p, p, ctypes.c_long, ctypes.c_long, p, p])
     lib.fgumi_segment_depth_errors_ranges.restype = None
     lib.fgumi_segment_depth_errors_ranges.argtypes = (
-        [p, p, p, p, ctypes.c_long, ctypes.c_long, p, p])
+        [p, ctypes.c_long, p, p, p, p, ctypes.c_long, ctypes.c_long, p, p])
     lib.fgumi_build_wire.restype = ctypes.c_long
     lib.fgumi_build_wire.argtypes = (
         [p, p, ctypes.c_long, p, ctypes.c_long, ctypes.c_long, ctypes.c_long]

@@ -397,6 +397,12 @@ def wire_inputs_ok(codes: np.ndarray, quals: np.ndarray, rows=None,
     return not len(rows) or (int(rows.min()) >= 0 and int(rows.max()) < R)
 
 
+def _row_stride(mat: np.ndarray) -> int:
+    """Bytes from one row of a vetted uint8 matrix to the next (numpy
+    reports any stride for a matrix of one row)."""
+    return mat.strides[0] if len(mat) > 1 else mat.shape[1]
+
+
 def build_wire(codes: np.ndarray, quals: np.ndarray, rows, n_pad: int,
                L: int, wire: np.ndarray, codes_dev: np.ndarray = None,
                quals_dev: np.ndarray = None):
@@ -418,8 +424,7 @@ def build_wire(codes: np.ndarray, quals: np.ndarray, rows, n_pad: int,
                          f"({n_pad}, {L}) uint8 arrays holding {n} rows")
     vals = np.empty(64, dtype=np.uint8)
     k = get_lib().fgumi_build_wire(
-        codes.ctypes.data, quals.ctypes.data,
-        codes.strides[0] if len(codes) > 1 else codes.shape[1],
+        codes.ctypes.data, quals.ctypes.data, _row_stride(codes),
         None if rows is None else rows.ctypes.data, n, n_pad, L,
         wire.ctypes.data,
         None if codes_dev is None else codes_dev.ctypes.data,
@@ -428,19 +433,33 @@ def build_wire(codes: np.ndarray, quals: np.ndarray, rows, n_pad: int,
     return None if k < 0 else vals[:k]
 
 
-def segment_depth_errors_ranges(codes2d: np.ndarray, winner: np.ndarray,
-                                lo, hi):
-    """segment_depth_errors over explicit [lo[j], hi[j]) row ranges."""
+def segment_depth_errors_ranges(codes: np.ndarray, rows: np.ndarray,
+                                winner: np.ndarray, lo, hi):
+    """segment_depth_errors over explicit [lo[j], hi[j]) ranges of a row
+    list: entry r of a range is ``codes[rows[r], :L]``, read where it lies
+    in the batch's packed (R, stride) uint8 ``codes`` (rows contiguous, at
+    least ``L`` = ``winner.shape[1]`` wide), so the rows are never copied
+    out. ``rows`` is an int64 vector of in-range row numbers and every
+    range lies within it."""
     lib = get_lib()
     J, L = winner.shape
-    depth = np.empty((J, L), dtype=np.int32)
-    errors = np.empty((J, L), dtype=np.int32)
-    codes2d = _as_c(codes2d, np.uint8)
-    winner = _as_c(winner, np.uint8)
     lo = _as_c(lo, np.int64)
     hi = _as_c(hi, np.int64)
+    ranges_ok = len(lo) == len(hi) == J and (
+        not J or (int(lo.min()) >= 0 and int(hi.max()) <= len(rows)))
+    # the wire pass reads the same packed arrays through the same kind of
+    # row list: one vetting for both
+    if not (ranges_ok and wire_inputs_ok(codes, codes, rows, L)):
+        raise ValueError(
+            "segment_depth_errors_ranges: codes must be a uint8 matrix of "
+            f"contiguous rows at least {L} wide, rows in-range int64, and "
+            f"the {J} ranges within the {len(rows)} rows")
+    depth = np.empty((J, L), dtype=np.int32)
+    errors = np.empty((J, L), dtype=np.int32)
+    winner = _as_c(winner, np.uint8)
     lib.fgumi_segment_depth_errors_ranges(
-        _addr(codes2d), _addr(winner), _addr(lo), _addr(hi), J, L,
+        codes.ctypes.data, _row_stride(codes), rows.ctypes.data,
+        _addr(winner), _addr(lo), _addr(hi), J, L,
         _addr(depth), _addr(errors))
     return depth, errors
 

@@ -92,7 +92,7 @@ extern "C" {
 // ABI version for the stale-.so guard in __init__.py: bump whenever any
 // exported signature changes (a symbol probe alone cannot detect an
 // argument-list change in an existing function).
-long fgumi_abi_version() { return 16; }
+long fgumi_abi_version() { return 17; }
 
 // Candidate UMI pairs with hamming(A[i], B[j]) <= d over (n, L)/(m, L) byte
 // matrices, via the d+1-part pigeonhole (umi/assigners.py
@@ -1107,10 +1107,13 @@ void fgumi_segment_depth_errors(const uint8_t* codes, const uint8_t* winner,
   }
 }
 
-// fgumi_segment_depth_errors with explicit, possibly non-contiguous row
+// fgumi_segment_depth_errors with explicit, possibly non-contiguous
 // ranges [lo[j], hi[j]) per segment (the duplex exact-error pass sums a
-// molecule's two strand segs, which are not adjacent in the dense layout).
-void fgumi_segment_depth_errors_ranges(const uint8_t* codes,
+// molecule's two strand segs, which are not adjacent), over rows named by
+// an index: entry r of a range is row rows[r] of the batch's packed codes,
+// `stride` bytes a row (>= L), so no dense copy of the rows is made.
+void fgumi_segment_depth_errors_ranges(const uint8_t* codes, long stride,
+                                       const int64_t* rows,
                                        const uint8_t* winner,
                                        const int64_t* lo, const int64_t* hi,
                                        long J, long L, int32_t* depth,
@@ -1122,7 +1125,7 @@ void fgumi_segment_depth_errors_ranges(const uint8_t* codes,
     std::memset(drow, 0, static_cast<size_t>(L) * 4);
     std::memset(erow, 0, static_cast<size_t>(L) * 4);
     for (int64_t r = lo[j]; r < hi[j]; ++r) {
-      const uint8_t* crow = codes + r * L;
+      const uint8_t* crow = codes + rows[r] * stride;
       for (long i = 0; i < L; ++i) {
         const uint8_t c = crow[i];
         if (c != 4) {

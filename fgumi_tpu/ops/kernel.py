@@ -3009,7 +3009,8 @@ class ConsensusKernel:
         return codes_dev, quals_dev, seg_ids, starts, F_pad, N, None
 
     def submit_ragged(self, codes, quals, rows, L_max: int, counts,
-                      route: str, filter_params=None) -> PendingSegments:
+                      route: str, filter_params=None,
+                      resident_thresholds=None) -> PendingSegments:
         """Turn ragged rows into a dispatched batch (single device).
 
         ``rows`` index the batch's packed (R, L_stride) ``codes``/``quals``
@@ -3022,7 +3023,10 @@ class ConsensusKernel:
         (begin_in_flight), which it must agree with. ``filter_params``:
         device_call_segments_wire's fused consensus→filter selection (the
         caller gates it on every family being under 65536 rows); resolve
-        such a batch with :meth:`PendingSegments.resolve_filtered`."""
+        such a batch with :meth:`PendingSegments.resolve_filtered`.
+        ``resident_thresholds``: that call's (min_reads, min_qual) for the
+        fused duplex combine; dropped where a family of 65536 rows or more
+        rules the full-column kernel out."""
         if route == "host":
             # the native engine eats the batch CONCURRENTLY on the resolve
             # pool, so e2e throughput is device + host, not min of the two.
@@ -3033,13 +3037,16 @@ class ConsensusKernel:
                     self, HOST_DISPATCH,
                     np.ascontiguousarray(codes[rows, :L_max]),
                     np.ascontiguousarray(quals[rows, :L_max]), starts)
+        full = _full_column_ok(counts)
         with span("engine.pack", rusage=True):
+            count("engine.pack", "entry_ragged")
             t_pack0 = time.monotonic()
             codes_dev, quals_dev, seg_ids, starts, F_pad, N, prebuilt = \
                 self.pack_segments_wire(codes, quals, rows, L_max, counts)
             ticket = self.device_call_segments_wire(
                 codes_dev, quals_dev, seg_ids, F_pad, len(counts),
-                pack_t0=t_pack0, full=_full_column_ok(counts),
+                pack_t0=t_pack0, full=full,
+                resident_thresholds=resident_thresholds if full else None,
                 pred_s=_predicted_s(), filter_params=filter_params,
                 prebuilt=prebuilt)
             return PendingSegments(self, ticket, codes_dev[:N],
@@ -3056,9 +3063,8 @@ class ConsensusKernel:
         device route pads (pad_segments, or pad_segments_mesh's chunked
         layout where ``mesh`` has more than one device) under
         ``engine.pack.gather`` and dispatches through
-        device_call_segments_wire. ``resident_thresholds``: that call's
-        (min_reads, min_qual) for the fused duplex combine; dropped where
-        a family of 65536 rows or more rules the full-column kernel out."""
+        device_call_segments_wire. ``resident_thresholds`` as in
+        :meth:`submit_ragged`."""
         if route == "host":
             with span("engine.host_gather", rusage=True):
                 codes2d, quals2d = gather()
@@ -3067,6 +3073,7 @@ class ConsensusKernel:
                                    starts)
         full = _full_column_ok(counts)
         with span("engine.pack", rusage=True):
+            count("engine.pack", "entry_dense")
             t_pack0 = time.monotonic()
             with span("engine.pack.gather"):
                 codes2d, quals2d = gather()

@@ -263,3 +263,76 @@ def test_sharded_matches_single_device(tmp_path):
     mesh = make_mesh(dp=min(8, len(jax.devices())))
     for tb in (4096, 1 << 20):
         assert run(None, tb) == run(mesh, tb), tb
+
+
+#: what a batch's segments hold -> the simulator's parameters for it
+_SEGMENT_MIXES = {
+    "single_only": dict(reads_per_strand=1),
+    "multi_only": dict(reads_per_strand=3),
+    "both": dict(reads_per_strand=2, strand_bias_alpha=1.0,
+                 strand_bias_beta=1.0),
+}
+
+
+def record_batches(path, n_records):
+    """The file's records as RecordBatches of ``n_records`` (the reader
+    itself cuts no finer than a decoded chunk, a whole small file)."""
+    from fgumi_tpu.io.batch_reader import RecordBatch
+
+    with BamBatchReader(path) as reader:
+        for batch in reader:
+            ends = np.append(batch.rec_off, len(batch.buf))
+            for i in range(0, batch.n, n_records):
+                j = min(i + n_records, batch.n)
+                yield RecordBatch(
+                    bytearray(batch.buf[ends[i]:ends[j]]),
+                    np.ascontiguousarray(batch.rec_off[i:j] - ends[i]))
+
+
+@pytest.mark.parametrize("devices", [1, 2])
+@pytest.mark.parametrize("mix", list(_SEGMENT_MIXES))
+def test_device_entry_follows_the_mesh(tmp_path, monkeypatch, mix, devices):
+    """On the device route a batch of single-read segments only, of
+    multi-read segments only, and of both gives the per-molecule caller's
+    bytes; its multi-read rows enter through submit_ragged on one device
+    (``engine.pack`` counts ``entry_ragged`` a dispatch, ``entry_dense``
+    never) and through submit_dense on a two-device mesh (the reverse)."""
+    import jax
+
+    from fgumi_tpu.observe import trace
+    from fgumi_tpu.parallel.mesh import make_mesh
+
+    monkeypatch.setenv("FGUMI_TPU_HOST_ENGINE", "0")
+    monkeypatch.setenv("FGUMI_TPU_ROUTE", "device")
+    path = str(tmp_path / "dup.bam")
+    simulate_duplex_bam(path, num_molecules=90, seed=5, **_SEGMENT_MIXES[mix])
+    slow_out, _caller, _oc = run_slow(path)
+    mesh = make_mesh(jax.devices()[:2], dp=2) if devices == 2 else None
+
+    caller = make_caller()
+    fast = FastDuplexCaller(caller, b"MI", mesh=mesh)
+    trace.stop_trace()
+    trace.arm_spans()
+    try:
+        chunks = []
+        for batch in record_batches(path, 100):
+            chunks.extend(fast.process_batch(batch))
+        chunks.extend(fast.flush())
+        blob = b"".join(map(resolve_chunk, chunks))
+        by_name = trace.current_aggregate().snapshot()["by_name"]
+    finally:
+        trace.stop_trace()
+    assert blob == b"".join(len(r).to_bytes(4, "little") + r
+                            for r in slow_out)
+    if mix == "single_only":
+        assert "engine.pack" not in by_name
+        return
+    pack = by_name["engine.pack"]
+    took, other = (("entry_ragged", "entry_dense") if devices == 1
+                   else ("entry_dense", "entry_ragged"))
+    assert pack[took] == pack["count"] > 1
+    assert other not in pack
+    wires = pack.get("wire_native", 0) + pack.get("wire_numpy", 0)
+    assert wires == pack["count"]
+    if devices == 1:
+        assert pack["wire_native"] == pack["entry_ragged"]

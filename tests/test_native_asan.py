@@ -45,10 +45,14 @@ libasan = _runtime("libasan.so")
 libubsan = _runtime("libubsan.so")
 
 
-@pytest.mark.skipif(libasan is None or libubsan is None,
-                    reason="toolchain lacks ASAN/UBSAN runtimes")
-def test_native_suites_under_asan_ubsan(tmp_path):
-    so = str(tmp_path / "libfgumi_native_asan.so")
+pytestmark = pytest.mark.skipif(libasan is None or libubsan is None,
+                                reason="toolchain lacks ASAN/UBSAN runtimes")
+
+
+@pytest.fixture(scope="module")
+def sanitized_env(tmp_path_factory):
+    """The environment of a process that loads the sanitized library."""
+    so = str(tmp_path_factory.mktemp("asan") / "libfgumi_native_asan.so")
     build = subprocess.run(
         ["g++", "-O1", "-g", "-shared", "-fPIC", "-pthread",
          "-fsanitize=address,undefined", "-fno-sanitize-recover=all",
@@ -66,6 +70,11 @@ def test_native_suites_under_asan_ubsan(tmp_path):
         "JAX_PLATFORMS": "cpu",
         "PYTHONPATH": REPO,
     })
+    return env
+
+
+def test_native_suites_under_asan_ubsan(sanitized_env):
+    env = sanitized_env
     proc = subprocess.run(
         [sys.executable, "-m", "pytest", "-x", "-q"] + SANITIZED_SUITES,
         cwd=REPO, capture_output=True, text=True, timeout=900, env=env)
@@ -80,3 +89,37 @@ def test_native_suites_under_asan_ubsan(tmp_path):
     m = re.search(r"(\d+) passed", tail)
     assert m and int(m.group(1)) >= 20, \
         f"sanitized run passed too few tests (skip fallback?):\n{tail}"
+
+
+# the duplex error recount reads rows where they lie in the batch's packed
+# codes: the last listed row ends on the buffer's last byte, so a read of
+# `stride` bytes a row, or of a row past the list, is out of bounds
+_RANGES_TIGHT = """
+import numpy as np
+from fgumi_tpu.native import batch as nb
+
+assert nb.get_lib() is not None
+R, stride, L = 9, 40, 24
+buf = np.random.default_rng(3).integers(0, 5, (R - 1) * stride + L)
+buf = buf.astype(np.uint8)
+codes = np.lib.stride_tricks.as_strided(buf, (R, L), (stride, 1))
+rows = np.array([8, 0, 8, 3, 8], dtype=np.int64)
+lo = np.array([0, 2, 5, 0], dtype=np.int64)
+hi = np.array([2, 5, 5, 5], dtype=np.int64)
+winner = np.full((4, L), 1, dtype=np.uint8)
+depth, errors = nb.segment_depth_errors_ranges(codes, rows, winner, lo, hi)
+for j in range(4):
+    seg = codes[rows[lo[j]:hi[j]]]
+    assert (depth[j] == (seg != 4).sum(axis=0)).all()
+    assert (errors[j] == ((seg != 4) & (seg != 1)).sum(axis=0)).all()
+print("ranges ok")
+"""
+
+
+def test_depth_errors_ranges_tight_buffer_under_asan(sanitized_env):
+    proc = subprocess.run([sys.executable, "-c", _RANGES_TIGHT], cwd=REPO,
+                          capture_output=True, text=True, timeout=300,
+                          env=sanitized_env)
+    tail = (proc.stdout + "\n" + proc.stderr)[-4000:]
+    assert proc.returncode == 0 and "ranges ok" in proc.stdout, tail
+    assert "ERROR: AddressSanitizer" not in tail

@@ -458,3 +458,69 @@ def test_codec_combine_matches_numpy_oracle():
         for k, (r, g) in enumerate(zip(ref, got)):
             np.testing.assert_array_equal(np.asarray(r), np.asarray(g),
                                           err_msg=f"trial {trial} output {k}")
+
+
+def _depth_errors_numpy(codes, rows, winner, lo, hi):
+    """What fgumi_segment_depth_errors_ranges counts, in numpy."""
+    J, L = winner.shape
+    depth = np.zeros((J, L), dtype=np.int32)
+    errors = np.zeros((J, L), dtype=np.int32)
+    for j in range(J):
+        seg = codes[rows[lo[j]:hi[j]], :L]
+        valid = seg != 4
+        depth[j] = valid.sum(axis=0)
+        errors[j] = (valid & (seg != winner[j])).sum(axis=0)
+    return depth, errors
+
+
+@pytest.mark.parametrize("case", ["random", "empty_ranges", "repeated_rows",
+                                  "no_outputs"])
+def test_segment_depth_errors_ranges_reads_rows_in_place(case):
+    """The duplex error recount over a row list into the batch's packed
+    codes (stride wider than L) equals the dense form on a copy of those
+    rows, and numpy: random ranges, empty ones, a row listed twice, J = 0."""
+    from fgumi_tpu.native import batch as nb
+
+    rng = np.random.default_rng(17)
+    R, stride, L, n = 60, 40, 24, 45
+    codes = rng.integers(0, 5, size=(R, stride)).astype(np.uint8)
+    rows = rng.permutation(R)[:n].astype(np.int64)
+    if case == "repeated_rows":
+        rows[1::2] = rows[::2][:len(rows[1::2])]
+    J = 0 if case == "no_outputs" else 30
+    lo = rng.integers(0, n + 1, size=J).astype(np.int64)
+    hi = np.minimum(lo + rng.integers(0, 7, size=J), n).astype(np.int64)
+    if case == "empty_ranges":
+        hi[::2] = lo[::2]
+    winner = rng.integers(0, 5, size=(J, L)).astype(np.uint8)
+    depth, errors = nb.segment_depth_errors_ranges(codes, rows, winner, lo, hi)
+    want_d, want_e = _depth_errors_numpy(codes, rows, winner, lo, hi)
+    np.testing.assert_array_equal(depth, want_d)
+    np.testing.assert_array_equal(errors, want_e)
+    dense = np.ascontiguousarray(codes[rows, :L])
+    dense_d, dense_e = nb.segment_depth_errors_ranges(
+        dense, np.arange(n, dtype=np.int64), winner, lo, hi)
+    np.testing.assert_array_equal(depth, dense_d)
+    np.testing.assert_array_equal(errors, dense_e)
+    assert depth.shape == errors.shape == (J, L)
+
+
+@pytest.mark.parametrize("bad", ["row_past_end", "negative_row",
+                                 "range_past_rows", "rows_int32"])
+def test_segment_depth_errors_ranges_refuses_what_it_cannot_read(bad):
+    from fgumi_tpu.native import batch as nb
+
+    codes = np.zeros((8, 16), dtype=np.uint8)
+    rows = np.arange(8, dtype=np.int64)
+    winner = np.zeros((2, 12), dtype=np.uint8)
+    lo, hi = np.array([0, 4]), np.array([4, 8])
+    if bad == "row_past_end":
+        rows[3] = 8
+    elif bad == "negative_row":
+        rows[0] = -1
+    elif bad == "range_past_rows":
+        hi[1] = 9
+    else:
+        rows = rows.astype(np.int32)
+    with pytest.raises(ValueError, match="segment_depth_errors_ranges"):
+        nb.segment_depth_errors_ranges(codes, rows, winner, lo, hi)

@@ -344,29 +344,29 @@ def ragged_layout(rng, codes, quals, stride_extra=8, spare_rows=5):
 
 
 def submit_and_resolve(kernel, entry, codes, quals, counts, route="device",
-                       rng=None, **dense_kwargs):
+                       rng=None, **kwargs):
     """One batch through ConsensusKernel.submit_ragged / submit_dense and
     its PendingSegments.resolve()."""
     if entry == "ragged":
         codes_pk, quals_pk, rows, L = ragged_layout(
             rng or np.random.default_rng(0), codes, quals)
         pending = kernel.submit_ragged(codes_pk, quals_pk, rows, L, counts,
-                                       route)
+                                       route, **kwargs)
     else:
         pending = kernel.submit_dense(lambda: (codes, quals), counts, route,
-                                      **dense_kwargs)
+                                      **kwargs)
     return pending.resolve(want_extras=bool(
-        dense_kwargs.get("resident_thresholds")))
+        kwargs.get("resident_thresholds")))
 
 
 # every route a submitted batch can take x the entry that takes it there;
-# the pairs that do not exist (resident and mesh dispatches start from
-# dense rows only) are left out
+# the pair that does not exist (a mesh dispatch starts from dense rows
+# only) is left out
 _SUBMIT_CASES = [
     ("host", "ragged"), ("host", "dense"),
     ("wire", "ragged"), ("wire", "dense"),
     ("packed2", "ragged"), ("packed2", "dense"),
-    ("resident", "dense"), ("mesh", "dense"),
+    ("resident", "ragged"), ("resident", "dense"), ("mesh", "dense"),
 ]
 
 
@@ -405,6 +405,65 @@ def test_submit_resolve_parity(device_kernel, route, entry):
         extras["resident"].release()
     assert_oracle_parity(codes, quals, starts, *out[:4])
     assert DEVICE_STATS.in_flight_count() == 0
+
+
+@pytest.mark.parametrize("case", ["native", "numpy", "wide_quals"])
+def test_submit_ragged_resident_equals_dense(device_kernel, monkeypatch,
+                                             case):
+    """The duplex request (``resident_thresholds``) through submit_ragged
+    is the one through submit_dense on the same rows: the same kernel on
+    the same shapes, equal columns, suspects and resident arrays. Also
+    where the native pass declines its inputs (numpy packs instead), and
+    with more than 63 distinct quals, where both entries drop the request
+    and dispatch the packed-codes layout."""
+    from fgumi_tpu.native import batch as nb
+    from fgumi_tpu.ops.datapath import SHAPE_REGISTRY
+
+    if case == "numpy":
+        monkeypatch.setattr(nb, "wire_inputs_ok", lambda *a, **kw: False)
+    elif nb.get_lib() is None:
+        pytest.skip("no native library on this host")
+    rng = np.random.default_rng(29)
+    wide = case == "wide_quals"
+    codes, quals, counts, starts = make_ragged(
+        rng, J=37, L=32, err=0.15, qlo=2, qhi=88 if wide else 45)
+    codes_pk, quals_pk, rows, L = ragged_layout(rng, codes, quals)
+    shapes = []
+    observe = SHAPE_REGISTRY.observe
+    monkeypatch.setattr(
+        SHAPE_REGISTRY, "observe",
+        lambda kind, *dims: shapes.append((kind,) + dims) or observe(kind,
+                                                                     *dims))
+    got = {}
+    for entry in ("dense", "ragged"):
+        if entry == "dense":
+            pending = device_kernel.submit_dense(
+                lambda: (codes, quals), counts, "device",
+                resident_thresholds=(2, 10))
+        else:
+            pending = device_kernel.submit_ragged(
+                codes_pk, quals_pk, rows, L, counts, "device",
+                resident_thresholds=(2, 10))
+        *cols, extras = pending.resolve(want_extras=True)
+        resident = extras["resident"]
+        assert (resident is None) == wide
+        kept = []
+        if resident is not None:
+            kept = [np.asarray(a) for a in resident.arrays]
+            resident.release()
+        got[entry] = (cols, extras["suspect"], kept)
+    assert shapes[0] == shapes[1] and len(shapes) == 2
+    assert shapes[0][0] == ("segp2f" if wide else "segwr")
+    (cols_d, sus_d, kept_d), (cols_r, sus_r, kept_r) = \
+        got["dense"], got["ragged"]
+    for a, b in zip(cols_d + [sus_d] + kept_d, cols_r + [sus_r] + kept_r,
+                    strict=True):
+        np.testing.assert_array_equal(a, b)
+    assert sus_r.shape == (len(counts), L)
+    assert len(kept_r) == (0 if wide else 3)
+    assert_oracle_parity(codes, quals, starts, *cols_r)
+    assert DEVICE_STATS.in_flight_count() == 0
+    assert DEVICE_STATS.resident_bytes == 0
 
 
 @pytest.mark.parametrize("entry", ["ragged", "dense"])

@@ -11,6 +11,15 @@ any other CIGAR shape run the classic prepare() unchanged, in stream order
 Stage 2 (the SS device pass, geometry finish, combine/masks, record build)
 IS the classic caller's `_run_jobs` + `_finish`, so outputs are identical
 by construction; tests/test_fast_codec.py asserts byte parity end to end.
+
+Everything runs on the thread that calls `process_batch` (the device round
+trip resolves inline). Its spans: `process.decode`, `.group`, `.prep`, then
+`engine.codec.slow_molecule`, `.single`, `.gather`, `.place`, `.combine`,
+`.gates` and `resolve.serialize`; its run-report counters, by molecule:
+`codec.molecules` = `.emitted` + `.rejected` (`.rejected.<reason>`),
+`.slow_molecules`, `.strands`, `.single_strands`,
+`.combine_cells_device` / `_host`, `.duplex_bases`, `.disagreements`
+(docs/observability.md).
 """
 
 import struct
@@ -23,7 +32,22 @@ from ..io.bam import (FLAG_FIRST, FLAG_MATE_REVERSE, FLAG_MATE_UNMAPPED,
                       FLAG_PAIRED, FLAG_REVERSE, FLAG_SECONDARY,
                       FLAG_SUPPLEMENTARY, FLAG_UNMAPPED)
 from ..native import batch as nb
+from ..observe.metrics import METRICS
+from ..observe.trace import span as _span
 from .codec import _ASCII_COMPLEMENT, _SS, combine_arrays
+
+#: a molecule the classic ``prepare()`` returned nothing for is counted under
+#: the reason of the latest phase that recorded one (the phases run in order)
+_PREPARE_REASONS = ("IndelErrorBetweenStrands", "InsufficientOverlap",
+                    "InsufficientReads", "MinorityAlignment",
+                    "NotPrimaryFrPair", "FragmentRead")
+
+
+def _count_reject(reason):
+    """One whole molecule rejected for ``reason`` (run-report counters; the
+    caller's own stats count reads)."""
+    METRICS.inc("codec.rejected")
+    METRICS.inc("codec.rejected." + reason)
 
 
 class FastCodecCaller:
@@ -53,44 +77,51 @@ class FastCodecCaller:
         if n == 0:
             return self.flush() if final else []
         buf = batch.buf
-        # Z/H-typed presence gate matches the classic get_str-based grouping
-        mi_off, mi_len, _ = batch.tag_locs_str(self.tag)
-        if (mi_off < 0).any():
-            bad = int(np.nonzero(mi_off < 0)[0][0])
-            raise ValueError(
-                f"record {batch.name(bad)!r} missing {self.tag.decode()} tag")
-        starts = nb.group_starts(buf, np.ascontiguousarray(mi_off),
-                                 mi_len)
-        bounds = np.append(starts, n)
-        n_total = len(bounds) - 1
+        with _span("process.decode", rusage=True):
+            # Z/H-typed presence gate matches the classic get_str-based
+            # grouping
+            mi_off, mi_len, _ = batch.tag_locs_str(self.tag)
+            if (mi_off < 0).any():
+                bad = int(np.nonzero(mi_off < 0)[0][0])
+                raise ValueError(
+                    f"record {batch.name(bad)!r} missing "
+                    f"{self.tag.decode()} tag")
+        with _span("process.group", rusage=True):
+            starts = nb.group_starts(buf, np.ascontiguousarray(mi_off),
+                                     mi_len)
+            bounds = np.append(starts, n)
+            n_total = len(bounds) - 1
 
-        first_mi = batch.tag_bytes(self.tag, int(bounds[0])).decode()
-        merge_carry = self._carry is not None and self._carry[0] == first_mi
-        if merge_carry:
-            self._carry[1].extend(
-                batch.raw_records(np.arange(bounds[0], bounds[1])))
+            first_mi = batch.tag_bytes(self.tag, int(bounds[0])).decode()
+            merge_carry = self._carry is not None \
+                and self._carry[0] == first_mi
+            if merge_carry:
+                self._carry[1].extend(
+                    batch.raw_records(np.arange(bounds[0], bounds[1])))
 
-        g0 = 1 if merge_carry else 0
-        g1 = n_total if final else max(n_total - 1, g0)
-        deferred = None
-        if not final and n_total - 1 >= g0:
-            lo, hi = bounds[n_total - 1], bounds[n_total]
-            deferred = (batch.tag_bytes(self.tag, int(lo)).decode(),
-                        batch.raw_records(np.arange(lo, hi)))
+            g0 = 1 if merge_carry else 0
+            g1 = n_total if final else max(n_total - 1, g0)
+            deferred = None
+            if not final and n_total - 1 >= g0:
+                lo, hi = bounds[n_total - 1], bounds[n_total]
+                deferred = (batch.tag_bytes(self.tag, int(lo)).decode(),
+                            batch.raw_records(np.arange(lo, hi)))
 
         molecules = []
         if self._carry is not None:
             if (not merge_carry) or final or n_total >= 2:
                 mi, recs = self._carry
                 self._carry = None
-                mol = self.caller.prepare(recs, umi=mi)
+                mol = self._prepare_slow(recs, mi)
                 if mol is not None:
                     molecules.append(mol)
 
         codes_pk = quals_pk = None
         if g1 > g0:
-            span_mols, codes_pk, quals_pk = self._prepare_span(batch, bounds,
-                                                               g0, g1)
+            METRICS.inc("codec.molecules", g1 - g0)
+            with _span("process.prep", rusage=True):
+                span_mols, codes_pk, quals_pk = self._prepare_span(
+                    batch, bounds, g0, g1)
             molecules.extend(span_mols)
 
         if deferred is not None:
@@ -106,8 +137,32 @@ class FastCodecCaller:
             return []
         mi, recs = self._carry
         self._carry = None
-        mol = self.caller.prepare(recs, umi=mi)
+        mol = self._prepare_slow(recs, mi)
         return self._run([mol] if mol is not None else [])
+
+    def _prepare_slow(self, records, mi, counted=False):
+        """One molecule through the classic ``prepare()`` (the semantic
+        reference): the one a batch boundary cut, or (``counted``: a group of
+        the vectorized span, already in ``codec.molecules``) one whose CIGAR
+        shape the closed forms do not cover."""
+        METRICS.inc("codec.slow_molecules")
+        if not counted:
+            METRICS.inc("codec.molecules")
+        before = dict(self.caller.stats.rejection_reasons)
+        with _span("engine.codec.slow_molecule", rusage=True):
+            mol = self.caller.prepare(records, umi=mi)
+        if mol is None:
+            self._count_prepare_reject(before)
+        return mol
+
+    def _count_prepare_reject(self, before):
+        """Count one molecule a per-molecule prepare returned nothing for,
+        under the latest phase's reason among those that grew since
+        ``before`` (a copy of the caller's ``rejection_reasons``)."""
+        reasons = self.caller.stats.rejection_reasons
+        grew = [r for r in _PREPARE_REASONS
+                if reasons.get(r, 0) > before.get(r, 0)]
+        _count_reject(grew[0] if grew else "NoUsableReads")
 
     def _run(self, molecules, codes_pk=None, quals_pk=None):
         """One SS device pass + batched finish.
@@ -121,7 +176,6 @@ class FastCodecCaller:
         one device execution.
         """
         from ..ops import oracle
-        from .vanilla import I16_MAX, VanillaConsensusRead
 
         caller = self.caller
         ss = caller.ss
@@ -130,20 +184,17 @@ class FastCodecCaller:
         strand_res = {}  # (mol_idx, strand) -> (bases, quals, depths, errs)
 
         vec_multi = []       # (mol_idx, strand, base_row, count, cl)
+        vec_single = []      # (mol_idx, strand, base_row, cl)
         classic_multi = []   # (mol_idx, strand, job)
+        classic_single = []  # (mol_idx, strand, job)
         for i, m in enumerate(molecules):
             if "job_r1" in m:
                 # carry/fallback molecules: the same dispatch, rows repacked
                 # below (a separate _run_jobs call would cost a second
                 # device execution on essentially every streamed batch)
                 for s, job in enumerate((m["job_r1"], m["job_r2"])):
-                    cl = job.consensus_len
-                    if len(job.codes) == 1:
-                        strand_res[(i, s)] = oracle.single_read_consensus(
-                            job.codes[0][:cl], job.quals[0][:cl], ss.tables,
-                            ss.options.min_consensus_base_quality)
-                    else:
-                        classic_multi.append((i, s, job))
+                    (classic_single if len(job.codes) == 1
+                     else classic_multi).append((i, s, job))
                 continue
             base = m["pk0"]
             for s, (b0, cnt, flens) in enumerate(
@@ -151,43 +202,66 @@ class FastCodecCaller:
                      (base + m["n_r1"], m["n_r2"], m["r2_flens"]))):
                 cl = int(flens.max())
                 if cnt == 1:
-                    strand_res[(i, s)] = oracle.single_read_consensus(
-                        codes_pk[b0, :cl], quals_pk[b0, :cl], ss.tables,
-                        ss.options.min_consensus_base_quality)
+                    vec_single.append((i, s, b0, cl))
                 else:
                     vec_multi.append((i, s, b0, cnt, cl))
+        METRICS.inc("codec.strands", 2 * len(molecules))
+        METRICS.inc("codec.single_strands",
+                    len(vec_single) + len(classic_single))
+
+        if vec_single or classic_single:
+            # the single-read strands never leave the host: one table pass
+            # over their pack rows (elementwise, so each strand's slice is
+            # what a call of its own would give)
+            with _span("engine.codec.single", rusage=True):
+                min_q = ss.options.min_consensus_base_quality
+                if vec_single:
+                    rows = np.fromiter((v[2] for v in vec_single), np.int64,
+                                       len(vec_single))
+                    b, q, d, e = oracle.single_read_consensus(
+                        codes_pk[rows], quals_pk[rows], ss.tables, min_q)
+                    for k, (i, s, _b0, cl) in enumerate(vec_single):
+                        strand_res[(i, s)] = (b[k, :cl], q[k, :cl],
+                                              d[k, :cl], e[k, :cl])
+                for i, s, job in classic_single:
+                    cl = job.consensus_len
+                    strand_res[(i, s)] = oracle.single_read_consensus(
+                        job.codes[0][:cl], job.quals[0][:cl], ss.tables,
+                        min_q)
 
         if vec_multi or classic_multi:
-            cls = [(i, s, job.consensus_len, job)
-                   for i, s, job in classic_multi]
-            all_cl = [v[4] for v in vec_multi] + [c[2] for c in cls]
-            L_max = max(-(-max(all_cl) // 16) * 16, 16)
-            counts = np.array([v[3] for v in vec_multi]
-                              + [len(c[3].codes) for c in cls],
-                              dtype=np.int64)
-            n_vec_rows = int(sum(v[3] for v in vec_multi))
-            N = int(counts.sum())
-            codes2d = np.full((N, L_max), N_CODE, dtype=np.uint8)
-            quals2d = np.zeros((N, L_max), dtype=np.uint8)
-            if vec_multi:
-                rows_idx = np.concatenate(
-                    [np.arange(b0, b0 + cnt)
-                     for _, _, b0, cnt, _ in vec_multi])
-                # pack rows are N/Q0-padded past each read's final length,
-                # so a single fancy-index gather IS the dense job layout.
-                # A carry molecule's longer reads can push L_max past the
-                # span's pack stride; vec flens never exceed the stride, so
-                # clamping the gather width keeps the tail at N/Q0.
-                wv = min(L_max, codes_pk.shape[1])
-                codes2d[:n_vec_rows, :wv] = codes_pk[rows_idx, :wv]
-                quals2d[:n_vec_rows, :wv] = quals_pk[rows_idx, :wv]
-            row = n_vec_rows
-            for _, _, _, job in cls:
-                for c, q in zip(job.codes, job.quals):
-                    k = min(len(c), L_max)
-                    codes2d[row, :k] = c[:k]
-                    quals2d[row, :k] = q[:k]
-                    row += 1
+            with _span("engine.codec.gather", rusage=True):
+                cls = [(i, s, job.consensus_len, job)
+                       for i, s, job in classic_multi]
+                all_cl = [v[4] for v in vec_multi] + [c[2] for c in cls]
+                L_max = max(-(-max(all_cl) // 16) * 16, 16)
+                counts = np.array([v[3] for v in vec_multi]
+                                  + [len(c[3].codes) for c in cls],
+                                  dtype=np.int64)
+                n_vec_rows = int(sum(v[3] for v in vec_multi))
+                N = int(counts.sum())
+                codes2d = np.full((N, L_max), N_CODE, dtype=np.uint8)
+                quals2d = np.zeros((N, L_max), dtype=np.uint8)
+                if vec_multi:
+                    rows_idx = np.concatenate(
+                        [np.arange(b0, b0 + cnt)
+                         for _, _, b0, cnt, _ in vec_multi])
+                    # pack rows are N/Q0-padded past each read's final
+                    # length, so a single fancy-index gather IS the dense job
+                    # layout. A carry molecule's longer reads can push L_max
+                    # past the span's pack stride; vec flens never exceed the
+                    # stride, so clamping the gather width keeps the tail at
+                    # N/Q0.
+                    wv = min(L_max, codes_pk.shape[1])
+                    codes2d[:n_vec_rows, :wv] = codes_pk[rows_idx, :wv]
+                    quals2d[:n_vec_rows, :wv] = quals_pk[rows_idx, :wv]
+                row = n_vec_rows
+                for _, _, _, job in cls:
+                    for c, q in zip(job.codes, job.quals):
+                        k = min(len(c), L_max)
+                        codes2d[row, :k] = c[:k]
+                        quals2d[row, :k] = q[:k]
+                        row += 1
             # adaptive offload: host f64 engine or full-column wire,
             # decided per batch (ops/kernel helper)
             from ..ops.kernel import route_and_call_segments
@@ -200,9 +274,10 @@ class FastCodecCaller:
             # thresholds are elementwise: one vectorized pass over the whole
             # (F, L) batch, then per-slot length slicing (positions past a
             # slot's consensus length are computed and discarded)
-            b_all, q_all = oracle.apply_consensus_thresholds(
-                w, q_, d, ss.options.min_reads,
-                ss.options.min_consensus_base_quality)
+            with _span("resolve.unpack", rusage=True):
+                b_all, q_all = oracle.apply_consensus_thresholds(
+                    w, q_, d, ss.options.min_reads,
+                    ss.options.min_consensus_base_quality)
             for fi, (i, s, cl) in enumerate(slots):
                 strand_res[(i, s)] = ("slot", fi, cl)
             slot_mats = (b_all, q_all, d, e)
@@ -232,109 +307,113 @@ class FastCodecCaller:
 
         caller = self.caller
         st, opts = caller.stats, caller.options
-        keep = []
-        for i, mol in enumerate(molecules):
-            en1, en2 = strand_res[(i, 0)], strand_res[(i, 1)]
-            L = mol["consensus_length"]
-            if L < self._strand_len(en1) or L < self._strand_len(en2):
-                st.reject("ClipOverlapFailed", mol["n_r1"] + mol["n_r2"])
-                continue
-            keep.append((mol, en1, en2))
-        if not keep:
-            return []
-        J = len(keep)
-        # ONE pass over the kept molecules collects every per-molecule
-        # scalar the batched placement/serialization needs (this loop ran
-        # five times before: lengths, two placement passes, rc flags,
-        # rejects)
-        Ls = np.empty(J, dtype=np.int64)
-        r1n = np.empty(J, dtype=bool)
-        r2n = np.empty(J, dtype=bool)
-        slot_j = ([], [])
-        slot_row = ([], [])
-        slot_k = ([], [])
-        arr_items = []  # (side, j, en) — materialized strands, placed scalarly
-        for j, (mol, en1, en2) in enumerate(keep):
-            Ls[j] = mol["consensus_length"]
-            r1n[j] = mol["r1_is_negative"]
-            r2n[j] = mol["r2_is_negative"]
-            for side, en in ((0, en1), (1, en2)):
-                if len(en) == 3:
-                    slot_j[side].append(j)
-                    slot_row[side].append(en[1])
-                    slot_k[side].append(en[2])
-                else:
-                    arr_items.append((side, j, en))
-        offs = np.zeros(J + 1, dtype=np.int64)
-        np.cumsum(Ls, out=offs[1:])
-        T = int(offs[-1])
-
-        # oriented + padded strands (pad = lowercase n / Q0 / depth 0)
-        b1 = np.full(T, NO_CALL_BASE_LOWER, np.uint8)
-        b2 = np.full(T, NO_CALL_BASE_LOWER, np.uint8)
-        q1 = np.zeros(T, np.uint8)
-        q2 = np.zeros(T, np.uint8)
-        # int32: every value here is pre-capped at I16_MAX, and the combine's
-        # sums stay well under 2^31 — int64 was pure memory traffic
-        d1 = np.zeros(T, np.int32)
-        d2 = np.zeros(T, np.int32)
-        e1 = np.zeros(T, np.int32)
-        e2 = np.zeros(T, np.int32)
-
-        def place_arr(bases_c, quals, dep, err, rc, pad_left, o, L,
-                      b, q, d, e):
-            bases = CODE_TO_BASE[np.minimum(bases_c, N_CODE)]
-            k = len(bases)
-            sl = slice(o + L - k, o + L) if pad_left else slice(o, o + k)
-            if rc:
-                b[sl] = _ASCII_COMPLEMENT[bases[::-1]]
-                q[sl] = quals[::-1]
-                d[sl] = np.minimum(dep[::-1], I16_MAX)
-                e[sl] = np.minimum(err[::-1], I16_MAX)
-            else:
-                b[sl] = bases
-                q[sl] = quals
-                d[sl] = np.minimum(dep, I16_MAX)
-                e[sl] = np.minimum(err, I16_MAX)
-
-        def place_side(side, bt, qt, dt, et):
-            """One side's placement: slot-backed strands in one vectorized
-            gather+scatter; array-backed strands scalarly (collected by the
-            single pass above)."""
-            for aside, j, en in arr_items:
-                if aside != side:
+        with _span("engine.codec.place", rusage=True):
+            keep = []
+            for i, mol in enumerate(molecules):
+                en1, en2 = strand_res[(i, 0)], strand_res[(i, 1)]
+                L = mol["consensus_length"]
+                if L < self._strand_len(en1) or L < self._strand_len(en2):
+                    st.reject("ClipOverlapFailed", mol["n_r1"] + mol["n_r2"])
+                    _count_reject("ClipOverlapFailed")
                     continue
-                rc = r1n[j] if side == 0 else not r1n[j]
-                pl = r1n[j] if side == 0 else r2n[j]
-                place_arr(en[0], en[1], en[2], en[3], bool(rc), bool(pl),
-                          int(offs[j]), int(Ls[j]), bt, qt, dt, et)
-            if not slot_j[side]:
-                return
-            b_all, q_all, dmat, emat = slot_mats
-            jarr = np.asarray(slot_j[side], np.int64)
-            rows = np.asarray(slot_row[side], np.int64)
-            ks = np.asarray(slot_k[side], np.int64)
-            os_ = offs[jarr]
-            rcs = r1n[jarr] if side == 0 else ~r1n[jarr]
-            pls = r1n[jarr] if side == 0 else r2n[jarr]
-            base = os_ + np.where(pls, Ls[jarr] - ks, 0)
-            n_obs = int(ks.sum())
-            within = np.arange(n_obs, dtype=np.int64) \
-                - np.repeat(np.concatenate(([0], np.cumsum(ks)[:-1]))
-                            if len(ks) else np.zeros(0, np.int64), ks)
-            tgt = np.repeat(base, ks) + within
-            rc_rep = np.repeat(rcs, ks)
-            src_col = np.where(rc_rep, np.repeat(ks, ks) - 1 - within,
-                               within)
-            src_row = np.repeat(rows, ks)
-            bb = CODE_TO_BASE[np.minimum(b_all[src_row, src_col], N_CODE)]
-            bt[tgt] = np.where(rc_rep, _ASCII_COMPLEMENT[bb], bb)
-            qt[tgt] = q_all[src_row, src_col]
-            dt[tgt] = np.minimum(dmat[src_row, src_col], I16_MAX)
-            et[tgt] = np.minimum(emat[src_row, src_col], I16_MAX)
+                keep.append((mol, en1, en2))
+            if not keep:
+                return []
+            J = len(keep)
+            # ONE pass over the kept molecules collects every per-molecule
+            # scalar the batched placement/serialization needs (this loop ran
+            # five times before: lengths, two placement passes, rc flags,
+            # rejects)
+            Ls = np.empty(J, dtype=np.int64)
+            r1n = np.empty(J, dtype=bool)
+            r2n = np.empty(J, dtype=bool)
+            slot_j = ([], [])
+            slot_row = ([], [])
+            slot_k = ([], [])
+            # (side, j, en): materialized strands, placed scalarly
+            arr_items = []
+            for j, (mol, en1, en2) in enumerate(keep):
+                Ls[j] = mol["consensus_length"]
+                r1n[j] = mol["r1_is_negative"]
+                r2n[j] = mol["r2_is_negative"]
+                for side, en in ((0, en1), (1, en2)):
+                    if len(en) == 3:
+                        slot_j[side].append(j)
+                        slot_row[side].append(en[1])
+                        slot_k[side].append(en[2])
+                    else:
+                        arr_items.append((side, j, en))
+            offs = np.zeros(J + 1, dtype=np.int64)
+            np.cumsum(Ls, out=offs[1:])
+            T = int(offs[-1])
 
-        place_side(0, b1, q1, d1, e1)
-        place_side(1, b2, q2, d2, e2)
+            # oriented + padded strands (pad = lowercase n / Q0 / depth 0)
+            b1 = np.full(T, NO_CALL_BASE_LOWER, np.uint8)
+            b2 = np.full(T, NO_CALL_BASE_LOWER, np.uint8)
+            q1 = np.zeros(T, np.uint8)
+            q2 = np.zeros(T, np.uint8)
+            # int32: every value here is pre-capped at I16_MAX, and the
+            # combine's sums stay well under 2^31 — int64 was pure memory
+            # traffic
+            d1 = np.zeros(T, np.int32)
+            d2 = np.zeros(T, np.int32)
+            e1 = np.zeros(T, np.int32)
+            e2 = np.zeros(T, np.int32)
+
+            def place_arr(bases_c, quals, dep, err, rc, pad_left, o, L,
+                          b, q, d, e):
+                bases = CODE_TO_BASE[np.minimum(bases_c, N_CODE)]
+                k = len(bases)
+                sl = slice(o + L - k, o + L) if pad_left else slice(o, o + k)
+                if rc:
+                    b[sl] = _ASCII_COMPLEMENT[bases[::-1]]
+                    q[sl] = quals[::-1]
+                    d[sl] = np.minimum(dep[::-1], I16_MAX)
+                    e[sl] = np.minimum(err[::-1], I16_MAX)
+                else:
+                    b[sl] = bases
+                    q[sl] = quals
+                    d[sl] = np.minimum(dep, I16_MAX)
+                    e[sl] = np.minimum(err, I16_MAX)
+
+            def place_side(side, bt, qt, dt, et):
+                """One side's placement: slot-backed strands in one vectorized
+                gather+scatter; array-backed strands scalarly (collected by the
+                single pass above)."""
+                for aside, j, en in arr_items:
+                    if aside != side:
+                        continue
+                    rc = r1n[j] if side == 0 else not r1n[j]
+                    pl = r1n[j] if side == 0 else r2n[j]
+                    place_arr(en[0], en[1], en[2], en[3], bool(rc), bool(pl),
+                              int(offs[j]), int(Ls[j]), bt, qt, dt, et)
+                if not slot_j[side]:
+                    return
+                b_all, q_all, dmat, emat = slot_mats
+                jarr = np.asarray(slot_j[side], np.int64)
+                rows = np.asarray(slot_row[side], np.int64)
+                ks = np.asarray(slot_k[side], np.int64)
+                os_ = offs[jarr]
+                rcs = r1n[jarr] if side == 0 else ~r1n[jarr]
+                pls = r1n[jarr] if side == 0 else r2n[jarr]
+                base = os_ + np.where(pls, Ls[jarr] - ks, 0)
+                n_obs = int(ks.sum())
+                within = np.arange(n_obs, dtype=np.int64) \
+                    - np.repeat(np.concatenate(([0], np.cumsum(ks)[:-1]))
+                                if len(ks) else np.zeros(0, np.int64), ks)
+                tgt = np.repeat(base, ks) + within
+                rc_rep = np.repeat(rcs, ks)
+                src_col = np.where(rc_rep, np.repeat(ks, ks) - 1 - within,
+                                   within)
+                src_row = np.repeat(rows, ks)
+                bb = CODE_TO_BASE[np.minimum(b_all[src_row, src_col], N_CODE)]
+                bt[tgt] = np.where(rc_rep, _ASCII_COMPLEMENT[bb], bb)
+                qt[tgt] = q_all[src_row, src_col]
+                dt[tgt] = np.minimum(dmat[src_row, src_col], I16_MAX)
+                et[tgt] = np.minimum(emat[src_row, src_col], I16_MAX)
+
+            place_side(0, b1, q1, d1, e1)
+            place_side(1, b2, q2, d2, e2)
 
         # ---- duplex combine, one pass over the concatenated strands:
         # device jit (ops/kernel._codec_combine_jit), native C pass, or
@@ -354,95 +433,104 @@ class FastCodecCaller:
                     NO_CALL_BASE_LOWER, I16_MAX)
             return combine_arrays(b1, b2, q1, q2, d1, d2, e1, e2)
 
-        if T > 0 and comb_env != "host" and not kernel.host_mode():
-            from ..ops.kernel import codec_combine_device
-            from ..ops.router import CODEC_COMBINE, run_adaptive_stage
+        with _span("engine.codec.combine", rusage=True) as sp:
+            side = "host"
+            if T > 0 and comb_env != "host" and not kernel.host_mode():
+                from ..ops.kernel import codec_combine_device
+                from ..ops.router import CODEC_COMBINE, run_adaptive_stage
 
-            res, _side = run_adaptive_stage(
-                CODEC_COMBINE, T, comb_env,
-                lambda: codec_combine_device(b1, b2, q1, q2, d1, d2,
-                                             e1, e2, mesh=self.mesh),
-                _host_combine)
-        else:
-            res = _host_combine()
+                res, side = run_adaptive_stage(
+                    CODEC_COMBINE, T, comb_env,
+                    lambda: codec_combine_device(b1, b2, q1, q2, d1, d2,
+                                                 e1, e2, mesh=self.mesh),
+                    _host_combine)
+            else:
+                res = _host_combine()
+            sp.set(side=side)
+            METRICS.inc("codec.combine_cells_" + side, T)
         cb, cq, cd, ce, both, disag = res
 
-        # per-molecule disagreement thresholds (recoverable rejects)
-        def seg_sum(x):
-            cs = np.zeros(T + 1, np.int64)
-            np.cumsum(x, out=cs[1:])
-            return cs[offs[1:]] - cs[offs[:-1]]
+        with _span("engine.codec.gates", rusage=True):
+            # per-molecule disagreement thresholds (recoverable rejects)
+            def seg_sum(x):
+                cs = np.zeros(T + 1, np.int64)
+                np.cumsum(x, out=cs[1:])
+                return cs[offs[1:]] - cs[offs[:-1]]
 
-        duplex_bases = seg_sum(both)
-        disagreements = seg_sum(disag)
-        st.consensus_duplex_bases_emitted += int(duplex_bases.sum())
-        st.duplex_disagreement_base_count += int(disagreements.sum())
-        nz = duplex_bases > 0
-        bad = np.zeros(J, dtype=bool)
-        if opts.max_duplex_disagreements is not None:
-            bad |= nz & (disagreements > opts.max_duplex_disagreements)
-        rate = np.divide(disagreements.astype(np.float64), duplex_bases,
-                         out=np.zeros(J, np.float64), where=nz)
-        bad |= nz & (rate > opts.max_duplex_disagreement_rate)
+            duplex_bases = seg_sum(both)
+            disagreements = seg_sum(disag)
+            st.consensus_duplex_bases_emitted += int(duplex_bases.sum())
+            st.duplex_disagreement_base_count += int(disagreements.sum())
+            METRICS.inc("codec.duplex_bases", int(duplex_bases.sum()))
+            METRICS.inc("codec.disagreements", int(disagreements.sum()))
+            nz = duplex_bases > 0
+            bad = np.zeros(J, dtype=bool)
+            if opts.max_duplex_disagreements is not None:
+                bad |= nz & (disagreements > opts.max_duplex_disagreements)
+            rate = np.divide(disagreements.astype(np.float64), duplex_bases,
+                             out=np.zeros(J, np.float64), where=nz)
+            bad |= nz & (rate > opts.max_duplex_disagreement_rate)
 
-        # ---- quality masks (codec.py _mask_quals: outer bands, then SS)
-        if (opts.outer_bases_length > 0
-                and opts.outer_bases_qual is not None) \
-                or opts.single_strand_qual is not None:
-            if opts.outer_bases_length > 0 \
-                    and opts.outer_bases_qual is not None:
-                pos = np.arange(T, dtype=np.int64) \
-                    - np.repeat(offs[:-1], Ls)
-                l_rep = np.repeat(Ls, Ls)
-                n_rep = np.minimum(opts.outer_bases_length, l_rep)
-                cq[(pos < n_rep) | (pos >= l_rep - n_rep)] = \
-                    opts.outer_bases_qual
-            if opts.single_strand_qual is not None:
-                is_n = lambda x: ((x == NO_CALL_BASE)
-                                  | (x == NO_CALL_BASE_LOWER))
-                cq[is_n(b1) | is_n(b2)] = opts.single_strand_qual
+            # ---- quality masks (codec.py _mask_quals: outer bands, then SS)
+            if (opts.outer_bases_length > 0
+                    and opts.outer_bases_qual is not None) \
+                    or opts.single_strand_qual is not None:
+                if opts.outer_bases_length > 0 \
+                        and opts.outer_bases_qual is not None:
+                    pos = np.arange(T, dtype=np.int64) \
+                        - np.repeat(offs[:-1], Ls)
+                    l_rep = np.repeat(Ls, Ls)
+                    n_rep = np.minimum(opts.outer_bases_length, l_rep)
+                    cq[(pos < n_rep) | (pos >= l_rep - n_rep)] = \
+                        opts.outer_bases_qual
+                if opts.single_strand_qual is not None:
+                    is_n = lambda x: ((x == NO_CALL_BASE)
+                                      | (x == NO_CALL_BASE_LOWER))
+                    cq[is_n(b1) | is_n(b2)] = opts.single_strand_qual
 
+            good = []
+            for j, (mol, _, _) in enumerate(keep):
+                if bad[j]:
+                    st.reject("HighDuplexDisagreement",
+                              mol["n_r1"] + mol["n_r2"])
+                    st.consensus_reads_rejected_hdd += 1
+                    _count_reject("HighDuplexDisagreement")
+                else:
+                    good.append(j)
         # ---- record serialization
-        good = []
-        for j, (mol, _, _) in enumerate(keep):
-            if bad[j]:
-                st.reject("HighDuplexDisagreement",
-                          mol["n_r1"] + mol["n_r2"])
-                st.consensus_reads_rejected_hdd += 1
-            else:
-                good.append(j)
         if not good:
             return []
+        METRICS.inc("codec.emitted", len(good))
+        with _span("resolve.serialize", rusage=True):
+            if opts.cell_tag is not None:
+                # rare option: the cell tag needs per-record raw scans, so
+                # build through the classic RecordBuilder path
+                out = []
+                for j in good:
+                    mol = keep[j][0]
+                    sl = slice(int(offs[j]), int(offs[j] + Ls[j]))
+                    rc = mol["r1_is_negative"]
 
-        if opts.cell_tag is not None:
-            # rare option: the cell tag needs per-record raw scans, so build
-            # through the classic RecordBuilder path
-            out = []
-            for j in good:
-                mol = keep[j][0]
-                sl = slice(int(offs[j]), int(offs[j] + Ls[j]))
-                rc = mol["r1_is_negative"]
+                    def ss_of(b, q, d, e, count):
+                        if rc:
+                            return _SS(_ASCII_COMPLEMENT[b[sl][::-1]],
+                                       q[sl][::-1], d[sl][::-1], e[sl][::-1],
+                                       count)
+                        return _SS(b[sl], q[sl], d[sl], e[sl], count)
 
-                def ss_of(b, q, d, e, count):
-                    if rc:
-                        return _SS(_ASCII_COMPLEMENT[b[sl][::-1]],
-                                   q[sl][::-1], d[sl][::-1], e[sl][::-1],
-                                   count)
-                    return _SS(b[sl], q[sl], d[sl], e[sl], count)
+                    rec = caller._build_record(
+                        ss_of(cb, cq, cd, ce, mol["n_r1"] + mol["n_r2"]),
+                        ss_of(b1, q1, d1, e1, mol["n_r1"]),
+                        ss_of(b2, q2, d2, e2, mol["n_r2"]),
+                        mol["umi"], mol["source_raws"], mol["records"],
+                        rx_umis=mol.get("rx_umis"))
+                    out.append(struct.pack("<I", len(rec)) + rec)
+                return out
 
-                rec = caller._build_record(
-                    ss_of(cb, cq, cd, ce, mol["n_r1"] + mol["n_r2"]),
-                    ss_of(b1, q1, d1, e1, mol["n_r1"]),
-                    ss_of(b2, q2, d2, e2, mol["n_r2"]),
-                    mol["umi"], mol["source_raws"], mol["records"],
-                    rx_umis=mol.get("rx_umis"))
-                out.append(struct.pack("<I", len(rec)) + rec)
-            return out
-
-        return self._serialize_native(keep, good, offs, Ls, r1n, cb, cq,
-                                      np.ascontiguousarray(ce,
-                                                           dtype=np.int64),
-                                      b1, q1, d1, e1, b2, q2, d2, e2)
+            return self._serialize_native(keep, good, offs, Ls, r1n, cb, cq,
+                                          np.ascontiguousarray(ce,
+                                                               dtype=np.int64),
+                                          b1, q1, d1, e1, b2, q2, d2, e2)
 
     def _serialize_native(self, keep, good, offs, Ls, r1n, cb, cq, ce,
                           b1, q1, d1, e1, b2, q2, d2, e2):
@@ -603,32 +691,45 @@ class FastCodecCaller:
             if not grp_ok[g - g0]:
                 # classic prepare runs HERE, in stream order — the shared
                 # downsample RNG stream must see molecules in input order
-                mol = caller.prepare(batch.raw_records(rows), umi=mi)
+                mol = self._prepare_slow(batch.raw_records(rows), mi,
+                                         counted=True)
                 pending.append(("mol", mol) if mol is not None
                                else ("none", None))
                 continue
             if (g - g0) in py_groups:
+                before = dict(st.rejection_reasons)
                 prep = self._prepare_molecule_vec(batch, rows, mi, pack_rows,
                                                   pack_clips, pk_base)
+                if prep is None:
+                    self._count_prepare_reject(before)
             else:
                 k = int(loc[g - g0])
                 if k < 0:
                     prep = None  # no surviving FR pair in this group
+                    _count_reject("NotPrimaryFrPair"
+                                  if paired_primary[rows - lo].any()
+                                  else "FragmentRead")
                 elif geom["small"][k]:
                     st.reject("InsufficientReads", 2 * int(geom["n_g"][k]))
+                    _count_reject("InsufficientReads")
                     prep = None
                 elif geom["downs"][k]:
                     # downsample consumes the shared RNG stream — the
                     # per-molecule reference path runs, in stream order
+                    before = dict(st.rejection_reasons)
                     prep = self._finish_molecule_vec(
                         rows, mi, pair_of_group.get(g - g0), pack_rows,
                         pack_clips, pk_base)
+                    if prep is None:
+                        self._count_prepare_reject(before)
                 elif geom["short"][k]:
                     st.reject("InsufficientOverlap", 2 * int(geom["n_g"][k]))
+                    _count_reject("InsufficientOverlap")
                     prep = None
                 elif geom["indel"][k]:
                     st.reject("IndelErrorBetweenStrands",
                               2 * int(geom["n_g"][k]))
+                    _count_reject("IndelErrorBetweenStrands")
                     prep = None
                 else:
                     s_, e_ = int(geom["starts"][k]), int(geom["ends"][k])

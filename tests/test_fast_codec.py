@@ -7,6 +7,7 @@ from fgumi_tpu.cli import main
 from fgumi_tpu.io.bam import BamHeader, BamReader, BamWriter, RecordBuilder
 from fgumi_tpu.native import batch as nb
 from fgumi_tpu.simulate import simulate_codec_bam
+from record_batches import record_batches
 
 pytestmark = pytest.mark.skipif(not nb.available(),
                                 reason="native library unavailable")
@@ -121,6 +122,34 @@ def test_parity_adversarial(adversarial_bam, tmp_path, extra):
     assert_cli_parity(adversarial_bam, tmp_path, extra)
 
 
+@pytest.mark.parametrize("extra", [[], ["--min-reads", "2"],
+                                   ["--min-duplex-length", "40"]])
+def test_molecule_counters_add_up_on_mixed_shapes(adversarial_bam, tmp_path,
+                                                  extra):
+    """Every MI group of the mixed-shape fixture (fragments, broken pairs,
+    clipped and indel CIGARs beside plain pairs) is emitted or rejected under
+    one reason, whichever prepare it went down."""
+    import json
+
+    report = str(tmp_path / "report.json")
+    assert main(["--run-report", report, "codec", "-i", adversarial_bam,
+                 "-o", str(tmp_path / "out.bam"), "--min-reads", "1"]
+                + extra) == 0
+    with open(report) as f:
+        m = json.load(f)["metrics"]
+    with BamReader(adversarial_bam) as r:
+        groups = len({rec.get_str(b"MI") for rec in r})
+    assert m["codec.molecules"] == groups
+    assert m["codec.emitted"] + m["codec.rejected"] == groups
+    assert sum(v for k, v in m.items()
+               if k.startswith("codec.rejected.")) == m["codec.rejected"] > 0
+    assert m["codec.emitted"] == len(records_of(str(tmp_path / "out.bam")))
+    assert 0 < m["codec.slow_molecules"] < groups
+    assert m["codec.strands"] == 2 * (
+        m["codec.emitted"] + m.get("codec.rejected.ClipOverlapFailed", 0)
+        + m.get("codec.rejected.HighDuplexDisagreement", 0))
+
+
 def test_all_m_filter_keeps_all():
     """Single-op M CIGARs of any length mix form one prefix-compatible
     group (the vector path's keep-all assumption for phase 3)."""
@@ -133,22 +162,16 @@ def test_all_m_filter_keeps_all():
     assert sorted(keep) == list(range(7))
 
 
-def test_parity_tiny_batches(codec_bam):
-    """Molecules spanning batch boundaries: carry merge + deferred flush."""
+@pytest.mark.parametrize("n_records", [4, 7, 50])
+def test_parity_tiny_batches(codec_bam, n_records):
+    """Molecules spanning batch boundaries: carry merge + deferred flush.
+    Batches of 4 records end inside every molecule of three pairs but one in
+    three, of 7 inside most, of 50 inside some: the carried molecule goes
+    down the classic prepare() and joins the next batch's device pass."""
     from fgumi_tpu.consensus.codec import CodecConsensusCaller, CodecOptions
     from fgumi_tpu.consensus.fast_codec import FastCodecCaller
     from fgumi_tpu.core.grouper import iter_mi_group_batches
-    from fgumi_tpu.io.batch_reader import BamBatchReader
-
-    def run_fast(tb):
-        caller = CodecConsensusCaller("fgumi", "A", CodecOptions())
-        fast = FastCodecCaller(caller, b"MI")
-        out = []
-        with BamBatchReader(codec_bam, target_bytes=tb) as r:
-            for batch in r:
-                out.extend(fast.process_batch(batch))
-        out.extend(fast.flush())
-        return out, caller.stats.rejection_reasons
+    from fgumi_tpu.observe.metrics import METRICS
 
     import struct
 
@@ -158,10 +181,24 @@ def test_parity_tiny_batches(codec_bam):
         for batch in iter_mi_group_batches(r, 50, tag=b"MI"):
             expected.extend(caller.call_groups(batch))
     expected_wire = b"".join(struct.pack("<I", len(r)) + r for r in expected)
-    for tb in (600, 5000):
-        got, rej = run_fast(tb)
-        assert b"".join(got) == expected_wire, tb
-        assert rej == caller.stats.rejection_reasons
+
+    fast_caller = CodecConsensusCaller("fgumi", "A", CodecOptions())
+    fast = FastCodecCaller(fast_caller, b"MI")
+    before = METRICS.snapshot().get("codec.slow_molecules", 0)
+    got = []
+    for batch in record_batches(codec_bam, n_records):
+        got.extend(fast.process_batch(batch))
+    got.extend(fast.flush())
+    assert b"".join(got) == expected_wire
+    assert fast_caller.stats.rejection_reasons \
+        == caller.stats.rejection_reasons
+    # 300 molecules of 6 records: the molecule that holds a batch's last
+    # record is carried (the engine cannot know it is complete) and goes
+    # down the slow path, once however many batches it spans
+    slow = METRICS.snapshot()["codec.slow_molecules"] - before
+    assert slow == len({(min(end, 1800) - 1) // 6
+                        for end in range(n_records, 1800 + n_records,
+                                         n_records)})
 
 
 @pytest.mark.parametrize("seed", [101, 202, 303])

@@ -18,6 +18,7 @@ from fgumi_tpu.io.bam import BamHeader, BamReader, BamWriter, RecordBuilder
 from fgumi_tpu.io.batch_reader import BamBatchReader
 from fgumi_tpu.native import batch as nb
 from fgumi_tpu.simulate import simulate_duplex_bam
+from record_batches import record_batches
 
 pytestmark = pytest.mark.skipif(not nb.available(),
                                 reason="native library unavailable")
@@ -272,21 +273,6 @@ _SEGMENT_MIXES = {
     "both": dict(reads_per_strand=2, strand_bias_alpha=1.0,
                  strand_bias_beta=1.0),
 }
-
-
-def record_batches(path, n_records):
-    """The file's records as RecordBatches of ``n_records`` (the reader
-    itself cuts no finer than a decoded chunk, a whole small file)."""
-    from fgumi_tpu.io.batch_reader import RecordBatch
-
-    with BamBatchReader(path) as reader:
-        for batch in reader:
-            ends = np.append(batch.rec_off, len(batch.buf))
-            for i in range(0, batch.n, n_records):
-                j = min(i + n_records, batch.n)
-                yield RecordBatch(
-                    bytearray(batch.buf[ends[i]:ends[j]]),
-                    np.ascontiguousarray(batch.rec_off[i:j] - ends[i]))
 
 
 @pytest.mark.parametrize("devices", [1, 2])

@@ -8,6 +8,16 @@ arithmetic and the SourceRead conversion is one native pack. Molecules with
 any other CIGAR shape run the classic prepare() unchanged, in stream order
 (sharing the caller's stats and downsample RNG stream).
 
+A batch's molecules travel from the prepare to `nb.build_codec_records` as
+columns (`_Molecules`: one array a field, one entry a molecule or a strand),
+so the verdicts, the strand dispatch, the placement, the gates and the
+names / MI / RX of the records are whole-array passes. A Python loop runs
+only over the molecules the closed forms do not cover, one at a time and in
+stream order among themselves (`codec.row_molecules`): the one a batch
+boundary cut, a group with a CIGAR that is not one M run, a group whose
+read names collide in the hash, a group that downsamples; and over every
+emitted molecule under `--cell-tag`, which needs raw records.
+
 Stage 2 (the SS device pass, geometry finish, combine/masks, record build)
 IS the classic caller's `_run_jobs` + `_finish`, so outputs are identical
 by construction; tests/test_fast_codec.py asserts byte parity end to end.
@@ -17,7 +27,7 @@ trip resolves inline). Its spans: `process.decode`, `.group`, `.prep`, then
 `engine.codec.slow_molecule`, `.single`, `.gather`, `.place`, `.combine`,
 `.gates` and `resolve.serialize`; its run-report counters, by molecule:
 `codec.molecules` = `.emitted` + `.rejected` (`.rejected.<reason>`),
-`.slow_molecules`, `.strands`, `.single_strands`,
+`.slow_molecules`, `.row_molecules`, `.strands`, `.single_strands`,
 `.combine_cells_device` / `_host`, `.duplex_bases`, `.disagreements`
 (docs/observability.md).
 """
@@ -35,6 +45,7 @@ from ..native import batch as nb
 from ..observe.metrics import METRICS
 from ..observe.trace import span as _span
 from .codec import _ASCII_COMPLEMENT, _SS, combine_arrays
+from .simple_umi import _ACGTN_UPPER, consensus_umis_batch
 
 #: a molecule the classic ``prepare()`` returned nothing for is counted under
 #: the reason of the latest phase that recorded one (the phases run in order)
@@ -42,12 +53,98 @@ _PREPARE_REASONS = ("IndelErrorBetweenStrands", "InsufficientOverlap",
                     "InsufficientReads", "MinorityAlignment",
                     "NotPrimaryFrPair", "FragmentRead")
 
+#: simple_umi's all-equal rule as a byte table: only ``acgtn`` have an
+#: uppercase image in ACGTN, every other byte passes
+_RX_UPPER = np.arange(256, dtype=np.uint8)
+for _a, _b in _ACGTN_UPPER.items():
+    _RX_UPPER[_a] = _b
 
-def _count_reject(reason):
-    """One whole molecule rejected for ``reason`` (run-report counters; the
-    caller's own stats count reads)."""
-    METRICS.inc("codec.rejected")
-    METRICS.inc("codec.rejected." + reason)
+#: a strand's result codes -> the record's bases: the R1 strand as it was
+#: called, the R2 strand complemented (it is also reversed; `_finish_batch`)
+_BASE_OF_CODE = CODE_TO_BASE[np.minimum(np.arange(256), N_CODE)]
+_COMPLEMENT_OF_CODE = _ASCII_COMPLEMENT[_BASE_OF_CODE]
+
+#: where a strand's consensus lies after the dispatch (`_run`'s strand
+#: column ``src``): a row of the dense batch's result matrices, a row of the
+#: single-read table pass's, or an entry of the materialised list (the
+#: single-read strands of a classic-prepared molecule)
+_SRC_SLOT, _SRC_SINGLE, _SRC_ARRAYS = 0, 1, 2
+
+
+def _count_reject(reason, molecules=1):
+    """``molecules`` whole molecules rejected for ``reason`` (run-report
+    counters; the caller's own stats count reads)."""
+    if molecules:
+        METRICS.inc("codec.rejected", molecules)
+        METRICS.inc("codec.rejected." + reason, molecules)
+
+
+def _ragged_arange(starts, counts, step=1):
+    """``concatenate([arange(s, s + step * c, step) for s, c in zip(starts,
+    counts)])`` (``step`` 1 or -1) as one repeat and a running offset."""
+    excl = np.cumsum(counts) - counts
+    return np.repeat(starts - step * excl, counts) \
+        + step * np.arange(int(counts.sum()), dtype=np.int64)
+
+
+class _Molecules:
+    """The prepared molecules of one batch as columns, in emission order.
+
+    ``n_r1`` / ``n_r2`` reads a strand, ``len1`` / ``len2`` each strand's
+    consensus length (its longest clipped read), ``pk0`` the molecule's
+    first row in the span's pack arrays (R1 block, then R2 block),
+    ``r1_neg`` / ``r2_neg``, ``length`` the fragment's consensus length,
+    ``row_lo`` / ``row_hi`` the group's records in ``batch``. A molecule
+    from the classic ``prepare()`` (``classic`` >= 0: its index in
+    ``classic_mols``) keeps its SS jobs, MI and records in that dict.
+    ``pack_rows``: the batch row of every pack row (``--cell-tag`` only).
+    """
+
+    _INT = ("n_r1", "n_r2", "len1", "len2", "pk0", "length", "row_lo",
+            "row_hi", "classic")
+    _BOOL = ("r1_neg", "r2_neg")
+    __slots__ = _INT + _BOOL + ("classic_mols", "batch", "pack_rows")
+
+    def __init__(self, n, lead=(), batch=None):
+        """``n`` entries, the first ``len(lead)`` of them the
+        classic-prepared molecules ``lead``."""
+        for name in self._INT:
+            setattr(self, name, np.zeros(n, dtype=np.int64))
+        for name in self._BOOL:
+            setattr(self, name, np.zeros(n, dtype=bool))
+        self.classic[:] = -1
+        self.classic_mols = []
+        self.batch = batch
+        self.pack_rows = None
+        for i, mol in enumerate(lead):
+            self.set_classic(i, mol)
+
+    def __len__(self):
+        return len(self.classic)
+
+    def set_classic(self, i, mol):
+        """Entry ``i`` is the classic-prepared ``mol`` (appended)."""
+        self.classic[i] = len(self.classic_mols)
+        self.classic_mols.append(mol)
+        self.set_vec(i, -1, mol["n_r1"], mol["n_r2"],
+                     mol["job_r1"].consensus_len, mol["job_r2"].consensus_len,
+                     mol["r1_is_negative"], mol["r2_is_negative"],
+                     mol["consensus_length"])
+
+    def set_vec(self, i, pk0, n_r1, n_r2, len1, len2, r1_neg, r2_neg,
+                length):
+        self.pk0[i], self.n_r1[i], self.n_r2[i] = pk0, n_r1, n_r2
+        self.len1[i], self.len2[i] = len1, len2
+        self.r1_neg[i], self.r2_neg[i] = r1_neg, r2_neg
+        self.length[i] = length
+
+    def take(self, idx):
+        """The molecules ``idx`` (an index array), same batch."""
+        out = _Molecules(0, batch=self.batch)
+        for name in self._INT + self._BOOL:
+            setattr(out, name, getattr(self, name)[idx])
+        out.classic_mols, out.pack_rows = self.classic_mols, self.pack_rows
+        return out
 
 
 class FastCodecCaller:
@@ -107,27 +204,28 @@ class FastCodecCaller:
                 deferred = (batch.tag_bytes(self.tag, int(lo)).decode(),
                             batch.raw_records(np.arange(lo, hi)))
 
-        molecules = []
+        lead = []  # the carried molecule leaves first
         if self._carry is not None:
             if (not merge_carry) or final or n_total >= 2:
                 mi, recs = self._carry
                 self._carry = None
                 mol = self._prepare_slow(recs, mi)
                 if mol is not None:
-                    molecules.append(mol)
+                    lead.append(mol)
 
         codes_pk = quals_pk = None
         if g1 > g0:
             METRICS.inc("codec.molecules", g1 - g0)
             with _span("process.prep", rusage=True):
-                span_mols, codes_pk, quals_pk = self._prepare_span(
-                    batch, bounds, g0, g1)
-            molecules.extend(span_mols)
+                mols, codes_pk, quals_pk = self._prepare_span(
+                    batch, bounds, g0, g1, lead)
+        else:
+            mols = _Molecules(len(lead), lead)
 
         if deferred is not None:
             self._carry = deferred
 
-        out = self._run(molecules, codes_pk, quals_pk)
+        out = self._run(mols, codes_pk, quals_pk)
         if final:
             out.extend(self.flush())
         return out
@@ -138,7 +236,8 @@ class FastCodecCaller:
         mi, recs = self._carry
         self._carry = None
         mol = self._prepare_slow(recs, mi)
-        return self._run([mol] if mol is not None else [])
+        return self._run(_Molecules(1, [mol]) if mol is not None
+                         else _Molecules(0))
 
     def _prepare_slow(self, records, mi, counted=False):
         """One molecule through the classic ``prepare()`` (the semantic
@@ -146,6 +245,7 @@ class FastCodecCaller:
         the vectorized span, already in ``codec.molecules``) one whose CIGAR
         shape the closed forms do not cover."""
         METRICS.inc("codec.slow_molecules")
+        METRICS.inc("codec.row_molecules")
         if not counted:
             METRICS.inc("codec.molecules")
         before = dict(self.caller.stats.rejection_reasons)
@@ -164,7 +264,7 @@ class FastCodecCaller:
                 if reasons.get(r, 0) > before.get(r, 0)]
         _count_reject(grew[0] if grew else "NoUsableReads")
 
-    def _run(self, molecules, codes_pk=None, quals_pk=None):
+    def _run(self, mols, codes_pk=None, quals_pk=None):
         """One SS device pass + batched finish.
 
         Vec-prepared molecules (strand rows resident in the pack arrays)
@@ -174,78 +274,88 @@ class FastCodecCaller:
         Classic-prepared molecules (carry/fallback ConsensusJobs) repack
         their few rows into the same layout, so every batch costs exactly
         one device execution.
+
+        The strands are columns of 2M entries, strand ``2 * i + side`` of
+        molecule ``i``: ``cnt`` reads, ``slen`` consensus length, ``b0``
+        first pack row; the dispatch fills ``src`` (a ``_SRC_*``) and
+        ``srow`` (the row or entry in that source).
         """
         from ..ops import oracle
 
         caller = self.caller
         ss = caller.ss
-        if not molecules:
+        M = len(mols)
+        if M == 0:
             return []
-        strand_res = {}  # (mol_idx, strand) -> (bases, quals, depths, errs)
 
-        vec_multi = []       # (mol_idx, strand, base_row, count, cl)
-        vec_single = []      # (mol_idx, strand, base_row, cl)
-        classic_multi = []   # (mol_idx, strand, job)
-        classic_single = []  # (mol_idx, strand, job)
-        for i, m in enumerate(molecules):
-            if "job_r1" in m:
-                # carry/fallback molecules: the same dispatch, rows repacked
-                # below (a separate _run_jobs call would cost a second
-                # device execution on essentially every streamed batch)
-                for s, job in enumerate((m["job_r1"], m["job_r2"])):
-                    (classic_single if len(job.codes) == 1
-                     else classic_multi).append((i, s, job))
-                continue
-            base = m["pk0"]
-            for s, (b0, cnt, flens) in enumerate(
-                    ((base, m["n_r1"], m["r1_flens"]),
-                     (base + m["n_r1"], m["n_r2"], m["r2_flens"]))):
-                cl = int(flens.max())
-                if cnt == 1:
-                    vec_single.append((i, s, b0, cl))
-                else:
-                    vec_multi.append((i, s, b0, cnt, cl))
-        METRICS.inc("codec.strands", 2 * len(molecules))
-        METRICS.inc("codec.single_strands",
-                    len(vec_single) + len(classic_single))
+        def strands(r1_col, r2_col):
+            col = np.empty(2 * M, dtype=np.int64)
+            col[0::2] = r1_col
+            col[1::2] = r2_col
+            return col
 
-        if vec_single or classic_single:
+        cnt = strands(mols.n_r1, mols.n_r2)
+        slen = strands(mols.len1, mols.len2)
+        b0 = strands(mols.pk0, mols.pk0 + mols.n_r1)
+        src = np.full(2 * M, _SRC_SLOT, dtype=np.int8)
+        srow = np.zeros(2 * M, dtype=np.int64)
+        vec = np.repeat(mols.classic < 0, 2)
+        sv = np.nonzero(vec & (cnt == 1))[0]  # single-read strands
+        mv = np.nonzero(vec & (cnt > 1))[0]   # multi-read strands
+        # carry/fallback molecules: the same dispatch, rows repacked below
+        # (a separate _run_jobs call would cost a second device execution
+        # on essentially every streamed batch)
+        classic_multi = []   # (strand, job)
+        classic_single = []  # (strand, job)
+        for i in np.nonzero(mols.classic >= 0)[0]:
+            m = mols.classic_mols[mols.classic[i]]
+            for s, job in enumerate((m["job_r1"], m["job_r2"])):
+                (classic_single if len(job.codes) == 1
+                 else classic_multi).append((2 * int(i) + s, job))
+        METRICS.inc("codec.strands", 2 * M)
+        METRICS.inc("codec.single_strands", len(sv) + len(classic_single))
+
+        single_mats = None
+        arrays = []  # materialised strands: (bases, quals, depths, errors)
+        if len(sv) or classic_single:
             # the single-read strands never leave the host: one table pass
-            # over their pack rows (elementwise, so each strand's slice is
-            # what a call of its own would give)
+            # over their pack rows (elementwise, so each strand's row is
+            # what a call of its own would give), a second slot source
             with _span("engine.codec.single", rusage=True):
                 min_q = ss.options.min_consensus_base_quality
-                if vec_single:
-                    rows = np.fromiter((v[2] for v in vec_single), np.int64,
-                                       len(vec_single))
-                    b, q, d, e = oracle.single_read_consensus(
+                if len(sv):
+                    rows = b0[sv]
+                    single_mats = oracle.single_read_consensus(
                         codes_pk[rows], quals_pk[rows], ss.tables, min_q)
-                    for k, (i, s, _b0, cl) in enumerate(vec_single):
-                        strand_res[(i, s)] = (b[k, :cl], q[k, :cl],
-                                              d[k, :cl], e[k, :cl])
-                for i, s, job in classic_single:
+                    src[sv] = _SRC_SINGLE
+                    srow[sv] = np.arange(len(sv))
+                for s, job in classic_single:
                     cl = job.consensus_len
-                    strand_res[(i, s)] = oracle.single_read_consensus(
+                    res = oracle.single_read_consensus(
                         job.codes[0][:cl], job.quals[0][:cl], ss.tables,
                         min_q)
+                    src[s], srow[s], slen[s] = _SRC_ARRAYS, len(arrays), \
+                        len(res[0])
+                    arrays.append(res)
 
-        if vec_multi or classic_multi:
+        slot_mats = None
+        if len(mv) or classic_multi:
             with _span("engine.codec.gather", rusage=True):
-                cls = [(i, s, job.consensus_len, job)
-                       for i, s, job in classic_multi]
-                all_cl = [v[4] for v in vec_multi] + [c[2] for c in cls]
-                L_max = max(-(-max(all_cl) // 16) * 16, 16)
-                counts = np.array([v[3] for v in vec_multi]
-                                  + [len(c[3].codes) for c in cls],
-                                  dtype=np.int64)
-                n_vec_rows = int(sum(v[3] for v in vec_multi))
+                cnt_v = cnt[mv]
+                counts = np.concatenate(
+                    [cnt_v, np.array([len(job.codes)
+                                      for _, job in classic_multi],
+                                     dtype=np.int64)])
+                max_cl = max(([int(slen[mv].max())] if len(mv) else [])
+                             + [job.consensus_len
+                                for _, job in classic_multi])
+                L_max = max(-(-max_cl // 16) * 16, 16)
+                n_vec_rows = int(cnt_v.sum())
                 N = int(counts.sum())
                 codes2d = np.full((N, L_max), N_CODE, dtype=np.uint8)
                 quals2d = np.zeros((N, L_max), dtype=np.uint8)
-                if vec_multi:
-                    rows_idx = np.concatenate(
-                        [np.arange(b0, b0 + cnt)
-                         for _, _, b0, cnt, _ in vec_multi])
+                if len(mv):
+                    rows_idx = _ragged_arange(b0[mv], cnt_v)
                     # pack rows are N/Q0-padded past each read's final
                     # length, so a single fancy-index gather IS the dense job
                     # layout. A carry molecule's longer reads can push L_max
@@ -255,12 +365,14 @@ class FastCodecCaller:
                     wv = min(L_max, codes_pk.shape[1])
                     codes2d[:n_vec_rows, :wv] = codes_pk[rows_idx, :wv]
                     quals2d[:n_vec_rows, :wv] = quals_pk[rows_idx, :wv]
+                    srow[mv] = np.arange(len(mv))
                 row = n_vec_rows
-                for _, _, _, job in cls:
+                for k, (s, job) in enumerate(classic_multi):
+                    srow[s] = len(mv) + k
                     for c, q in zip(job.codes, job.quals):
-                        k = min(len(c), L_max)
-                        codes2d[row, :k] = c[:k]
-                        quals2d[row, :k] = q[:k]
+                        w = min(len(c), L_max)
+                        codes2d[row, :w] = c[:w]
+                        quals2d[row, :w] = q[:w]
                         row += 1
             # adaptive offload: host f64 engine or full-column wire,
             # decided per batch (ops/kernel helper)
@@ -269,8 +381,6 @@ class FastCodecCaller:
             w, q_, d, e = route_and_call_segments(ss.kernel, codes2d,
                                                   quals2d, counts,
                                                   mesh=self.mesh)
-            slots = [(v[0], v[1], v[4]) for v in vec_multi] \
-                + [(c[0], c[1], c[2]) for c in cls]
             # thresholds are elementwise: one vectorized pass over the whole
             # (F, L) batch, then per-slot length slicing (positions past a
             # slot's consensus length are computed and discarded)
@@ -278,71 +388,43 @@ class FastCodecCaller:
                 b_all, q_all = oracle.apply_consensus_thresholds(
                     w, q_, d, ss.options.min_reads,
                     ss.options.min_consensus_base_quality)
-            for fi, (i, s, cl) in enumerate(slots):
-                strand_res[(i, s)] = ("slot", fi, cl)
             slot_mats = (b_all, q_all, d, e)
-        else:
-            slot_mats = None
-        return self._finish_batch(molecules, strand_res, slot_mats)
+        return self._finish_batch(mols, src, srow, slen,
+                                  (slot_mats, single_mats, arrays))
 
-    @staticmethod
-    def _strand_len(entry) -> int:
-        # slot refs are ("slot", row, len) 3-tuples; materialized strands
-        # are (bases, quals, depths, errors) 4-tuples of arrays
-        return entry[2] if len(entry) == 3 else len(entry[0])
-
-    def _finish_batch(self, molecules, strand_res, slot_mats):
+    def _finish_batch(self, mols, src, srow, slen, sources):
         """Batched `_finish` (codec.py:527-568): strand geometry lands in
         concatenated position arrays, the duplex combine + quality-mask math
         of codec.py:360-456 runs once over all molecules (each molecule's
-        slice is element-identical to the per-molecule version), and records
-        serialize per molecule. Stats totals match the sequential path.
+        slice is element-identical to the per-molecule version), and the
+        records serialize in one native pass. Stats totals match the
+        sequential path.
 
-        Strand results arrive either as ("slot", row, len) references into
-        the batch (F, L) result matrices (the common case — the whole
-        orient/pad placement runs as ONE gather+scatter instead of 2 numpy
-        calls per molecule) or as materialized arrays (single-read and
-        classic-carry strands), placed scalarly."""
+        A strand's result is a row of one of two sets of result matrices
+        (``src``: the dense batch's (F, L), the single-read table pass's
+        (n_single, stride)); each set places its strands of a side with ONE
+        gather + scatter. The materialised strands of a carry or fallback
+        molecule (one or two a batch) are placed one by one."""
         from .vanilla import I16_MAX
 
         caller = self.caller
         st, opts = caller.stats, caller.options
         with _span("engine.codec.place", rusage=True):
-            keep = []
-            for i, mol in enumerate(molecules):
-                en1, en2 = strand_res[(i, 0)], strand_res[(i, 1)]
-                L = mol["consensus_length"]
-                if L < self._strand_len(en1) or L < self._strand_len(en2):
-                    st.reject("ClipOverlapFailed", mol["n_r1"] + mol["n_r2"])
-                    _count_reject("ClipOverlapFailed")
-                    continue
-                keep.append((mol, en1, en2))
-            if not keep:
-                return []
-            J = len(keep)
-            # ONE pass over the kept molecules collects every per-molecule
-            # scalar the batched placement/serialization needs (this loop ran
-            # five times before: lengths, two placement passes, rc flags,
-            # rejects)
-            Ls = np.empty(J, dtype=np.int64)
-            r1n = np.empty(J, dtype=bool)
-            r2n = np.empty(J, dtype=bool)
-            slot_j = ([], [])
-            slot_row = ([], [])
-            slot_k = ([], [])
-            # (side, j, en): materialized strands, placed scalarly
-            arr_items = []
-            for j, (mol, en1, en2) in enumerate(keep):
-                Ls[j] = mol["consensus_length"]
-                r1n[j] = mol["r1_is_negative"]
-                r2n[j] = mol["r2_is_negative"]
-                for side, en in ((0, en1), (1, en2)):
-                    if len(en) == 3:
-                        slot_j[side].append(j)
-                        slot_row[side].append(en[1])
-                        slot_k[side].append(en[2])
-                    else:
-                        arr_items.append((side, j, en))
+            failed = (mols.length < slen[0::2]) | (mols.length < slen[1::2])
+            n_failed = int(failed.sum())
+            if n_failed:
+                st.reject("ClipOverlapFailed",
+                          int((mols.n_r1 + mols.n_r2)[failed].sum()))
+                _count_reject("ClipOverlapFailed", n_failed)
+                keep = np.nonzero(~failed)[0]
+                if not len(keep):
+                    return []
+                mols = mols.take(keep)
+                sidx = np.repeat(2 * keep, 2)
+                sidx[1::2] += 1
+                src, srow, slen = src[sidx], srow[sidx], slen[sidx]
+            J = len(mols)
+            Ls, r1n, r2n = mols.length, mols.r1_neg, mols.r2_neg
             offs = np.zeros(J + 1, dtype=np.int64)
             np.cumsum(Ls, out=offs[1:])
             T = int(offs[-1])
@@ -360,60 +442,58 @@ class FastCodecCaller:
             e1 = np.zeros(T, np.int32)
             e2 = np.zeros(T, np.int32)
 
-            def place_arr(bases_c, quals, dep, err, rc, pad_left, o, L,
-                          b, q, d, e):
-                bases = CODE_TO_BASE[np.minimum(bases_c, N_CODE)]
-                k = len(bases)
+            def place_arr(bases_c, quals, dep, err, table, rc, pad_left, o,
+                          L, b, q, d, e):
+                k = len(bases_c)
                 sl = slice(o + L - k, o + L) if pad_left else slice(o, o + k)
-                if rc:
-                    b[sl] = _ASCII_COMPLEMENT[bases[::-1]]
-                    q[sl] = quals[::-1]
-                    d[sl] = np.minimum(dep[::-1], I16_MAX)
-                    e[sl] = np.minimum(err[::-1], I16_MAX)
-                else:
-                    b[sl] = bases
-                    q[sl] = quals
-                    d[sl] = np.minimum(dep, I16_MAX)
-                    e[sl] = np.minimum(err, I16_MAX)
+                step = -1 if rc else 1
+                b[sl] = table[bases_c[::step]]
+                q[sl] = quals[::step]
+                d[sl] = np.minimum(dep[::step], I16_MAX)
+                e[sl] = np.minimum(err[::step], I16_MAX)
 
-            def place_side(side, bt, qt, dt, et):
-                """One side's placement: slot-backed strands in one vectorized
-                gather+scatter; array-backed strands scalarly (collected by the
-                single pass above)."""
-                for aside, j, en in arr_items:
-                    if aside != side:
-                        continue
-                    rc = r1n[j] if side == 0 else not r1n[j]
-                    pl = r1n[j] if side == 0 else r2n[j]
-                    place_arr(en[0], en[1], en[2], en[3], bool(rc), bool(pl),
-                              int(offs[j]), int(Ls[j]), bt, qt, dt, et)
-                if not slot_j[side]:
-                    return
-                b_all, q_all, dmat, emat = slot_mats
-                jarr = np.asarray(slot_j[side], np.int64)
-                rows = np.asarray(slot_row[side], np.int64)
-                ks = np.asarray(slot_k[side], np.int64)
-                os_ = offs[jarr]
-                rcs = r1n[jarr] if side == 0 else ~r1n[jarr]
-                pls = r1n[jarr] if side == 0 else r2n[jarr]
-                base = os_ + np.where(pls, Ls[jarr] - ks, 0)
-                n_obs = int(ks.sum())
-                within = np.arange(n_obs, dtype=np.int64) \
-                    - np.repeat(np.concatenate(([0], np.cumsum(ks)[:-1]))
-                                if len(ks) else np.zeros(0, np.int64), ks)
-                tgt = np.repeat(base, ks) + within
-                rc_rep = np.repeat(rcs, ks)
-                src_col = np.where(rc_rep, np.repeat(ks, ks) - 1 - within,
-                                   within)
-                src_row = np.repeat(rows, ks)
-                bb = CODE_TO_BASE[np.minimum(b_all[src_row, src_col], N_CODE)]
-                bt[tgt] = np.where(rc_rep, _ASCII_COMPLEMENT[bb], bb)
-                qt[tgt] = q_all[src_row, src_col]
-                dt[tgt] = np.minimum(dmat[src_row, src_col], I16_MAX)
-                et[tgt] = np.minimum(emat[src_row, src_col], I16_MAX)
+            def place_rows(mats, rows, ks, base, table, rc, bt, qt, dt, et):
+                """Strands of one side at fragment positions ``base``, rows
+                ``rows`` (lengths ``ks``) of the result matrices ``mats``:
+                one gather + scatter, reversed where ``rc``."""
+                tgt = _ragged_arange(base, ks)
+                first = rows * mats[0].shape[1]
+                flat = _ragged_arange(first + ks - 1, ks, -1) if rc \
+                    else _ragged_arange(first, ks)
+                b_all, q_all, dmat, emat = (m.reshape(-1) for m in mats)
+                bt[tgt] = table[b_all[flat]]
+                qt[tgt] = q_all[flat]
+                dt[tgt] = np.minimum(dmat[flat], I16_MAX)
+                et[tgt] = np.minimum(emat[flat], I16_MAX)
 
-            place_side(0, b1, q1, d1, e1)
-            place_side(1, b2, q2, d2, e2)
+            # The strands land in the RECORD's orientation, not the
+            # fragment's (codec.py _finish orients both strands onto the
+            # forward fragment, combines, and reverse-complements the result
+            # of an R1-negative molecule): the R1 strand as it was called,
+            # from the record's first base; the R2 strand reverse-
+            # complemented, from the end its overlap geometry gives. The
+            # combine, the gates' sums and the quality masks are positionwise
+            # or symmetric, so every base is what the two steps would give
+            # and the serializer copies nothing around.
+            slot_mats, single_mats, arrays = sources
+            for side, table, out in ((0, _BASE_OF_CODE, (b1, q1, d1, e1)),
+                                     (1, _COMPLEMENT_OF_CODE,
+                                      (b2, q2, d2, e2))):
+                s_src, s_row, s_len = src[side::2], srow[side::2], \
+                    slen[side::2]
+                rc = side == 1
+                base = offs[:-1] + np.where(r1n ^ r2n, Ls - s_len, 0) \
+                    if rc else offs[:-1]
+                for which, mats in ((_SRC_SLOT, slot_mats),
+                                    (_SRC_SINGLE, single_mats)):
+                    jarr = np.nonzero(s_src == which)[0]
+                    if len(jarr):
+                        place_rows(mats, s_row[jarr], s_len[jarr],
+                                   base[jarr], table, rc, *out)
+                for j in np.nonzero(s_src == _SRC_ARRAYS)[0]:
+                    place_arr(*arrays[s_row[j]], table, rc,
+                              bool(rc and r1n[j] != r2n[j]), int(offs[j]),
+                              int(Ls[j]), *out)
 
         # ---- duplex combine, one pass over the concatenated strands:
         # device jit (ops/kernel._codec_combine_jit), native C pass, or
@@ -488,181 +568,274 @@ class FastCodecCaller:
                                       | (x == NO_CALL_BASE_LOWER))
                     cq[is_n(b1) | is_n(b2)] = opts.single_strand_qual
 
-            good = []
-            for j, (mol, _, _) in enumerate(keep):
-                if bad[j]:
-                    st.reject("HighDuplexDisagreement",
-                              mol["n_r1"] + mol["n_r2"])
-                    st.consensus_reads_rejected_hdd += 1
-                    _count_reject("HighDuplexDisagreement")
-                else:
-                    good.append(j)
+            n_bad = int(bad.sum())
+            if n_bad:
+                st.reject("HighDuplexDisagreement",
+                          int((mols.n_r1 + mols.n_r2)[bad].sum()))
+                st.consensus_reads_rejected_hdd += n_bad
+                _count_reject("HighDuplexDisagreement", n_bad)
+            good = np.nonzero(~bad)[0]
         # ---- record serialization
-        if not good:
+        if not len(good):
             return []
         METRICS.inc("codec.emitted", len(good))
         with _span("resolve.serialize", rusage=True):
-            if opts.cell_tag is not None:
-                # rare option: the cell tag needs per-record raw scans, so
-                # build through the classic RecordBuilder path
-                out = []
-                for j in good:
-                    mol = keep[j][0]
-                    sl = slice(int(offs[j]), int(offs[j] + Ls[j]))
-                    rc = mol["r1_is_negative"]
+            serialize = self._serialize_native if opts.cell_tag is None \
+                else self._serialize_records
+            return serialize(mols, good, offs, Ls, (cb, cq, cd, ce),
+                             (b1, q1, d1, e1), (b2, q2, d2, e2))
 
-                    def ss_of(b, q, d, e, count):
-                        if rc:
-                            return _SS(_ASCII_COMPLEMENT[b[sl][::-1]],
-                                       q[sl][::-1], d[sl][::-1], e[sl][::-1],
-                                       count)
-                        return _SS(b[sl], q[sl], d[sl], e[sl], count)
+    def _serialize_records(self, mols, good, offs, Ls, cons, side_a, side_b):
+        """``--cell-tag`` (rare): the cell tag needs each molecule's raw
+        source records, so every emitted molecule builds through the classic
+        RecordBuilder path, one at a time."""
+        caller = self.caller
+        batch = mols.batch
+        out = []
+        for j in good:
+            sl = slice(int(offs[j]), int(offs[j] + Ls[j]))
+            n_r1, n_r2 = int(mols.n_r1[j]), int(mols.n_r2[j])
+            if mols.classic[j] >= 0:
+                m = mols.classic_mols[mols.classic[j]]
+                umi, records, source_raws = m["umi"], m["records"], \
+                    m["source_raws"]
+            else:
+                rows = range(int(mols.row_lo[j]), int(mols.row_hi[j]))
+                umi = batch.tag_bytes(self.tag, rows.start).decode()
+                records = batch.raw_records(rows)
+                p0 = int(mols.pk0[j])
+                source_raws = [records[int(r) - rows.start] for r in
+                               mols.pack_rows[p0:p0 + n_r1 + n_r2]]
 
-                    rec = caller._build_record(
-                        ss_of(cb, cq, cd, ce, mol["n_r1"] + mol["n_r2"]),
-                        ss_of(b1, q1, d1, e1, mol["n_r1"]),
-                        ss_of(b2, q2, d2, e2, mol["n_r2"]),
-                        mol["umi"], mol["source_raws"], mol["records"],
-                        rx_umis=mol.get("rx_umis"))
-                    out.append(struct.pack("<I", len(rec)) + rec)
-                return out
+            def ss_of(arrs, count):
+                return _SS(*(a[sl] for a in arrs), count)
 
-            return self._serialize_native(keep, good, offs, Ls, r1n, cb, cq,
-                                          np.ascontiguousarray(ce,
-                                                               dtype=np.int64),
-                                          b1, q1, d1, e1, b2, q2, d2, e2)
+            # rx_umis=None: the RX consensus scans the group's records
+            rec = caller._build_record(
+                ss_of(cons, n_r1 + n_r2), ss_of(side_a, n_r1),
+                ss_of(side_b, n_r2), umi, source_raws, records)
+            out.append(struct.pack("<I", len(rec)) + rec)
+        return out
 
-    def _serialize_native(self, keep, good, offs, Ls, r1n, cb, cq, ce,
-                          b1, q1, d1, e1, b2, q2, d2, e2):
+    def _serialize_native(self, mols, good, offs, Ls, cons, side_a, side_b):
         """One native serialization pass (codec.py _build_record byte-exact).
 
-        The final reverse-complement for r1-negative molecules is a single
-        vectorized gather (consensus errors stay unreversed: they only feed
-        the cE sum and have no per-base tag); names/MI/RX pack into one blob
-        and all rows pass to C as raw addresses.
+        The arrays lie in the records' orientation already (`_finish_batch`
+        placed them so), so the rows pass to C as raw addresses into them
+        (the consensus depths are not a record field; its errors only feed
+        the cE sum); names, MI and RX go into one blob by ragged copies from
+        the batch's bytes (`_name_rx_blob`).
         """
-        from .simple_umi import consensus_umis_batch
-
         caller = self.caller
         st, opts = caller.stats, caller.options
-        T = int(offs[-1])
-        pos = np.arange(T, dtype=np.int64) - np.repeat(offs[:-1], Ls)
-        rc_rep = np.repeat(r1n, Ls)
-        src = np.where(rc_rep,
-                       np.repeat(offs[:-1] + Ls - 1, Ls) - pos,
-                       np.arange(T, dtype=np.int64))
+        u8 = lambda x: np.ascontiguousarray(x, dtype=np.uint8)
+        # the native builder reads 8-byte depth and error elements (the
+        # combine math upstream runs in int32)
+        i64 = lambda x: np.ascontiguousarray(x, dtype=np.int64)
+        cb, cq, ce = u8(cons[0]), u8(cons[1]), i64(cons[3])
+        b1, q1, a_d, a_e = u8(side_a[0]), u8(side_a[1]), i64(side_a[2]), \
+            i64(side_a[3])
+        b2, q2, b_d, b_e = u8(side_b[0]), u8(side_b[1]), i64(side_b[2]), \
+            i64(side_b[3])
 
-        def gath(a, comp=False, dtype=None):
-            # dtype=int64 where the native builder reads 8-byte elements
-            # (the combine math upstream runs in int32; widening costs a
-            # second copy of the gathered temp, cheap next to the combine)
-            g = np.ascontiguousarray(a[src], dtype=dtype)
-            if comp:
-                g[rc_rep] = _ASCII_COMPLEMENT[g[rc_rep]]
-            return g
-
-        seq = gath(cb, comp=True)
-        qual = gath(cq)
-        a_b = gath(b1, comp=True)
-        a_q = gath(q1)
-        a_d = gath(d1, dtype=np.int64)
-        a_e = gath(e1, dtype=np.int64)
-        b_b = gath(b2, comp=True)
-        b_q = gath(q2)
-        b_d = gath(d2, dtype=np.int64)
-        b_e = gath(e2, dtype=np.int64)
-
-        # RX consensus per molecule, all non-trivial families in one pass
-        fams = []
-        for j in good:
-            mol = keep[j][0]
-            ru = mol.get("rx_umis")
-            if ru is None:  # classic-prepared molecule: scan its records
-                ru = [u for u in (r.get_str(b"RX") for r in mol["records"])
-                      if u]
-            fams.append(ru)
-        nonempty = [i for i, f in enumerate(fams) if f]
-        consensi = consensus_umis_batch([fams[i] for i in nonempty]) \
-            if nonempty else []
-        rx_strs = [None] * len(fams)
-        for i, cu in zip(nonempty, consensi):
-            if cu:
-                rx_strs[i] = cu.encode()
-
-        # names / MI / RX share one blob; addresses point into it
         G = len(good)
-        blob = bytearray()
-        name_off = np.empty(G, np.int64)
-        name_len = np.empty(G, np.int32)
-        mi_off = np.zeros(G, np.int64)
-        mi_len = np.full(G, -1, np.int32)
-        rx_off = np.zeros(G, np.int64)
-        rx_len = np.zeros(G, np.int32)
-        prefix = caller.prefix
-        for k, j in enumerate(good):
-            umi = keep[j][0]["umi"]
-            caller._counter += 1
-            name = (f"{prefix}:{umi}" if umi
-                    else f"{prefix}:{caller._counter}").encode()
-            name_off[k] = len(blob)
-            name_len[k] = len(name)
-            blob.extend(name)
-            if umi:
-                mi = umi.encode()
-                mi_off[k] = len(blob)
-                mi_len[k] = len(mi)
-                blob.extend(mi)
-            if rx_strs[k] is not None:
-                rx_off[k] = len(blob)
-                rx_len[k] = len(rx_strs[k])
-                blob.extend(rx_strs[k])
-        blob_arr = np.frombuffer(bytes(blob), dtype=np.uint8)
-        base = blob_arr.ctypes.data if len(blob_arr) else 0
+        name_addr, name_len, mi_addr, mi_len, rx_addr, rx_len, keep_alive = \
+            self._name_rx_blob(mols.take(good) if G < len(mols) else mols)
 
-        gi = np.asarray(good, dtype=np.int64)
-        og = offs[:-1][gi]
+        og = offs[:-1][good]
         wire, rec_end = nb.build_codec_records(
-            seq.ctypes.data + og, qual.ctypes.data + og,
+            cb.ctypes.data + og, cq.ctypes.data + og,
             ce.ctypes.data + 8 * og,
-            a_b.ctypes.data + og, a_q.ctypes.data + og,
+            b1.ctypes.data + og, q1.ctypes.data + og,
             a_d.ctypes.data + 8 * og, a_e.ctypes.data + 8 * og,
-            b_b.ctypes.data + og, b_q.ctypes.data + og,
+            b2.ctypes.data + og, q2.ctypes.data + og,
             b_d.ctypes.data + 8 * og, b_e.ctypes.data + 8 * og,
-            Ls[gi], base + name_off, name_len,
-            np.where(mi_len >= 0, base + mi_off, 0), mi_len,
-            np.where(rx_len > 0, base + rx_off, 0), rx_len,
+            Ls[good], name_addr, name_len, mi_addr, mi_len, rx_addr, rx_len,
             caller.read_group_id.encode(), FLAG_UNMAPPED,
             opts.produce_per_base_tags)
+        del keep_alive
         st.consensus_reads_generated += G
         return [wire]  # records carry their block_size prefixes
 
+    def _name_rx_blob(self, mols):
+        """Names, MI and RX of the emitted molecules ``mols`` as addresses
+        for `nb.build_codec_records`: ``(name_addr, name_len, mi_addr,
+        mi_len, rx_addr, rx_len, keep_alive)``; ``mi_len`` -1 = no MI tag,
+        ``rx_addr`` 0 = no RX tag.
+
+        One ragged copy (`nb.concat_spans`) lays down ``prefix:MI`` a
+        molecule from the batch's bytes (the MI tag's value is the name's
+        tail: no second copy; the counter's digits where the MI is empty)
+        and each molecule's first RX. The RX consensus follows
+        `consensus_umis_batch`'s own trivial cases on bytes: one RX is taken
+        as it is; rows whose RX byte ranges all equal the first take it
+        through the ACGTN uppercase table. Only a molecule whose RX values
+        differ, or hold a byte past ASCII (``decode(errors="replace")``
+        rewrites it), or that came from the classic prepare with its own
+        records, goes to `consensus_umis_batch` as strings."""
+        caller = self.caller
+        G = len(mols)
+        batch = mols.batch
+        vec = mols.classic < 0
+        kv = np.nonzero(vec)[0]
+        head = (caller.prefix + ":").encode()
+        texts = bytearray()  # made in Python: counter names, a carry's MI
+        # name tail: (source, offset, length) a molecule; source 1 = the
+        # batch's bytes, 2 = ``texts``
+        t_src = np.ones(G, dtype=np.int32)
+        t_off = np.zeros(G, dtype=np.int64)
+        t_len = np.zeros(G, dtype=np.int32)
+        if len(kv):
+            mo, ml, _ = batch.tag_locs_str(self.tag)
+            first_rows = mols.row_lo[kv]
+            t_off[kv] = mo[first_rows]
+            t_len[kv] = ml[first_rows]
+        strings = {}  # molecule -> its RX values as the classic path reads
+        for k in np.nonzero(~vec)[0]:
+            m = mols.classic_mols[mols.classic[k]]
+            mi = (m["umi"] or "").encode()
+            t_src[k], t_off[k], t_len[k] = 2, len(texts), len(mi)
+            texts += mi
+            strings[int(k)] = [u for u in (r.get_str(b"RX")
+                                           for r in m["records"]) if u]
+        mi_len = np.where(t_len > 0, t_len, -1).astype(np.int32)
+        counter0 = caller._counter
+        caller._counter += G
+        for k in np.nonzero(t_len == 0)[0]:
+            digits = str(counter0 + int(k) + 1).encode()
+            t_src[k], t_off[k], t_len[k] = 2, len(texts), len(digits)
+            texts += digits
+
+        # RX: every vec molecule's present rows are consecutive entries of
+        # ``present_rows`` (a group's records are consecutive)
+        first_rx = np.zeros(0, dtype=np.int64)  # a batch row a molecule
+        kv_rx = kv[:0]       # vec molecules with an RX taken from bytes
+        upper = np.zeros(0, dtype=bool)
+        if len(kv):
+            ro, rl, _ = batch.tag_locs_str(b"RX")
+            present = (ro >= 0) & (rl > 0)
+            present_rows = np.nonzero(present)[0]
+            rank = np.zeros(len(present) + 1, dtype=np.int64)
+            np.cumsum(present, out=rank[1:])
+            r0 = rank[mols.row_lo[kv]]
+            n_rx = rank[mols.row_hi[kv]] - r0
+            some = n_rx > 0
+            kv_rx, r0, n_rx = kv[some], r0[some], n_rx[some]
+            first_rx = present_rows[r0]
+            upper = n_rx > 1
+            same = np.ones(len(kv_rx), dtype=bool)
+            if upper.any():
+                rows = present_rows[_ragged_arange(r0[upper], n_rx[upper])]
+                heads = np.repeat(first_rx[upper], n_rx[upper])
+                same[upper] = np.minimum.reduceat(
+                    nb.ranges_equal(batch.buf, ro[rows], rl[rows], ro[heads],
+                                    rl[heads]),
+                    np.cumsum(n_rx[upper]) - n_rx[upper])
+            for k in kv_rx[~same]:
+                strings[int(k)] = self._rx_strings(batch, mols, int(k))
+            # the verbatim values first, then those for the uppercase table
+            order = np.argsort(upper[same], kind="stable")
+            kv_rx, first_rx, upper = (a[same][order]
+                                      for a in (kv_rx, first_rx, upper))
+
+        # one ragged copy: [head, tail] a molecule, then the RX values
+        n_rx_spans = len(kv_rx)
+        sid = np.empty(2 * G + n_rx_spans, dtype=np.int32)
+        off = np.empty(len(sid), dtype=np.int64)
+        ln = np.empty(len(sid), dtype=np.int32)
+        sid[0:2 * G:2], off[0:2 * G:2], ln[0:2 * G:2] = 0, 0, len(head)
+        sid[1:2 * G:2], off[1:2 * G:2], ln[1:2 * G:2] = t_src, t_off, t_len
+        if n_rx_spans:
+            sid[2 * G:], off[2 * G:], ln[2 * G:] = 1, ro[first_rx], \
+                rl[first_rx]
+        nothing = np.zeros(1, dtype=np.uint8)
+        blob, at = nb.concat_spans(
+            [np.frombuffer(head, dtype=np.uint8),
+             batch.buf if batch is not None else nothing,
+             np.frombuffer(bytes(texts), dtype=np.uint8) if texts
+             else nothing], sid, off, ln)
+        base = blob.ctypes.data
+        name_addr = base + at[0:2 * G:2]
+        name_len = (at[2:2 * G + 2:2] - at[0:2 * G:2]).astype(np.int32)
+        mi_addr = np.where(mi_len >= 0, name_addr + len(head), 0)
+        rx_addr = np.zeros(G, dtype=np.int64)
+        rx_len = np.zeros(G, dtype=np.int32)
+        if n_rx_spans:
+            rx_at = at[2 * G:]
+            n_verbatim = n_rx_spans - int(upper.sum())
+            region = blob[rx_at[0]:rx_at[-1]]
+            high = np.nonzero(region >= 0x80)[0]
+            if len(high):
+                # bytes past ASCII: those molecules' values as strings
+                hit = np.unique(np.searchsorted(rx_at[1:] - rx_at[0], high,
+                                                side="right"))
+                for h in hit:
+                    strings[int(kv_rx[h])] = self._rx_strings(
+                        batch, mols, int(kv_rx[h]))
+            tail = blob[rx_at[n_verbatim]:rx_at[-1]]
+            tail[:] = _RX_UPPER[tail]
+            rx_addr[kv_rx] = base + rx_at[:-1]
+            rx_len[kv_rx] = np.diff(rx_at)
+
+        keep_alive = [blob]
+        if strings:
+            ks = sorted(strings)
+            texts = bytearray()
+            cut = [0]
+            for cu in consensus_umis_batch([strings[k] for k in ks]):
+                texts += (cu or "").encode()
+                cut.append(len(texts))
+            consensi = np.frombuffer(bytes(texts) or b"\0", dtype=np.uint8)
+            keep_alive.append(consensi)
+            ks = np.asarray(ks, dtype=np.int64)
+            rx_len[ks] = np.diff(cut)
+            rx_addr[ks] = np.where(rx_len[ks] > 0, consensi.ctypes.data
+                                   + np.asarray(cut[:-1], dtype=np.int64), 0)
+        return name_addr, name_len, mi_addr, mi_len, rx_addr, rx_len, \
+            keep_alive
+
+    @staticmethod
+    def _rx_strings(batch, mols, k):
+        """The RX values of vec molecule ``k``'s records, as
+        ``RawRecord.get_str`` reads them (Z/H typed, lenient decode)."""
+        ro, rl, _ = batch.tag_locs_str(b"RX")
+        buf = batch.buf
+        return [buf[ro[r]:ro[r] + rl[r]].tobytes().decode(errors="replace")
+                for r in range(int(mols.row_lo[k]), int(mols.row_hi[k]))
+                if ro[r] >= 0 and rl[r] > 0]
+
     # ---------------------------------------------------------------- prepare
 
-    def _prepare_span(self, batch, bounds, g0, g1):
-        """Vectorized prepare for complete groups [g0, g1); shape-ineligible
-        molecules run the classic prepare in stream order."""
+    def _prepare_span(self, batch, bounds, g0, g1, lead=()):
+        """Vectorized prepare for complete groups [g0, g1), after the
+        classic-prepared molecules ``lead``: the molecules as columns
+        (`_Molecules`) and their pack arrays. The groups the closed forms
+        cover fill their columns in whole-array passes and their verdicts
+        reject by mask; the others (a CIGAR that is not one M run, names
+        that collide in the hash, a downsample) are prepared one at a time,
+        in stream order among themselves."""
         caller = self.caller
+        st = caller.stats
         buf = batch.buf
         lo, hi = int(bounds[g0]), int(bounds[g1])
         span = np.arange(lo, hi)
         flag = batch.flag
-        l_seq = batch.l_seq
+        l_seq = batch.l_seq[lo:hi]
+        nG = g1 - g0
+        gb = bounds[g0:g1 + 1] - lo  # the groups' bounds within the span
 
         # single-op all-M CIGAR covering the whole read
-        co = batch.cigar_off
+        co = batch.cigar_off[lo:hi]
         v = np.zeros(len(span), dtype=np.uint32)
         for j in range(4):
-            v |= buf[co[span] + j].astype(np.uint32) << (8 * j)
-        m_only = ((batch.n_cigar[span] == 1) & ((v & 0xF) == 0)
-                  & ((v >> 4) == l_seq[span]) & (l_seq[span] > 0))
-        fl = flag[span]
+            v |= buf[co + j].astype(np.uint32) << (8 * j)
+        m_only = ((batch.n_cigar[lo:hi] == 1) & ((v & 0xF) == 0)
+                  & ((v >> 4) == l_seq) & (l_seq > 0))
+        fl = flag[lo:hi]
         paired_primary = ((fl & FLAG_PAIRED) != 0) \
             & ((fl & (FLAG_SECONDARY | FLAG_SUPPLEMENTARY)) == 0)
-        row_ok = m_only | ~paired_primary
-        g_of_row = np.repeat(np.arange(g1 - g0),
-                             np.diff(bounds[g0:g1 + 1]))
-        grp_ok = np.ones(g1 - g0, dtype=bool)
-        np.logical_and.at(grp_ok, g_of_row, row_ok)
+        g_of_row = np.repeat(np.arange(nG), np.diff(gb))
+        grp_ok = np.logical_and.reduceat(m_only | ~paired_primary, gb[:-1])
 
         # phases 1-2 (primary-pair formation by name + clip closed forms)
         # run once over the whole eligible span, then phases 3-4 (overlap
@@ -672,108 +845,105 @@ class FastCodecCaller:
         pair_of_group, py_groups, geom = self._pair_span(
             batch, span, g_of_row, grp_ok, fl, paired_primary)
 
+        nL = len(lead)
+        mols = _Molecules(nL + nG, lead, batch)
+        mols.row_lo[nL:] = bounds[g0:g1]
+        mols.row_hi[nL:] = bounds[g0 + 1:g1 + 1]
+        alive = np.zeros(nL + nG, dtype=bool)
+        alive[:nL] = True
+        # a group with no verdict yet: not in the geometry, not an exception
+        unpaired = grp_ok.copy()
+        by_row = ~grp_ok  # groups prepared one at a time
+        if py_groups:
+            by_row[np.fromiter(py_groups, np.int64, len(py_groups))] = True
+            unpaired &= ~by_row
+
         # bulk pack layout of the geometry-ok groups occupies [0, pk_base);
         # per-molecule fallbacks append after it
-        st = caller.stats
-        nG = g1 - g0
-        loc = np.full(nG, -1, dtype=np.int64)
+        pk_base = 0
         if geom is not None:
-            loc[geom["gid"]] = np.arange(len(geom["gid"]))
-        pk_base = len(geom["pack0"]) if geom is not None else 0
+            pk_base = len(geom["pack0"])
+            gid, n_g = geom["gid"], geom["n_g"]
+            unpaired[gid] = False
+            ok = geom["okg"]
+            k = nL + gid[ok]
+            alive[k] = True
+            mols.pk0[k] = geom["pk0_seg"][ok]
+            mols.n_r1[k] = mols.n_r2[k] = n_g[ok]
+            mols.len1[k], mols.len2[k] = geom["m1"][ok], geom["m2"][ok]
+            mols.r1_neg[k], mols.r2_neg[k] = geom["r1_neg"][ok], \
+                geom["r2_neg"][ok]
+            mols.length[k] = geom["consensus_length"][ok]
+            # the verdicts in the phases' order; a group that downsamples
+            # consumes the shared RNG stream and gets its own from its own
+            # prepare, below
+            by_row[gid[geom["downs"]]] = True
+            open_ = ~geom["downs"]
+            for reason, verdict in (
+                    ("InsufficientReads", geom["small"]),
+                    ("InsufficientOverlap", geom["short"]),
+                    ("IndelErrorBetweenStrands", geom["indel"])):
+                hit = open_ & verdict
+                open_ &= ~verdict
+                n_hit = int(hit.sum())
+                if n_hit:
+                    st.reject(reason, 2 * int(n_g[hit].sum()))
+                    _count_reject(reason, n_hit)
+        n_unpaired = int(unpaired.sum())
+        if n_unpaired:
+            # no surviving FR pair (the reads were counted in _pair_span)
+            n_pp = int((unpaired & np.logical_or.reduceat(
+                paired_primary, gb[:-1])).sum())
+            _count_reject("NotPrimaryFrPair", n_pp)
+            _count_reject("FragmentRead", n_unpaired - n_pp)
 
-        mols = []
         pack_rows = []     # per-molecule fallback rows, after the bulk block
         pack_clips = []
-        pending = []       # (kind, payload) preserving stream order
-        for g in range(g0, g1):
-            rows = np.arange(int(bounds[g]), int(bounds[g + 1]))
-            mi = batch.tag_bytes(self.tag, int(rows[0])).decode()
-            if not grp_ok[g - g0]:
+        for g in np.nonzero(by_row)[0]:
+            rows = np.arange(int(bounds[g0 + g]), int(bounds[g0 + g + 1]))
+            if not grp_ok[g]:
                 # classic prepare runs HERE, in stream order — the shared
                 # downsample RNG stream must see molecules in input order
+                mi = batch.tag_bytes(self.tag, int(rows[0])).decode()
                 mol = self._prepare_slow(batch.raw_records(rows), mi,
                                          counted=True)
-                pending.append(("mol", mol) if mol is not None
-                               else ("none", None))
+                if mol is not None:
+                    mols.set_classic(nL + g, mol)
+                    alive[nL + g] = True
                 continue
-            if (g - g0) in py_groups:
-                before = dict(st.rejection_reasons)
-                prep = self._prepare_molecule_vec(batch, rows, mi, pack_rows,
+            METRICS.inc("codec.row_molecules")
+            before = dict(st.rejection_reasons)
+            if int(g) in py_groups:
+                prep = self._prepare_molecule_vec(batch, rows, pack_rows,
                                                   pack_clips, pk_base)
-                if prep is None:
-                    self._count_prepare_reject(before)
             else:
-                k = int(loc[g - g0])
-                if k < 0:
-                    prep = None  # no surviving FR pair in this group
-                    _count_reject("NotPrimaryFrPair"
-                                  if paired_primary[rows - lo].any()
-                                  else "FragmentRead")
-                elif geom["small"][k]:
-                    st.reject("InsufficientReads", 2 * int(geom["n_g"][k]))
-                    _count_reject("InsufficientReads")
-                    prep = None
-                elif geom["downs"][k]:
-                    # downsample consumes the shared RNG stream — the
-                    # per-molecule reference path runs, in stream order
-                    before = dict(st.rejection_reasons)
-                    prep = self._finish_molecule_vec(
-                        rows, mi, pair_of_group.get(g - g0), pack_rows,
-                        pack_clips, pk_base)
-                    if prep is None:
-                        self._count_prepare_reject(before)
-                elif geom["short"][k]:
-                    st.reject("InsufficientOverlap", 2 * int(geom["n_g"][k]))
-                    _count_reject("InsufficientOverlap")
-                    prep = None
-                elif geom["indel"][k]:
-                    st.reject("IndelErrorBetweenStrands",
-                              2 * int(geom["n_g"][k]))
-                    _count_reject("IndelErrorBetweenStrands")
-                    prep = None
-                else:
-                    s_, e_ = int(geom["starts"][k]), int(geom["ends"][k])
-                    prep = {
-                        "mi": mi, "rows": rows,
-                        "pk0": int(geom["pk0_seg"][k]),
-                        "r1_rows": geom["r1"][s_:e_],
-                        "r2_rows": geom["r2"][s_:e_],
-                        "r1_flens": geom["flen1"][s_:e_],
-                        "r2_flens": geom["flen2"][s_:e_],
-                        "r1_neg": bool(geom["r1_neg"][k]),
-                        "r2_neg": bool(geom["r2_neg"][k]),
-                        "consensus_length":
-                            int(geom["consensus_length"][k]),
-                    }
-            pending.append(("vec", prep) if prep is not None
-                           else ("none", None))
+                prep = self._finish_molecule_vec(
+                    pair_of_group[int(g)], pack_rows, pack_clips, pk_base)
+            if prep is None:
+                self._count_prepare_reject(before)
+            else:
+                mols.set_vec(nL + g, *prep)
+                alive[nL + g] = True
 
         codes_pk = quals_pk = None
         if pk_base or pack_rows:
-            parts_r = []
-            parts_c = []
+            rows_arr, clips_arr = np.asarray(pack_rows, dtype=np.int64), \
+                np.asarray(pack_clips, dtype=np.int64)
             if pk_base:
-                parts_r.append(geom["pack0"])
-                parts_c.append(geom["clips0"])
-            if pack_rows:
-                parts_r.append(np.asarray(pack_rows, dtype=np.int64))
-                parts_c.append(np.asarray(pack_clips, dtype=np.int64))
-            rows_arr = np.concatenate(parts_r)
-            clips_arr = np.concatenate(parts_c)
-            stride = max(-(-int(l_seq[rows_arr].max()) // 32) * 32, 32)
+                rows_arr = np.concatenate([geom["pack0"], rows_arr])
+                clips_arr = np.concatenate([geom["clips0"], clips_arr])
+            row_len = batch.l_seq[rows_arr]
+            stride = max(-(-int(row_len.max()) // 32) * 32, 32)
             rev = ((flag[rows_arr] & FLAG_REVERSE) != 0).astype(np.uint8)
             codes_pk, quals_pk, _ = nb.pack_reads(
                 buf, np.ascontiguousarray(batch.seq_off[rows_arr]),
                 np.ascontiguousarray(batch.qual_off[rows_arr]),
-                l_seq[rows_arr], rev,
-                clips_arr.astype(np.int32), 0, stride, mode=3)
+                row_len, rev, clips_arr.astype(np.int32), 0, stride, mode=3)
+            mols.pack_rows = rows_arr
 
-        for item in pending:
-            if item[0] == "mol":
-                mols.append(item[1])
-            elif item[0] == "vec":
-                mols.append(self._finalize_vec(batch, item[1]))
-        return [m for m in mols if m is not None], codes_pk, quals_pk
+        if not alive.all():
+            mols = mols.take(np.nonzero(alive)[0])
+        return mols, codes_pk, quals_pk
 
     def _pair_span(self, batch, span, g_of_row, grp_ok, fl_span, pp_span):
         """Phases 1-2 for every eligible group in one pass: primary FR
@@ -999,17 +1169,15 @@ class FastCodecCaller:
                 "indel": indel, "okg": okg, "r1_neg": r1_neg,
                 "r2_neg": r2_neg, "consensus_length": consensus_length,
                 "pk0_seg": pk0_seg, "pack0": pack0, "clips0": clips0,
-                "r1": r1, "r2": r2, "flen1": flen1, "flen2": flen2}
+                "m1": m1, "m2": m2}
 
-    def _finish_molecule_vec(self, rows, mi, pairs, pack_rows, pack_clips,
-                             pk_base=0):
-        """Phases 3-5 for one group given its span-paired arrays; returns a
-        partial mol (pack rows staged) or None with classic reject stats."""
+    def _finish_molecule_vec(self, pairs, pack_rows, pack_clips, pk_base=0):
+        """Phases 3-5 for one group given its span-paired arrays; returns
+        the molecule's columns (`_Molecules.set_vec`'s arguments, pack rows
+        staged) or None with classic reject stats."""
         caller = self.caller
         st = caller.stats
         opts = caller.options
-        if pairs is None:  # no surviving FR pair in this group
-            return None
         (r1, c1, rev1, flen1, adj1, r2, c2, rev2, flen2, adj2) = pairs
         n = len(r1)
         if n < opts.min_reads_per_strand:
@@ -1059,18 +1227,14 @@ class FastCodecCaller:
         pack_clips.extend(c1.tolist())
         pack_rows.extend(r2.tolist())
         pack_clips.extend(c2.tolist())
-        return {
-            "mi": mi, "rows": rows, "pk0": pk0,
-            "r1_rows": r1, "r2_rows": r2,
-            "r1_flens": flen1, "r2_flens": flen2,
-            "r1_neg": r1_neg, "r2_neg": r2_neg,
-            "consensus_length": consensus_length,
-        }
+        return (pk0, n, n, int(flen1[i1]), int(flen2[i2]), r1_neg, r2_neg,
+                consensus_length)
 
-    def _prepare_molecule_vec(self, batch, rows, mi, pack_rows, pack_clips,
+    def _prepare_molecule_vec(self, batch, rows, pack_rows, pack_clips,
                               pk_base=0):
-        """Phases 1-4 on arrays; returns a partial mol (pack indices staged)
-        or None (rejected, reasons recorded like classic prepare)."""
+        """Phases 1-4 on arrays; returns the molecule's columns
+        (`_Molecules.set_vec`'s arguments, pack rows staged) or None
+        (rejected, reasons recorded like classic prepare)."""
         caller = self.caller
         st = caller.stats
         opts = caller.options
@@ -1186,55 +1350,8 @@ class FastCodecCaller:
         for i in r2i:
             pack_rows.append(i[0])
             pack_clips.append(i[1])
-        return {
-            "mi": mi, "rows": rows, "pk0": pk0,
-            "r1_rows": np.array([i[0] for i in r1i], dtype=np.int64),
-            "r2_rows": np.array([i[0] for i in r2i], dtype=np.int64),
-            "r1_flens": np.array([i[3] for i in r1i], dtype=np.int64),
-            "r2_flens": np.array([i[3] for i in r2i], dtype=np.int64),
-            "r1_neg": r1_neg, "r2_neg": r2_neg,
-            "consensus_length": consensus_length,
-        }
-
-    def _finalize_vec(self, batch, prep):
-        """Phase 5: the mol dict for the dense dispatch in _run.
-
-        No SS jobs are materialized — the strand rows stay resident in the
-        span's pack arrays and _run gathers them directly (the SS caller's
-        min_reads=1 / max_reads=None construction makes per-strand
-        consensus_len = longest clipped read, carried via the flens).
-        """
-        caller = self.caller
-        f1, f2 = prep["r1_flens"], prep["r2_flens"]
-        umi = prep["mi"]
-        if caller.options.cell_tag is not None:
-            # only the cell-tag fallback reads raw records back
-            records = batch.raw_records(prep["rows"])
-            row_to_rec = {int(r): rec
-                          for r, rec in zip(prep["rows"], records)}
-            source_raws = [row_to_rec[int(r)] for r in
-                           np.concatenate([prep["r1_rows"], prep["r2_rows"]])]
-        else:
-            records, source_raws = None, None
-        # RX strings for the whole group from the batch tag scan (same Z/H
-        # gate and lenient decode as RawRecord.get_str; codec.py RX consensus)
-        rx_off, rx_len, _ = batch.tag_locs_str(b"RX")
-        buf = batch.buf
-        rx_umis = []
-        for r in prep["rows"]:
-            o, ln = int(rx_off[r]), int(rx_len[r])
-            if o >= 0 and ln > 0:
-                rx_umis.append(buf[o:o + ln].tobytes().decode(errors="replace"))
-        return {
-            "umi": umi, "records": records,
-            "pk0": prep["pk0"], "r1_flens": f1, "r2_flens": f2,
-            "n_r1": len(f1), "n_r2": len(f2),
-            "r1_is_negative": prep["r1_neg"],
-            "r2_is_negative": prep["r2_neg"],
-            "consensus_length": prep["consensus_length"],
-            "source_raws": source_raws,
-            "rx_umis": rx_umis,
-        }
+        return (pk0, len(r1i), len(r2i), L1[3], L2[3], r1_neg, r2_neg,
+                consensus_length)
 
     @staticmethod
     def _is_primary_fr_pair(batch, ia, ib):

@@ -243,10 +243,10 @@ def test_carry_reads_longer_than_span(tmp_path):
     """A carried molecule's reads can be longer than every read in the next
     batch's span, pushing the dispatch L_max past the span's pack stride;
     the dense gather must clamp its width (N/Q0 tails) instead of crashing.
-    Drives _run directly with a mixed vec + classic molecule list and checks
+    Drives _run directly with a mixed vec + classic molecule table and checks
     it against the same molecules run classic-only."""
     from fgumi_tpu.consensus.codec import CodecConsensusCaller, CodecOptions
-    from fgumi_tpu.consensus.fast_codec import FastCodecCaller
+    from fgumi_tpu.consensus.fast_codec import FastCodecCaller, _Molecules
     from fgumi_tpu.consensus.vanilla import ConsensusJob, R1
 
     rng = np.random.default_rng(8)
@@ -264,54 +264,50 @@ def test_carry_reads_longer_than_span(tmp_path):
     c2, q2 = strand_rows(2, 40, stride)
     codes_pk = np.vstack([c1, c2])
     quals_pk = np.vstack([q1, q2])
-    vec_mol = {
-        "umi": "7", "records": None, "source_raws": None, "rx_umis": [],
-        "pk0": 0, "n_r1": 2, "n_r2": 2,
-        "r1_flens": np.array([40, 40], dtype=np.int64),
-        "r2_flens": np.array([40, 40], dtype=np.int64),
-        "r1_is_negative": False, "r2_is_negative": True,
-        "consensus_length": 40,
-    }
     lc, lq = strand_rows(4, long_len, long_len)
 
-    def long_mol():
+    def classic_mol(umi, codes, quals, length):
         def job(rows):
             return ConsensusJob(
-                umi="9", read_type=R1,
-                codes=[lc[r, :long_len] for r in rows],
-                quals=[lq[r, :long_len] for r in rows],
-                consensus_len=long_len, original_raws=[])
+                umi=umi, read_type=R1,
+                codes=[codes[r, :length] for r in rows],
+                quals=[quals[r, :length] for r in rows],
+                consensus_len=length, original_raws=[])
 
         return {
-            "umi": "9", "records": [], "source_raws": [], "rx_umis": [],
+            "umi": umi, "records": [], "source_raws": [],
             "job_r1": job([0, 1]), "job_r2": job([2, 3]),
             "n_r1": 2, "n_r2": 2,
             "r1_is_negative": False, "r2_is_negative": True,
-            "consensus_length": long_len,
+            "consensus_length": length,
         }
+
+    class OneGroupBatch:
+        """The vec molecule's group: one record, MI 7, no RX."""
+        buf = np.frombuffer(b"7", dtype=np.uint8)
+
+        def tag_locs_str(self, tag):
+            if tag == b"MI":
+                return np.array([0]), np.array([1], np.int32), None
+            return np.array([-1]), np.array([0], np.int32), None
+
+    # the carried molecule (classic jobs) first, then a vec molecule whose
+    # four strand rows lie in the pack arrays
+    mols = _Molecules(2, [classic_mol("9", lc, lq, long_len)],
+                      OneGroupBatch())
+    mols.set_vec(1, 0, 2, 2, 40, 40, False, True, 40)
+    mols.row_hi[1] = 1
 
     caller = CodecConsensusCaller("fgumi", "A", CodecOptions())
     fast = FastCodecCaller(caller, b"MI")
-    mixed = b"".join(fast._run([long_mol(), vec_mol], codes_pk, quals_pk))
+    mixed = b"".join(fast._run(mols, codes_pk, quals_pk))
 
     # reference: the same two molecules, both via the classic-job path
-    def vec_as_classic():
-        def job(base):
-            return ConsensusJob(
-                umi="7", read_type=R1,
-                codes=[codes_pk[base + k, :40] for k in range(2)],
-                quals=[quals_pk[base + k, :40] for k in range(2)],
-                consensus_len=40, original_raws=[])
-
-        m = dict(vec_mol)
-        for k in ("pk0", "r1_flens", "r2_flens"):
-            del m[k]
-        m["job_r1"], m["job_r2"] = job(0), job(2)
-        return m
-
     caller2 = CodecConsensusCaller("fgumi", "A", CodecOptions())
     fast2 = FastCodecCaller(caller2, b"MI")
-    ref = b"".join(fast2._run([long_mol(), vec_as_classic()], None, None))
+    ref = b"".join(fast2._run(_Molecules(
+        2, [classic_mol("9", lc, lq, long_len),
+            classic_mol("7", codes_pk, quals_pk, 40)])))
     assert mixed == ref
 
 
@@ -370,3 +366,436 @@ def test_all_groups_shape_ineligible(tmp_path):
         for r in records:
             w.write_record_bytes(r)
     assert_cli_parity(path, tmp_path, ["--min-reads", "1"])
+
+
+# ---------------------------------------------------------------- columns
+#
+# The batch engine carries a batch's molecules as columns and loops only over
+# the molecules its closed forms do not cover. One stream holds every shape
+# those two paths have to agree on, and every case below runs it through both
+# engines, cut into batches of ``n_records`` so that molecules of every shape
+# get carried across a boundary too.
+
+def _mixed_stream(path):
+    """An MI-grouped BAM whose reads come from one reference (so a
+    molecule's strands agree where they should): single- and multi-pair
+    molecules, R1 forward and R1 reverse, a soft-clipped group, an empty MI,
+    RX values equal / differing / absent / lower case / dual / on one record
+    only / not ASCII, a deep molecule (downsampling), a short overlap, a
+    molecule out of phase, strands that disagree, fragments and a
+    same-strand pair, cell tags on some reads."""
+    rng = np.random.default_rng(5)
+    header = BamHeader(
+        text="@HD\tVN:1.6\tSO:unsorted\tGO:query\n@SQ\tSN:c\tLN:100000\n",
+        ref_names=["c"], ref_lengths=[100000])
+    bases = np.frombuffer(b"ACGT", np.uint8)
+    truth = rng.choice(bases, size=100000)
+
+    def rec(name, flag, pos, length, mi, next_pos, tlen, cigar=None,
+            rx=b"ACGTAC", cb=None, noise=0.0):
+        sq = truth[pos:pos + length].copy()
+        wrong = rng.random(length) < noise
+        sq[wrong] = rng.choice(bases, size=int(wrong.sum()))
+        b = RecordBuilder().start_mapped(
+            name, flag, 0, pos, 60, cigar or [("M", length)], bytes(sq),
+            rng.integers(20, 41, size=length).astype(np.uint8),
+            next_ref_id=0, next_pos=next_pos, tlen=tlen)
+        b.tag_str(b"MI", mi)
+        if rx is not None:
+            b.tag_str(b"RX", rx)
+        if cb is not None:
+            b.tag_str(b"CB", cb)
+        return b.finish()
+
+    def fr_pair(name, mi, p1, p2, length=60, length2=None, r1_reverse=False,
+                rx=b"ACGTAC", rx2=0, cigars=(None, None), **kw):
+        """The forward read at p1, the reverse at p2 (``length2`` long if it
+        differs); R1 is the forward read unless ``r1_reverse``. ``rx2``: the
+        second record's RX if it differs from the first's."""
+        length2 = length2 or length
+        tlen = p2 + length2 - p1
+        f1, f2 = (0x80, 0x40) if r1_reverse else (0x40, 0x80)
+        rx2 = rx if rx2 == 0 else rx2
+        fwd = rec(name, 0x1 | f1 | 0x20, p1, length, mi, p2, tlen,
+                  cigar=cigars[0], rx=rx2 if r1_reverse else rx, **kw)
+        rev = rec(name, 0x1 | f2 | 0x10, p2, length2, mi, p1, -tlen,
+                  cigar=cigars[1], rx=rx if r1_reverse else rx2, **kw)
+        return [rev, fwd] if r1_reverse else [fwd, rev]
+
+    records = []
+    mol = [0]
+
+    def molecule(pairs, mi=None, **kw):
+        m = mol[0]
+        mol[0] += 1
+        mi = b"%d" % m if mi is None else mi
+        p1 = 1000 + 300 * m
+        for t in range(pairs):
+            records.extend(fr_pair(b"m%dt%d" % (m, t), mi, p1, p1 + 25, **kw))
+
+    for _ in range(3):  # the common shapes, several times over
+        molecule(3)
+        molecule(1)
+        molecule(2, r1_reverse=True)
+        molecule(1, r1_reverse=True)
+    molecule(2, cigars=([("S", 4), ("M", 56)], [("M", 56), ("S", 4)]))
+    molecule(2, mi=b"")                        # named by the counter
+    molecule(1, rx=b"")                        # an empty UMI is no UMI
+    molecule(3, rx=b"ACGTAC", rx2=b"ACGTTC")   # differing RX: likelihood
+    molecule(2, rx=None)                       # no RX at all
+    molecule(2, rx=b"acgtna")                  # equal, lower case
+    molecule(1, rx=b"acgtna", rx2=None)        # one RX: taken as it is
+    molecule(2, rx=b"ACG-TTA", r1_reverse=True)  # a dual UMI's separator
+    molecule(2, rx=b"AC\xc3\x28GT")            # not ASCII, equal
+    molecule(1, rx=b"A\xffGT", rx2=None)       # not ASCII, one RX
+    molecule(6)                                # deep: --max-reads samples it
+    molecule(5, r1_reverse=True)
+    molecule(2, noise=0.4)                     # the strands disagree
+    molecule(2, cb=b"CELL1")
+    m = mol[0]
+    mol[0] += 4
+    p = 1000 + 300 * m
+    # 12 bases of overlap; then out of phase (the longest reverse read
+    # starts before the longest forward read)
+    records.extend(fr_pair(b"m%dt0" % m, b"%d" % m, p, p + 48))
+    records.extend(fr_pair(b"m%dt0" % (m + 1), b"%d" % (m + 1), p + 30,
+                           p + 50, length2=30)
+                   + fr_pair(b"m%dt1" % (m + 1), b"%d" % (m + 1), p, p + 10,
+                             length=30, length2=60))
+    # a fragment and a secondary beside a good pair; fragments only
+    records.append(rec(b"m%df" % (m + 2), 0, p, 60, b"%d" % (m + 2), -1, 0))
+    records.append(rec(b"m%ds" % (m + 2), 0x1 | 0x40 | 0x100, p, 60,
+                       b"%d" % (m + 2), p + 25, 85))
+    records.extend(fr_pair(b"m%dt0" % (m + 2), b"%d" % (m + 2), p, p + 25))
+    records.append(rec(b"m%df" % (m + 3), 0, p, 60, b"%d" % (m + 3), -1, 0))
+    # a same-strand pair beside a good one and alone, then the common shapes
+    records.append(rec(b"m%dx" % (m + 4), 0x1 | 0x40, p, 60, b"%d" % (m + 4),
+                       p + 25, 85))
+    records.append(rec(b"m%dx" % (m + 4), 0x1 | 0x80, p + 25, 60,
+                       b"%d" % (m + 4), p, -85))
+    records.extend(fr_pair(b"m%dt0" % (m + 4), b"%d" % (m + 4), p, p + 25))
+    records.append(rec(b"m%dx" % (m + 5), 0x1 | 0x40, p, 60, b"%d" % (m + 5),
+                       p + 25, 85))
+    records.append(rec(b"m%dx" % (m + 5), 0x1 | 0x80, p + 25, 60,
+                       b"%d" % (m + 5), p, -85))
+    mol[0] += 2
+    molecule(4)
+    molecule(1, r1_reverse=True)
+    with BamWriter(path, header) as w:
+        for r in records:
+            w.write_record_bytes(r)
+    return path
+
+
+@pytest.fixture(scope="module")
+def mixed_bam(tmp_path_factory):
+    return _mixed_stream(str(tmp_path_factory.mktemp("fc") / "mixed.bam"))
+
+
+#: the molecule-level reason of a reject, latest phase first (as the batch
+#: engine's counters name it)
+_REASONS = ("HighDuplexDisagreement", "ClipOverlapFailed",
+            "IndelErrorBetweenStrands", "InsufficientOverlap",
+            "InsufficientReads", "MinorityAlignment", "NotPrimaryFrPair",
+            "FragmentRead")
+
+
+def _classic_by_molecule(path, options):
+    """The classic engine over the stream, one molecule a call: the records'
+    wire bytes, the caller's stats, and how many molecules were rejected
+    under each reason."""
+    import struct
+    from collections import Counter
+
+    from fgumi_tpu.consensus.codec import CodecConsensusCaller
+    from fgumi_tpu.core.grouper import iter_mi_groups
+
+    caller = CodecConsensusCaller("fgumi", "A", options)
+    wire, rejected = [], Counter()
+    with BamReader(path) as r:
+        for group in iter_mi_groups(r, b"MI"):
+            before = dict(caller.stats.rejection_reasons)
+            out = caller.call_groups([group])
+            wire.extend(struct.pack("<I", len(x)) + x for x in out)
+            if not out:
+                after = caller.stats.rejection_reasons
+                grew = [x for x in _REASONS
+                        if after.get(x, 0) > before.get(x, 0)]
+                rejected[grew[0] if grew else "NoUsableReads"] += 1
+    return b"".join(wire), caller.stats, rejected
+
+
+def _batch_engine(path, options, n_records):
+    """The batch engine over the stream in batches of ``n_records``: wire
+    bytes, the caller's stats, its ``codec.*`` counters."""
+    from fgumi_tpu.consensus.codec import CodecConsensusCaller
+    from fgumi_tpu.consensus.fast_codec import FastCodecCaller
+    from fgumi_tpu.observe.metrics import METRICS
+
+    caller = CodecConsensusCaller("fgumi", "A", options)
+    fast = FastCodecCaller(caller, b"MI")
+    before = METRICS.snapshot()
+    got = []
+    for batch in record_batches(path, n_records):
+        got.extend(fast.process_batch(batch))
+    got.extend(fast.flush())
+    after = METRICS.snapshot()
+    counters = {k: v - before.get(k, 0) for k, v in after.items()
+                if k.startswith("codec.") and v != before.get(k, 0)}
+    return b"".join(got), caller.stats, counters
+
+
+def _codec_options(**kw):
+    from fgumi_tpu.consensus.codec import CodecOptions
+
+    return CodecOptions(**kw)
+
+
+@pytest.mark.parametrize("n_records", [10 ** 9, 7, 10])
+@pytest.mark.parametrize("case", [
+    "defaults", "min_reads_2", "hash_collision", "max_reads",
+    "max_reads_hash_collision", "cell_tag", "per_base_tags",
+    "rate_and_length_gates", "count_gate", "quality_masks",
+    "hash_collision_gates", "max_reads_gates"])
+def test_columns_match_classic_on_mixed_stream(mixed_bam, monkeypatch, case,
+                                               n_records):
+    """Wire bytes and `CodecStats` of the batch engine equal the classic
+    engine's on the mixed stream, whichever path a molecule takes (columns,
+    one at a time, carried), and the molecules the masks reject are counted
+    as the per-molecule path counts them."""
+    options = {
+        "defaults": {},
+        "min_reads_2": dict(min_reads_per_strand=2),
+        "hash_collision": {},
+        "max_reads": dict(max_reads_per_strand=2),
+        "max_reads_hash_collision": dict(max_reads_per_strand=3),
+        "cell_tag": dict(cell_tag="CB"),
+        "per_base_tags": dict(produce_per_base_tags=True),
+        "rate_and_length_gates": dict(max_duplex_disagreement_rate=0.05,
+                                      min_duplex_length=20),
+        "count_gate": dict(max_duplex_disagreements=2,
+                           min_reads_per_strand=2),
+        "quality_masks": dict(single_strand_qual=7, outer_bases_qual=5,
+                              outer_bases_length=3),
+        "hash_collision_gates": dict(min_reads_per_strand=2,
+                                     min_duplex_length=20,
+                                     max_duplex_disagreement_rate=0.05),
+        "max_reads_gates": dict(max_reads_per_strand=1, min_duplex_length=20,
+                                max_duplex_disagreements=2),
+    }[case]
+    want, want_stats, want_rejected = _classic_by_molecule(
+        mixed_bam, _codec_options(**options))
+    if "hash_collision" in case:
+        # every read name of a group lands in one bucket: its byte check
+        # fails and the group is paired by the python fallback
+        monkeypatch.setattr(
+            nb, "hash_ranges",
+            lambda buf, off, length: np.full(len(off), 7, dtype=np.uint64))
+    got, got_stats, counters = _batch_engine(
+        mixed_bam, _codec_options(**options), n_records)
+    assert got == want
+    assert got_stats == want_stats
+    rejected = {k[len("codec.rejected."):]: v for k, v in counters.items()
+                if k.startswith("codec.rejected.")}
+    assert rejected == dict(want_rejected)
+    assert counters["codec.molecules"] \
+        == counters["codec.emitted"] + counters.get("codec.rejected", 0)
+    assert counters["codec.strands"] == 2 * (
+        counters["codec.emitted"]
+        + rejected.get("HighDuplexDisagreement", 0))
+    assert counters["codec.row_molecules"] >= counters["codec.slow_molecules"]
+    if case == "defaults" and n_records == 10 ** 9:
+        # one batch: the soft-clipped group and the batch's last molecule
+        # (carried to the flush) leave the columns, nothing else
+        assert counters["codec.row_molecules"] == 2
+    if "hash_collision" in case:
+        assert counters["codec.row_molecules"] \
+            > counters["codec.slow_molecules"]
+
+
+def _cell_layout_bam(tmp_path, molecules, seed):
+    """An input of the benchmark cell ``codec-c4.linked``'s layout
+    (``benchmark/traffic/codec_bam.py``) at ``molecules`` molecules."""
+    import os
+    import sys
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    sys.path.insert(0, bench)
+    try:
+        import traffic
+    finally:
+        sys.path.remove(bench)
+    params = traffic.load("linked", bench)
+    params["num_families"] = molecules
+    (path,) = traffic.write_inputs(traffic.generate(params, seed),
+                                   str(tmp_path / "linked"))
+    return path
+
+
+@pytest.mark.parametrize("n_records", [2500, 6000])
+def test_row_molecules_are_the_carried_ones_on_the_cells_layout(tmp_path,
+                                                                n_records):
+    """On the cell's traffic every molecule goes through the columns end to
+    end but the one a batch boundary cuts: no hash collision, no downsample,
+    no CIGAR other than one M run."""
+    path = _cell_layout_bam(tmp_path, 3000, 2147483659)
+    with BamReader(path) as r:
+        mi_of = [rec.get_str(b"MI") for rec in r]
+    n = len(mi_of)
+    # the molecule that holds a batch's last record is carried (the engine
+    # cannot know it is complete), once however many batches it spans
+    carried = len({mi_of[min(end, n) - 1]
+                   for end in range(n_records, n + n_records, n_records)})
+    assert carried >= 2
+    _wire, stats, counters = _batch_engine(path, _codec_options(), n_records)
+    assert counters["codec.row_molecules"] == carried
+    assert counters["codec.slow_molecules"] == carried
+    assert counters["codec.molecules"] == len(set(mi_of)) == 3000
+    assert counters["codec.molecules"] == counters["codec.emitted"] \
+        + counters.get("codec.rejected", 0)
+    assert counters["codec.emitted"] == stats.consensus_reads_generated
+    assert stats.total_input_reads == n
+
+
+@pytest.mark.parametrize("family", [
+    [b"ACGTACGT"],                               # one RX: as it is
+    [b"acgtnacg"],                               # one RX, lower case: kept
+    [b"ACGTACGT"] * 4,                           # n identical
+    [b"acgtacgt"] * 4,                           # identical, lower case
+    [b"ACNTACGN"] * 6,                           # identical, with N
+    [b"ACG-TTA", b"ACG-TTA"],                    # a dual UMI's separator
+    [b"acg-tnx", b"acg-tnx"],                    # a byte the table leaves
+    [b"ACGTACGT", b"ACGTACGT", b"ACGTTCGT", b"ACGTACGT"],   # differing
+    [b"ACGTAC", b"ACGTAC", b"ACGTAA", b"ACTTAC", b"ACGTAC", b"ACGTAC"],
+    [b"AC\xc3\x28GT"] * 2,                       # not ASCII, identical
+    [b"A\xffGT"],                                # not ASCII, one RX
+    [b"ACGT", None, b"ACGT", None],              # absent on some records
+    [None, None],                                # absent
+    [b"", b""],                                  # empty: no RX
+], ids=lambda f: "+".join("none" if u is None else u.decode("latin-1")
+                          for u in f) or "empty")
+def test_rx_on_bytes_equals_consensus_umis_batch(tmp_path, family):
+    """The RX consensus the batch engine takes on bytes (one RX as it is,
+    identical ones through the ACGTN uppercase table, the rest handed to
+    the likelihood as strings) is `consensus_umis_batch` on the strings the
+    classic path reads."""
+    import struct
+
+    from fgumi_tpu.consensus.simple_umi import consensus_umis_batch
+    from fgumi_tpu.io.bam import RawRecord
+
+    rng = np.random.default_rng(len(family))
+    header = BamHeader(
+        text="@HD\tVN:1.6\tSO:unsorted\tGO:query\n@SQ\tSN:c\tLN:100000\n",
+        ref_names=["c"], ref_lengths=[100000])
+    truth = rng.choice(np.frombuffer(b"ACGT", np.uint8), size=2000)
+
+    def rec(name, flag, pos, mi, next_pos, tlen, rx):
+        b = RecordBuilder().start_mapped(
+            name, flag, 0, pos, 60, [("M", 50)], bytes(truth[pos:pos + 50]),
+            np.full(50, 30, dtype=np.uint8), next_ref_id=0,
+            next_pos=next_pos, tlen=tlen)
+        b.tag_str(b"MI", mi)
+        if rx is not None:
+            b.tag_str(b"RX", rx)
+        return b.finish()
+
+    # the family's molecule first (one RX a record, pair by pair), then two
+    # plain ones: a batch's last molecule is carried and read as strings
+    values = list(family) + [None] * (len(family) % 2)
+    records = []
+    for mi, rxs in ((b"0", values), (b"1", [b"TTTT"] * 2),
+                    (b"2", [b"GGGG"] * 2)):
+        for t in range(len(rxs) // 2):
+            name = b"m%st%d" % (mi, t)
+            records.append(rec(name, 0x1 | 0x40 | 0x20, 100, mi, 120, 70,
+                               rxs[2 * t]))
+            records.append(rec(name, 0x1 | 0x80 | 0x10, 120, mi, 100, -70,
+                               rxs[2 * t + 1]))
+    path = str(tmp_path / "rx.bam")
+    with BamWriter(path, header) as w:
+        for r in records:
+            w.write_record_bytes(r)
+
+    wire, _stats, counters = _batch_engine(path, _codec_options(), 10 ** 9)
+    assert counters["codec.row_molecules"] == 1  # the last one alone
+    n = struct.unpack_from("<I", wire, 0)[0]
+    first = RawRecord(wire[4:4 + n])
+    assert first.get_str(b"MI") == "0"
+    strings = [u.decode(errors="replace") for u in family if u]
+    want = consensus_umis_batch([strings])[0] if strings else None
+    assert first.get_str(b"RX") == (want or None)
+
+
+def test_clip_overlap_failed_by_mask_equals_per_molecule():
+    """A fragment shorter than one of its strands (`ClipOverlapFailed`; no
+    all-M geometry gives it, so the table is built by hand): the batch
+    engine's mask and summed counts against the classic `_finish`, molecule
+    by molecule, among molecules that pass."""
+    import struct
+
+    from fgumi_tpu.consensus.codec import CodecConsensusCaller, CodecOptions
+    from fgumi_tpu.consensus.fast_codec import FastCodecCaller, _Molecules
+    from fgumi_tpu.consensus.vanilla import ConsensusJob, R1
+
+    rng = np.random.default_rng(12)
+    # (pairs, fragment length, r1 negative): 40-base strands
+    shapes = [(2, 60, False), (2, 30, False), (1, 52, True), (1, 39, True),
+              (3, 40, False), (3, 12, True)]
+    stride = 64
+    n_rows = sum(2 * n for n, _, _ in shapes)
+    codes_pk = np.full((n_rows, stride), 4, dtype=np.uint8)
+    quals_pk = np.zeros((n_rows, stride), dtype=np.uint8)
+    codes_pk[:, :40] = rng.integers(0, 4, size=(n_rows, 40))
+    quals_pk[:, :40] = rng.integers(10, 41, size=(n_rows, 40))
+
+    class Batch:
+        """One record a molecule: MI its index, no RX."""
+        buf = np.frombuffer(b"012345", dtype=np.uint8)
+
+        def tag_locs_str(self, tag):
+            n = len(shapes)
+            if tag == b"MI":
+                return np.arange(n), np.ones(n, np.int32), None
+            return np.full(n, -1), np.zeros(n, np.int32), None
+
+    mols = _Molecules(len(shapes), batch=Batch())
+    classic = []
+    pk0 = 0
+    for i, (n, length, r1_neg) in enumerate(shapes):
+        mols.set_vec(i, pk0, n, n, 40, 40, r1_neg, not r1_neg, length)
+        mols.row_lo[i], mols.row_hi[i] = i, i + 1
+
+        def job(first):
+            return ConsensusJob(
+                umi=str(i), read_type=R1,
+                codes=[codes_pk[r, :40] for r in range(first, first + n)],
+                quals=[quals_pk[r, :40] for r in range(first, first + n)],
+                consensus_len=40, original_raws=[])
+
+        classic.append({
+            "umi": str(i), "records": [], "source_raws": [],
+            "job_r1": job(pk0), "job_r2": job(pk0 + n), "n_r1": n, "n_r2": n,
+            "r1_is_negative": r1_neg, "r2_is_negative": not r1_neg,
+            "consensus_length": length})
+        pk0 += 2 * n
+
+    want_caller = CodecConsensusCaller("fgumi", "A", CodecOptions())
+    ss = want_caller.ss
+    results = ss._run_jobs([j for m in classic
+                            for j in (m["job_r1"], m["job_r2"])])
+    want = []
+    for i, m in enumerate(classic):
+        rec = want_caller._finish(
+            m, ss.result_to_consensus_read(m["job_r1"], results[2 * i]),
+            ss.result_to_consensus_read(m["job_r2"], results[2 * i + 1]))
+        if rec is not None:
+            want.append(struct.pack("<I", len(rec)) + rec)
+    assert len(want) == 3
+
+    caller = CodecConsensusCaller("fgumi", "A", CodecOptions())
+    got = FastCodecCaller(caller, b"MI")._run(mols, codes_pk, quals_pk)
+    assert b"".join(got) == b"".join(want)
+    assert caller.stats.rejection_reasons == {"ClipOverlapFailed": 4 + 2 + 6}
+    assert caller.stats == want_caller.stats

@@ -2588,6 +2588,13 @@ def pad_segments_mesh(codes2d: np.ndarray, quals2d: np.ndarray,
                 seg_g[row0 + m:row0 + N_chunk] = seg_local[hi - 1]
         gather[lo_j:hi_j] = d * F_loc + np.arange(hi_j - lo_j)
     DEVICE_STATS.add_pad(N, dp * sp * N_chunk)
+    # what the layout cost in rows, on the pack span of the batch it serves
+    # (a no-op for a caller outside one): rows as add_pad counts them, and
+    # the fullest dp shard's real rows, which set the mesh kernel's time
+    count("engine.pack", "mesh.rows", N)
+    count("engine.pack", "mesh.rows_padded", dp * sp * N_chunk)
+    count("engine.pack", "mesh.shard_rows_max", int(n_rows.max()))
+    count("engine.pack", "mesh.families", J)
     return codes_g, quals_g, seg_g, starts, F_loc, gather
 
 
@@ -3061,9 +3068,10 @@ class ConsensusKernel:
         span, so a caller's row copies are charged to the batch they
         belong to. ``counts``/``route`` as in :meth:`submit_ragged`. The
         device route pads (pad_segments, or pad_segments_mesh's chunked
-        layout where ``mesh`` has more than one device) under
-        ``engine.pack.gather`` and dispatches through
-        device_call_segments_wire. ``resident_thresholds`` as in
+        layout where ``mesh`` has more than one device: the child span
+        ``engine.pack.mesh_layout``, so the parent's self time is the
+        caller's copies) under ``engine.pack.gather`` and dispatches
+        through device_call_segments_wire. ``resident_thresholds`` as in
         :meth:`submit_ragged`."""
         if route == "host":
             with span("engine.host_gather", rusage=True):
@@ -3078,8 +3086,9 @@ class ConsensusKernel:
             with span("engine.pack.gather"):
                 codes2d, quals2d = gather()
                 if mesh is not None and mesh.size > 1:
-                    cd, qd, seg_ids, starts, F_pad, order = \
-                        pad_segments_mesh(codes2d, quals2d, counts, mesh)
+                    with span("engine.pack.mesh_layout"):
+                        cd, qd, seg_ids, starts, F_pad, order = \
+                            pad_segments_mesh(codes2d, quals2d, counts, mesh)
                 else:
                     cd, qd, seg_ids, starts, F_pad = pad_segments(
                         codes2d, quals2d, counts)
@@ -3396,8 +3405,10 @@ class ConsensusKernel:
             codes_g.shape[0], codes_g.shape[1], dp * F_loc))
         slot = DEVICE_STATS.begin_in_flight(
             upload, pack_s=time.monotonic() - t_pack0)
-        DEVICE_STATS.note_mesh(slot, mesh.size, upload // mesh.size,
-                               2 if sp > 1 else 0)
+        psums = 2 if sp > 1 else 0
+        DEVICE_STATS.note_mesh(slot, mesh.size, upload // mesh.size, psums)
+        count("engine.pack", "mesh.dispatches")
+        count("engine.pack", "mesh.psums", psums)
         if pred_s is not None:
             DEVICE_STATS.note_pred(slot, pred_s)
         with SHAPE_REGISTRY.attribute_compiles(new):
@@ -3538,11 +3549,12 @@ class ConsensusKernel:
             # The resident handles stay shard-ordered ON DEVICE — the
             # duplex combine maps its indices through ``gather`` instead
             # of paying a device-side re-shuffle.
-            qs = qs[gather]
-            wp = wp[gather]
-            if d16 is not None:
-                d16 = d16[gather]
-                e16 = e16[gather]
+            with span("resolve.mesh_gather"):
+                qs = qs[gather]
+                wp = wp[gather]
+                if d16 is not None:
+                    d16 = d16[gather]
+                    e16 = e16[gather]
         winner, qual, suspect = unpack_result_split(qs, wp, J)
         if d16 is not None:
             # full-column dispatch: the device already counted depth/errors

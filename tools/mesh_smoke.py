@@ -132,7 +132,7 @@ def main():
                      "-i", j("sim.bam"), "-o", j("obs.bam"),
                      "--min-reads", "1", "--stats"])
         good = p.returncode == 0
-        mesh_sec = gauges = stamps = False
+        mesh_sec = gauges = False
         if good:
             r = json.load(open(rep))
             dev = r.get("device", {})
@@ -142,11 +142,12 @@ def main():
             gauges = (m.get("device.mesh.dp") == 4
                       and m.get("device.mesh.sp") == 2
                       and m.get("device.mesh.devices") == 8)
-            routing = dev.get("routing", {})
-            stamps = "8" in routing.get("mesh", {})
+        # the per-mesh routing EWMAs are not checked here: this job is one
+        # dispatch, a shape's first dispatch is a compile and feeds the
+        # router nothing; tests/test_mesh_cell.py reads them from a job of
+        # several dispatches
         ok &= check("report device.mesh section", good and mesh_sec)
         ok &= check("report device.mesh.* gauges", good and gauges)
-        ok &= check("report per-mesh routing EWMAs", good and stamps)
 
         # timeline shard stamps (in-process: the subprocess report has no
         # timeline; assert via a short library run)

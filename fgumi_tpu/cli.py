@@ -166,8 +166,8 @@ def _consensus_stage_kwargs(args):
     runs: >=2 resolve workers so a worker blocked on a device fetch never
     starves a host-engine (hybrid) chunk queued behind it. Host-only runs
     keep the threads-3 default (no point oversubscribing pure CPU work).
-    Only for commands that pass a real resolve_fn (simplex/duplex) — a
-    pool applying the identity is pure queue overhead."""
+    Only for commands that pass a real resolve_fn (simplex/duplex/codec) —
+    a pool applying the identity is pure queue overhead."""
     kw = _stage_kwargs(args)
     from .ops.kernel import use_host_engine
 
@@ -1164,6 +1164,7 @@ def cmd_codec(args):
                  else "native runtime unavailable")
     t0 = time.monotonic()
     if use_fast:
+        from .consensus.fast import resolve_chunk
         from .consensus.fast_codec import FastCodecCaller
         from .io.batch_reader import BamBatchReader
         from .pipeline import StageTimes, run_stages
@@ -1186,9 +1187,10 @@ def cmd_codec(args):
                 writer = wrap_filter_writer(writer, filter_tap)
                 run_stages(iter(reader), _process, writer.write_serialized,
                            threads=args.threads, stats=stats_t,
-                           **_stage_kwargs(args))
+                           resolve_fn=resolve_chunk,
+                           **_consensus_stage_kwargs(args))
                 for chunk in fast.flush():
-                    writer.write_serialized(chunk)
+                    writer.write_serialized(resolve_chunk(chunk))
                 if filter_tap is not None:
                     writer.finish()
                 n_out = caller.stats.consensus_reads_generated

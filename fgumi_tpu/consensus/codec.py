@@ -28,6 +28,7 @@ The single-strand hot loop runs on the batched TPU kernel through the shared
 vanilla job machinery; geometry and the pairwise combine are vectorized host math.
 """
 
+import threading
 from dataclasses import dataclass, field
 from typing import Optional
 
@@ -85,7 +86,13 @@ class CodecOptions:
 
 @dataclass
 class CodecStats:
-    """CodecConsensusStats analog (codec_caller.rs:214-259)."""
+    """CodecConsensusStats analog (codec_caller.rs:214-259).
+
+    `reject` and `add` take the lock because the batch engine's stage 2
+    bumps them from whichever thread resolves a chunk (fast_codec.py) while
+    the processing thread records the prepare phases' rejects;
+    total_input_reads stays on the processing thread.
+    """
 
     total_input_reads: int = 0
     consensus_reads_generated: int = 0
@@ -94,10 +101,20 @@ class CodecStats:
     consensus_duplex_bases_emitted: int = 0
     duplex_disagreement_base_count: int = 0
     rejection_reasons: dict = field(default_factory=dict)
+    lock: threading.Lock = field(default_factory=threading.Lock, repr=False,
+                                 compare=False)
 
     def reject(self, reason: str, count: int):
-        self.rejection_reasons[reason] = self.rejection_reasons.get(reason, 0) + count
-        self.reads_filtered += count
+        with self.lock:
+            self.rejection_reasons[reason] = \
+                self.rejection_reasons.get(reason, 0) + count
+            self.reads_filtered += count
+
+    def add(self, **counts):
+        """Add to the named tallies (the fields above but the dict)."""
+        with self.lock:
+            for name, count in counts.items():
+                setattr(self, name, getattr(self, name) + count)
 
     def duplex_disagreement_rate(self) -> float:
         if self.consensus_duplex_bases_emitted:
@@ -472,15 +489,19 @@ class CodecConsensusCaller:
 
     def _build_record(self, consensus: _SS, ss_a: _SS, ss_b: _SS,
                       umi: Optional[str], source_raws: list,
-                      all_records: list, rx_umis=None) -> bytes:
+                      all_records: list, rx_umis=None,
+                      number: int = None) -> bytes:
         """build_output_record_into (rs:1374-1539); tag order preserved.
 
         rx_umis: precomputed per-record RX strings (batch engine); None means
-        scan all_records here.
+        scan all_records here. number: the record's place in the output (1 =
+        first) where the caller keeps the count itself (the batch engine's
+        chunks, fast_codec.py `_EmittedOrder`); None takes the next.
         """
-        self._counter += 1
-        name = (f"{self.prefix}:{umi}" if umi
-                else f"{self.prefix}:{self._counter}").encode()
+        if number is None:
+            self._counter += 1
+            number = self._counter
+        name = f"{self.prefix}:{umi or number}".encode()
         b = self._builder
         b.start_unmapped(name, FLAG_UNMAPPED, consensus.bases.tobytes(),
                          consensus.quals)
@@ -534,7 +555,7 @@ class CodecConsensusCaller:
             if cu:
                 b.tag_str(b"RX", cu.encode())
 
-        self.stats.consensus_reads_generated += 1
+        self.stats.add(consensus_reads_generated=1)
         return b.finish()
 
     def _finish(self, mol, vcr_r1, vcr_r2) -> Optional[bytes]:

@@ -22,17 +22,30 @@ Stage 2 (the SS device pass, geometry finish, combine/masks, record build)
 IS the classic caller's `_run_jobs` + `_finish`, so outputs are identical
 by construction; tests/test_fast_codec.py asserts byte parity end to end.
 
-Everything runs on the thread that calls `process_batch` (the device round
-trip resolves inline). Its spans: `process.decode`, `.group`, `.prep`, then
-`engine.codec.slow_molecule`, `.single`, `.gather`, `.place`, `.combine`,
-`.gates` and `resolve.serialize`; its run-report counters, by molecule:
+`process_batch` returns one pending chunk a batch, straight after the
+dispatch (`_CodecPending`, the shape of fast_duplex's `_DuplexPending`); its
+`resolve()` does the rest on whichever thread `run_stages` resolves on (the
+resolve workers at ``--threads 4``, the writer at 2-3, the caller inline at
+0-1). On the thread that calls `process_batch`: the spans `process.decode`,
+`.group`, `.prep`, `engine.codec.slow_molecule`, `.single`, `.gather` and
+the pack and dispatch; what is a function of the stream and not of the
+batch (the carry, the classic ``prepare()`` of a carried or fallback
+molecule, ``codec.molecules`` / ``.strands`` and the prepare-phase
+rejects). Where the chunk resolves: `resolve.wait`, `device.fetch`,
+`resolve.unpack` (the thresholds), `engine.codec.place`, `.combine`,
+`.gates` and `resolve.serialize`. Stage 2 is a function of its batch but
+for two things chunks share: the caller's `CodecStats` (locked) and its
+record counter, which names a molecule whose MI value is empty
+(`_EmittedOrder`). Run-report counters, by molecule:
 `codec.molecules` = `.emitted` + `.rejected` (`.rejected.<reason>`),
 `.slow_molecules`, `.row_molecules`, `.strands`, `.single_strands`,
-`.combine_cells_device` / `_host`, `.duplex_bases`, `.disagreements`
-(docs/observability.md).
+`.combine_cells_device` / `_host`, `.duplex_bases`, `.disagreements`; by
+chunk: `.stage2_batches`, `.stage2_off_thread` (docs/observability.md).
 """
 
+import itertools
 import struct
+import threading
 
 import numpy as np
 
@@ -147,6 +160,93 @@ class _Molecules:
         return out
 
 
+class _EmittedOrder:
+    """The caller's record counter over chunks that resolve in any order.
+
+    The counter names a molecule whose MI value is empty by its place in
+    the output, and a chunk knows how many molecules it emits only after
+    its gates. So chunks are numbered as they are made, each publishes its
+    emitted count when its gates are done, and a chunk that has to name a
+    molecule by the counter waits until every earlier chunk has published.
+    Earlier chunks never wait on later ones and every resolver takes
+    chunks in the order they were made, so the earliest unpublished chunk
+    always runs; a chunk that fails or is dropped publishes too
+    (`_CodecPending`), so a failed run drains instead of hanging a waiter.
+    """
+
+    def __init__(self, caller):
+        self._caller = caller
+        self._cond = threading.Condition()
+        self._ahead = {}  # chunk -> its count, published before an earlier one
+        self._next = 0    # every chunk below it is in caller._counter
+        #: the number of the chunk being made (the processing thread's)
+        self.issue = itertools.count().__next__
+
+    def publish(self, serial, emitted, wait=False):
+        """Chunk ``serial`` emits ``emitted`` molecules. ``wait``: only
+        after every earlier chunk has published, returning the counter
+        before this chunk's first molecule."""
+        with self._cond:
+            try:
+                if wait:
+                    self._cond.wait_for(lambda: self._next == serial)
+            finally:  # an interrupted wait publishes too
+                before = self._caller._counter
+                self._ahead[serial] = emitted
+                while self._next in self._ahead:
+                    self._caller._counter += self._ahead.pop(self._next)
+                    self._next += 1
+                self._cond.notify_all()
+            return before
+
+
+class _CodecPending:
+    """One batch between its dispatch and its bytes.
+
+    process_batch returns it as soon as the multi-read strands are packed
+    and handed to the feeder (or kept for the host engine; a batch with
+    none dispatches nothing and resolves the same way); resolve() does the
+    rest on whichever thread run_stages resolves on: the fetch and unpack,
+    the thresholds, stage 2 and the serialization. A chunk dropped
+    unresolved (a failed run) hands its dispatch back, so the feeder slot
+    and the resident-byte accounting are not leaked, and publishes no
+    molecules, so a later chunk does not wait for it."""
+
+    __slots__ = ("_finish", "_discard", "_made_on", "_order", "_serial",
+                 "_published")
+
+    def __init__(self, finish, discard, order):
+        self._finish = finish
+        self._discard = discard  # None: nothing in flight on the device
+        self._made_on = threading.get_ident()
+        self._order = order
+        self._serial = order.issue()
+        self._published = False
+
+    def publish(self, emitted, wait=False):
+        """`_EmittedOrder.publish` for this chunk (once: `resolve` and
+        `__del__` publish 0 for a chunk that has not)."""
+        self._published = True
+        return self._order.publish(self._serial, emitted, wait)
+
+    def resolve(self) -> bytes:
+        finish, self._finish, self._discard = self._finish, None, None
+        METRICS.inc("codec.stage2_batches")
+        if threading.get_ident() != self._made_on:
+            METRICS.inc("codec.stage2_off_thread")
+        try:
+            return b"".join(finish(self))
+        finally:
+            if not self._published:
+                self.publish(0)
+
+    def __del__(self):
+        if self._discard is not None:
+            self._discard()
+        if not self._published:
+            self.publish(0)
+
+
 class FastCodecCaller:
     """Batch CODEC engine wrapping a CodecConsensusCaller."""
 
@@ -162,13 +262,17 @@ class FastCodecCaller:
         self.tag = tag
         self.mesh = mesh if mesh is not None and mesh.size > 1 else None
         self._carry = None  # (mi string, [RawRecord])
+        self._order = _EmittedOrder(caller)
+        self._records_lock = threading.Lock()  # --cell-tag's builder
 
     # ----------------------------------------------------------------- driver
 
     def process_batch(self, batch, final: bool = False):
-        """Consume one RecordBatch -> serialized consensus blobs.
+        """Consume one RecordBatch -> pending chunks (`_CodecPending`: at
+        most one, and the flush's when ``final``), to be resolved in the
+        order they were made (`fast.resolve_chunk`).
 
-        Each returned chunk carries its records' block_size prefixes
+        Each chunk's bytes carry its records' block_size prefixes
         (BamWriter.write_serialized framing)."""
         n = batch.n
         if n == 0:
@@ -231,6 +335,7 @@ class FastCodecCaller:
         return out
 
     def flush(self):
+        """The carried molecule's chunk (pending, as `process_batch`'s)."""
         if self._carry is None:
             return []
         mi, recs = self._carry
@@ -248,28 +353,37 @@ class FastCodecCaller:
         METRICS.inc("codec.row_molecules")
         if not counted:
             METRICS.inc("codec.molecules")
-        before = dict(self.caller.stats.rejection_reasons)
+        before = self._prepare_rejects()
         with _span("engine.codec.slow_molecule", rusage=True):
             mol = self.caller.prepare(records, umi=mi)
         if mol is None:
             self._count_prepare_reject(before)
         return mol
 
+    def _prepare_rejects(self):
+        """The caller's reads rejected so far under each prepare-phase
+        reason. Only the processing thread records those; stage 2's own
+        reasons, which a resolving thread may be adding meanwhile, are not
+        read."""
+        reasons = self.caller.stats.rejection_reasons
+        return [reasons.get(r, 0) for r in _PREPARE_REASONS]
+
     def _count_prepare_reject(self, before):
         """Count one molecule a per-molecule prepare returned nothing for,
         under the latest phase's reason among those that grew since
-        ``before`` (a copy of the caller's ``rejection_reasons``)."""
-        reasons = self.caller.stats.rejection_reasons
-        grew = [r for r in _PREPARE_REASONS
-                if reasons.get(r, 0) > before.get(r, 0)]
+        ``before`` (`_prepare_rejects` then)."""
+        grew = [r for r, was, now in zip(_PREPARE_REASONS, before,
+                                         self._prepare_rejects())
+                if now > was]
         _count_reject(grew[0] if grew else "NoUsableReads")
 
     def _run(self, mols, codes_pk=None, quals_pk=None):
-        """One SS device pass + batched finish.
+        """One SS device dispatch -> the batch's pending chunk (a list of
+        one; none for no molecules), whose resolve() is the batched finish.
 
         Vec-prepared molecules (strand rows resident in the pack arrays)
         land in the dense layout via ONE gather from codes_pk/quals_pk —
-        the same route_and_call_segments/thresholds sequence as
+        the same decide/submit_dense/thresholds sequence as
         VanillaConsensusCaller._run_jobs, minus the per-read row repack.
         Classic-prepared molecules (carry/fallback ConsensusJobs) repack
         their few rows into the same layout, so every batch costs exactly
@@ -338,7 +452,7 @@ class FastCodecCaller:
                         len(res[0])
                     arrays.append(res)
 
-        slot_mats = None
+        pending = None
         if len(mv) or classic_multi:
             with _span("engine.codec.gather", rusage=True):
                 cnt_v = cnt[mv]
@@ -375,30 +489,51 @@ class FastCodecCaller:
                         quals2d[row, :w] = q[:w]
                         row += 1
             # adaptive offload: host f64 engine or full-column wire,
-            # decided per batch (ops/kernel helper)
-            from ..ops.kernel import route_and_call_segments
+            # decided per batch (ops/kernel.py route_and_call_segments'
+            # decision; the batch is resolved later, where its chunk is)
+            from ..ops.router import ROUTER
 
-            w, q_, d, e = route_and_call_segments(ss.kernel, codes2d,
-                                                  quals2d, counts,
-                                                  mesh=self.mesh)
-            # thresholds are elementwise: one vectorized pass over the whole
-            # (F, L) batch, then per-slot length slicing (positions past a
-            # slot's consensus length are computed and discarded)
-            with _span("resolve.unpack", rusage=True):
-                b_all, q_all = oracle.apply_consensus_thresholds(
-                    w, q_, d, ss.options.min_reads,
-                    ss.options.min_consensus_base_quality)
-            slot_mats = (b_all, q_all, d, e)
-        return self._finish_batch(mols, src, srow, slen,
-                                  (slot_mats, single_mats, arrays))
+            kernel = ss.kernel
+            route = "host"
+            if not kernel.host_mode():
+                route = ROUTER.decide_batch(
+                    kernel, N, len(counts), L_max,
+                    devices=self.mesh.size if self.mesh is not None else 1)
+            pending = kernel.submit_dense(lambda: (codes2d, quals2d), counts,
+                                          route, mesh=self.mesh)
 
-    def _finish_batch(self, mols, src, srow, slen, sources):
+        def finish(chunk):
+            slot_mats = None
+            if pending is not None:
+                w, q_, d, e = pending.resolve()
+                # thresholds are elementwise: one vectorized pass over the
+                # whole (F, L) batch, then per-slot length slicing (positions
+                # past a slot's consensus length are computed and discarded)
+                with _span("resolve.unpack", rusage=True):
+                    b_all, q_all = oracle.apply_consensus_thresholds(
+                        w, q_, d, ss.options.min_reads,
+                        ss.options.min_consensus_base_quality)
+                slot_mats = (b_all, q_all, d, e)
+            return self._finish_batch(mols, src, srow, slen,
+                                      (slot_mats, single_mats, arrays), chunk)
+
+        return [_CodecPending(
+            finish, pending.discard if pending is not None else None,
+            self._order)]
+
+    def _finish_batch(self, mols, src, srow, slen, sources, chunk):
         """Batched `_finish` (codec.py:527-568): strand geometry lands in
         concatenated position arrays, the duplex combine + quality-mask math
         of codec.py:360-456 runs once over all molecules (each molecule's
         slice is element-identical to the per-molecule version), and the
         records serialize in one native pass. Stats totals match the
         sequential path.
+
+        Runs on whichever thread resolves ``chunk``, several batches at
+        once on a resolve pool: everything it writes is its own batch's but
+        the tallies (`CodecStats`' locked methods, METRICS), the locked
+        CODEC_COMBINE chooser and the record counter, which ``chunk``
+        publishes to once the gates have said how many molecules it emits.
 
         A strand's result is a row of one of two sets of result matrices
         (``src``: the dense batch's (F, L), the single-read table pass's
@@ -539,10 +674,12 @@ class FastCodecCaller:
 
             duplex_bases = seg_sum(both)
             disagreements = seg_sum(disag)
-            st.consensus_duplex_bases_emitted += int(duplex_bases.sum())
-            st.duplex_disagreement_base_count += int(disagreements.sum())
-            METRICS.inc("codec.duplex_bases", int(duplex_bases.sum()))
-            METRICS.inc("codec.disagreements", int(disagreements.sum()))
+            n_duplex, n_disag = int(duplex_bases.sum()), \
+                int(disagreements.sum())
+            st.add(consensus_duplex_bases_emitted=n_duplex,
+                   duplex_disagreement_base_count=n_disag)
+            METRICS.inc("codec.duplex_bases", n_duplex)
+            METRICS.inc("codec.disagreements", n_disag)
             nz = duplex_bases > 0
             bad = np.zeros(J, dtype=bool)
             if opts.max_duplex_disagreements is not None:
@@ -572,7 +709,7 @@ class FastCodecCaller:
             if n_bad:
                 st.reject("HighDuplexDisagreement",
                           int((mols.n_r1 + mols.n_r2)[bad].sum()))
-                st.consensus_reads_rejected_hdd += n_bad
+                st.add(consensus_reads_rejected_hdd=n_bad)
                 _count_reject("HighDuplexDisagreement", n_bad)
             good = np.nonzero(~bad)[0]
         # ---- record serialization
@@ -582,17 +719,20 @@ class FastCodecCaller:
         with _span("resolve.serialize", rusage=True):
             serialize = self._serialize_native if opts.cell_tag is None \
                 else self._serialize_records
-            return serialize(mols, good, offs, Ls, (cb, cq, cd, ce),
+            return serialize(chunk, mols, good, offs, Ls, (cb, cq, cd, ce),
                              (b1, q1, d1, e1), (b2, q2, d2, e2))
 
-    def _serialize_records(self, mols, good, offs, Ls, cons, side_a, side_b):
+    def _serialize_records(self, chunk, mols, good, offs, Ls, cons, side_a,
+                           side_b):
         """``--cell-tag`` (rare): the cell tag needs each molecule's raw
         source records, so every emitted molecule builds through the classic
-        RecordBuilder path, one at a time."""
+        RecordBuilder path, one at a time, numbered from the records of
+        every earlier chunk."""
         caller = self.caller
         batch = mols.batch
         out = []
-        for j in good:
+        counter0 = chunk.publish(len(good), wait=True)
+        for number, j in enumerate(good, counter0 + 1):
             sl = slice(int(offs[j]), int(offs[j] + Ls[j]))
             n_r1, n_r2 = int(mols.n_r1[j]), int(mols.n_r2[j])
             if mols.classic[j] >= 0:
@@ -611,13 +751,16 @@ class FastCodecCaller:
                 return _SS(*(a[sl] for a in arrs), count)
 
             # rx_umis=None: the RX consensus scans the group's records
-            rec = caller._build_record(
-                ss_of(cons, n_r1 + n_r2), ss_of(side_a, n_r1),
-                ss_of(side_b, n_r2), umi, source_raws, records)
+            with self._records_lock:  # the caller has one RecordBuilder
+                rec = caller._build_record(
+                    ss_of(cons, n_r1 + n_r2), ss_of(side_a, n_r1),
+                    ss_of(side_b, n_r2), umi, source_raws, records,
+                    number=number)
             out.append(struct.pack("<I", len(rec)) + rec)
         return out
 
-    def _serialize_native(self, mols, good, offs, Ls, cons, side_a, side_b):
+    def _serialize_native(self, chunk, mols, good, offs, Ls, cons, side_a,
+                          side_b):
         """One native serialization pass (codec.py _build_record byte-exact).
 
         The arrays lie in the records' orientation already (`_finish_batch`
@@ -628,6 +771,13 @@ class FastCodecCaller:
         """
         caller = self.caller
         st, opts = caller.stats, caller.options
+        # the names first: they publish the chunk's count, which a later
+        # chunk may be waiting for
+        G = len(good)
+        name_addr, name_len, mi_addr, mi_len, rx_addr, rx_len, keep_alive = \
+            self._name_rx_blob(mols.take(good) if G < len(mols) else mols,
+                               chunk)
+
         u8 = lambda x: np.ascontiguousarray(x, dtype=np.uint8)
         # the native builder reads 8-byte depth and error elements (the
         # combine math upstream runs in int32)
@@ -637,10 +787,6 @@ class FastCodecCaller:
             i64(side_a[3])
         b2, q2, b_d, b_e = u8(side_b[0]), u8(side_b[1]), i64(side_b[2]), \
             i64(side_b[3])
-
-        G = len(good)
-        name_addr, name_len, mi_addr, mi_len, rx_addr, rx_len, keep_alive = \
-            self._name_rx_blob(mols.take(good) if G < len(mols) else mols)
 
         og = offs[:-1][good]
         wire, rec_end = nb.build_codec_records(
@@ -654,10 +800,10 @@ class FastCodecCaller:
             caller.read_group_id.encode(), FLAG_UNMAPPED,
             opts.produce_per_base_tags)
         del keep_alive
-        st.consensus_reads_generated += G
+        st.add(consensus_reads_generated=G)
         return [wire]  # records carry their block_size prefixes
 
-    def _name_rx_blob(self, mols):
+    def _name_rx_blob(self, mols, chunk):
         """Names, MI and RX of the emitted molecules ``mols`` as addresses
         for `nb.build_codec_records`: ``(name_addr, name_len, mi_addr,
         mi_len, rx_addr, rx_len, keep_alive)``; ``mi_len`` -1 = no MI tag,
@@ -699,9 +845,11 @@ class FastCodecCaller:
             strings[int(k)] = [u for u in (r.get_str(b"RX")
                                            for r in m["records"]) if u]
         mi_len = np.where(t_len > 0, t_len, -1).astype(np.int32)
-        counter0 = caller._counter
-        caller._counter += G
-        for k in np.nonzero(t_len == 0)[0]:
+        # the counter's names need every earlier chunk's count: wait for
+        # them only where this chunk has such a name
+        unnamed = np.nonzero(t_len == 0)[0]
+        counter0 = chunk.publish(G, wait=len(unnamed) > 0)
+        for k in unnamed:
             digits = str(counter0 + int(k) + 1).encode()
             t_src[k], t_off[k], t_len[k] = 2, len(texts), len(digits)
             texts += digits
@@ -912,7 +1060,7 @@ class FastCodecCaller:
                     alive[nL + g] = True
                 continue
             METRICS.inc("codec.row_molecules")
-            before = dict(st.rejection_reasons)
+            before = self._prepare_rejects()
             if int(g) in py_groups:
                 prep = self._prepare_molecule_vec(batch, rows, pack_rows,
                                                   pack_clips, pk_base)

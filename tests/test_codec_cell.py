@@ -66,7 +66,7 @@ SPANS = {
 COUNTERS = (
     "codec.molecules", "codec.emitted", "codec.slow_molecules",
     "codec.strands", "codec.single_strands", "codec.duplex_bases",
-    "codec.disagreements")
+    "codec.disagreements", "codec.stage2_batches", "codec.stage2_off_thread")
 
 
 @functools.lru_cache(maxsize=None)
@@ -210,13 +210,25 @@ def test_run_report_names_what_codec_does(route):
     assert [n for n in COUNTERS if n not in report["metrics"]] == []
     side = "device" if route == "fast-device" else "host"
     assert report["metrics"]["codec.combine_cells_" + side] > 0
-    # no route change in this PR: the device round trip resolves inline, so
-    # every span of stage 2 is the processing thread's (MainThread here: the
-    # flush of the last molecule runs there too)
-    for name in ("process.prep", "engine.codec.single", "engine.codec.place",
+    # what is a function of the stream stays on the processing thread
+    # (MainThread here) with the pack and the dispatch; the fetch, the
+    # thresholds and stage 2 run where the chunk resolves: the resolve
+    # workers at the command's --threads 4, and MainThread for the flush's
+    # chunk of the last molecule alone
+    for name in ("process.prep", "engine.codec.single",
+                 "engine.codec.gather"):
+        assert by_name[name]["threads"] == ["MainThread"], name
+    for name in ("resolve.unpack", "engine.codec.place",
                  "engine.codec.combine", "engine.codec.gates",
                  "resolve.serialize"):
-        assert by_name[name]["threads"] == ["MainThread"], name
+        workers = [t for t in by_name[name]["threads"] if t != "MainThread"]
+        assert workers and all(t.startswith("fgumi-worker-")
+                               for t in workers), name
+    m = report["metrics"]
+    assert m["codec.stage2_off_thread"] == m["codec.stage2_batches"] - 1 >= 1
+    busy = [v["busy_s"] for k, v in report["stages"].items()
+            if k.startswith("resolve[")]
+    assert busy and max(busy) > 0
     if route == "fast-device":
         pack = by_name["engine.pack"]
         assert pack["entry_dense"] == pack["count"] >= 1

@@ -1,5 +1,7 @@
 """Parity: FastCodecCaller (vectorized prepare) vs classic CODEC engine."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -16,6 +18,11 @@ pytestmark = pytest.mark.skipif(not nb.available(),
 def records_of(path):
     with BamReader(path) as r:
         return [rec.data for rec in r]
+
+
+def wire_of(chunks):
+    """The bytes of the engine's pending chunks, resolved in order."""
+    return b"".join(chunk.resolve() for chunk in chunks)
 
 
 def assert_cli_parity(src, tmp_path, extra=()):
@@ -189,7 +196,7 @@ def test_parity_tiny_batches(codec_bam, n_records):
     for batch in record_batches(codec_bam, n_records):
         got.extend(fast.process_batch(batch))
     got.extend(fast.flush())
-    assert b"".join(got) == expected_wire
+    assert wire_of(got) == expected_wire
     assert fast_caller.stats.rejection_reasons \
         == caller.stats.rejection_reasons
     # 300 molecules of 6 records: the molecule that holds a batch's last
@@ -300,12 +307,12 @@ def test_carry_reads_longer_than_span(tmp_path):
 
     caller = CodecConsensusCaller("fgumi", "A", CodecOptions())
     fast = FastCodecCaller(caller, b"MI")
-    mixed = b"".join(fast._run(mols, codes_pk, quals_pk))
+    mixed = wire_of(fast._run(mols, codes_pk, quals_pk))
 
     # reference: the same two molecules, both via the classic-job path
     caller2 = CodecConsensusCaller("fgumi", "A", CodecOptions())
     fast2 = FastCodecCaller(caller2, b"MI")
-    ref = b"".join(fast2._run(_Molecules(
+    ref = wire_of(fast2._run(_Molecules(
         2, [classic_mol("9", lc, lq, long_len),
             classic_mol("7", codes_pk, quals_pk, 40)])))
     assert mixed == ref
@@ -539,10 +546,11 @@ def _batch_engine(path, options, n_records):
     for batch in record_batches(path, n_records):
         got.extend(fast.process_batch(batch))
     got.extend(fast.flush())
+    wire = wire_of(got)
     after = METRICS.snapshot()
     counters = {k: v - before.get(k, 0) for k, v in after.items()
                 if k.startswith("codec.") and v != before.get(k, 0)}
-    return b"".join(got), caller.stats, counters
+    return wire, caller.stats, counters
 
 
 def _codec_options(**kw):
@@ -796,6 +804,437 @@ def test_clip_overlap_failed_by_mask_equals_per_molecule():
 
     caller = CodecConsensusCaller("fgumi", "A", CodecOptions())
     got = FastCodecCaller(caller, b"MI")._run(mols, codes_pk, quals_pk)
-    assert b"".join(got) == b"".join(want)
+    assert wire_of(got) == b"".join(want)
     assert caller.stats.rejection_reasons == {"ClipOverlapFailed": 4 + 2 + 6}
     assert caller.stats == want_caller.stats
+
+
+# ---------------------------------------------------------------- hand-off
+#
+# `process_batch` returns a pending chunk straight after the dispatch and
+# whichever thread `run_stages` resolves on does the fetch, the thresholds
+# and stage 2 (ISSUE 34 / ROADMAP S13). The cases below hold the `codec`
+# command to one record stream, one `CodecStats` and one set of `codec.*`
+# counters whatever the thread count, the route, the batch size and the
+# order in which chunks finish.
+
+#: the gates bite, so a chunk emits fewer molecules than it was given
+GATES = ["--min-reads", "1", "--max-duplex-disagreement-rate", "0.05",
+         "--min-duplex-length", "20"]
+HANDOFF_ROUTES = {
+    "host": {"FGUMI_TPU_HOST_ENGINE": "1"},
+    "device": {"FGUMI_TPU_HOST_ENGINE": "0", "FGUMI_TPU_ROUTE": "device"},
+}
+#: molecules with an empty MI value (named by the counter): with batches of
+#: 4, 7 or 50 records, each in a batch of its own
+UNNAMED = (11, 41, 71)
+N_GATED = 360  # the gated input's records
+
+
+def _gated_stream(path, molecules=90):
+    """An MI-grouped BAM of plain FR pairs from one reference: one to three
+    pairs a molecule, R1 forward or reverse; every 7th molecule's strands
+    disagree (rate gate), every 5th overlaps 12 bases (length gate), three
+    carry an empty MI, every 4th a cell tag, the last one passes (the flush
+    has a chunk)."""
+    rng = np.random.default_rng(17)
+    header = BamHeader(
+        text="@HD\tVN:1.6\tSO:unsorted\tGO:query\n@SQ\tSN:c\tLN:100000\n",
+        ref_names=["c"], ref_lengths=[100000])
+    bases = np.frombuffer(b"ACGT", np.uint8)
+    truth = rng.choice(bases, size=100000)
+    n_records = 0
+
+    def rec(name, flag, pos, mi, next_pos, tlen, noise, cb):
+        sq = truth[pos:pos + 60].copy()
+        wrong = rng.random(60) < noise
+        sq[wrong] = rng.choice(bases, size=int(wrong.sum()))
+        b = RecordBuilder().start_mapped(
+            name, flag, 0, pos, 60, [("M", 60)], bytes(sq),
+            rng.integers(20, 41, size=60).astype(np.uint8), next_ref_id=0,
+            next_pos=next_pos, tlen=tlen)
+        b.tag_str(b"MI", mi)
+        b.tag_str(b"RX", b"ACGTAC")
+        if cb:
+            b.tag_str(b"CB", b"CELL%d" % (pos % 3))
+        return b.finish()
+
+    with BamWriter(path, header) as w:
+        for m in range(molecules):
+            last = m == molecules - 1
+            mi = b"" if m in UNNAMED else b"%d" % m
+            p1 = 1000 + 300 * m
+            p2 = p1 + (48 if m % 5 == 4 and not last else 25)
+            noise = 0.4 if m % 7 == 3 and not last else 0.0
+            f1, f2 = (0x80, 0x40) if m % 2 else (0x40, 0x80)
+            for t in range(1 + m % 3):
+                name = b"m%dt%d" % (m, t)
+                fwd = rec(name, 0x1 | f1 | 0x20, p1, mi, p2, p2 + 60 - p1,
+                          noise, m % 4 == 0)
+                rev = rec(name, 0x1 | f2 | 0x10, p2, mi, p1, p1 - p2 - 60,
+                          noise, m % 4 == 0)
+                for r in ((rev, fwd) if m % 2 else (fwd, rev)):
+                    w.write_record_bytes(r)
+                    n_records += 1
+    return n_records
+
+
+@pytest.fixture(scope="module")
+def gated_bam(tmp_path_factory):
+    path = str(tmp_path_factory.mktemp("fc") / "gated.bam")
+    assert _gated_stream(path) == N_GATED
+    return path
+
+
+def _run_codec(monkeypatch, tmp_path, bam, name, route, threads, n_records,
+               extra=(), expect_rc=0):
+    """One in-process `codec` run over ``bam`` cut into batches of
+    ``n_records`` -> (records, the caller's `CodecStats`, the run report,
+    how many chunks the engine made)."""
+    import json
+
+    from fgumi_tpu.consensus import codec as codec_mod
+    from fgumi_tpu.consensus.fast_codec import FastCodecCaller
+    from fgumi_tpu.io import batch_reader as batch_reader_mod
+
+    for key in ("FGUMI_TPU_HOST_ENGINE", "FGUMI_TPU_ROUTE",
+                "FGUMI_TPU_INLINE_FLIGHT", "FGUMI_TPU_HYBRID",
+                "FGUMI_TPU_MAX_INFLIGHT"):
+        monkeypatch.delenv(key, raising=False)
+    for key, value in HANDOFF_ROUTES[route].items():
+        monkeypatch.setenv(key, value)
+    made, chunks, written = [], [], []  # chunks: how many each call made
+
+    class Spy(codec_mod.CodecConsensusCaller):
+        def __init__(self, *a, **kw):
+            super().__init__(*a, **kw)
+            made.append(self)
+
+    class CutReader:
+        """`BamBatchReader`'s part in `cmd_codec`, cut by record count."""
+
+        def __init__(self, path, target_bytes=None):
+            self.path = path
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+        def __iter__(self):
+            return record_batches(self.path, n_records)
+
+    real_process, real_flush = FastCodecCaller.process_batch, \
+        FastCodecCaller.flush
+    real_write = BamWriter.write_serialized
+
+    def counted(real):
+        def call(self, *a, **kw):
+            out = real(self, *a, **kw)
+            chunks.append(len(out))  # the chunks themselves are not kept
+            return out
+        return call
+
+    def write_serialized(self, blob):
+        written.append(type(blob))
+        return real_write(self, blob)
+
+    monkeypatch.setattr(codec_mod, "CodecConsensusCaller", Spy)
+    if "--classic" not in extra:  # the classic reader wraps the real one
+        monkeypatch.setattr(batch_reader_mod, "BamBatchReader", CutReader)
+    monkeypatch.setattr(FastCodecCaller, "process_batch",
+                        counted(real_process))
+    monkeypatch.setattr(FastCodecCaller, "flush", counted(real_flush))
+    monkeypatch.setattr(BamWriter, "write_serialized", write_serialized)
+    out = str(tmp_path / (name + ".bam"))
+    report = out + ".json"
+    rc = main(["--run-report", report, "codec", "-i", bam, "-o", out,
+               "--threads", str(threads), "--devices", "1", *GATES, *extra])
+    assert rc == expect_rc
+    with open(report) as f:
+        rep = json.load(f)
+    if rc:
+        return None, None, rep, sum(chunks)
+    # every chunk, the flush's too, reached the writer as bytes
+    assert set(written) <= {bytes}
+    return records_of(out), made[-1].stats, rep, sum(chunks)
+
+
+def _codec_counters(metrics):
+    """The engine's counters; the combine's cells as one sum, since the
+    chooser places them by what it measures."""
+    c = {k: v for k, v in metrics.items() if k.startswith("codec.")}
+    c["codec.combine_cells"] = c.pop("codec.combine_cells_host", 0) \
+        + c.pop("codec.combine_cells_device", 0)
+    return c
+
+
+@pytest.fixture(scope="module")
+def gated_classic(gated_bam, tmp_path_factory):
+    """The per-molecule caller on the same input under the same gates: what
+    every run below has to write and to count."""
+    mp = pytest.MonkeyPatch()
+    try:
+        recs, stats, _rep, _n = _run_codec(
+            mp, tmp_path_factory.mktemp("fc_ref"), gated_bam, "classic",
+            "host", 0, 10 ** 9, extra=("--classic",))
+    finally:
+        mp.undo()
+    return recs, stats
+
+
+def test_the_gated_input_rejects_at_both_gates(gated_classic):
+    recs, stats = gated_classic
+    assert stats.total_input_reads == N_GATED
+    assert stats.rejection_reasons.keys() \
+        >= {"HighDuplexDisagreement", "InsufficientOverlap"}
+    assert 40 < len(recs) == stats.consensus_reads_generated < 90
+    # the three unnamed molecules are named by their place in the output,
+    # which the molecules rejected before them shift
+    numbered = [int(r[32:r.index(b"\0", 32)].split(b":")[1]) for r in recs
+                if b"MI" not in r]
+    assert len(numbered) == len(UNNAMED)
+    assert all(n < m + 1 for n, m in zip(numbered, UNNAMED))
+
+
+@functools.lru_cache(maxsize=None)
+def _inline_counters(gated_bam, n_records):
+    """The counters of the `--threads 0` run at this batch size."""
+    import tempfile
+
+    mp = pytest.MonkeyPatch()
+    try:
+        with tempfile.TemporaryDirectory(prefix="fc_inline_") as d:
+            from pathlib import Path
+
+            _r, _s, rep, _n = _run_codec(mp, Path(d), gated_bam, "inline",
+                                         "host", 0, n_records)
+    finally:
+        mp.undo()
+    return _codec_counters(rep["metrics"])
+
+
+@pytest.mark.parametrize("route,threads,n_records", [
+    ("host", threads, n) for n in (4, 7, 50) for threads in (0, 1, 2, 4, 5)]
+    # one batch size is enough to compile the device route's shapes for
+    + [("device", threads, 50) for threads in (0, 1, 2, 4)])
+def test_bytes_and_stats_at_every_thread_count(monkeypatch, tmp_path,
+                                               gated_bam, gated_classic,
+                                               route, threads, n_records):
+    recs, stats, rep, n_chunks = _run_codec(
+        monkeypatch, tmp_path, gated_bam, "fast", route, threads, n_records)
+    assert recs == gated_classic[0]
+    assert stats == gated_classic[1]
+    m = rep["metrics"]
+    # what was counted does not depend on where a chunk resolved
+    counters = _codec_counters(m)
+    counters.pop("codec.stage2_off_thread", None)
+    assert counters == _inline_counters(gated_bam, n_records)
+    # carried molecules cross chunk boundaries, and the flush has a chunk
+    assert m["codec.slow_molecules"] >= N_GATED // max(n_records, 6)
+    assert m["codec.stage2_batches"] == n_chunks >= 8
+    # inline every chunk resolves where it was made; with a writer or
+    # workers every chunk but the flush's, which `cmd_codec` resolves
+    assert m.get("codec.stage2_off_thread", 0) \
+        == (0 if threads <= 1 else n_chunks - 1)
+    if threads >= 4:
+        assert rep["stages"]["resolve[0]"]["busy_s"] > 0
+    if route == "device":
+        assert m["device.dispatches"] > 0
+        assert m.get("device.resident_bytes", 0) == 0
+
+
+@pytest.mark.parametrize("cell_tag", [False, True])
+@pytest.mark.parametrize("route,threads", [("device", 4), ("host", 5)])
+def test_counter_names_when_later_chunks_run_ahead(monkeypatch, tmp_path,
+                                                   gated_bam, gated_classic,
+                                                   route, threads, cell_tag):
+    """Two workers, and every even chunk held back: a chunk that names a
+    molecule by the counter gets there before the chunk in front of it has
+    published, waits for it, and writes the synchronous run's name. Under
+    ``--cell-tag`` every chunk numbers its records, so every chunk waits."""
+    import itertools
+    import time
+
+    from fgumi_tpu.consensus.fast_codec import (FastCodecCaller,
+                                                _EmittedOrder)
+
+    waited = []  # (chunk, chunks published when it asked) of a waiting chunk
+    real_publish = _EmittedOrder.publish
+    real_process = FastCodecCaller.process_batch
+    places = itertools.count()
+
+    def publish(self, serial, emitted, wait=False):
+        if wait:
+            waited.append((serial, self._next))
+        return real_publish(self, serial, emitted, wait)
+
+    class Held:
+        def __init__(self, chunk):
+            self.chunk, self.i = chunk, next(places)
+
+        def resolve(self):
+            if self.i % 2 == 0:
+                time.sleep(0.15)
+            return self.chunk.resolve()
+
+    def process_batch(self, batch, *a, **kw):
+        return [Held(c) for c in real_process(self, batch, *a, **kw)]
+
+    extra = ("--cell-tag", "CB") if cell_tag else ()
+    want = gated_classic
+    if cell_tag:
+        want = _run_codec(monkeypatch, tmp_path, gated_bam, "classic", "host",
+                          0, 10 ** 9, extra=extra + ("--classic",))[:2]
+        assert any(b"CBZCELL" in r for r in want[0])
+    monkeypatch.setattr(_EmittedOrder, "publish", publish)
+    monkeypatch.setattr(FastCodecCaller, "process_batch", process_batch)
+    recs, stats, _rep, n_chunks = _run_codec(
+        monkeypatch, tmp_path, gated_bam, "ahead", route, threads, 50,
+        extra=extra)
+    assert recs == want[0]
+    assert stats == want[1]
+    # the three unnamed molecules lie in three chunks, and only they wait
+    assert len(waited) == (n_chunks if cell_tag else len(UNNAMED))
+    assert any(published < serial for serial, published in waited)
+
+
+def _gated_chunks(monkeypatch, bam, route, n_records=50, batches=None):
+    """The engine's chunks over the input's first ``batches`` batches (all,
+    and the flush's, by default), none resolved."""
+    import itertools
+
+    from fgumi_tpu.consensus.codec import CodecConsensusCaller, CodecOptions
+    from fgumi_tpu.consensus.fast_codec import FastCodecCaller
+
+    for key, value in HANDOFF_ROUTES[route].items():
+        monkeypatch.setenv(key, value)
+    caller = CodecConsensusCaller(
+        "fgumi", "A", CodecOptions(max_duplex_disagreement_rate=0.05,
+                                   min_duplex_length=20))
+    fast = FastCodecCaller(caller, b"MI")
+    chunks = []
+    for batch in itertools.islice(record_batches(bam, n_records), batches):
+        chunks.extend(fast.process_batch(batch))
+    if batches is None:
+        chunks.extend(fast.flush())
+    return caller, fast, chunks
+
+
+def _metric_counters():
+    from fgumi_tpu.observe.metrics import METRICS
+
+    return _codec_counters(METRICS.snapshot())
+
+
+@pytest.mark.parametrize("route", list(HANDOFF_ROUTES))
+def test_concurrent_stage2_tallies(monkeypatch, gated_bam, gated_classic,
+                                   route):
+    """Every chunk of the input (48 of batches of 7 records on the host
+    engine, 9 of 50 on the device route) resolved at once on four threads,
+    under a switch interval short enough to interleave them, against the
+    same chunks resolved in turn: bytes, `CodecStats` and counters."""
+    import sys
+    from concurrent.futures import ThreadPoolExecutor
+
+    n = 50 if route == "device" else 7
+    c0 = _metric_counters()
+    caller_a, _fast, chunks_a = _gated_chunks(monkeypatch, gated_bam, route,
+                                              n)
+    turn = [c.resolve() for c in chunks_a]
+    c1 = _metric_counters()
+    caller_b, _fast, chunks_b = _gated_chunks(monkeypatch, gated_bam, route,
+                                              n)
+    assert len(chunks_b) == len(chunks_a) >= 8
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        with ThreadPoolExecutor(max_workers=4) as pool:
+            futures = [pool.submit(c.resolve) for c in chunks_b]
+            pooled = [f.result(timeout=120) for f in futures]
+    finally:
+        sys.setswitchinterval(old)
+    c2 = _metric_counters()
+    assert pooled == turn
+    assert b"".join(pooled) == b"".join(
+        len(r).to_bytes(4, "little") + r for r in gated_classic[0])
+    assert caller_b.stats == caller_a.stats == gated_classic[1]
+    assert caller_b._counter == caller_a._counter == len(gated_classic[0])
+    delta = lambda after, before: {k: v - before.get(k, 0)
+                                   for k, v in after.items()
+                                   if v != before.get(k, 0)}
+    in_turn, at_once = delta(c1, c0), delta(c2, c1)
+    assert at_once.pop("codec.stage2_off_thread") \
+        == at_once["codec.stage2_batches"] == len(chunks_b)
+    assert "codec.stage2_off_thread" not in in_turn
+    assert at_once == in_turn
+
+
+def test_a_dropped_chunk_gives_back_its_dispatch(monkeypatch, gated_bam):
+    """Chunks nobody resolves (a run that failed with them in flight) hand
+    their dispatches back without waiting for them (no dispatch in flight,
+    no feeder slot held, no resident bytes) and publish no molecules, so
+    the chunk behind them that names a molecule by the counter goes on."""
+    import threading
+
+    from fgumi_tpu.ops import kernel as K
+
+    _caller, _fast, chunks = _gated_chunks(monkeypatch, gated_bam, "device",
+                                           batches=5)
+    assert len(chunks) == 5 and K.DEVICE_STATS.in_flight_count() == 5
+    first = chunks.pop(0).resolve()  # one ends as it should
+    assert first and K.DEVICE_STATS.in_flight_count() == 4
+    del chunks[0], chunks[0]
+    assert K.DEVICE_STATS.in_flight_count() == 2
+    # molecule 41, unnamed, lies in the fourth batch: its chunk waits for
+    # the two dropped ones, which have published nothing emitted
+    got = []
+    t = threading.Thread(
+        target=lambda: got.extend(c.resolve() for c in chunks), daemon=True)
+    t.start()
+    t.join(timeout=60)
+    assert not t.is_alive() and len(got) == 2 and all(got)
+    assert K.DEVICE_STATS.in_flight_count() == 0
+    K.DEVICE_FEEDER.drain(timeout=30)
+    assert K.DEVICE_STATS.resident_bytes == 0
+    assert K.DEVICE_FEEDER._inflight == 0
+
+
+@pytest.mark.parametrize("threads", [1, 2, 4])
+@pytest.mark.parametrize("where", ["process", "resolve"])
+def test_a_failed_run_drains_and_exits_with_its_code(monkeypatch, tmp_path,
+                                                     gated_bam, where,
+                                                     threads):
+    """The third batch fails, at process time or where its chunk resolves,
+    with chunks before and behind it and unnamed molecules among them: the
+    run ends with the fault's exit code (no waiter hangs) and every
+    dispatch it had started has been completed or handed back."""
+    from fgumi_tpu.consensus.fast_codec import FastCodecCaller
+    from fgumi_tpu.ops import kernel as K
+    from fgumi_tpu.utils.faults import InjectedFault
+
+    calls = {"process": 0, "resolve": 0}
+    fault = InjectedFault("the third batch")
+    real = {"process": FastCodecCaller._prepare_span,
+            "resolve": FastCodecCaller._finish_batch}
+
+    def failing(kind):
+        def call(self, *a, **kw):
+            calls[kind] += 1
+            if kind == where and calls[kind] == 3:
+                raise fault
+            return real[kind](self, *a, **kw)
+        return call
+
+    monkeypatch.setattr(FastCodecCaller, "_prepare_span", failing("process"))
+    monkeypatch.setattr(FastCodecCaller, "_finish_batch", failing("resolve"))
+    _recs, _stats, rep, n_chunks = _run_codec(
+        monkeypatch, tmp_path, gated_bam, "fault", "device", threads, 50,
+        expect_rc=3)
+    assert n_chunks >= 2 and calls[where] >= 3
+    assert rep["metrics"]["device.dispatches"] >= 2
+    # pytest keeps the logged error, whose traceback holds the inline run's
+    # frames, and they the chunk it dropped: a process would have exited
+    fault.__traceback__ = None
+    assert K.DEVICE_STATS.in_flight_count() == 0

@@ -3126,7 +3126,7 @@ def _pipeline_fused(args):
 
     from .observe import heartbeat as _hb
     from .observe.metrics import METRICS
-    from .observe.scope import spawn_thread
+    from .observe.scope import set_thread_prefix, spawn_thread
     from .observe.trace import span
     from .pipeline_chain import (ChainAborted, ChainChannel,
                                  ChannelBamWriter, ChannelBatchReader)
@@ -3183,6 +3183,9 @@ def _pipeline_fused(args):
     active = {}
 
     def runner(name):
+        # this stage's helper threads: chain-<stage>-reader / -writer /
+        # -worker-i (the stage thread runs in a context copy of its own)
+        set_thread_prefix(f"chain-{name}")
         sargs = ns[name]
         t0 = time.monotonic()
         rc = None
@@ -4571,7 +4574,12 @@ def _main_scoped(args, argv):
         # Chrome tracer below only under --trace
         from .observe.trace import arm_spans
 
-        arm_spans()
+        agg = arm_spans()
+        if report_path:
+            # the allocator at the job's start; the report reads its end
+            from .observe import alloc
+
+            agg.alloc_start = alloc.read()
     if trace_path:
         from .observe.scope import current_scope
         from .observe.trace import start_trace

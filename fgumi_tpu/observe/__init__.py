@@ -9,7 +9,9 @@ fgumi-tpu, as one layer with a zero-overhead-when-disabled contract:
   at every layer boundary (start-up, host stages, chain stages, batch
   engines, router, feeder, resolve, sink), live under ``--trace`` or
   ``--run-report``; each span knows its parent and goes to the run
-  report's aggregate, onto the profiler's clock
+  report's aggregate (by name, and by thread from each thread's root
+  spans: which thread paces a job, and how much of its work was off the
+  CPU or in the kernel), onto the profiler's clock
   (``jax.profiler.TraceAnnotation``) and, under ``--trace``, into Chrome
   trace-event JSON loadable in Perfetto.
 - :mod:`.metrics` — a process-wide :class:`MetricsRegistry` aggregating the
@@ -31,6 +33,8 @@ fgumi-tpu, as one layer with a zero-overhead-when-disabled contract:
   warm-kernel evidence the serve smoke gate asserts on.
 - :mod:`.process` — the process-level record every run report carries:
   start-up spans and each compile / cache load of the process so far.
+- :mod:`.alloc` — glibc's allocator counters (arenas, what they hold and
+  hold free, mapped blocks) at a job's two ends, for its run report.
 
 Disabled is the default and costs nothing on the hot path: ``span`` returns
 a shared no-op context manager, metric folding happens once per command at

@@ -78,7 +78,18 @@ import time
 #: far (``kind``, ``s``, ``at_s`` since process start, ``shape``, ``fun``;
 #: observe/process.py). ``metrics.device.compile_cache_load_s`` holds the
 #: seconds of this run's cache loads.
-SCHEMA_VERSION = 9
+#: v10 (ISSUE 35): optional ``threads`` section, beside ``spans`` whenever
+#: spans were live — one record per thread of the job, from its root spans
+#: (``root_wall_s`` = ``work_s`` + ``wait_s``; ``utime_s``, ``stime_s``
+#: and ``offcpu_s`` where its roots read ``getrusage``; ``roots`` (root
+#: span name -> work seconds) and ``self_s`` by span name) — with ``threads_pacing`` (the thread with the most
+#: ``work_s``: ``thread``, ``role`` = the root span most of it was under,
+#: ``work_s``), and the optional ``alloc`` section (``start`` / ``end``:
+#: glibc's ``mallinfo2`` sums, the arena count and ``ru_maxrss`` at the
+#: job's two ends; only when a run report was asked for). A ``spans``
+#: record no longer lists the names of its threads: ``threads.*.self_s``
+#: says where a span ran, and how long there.
+SCHEMA_VERSION = 10
 
 
 def _device_stats():
@@ -135,6 +146,12 @@ _OPTIONAL = {
                       # skipped_explicit, fingerprint mismatches, whether
                       # router priors were seeded (tune/profile.py; v7)
     "spans": dict,    # span aggregate by name (observe/trace.py; v9)
+    "threads": dict,  # the same spans by thread: wall, waits, CPU seconds
+                      # of each thread's root spans (observe/trace.py; v10)
+    "threads_pacing": dict,  # {thread, role, work_s} of the thread with
+                             # the most work_s (v10)
+    "alloc": dict,    # allocator counters at the job's start and end
+                      # (observe/alloc.py; v10)
     "process": dict,  # process-level record: start-up spans and every
                       # compile / cache load so far (observe/process.py; v9)
 }
@@ -156,6 +173,11 @@ _AUDIT_COUNTERS = ("sampled", "clean", "divergent", "dropped")
 
 #: Required numeric fields of one ``spans.by_name`` record (v9).
 _SPAN_FIELDS = ("count", "wall_s", "self_s", "wait_s", "p50_s", "max_s")
+
+#: Required numeric fields of one ``threads`` record (v10), and those a
+#: thread has, all or none, where its root spans read ``getrusage``.
+_THREAD_FIELDS = ("root_wall_s", "wait_s", "work_s")
+_THREAD_CLOCK_FIELDS = ("utime_s", "stime_s", "offcpu_s")
 
 
 def _is_number(v) -> bool:
@@ -183,8 +205,66 @@ def _validate_spans(spans: dict, errors: list):
                 or rec["wait_s"] > rec["wall_s"] + 1e-6:
             errors.append(f"spans entry {name!r}: self_s or wait_s "
                           "exceeds wall_s")
-        if not isinstance(rec.get("threads"), list):
-            errors.append(f"spans entry {name!r} has no threads list")
+
+
+def _numbers(obj) -> bool:
+    return isinstance(obj, dict) and all(_is_number(v) for v in obj.values())
+
+
+def _validate_threads(obj: dict, errors: list):
+    threads = obj["threads"]
+    for name, rec in threads.items():
+        if not isinstance(rec, dict):
+            errors.append(f"threads entry {name!r} is not an object")
+            continue
+        missing = [f for f in _THREAD_FIELDS if not _is_number(rec.get(f))]
+        if missing:
+            errors.append(f"threads entry {name!r} missing numeric fields "
+                          f"{missing}")
+            continue
+        # a thread's root spans are its work and its declared waits
+        if abs(rec["work_s"] + rec["wait_s"] - rec["root_wall_s"]) > 1e-5:
+            errors.append(f"threads entry {name!r}: work_s + wait_s is not "
+                          "root_wall_s")
+        clock = [f for f in _THREAD_CLOCK_FIELDS if f in rec]
+        if clock and (len(clock) != len(_THREAD_CLOCK_FIELDS)
+                      or not all(_is_number(rec[f]) for f in clock)):
+            errors.append(f"threads entry {name!r} has some of "
+                          f"{list(_THREAD_CLOCK_FIELDS)} and not all, or "
+                          "not as numbers")
+        if not _numbers(rec.get("roots")) or not _numbers(rec.get("self_s")):
+            errors.append(f"threads entry {name!r}: roots or self_s is not "
+                          "{span name: seconds}")
+    pacing = obj.get("threads_pacing")
+    if pacing is not None and not (
+            pacing.get("thread") in threads
+            and isinstance(pacing.get("role"), str)
+            and _is_number(pacing.get("work_s"))):
+        errors.append("threads_pacing is not {thread, role, work_s} of a "
+                      "thread of the threads section")
+    # what the spans section says by name, this one says by thread
+    by_name = (obj.get("spans") or {}).get("by_name")
+    if isinstance(by_name, dict):
+        for name, rec in by_name.items():
+            by_thread = sum(t["self_s"].get(name, 0.0)
+                            for t in threads.values()
+                            if _numbers(t.get("self_s")))
+            if _is_number(rec.get("self_s")) and abs(
+                    by_thread - rec["self_s"]) > 1e-5 * (len(threads) + 1):
+                errors.append(f"threads: self_s of {name!r} sums to "
+                              f"{by_thread:.6f} over the threads, spans "
+                              f"has {rec['self_s']:.6f}")
+
+
+def _validate_alloc(alloc: dict, errors: list):
+    for end in ("start", "end"):
+        rec = alloc.get(end)
+        if not _numbers(rec) or "maxrss_kb" not in rec:
+            errors.append(f"alloc.{end} is not an object of numbers with "
+                          "maxrss_kb")
+    unknown = set(alloc) - {"start", "end"}
+    if unknown:
+        errors.append(f"alloc unknown fields {sorted(unknown)}")
 
 
 def _validate_process(proc: dict, errors: list):
@@ -305,6 +385,12 @@ def validate_report(obj) -> list:
                           f"{comp_sum:.6f} past total_s {total:.6f}")
     if isinstance(obj.get("spans"), dict):
         _validate_spans(obj["spans"], errors)
+    if isinstance(obj.get("threads"), dict):
+        _validate_threads(obj, errors)
+    elif "threads_pacing" in obj:
+        errors.append("threads_pacing without a threads section")
+    if isinstance(obj.get("alloc"), dict):
+        _validate_alloc(obj["alloc"], errors)
     if isinstance(obj.get("process"), dict):
         _validate_process(obj["process"], errors)
     return errors
@@ -561,8 +647,28 @@ def build_report(command: str, argv, started_unix: float, wall_s: float,
     agg = current_aggregate()
     if agg is not None:
         spans = agg.snapshot()
+        threads = spans.pop("threads")
         if spans["by_name"]:
             report["spans"] = spans
+        # the same spans by thread (v10), and which thread paces the job:
+        # the one whose root spans hold the most seconds that are not
+        # declared waits, named by the root span most of them were under
+        if threads:
+            report["threads"] = threads
+            name, rec = max(threads.items(),
+                            key=lambda kv: kv[1]["work_s"])
+            if rec["roots"]:
+                report["threads_pacing"] = {
+                    "thread": name,
+                    "role": max(rec["roots"], key=rec["roots"].get),
+                    "work_s": rec["work_s"]}
+        # allocator counters at the job's two ends (v10): only where the
+        # invocation took the start record, which it does for a run report
+        if agg.alloc_start is not None:
+            from . import alloc
+
+            report["alloc"] = {"start": agg.alloc_start,
+                               "end": alloc.read()}
     # process-level record (v9): what this process paid once — start-up
     # spans, every compile and cache load so far — in every report, since
     # the commands that paid may have written none

@@ -219,12 +219,25 @@ def publish_to_global(scope: TelemetryScope):
 _setname = None  # libc's pthread_setname_np; False once known missing
 
 
+def os_thread_name(name: str) -> str:
+    """``name`` in the 15 bytes the kernel keeps for a thread: without its
+    ``fgumi-`` / ``chain-`` prefix where it is longer (fgumi-device-feeder
+    -> device-feeder), and if that is still too long both its ends
+    (chain-simplex-worker-0 -> simplexworker-0: the head says whose, the
+    tail which; a plain cut would give two workers one name)."""
+    if len(name) > 15 and name.startswith(("fgumi-", "chain-")):
+        name = name[6:]
+    if len(name) > 15:
+        name = name[:7] + name[-8:]
+    return name
+
+
 def name_os_thread(name: str):
-    """Give the calling thread its OS name (``pthread_setname_np``; the
-    kernel keeps 15 bytes), which is what a profiler's host plane and
-    ``top -H`` show: Python 3.12 names only its own Thread objects, so
-    every thread of the process otherwise reads ``python3``. Best-effort;
-    a platform without the call keeps the old names."""
+    """Give the calling thread its OS name (``pthread_setname_np``), which
+    is what a profiler's host plane and ``top -H`` show: Python 3.12 names
+    only its own Thread objects, so every thread of the process otherwise
+    reads ``python3``. Best-effort; a platform without the call keeps the
+    old names."""
     global _setname
     if _setname is None:
         try:
@@ -237,10 +250,21 @@ def name_os_thread(name: str):
         except (OSError, AttributeError):
             _setname = False
     if _setname:
-        if len(name) > 15 and name.startswith("fgumi-"):
-            name = name[6:]  # fgumi-device-feeder -> device-feeder
         # CPython's thread ident is the pthread_t on POSIX
-        _setname(threading.get_ident(), name.encode()[:15])
+        _setname(threading.get_ident(), os_thread_name(name).encode()[:15])
+
+
+#: what replaces the ``fgumi`` of a helper thread's name: a fused chain's
+#: stage thread sets ``chain-<stage>``, so that the readers, writers and
+#: workers its ``run_stages`` starts are told apart from the next stage's
+_thread_prefix = contextvars.ContextVar("fgumi_tpu_thread_prefix",
+                                        default=None)
+
+
+def set_thread_prefix(prefix: str):
+    """Name the helper threads started from this context (and its copies)
+    ``<prefix>-reader`` ... instead of ``fgumi-reader`` ...: a name only."""
+    _thread_prefix.set(prefix)
 
 
 def spawn_thread(target, *, name=None, daemon=True, args=()):
@@ -249,6 +273,9 @@ def spawn_thread(target, *, name=None, daemon=True, args=()):
     its helper threads — under its OS thread name. Returned un-started
     (call ``.start()``)."""
     ctx = contextvars.copy_context()
+    prefix = _thread_prefix.get()
+    if prefix and name and name.startswith("fgumi-"):
+        name = prefix + name[5:]
 
     def run():
         if name:

@@ -10,7 +10,10 @@ thread when it began (its parent, from a thread-local stack) and goes to:
   direct children's cover), ``wait_s`` (cover of the ``wait=True`` spans
   below it on the same thread) and the 50th percentile; for spans opened
   with ``rusage=True`` also the thread's ``RUSAGE_THREAD`` deltas. It
-  becomes the run report's ``spans`` section.
+  becomes the run report's ``spans`` section. The same records, kept per
+  thread, are the ``threads`` section: a thread's *root* spans (no parent
+  on its stack) give its wall, its declared waits and, from one
+  ``getrusage`` pair a root span, its CPU seconds.
 - **the profiler's clock**: once ``jax`` is imported a live span also
   enters a ``jax.profiler.TraceAnnotation`` of the same name, so while a
   profiler session is open (``--xla-profile``, or a harness's
@@ -168,7 +171,8 @@ def current_aggregate():
 MAX_EVENTS = 500_000
 
 #: spans whose names start so are per-block I/O: they never reach the
-#: flight ring, which is for dispatches and breaker transitions
+#: flight ring, which is for dispatches and breaker transitions, and as a
+#: thread's root spans they read no ``getrusage``
 _PER_BLOCK_PREFIXES = ("bgzf.", "io.")
 
 #: per-thread stack of the open live spans, outermost first
@@ -186,6 +190,17 @@ except ImportError:  # pragma: no cover - non-POSIX
 _RUSAGE_FIELDS = (("utime_s", "ru_utime"), ("stime_s", "ru_stime"),
                   ("minflt", "ru_minflt"), ("majflt", "ru_majflt"),
                   ("nvcsw", "ru_nvcsw"), ("nivcsw", "ru_nivcsw"))
+#: those of them a thread's record keeps, summed over its root spans
+_THREAD_RUSAGE = ("utime_s", "stime_s")
+_THREAD_RUSAGE_AT = tuple(i for i, (key, _f) in enumerate(_RUSAGE_FIELDS)
+                          if key in _THREAD_RUSAGE)
+
+
+def _rusage_value(key, v):
+    """A summed ``getrusage`` delta as the report has it: seconds to the
+    microsecond, counts whole."""
+    return round(v, 6) if key.endswith("_s") else int(v)
+
 
 _annotation = None  # jax.profiler.TraceAnnotation once jax is imported
 
@@ -209,7 +224,8 @@ class _Span:
     """One in-flight span: recorded in every armed sink on exit."""
 
     __slots__ = ("_agg", "_tracer", "name", "args", "_t0", "_parent",
-                 "_child_s", "_wait_s", "_wait", "_ru0", "_annot", "_counts")
+                 "_child_s", "_wait_s", "_wait", "_rusage", "_ru0", "_annot",
+                 "_counts")
 
     def __init__(self, agg, tracer, name, args, rusage, wait):
         self._agg = agg
@@ -220,6 +236,7 @@ class _Span:
         self._child_s = 0.0  # cover of the direct children
         self._wait_s = 0.0   # cover of the wait spans anywhere below
         self._wait = wait
+        self._rusage = rusage  # the call site's: deltas in the name's record
         self._ru0 = rusage   # True until __enter__ reads the counters
         self._annot = None
         self._counts = None
@@ -242,6 +259,8 @@ class _Span:
             _tls.name = threading.current_thread().name
         if stack:
             self._parent = stack[-1]
+        elif not (self._wait or self.name.startswith(_PER_BLOCK_PREFIXES)):
+            self._ru0 = True  # a root span: the thread's record reads them
         stack.append(self)
         cls = _annotation or _annotation_cls()
         if cls is not None:
@@ -277,7 +296,8 @@ class _Span:
             parent._child_s += dur
             parent._wait_s += waited
         self._agg.record(self.name, dur, max(dur - self._child_s, 0.0),
-                         waited, ru, self._counts, _tls.name)
+                         waited, ru if self._rusage else None, self._counts,
+                         _tls.name, parent is None, ru)
         if self._tracer is not None:
             args = self.args
             if parent is not None:
@@ -291,7 +311,7 @@ class _Span:
 
 class _SpanStat:
     __slots__ = ("count", "wall_s", "self_s", "wait_s", "hist", "rusage",
-                 "counts", "threads")
+                 "counts")
 
     def __init__(self):
         from .metrics import Histogram
@@ -303,23 +323,46 @@ class _SpanStat:
         self.hist = Histogram()
         self.rusage = None
         self.counts = None
-        self.threads = set()
+
+
+class _ThreadStat:
+    """What one thread of the job did, from its root spans."""
+
+    __slots__ = ("root_wall_s", "wait_s", "clocked_work_s", "rusage",
+                 "roots", "self_s")
+
+    def __init__(self):
+        self.root_wall_s = 0.0
+        self.wait_s = 0.0
+        self.clocked_work_s = 0.0  # work of the roots that read getrusage
+        self.rusage = None         # _THREAD_RUSAGE sums over those roots
+        self.roots = {}            # root span name -> its work seconds
+        self.self_s = {}           # span name -> self seconds on this thread
 
 
 class SpanAggregate:
-    """Per-scope totals by span name: the run report's ``spans`` section.
+    """Per-scope totals by span name and by thread: the run report's
+    ``spans`` and ``threads`` sections.
 
     ``job`` names whose spans these are: the serve job id, else the
     ordinal of the top-level invocation in this process (0 with no scope).
+    ``alloc_start`` is the allocator record of the job's start when a run
+    report was asked for (observe/alloc.py), else None.
     """
 
     def __init__(self, job=0):
         self.job = job
+        self.alloc_start = None
         self._lock = threading.Lock()
         self._by_name = {}
+        self._by_thread = {}
 
     def record(self, name, dur, self_s, wait_s=0.0, rusage=None,
-               counts=None, thread=None):
+               counts=None, thread=None, root=False, root_rusage=None):
+        """Fold one finished span in. ``root``: it had no parent on its
+        thread's stack, so it counts towards the thread's wall and waits,
+        with ``root_rusage`` (the ``_RUSAGE_FIELDS`` deltas over it, or
+        None where it read none) towards the thread's CPU seconds."""
         if thread is None:
             thread = threading.current_thread().name
         with self._lock:
@@ -331,8 +374,6 @@ class SpanAggregate:
             st.self_s += self_s
             st.wait_s += wait_s
             st.hist.observe(dur)
-            if len(st.threads) < 8:
-                st.threads.add(thread)
             if rusage is not None:
                 if st.rusage is None:
                     st.rusage = [0] * len(_RUSAGE_FIELDS)
@@ -343,10 +384,36 @@ class SpanAggregate:
                     st.counts = {}
                 for k, v in counts.items():
                     st.counts[k] = st.counts.get(k, 0) + v
+            th = self._by_thread.get(thread)
+            if th is None:
+                th = self._by_thread[thread] = _ThreadStat()
+            th.self_s[name] = th.self_s.get(name, 0.0) + self_s
+            if root:
+                work = max(dur - wait_s, 0.0)
+                th.root_wall_s += dur
+                th.wait_s += wait_s
+                th.roots[name] = th.roots.get(name, 0.0) + work
+                if root_rusage is not None:
+                    th.clocked_work_s += work
+                    if th.rusage is None:
+                        th.rusage = [0] * len(_THREAD_RUSAGE)
+                    for i, at in enumerate(_THREAD_RUSAGE_AT):
+                        th.rusage[i] += root_rusage[at]
 
     def snapshot(self) -> dict:
-        """``{"job": ..., "by_name": {name: record}}``, names sorted."""
+        """``{"job": ..., "by_name": {span name: record}, "threads":
+        {thread name: record}}``, names sorted, both views as of one
+        instant.
+
+        A thread's record, of every thread that ended a span:
+        ``root_wall_s`` = ``work_s`` + ``wait_s`` over its root spans,
+        ``roots`` (root span name -> work seconds) and ``self_s`` by span
+        name. A thread whose root spans read ``getrusage`` (all but waits
+        and per-block I/O) also has ``utime_s`` and ``stime_s`` over them
+        and ``offcpu_s``: the seconds of their work the thread was on no
+        CPU, having declared no wait."""
         by_name = {}
+        threads = {}
         with self._lock:
             for name in sorted(self._by_name):
                 st = self._by_name[name]
@@ -354,16 +421,31 @@ class SpanAggregate:
                        "self_s": round(st.self_s, 6),
                        "wait_s": round(st.wait_s, 6),
                        "p50_s": round(st.hist.quantile(0.50), 6),
-                       "max_s": round(st.hist.max, 6),
-                       "threads": sorted(st.threads)}
+                       "max_s": round(st.hist.max, 6)}
                 if st.rusage is not None:
                     for (key, _f), v in zip(_RUSAGE_FIELDS, st.rusage):
-                        rec[key] = round(v, 6) if key.endswith("_s") \
-                            else int(v)
+                        rec[key] = _rusage_value(key, v)
                 if st.counts:
                     rec.update(st.counts)
                 by_name[name] = rec
-        return {"job": self.job, "by_name": by_name}
+            for thread in sorted(self._by_thread):
+                th = self._by_thread[thread]
+                rec = {"root_wall_s": round(th.root_wall_s, 6),
+                       "wait_s": round(th.wait_s, 6),
+                       "work_s": round(max(th.root_wall_s - th.wait_s, 0.0),
+                                       6)}
+                if th.rusage is not None:
+                    for key, v in zip(_THREAD_RUSAGE, th.rusage):
+                        rec[key] = _rusage_value(key, v)
+                    rec["offcpu_s"] = round(max(
+                        th.clocked_work_s - th.rusage[0] - th.rusage[1],
+                        0.0), 6)
+                rec["roots"] = {n: round(v, 6)
+                                for n, v in sorted(th.roots.items())}
+                rec["self_s"] = {n: round(v, 6)
+                                 for n, v in sorted(th.self_s.items())}
+                threads[thread] = rec
+        return {"job": self.job, "by_name": by_name, "threads": threads}
 
 
 class _Tracer:

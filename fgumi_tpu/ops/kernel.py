@@ -1456,8 +1456,9 @@ class _DeadlineRunner:
     simply not returned to the free list: it is left to die with the
     wedge (daemon thread), and the next call starts a fresh one."""
 
-    def __init__(self, name: str):
+    def __init__(self, name: str, wait_span: str):
         self._name = name
+        self._wait_span = wait_span  # the caller's wait for its helper
         self._lock = threading.Lock()
         self._free = []     # idle worker queues
         self._seq = 0
@@ -1481,7 +1482,9 @@ class _DeadlineRunner:
                 threading.Thread(target=self._loop, args=(q, name),
                                  name=name, daemon=True).start()
         q.put((ctx, fn, box, done))
-        if not done.wait(deadline_s):
+        with span(self._wait_span, wait=True):
+            in_time = done.wait(deadline_s)
+        if not in_time:
             # wedged: the worker is abandoned with its call (never reused;
             # if the wedge ever clears it parks in q.get() forever)
             raise DeadlineExceeded(
@@ -1507,8 +1510,9 @@ class _DeadlineRunner:
                 done.set()
 
 
-_FETCH_RUNNER = _DeadlineRunner("fgumi-device-fetch")
-_DISPATCH_RUNNER = _DeadlineRunner("fgumi-device-dispatch")
+_FETCH_RUNNER = _DeadlineRunner("fgumi-device-fetch", "device.fetch_wait")
+_DISPATCH_RUNNER = _DeadlineRunner("fgumi-device-dispatch",
+                                   "device.dispatch_wait")
 
 
 def _fetch_with_deadline(dev, deadline_s):

@@ -131,7 +131,7 @@ def test_deadline_runner_reuses_worker():
     bounded calls run on the same helper thread."""
     from fgumi_tpu.ops.kernel import _DeadlineRunner
 
-    r = _DeadlineRunner("test-runner")
+    r = _DeadlineRunner("test-runner", "test.runner_wait")
     names = [r.run(lambda: threading.current_thread().name, 5, "probe")
              for _ in range(4)]
     assert len(set(names)) == 1
@@ -142,7 +142,7 @@ def test_deadline_runner_replaces_wedged_worker():
     fresh worker and still completes."""
     from fgumi_tpu.ops.kernel import _DeadlineRunner
 
-    r = _DeadlineRunner("test-runner")
+    r = _DeadlineRunner("test-runner", "test.runner_wait")
     gate = threading.Event()
     with pytest.raises(DeadlineExceeded):
         r.run(lambda: gate.wait(10), 0.05, "wedge")

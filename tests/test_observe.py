@@ -573,8 +573,13 @@ def test_span_parent_and_self_time_nested_and_siblings_two_threads():
     by = _spans()
     outer, child, top = by["outer"], by["child"], by["main-top"]
     assert outer["count"] == 2 and child["count"] == 4
-    assert set(outer["threads"]) == {"obs-other",
-                                     threading.current_thread().name}
+    by_thread = trace.current_aggregate().snapshot()["threads"]
+    assert {t for t, rec in by_thread.items() if "outer" in rec["self_s"]} \
+        == {"obs-other", threading.current_thread().name}
+    # a root is a root of its own thread: main-top is none of obs-other's
+    assert set(by_thread["obs-other"]["roots"]) == {"outer"}
+    assert set(by_thread[threading.current_thread().name]["roots"]) \
+        == {"outer", "main-top"}
     # self time = duration minus the children's cover, per thread
     assert child["self_s"] == pytest.approx(child["wall_s"])
     assert outer["self_s"] == pytest.approx(

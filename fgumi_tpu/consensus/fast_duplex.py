@@ -15,6 +15,7 @@ per molecule, in stream order.
 """
 
 import threading
+from typing import NamedTuple
 
 import numpy as np
 
@@ -37,6 +38,96 @@ AB_R1, AB_R2, BA_R1, BA_R2 = 0, 1, 2, 3
 def _flip_umi(value: str) -> str:
     """Dual-UMI strand reorientation (duplex_caller.rs:1226-1231)."""
     return "-".join(reversed(value.split("-")))
+
+
+class OutputReads(NamedTuple):
+    """A span's K output reads as columns, in output order: molecule
+    order, R1 then R2 of each emitted molecule."""
+
+    mols: np.ndarray   # int64: the read's molecule within the span
+    flags: np.ndarray  # int32: _TYPE_FLAGS of R1 / R2
+    kinds: np.ndarray  # int8: 2 = combined, 1 = a passed through, 0 = b
+    aseg: np.ndarray   # int64: the first (or only) seg it is made of
+    bseg: np.ndarray   # int64: the second seg of a combined read, else -1
+    lens: np.ndarray   # int32: the read's length
+    rx_a: np.ndarray   # int64: AB seg whose reads' RX values count, or -1
+    rx_b: np.ndarray   # int64: BA seg (values strand-flipped), or -1
+
+
+def output_read_columns(seg_map, seg_len, d16, live_mol, min_total, min_xy,
+                        min_yx):
+    """Which output reads a span's molecules give, as whole-array numpy
+    over the ``(nG, 4)`` molecule -> seg map (duplex.py _combine_molecule
+    and _has_min_reads, a molecule a row).
+
+    Returns ``(reads, emitted, full, ab_only, ba_only)``: the columns and
+    bool masks over the span's molecules. Output read R1 is made of
+    (AB_R1, BA_R2) and R2 of (AB_R2, BA_R1); a molecule with one strand
+    keeps that strand's two segs and is a candidate only where YX may be
+    0. Within a read a seg is alive if it has a positive depth before the
+    read's length (the shorter seg's where both are present): both alive
+    combine, one alive passes through at its own length, and a molecule
+    is emitted only if both its reads are alive and, with both strands,
+    pass the min-reads gate on their output depths."""
+    nG = len(seg_map)
+    p = seg_map >= 0
+    full = p.all(axis=1) & live_mol
+    one_ok = live_mol & (min_yx == 0)
+    ab_only = p[:, AB_R1] & p[:, AB_R2] & ~p[:, BA_R1] & ~p[:, BA_R2] & one_ok
+    ba_only = ~p[:, AB_R1] & ~p[:, AB_R2] & p[:, BA_R1] & p[:, BA_R2] & one_ok
+    cand = np.nonzero(full | ab_only | ba_only)[0]
+
+    # the sides as taken, (n, 2) with R1 then R2: they are the RX sides
+    # too, since the reference folds in the raws of BOTH segs even when
+    # one strand's consensus is depth-dead (duplex.py:421-434)
+    sm = seg_map[cand]
+    a = np.where(ba_only[cand, None], -1, sm[:, [AB_R1, AB_R2]])
+    b = np.where(ab_only[cand, None], -1, sm[:, [BA_R2, BA_R1]])
+    has_a, has_b = a >= 0, b >= 0
+    a0, b0 = np.maximum(a, 0), np.maximum(b, 0)
+
+    # per-seg aliveness: one pass finds each seg's first positive-depth
+    # column, and a read's check (lengths differ by pairing) is a compare
+    pos_depth = d16 > 0
+    first_nz = np.where(pos_depth.any(axis=1), np.argmax(pos_depth, axis=1),
+                        1 << 30)
+    La = np.where(has_a, seg_len[a0], 0)
+    Lb = np.where(has_b, seg_len[b0], 0)
+    both = has_a & has_b
+    shorter = np.minimum(La, Lb)
+    alive_a = has_a & (first_nz[a0] < np.where(both, shorter, La))
+    alive_b = has_b & (first_nz[b0] < np.where(both, shorter, Lb))
+    comb = alive_a & alive_b
+    kinds = np.where(comb, 2, np.where(alive_a, 1, 0)).astype(np.int8)
+    aseg = np.where(alive_a, a, b)
+    bseg = np.where(comb, b, -1)
+    lens = np.where(comb, shorter, np.where(alive_a, La, Lb))
+    ok = (alive_a | alive_b).all(axis=1)
+
+    # _has_min_reads on both output reads of a both-strand molecule
+    # (duplex.py:304-308): the largest depth within the read's length on
+    # each side, 0 for the side a passed-through read lacks
+    gated = np.nonzero(ok & full[cand])[0]
+    if len(gated):
+        in_len = np.arange(d16.shape[1]) < lens[gated, :, None]
+        na = np.where(in_len, d16[aseg[gated]], 0).max(axis=2)
+        nb_ = np.where(in_len & comb[gated, :, None],
+                       d16[np.maximum(bseg[gated], 0)], 0).max(axis=2)
+        xy, yx = np.maximum(na, nb_), np.minimum(na, nb_)
+        passes = (min_total <= xy + yx) & (min_xy <= xy) & (min_yx <= yx)
+        ok[gated] = passes.all(axis=1)
+
+    emitted = np.zeros(nG, dtype=bool)
+    emitted[cand[ok]] = True
+    n_out = int(ok.sum())
+    reads = OutputReads(
+        mols=np.repeat(cand[ok], 2),
+        flags=np.tile(np.array([_TYPE_FLAGS[R1], _TYPE_FLAGS[R2]],
+                               dtype=np.int32), n_out),
+        kinds=kinds[ok].ravel(), aseg=aseg[ok].ravel(),
+        bseg=bseg[ok].ravel(), lens=lens[ok].ravel().astype(np.int32),
+        rx_a=a[ok].ravel(), rx_b=b[ok].ravel())
+    return reads, emitted, full, ab_only, ba_only
 
 
 class _DuplexPending:
@@ -421,9 +512,9 @@ class FastDuplexCaller:
             tb, tq, d16, e16, ctx = finish_ss()
             try:
                 return self._stage2(
-                    batch, span, gb, sizes, n_paired, slow, live_mol,
-                    seg_map, seg_len, tb, tq, d16, e16, codes, vrows,
-                    vstarts, L_max, ctx)
+                    batch, span, gb, n_paired, slow, live_mol, seg_map,
+                    seg_len, tb, tq, d16, e16, codes, vrows, vstarts, L_max,
+                    ctx)
             finally:
                 if ctx is not None:
                     ctx["resident"].release()
@@ -600,9 +691,9 @@ class FastDuplexCaller:
 
     # ---------------------------------------------------------------- stage 2
 
-    def _stage2(self, batch, span, gb, sizes, n_paired, slow, live_mol,
-                seg_map, seg_len, tb, tq, d16, e16, codes, vrows, vstarts,
-                L_max, combine_ctx=None) -> bytes:
+    def _stage2(self, batch, span, gb, n_paired, slow, live_mol, seg_map,
+                seg_len, tb, tq, d16, e16, codes, vrows, vstarts, L_max,
+                combine_ctx=None) -> bytes:
         """Strand combination + serialization, molecule order preserved.
 
         Runs on whichever thread resolves the span's chunk, several spans
@@ -613,98 +704,11 @@ class FastDuplexCaller:
         time; they are only interleaved here."""
         caller = self.caller
         stats = caller.stats
-        nG = len(sizes)
 
         with _span("engine.duplex.classify", rusage=True):
-            p = seg_map >= 0
-            full = p.all(axis=1) & live_mol
-            ab_only = p[:, AB_R1] & p[:, AB_R2] & ~p[:, BA_R1] & ~p[:, BA_R2] \
-                & live_mol & (caller.min_yx == 0)
-            ba_only = ~p[:, AB_R1] & ~p[:, AB_R2] & p[:, BA_R1] & p[:, BA_R2] \
-                & live_mol & (caller.min_yx == 0)
-
-            # per-seg aliveness: any positive depth within a length limit.
-            # One vector pass finds each seg's first positive-depth column;
-            # the per-output-read check (lengths differ per pairing) is then a
-            # scalar compare instead of a numpy any() per molecule
-            pos_depth = d16 > 0
-            has_depth = pos_depth.any(axis=1)
-            first_nz = np.where(has_depth, np.argmax(pos_depth, axis=1),
-                                1 << 30)
-
-            def seg_alive(s, limit):
-                return first_nz[s] < limit
-
-            # build output reads in molecule order: 2 per emitted molecule
-            out_specs = []   # (mol, flags, aseg, bseg, kind) kind: 2=combined,
-            #                   1=a-passthrough, 0=b-passthrough(is_ba_only)
-            emitted = np.zeros(nG, dtype=bool)
-            col = np.arange(L_max)
-
-            def classify(mol, a_s, b_s):
-                """One output read's effective sides; None = dead molecule."""
-                La, Lb = int(seg_len[a_s]) if a_s >= 0 else 0, \
-                    int(seg_len[b_s]) if b_s >= 0 else 0
-                if a_s >= 0 and b_s >= 0:
-                    length = min(La, Lb)
-                    aa = seg_alive(a_s, length)
-                    ba = seg_alive(b_s, length)
-                    if aa and ba:
-                        return (2, a_s, b_s, length)
-                    if aa:
-                        return (1, a_s, -1, La)
-                    if ba:
-                        return (0, b_s, -1, Lb)
-                    return None
-                if a_s >= 0:
-                    return (1, a_s, -1, La) if seg_alive(a_s, La) else None
-                if b_s >= 0:
-                    return (0, b_s, -1, Lb) if seg_alive(b_s, Lb) else None
-                return None
-
-            for g in np.nonzero(full | ab_only | ba_only)[0]:
-                # rx1/rx2: the AB and BA segs contributing RX values per
-                # output read — the reference folds in raws of BOTH segs even
-                # when one strand's consensus is depth-dead (duplex.py:421-434
-                # iterates raws_a + raws_b of the branch taken)
-                if full[g]:
-                    spec1 = classify(g, seg_map[g, AB_R1], seg_map[g, BA_R2])
-                    spec2 = classify(g, seg_map[g, AB_R2], seg_map[g, BA_R1])
-                    rx1 = (seg_map[g, AB_R1], seg_map[g, BA_R2])
-                    rx2 = (seg_map[g, AB_R2], seg_map[g, BA_R1])
-                    if spec1 is None or spec2 is None:
-                        continue
-                    # _has_min_reads on both output reads (duplex.py:304-308)
-                    okmin = True
-                    for spec in (spec1, spec2):
-                        kind, s1, s2, length = spec
-                        na = int(d16[s1, :length].max()) if length else 0
-                        nb_ = int(d16[s2, :length].max()) \
-                            if kind == 2 and length else 0
-                        xy, yx = max(na, nb_), min(na, nb_)
-                        if not (caller.min_total <= xy + yx
-                                and caller.min_xy <= xy
-                                and caller.min_yx <= yx):
-                            okmin = False
-                    if not okmin:
-                        continue
-                elif ab_only[g]:
-                    spec1 = classify(g, seg_map[g, AB_R1], -1)
-                    spec2 = classify(g, seg_map[g, AB_R2], -1)
-                    rx1 = (seg_map[g, AB_R1], -1)
-                    rx2 = (seg_map[g, AB_R2], -1)
-                    if spec1 is None or spec2 is None:
-                        continue
-                else:
-                    spec1 = classify(g, -1, seg_map[g, BA_R2])
-                    spec2 = classify(g, -1, seg_map[g, BA_R1])
-                    rx1 = (-1, seg_map[g, BA_R2])
-                    rx2 = (-1, seg_map[g, BA_R1])
-                    if spec1 is None or spec2 is None:
-                        continue
-                emitted[g] = True
-                out_specs.append((g, _TYPE_FLAGS[R1]) + spec1 + rx1)
-                out_specs.append((g, _TYPE_FLAGS[R2]) + spec2 + rx2)
+            reads, emitted, full, ab_only, ba_only = output_read_columns(
+                seg_map, seg_len, d16, live_mol, caller.min_total,
+                caller.min_xy, caller.min_yx)
 
             # InsufficientReads for live-but-unemitted molecules (the
             # fallthrough reject in _combine_molecule, duplex.py:361-363)
@@ -721,33 +725,32 @@ class FastDuplexCaller:
                 METRICS.inc("duplex.rejected.no_consensus", int(dead.sum()))
                 METRICS.inc("duplex.rejected", int(dead.sum()))
 
-        K = len(out_specs)
+        K = len(reads.mols)
         fast_blob = b""
         rec_end = np.zeros(0, dtype=np.int64)
         if K:
             fast_blob, rec_end = self._serialize_outputs(
-                batch, span, gb, out_specs, seg_map, seg_len, tb, tq, d16,
-                e16, codes, vrows, vstarts, L_max, col, combine_ctx)
+                batch, span, gb, reads, tb, tq, d16, e16, codes, vrows,
+                vstarts, L_max, combine_ctx)
             stats.add_consensus_reads(K)
         if not slow:
             return fast_blob
         # molecule order: each fallback molecule's bytes go in after the
-        # fast records of the molecules before it
+        # fast records of the molecules before it (ascending by
+        # construction, and none of them among the columns' molecules)
+        before = np.searchsorted(reads.mols,
+                                 np.fromiter(slow, np.int64, len(slow)))
+        ends = np.concatenate(([0], rec_end))[before].tolist()
         parts = []
-        out_i = 0
         start = 0
-        for g, blob in slow.items():  # ascending by construction
-            while out_i < K and out_specs[out_i][0] < g:
-                out_i += 2
-            end = int(rec_end[out_i - 1]) if out_i else 0
+        for end, blob in zip(ends, slow.values()):
             parts += (fast_blob[start:end], blob)
             start = end
         parts.append(fast_blob[start:])
         return b"".join(parts)
 
-    def _serialize_outputs(self, batch, span, gb, out_specs, seg_map, seg_len,
-                           tb, tq, d16, e16, codes, vrows, vstarts, L_max,
-                           col, combine_ctx=None):
+    def _serialize_outputs(self, batch, span, gb, reads, tb, tq, d16, e16,
+                           codes, vrows, vstarts, L_max, combine_ctx=None):
         """Combine + native-serialize the K fast output reads (order kept).
 
         The strand combine runs either as numpy (the semantic reference) or
@@ -757,19 +760,18 @@ class FastDuplexCaller:
         whose inputs carry an oracle patch (suspect positions) always take
         the host combine: the resident arrays are pre-patch."""
         caller = self.caller
-        K = len(out_specs)
+        kinds, aseg, lens = reads.kinds, reads.aseg, reads.lens
+        K = len(kinds)
         with _span("engine.duplex.combine", rusage=True) as sp:
-            mols, flags, kinds, aseg, bseg, lens, out_b, out_q, out_e = \
-                self._combine_outputs(out_specs, tb, tq, e16, codes, vrows,
-                                      vstarts, L_max, col, combine_ctx, sp)
+            out_b, out_q, out_e = self._combine_outputs(
+                reads, tb, tq, e16, codes, vrows, vstarts, L_max,
+                combine_ctx, sp)
 
-        # serializer strand inputs: 'a' side = dup.ab_consensus (the alive /
-        # AB side, truncated to the combined length), 'b' side =
+        # serializer strand inputs: 'a' side = dup.ab_consensus (aseg: the
+        # alive / AB side, truncated to the combined length), 'b' side =
         # ba_consensus (combined case only)
-        a_rows = aseg
-        a_len = lens.astype(np.int32)
         b_present = (kinds == 2).astype(np.uint8)
-        b_rows = np.where(kinds == 2, bseg, 0)
+        b_rows = np.where(kinds == 2, reads.bseg, 0)
         b_len = np.where(kinds == 2, lens, 0).astype(np.int32)
 
         def row_addrs(arr, rows):
@@ -778,21 +780,21 @@ class FastDuplexCaller:
         # RX per output read (strand-reoriented consensus, duplex.py:421-434)
         with _span("engine.duplex.rx", rusage=True):
             rx_addr, rx_len, keep_alive = self._output_rx(
-                batch, span, out_specs, seg_map, vrows, vstarts)
+                batch, span, reads.rx_a, reads.rx_b, vrows, vstarts)
 
         with _span("resolve.serialize", rusage=True):
             mi_off, mi_len, _ = batch.tag_locs(self.tag)
-            first_rows = span[gb[mols]]
+            first_rows = span[gb[reads.mols]]
             mi_addr = batch.buf.ctypes.data + mi_off[first_rows]
             # base MI, no /A|/B
             mi_l = (mi_len[first_rows] - 2).astype(np.int32)
 
             blob, rec_end = nb.build_duplex_records(
                 row_addrs(out_b, np.arange(K)), row_addrs(out_q, np.arange(K)),
-                row_addrs(out_e, np.arange(K)), lens, flags,
+                row_addrs(out_e, np.arange(K)), lens, reads.flags,
                 caller.prefix.encode(), mi_addr, mi_l,
-                row_addrs(tb, a_rows), row_addrs(tq, a_rows),
-                row_addrs(d16, a_rows), row_addrs(e16, a_rows), a_len,
+                row_addrs(tb, aseg), row_addrs(tq, aseg),
+                row_addrs(d16, aseg), row_addrs(e16, aseg), lens,
                 row_addrs(tb, b_rows), row_addrs(tq, b_rows),
                 row_addrs(d16, b_rows), row_addrs(e16, b_rows), b_len,
                 b_present, rx_addr, rx_len, caller.read_group_id.encode(),
@@ -800,21 +802,18 @@ class FastDuplexCaller:
         del keep_alive
         return blob, rec_end
 
-    def _combine_outputs(self, out_specs, tb, tq, e16, codes, vrows, vstarts,
-                         L_max, col, combine_ctx, sp):
-        """The K output reads' arrays: the specs as columns, and the reads'
-        bases, quals and errors, strand-combined or passed through.
-        ``codes`` is the batch's packed rows and ``vrows`` the valid ones
-        in seg order (seg s is ``vrows[vstarts[s]:vstarts[s + 1]]``): the
-        error recount reads them in place. ``sp`` is the
-        ``engine.duplex.combine`` span this runs in."""
-        K = len(out_specs)
-        mols = np.array([s[0] for s in out_specs], dtype=np.int64)
-        flags = np.array([s[1] for s in out_specs], dtype=np.int32)
-        kinds = np.array([s[2] for s in out_specs], dtype=np.int8)
-        aseg = np.array([s[3] for s in out_specs], dtype=np.int64)
-        bseg = np.array([s[4] for s in out_specs], dtype=np.int64)
-        lens = np.array([s[5] for s in out_specs], dtype=np.int32)
+    def _combine_outputs(self, reads, tb, tq, e16, codes, vrows, vstarts,
+                         L_max, combine_ctx, sp):
+        """The K output reads' bases, quals and errors, strand-combined
+        or passed through. ``codes`` is the batch's packed rows and
+        ``vrows`` the valid ones in seg order (seg s is
+        ``vrows[vstarts[s]:vstarts[s + 1]]``): the error recount reads
+        them in place. ``sp`` is the ``engine.duplex.combine`` span this
+        runs in."""
+        kinds, aseg, bseg, lens = reads.kinds, reads.aseg, reads.bseg, \
+            reads.lens
+        K = len(kinds)
+        col = np.arange(L_max)
 
         out_b = np.zeros((K, L_max), dtype=np.uint8)
         out_q = np.zeros((K, L_max), dtype=np.uint8)
@@ -923,23 +922,20 @@ class FastDuplexCaller:
             combine_ctx["resident"].release()
 
         passthrough = np.nonzero(kinds != 2)[0]
-        for k in passthrough:
-            s = aseg[k]
-            L = lens[k]
-            out_b[k, :L] = tb[s, :L]
-            out_q[k, :L] = tq[s, :L]
-            out_e[k, :L] = e16[s, :L]
-        return mols, flags, kinds, aseg, bseg, lens, out_b, out_q, out_e
+        if len(passthrough):
+            src = aseg[passthrough]
+            in_len = col[None, :] < lens[passthrough, None]
+            out_b[passthrough] = np.where(in_len, tb[src], 0)
+            out_q[passthrough] = np.where(in_len, tq[src], 0)
+            out_e[passthrough] = np.where(in_len, e16[src], 0)
+        return out_b, out_q, out_e
 
-    def _output_rx(self, batch, span, out_specs, seg_map, vrows, vstarts):
-        """RX tag per output read: a-side values verbatim, b-side values
-        strand-flipped, then the UMI consensus (unanimous fast path)."""
+    def _output_rx(self, batch, span, rx_a, rx_b, vrows, vstarts):
+        """RX tag per output read: the values of seg ``rx_a`` verbatim,
+        those of seg ``rx_b`` strand-flipped, then the UMI consensus
+        (unanimous fast path)."""
         rx_vo, rx_vl, _ = batch.tag_locs_str(b"RX")
         buf = batch.buf
-        K = len(out_specs)
-        rx_off_in_blob = np.zeros(K, dtype=np.int64)
-        rx_len = np.zeros(K, dtype=np.int32)
-        blob = bytearray()  # one allocation for all values, not one per emit
 
         span_v = span[vrows]
         una_off, una_len = nb.rx_unanimous(buf, rx_vo[span_v], rx_vl[span_v],
@@ -950,21 +946,19 @@ class FastDuplexCaller:
 
         # native fast path: every output whose contributing segs are
         # unanimous/absent resolves in one C pass (single-read verbatim /
-        # all-equal uppercased, b-side flip on bytes); only divergent or
-        # disagreeing outputs fall through to the Python likelihood loop
-        fb_set = None
-        if K and nb.available():
-            a_arr = np.fromiter((s[6] for s in out_specs), np.int64, K)
-            b_arr = np.fromiter((s[7] for s in out_specs), np.int64, K)
-            n_off, n_len, n_blob, fb = nb.duplex_rx_fast(
-                buf, una_off, una_len, cnt, a_arr, b_arr)
-            if len(fb) == 0:
-                blob_arr = n_blob if len(n_blob) else \
-                    np.zeros(1, dtype=np.uint8)
-                rx_addr = np.where(n_len > 0,
-                                   blob_arr.ctypes.data + n_off, 0)
-                return rx_addr, n_len, [blob_arr]
-            fb_set = set(int(x) for x in fb)
+        # all-equal uppercased, b-side flip on bytes); only the divergent or
+        # disagreeing outputs it hands back go through the Python
+        # likelihood loop
+        n_off, n_len, n_blob, fallback = nb.duplex_rx_fast(
+            buf, una_off, una_len, cnt, rx_a, rx_b)
+        n_blob_arr = n_blob if len(n_blob) else np.zeros(1, dtype=np.uint8)
+        rx_addr = np.where(n_len > 0, n_blob_arr.ctypes.data + n_off, 0)
+        if len(fallback) == 0:
+            return rx_addr, n_len, [n_blob_arr]
+
+        rx_off_in_blob = np.zeros(len(rx_a), dtype=np.int64)
+        rx_len = np.zeros(len(rx_a), dtype=np.int32)
+        blob = bytearray()  # one allocation for all values, not one per emit
 
         def seg_values(s):
             """Ordered present RX strings of seg s."""
@@ -983,12 +977,10 @@ class FastDuplexCaller:
 
         fams = []
         fam_ks = []
-        for k, spec in enumerate(out_specs):
-            if fb_set is not None and k not in fb_set:
-                continue  # resolved by the native fast path
+        for k in fallback.tolist():
             # AB-seg values verbatim, BA-seg values flipped — BOTH segs of
             # the branch contribute, independent of consensus aliveness
-            a_s, b_s = spec[6], spec[7]
+            a_s, b_s = int(rx_a[k]), int(rx_b[k])
             # fast path: when every contributing seg is unanimous, the
             # family holds at most two distinct values — if they agree, the
             # consensus is that value (simple_umi's all-equal rule: verbatim
@@ -1040,18 +1032,11 @@ class FastDuplexCaller:
             fam_ks.append(k)
         for k, rx in zip(fam_ks, consensus_umis_batch(fams)):
             emit(k, rx)
+        # the Python-resolved outputs override the native arena's entries;
+        # both arenas stay alive through the returned list
         blob_arr = np.frombuffer(bytes(blob) or b"\x00", dtype=np.uint8)
-        if fb_set is not None:
-            # merge: python-resolved (fallback) outputs override the
-            # native arena's entries; both arenas stay alive via the
-            # returned keepalive list
-            n_blob_arr = n_blob if len(n_blob) else np.zeros(1, np.uint8)
-            py_mask = rx_len > 0
-            rx_addr = np.where(
-                py_mask, blob_arr.ctypes.data + rx_off_in_blob,
-                np.where(n_len > 0, n_blob_arr.ctypes.data + n_off, 0))
-            return (rx_addr, np.where(py_mask, rx_len, n_len),
-                    [blob_arr, n_blob_arr])
-        rx_addr = np.where(rx_len > 0,
-                           blob_arr.ctypes.data + rx_off_in_blob, 0)
-        return rx_addr, rx_len, [blob_arr]
+        py_mask = rx_len > 0
+        rx_addr = np.where(py_mask, blob_arr.ctypes.data + rx_off_in_blob,
+                           rx_addr)
+        return (rx_addr, np.where(py_mask, rx_len, n_len),
+                [blob_arr, n_blob_arr])

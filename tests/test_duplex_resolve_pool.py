@@ -248,43 +248,60 @@ def test_stage2_runs_off_the_processing_thread(monkeypatch, tmp_path,
         assert min(busy) > 0
 
 
-@pytest.mark.parametrize("route,threads", [("device", 4), ("host", 8)])
-def test_even_chunks_finish_after_odd_ones(monkeypatch, tmp_path, mixed_bam,
-                                           classic, route, threads):
-    """Chunk 2k does not resolve until chunk 2k+1 has: the workers hand
-    their bytes over out of order, and the writer's serial-number reorder
-    is what keeps the record stream in molecule order."""
-    done = {}       # a chunk's place in the run -> set once its bytes exist
-    finished = []
-    lock = threading.Lock()
+def hold_chunks(monkeypatch, resolve):
+    """Hand run_stages' resolve_fn, in each chunk's place, an object whose
+    ``resolve()`` is ``resolve(i, chunk)``, ``i`` the chunk's place in the
+    run (made on the processing thread, in order)."""
     places = itertools.count()
     real_process = FastDuplexCaller.process_batch
 
-    def event(i):
-        with lock:
-            return done.setdefault(i, threading.Event())
-
     class Held:
-        """What run_stages' resolve_fn is handed in a chunk's place."""
-
         def __init__(self, chunk):
             self.chunk = chunk
-            self.i = next(places)  # made on the processing thread, in order
+            self.i = next(places)
 
         def resolve(self):
-            if self.i % 2 == 0:
-                event(self.i + 1).wait(timeout=2.0)  # the last one times out
-            out = self.chunk.resolve()
-            with lock:
-                finished.append(self.i)
-            event(self.i).set()
-            return out
+            return resolve(self.i, self.chunk)
 
     def process_batch(self, batch, *a, **kw):
         return [item if isinstance(item, bytes) else Held(item)
                 for item in real_process(self, batch, *a, **kw)]
 
     monkeypatch.setattr(FastDuplexCaller, "process_batch", process_batch)
+
+
+class Events:
+    """One threading.Event a chunk, made on first use by either side."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._events = {}
+
+    def __getitem__(self, i):
+        with self._lock:
+            return self._events.setdefault(i, threading.Event())
+
+
+@pytest.mark.parametrize("route,threads", [("device", 4), ("host", 8)])
+def test_even_chunks_finish_after_odd_ones(monkeypatch, tmp_path, mixed_bam,
+                                           classic, route, threads):
+    """Chunk 2k does not resolve until chunk 2k+1 has: the workers hand
+    their bytes over out of order, and the writer's serial-number reorder
+    is what keeps the record stream in molecule order."""
+    done = Events()  # a chunk's place in the run -> set once its bytes exist
+    finished = []
+    lock = threading.Lock()
+
+    def resolve(i, chunk):
+        if i % 2 == 0:
+            done[i + 1].wait(timeout=2.0)  # the last one times out
+        out = chunk.resolve()
+        with lock:
+            finished.append(i)
+        done[i].set()
+        return out
+
+    hold_chunks(monkeypatch, resolve)
     recs, stats, _rep = run_duplex(monkeypatch, tmp_path, mixed_bam, "swap",
                                    route, threads)
     n = len(finished)
@@ -294,6 +311,44 @@ def test_even_chunks_finish_after_odd_ones(monkeypatch, tmp_path, mixed_bam,
     assert late == n // 2
     assert recs == classic[0]
     assert stats == classic[1]
+
+
+def test_molecule_tallies_add_up_with_two_chunks_at_once(monkeypatch,
+                                                        tmp_path, mixed_bam,
+                                                        classic):
+    """At --threads 4 chunks 2k and 2k+1 start their stage 2 together, one
+    on each resolve worker: every molecule is still counted once, as what
+    the columns made of it, a reject, or a call of the per-molecule
+    caller."""
+    started = Events()
+    lock = threading.Lock()
+    active = [0, 0]  # chunks inside resolve() now, and the most there were
+
+    def resolve(i, chunk):
+        started[i].set()
+        started[i ^ 1].wait(timeout=2.0)  # the last one times out
+        with lock:
+            active[0] += 1
+            active[1] = max(active)
+        try:
+            return chunk.resolve()
+        finally:
+            with lock:
+                active[0] -= 1
+
+    hold_chunks(monkeypatch, resolve)
+    recs, stats, rep = run_duplex(monkeypatch, tmp_path, mixed_bam, "pairs",
+                                  "device", 4)
+    assert active[1] == 2
+    assert recs == classic[0] and stats == classic[1]
+    m = rep["metrics"]
+    parts = [m[f"duplex.{name}"] for name in
+             ("full", "ab_only", "ba_only", "rejected", "slow_molecules")]
+    assert min(parts) > 0
+    assert sum(parts) == m["duplex.molecules"] == 120
+    assert m["duplex.rejected"] == sum(
+        v for k, v in m.items() if k.startswith("duplex.rejected."))
+    assert m["duplex.stage2_off_thread"] == m["duplex.stage2_batches"] >= 8
 
 
 def _caller():

@@ -8,9 +8,13 @@ molecules, and min-reads gating.
 import numpy as np
 import pytest
 
-from fgumi_tpu.consensus.duplex import DuplexConsensusCaller, iter_duplex_groups
+from fgumi_tpu.consensus.duplex import (DuplexConsensusCaller,
+                                        iter_duplex_groups, parse_min_reads)
 from fgumi_tpu.consensus.fast import resolve_chunk
-from fgumi_tpu.consensus.fast_duplex import FastDuplexCaller
+from fgumi_tpu.consensus.fast_duplex import (AB_R1, AB_R2, BA_R1, BA_R2,
+                                             FastDuplexCaller,
+                                             output_read_columns)
+from fgumi_tpu.consensus.vanilla import R1, R2, _TYPE_FLAGS
 from fgumi_tpu.consensus.overlapping import (OverlappingBasesConsensusCaller,
                                              apply_overlapping_consensus)
 from fgumi_tpu.core.grouper import consensus_pregroup_keep
@@ -44,15 +48,24 @@ def run_slow(path, min_reads=(1,), overlap=False, **kw):
     return out, caller, oc
 
 
-def run_fast(path, min_reads=(1,), overlap=False, target_bytes=4096, **kw):
+def batches_of(path, target_bytes, n_records):
+    """The reader's batches, or batches of ``n_records`` records."""
+    if n_records:
+        yield from record_batches(path, n_records)
+        return
+    with BamBatchReader(path, target_bytes=target_bytes) as reader:
+        yield from reader
+
+
+def run_fast(path, min_reads=(1,), overlap=False, target_bytes=4096,
+             n_records=None, **kw):
     caller = make_caller(min_reads, **kw)
     oc = OverlappingBasesConsensusCaller("consensus", "consensus") \
         if overlap else None
     fast = FastDuplexCaller(caller, b"MI", overlap_caller=oc)
     chunks = []
-    with BamBatchReader(path, target_bytes=target_bytes) as reader:
-        for batch in reader:
-            chunks.extend(fast.process_batch(batch))
+    for batch in batches_of(path, target_bytes, n_records):
+        chunks.extend(fast.process_batch(batch))
     chunks.extend(fast.flush())
     recs = []
     for blob in map(resolve_chunk, chunks):
@@ -66,10 +79,10 @@ def run_fast(path, min_reads=(1,), overlap=False, target_bytes=4096, **kw):
 
 
 def assert_parity(path, min_reads=(1,), overlap=False, target_bytes=4096,
-                  **kw):
+                  n_records=None, **kw):
     slow_out, slow_caller, slow_oc = run_slow(path, min_reads, overlap, **kw)
     fast_out, fast_caller, fast_oc = run_fast(path, min_reads, overlap,
-                                              target_bytes, **kw)
+                                              target_bytes, n_records, **kw)
     assert len(fast_out) == len(slow_out)
     for i, (f, s) in enumerate(zip(fast_out, slow_out)):
         assert f == s, f"consensus record {i} differs"
@@ -105,9 +118,29 @@ def test_parity_large_batches(duplex_bam):
     assert_parity(duplex_bam, target_bytes=64 << 20)
 
 
-def test_parity_tiny_batches(duplex_bam):
-    """Every molecule crosses a batch boundary (full carry coverage)."""
-    assert_parity(duplex_bam, target_bytes=512)
+@pytest.mark.parametrize("n_records", [4, 7, 50])
+def test_parity_tiny_batches(duplex_bam, n_records):
+    """Molecules really cross batch boundaries (the batch reader itself
+    cuts no finer than one decoded chunk, which is this whole file). A
+    molecule is 12 records: batches of 4 and 7 end inside every one, so
+    each is carried and called by the per-molecule caller; batches of 50
+    carry one molecule in four or five, and its bytes are interleaved
+    between the records the columns built."""
+    from fgumi_tpu.observe.metrics import METRICS
+
+    before = METRICS.snapshot()
+    assert_parity(duplex_bam, n_records=n_records)
+    after = METRICS.snapshot()
+    m = {k: after.get(k, 0) - before.get(k, 0)
+         for k in ("duplex.molecules", "duplex.slow_molecules",
+                   "duplex.full")}
+    # the molecule that holds a batch's last record is carried, once
+    # however many batches it spans
+    carried = len({(min(end, 1800) - 1) // 12
+                   for end in range(n_records, 1800 + n_records, n_records)})
+    assert m["duplex.molecules"] == 150
+    assert m["duplex.slow_molecules"] == carried
+    assert m["duplex.full"] == 150 - carried
 
 
 def test_parity_max_reads_per_strand(duplex_bam):
@@ -224,6 +257,221 @@ def adversarial_bam(tmp_path_factory):
 def test_parity_adversarial(adversarial_bam, overlap, min_reads):
     assert_parity(adversarial_bam, min_reads, overlap=overlap,
                   target_bytes=2048)
+
+
+def test_every_molecule_a_fallback(duplex_bam):
+    """With the rejects tracked every molecule of a span goes to the
+    per-molecule caller: stage 2 has no output read of its own (K = 0) and
+    only puts the fallback molecules' bytes in order."""
+    assert_parity(duplex_bam, track_rejects=True)
+
+
+# --------------------------------------------------- stage 2's column build
+
+#: the width of the synthetic segs' arrays
+_L = 8
+
+
+def oracle_output_reads(seg_map, seg_len, d16, live_mol, min_total, min_xy,
+                        min_yx):
+    """The per-molecule rule stage 2 ran as a Python loop until PR 36
+    (duplex.py _combine_molecule and _has_min_reads), kept as the oracle
+    of ``output_read_columns``: the output reads as 8-tuples ``(mol, read
+    flags, kind, aseg, bseg, length, rx_a, rx_b)`` in output order, and
+    the emitted molecules."""
+    def alive(s, limit):
+        return bool((d16[s, :limit] > 0).any())
+
+    def classify(a_s, b_s):
+        La = int(seg_len[a_s]) if a_s >= 0 else 0
+        Lb = int(seg_len[b_s]) if b_s >= 0 else 0
+        if a_s >= 0 and b_s >= 0:
+            length = min(La, Lb)
+            aa, ba = alive(a_s, length), alive(b_s, length)
+            if aa and ba:
+                return (2, a_s, b_s, length)
+            if aa:
+                return (1, a_s, -1, La)
+            if ba:
+                return (0, b_s, -1, Lb)
+            return None
+        if a_s >= 0:
+            return (1, a_s, -1, La) if alive(a_s, La) else None
+        if b_s >= 0:
+            return (0, b_s, -1, Lb) if alive(b_s, Lb) else None
+        return None
+
+    specs, emitted = [], []
+    for g in range(len(seg_map)):
+        if not live_mol[g]:
+            continue
+        ab1, ab2, ba1, ba2 = (int(x) for x in seg_map[g])
+        has = [x >= 0 for x in (ab1, ab2, ba1, ba2)]
+        if all(has):
+            sides = ((ab1, ba2), (ab2, ba1))
+        elif has == [True, True, False, False] and min_yx == 0:
+            sides = ((ab1, -1), (ab2, -1))
+        elif has == [False, False, True, True] and min_yx == 0:
+            sides = ((-1, ba2), (-1, ba1))
+        else:
+            continue
+        reads = [classify(*side) for side in sides]
+        if None in reads:
+            continue
+        if all(has):
+            ok = True
+            for kind, s1, s2, length in reads:
+                na = int(d16[s1, :length].max()) if length else 0
+                nb_ = int(d16[s2, :length].max()) \
+                    if kind == 2 and length else 0
+                xy, yx = max(na, nb_), min(na, nb_)
+                ok &= min_total <= xy + yx and min_xy <= xy and min_yx <= yx
+            if not ok:
+                continue
+        emitted.append(g)
+        for flags, read, side in zip((_TYPE_FLAGS[R1], _TYPE_FLAGS[R2]),
+                                     reads, sides):
+            specs.append((g, flags) + read + side)
+    return specs, emitted
+
+
+def make_span(molecules):
+    """``(seg_map, seg_len, d16, live_mol)`` of a span whose molecules are
+    dicts seg type -> depths by column (the seg's length is the list's);
+    a molecule under the key ``"dead"`` failed a gate before stage 2."""
+    seg_map = np.full((len(molecules), 4), -1, dtype=np.int64)
+    live = np.ones(len(molecules), dtype=bool)
+    lens, rows = [], []
+    for g, mol in enumerate(molecules):
+        for t, depths in mol.items():
+            if t == "dead":
+                live[g] = False
+                continue
+            seg_map[g, t] = len(rows)
+            lens.append(len(depths))
+            rows.append(list(depths) + [0] * (_L - len(depths)))
+    d16 = np.array(rows, dtype=np.int32).reshape(len(rows), _L)
+    return seg_map, np.array(lens, dtype=np.int64), d16, live
+
+
+def check_columns(span, min_reads):
+    gates = parse_min_reads(min_reads)
+    reads, emitted, full, ab_only, ba_only = output_read_columns(*span,
+                                                                 *gates)
+    specs, want_emitted = oracle_output_reads(*span, *gates)
+    got = list(zip(*(col.tolist() for col in reads)))
+    assert got == specs
+    assert np.nonzero(emitted)[0].tolist() == want_emitted
+    assert [c.dtype for c in reads] == [np.int64, np.int32, np.int8,
+                                        np.int64, np.int64, np.int32,
+                                        np.int64, np.int64]
+    # each molecule is of one shape at most, and only a live one of any
+    assert not (full & ab_only).any() and not (full & ba_only).any() \
+        and not (ab_only & ba_only).any()
+    assert not ((full | ab_only | ba_only) & ~span[3]).any()
+    return specs, want_emitted
+
+
+def _mol(ab1=None, ab2=None, ba1=None, ba2=None, dead=False):
+    mol = {t: d for t, d in ((AB_R1, ab1), (AB_R2, ab2), (BA_R1, ba1),
+                             (BA_R2, ba2)) if d is not None}
+    if dead:
+        mol["dead"] = True
+    return mol
+
+
+_D = [3, 3, 3, 3, 3, 3]   # a seg alive from its first column
+_Z = [0, 0, 0, 0, 0, 0]   # a seg with no depth anywhere
+
+#: name -> (molecules, the kinds of the output reads expected at 1 1 0)
+_COLUMN_CASES = {
+    "both_alive": ([_mol(_D, _D, _D, _D)], [2, 2]),
+    "ab_dead_in_full": ([_mol(_Z, _D, _D, _D)], [0, 2]),
+    "ba_dead_in_full": ([_mol(_D, _D, _Z, _D)], [2, 1]),
+    "each_read_loses_a_strand": ([_mol(_Z, _D, _Z, _D)], [0, 1]),
+    "both_dead_in_one_read": ([_mol(_Z, _D, _D, _Z)], []),
+    "all_dead": ([_mol(_Z, _Z, _Z, _Z)], []),
+    "ab_only": ([_mol(_D, _D)], [1, 1]),
+    "ab_only_dead_read": ([_mol(_D, _Z)], []),
+    "ba_only": ([_mol(ba1=_D, ba2=_D)], [0, 0]),
+    "ba_only_dead_read": ([_mol(ba1=_Z, ba2=_D)], []),
+    # R1 is 4 long where AB_R1 is 7: combined at the shorter seg's length
+    "unequal_lengths": ([_mol([2] * 7, [2] * 5, [1] * 6, [1] * 4)], [2, 2]),
+    # AB_R1's first depth is at column 4 = the combined length: dead within
+    # it, alive within its own 7, but the other strand is what passes
+    "first_depth_at_length": ([_mol([0, 0, 0, 0, 5, 5, 5], _D, _D,
+                                    [1, 1, 1, 1])], [0, 2]),
+    # ... beyond it, beside a strand with none: AB_R1 would be alive alone,
+    # but within the combined length neither is, and the molecule goes
+    "first_depth_beyond_length": ([_mol([0, 0, 0, 0, 0, 5, 5], _D, _D,
+                                        [0, 0, 0, 0])], []),
+    # the first depth in the last column inside the combined length
+    "first_depth_inside_length": ([_mol([0, 0, 0, 5, 5, 5, 5], _D, _D,
+                                        [1, 1, 1, 1])], [2, 2]),
+    "three_segs": ([_mol(_D, _D, _D)], []),
+    "failed_a_gate": ([_mol(_D, _D, _D, _D, dead=True)], []),
+    "mixed_span": ([_mol(_D, _D, _D, _D), _mol(_D, _D), _mol(_Z, _D, _D, _Z),
+                    _mol(_D, _D, _D, _D, dead=True), _mol(ba1=_D, ba2=_D),
+                    _mol(_D, _D, _Z, _D), _mol()], [2, 2, 1, 1, 0, 0, 2, 1]),
+    "no_molecules": ([], []),
+    "every_molecule_a_fallback": ([_mol(), _mol(), _mol()], []),
+}
+
+
+@pytest.mark.parametrize("case", list(_COLUMN_CASES))
+def test_output_read_columns(case):
+    """The column build against the per-molecule rule it replaced."""
+    molecules, kinds = _COLUMN_CASES[case]
+    specs, _emitted = check_columns(make_span(molecules), (1, 1, 0))
+    assert [s[2] for s in specs] == kinds
+
+
+@pytest.mark.parametrize("min_reads,emitted", [
+    ((1,), [0, 1, 2, 3, 4]), ((1, 1, 0), [0, 1, 2, 3, 4, 5, 6, 7]),
+    ((3, 2, 1), [0, 1, 2]), ((4, 2, 2), [0, 1])])
+def test_output_read_columns_min_reads(min_reads, emitted):
+    """_has_min_reads on the output reads' depths: the largest depth of
+    each side within the read's length, not the seg's; a passed-through
+    read's other side counts 0; one-strand molecules are not gated, and
+    exist only where YX may be 0."""
+    molecules = [
+        _mol([2] * 6, [2] * 6, [2] * 6, [2] * 6),     # 2 + 2 everywhere
+        _mol([3] * 6, [2] * 6, [2] * 6, [2] * 6),     # 3 + 2 and 2 + 2
+        _mol([2] * 6, [2] * 6, [1] * 6, [1] * 6),     # 2 + 1
+        # AB_R1's depth 4 lies beyond the combined length 3: 1 + 1
+        _mol([1, 1, 1, 4, 4, 4], [1] * 6, [1] * 6, [1, 1, 1]),
+        _mol([1] * 6, [1] * 6, [1] * 6, [1] * 6),     # 1 + 1
+        # R1 loses its BA strand and passes AB_R1 through: 3 + 0
+        _mol([3] * 6, [3] * 6, [3] * 6, _Z),
+        _mol([9] * 6, [9] * 6),                       # /A only
+        _mol(ba1=[9] * 6, ba2=[9] * 6),               # /B only
+    ]
+    assert check_columns(make_span(molecules), min_reads)[1] == emitted
+
+
+@pytest.mark.parametrize("min_reads", [(1,), (1, 1, 0), (3, 2, 1), (4, 2, 2)])
+@pytest.mark.parametrize("seed", [3, 17, 101])
+def test_output_read_columns_random_spans(seed, min_reads):
+    """200 molecules of random shape, seg lengths and depth runs."""
+    rng = np.random.default_rng(seed)
+
+    def seg():
+        n = int(rng.integers(1, _L + 1))
+        depths = rng.integers(0, 5, size=n)
+        depths[:int(rng.integers(0, n + 1))] = 0  # a dead head, often all
+        return depths.tolist()
+
+    shapes = [(1, 1, 1, 1)] * 5 + [(1, 1, 0, 0), (0, 0, 1, 1), (1, 1, 1, 0),
+                                   (0, 1, 1, 0), (0, 0, 0, 0)]
+    molecules = []
+    for _ in range(200):
+        shape = shapes[int(rng.integers(len(shapes)))]
+        molecules.append(_mol(*(seg() if present else None
+                                for present in shape),
+                              dead=rng.random() < 0.1))
+    specs, _emitted = check_columns(make_span(molecules), min_reads)
+    if min_reads == (1, 1, 0):
+        assert {s[2] for s in specs} == {0, 1, 2}
 
 
 def test_missing_suffix_raises(tmp_path):

@@ -1270,7 +1270,12 @@ def _add_group(sub):
                    help="minimum distinct UMIs per group before the indexed "
                         "candidate search (pigeonhole/BK-tree) replaces the "
                         "dense pairwise scan; 0 = always dense. Default is "
-                        "measured for the vectorized scan (8192)")
+                        "measured for the vectorized scan (8192). A group's "
+                        "neighbour graph takes one of three routes by its "
+                        "distinct UMIs: under 1,024 a dense numpy compare on "
+                        "the host, from 1,024 up to this threshold the "
+                        "device Hamming kernel, from the threshold on the "
+                        "native index on the host")
     p.add_argument("--parallel-group-min-templates", default=None,
                    metavar="N|auto",
                    help="accepted for compatibility: this engine "

@@ -24,6 +24,7 @@ from ..io.bam import (FLAG_FIRST, FLAG_LAST, FLAG_MATE_UNMAPPED, FLAG_PAIRED,
                       FLAG_QC_FAIL, FLAG_REVERSE, FLAG_SECONDARY,
                       FLAG_SUPPLEMENTARY, FLAG_UNMAPPED)
 from ..native import batch as nb
+from ..observe.metrics import METRICS
 from ..observe.trace import spanned
 from .group import (FilterMetrics, append_mi_tag, assign_group, extract_umi,
                     filter_template, pair_orientation)
@@ -81,6 +82,9 @@ class FastGrouper:
         self.family_sizes = {}
         self.position_group_sizes = {}
         self.records_out = 0
+        # run-report counters, folded into METRICS once, by flush()
+        self._counts = {"position_groups": 0, "subgroups": 0, "templates": 0}
+        self._ids_folded = 0
         self._carry = []        # python Templates of the open position group
         self._carry_key = None  # their read_info_key
         self._tail = None       # the held-back, possibly-split last template
@@ -108,6 +112,9 @@ class FastGrouper:
         m.accepted += sum(len(t.primary_records()) for t in kept)
         assign_group(kept, self.assigner, self.umi_tag, self.min_umi_length,
                      self.no_umi)
+        self._count_group(len(kept),
+                          len({pair_orientation(t) for t in kept})
+                          if self.assigner.split_by_orientation() else 1)
         self._tally(kept)
         out = bytearray()
         for t in kept:
@@ -224,7 +231,26 @@ class FastGrouper:
         """End of stream: resolve the held template and close the open group."""
         out = self._resolve_tail()
         out.extend(self._flush_carry())
+        self._fold_counts()
         return out
+
+    def _count_group(self, templates, subgroups):
+        c = self._counts
+        c["position_groups"] += 1
+        c["subgroups"] += subgroups
+        c["templates"] += templates
+
+    def _fold_counts(self):
+        """The stream's tallies into the metrics registry, once (a counter a
+        position group would cost a capture panel's million groups a
+        second)."""
+        for key, n in self._counts.items():
+            METRICS.inc("group." + key, n)
+        tally = self.assigner.counter
+        METRICS.inc("group.molecules", tally.value - self._ids_folded)
+        METRICS.inc("group.unique_umis", tally.uniques)
+        self._counts = dict.fromkeys(self._counts, 0)
+        self._ids_folded, tally.uniques = tally.value, 0
 
     # ----------------------------------------------------------------- driver
 
@@ -740,6 +766,7 @@ class FastGrouper:
         strings; returns MoleculeIds in entry order."""
         assigner = self.assigner
         if not assigner.split_by_orientation():
+            self._count_group(len(umis), 1)
             return assigner.assign(self._truncate(umis))
         # okeys are (r1_positive, r2_positive) bool pairs over (possibly)
         # hundreds of thousands of templates: one numpy unique+argsort beats
@@ -749,6 +776,7 @@ class FastGrouper:
         ok_arr = np.asarray(okeys, dtype=bool)
         inv_raw = (ok_arr[:, 0].astype(np.int8) << 1) | ok_arr[:, 1]
         uniq_ok, inv_ok = np.unique(inv_raw, return_inverse=True)
+        self._count_group(len(umis), len(uniq_ok))
         mids = [None] * len(umis)
         if len(uniq_ok) == 1:
             sub = umis if self.no_umi else self._truncate(umis)

@@ -17,10 +17,21 @@ distinct (uppercased) invalid string gets its own Single id
 (assign_with_invalid_fallback, assigner.rs:692-707).
 
 The all-pairs Hamming distance work — the hot part for large position groups — is
-vectorized over byte matrices; groups above ``DEVICE_THRESHOLD`` unique UMIs compute
-the candidate-distance matrix as an XLA kernel on the accelerator (XOR/compare +
-popcount-style reduction), the "brute-force-on-accelerator" design SURVEY.md §7
-replaces the reference's BK-tree/N-gram indexes with.
+vectorized over byte matrices and takes one of three routes by the group's unique
+UMIs (``build_neighbor_graph``): under ``DEVICE_THRESHOLD`` (1,024) a dense numpy
+compare on the host; from there to ``SPARSE_THRESHOLD`` (8,192, ``--index-threshold``)
+the candidate-distance matrix as an XLA kernel on the accelerator (one-hot bf16
+einsum on the MXU), the "brute-force-on-accelerator" design SURVEY.md §7 replaces
+the reference's BK-tree/N-gram indexes with; from ``SPARSE_THRESHOLD`` on the native
+pigeonhole candidate pass on the host, where a dense matrix is O(U^2) bytes over
+the link.
+
+Spans and counters (live under ``--trace`` / ``--run-report``; children of
+``group.assign``): ``group.assign.umis``, ``group.assign.graph`` (attribute
+``route``; on the device route ``group.hamming.upload``, ``group.hamming.dispatch``
+and ``device.fetch`` below it), ``group.assign.threshold``, ``group.assign.bfs``,
+``group.assign.ids``; counters ``group.graph.<route>``, ``group.hamming.*``,
+``group.neighbor_pairs``, ``group.unique_umis`` (docs/observability.md).
 """
 
 from collections import deque
@@ -28,9 +39,11 @@ from dataclasses import dataclass
 
 import numpy as np
 
-# Unique-UMI count above which the pairwise distance matrix moves to the device.
-DEVICE_THRESHOLD = 1024
+from ..observe.metrics import METRICS
+from ..observe.trace import NULL_SPAN, span, tracing_enabled
 
+# Unique-UMI count from which the pairwise distance matrix moves to the device.
+DEVICE_THRESHOLD = 1024
 
 
 @dataclass(frozen=True)
@@ -119,6 +132,18 @@ def set_index_threshold(n):
     global SPARSE_THRESHOLD
     SPARSE_THRESHOLD = (8192 if n is None
                         else (1 << 62) if int(n) == 0 else int(n))
+# A sub-group's child spans of ``group.assign`` open from this many UMIs on
+# (strings where a span times strings, uniques where it times uniques): under
+# it an assign is tens of microseconds, which seven spans and their
+# ``getrusage`` pairs would more than double on an input of a million small
+# position groups; ``group.assign``'s own record has their time.
+_SPAN_MIN_UMIS = 64
+
+
+def _sized_span(n, name, **span_kw):
+    return span(name, **span_kw) if n >= _SPAN_MIN_UMIS else NULL_SPAN
+
+
 # unique-UMI count above which the directed BFS runs natively
 # (fgumi_adjacency_bfs); tests force the Python loop by raising this
 _NATIVE_BFS_THRESHOLD = 512
@@ -144,12 +169,13 @@ class NeighborGraph:
     def flat(self):
         """(nbr_flat, nbr_start) arrays for the native BFS: neighbors of i
         are nbr_flat[nbr_start[i]:nbr_start[i+1]], ascending."""
-        lists = (self._lists if self._lists is not None
-                 else [self.neighbors(i) for i in range(self.n)])
-        starts = np.zeros(self.n + 1, dtype=np.int64)
-        np.cumsum([len(x) for x in lists], out=starts[1:])
-        flat = (np.concatenate(lists).astype(np.int64)
-                if self.n else np.empty(0, np.int64))
+        with _sized_span(self.n, "group.assign.threshold", rusage=True):
+            lists = (self._lists if self._lists is not None
+                     else [self.neighbors(i) for i in range(self.n)])
+            starts = np.zeros(self.n + 1, dtype=np.int64)
+            np.cumsum([len(x) for x in lists], out=starts[1:])
+            flat = (np.concatenate(lists).astype(np.int64)
+                    if self.n else np.empty(0, np.int64))
         return flat, starts
 
 
@@ -162,22 +188,44 @@ def build_neighbor_graph(mat: np.ndarray, max_mismatches: int,
     # pigeonhole completeness needs d+1 disjoint chunks: with d+1 > L a pair
     # can differ everywhere yet still be within distance d, so stay dense
     if n < SPARSE_THRESHOLD or max_mismatches + 1 > mat.shape[1]:
-        within = pairwise_distances(mat) <= max_mismatches
-        if rev_mat is not None:
-            within |= pairwise_distances(rev_mat, mat) <= max_mismatches
+        route = "device" if n >= DEVICE_THRESHOLD else "dense_host"
+        if n > 1:  # the routes sum to the sub-groups of two or more uniques
+            METRICS.inc("group.graph." + route)
+        within = None
+        passes = [(mat, None)] + ([(rev_mat, mat)] if rev_mat is not None
+                                  else [])
+        for a, b in passes:
+            with _sized_span(n, "group.assign.graph",
+                             rusage=route != "device", route=route,
+                             uniques=n):
+                dist = pairwise_distances(a, b)
+            with _sized_span(n, "group.assign.threshold", rusage=True):
+                if within is None:
+                    within = dist <= max_mismatches
+                else:
+                    within |= dist <= max_mismatches
+            del dist  # 2 bytes a padded pair: one matrix at a time
+        if tracing_enabled():  # a pass over n x n: only when it is read
+            METRICS.inc("group.neighbor_pairs",
+                        (int(np.count_nonzero(within)) - n) // 2)
         return NeighborGraph(n, within=within)
     from ..native import batch as nb
 
-    if nb.available():
-        pair_sets = [nb.umi_neighbor_pairs(mat, None, max_mismatches)]
+    METRICS.inc("group.graph.sparse_native")
+    with span("group.assign.graph", rusage=True, route="sparse_native",
+              uniques=n):
+        pairs = nb.umi_neighbor_pairs if nb.available() else None
+        pair_sets = [pairs(mat, None, max_mismatches) if pairs
+                     else _pigeonhole_pairs(mat, mat, max_mismatches)]
         if rev_mat is not None:
             pair_sets.append(
-                nb.umi_neighbor_pairs(rev_mat, mat, max_mismatches))
-        return _lists_from_pairs(n, pair_sets)
-    pair_sets = [_pigeonhole_pairs(mat, mat, max_mismatches)]
-    if rev_mat is not None:
-        pair_sets.append(_pigeonhole_pairs(rev_mat, mat, max_mismatches))
-    return _lists_from_pairs(n, pair_sets)
+                pairs(rev_mat, mat, max_mismatches) if pairs
+                else _pigeonhole_pairs(rev_mat, mat, max_mismatches))
+    with span("group.assign.threshold", rusage=True):
+        graph = _lists_from_pairs(n, pair_sets)
+        METRICS.inc("group.neighbor_pairs",
+                    sum(map(len, graph._lists)) // 2)
+    return graph
 
 
 def _pigeonhole_pairs(A: np.ndarray, B: np.ndarray, d: int):
@@ -334,7 +382,17 @@ def _device_pairwise(mat_a: np.ndarray, mat_b: np.ndarray) -> np.ndarray:
     pad_b = _pow2_pad_rows(mat_b)
     DEVICE_STATS.add_dispatch(2 * pad_a.shape[0] * pad_b.shape[0]
                               * pad_a.shape[1] * 8)  # one-hot matmul (K=8)
-    full = DEVICE_STATS.fetch(dist(jnp.asarray(pad_a), jnp.asarray(pad_b)))
+    with span("group.hamming.upload", rows=pad_a.shape[0] + pad_b.shape[0]):
+        dev_a, dev_b = jnp.asarray(pad_a), jnp.asarray(pad_b)
+    with span("group.hamming.dispatch"):
+        dev_dist = dist(dev_a, dev_b)
+    full = DEVICE_STATS.fetch(dev_dist)
+    METRICS.inc("group.hamming.dispatches")
+    METRICS.inc("group.hamming.rows", n + m)
+    METRICS.inc("group.hamming.cells", n * m)
+    METRICS.inc("group.hamming.cells_padded",
+                pad_a.shape[0] * pad_b.shape[0])
+    METRICS.inc("group.hamming.bytes_fetched", full.nbytes)
     return full[:n, :m]
 
 
@@ -349,10 +407,15 @@ def _assert_uniform_length(lengths) -> None:
 
 
 class _Counter:
-    __slots__ = ("value",)
+    """An assigner's running tallies: ``value`` the next molecule id (so the
+    ids minted so far), ``uniques`` the valid distinct UMIs it has seen,
+    summed over sub-groups (run-report counter ``group.unique_umis``)."""
+
+    __slots__ = ("value", "uniques")
 
     def __init__(self):
         self.value = 0
+        self.uniques = 0
 
     def next_id(self) -> int:
         v = self.value
@@ -409,6 +472,7 @@ class SimpleErrorUmiAssigner:
         valid = sorted({u for u in set(upper) if _is_encodable(u)})
         _assert_uniform_length(len(u) for u in valid)
         umi_to_id = {}
+        self.counter.uniques += len(valid)
         if valid:
             mat = _umi_matrix(valid)
             graph = build_neighbor_graph(mat, self.max_mismatches)
@@ -511,32 +575,42 @@ class AdjacencyUmiAssigner:
         Returns a list of MoleculeIds aligned with `unique`; id minting
         order (roots in BFS-root order) is the shared contract of both the
         scalar and vectorized assign paths."""
+        self.counter.uniques += len(unique)
         if len(unique) == 1:
             return [MoleculeId("S", self.counter.next_id())]
-        mat = _umi_matrix(unique)
+        n = len(unique)
+        with _sized_span(n, "group.assign.umis", rusage=True):
+            mat = _umi_matrix(unique)
         graph = build_neighbor_graph(mat, self.max_mismatches)
-        roots, root_of = _adjacency_bfs(unique, counts, graph)
-        root_ids = {r: MoleculeId("S", self.counter.next_id()) for r in roots}
-        return [root_ids[int(root_of[i])] for i in range(len(unique))]
+        with _sized_span(n, "group.assign.bfs", rusage=True):
+            roots, root_of = _adjacency_bfs(unique, counts, graph)
+        with _sized_span(n, "group.assign.ids", rusage=True):
+            root_ids = {r: MoleculeId("S", self.counter.next_id())
+                        for r in roots}
+            return [root_ids[int(root_of[i])] for i in range(len(unique))]
 
     def assign(self, raw_umis):
         if not raw_umis:
             return []
         if len(raw_umis) >= self._VEC_THRESHOLD:
             return self._assign_vectorized(raw_umis)
-        upper = [u.upper() for u in raw_umis]
-        # count first, validate per DISTINCT string: distinct UMIs are a
-        # small fraction of reads in large position groups, and the filtered
-        # list keeps the (-count, umi) order _count_sorted_unique establishes
-        counted = [(u, c) for u, c in _count_sorted_unique(upper)
-                   if _is_encodable(u)]
+        with _sized_span(len(raw_umis), "group.assign.umis", rusage=True):
+            upper = [u.upper() for u in raw_umis]
+            # count first, validate per DISTINCT string: distinct UMIs are a
+            # small fraction of reads in large position groups, and the
+            # filtered list keeps the (-count, umi) order
+            # _count_sorted_unique establishes
+            counted = [(u, c) for u, c in _count_sorted_unique(upper)
+                       if _is_encodable(u)]
         if not counted:
             return _with_invalid_fallback(upper, lambda *_: None, self.counter)
         _assert_uniform_length(len(u) for u, _ in counted)
         unique = [u for u, _ in counted]
         counts = [c for _, c in counted]
         umi_to_id = dict(zip(unique, self._assign_uniques(unique, counts)))
-        return _with_invalid_fallback(upper, lambda _i, u: umi_to_id.get(u), self.counter)
+        with _sized_span(len(raw_umis), "group.assign.ids", rusage=True):
+            return _with_invalid_fallback(
+                upper, lambda _i, u: umi_to_id.get(u), self.counter)
 
     def _assign_vectorized(self, raw_umis):
         """Large-group assign: numpy passes over the input, Python per
@@ -548,28 +622,32 @@ class AdjacencyUmiAssigner:
         - valid molecule ids minted first (BFS-root order), then one id per
           distinct invalid string in first-occurrence input order, exactly
           as _with_invalid_fallback's forward walk mints them."""
-        arr = np.char.upper(np.asarray(raw_umis, dtype=np.str_))
-        uniq, first_idx, inverse, ucounts = np.unique(
-            arr, return_index=True, return_inverse=True, return_counts=True)
-        valid_mask = np.fromiter((_is_encodable(u) for u in uniq),
-                                 bool, len(uniq))
-        mids_u = np.empty(len(uniq), dtype=object)
-        valid_idx = np.nonzero(valid_mask)[0]
+        with span("group.assign.umis", rusage=True):
+            arr = np.char.upper(np.asarray(raw_umis, dtype=np.str_))
+            uniq, first_idx, inverse, ucounts = np.unique(
+                arr, return_index=True, return_inverse=True,
+                return_counts=True)
+            valid_mask = np.fromiter((_is_encodable(u) for u in uniq),
+                                     bool, len(uniq))
+            mids_u = np.empty(len(uniq), dtype=object)
+            valid_idx = np.nonzero(valid_mask)[0]
         if len(valid_idx):
-            order = np.argsort(-ucounts[valid_idx], kind="stable")
-            sorted_idx = valid_idx[order]
-            unique = [str(uniq[i]) for i in sorted_idx]
-            _assert_uniform_length(len(u) for u in unique)
-            counts = ucounts[sorted_idx].tolist()
+            with span("group.assign.umis", rusage=True):
+                order = np.argsort(-ucounts[valid_idx], kind="stable")
+                sorted_idx = valid_idx[order]
+                unique = [str(uniq[i]) for i in sorted_idx]
+                _assert_uniform_length(len(u) for u in unique)
+                counts = ucounts[sorted_idx].tolist()
             for i, mid in zip(sorted_idx,
                               self._assign_uniques(unique, counts)):
                 mids_u[i] = mid
-        invalid_idx = np.nonzero(~valid_mask)[0]
-        if len(invalid_idx):
-            for i in invalid_idx[np.argsort(first_idx[invalid_idx],
-                                            kind="stable")]:
-                mids_u[i] = MoleculeId("S", self.counter.next_id())
-        return list(mids_u[inverse])
+        with span("group.assign.ids", rusage=True):
+            invalid_idx = np.nonzero(~valid_mask)[0]
+            if len(invalid_idx):
+                for i in invalid_idx[np.argsort(first_idx[invalid_idx],
+                                                kind="stable")]:
+                    mids_u[i] = MoleculeId("S", self.counter.next_id())
+            return list(mids_u[inverse])
 
 
 class PairedUmiAssigner:
@@ -631,6 +709,7 @@ class PairedUmiAssigner:
         unique = [u for u, _ in counted]
         counts = [c for _, c in counted]
 
+        self.counter.uniques += len(unique)
         umi_to_id = {}
         if len(unique) == 1:
             mid = self.counter.next_id()

@@ -80,11 +80,18 @@ for full in (True, False):  # the segp2f and segp2 kernels
             f"packed2 full={full}: {name} differs from the host engine"
 umis = np.frombuffer(b"ACGT", dtype=np.uint8)[
     rng.integers(0, 4, size=(2048, 8))]
-dist = assigners.pairwise_distances(umis)
-want = (umis[:, None, :] != umis[None, :, :]).sum(-1)
-assert np.array_equal(np.asarray(dist), want), "device Hamming differs"
+umis[1::2] = umis[::2]  # neighbours to find: every odd row one edit away
+umis[1::2, 3] = ord("A")
+bits = assigners._device_within_bits(umis, umis, 1)
+assert bits.shape == (2048, 256), "the device sends back a bit a pair"
+got = assigners._unpack_within(bits, 2048, 2048)
+want = (umis[:, None, :] != umis[None, :, :]).sum(-1) <= 1
+assert want.sum() > 2 * 2048 and np.array_equal(got, want), \
+    "device Hamming differs"
 snap = DEVICE_STATS.snapshot()
+# the two wire kernels; the Hamming executable is a dispatch, not a wire kernel
 assert snap.get("kernel_xla", 0) == 2 and not snap.get("host_fallbacks")
+assert snap["dispatches"] == 3 and snap["bytes_fetched"] >= bits.nbytes
 print(json.dumps({"device": device_identity(), "stats": snap}))
 """
 

@@ -20,16 +20,18 @@ The all-pairs Hamming distance work â€” the hot part for large position groups â
 vectorized over byte matrices and takes one of three routes by the group's unique
 UMIs (``build_neighbor_graph``): under ``DEVICE_THRESHOLD`` (1,024) a dense numpy
 compare on the host; from there to ``SPARSE_THRESHOLD`` (8,192, ``--index-threshold``)
-the candidate-distance matrix as an XLA kernel on the accelerator (one-hot bf16
-einsum on the MXU), the "brute-force-on-accelerator" design SURVEY.md Â§7 replaces
-the reference's BK-tree/N-gram indexes with; from ``SPARSE_THRESHOLD`` on the native
-pigeonhole candidate pass on the host, where a dense matrix is O(U^2) bytes over
-the link.
+one XLA kernel on the accelerator (``dist``: a one-hot bf16 einsum on the MXU, the
+compare with ``edits`` and ``packbits``), the "brute-force-on-accelerator" design
+SURVEY.md Â§7 replaces the reference's BK-tree/N-gram indexes with, which answers
+what the graph asks, within ``edits`` or not: one bit a padded pair comes back over
+the link, not a distance; from ``SPARSE_THRESHOLD`` on the native pigeonhole
+candidate pass on the host, where even a bit a pair is O(U^2) over the link.
 
 Spans and counters (live under ``--trace`` / ``--run-report``; children of
 ``group.assign``): ``group.assign.umis``, ``group.assign.graph`` (attribute
 ``route``; on the device route ``group.hamming.upload``, ``group.hamming.dispatch``
-and ``device.fetch`` below it), ``group.assign.threshold``, ``group.assign.bfs``,
+and ``device.fetch`` below it), ``group.assign.threshold`` (the host's compare with
+``edits``, or the device's bits unpacked), ``group.assign.bfs``,
 ``group.assign.ids``; counters ``group.graph.<route>``, ``group.hamming.*``,
 ``group.neighbor_pairs``, ``group.unique_umis`` (docs/observability.md).
 """
@@ -42,7 +44,7 @@ import numpy as np
 from ..observe.metrics import METRICS
 from ..observe.trace import NULL_SPAN, span, tracing_enabled
 
-# Unique-UMI count from which the pairwise distance matrix moves to the device.
+# Unique-UMI count from which the all-pairs search moves to the device.
 DEVICE_THRESHOLD = 1024
 
 
@@ -188,23 +190,25 @@ def build_neighbor_graph(mat: np.ndarray, max_mismatches: int,
     # pigeonhole completeness needs d+1 disjoint chunks: with d+1 > L a pair
     # can differ everywhere yet still be within distance d, so stay dense
     if n < SPARSE_THRESHOLD or max_mismatches + 1 > mat.shape[1]:
-        route = "device" if n >= DEVICE_THRESHOLD else "dense_host"
+        on_device = n >= DEVICE_THRESHOLD and _device_encodable(mat)
+        route = "device" if on_device else "dense_host"
         if n > 1:  # the routes sum to the sub-groups of two or more uniques
             METRICS.inc("group.graph." + route)
         within = None
-        passes = [(mat, None)] + ([(rev_mat, mat)] if rev_mat is not None
-                                  else [])
+        passes = [(mat, mat)] + ([(rev_mat, mat)] if rev_mat is not None
+                                 else [])
         for a, b in passes:
-            with _sized_span(n, "group.assign.graph",
-                             rusage=route != "device", route=route,
-                             uniques=n):
-                dist = pairwise_distances(a, b)
+            with _sized_span(n, "group.assign.graph", rusage=not on_device,
+                             route=route, uniques=n):
+                found = (_device_within_bits(a, b, max_mismatches)
+                         if on_device else pairwise_distances(a, b))
             with _sized_span(n, "group.assign.threshold", rusage=True):
+                found = (_unpack_within(found, n, n) if on_device
+                         else found <= max_mismatches)
                 if within is None:
-                    within = dist <= max_mismatches
+                    within = found
                 else:
-                    within |= dist <= max_mismatches
-            del dist  # 2 bytes a padded pair: one matrix at a time
+                    within |= found
         if tracing_enabled():  # a pass over n x n: only when it is read
             METRICS.inc("group.neighbor_pairs",
                         (int(np.count_nonzero(within)) - n) // 2)
@@ -309,17 +313,11 @@ def _lists_from_pairs(n: int, pair_sets) -> NeighborGraph:
 
 
 def pairwise_distances(mat_a: np.ndarray, mat_b: np.ndarray = None) -> np.ndarray:
-    """All-pairs Hamming distances between byte matrices (int16).
-
-    Large inputs run as a one-hot einsum on the accelerator â€” the XLA equivalent
-    of the reference's XOR+popcount BitEnc path (crates/fgumi-dna/src/bitenc.rs:111-124),
-    batched over the whole position group at once.
-    """
+    """All-pairs Hamming distances between byte matrices (int16), on the host:
+    the dense route of small groups, the pigeonhole buckets, and the reference
+    the tests hold the device's bits to."""
     if mat_b is None:
         mat_b = mat_a
-    n, m = mat_a.shape[0], mat_b.shape[0]
-    if max(n, m) >= DEVICE_THRESHOLD:
-        return _device_pairwise(mat_a, mat_b)
     return (mat_a[:, None, :] != mat_b[None, :, :]).sum(axis=2, dtype=np.int16)
 
 
@@ -339,13 +337,33 @@ def _pow2_pad_rows(mat: np.ndarray) -> np.ndarray:
     return np.concatenate([mat, pad])
 
 
+# Every byte a valid UMI puts into ``_umi_matrix``: the bases ``_is_encodable``
+# admits (strings are upper-cased first), the ``-`` between a dual UMI's halves,
+# and the ``:`` and ``B`` of the ``paired`` strategy's orientation prefixes
+# ("aa:ACGT-bb:TTTT", upper-cased). ``_pow2_pad_rows``' byte 0 is not among
+# them, so a pad row's one-hot is all zeros and matches nothing, itself included.
+_ALPHABET = b"ACGT-:B"
+_IN_ALPHABET = np.zeros(256, dtype=bool)
+_IN_ALPHABET[list(_ALPHABET)] = True
+
+
+def _device_encodable(mat: np.ndarray) -> bool:
+    """Whether the device's one-hot sees every byte of ``mat``.
+    ``_is_encodable`` lets any text before a ``:`` through ("XY:ACGT"), and a
+    byte outside ``_ALPHABET`` would equal nothing on the device, so such a
+    group keeps the host's compare: a pass over n x L bytes buys the same
+    graph on every route."""
+    return bool(_IN_ALPHABET[mat].all())
+
+
 _dist_jit = None
 
 
 def _get_dist_jit():
-    """Module-level jitted pairwise kernel: one compile per padded shape for
-    the process lifetime (a per-call jax.jit closure would recompile every
-    call â€” measured at ~0.5s per group)."""
+    """Module-level jitted within-``edits`` kernel: one compile per padded
+    shape for the process lifetime, whatever ``edits`` (a traced scalar; a
+    per-call jax.jit closure would recompile every call â€” measured at ~0.5s
+    per group)."""
     global _dist_jit
     if _dist_jit is None:
         # the one guarded first import of jax (a fused chain's simplex
@@ -357,21 +375,32 @@ def _get_dist_jit():
         jax = _ensure_jax()
         import jax.numpy as jnp
 
+        alphabet = np.frombuffer(_ALPHABET, dtype=np.uint8)
+
+        # the device plane names the executable after this function,
+        # ``jit_dist(...)``: the benchmark's Hamming readers find it by that
         @jax.jit
-        def dist(a, b):
-            # one-hot over the observed byte alphabet -> matmul on the MXU
-            alphabet = jnp.unique(jnp.concatenate([a.ravel(), b.ravel()]),
-                                  size=8, fill_value=0)
+        def dist(a, b, edits):
+            # one-hot over the alphabet -> matmul on the MXU; the count of
+            # matching bases is exact in the f32 accumulator at any length
             oh_a = (a[..., None] == alphabet).astype(jnp.bfloat16)  # (N, L, K)
             oh_b = (b[..., None] == alphabet).astype(jnp.bfloat16)
-            matches = jnp.einsum("nlk,mlk->nm", oh_a, oh_b)
-            return (a.shape[1] - matches).astype(jnp.int16)
+            matches = jnp.einsum("nlk,mlk->nm", oh_a, oh_b,
+                                 preferred_element_type=jnp.float32)
+            # the answer the graph asks for, a bit a pair: (N, ceil(M / 8))
+            return jnp.packbits(a.shape[1] - matches <= edits, axis=1)
 
         _dist_jit = dist
     return _dist_jit
 
 
-def _device_pairwise(mat_a: np.ndarray, mat_b: np.ndarray) -> np.ndarray:
+def _device_within_bits(mat_a: np.ndarray, mat_b: np.ndarray,
+                        edits: int) -> np.ndarray:
+    """hamming(mat_a[i], mat_b[j]) <= edits for every pair of the padded
+    matrices, as the device packs it: ``(n_pad, ceil(m_pad / 8))`` uint8, the
+    high bit first (``_unpack_within`` undoes it). The real ``n x m`` corner is
+    not cut out on the device: every (n, m) would be a shape and a compile,
+    and at a bit a pair the padding is a few MB."""
     dist = _get_dist_jit()
     import jax.numpy as jnp
 
@@ -380,20 +409,26 @@ def _device_pairwise(mat_a: np.ndarray, mat_b: np.ndarray) -> np.ndarray:
     n, m = mat_a.shape[0], mat_b.shape[0]
     pad_a = _pow2_pad_rows(mat_a)
     pad_b = _pow2_pad_rows(mat_b)
-    DEVICE_STATS.add_dispatch(2 * pad_a.shape[0] * pad_b.shape[0]
-                              * pad_a.shape[1] * 8)  # one-hot matmul (K=8)
+    cells_padded = pad_a.shape[0] * pad_b.shape[0]
+    DEVICE_STATS.add_dispatch(2 * cells_padded * pad_a.shape[1]
+                              * len(_ALPHABET))  # the one-hot matmul
     with span("group.hamming.upload", rows=pad_a.shape[0] + pad_b.shape[0]):
         dev_a, dev_b = jnp.asarray(pad_a), jnp.asarray(pad_b)
     with span("group.hamming.dispatch"):
-        dev_dist = dist(dev_a, dev_b)
-    full = DEVICE_STATS.fetch(dev_dist)
+        dev_bits = dist(dev_a, dev_b, np.int32(edits))
+    bits = DEVICE_STATS.fetch(dev_bits)
     METRICS.inc("group.hamming.dispatches")
     METRICS.inc("group.hamming.rows", n + m)
     METRICS.inc("group.hamming.cells", n * m)
-    METRICS.inc("group.hamming.cells_padded",
-                pad_a.shape[0] * pad_b.shape[0])
-    METRICS.inc("group.hamming.bytes_fetched", full.nbytes)
-    return full[:n, :m]
+    METRICS.inc("group.hamming.cells_padded", cells_padded)
+    METRICS.inc("group.hamming.bytes_fetched", bits.nbytes)
+    return bits
+
+
+def _unpack_within(bits: np.ndarray, n: int, m: int) -> np.ndarray:
+    """The real ``(n, m)`` corner of ``_device_within_bits``' answer as the
+    boolean matrix ``NeighborGraph`` takes."""
+    return np.unpackbits(bits[:n], axis=1, count=m).view(np.bool_)
 
 
 def _assert_uniform_length(lengths) -> None:

@@ -698,6 +698,13 @@ def cmd_simplex(args, source=None, sink=None):
                  ocs.bases_disagreeing, ocs.bases_corrected)
     if s.rejected:
         log.info("rejections: %s", dict(sorted(s.rejected.items())))
+    # the caller's account of every input read, in the run report too
+    from .observe.metrics import METRICS
+
+    METRICS.inc("simplex.input_reads", s.input_reads)
+    METRICS.inc("simplex.consensus_reads", n_out)
+    for reason, count in s.rejected.items():
+        METRICS.inc("simplex.rejected." + reason, count)
     kf, kt = caller.kernel.fallback_positions, caller.kernel.total_positions
     if kt:
         log.info("kernel fallback rate: %.4f%% (%d/%d positions)",

@@ -13,6 +13,8 @@ tests/test_fast_simplex.py). Families the vectorized path cannot express
 most-common-alignment filter) fall back to the slow path per group.
 """
 
+import time
+
 import numpy as np
 
 from ..core import cigar as cigar_utils
@@ -20,6 +22,8 @@ from ..io.bam import (FLAG_FIRST, FLAG_LAST, FLAG_MATE_UNMAPPED, FLAG_PAIRED,
                       FLAG_REVERSE, FLAG_SECONDARY, FLAG_SUPPLEMENTARY,
                       FLAG_UNMAPPED)
 from ..native import batch as nb
+from ..observe.metrics import METRICS
+from ..observe.trace import record_interval, tracing_enabled
 from ..observe.trace import span as _span
 from ..ops import oracle
 from .overlapping import (AGREEMENT_CODES, DISAGREEMENT_CODES,
@@ -179,6 +183,20 @@ def _table_from_legacy(entries, span):
     return _JobTable(counts, vlo, rt, cl, mi, pool, span[pool])
 
 
+class _FilterTally:
+    """What the alignment filters of one span's per-group scan did: runs,
+    runs that kept every read, reads in, reads rejected, and (``timed``:
+    while spans are armed) their summed seconds."""
+
+    __slots__ = ("segments", "kept_all", "reads_in", "rejected", "seconds",
+                 "timed")
+
+    def __init__(self, timed):
+        self.segments = self.kept_all = self.reads_in = self.rejected = 0
+        self.seconds = 0.0
+        self.timed = timed
+
+
 class _PendingChunk:
     """Deferred half of a batch: fetch packed device results, recompute
     depth/errors on host, apply thresholds, serialize (SURVEY §7 step 4
@@ -257,6 +275,7 @@ class FastSimplexCaller:
         # conditions the vectorized conversion cannot express
         self._vector_ok = (not opts.trim and not opts.methylation_mode)
         self._carry = None  # (mi_bytes, [RawRecord]) spanning batch boundary
+        self._filter_tally = None  # a _FilterTally while a span is scanned
         self._palin_cache = {}  # cigar bytes -> simplified-CIGAR palindromicity
 
     # ------------------------------------------------------------------ driver
@@ -354,11 +373,28 @@ class FastSimplexCaller:
             from .overlapping import apply_overlapping_consensus
 
             records = apply_overlapping_consensus(records, self.overlap_caller)
-        recs = self.caller.call_groups([(mi_bytes.decode(), records)])
+        METRICS.inc("simplex.groups.carried")
+        recs = self._call_counted([(mi_bytes.decode(), records)])
         if not recs:
             return []
         return self._post_slow(
             [b"".join(len(r).to_bytes(4, "little") + r for r in recs)])
+
+    def _call_counted(self, groups):
+        """The per-group caller on whole groups (one a batch boundary cuts,
+        or a mode the vectorized path cannot express), counted like the
+        groups of ``_prepare_groups_vec`` so that the run report's
+        ``simplex.reads`` is every input read and ``.filter.reads_rejected``
+        every ``MinorityAlignment`` reject."""
+        stats = self.caller.stats
+        before = stats.rejected.get("MinorityAlignment", 0)
+        recs = self.caller.call_groups(groups)
+        METRICS.inc("simplex.groups", len(groups))
+        METRICS.inc("simplex.reads", sum(len(r) for _mi, r in groups))
+        rejected = stats.rejected.get("MinorityAlignment", 0) - before
+        if rejected:
+            METRICS.inc("simplex.filter.reads_rejected", rejected)
+        return recs
 
     def _post_slow(self, chunks):
         """Fused-filter pass over slow-path record blobs (already-serialized
@@ -386,7 +422,7 @@ class FastSimplexCaller:
                 members = idx[bounds[g]:bounds[g + 1]]
                 mi = batch.tag_bytes(self.tag, int(members[0]))
                 groups.append((mi.decode(), batch.raw_records(members)))
-            recs = caller.call_groups(groups)
+            recs = self._call_counted(groups)
             if not recs:
                 return []
             return self._post_slow(
@@ -449,6 +485,9 @@ class FastSimplexCaller:
         # downsampling or the most-common-alignment filter
         if caller.track_rejects:
             legacy = []
+            self._count_groups(np.diff(rel_bounds[g0:g1 + 1]),
+                               (("rejects", np.ones(g1 - g0, dtype=bool)),))
+            self._filter_tally = _FilterTally(timed=False)
             for g in range(g0, g1):
                 s, e = rel_bounds[g], rel_bounds[g + 1]
                 jobs_g = []
@@ -457,6 +496,7 @@ class FastSimplexCaller:
                                          bool(group_uniform[g - g0]))
                 legacy.extend(((g - g0) * 3 + i, int(s), jg)
                               for i, jg in enumerate(jobs_g))
+            self._fold_filter_tally()
             table = _table_from_legacy(legacy, span)
         else:
             # rel_bounds is already span-relative (rel_bounds[g0] == 0)
@@ -495,6 +535,7 @@ class FastSimplexCaller:
         row_ok = (~small & ~downs)[g_of_row] & (rtype >= 0)
         er = np.nonzero(row_ok)[0]
         legacy_g = downs.copy()
+        cigar_g = np.zeros(nG, dtype=bool)  # ... for the alignment filter
         nseg = 0
         if len(er):
             key = g_of_row[er] * 4 + rtype[er]
@@ -551,6 +592,7 @@ class FastSimplexCaller:
                         row_sm.astype(np.uint8),
                         vstarts[:-1][nonempty]).astype(bool)
                     need &= ~seg_sm
+                cigar_g[seg_g[need]] = True
             rev8 = ((batch.flag[span_v] & FLAG_REVERSE) != 0).astype(np.uint8)
             mixed = np.zeros(nseg, dtype=bool)
             if nonempty.any():
@@ -621,14 +663,26 @@ class FastSimplexCaller:
         # legacy groups (downsample / alignment-filter / strand cases): the
         # per-group scan, collected as (order-key, group-start, job-tuple)
         legacy = []
-        for g in np.nonzero(legacy_g)[0]:
-            jobs_g = []
-            self._prepare_group_fast(batch, span, gb[g], gb[g + 1], rtype,
-                                     final_len, jobs_g,
-                                     bool(group_uniform[g]),
-                                     ordinal=ord0 + int(g))
-            legacy.extend((int(g) * 3 + i, int(gb[g]), jg)
-                          for i, jg in enumerate(jobs_g))
+        legacy_ids = np.nonzero(legacy_g)[0]
+        self._count_groups(sizes, (
+            ("downsample", downs), ("cigar", cigar_g & ~downs),
+            ("strand", legacy_g & ~downs & ~cigar_g))
+            if len(legacy_ids) else ())
+        if len(legacy_ids):
+            # one span a call, never one a group; the filter's seconds are
+            # summed over the loop and recorded once (docs/observability.md)
+            self._filter_tally = _FilterTally(timed=tracing_enabled())
+            with _span("process.prep.legacy", rusage=True,
+                       groups=len(legacy_ids)):
+                for g in legacy_ids:
+                    jobs_g = []
+                    self._prepare_group_fast(batch, span, gb[g], gb[g + 1],
+                                             rtype, final_len, jobs_g,
+                                             bool(group_uniform[g]),
+                                             ordinal=ord0 + int(g))
+                    legacy.extend((int(g) * 3 + i, int(gb[g]), jg)
+                                  for i, jg in enumerate(jobs_g))
+                self._fold_filter_tally()
 
         # vectorized emission: seg_map columns are already in output order
         # (fragment, R1, R2 per group; vanilla.py:377-386), so the row-major
@@ -679,6 +733,37 @@ class FastSimplexCaller:
             np.concatenate((mi_v, mi_l))[order],
             np.concatenate((vrows, aux)),
             np.concatenate((span_v, span[aux])))
+
+    @staticmethod
+    def _count_groups(sizes, legacy):
+        """Run-report counters of one span's groups: how many, and by
+        ``legacy``'s disjoint ``(why, mask)`` pairs how many left the
+        whole-array path for the per-group scan (a downsample, differing
+        CIGARs, mixed strands over a non-palindromic CIGAR; under
+        ``--rejects`` every group)."""
+        METRICS.inc("simplex.groups", len(sizes))
+        METRICS.inc("simplex.reads", int(sizes.sum()))
+        for why, sel in legacy:
+            if sel.any():
+                METRICS.inc("simplex.groups.legacy", int(sel.sum()))
+                METRICS.inc("simplex.groups.legacy." + why, int(sel.sum()))
+                METRICS.inc("simplex.reads.legacy", int(sizes[sel].sum()))
+
+    def _fold_filter_tally(self):
+        """Fold the span's ``_FilterTally`` in, once a span: counters, and
+        the summed seconds as one ``process.prep.align_filter`` record
+        ending now."""
+        tally, self._filter_tally = self._filter_tally, None
+        if not tally.segments:
+            return
+        METRICS.inc("simplex.filter.segments", tally.segments)
+        METRICS.inc("simplex.filter.segments_kept_all", tally.kept_all)
+        METRICS.inc("simplex.filter.reads_in", tally.reads_in)
+        METRICS.inc("simplex.filter.reads_rejected", tally.rejected)
+        if tally.timed:
+            now = time.monotonic()
+            record_interval("process.prep.align_filter", now - tally.seconds,
+                            now, segments=tally.segments)
 
     def _prepare_group_fast(self, batch, span, s, e, rtype, final_len, jobs,
                             group_uniform=False, ordinal=None):
@@ -762,8 +847,18 @@ class FastSimplexCaller:
                         self._decode_cigar(batch, int(span[t_rows[0]])))
                     need_filter = cig != list(reversed(cig))
             if need_filter:
+                tally = self._filter_tally
+                timed = tally is not None and tally.timed
+                t0 = time.monotonic() if timed else 0.0
                 keep_rows = self._alignment_filter(batch, span, t_rows, lens)
                 rejected = len(t_rows) - len(keep_rows)
+                if tally is not None:
+                    tally.segments += 1
+                    tally.kept_all += not rejected
+                    tally.reads_in += len(t_rows)
+                    tally.rejected += rejected
+                    if timed:
+                        tally.seconds += time.monotonic() - t0
                 if rejected:
                     stats.reject("MinorityAlignment", rejected)
                     keep_set = set(keep_rows.tolist())

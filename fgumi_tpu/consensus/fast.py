@@ -8,12 +8,13 @@ index slices, and the likelihood loop on the device kernel.
 
 Semantics contract: byte-identical output and identical rejection statistics
 to VanillaConsensusCaller.call_groups on the same stream (tested in
-tests/test_fast_simplex.py). Families the vectorized path cannot express
-(methylation mode, quality trimming, non-uniform CIGARs needing the
-most-common-alignment filter) fall back to the slow path per group.
+tests/test_fast_simplex.py). Families whose CIGARs need the
+most-common-alignment filter stay on the vectorized path: the filter is one
+native pass a span (nb.alignment_filter) whose answer is a keep mask. What
+the vectorized path cannot express (methylation mode, quality trimming) falls
+back to the slow path per group; a seeded downsample and rejects tracking
+take the per-group scan.
 """
-
-import time
 
 import numpy as np
 
@@ -23,7 +24,6 @@ from ..io.bam import (FLAG_FIRST, FLAG_LAST, FLAG_MATE_UNMAPPED, FLAG_PAIRED,
                       FLAG_UNMAPPED)
 from ..native import batch as nb
 from ..observe.metrics import METRICS
-from ..observe.trace import record_interval, tracing_enabled
 from ..observe.trace import span as _span
 from ..ops import oracle
 from .overlapping import (AGREEMENT_CODES, DISAGREEMENT_CODES,
@@ -184,17 +184,16 @@ def _table_from_legacy(entries, span):
 
 
 class _FilterTally:
-    """What the alignment filters of one span's per-group scan did: runs,
-    runs that kept every read, reads in, reads rejected, and (``timed``:
-    while spans are armed) their summed seconds."""
+    """What the most-common-alignment filter did in one span: segments it
+    ran on, segments that kept every read, reads in, reads rejected, and
+    the groups (with their reads) that had such a segment."""
 
-    __slots__ = ("segments", "kept_all", "reads_in", "rejected", "seconds",
-                 "timed")
+    __slots__ = ("segments", "kept_all", "reads_in", "rejected", "groups",
+                 "group_reads")
 
-    def __init__(self, timed):
+    def __init__(self):
         self.segments = self.kept_all = self.reads_in = self.rejected = 0
-        self.seconds = 0.0
-        self.timed = timed
+        self.groups = self.group_reads = 0
 
 
 class _PendingChunk:
@@ -482,12 +481,12 @@ class FastSimplexCaller:
 
         # per-group preparation: vectorized common path; the per-group Python
         # scan remains for rejects-tracking mode and for groups needing
-        # downsampling or the most-common-alignment filter
+        # downsampling
         if caller.track_rejects:
             legacy = []
-            self._count_groups(np.diff(rel_bounds[g0:g1 + 1]),
-                               (("rejects", np.ones(g1 - g0, dtype=bool)),))
-            self._filter_tally = _FilterTally(timed=False)
+            self._count_groups(np.diff(rel_bounds[g0:g1 + 1]), "rejects",
+                               np.ones(g1 - g0, dtype=bool))
+            self._filter_tally = _FilterTally()
             for g in range(g0, g1):
                 s, e = rel_bounds[g], rel_bounds[g + 1]
                 jobs_g = []
@@ -510,10 +509,12 @@ class FastSimplexCaller:
         """Vectorized _prepare_group_fast over all groups of the span.
 
         gb: (nG+1,) span-relative group boundaries. Groups that need the
-        seeded downsample or the most-common-alignment filter fall back to
-        the per-group scan (identical semantics); everything else — type
-        subgrouping, min-reads/zero-length rejection, consensus length,
-        orphan handling — happens in whole-span array passes.
+        seeded downsample fall back to the per-group scan (identical
+        semantics); everything else — type subgrouping, min-reads/
+        zero-length rejection, the most-common-alignment filter (one native
+        pass over every segment that needs it, answered as a keep mask),
+        consensus length, orphan handling — happens in whole-span array
+        passes.
 
         Returns a _JobTable (jobs in output order, arrays only).
         """
@@ -534,8 +535,7 @@ class FastSimplexCaller:
         g_of_row = np.repeat(np.arange(nG), sizes)
         row_ok = (~small & ~downs)[g_of_row] & (rtype >= 0)
         er = np.nonzero(row_ok)[0]
-        legacy_g = downs.copy()
-        cigar_g = np.zeros(nG, dtype=bool)  # ... for the alignment filter
+        tally = self._filter_tally = _FilterTally()
         nseg = 0
         if len(er):
             key = g_of_row[er] * 4 + rtype[er]
@@ -551,7 +551,8 @@ class FastSimplexCaller:
             c0 = np.bincount(seg_of_row, minlength=nseg)
 
             valid_row = final_len[srows] > 0
-            c1 = np.bincount(seg_of_row[valid_row], minlength=nseg)
+            seg_v = seg_of_row[valid_row]  # seg of each compacted valid row
+            c1 = np.bincount(seg_v, minlength=nseg)
             alive0 = c0 >= min_reads
             alive = alive0 & (c1 >= min_reads)
 
@@ -569,8 +570,8 @@ class FastSimplexCaller:
             first_valid = np.zeros(nseg, dtype=np.int64)
             first_valid[nonempty] = vrows[vstarts[:-1][nonempty]]
             check = alive & ~guniform_seg
+            co = batch.cigar_off
             if check.any():
-                co = batch.cigar_off
                 cl = (4 * batch.n_cigar).astype(np.int32)
                 rep_first = np.repeat(span[first_valid], c1)
                 eq = nb.ranges_equal(batch.buf, co[span_v], cl[span_v],
@@ -583,8 +584,8 @@ class FastSimplexCaller:
                     # all-single-op-M segs (ragged read lengths, e.g. 80M vs
                     # 100M) are mutually prefix-compatible after simplify:
                     # the most-common-alignment filter provably keeps every
-                    # read, so skip it (the dominant cost on length-jittered
-                    # inputs — one Python CIGAR decode per read otherwise)
+                    # read, so they never reach it (the common case on
+                    # length-jittered inputs)
                     row_sm = (batch.n_cigar[span_v] == 1) \
                         & ((batch.buf[co[span_v]] & 0xF) == 0)
                     seg_sm = np.zeros(nseg, dtype=bool)
@@ -592,7 +593,6 @@ class FastSimplexCaller:
                         row_sm.astype(np.uint8),
                         vstarts[:-1][nonempty]).astype(bool)
                     need &= ~seg_sm
-                cigar_g[seg_g[need]] = True
             rev8 = ((batch.flag[span_v] & FLAG_REVERSE) != 0).astype(np.uint8)
             mixed = np.zeros(nseg, dtype=bool)
             if nonempty.any():
@@ -619,9 +619,42 @@ class FastSimplexCaller:
                         self._palin_cache[cig_bytes] = palin
                     if not palin:
                         need[s] = True
-            legacy_g[seg_g[need]] = True
 
-        vec_g = ~legacy_g
+            c1_in, alive_in = c1, alive  # as the filter finds them
+            n_minor = 0
+            if need.any():
+                # the filter over every such segment's valid rows at once;
+                # a rejected row leaves the compacted arrays, and what hangs
+                # off them is made again before anything reads them
+                c1_need = c1[need]
+                at = _ranges(vstarts[:-1][need], c1_need)
+                rows_f = span_v[at]
+                cig_f, ncig_f = co[rows_f], batch.n_cigar[rows_f]
+                rev_f, len_f = rev8[at], vlens[at]
+                starts_f = np.concatenate(([0], np.cumsum(c1_need)))
+                with _span("process.prep.align_filter", rusage=True,
+                           segments=len(c1_need)):
+                    keep = nb.alignment_filter(batch.buf, cig_f, ncig_f,
+                                               rev_f, len_f, starts_f)
+                drop = at[keep == 0]
+                n_minor = len(drop)
+                filtered_g = np.unique(seg_g[need])
+                tally.segments += len(c1_need)
+                tally.reads_in += int(c1_need.sum())
+                tally.rejected += n_minor
+                tally.kept_all += len(c1_need) - len(np.unique(seg_v[drop]))
+                tally.groups += len(filtered_g)
+                tally.group_reads += int(sizes[filtered_g].sum())
+                if n_minor:
+                    kept = np.ones(len(vrows), dtype=bool)
+                    kept[drop] = False
+                    vrows, span_v = vrows[kept], span_v[kept]
+                    vlens, seg_v = vlens[kept], seg_v[kept]
+                    c1 = np.bincount(seg_v, minlength=nseg)
+                    vstarts = np.concatenate(([0], np.cumsum(c1)))
+                    alive = alive_in & (c1 >= min_reads)
+
+        vec_g = ~downs
         stats.input_reads += int(sizes[vec_g].sum())
         n_small = int(sizes[small & vec_g].sum())
         if n_small:
@@ -629,28 +662,31 @@ class FastSimplexCaller:
 
         seg_map = None
         if nseg:
-            seg_vec = vec_g[seg_g]
-            dead0 = seg_vec & ~alive0
-            if dead0.any():
-                stats.reject("InsufficientReads", int(c0[dead0].sum()))
-            zl = int((c0 - c1)[seg_vec & alive0].sum())
+            # the tallies of _prepare_group_fast, a segment's steps in its
+            # order: too few reads, zero length, too few left, the filter's
+            # minority, too few left after it (it never takes a segment's
+            # last read)
+            few = int(c0[~alive0].sum()) \
+                + int(c1_in[alive0 & ~alive_in].sum()) \
+                + int(c1[alive_in & ~alive].sum())
+            if few:
+                stats.reject("InsufficientReads", few)
+            zl = int((c0 - c1_in)[alive0].sum())
             if zl:
                 stats.reject("ZeroLengthAfterTrimming", zl)
-            dead1 = seg_vec & alive0 & ~alive & (c1 > 0)
-            if dead1.any():
-                stats.reject("InsufficientReads", int(c1[dead1].sum()))
+            if n_minor:
+                stats.reject("MinorityAlignment", n_minor)
 
             # consensus length: min_reads-th longest valid len per seg
-            ord2 = np.lexsort((-vlens.astype(np.int64), seg_of_row[valid_row]))
+            ord2 = np.lexsort((-vlens.astype(np.int64), seg_v))
             lens_sorted = vlens[ord2]
             pick = np.minimum(vstarts[:-1] + (min_reads - 1),
                               np.maximum(len(lens_sorted) - 1, 0))
             cons_len = (lens_sorted[pick] if len(lens_sorted)
                         else np.zeros(nseg, dtype=vlens.dtype))
 
-            live = alive & seg_vec
             seg_map = np.full((nG, 3), -1, dtype=np.int64)
-            seg_map[seg_g[live], seg_t[live]] = np.nonzero(live)[0]
+            seg_map[seg_g[alive], seg_t[alive]] = np.nonzero(alive)[0]
             # orphan R1/R2 rejection, aggregated (vanilla.py:346-357)
             have_r1 = seg_map[:, R1] >= 0
             have_r2 = seg_map[:, R2] >= 0
@@ -660,18 +696,14 @@ class FastSimplexCaller:
             if n_orphan:
                 stats.reject("OrphanConsensus", n_orphan)
 
-        # legacy groups (downsample / alignment-filter / strand cases): the
-        # per-group scan, collected as (order-key, group-start, job-tuple)
+        # downsampled groups: the per-group scan (it needs the group's own
+        # seeded permutation), collected as (order-key, group-start,
+        # job-tuple)
         legacy = []
-        legacy_ids = np.nonzero(legacy_g)[0]
-        self._count_groups(sizes, (
-            ("downsample", downs), ("cigar", cigar_g & ~downs),
-            ("strand", legacy_g & ~downs & ~cigar_g))
-            if len(legacy_ids) else ())
+        legacy_ids = np.nonzero(downs)[0]
+        self._count_groups(sizes, "downsample", downs)
         if len(legacy_ids):
-            # one span a call, never one a group; the filter's seconds are
-            # summed over the loop and recorded once (docs/observability.md)
-            self._filter_tally = _FilterTally(timed=tracing_enabled())
+            # one span a call, never one a group (docs/observability.md)
             with _span("process.prep.legacy", rusage=True,
                        groups=len(legacy_ids)):
                 for g in legacy_ids:
@@ -682,7 +714,7 @@ class FastSimplexCaller:
                                              ordinal=ord0 + int(g))
                     legacy.extend((int(g) * 3 + i, int(gb[g]), jg)
                                   for i, jg in enumerate(jobs_g))
-                self._fold_filter_tally()
+        self._fold_filter_tally()
 
         # vectorized emission: seg_map columns are already in output order
         # (fragment, R1, R2 per group; vanilla.py:377-386), so the row-major
@@ -735,35 +767,29 @@ class FastSimplexCaller:
             np.concatenate((span_v, span[aux])))
 
     @staticmethod
-    def _count_groups(sizes, legacy):
-        """Run-report counters of one span's groups: how many, and by
-        ``legacy``'s disjoint ``(why, mask)`` pairs how many left the
-        whole-array path for the per-group scan (a downsample, differing
-        CIGARs, mixed strands over a non-palindromic CIGAR; under
-        ``--rejects`` every group)."""
+    def _count_groups(sizes, why, legacy):
+        """Run-report counters of one span's groups: how many, and how many
+        (the mask ``legacy``) left the whole-array path for the per-group
+        scan, and ``why``: a downsample; under ``--rejects`` every group."""
         METRICS.inc("simplex.groups", len(sizes))
         METRICS.inc("simplex.reads", int(sizes.sum()))
-        for why, sel in legacy:
-            if sel.any():
-                METRICS.inc("simplex.groups.legacy", int(sel.sum()))
-                METRICS.inc("simplex.groups.legacy." + why, int(sel.sum()))
-                METRICS.inc("simplex.reads.legacy", int(sizes[sel].sum()))
+        if legacy.any():
+            METRICS.inc("simplex.groups.legacy", int(legacy.sum()))
+            METRICS.inc("simplex.groups.legacy." + why, int(legacy.sum()))
+            METRICS.inc("simplex.reads.legacy", int(sizes[legacy].sum()))
 
     def _fold_filter_tally(self):
-        """Fold the span's ``_FilterTally`` in, once a span: counters, and
-        the summed seconds as one ``process.prep.align_filter`` record
-        ending now."""
+        """Fold the span's ``_FilterTally`` into the run report's counters,
+        once a span."""
         tally, self._filter_tally = self._filter_tally, None
         if not tally.segments:
             return
+        METRICS.inc("simplex.groups.filtered", tally.groups)
+        METRICS.inc("simplex.reads.filtered", tally.group_reads)
         METRICS.inc("simplex.filter.segments", tally.segments)
         METRICS.inc("simplex.filter.segments_kept_all", tally.kept_all)
         METRICS.inc("simplex.filter.reads_in", tally.reads_in)
         METRICS.inc("simplex.filter.reads_rejected", tally.rejected)
-        if tally.timed:
-            now = time.monotonic()
-            record_interval("process.prep.align_filter", now - tally.seconds,
-                            now, segments=tally.segments)
 
     def _prepare_group_fast(self, batch, span, s, e, rtype, final_len, jobs,
                             group_uniform=False, ordinal=None):
@@ -801,6 +827,7 @@ class FastSimplexCaller:
             rows = rows[perm]  # permuted order, like _downsample
 
         group_jobs = {}
+        filtered = False  # a segment of this group ran the alignment filter
         for read_type in (FRAGMENT, R1, R2):
             t_rows = rows[rtype[rows] == read_type]
             if len(t_rows) == 0:
@@ -847,24 +874,21 @@ class FastSimplexCaller:
                         self._decode_cigar(batch, int(span[t_rows[0]])))
                     need_filter = cig != list(reversed(cig))
             if need_filter:
+                keep = self._alignment_filter(batch, span, t_rows, lens)
+                rejected = len(t_rows) - int(keep.sum())
                 tally = self._filter_tally
-                timed = tally is not None and tally.timed
-                t0 = time.monotonic() if timed else 0.0
-                keep_rows = self._alignment_filter(batch, span, t_rows, lens)
-                rejected = len(t_rows) - len(keep_rows)
-                if tally is not None:
-                    tally.segments += 1
-                    tally.kept_all += not rejected
-                    tally.reads_in += len(t_rows)
-                    tally.rejected += rejected
-                    if timed:
-                        tally.seconds += time.monotonic() - t0
+                if not filtered:
+                    filtered = True
+                    tally.groups += 1
+                    tally.group_reads += int(n_records)
+                tally.segments += 1
+                tally.kept_all += not rejected
+                tally.reads_in += len(t_rows)
+                tally.rejected += rejected
                 if rejected:
                     stats.reject("MinorityAlignment", rejected)
-                    keep_set = set(keep_rows.tolist())
-                    rej(np.array([r for r in t_rows if r not in keep_set],
-                                 dtype=np.int64))
-                t_rows = keep_rows
+                    rej(t_rows[~keep])
+                t_rows = t_rows[keep]
                 lens = final_len[t_rows]
                 if len(t_rows) < opts.min_reads:
                     if len(t_rows):
@@ -888,23 +912,17 @@ class FastSimplexCaller:
             stats.reject("OrphanConsensus", len(r2[1]))
             rej(r2[1])
 
-    def _alignment_filter(self, batch, span, t_rows, lens):
-        """Non-uniform CIGARs: decode + simplify + truncate per read, then the
-        exact fgbio filter (cigar_utils.select_most_common_alignment_group)."""
-        entries = []
-        for local, (row, ln) in enumerate(zip(t_rows, lens)):
-            rec_i = int(span[row])
-            cig = self._decode_cigar(batch, rec_i)
-            simplified = cigar_utils.simplify(cig)
-            if batch.flag[rec_i] & FLAG_REVERSE:
-                simplified = cigar_utils.reverse(simplified)
-            simplified = cigar_utils.truncate_to_query_length(
-                simplified, int(ln))
-            entries.append((local, int(ln), simplified))
-        entries.sort(key=lambda t: -t[1])
-        keep = cigar_utils.select_most_common_alignment_group(entries)
-        keep_set = set(keep)
-        return t_rows[[local in keep_set for local in range(len(t_rows))]]
+    @staticmethod
+    def _alignment_filter(batch, span, t_rows, lens):
+        """The most-common-alignment filter on one (group, read type): the
+        native pass of ``_prepare_groups_vec`` with a single segment, as a
+        keep mask over ``t_rows``."""
+        recs = span[t_rows]
+        keep = nb.alignment_filter(
+            batch.buf, batch.cigar_off[recs], batch.n_cigar[recs],
+            (batch.flag[recs] & FLAG_REVERSE) != 0, lens,
+            np.array([0, len(recs)]))
+        return keep.view(bool)
 
     @staticmethod
     def _decode_cigar(batch, rec_i):

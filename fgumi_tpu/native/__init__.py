@@ -25,7 +25,7 @@ _lock = threading.Lock()
 _lib = None
 _lib_failed = False
 # must equal fgumi_abi_version() in fgumi_native.cc (stale-.so guard)
-_ABI_VERSION = 17
+_ABI_VERSION = 18
 
 
 def build() -> bool:
@@ -116,6 +116,8 @@ def _declare(lib):
                                      ctypes.c_int, p, p, p]
     lib.fgumi_mate_clips.restype = None
     lib.fgumi_mate_clips.argtypes = [p] * 11 + [ctypes.c_long, p]
+    lib.fgumi_alignment_filter.restype = None
+    lib.fgumi_alignment_filter.argtypes = [p] * 6 + [ctypes.c_long, p]
     lib.fgumi_overlap_correct_pairs.restype = None
     lib.fgumi_overlap_correct_pairs.argtypes = [
         p, p, p, ctypes.c_long, ctypes.c_int, ctypes.c_int, p]

@@ -144,6 +144,39 @@ def mate_clips(buf: np.ndarray, cigar_off: np.ndarray, n_cigar: np.ndarray,
     return clip
 
 
+def alignment_filter(buf: np.ndarray, cigar_off: np.ndarray,
+                     n_cigar: np.ndarray, reverse: np.ndarray,
+                     final_len: np.ndarray, seg_starts: np.ndarray):
+    """The most-common-alignment filter over whole segments -> uint8[n] keep.
+
+    Rows are the reads of the segments that need the filter, concatenated
+    segment by segment in their original order; seg_starts (int64[n_seg + 1])
+    bounds them. A row keeps 1 when its read is in its segment's winning
+    compatibility group (core/cigar.py select_most_common_alignment_group on
+    the simplified, strand-oriented, final_len-truncated CIGARs).
+    """
+    lib = get_lib()
+    n = len(cigar_off)
+    keep = np.empty(n, dtype=np.uint8)
+    # converted arrays must stay referenced until the foreign call returns
+    cigar_off = np.ascontiguousarray(cigar_off, np.int64)
+    n_cigar = np.ascontiguousarray(n_cigar, np.int32)
+    reverse = np.ascontiguousarray(reverse, np.uint8)
+    final_len = np.ascontiguousarray(final_len, np.int32)
+    seg_starts = np.ascontiguousarray(seg_starts, np.int64)
+    if not (len(n_cigar) == len(reverse) == len(final_len) == n
+            and len(seg_starts) >= 1 and seg_starts[0] == 0
+            and seg_starts[-1] == n and (np.diff(seg_starts) >= 0).all()):
+        raise ValueError("alignment_filter: seg_starts must run from 0 to "
+                         "the row count without a step back, over arrays "
+                         "of one length")
+    lib.fgumi_alignment_filter(
+        _addr(buf), _addr(cigar_off), _addr(n_cigar), _addr(reverse),
+        _addr(final_len), _addr(seg_starts), len(seg_starts) - 1,
+        _addr(keep))
+    return keep
+
+
 def build_consensus_records(code_addr, qual_addr, depth_addr, err_addr, lens,
                             flags, prefix: bytes, mi_addr, mi_len,
                             rx_addr, rx_len, rg: bytes,

@@ -92,7 +92,7 @@ extern "C" {
 // ABI version for the stale-.so guard in __init__.py: bump whenever any
 // exported signature changes (a symbol probe alone cannot detect an
 // argument-list change in an existing function).
-long fgumi_abi_version() { return 17; }
+long fgumi_abi_version() { return 18; }
 
 // Candidate UMI pairs with hamming(A[i], B[j]) <= d over (n, L)/(m, L) byte
 // matrices, via the d+1-part pigeonhole (umi/assigners.py
@@ -1897,6 +1897,67 @@ int64_t bases_extending_past_mate(const CigarView& c, int32_t flag, int32_t pos,
   return v > 0 ? v : 0;
 }
 
+// The most-common-alignment filter's CIGAR of one read, an element a
+// uint64 `length << 4 | op` (so that comparing two elements compares the
+// length first and the BAM op code second: core/cigar.py compare): the
+// CIGAR words decoded where they lie, simplified (S = X H become M, equal
+// neighbours merged: core/cigar.py simplify), read from its end for a
+// reverse-strand read, then cut to `query_len` query bases with
+// truncate_to_query_length's exact loop (stop at the top once the query is
+// used up; a non-query op before that is kept whole; no re-merge). Appends
+// to `out`, returns the number of elements.
+int32_t filter_cigar(const CigarView& c, bool reverse, int64_t query_len,
+                     std::vector<uint64_t>* out) {
+  const size_t base = out->size();
+  for (int32_t k = 0; k < c.n; ++k) {
+    const int32_t i = reverse ? c.n - 1 - k : k;
+    uint32_t op = c.op(i);
+    if (op == 4 || op == 5 || op == 7 || op == 8) op = 0;  // S H = X -> M
+    const uint64_t len = static_cast<uint64_t>(c.len(i));
+    if (out->size() > base && (out->back() & 0xF) == op) {
+      out->back() += len << 4;
+    } else {
+      out->push_back(len << 4 | op);
+    }
+  }
+  uint64_t remaining = query_len > 0 ? static_cast<uint64_t>(query_len) : 0;
+  size_t k = base;
+  for (; k < out->size() && remaining != 0; ++k) {
+    const uint64_t op = (*out)[k] & 0xF;
+    if (op > 1) continue;  // of a simplified CIGAR only M and I use query
+    const uint64_t len = (*out)[k] >> 4;
+    const uint64_t take = len < remaining ? len : remaining;
+    (*out)[k] = take << 4 | op;
+    remaining -= take;
+  }
+  out->resize(k);
+  return static_cast<int32_t>(k - base);
+}
+
+// core/cigar.py is_prefix: every op equal; interior lengths equal, the last
+// element of `a` may be shorter. An empty `a` prefixes everything.
+inline bool filter_is_prefix(const uint64_t* a, int32_t na, const uint64_t* b,
+                             int32_t nb) {
+  if (na > nb) return false;
+  for (int32_t i = 0; i + 1 < na; ++i) {
+    if (a[i] != b[i]) return false;
+  }
+  if (na == 0) return true;
+  const uint64_t la = a[na - 1], lb = b[na - 1];
+  return (la & 0xF) == (lb & 0xF) && la <= lb;
+}
+
+// core/cigar.py compare < 0 (vanilla_caller.rs:79-111): element by element
+// the length, then the op code; on an equal prefix the shorter is smaller.
+inline bool filter_cigar_less(const uint64_t* a, int32_t na, const uint64_t* b,
+                              int32_t nb) {
+  const int32_t n = na < nb ? na : nb;
+  for (int32_t i = 0; i < n; ++i) {
+    if (a[i] != b[i]) return a[i] < b[i];
+  }
+  return na < nb;
+}
+
 // Size of a fixed-width aux value type, or 0 when variable/unknown.
 inline int64_t tag_fixed_size(uint8_t typ) {
   switch (typ) {
@@ -2111,6 +2172,85 @@ void fgumi_mate_clips(const uint8_t* buf, const int64_t* cigar_off,
     const int64_t mate_pos = static_cast<int64_t>(next_pos[i]) + 1;
     clip[i] = static_cast<int32_t>(bases_extending_past_mate(
         c, flag[i], pos[i], mate_pos - lead, mate_pos - 1 + rlen + trail));
+  }
+}
+
+// The most-common-alignment filter (fgbio filterToMostCommonAlignment,
+// vanilla_caller.rs:50-122; mirrors core/cigar.py
+// select_most_common_alignment_group on each read's filter_cigar) over n_seg
+// segments at once: segment s is rows [seg_starts[s], seg_starts[s + 1]), one
+// (group, read type)'s reads in their original order. keep[row] = 1 for the
+// reads of the winning compatibility group. A segment's reads are taken
+// longest `final_len` first (stable); each joins EVERY group whose CIGAR its
+// own prefixes (fgbio's quirk) or founds one; most reads win, a tie goes to
+// the smaller CIGAR, a full tie to the earlier group. A segment of fewer
+// than two rows keeps its row.
+void fgumi_alignment_filter(const uint8_t* buf, const int64_t* cigar_off,
+                            const int32_t* n_cigar, const uint8_t* reverse,
+                            const int32_t* final_len,
+                            const int64_t* seg_starts, long n_seg,
+                            uint8_t* keep) {
+  struct Group {
+    int32_t founder;  // position in the segment's length order
+    int64_t reads;
+  };
+  std::vector<uint64_t> elems;      // the segment's CIGARs, row after row
+  std::vector<int64_t> lo;          // row -> first element
+  std::vector<int32_t> cnt, order;  // row -> elements; the length order
+  std::vector<Group> groups;
+  for (long s = 0; s < n_seg; ++s) {
+    const int64_t r0 = seg_starts[s];
+    const int32_t n = static_cast<int32_t>(seg_starts[s + 1] - r0);
+    if (n <= 0) continue;
+    std::memset(keep + r0, 1, static_cast<size_t>(n));
+    if (n < 2) continue;
+    elems.clear();
+    lo.resize(n);
+    cnt.resize(n);
+    order.resize(n);
+    for (int32_t i = 0; i < n; ++i) {
+      const int64_t r = r0 + i;
+      lo[i] = static_cast<int64_t>(elems.size());
+      cnt[i] = filter_cigar(CigarView{buf + cigar_off[r], n_cigar[r]},
+                            reverse[r] != 0, final_len[r], &elems);
+      order[i] = i;
+    }
+    const int32_t* flen = final_len + r0;
+    std::stable_sort(order.begin(), order.end(),
+                     [flen](int32_t a, int32_t b) { return flen[a] > flen[b]; });
+    const uint64_t* e = elems.data();
+    groups.clear();
+    for (int32_t p = 0; p < n; ++p) {
+      const int32_t i = order[p];
+      bool found = false;
+      for (Group& g : groups) {
+        const int32_t f = order[g.founder];
+        if (filter_is_prefix(e + lo[i], cnt[i], e + lo[f], cnt[f])) {
+          ++g.reads;
+          found = true;
+        }
+      }
+      if (!found) groups.push_back(Group{p, 1});
+    }
+    if (groups.size() == 1) continue;  // one group: every read is in it
+    const Group* best = &groups[0];
+    for (size_t g = 1; g < groups.size(); ++g) {
+      const int32_t f = order[groups[g].founder], bf = order[best->founder];
+      if (groups[g].reads > best->reads ||
+          (groups[g].reads == best->reads &&
+           filter_cigar_less(e + lo[f], cnt[f], e + lo[bf], cnt[bf]))) {
+        best = &groups[g];
+      }
+    }
+    // the winner's reads: its founder and every later read that prefixes it
+    const int32_t bf = order[best->founder];
+    for (int32_t p = 0; p < n; ++p) {
+      const int32_t i = order[p];
+      keep[r0 + i] =
+          p == best->founder ||
+          (p > best->founder &&
+           filter_is_prefix(e + lo[i], cnt[i], e + lo[bf], cnt[bf]));
+    }
   }
 }
 

@@ -2,8 +2,9 @@
 configuration ``simplex-c2``) on the CPU: the ``simplex`` CLI against the
 benchmark's plain reference with the most-common-alignment filter, byte for
 byte, on inputs of the cell's own layout (soft clips and indels inside the
-families) at the cell's rates, at five times them and at none, and the spans
-and counters of the per-group preparation in its run report.
+families) at the cell's rates, at five times them and at none; the filter's
+native pass over whole segments against the per-group scan it replaced; and
+the spans and counters of both in the run report.
 
 Each (seed, rates, engine) is one CLI run in a process of its own, made once
 and shared by the tests below through ``_run``.
@@ -32,10 +33,16 @@ try:
 finally:
     sys.path.remove(BENCH)
 
+from cigar_segments import cigar_buffer  # noqa: E402
 from fgumi_tpu.consensus import fast  # noqa: E402
+from fgumi_tpu.consensus.vanilla import (VanillaConsensusCaller,  # noqa: E402
+                                         VanillaOptions)
 from fgumi_tpu.core import cigar as program_cigar  # noqa: E402
+from fgumi_tpu.io.bam import BamHeader, BamWriter  # noqa: E402
+from fgumi_tpu.io.batch_reader import BamBatchReader  # noqa: E402
 from fgumi_tpu.native import batch as nb  # noqa: E402
 from fgumi_tpu.observe import trace  # noqa: E402
+from fgumi_tpu.simulate import _build_mapped_record  # noqa: E402
 
 pytestmark = pytest.mark.skipif(not nb.available(),
                                 reason="native library required")
@@ -48,16 +55,20 @@ RATE_KEYS = ("softclip_read_rate", "indel_read_rate", "indel_molecule_rate")
 RATES = {"cell": 1, "five": 5, "none": 0}
 ENGINES = {"default": [], "one-thread": ["--threads", "1"],
            "classic": ["--classic"]}
+#: a run the reference does not follow: groups of over eight records are
+#: downsampled, which is what the per-group scan is left with
+DOWNSAMPLED = {"max-reads": ["--max-reads", "8"]}
 _WORK = tempfile.TemporaryDirectory(prefix="aligned_cell_")
 aligned = traffic.kind_module("aligned_bam")
 
 NEW_SPANS = ("process.prep.legacy", "process.prep.align_filter")
 COUNTERS = ("simplex.groups", "simplex.reads", "simplex.input_reads",
             "simplex.consensus_reads")
-LEGACY = ("simplex.groups.legacy", "simplex.groups.legacy.cigar",
-          "simplex.reads.legacy", "simplex.filter.segments",
-          "simplex.filter.segments_kept_all", "simplex.filter.reads_in",
-          "simplex.filter.reads_rejected")
+FILTER = ("simplex.groups.filtered", "simplex.reads.filtered",
+          "simplex.filter.segments", "simplex.filter.segments_kept_all",
+          "simplex.filter.reads_in", "simplex.filter.reads_rejected")
+LEGACY = ("simplex.groups.legacy", "simplex.groups.legacy.downsample",
+          "simplex.reads.legacy")
 READERS = ("simplex.legacy_group_share", "simplex.legacy_read_share",
            "simplex.legacy_prep_s_per_mread", "simplex.filter_keep_all_share")
 
@@ -96,7 +107,7 @@ def _run(seed, rates, engine):
     argv = [a.format(in0=path, out=out) for a in config["command"]]
     subprocess.run(
         [sys.executable, "-m", "fgumi_tpu", "--run-report", report] + argv
-        + ENGINES[engine], check=True, cwd=_WORK.name,
+        + {**ENGINES, **DOWNSAMPLED}[engine], check=True, cwd=_WORK.name,
         env={**os.environ, "PYTHONPATH": REPO, "JAX_PLATFORMS": "cpu",
              "XLA_FLAGS": ""})
     payload = bamio.read_bgzf(out)
@@ -288,16 +299,21 @@ def test_counters_add_up(seed, rates):
                                               "simplex.groups.legacy",
                                               "simplex.rejected"))] == []
         return
-    assert [n for n in LEGACY if n not in m] == []
-    assert 0 < m["simplex.reads.legacy"] <= m["simplex.reads"]
-    assert m["simplex.groups.legacy"] == m["simplex.groups.legacy.cigar"] \
-        < m["simplex.groups"]
+    # the filter is a native pass over whole segments: no group leaves the
+    # whole-array preparation for it
+    assert [n for n in m if n.startswith("simplex.groups.legacy")
+            or n == "simplex.reads.legacy"] == []
+    assert [n for n in FILTER if n not in m] == []
+    assert 0 < m["simplex.reads.filtered"] <= m["simplex.reads"]
+    assert 0 < m["simplex.groups.filtered"] < m["simplex.groups"]
     assert m["simplex.filter.reads_rejected"] == tallies["MinorityAlignment"] \
         == m["simplex.rejected.MinorityAlignment"]
     assert 0 < m["simplex.filter.segments_kept_all"] \
         <= m["simplex.filter.segments"]
+    assert m["simplex.groups.filtered"] <= m["simplex.filter.segments"] \
+        <= 2 * m["simplex.groups.filtered"]
     assert m["simplex.filter.reads_rejected"] < m["simplex.filter.reads_in"] \
-        <= m["simplex.reads.legacy"]
+        <= m["simplex.reads.filtered"]
     # every input read accounted for
     assert sum(tallies.values()) == d["n_reads"]
     assert tallies["ConsensusReads"] + sum(
@@ -305,24 +321,51 @@ def test_counters_add_up(seed, rates):
         == m["simplex.input_reads"]
 
 
+def _on_the_prep_thread(report, name):
+    """The threads whose account holds ``name`` are ``process.prep``'s."""
+    holders = {t for t, rec in report["threads"].items()
+               if name in rec["self_s"]}
+    return holders and holders == {
+        t for t, rec in report["threads"].items()
+        if "process.prep" in rec["self_s"]}
+
+
 @pytest.mark.parametrize("seed,rates", WITH_CIGARS)
-def test_run_report_names_the_per_group_preparation(seed, rates):
+def test_run_report_names_the_filters_native_pass(seed, rates):
     _got, _header, report = _run(seed, rates, "default")
     by_name = report["spans"]["by_name"]
+    assert "process.prep.legacy" not in by_name
+    prep, filt = by_name["process.prep"], by_name["process.prep.align_filter"]
+    # one span a batch round the one native call, not one a segment; a
+    # child of process.prep, whose self time therefore does not hold it
+    assert filt["count"] == 1 < report["metrics"]["simplex.filter.segments"]
+    assert "utime_s" in filt
+    assert 0 < filt["wall_s"] <= prep["wall_s"] - prep["self_s"] + 1e-5
+    assert _on_the_prep_thread(report, "process.prep.align_filter")
+
+
+def test_a_downsample_opens_the_per_group_preparation():
+    """``--max-reads``: the groups over it take the per-group scan, under
+    one ``process.prep.legacy`` span a batch, and the scan's filter is the
+    same native function, a segment a call."""
+    _got, _header, report = _run(SEEDS[0], "five", "max-reads")
+    m, by_name = report["metrics"], report["spans"]["by_name"]
     assert [n for n in NEW_SPANS if n not in by_name] == []
+    assert [n for n in LEGACY + FILTER if n not in m] == []
     prep, legacy = by_name["process.prep"], by_name["process.prep.legacy"]
-    # one span a batch, not one a group; a child of process.prep, whose
-    # self time therefore does not hold it
-    assert legacy["count"] == 1 < report["metrics"]["simplex.groups.legacy"]
+    assert legacy["count"] == 1 < m["simplex.groups.legacy"] \
+        == m["simplex.groups.legacy.downsample"] < m["simplex.groups"]
+    assert 0 < m["simplex.reads.legacy"] < m["simplex.reads"]
     assert "utime_s" in legacy
-    assert prep["wall_s"] - prep["self_s"] >= legacy["wall_s"] - 1e-5
-    # the filter's seconds lie inside it
-    assert 0 < by_name["process.prep.align_filter"]["wall_s"] \
-        <= legacy["wall_s"]
-    threads = {t for t, rec in report["threads"].items()
-               if "process.prep" in rec["self_s"]}
-    assert {t for t, rec in report["threads"].items()
-            if "process.prep.legacy" in rec["self_s"]} == threads
+    assert prep["wall_s"] - prep["self_s"] >= legacy["wall_s"] \
+        + by_name["process.prep.align_filter"]["wall_s"] - 1e-5
+    assert _on_the_prep_thread(report, "process.prep.legacy")
+    # the filter's counters hold both routes' segments
+    assert m["simplex.filter.reads_rejected"] \
+        == m["simplex.rejected.MinorityAlignment"] > 0
+    assert m["simplex.filter.reads_in"] <= m["simplex.reads.filtered"]
+    assert m["simplex.input_reads"] == m["simplex.reads"] \
+        == _cell(SEEDS[0], "five")[2]["n_reads"]
 
 
 def test_no_cigars_no_new_span():
@@ -335,9 +378,10 @@ def test_no_cigars_no_new_span():
 
 def test_spans_cost_nothing_when_not_armed():
     assert not trace.tracing_enabled()
-    assert trace.span("process.prep.legacy", groups=3) is trace.NULL_SPAN
+    for name in NEW_SPANS:
+        assert trace.span(name, rusage=True, segments=3) is trace.NULL_SPAN
     caller = fast.FastSimplexCaller.__new__(fast.FastSimplexCaller)
-    caller._filter_tally = fast._FilterTally(timed=False)
+    caller._filter_tally = fast._FilterTally()
     caller._fold_filter_tally()  # no filter ran: nothing is recorded
     assert caller._filter_tally is None
 
@@ -354,7 +398,8 @@ def _reader(name):
 
 @pytest.mark.parametrize("name", READERS)
 def test_the_readers(name):
-    report = _run(SEEDS[0], "five", "default")[2]
+    # where groups still take the per-group scan: a downsample
+    report = _run(SEEDS[0], "five", "max-reads")[2]
     d = _cell(SEEDS[0], "five")[2]
     run = {"reports": [report, report], "traced_jobs": 2,
            "reads_per_job": d["n_reads"]}
@@ -371,6 +416,19 @@ def test_the_readers(name):
             100.0 * m["simplex.filter.segments_kept_all"]
             / m["simplex.filter.segments"]}[name]
     assert value == pytest.approx(want) and value > 0
+    # the cell's command: no group leaves the whole-array preparation, so
+    # the shares read 0.0 and the span's reader nothing; the filter's share
+    # counts the input and is what it was
+    cell = _run(SEEDS[0], "five", "default")[2]
+    got = _reader(name).read({**run, "reports": [cell, cell]})
+    mc = cell["metrics"]
+    assert got == {
+        "simplex.legacy_group_share": 0.0,
+        "simplex.legacy_read_share": 0.0,
+        "simplex.legacy_prep_s_per_mread": None,
+        "simplex.filter_keep_all_share":
+            pytest.approx(100.0 * mc["simplex.filter.segments_kept_all"]
+                          / mc["simplex.filter.segments"])}[name]
     # a program from before the counters and spans: nothing, and no raise
     old = {k: v for k, v in report.items() if k != "spans"}
     old["metrics"] = {k: v for k, v in m.items()
@@ -457,6 +515,9 @@ def test_the_filters_rules(name):
     order = sorted(range(len(reads)), key=lambda i: -reads[i][0])
     assert sorted(program_cigar.select_most_common_alignment_group(
         [(i, reads[i][0], reads[i][1]) for i in order])) == kept
+    # and the program's native pass, on the same CIGARs as BAM words
+    assert _native_kept([(cigar, False, length)
+                         for length, cigar in reads]) == kept
 
 
 RAW_CASES = {
@@ -490,6 +551,17 @@ def _parse(text):
     return [(op, int(n)) for n, op in re.findall(r"(\d+)([MIDNSHP=X])", text)]
 
 
+def _native_kept(reads):
+    """The rows ``nb.alignment_filter`` keeps of one segment of
+    (raw CIGAR, reverse strand, length) reads."""
+    buf, cigar_off, n_cigar = cigar_buffer(
+        [cigar for cigar, _rev, _n in reads], np.random.default_rng(7))
+    keep = nb.alignment_filter(
+        buf, cigar_off, n_cigar, np.array([rev for _c, rev, _n in reads]),
+        np.array([n for _c, _rev, n in reads]), np.array([0, len(reads)]))
+    return np.flatnonzero(keep).tolist()
+
+
 @pytest.mark.parametrize("name", RAW_CASES)
 def test_the_filter_on_raw_cigars(name):
     raw, kept = RAW_CASES[name]
@@ -500,6 +572,8 @@ def test_the_filter_on_raw_cigars(name):
             cigar = cigar[::-1]
         reads.append((length, ra.truncate(cigar, length)))
     assert ra.most_common_alignment(reads) == kept
+    assert _native_kept([(_parse(text), reverse, length)
+                         for text, reverse, length in raw]) == kept
 
 
 MATE_CASES = {
@@ -523,6 +597,123 @@ def test_bases_past_the_mate(name):
     (text, pos, reverse, mate_text, mate_pos), clipped = MATE_CASES[name]
     assert ra.bases_past_mate(_parse(text), pos, reverse, _parse(mate_text),
                               mate_pos) == clipped
+
+
+# ------------------------------- the array path against the per-group scan
+
+def _prepared(path, min_reads, scan):
+    """The engine's job tables, a batch each, in a form that does not
+    depend on how the row pool is laid out, its tallies, and its records:
+    by the whole-array preparation, or (``scan``) by the per-group scan that
+    ``--rejects`` forces."""
+    caller = VanillaConsensusCaller(
+        "fgumi", "A", VanillaOptions(min_reads=min_reads),
+        track_rejects=scan)
+    engine = fast.FastSimplexCaller(caller, b"MI")
+    tables = []
+
+    def prepare(*args):
+        codes, quals, t = prepare_jobs(*args)
+        tables.append([
+            (int(t.read_type[j]), int(t.cons_len[j]), int(t.mi_rec[j]),
+             t.pool_rows[t.vlo[j]:t.vlo[j] + t.count[j]].tolist(),
+             t.pool_span[t.vlo[j]:t.vlo[j] + t.count[j]].tolist())
+            for j in range(len(t))])
+        return codes, quals, t
+
+    prepare_jobs, engine._prepare_jobs = engine._prepare_jobs, prepare
+    chunks = []
+    with BamBatchReader(path, target_bytes=64 << 10) as reader:
+        for batch in reader:
+            chunks.extend(engine.process_batch(batch))
+    chunks.extend(engine.flush())
+    records = b"".join(map(fast.resolve_chunk, chunks))
+    stats = caller.stats
+    return tables, dict(stats.rejected), stats.input_reads, \
+        stats.consensus_reads, records
+
+
+@pytest.mark.parametrize("min_reads", [1, 2, 3])
+@pytest.mark.parametrize("seed,rates", WITH_CIGARS)
+def test_the_array_path_prepares_what_the_scan_prepares(seed, rates,
+                                                        min_reads):
+    path = _cell(seed, rates)[3]
+    vec = _prepared(path, min_reads, scan=False)
+    assert vec == _prepared(path, min_reads, scan=True)
+    tables, rejected, _reads, _consensus, _records = vec
+    assert sum(map(len, tables)) > FAMILIES / 4 and rejected[
+        "MinorityAlignment"] > 0
+    if min_reads > 1:
+        assert rejected["InsufficientReads"] > 0
+
+
+def _hand_bam(path):
+    """Three paired families whose filter decides what the later steps see;
+    a fourth of fragment reads with one CIGAR on both strands, which read
+    from the reverse read's end is another CIGAR; and a fifth of three plain
+    fragment reads for the batch's open tail (the last group goes through
+    the per-group caller either way)."""
+    rng = np.random.default_rng(5)
+    m100, ins, dele = [("M", 100)], [("M", 40), ("I", 2), ("M", 58)], \
+        [("M", 60), ("D", 3), ("M", 40)]
+    families = [
+        # R2's three CIGARs found three groups of one read: the filter
+        # leaves one read, under --min-reads 2, and R1 is an orphan
+        ([m100, m100, m100], [m100, ins, dele]),
+        # the filter takes one read of each type and both stay
+        ([m100, m100, ins], [dele, dele, dele, m100]),
+        # a trimmed-to-nothing read goes before the filter sees the rest
+        ([m100, ins, ins, m100, m100], [m100, m100, m100, m100, m100]),
+    ]
+    header = BamHeader(
+        text="@HD\tVN:1.6\tSO:unsorted\tGO:query\n@SQ\tSN:c1\tLN:100000\n"
+             "@RG\tID:A\tSM:s\n", ref_names=["c1"], ref_lengths=[100000])
+    with BamWriter(path, header) as w:
+        for f, (r1s, r2s) in enumerate(families):
+            for mate, cigars in ((0, r1s), (1, r2s)):
+                for r, cig in enumerate(cigars):
+                    n = sum(k for op, k in cig if op in "MI")
+                    quals = np.full(n, 35, np.uint8)
+                    if f == 2 and mate == 0 and r == 0:
+                        quals[:] = 2  # every base masked: zero length
+                    seq = bytes(b"ACGT"[c] for c in rng.integers(0, 4, n))
+                    flag = 0x1 | (0x40 if mate == 0 else 0x80 | 0x10) \
+                        | (0x20 if mate == 0 else 0)
+                    w.write_record_bytes(_build_mapped_record(
+                        f"f{f}t{r}".encode(), flag, 0, 1000 + 300 * mate, 60,
+                        cig, seq, quals, 0, 1300 - 300 * mate, 0,
+                        [(b"MI", "Z", str(f).encode()),
+                         (b"RG", "Z", b"A")]))
+        for mi, cig, flags in ((b"3", [("M", 70), ("D", 2), ("M", 30)],
+                                (0x10, 0, 0)), (b"4", m100, (0, 0, 0))):
+            for r, flag in enumerate(flags):
+                w.write_record_bytes(_build_mapped_record(
+                    b"frag%s.%d" % (mi, r), flag, 0, 5000, 60, cig,
+                    bytes(b"ACGT"[c] for c in rng.integers(0, 4, 100)),
+                    np.full(100, 35, np.uint8), -1, -1, 0,
+                    [(b"MI", "Z", mi), (b"RG", "Z", b"A")]))
+    return path
+
+
+HAND_REJECTS = {
+    1: {"MinorityAlignment": 2 + 2 + 2 + 1, "ZeroLengthAfterTrimming": 1},
+    2: {"MinorityAlignment": 2 + 2 + 2 + 1, "ZeroLengthAfterTrimming": 1,
+        "InsufficientReads": 1, "OrphanConsensus": 3},
+    3: {"MinorityAlignment": 2 + 2 + 2 + 1, "ZeroLengthAfterTrimming": 1,
+        "InsufficientReads": 1 + 2 + 2 + 2, "OrphanConsensus": 3 + 3 + 5},
+}
+
+
+@pytest.mark.parametrize("min_reads", [1, 2, 3])
+def test_the_filter_can_take_a_segment_under_min_reads(min_reads):
+    path = _hand_bam(os.path.join(_WORK.name, "hand.bam"))
+    vec = _prepared(path, min_reads, scan=False)
+    assert vec == _prepared(path, min_reads, scan=True)
+    assert vec[1] == HAND_REJECTS[min_reads]
+    # every input read is rejected once or in one job (the tail's three
+    # in the per-group caller's)
+    assert vec[2] == 29 == sum(vec[1].values()) + 3 \
+        + sum(len(rows) for t in vec[0] for *_job, rows, _span in t)
 
 
 def test_the_reference_imports_nothing_of_the_program():

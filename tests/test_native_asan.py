@@ -123,3 +123,40 @@ def test_depth_errors_ranges_tight_buffer_under_asan(sanitized_env):
     tail = (proc.stdout + "\n" + proc.stderr)[-4000:]
     assert proc.returncode == 0 and "ranges ok" in proc.stdout, tail
     assert "ERROR: AddressSanitizer" not in tail
+
+
+# the alignment filter decodes CIGAR words where they lie, at odd offsets:
+# the last CIGAR ends on the buffer's last byte, so a word read past a
+# CIGAR's count, or an aligned 4-byte load, is out of bounds or misaligned
+_FILTER_TIGHT = """
+import sys
+import numpy as np
+sys.path.insert(0, "tests")
+from cigar_segments import OPS, cigar_buffer, filter_oracle
+from fgumi_tpu.native import batch as nb
+
+assert nb.get_lib() is not None
+rng = np.random.default_rng(9)
+cigars = [[(OPS[int(rng.integers(0, 9))], int(rng.integers(1, 30)))
+           for _ in range(int(rng.integers(0, 6)))] for _ in range(400)]
+buf, cigar_off, n_cigar = cigar_buffer(cigars, rng)
+buf = buf.copy()  # an allocation of its own, exactly as long
+assert cigar_off[-1] + 4 * n_cigar[-1] == len(buf) and (cigar_off % 2).all()
+reverse = rng.integers(0, 2, 400).astype(np.uint8)
+lens = rng.integers(1, 60, 400).astype(np.int32)
+starts = np.array([0, 1, 1, 40, 41, 43, 200, 400])
+keep = nb.alignment_filter(buf, cigar_off, n_cigar, reverse, lens, starts)
+for lo, hi in zip(starts[:-1], starts[1:]):
+    assert (keep[lo:hi] == filter_oracle(cigars[lo:hi], reverse[lo:hi],
+                                         lens[lo:hi])).all()
+print("filter ok")
+"""
+
+
+def test_alignment_filter_tight_buffer_under_asan(sanitized_env):
+    proc = subprocess.run([sys.executable, "-c", _FILTER_TIGHT], cwd=REPO,
+                          capture_output=True, text=True, timeout=300,
+                          env=sanitized_env)
+    tail = (proc.stdout + "\n" + proc.stderr)[-4000:]
+    assert proc.returncode == 0 and "filter ok" in proc.stdout, tail
+    assert "ERROR: AddressSanitizer" not in tail

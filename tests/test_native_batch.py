@@ -16,6 +16,8 @@ from fgumi_tpu.io.bam import FLAG_REVERSE, BamReader, RawRecord
 from fgumi_tpu.native import batch
 from fgumi_tpu.simulate import simulate_grouped_bam, simulate_mapped_bam
 
+from cigar_segments import OPS, cigar_buffer, filter_oracle
+
 pytestmark = pytest.mark.skipif(not batch.available(),
                                 reason="native library unavailable")
 
@@ -524,3 +526,98 @@ def test_segment_depth_errors_ranges_refuses_what_it_cannot_read(bad):
         rows = rows.astype(np.int32)
     with pytest.raises(ValueError, match="segment_depth_errors_ranges"):
         nb.segment_depth_errors_ranges(codes, rows, winner, lo, hi)
+
+
+# ------------------------------------------ the most-common-alignment filter
+
+def _random_segment(rng):
+    """One segment: (cigars, reverse flags, final lengths). Rows are drawn
+    round a few template CIGARs so that prefix groups, ties in length and
+    ties in group size all come up; some rows are random outright."""
+    n = int(rng.integers(1, 61))
+
+    def random_cigar():
+        return [(OPS[int(rng.integers(0, 9))], int(rng.integers(1, 40)))
+                for _ in range(int(rng.integers(1, 7)))]
+
+    templates = [random_cigar() for _ in range(int(rng.integers(1, 4)))]
+    mixed = rng.random() < 0.5
+    strand = bool(rng.integers(0, 2))
+    few_lens = rng.random() < 0.5
+    cigars, reverse, lens = [], [], []
+    for _ in range(n):
+        u = rng.random()
+        if u < 0.15:
+            cig = random_cigar()
+        else:
+            cig = list(templates[int(rng.integers(0, len(templates)))])
+            if u < 0.4:  # the same ops, one length moved
+                k = int(rng.integers(0, len(cig)))
+                cig[k] = (cig[k][0],
+                          max(1, cig[k][1] + int(rng.integers(-2, 3))))
+            elif u < 0.5:  # an op more, or one fewer
+                cig = cig[:-1] or cig if rng.random() < 0.5 \
+                    else cig + random_cigar()[:1]
+        query = sum(ln for op, ln in cig if op in "MIS=XH")
+        cigars.append(cig)
+        reverse.append(bool(rng.integers(0, 2)) if mixed else strand)
+        hi = max(query, 1)
+        lens.append(int(rng.choice([hi, max(hi - 3, 1), max(hi // 2, 1)]))
+                    if few_lens else int(rng.integers(1, hi + 1)))
+    return cigars, reverse, lens
+
+
+@pytest.mark.parametrize("seed", range(12))
+def test_alignment_filter_matches_the_python_filter(seed):
+    rng = np.random.default_rng(1000 + seed)
+    segs = [_random_segment(rng) for _ in range(150)]
+    cigars = [c for seg in segs for c in seg[0]]
+    reverse = np.array([r for seg in segs for r in seg[1]], dtype=np.uint8)
+    lens = np.array([ln for seg in segs for ln in seg[2]], dtype=np.int32)
+    starts = np.concatenate(([0], np.cumsum([len(seg[0]) for seg in segs])))
+    buf, cigar_off, n_cigar = cigar_buffer(cigars, rng)
+    keep = batch.alignment_filter(buf, cigar_off, n_cigar, reverse, lens,
+                                  starts)
+    rejected_some = 0
+    for s, (cg, rv, ln) in enumerate(segs):
+        want = filter_oracle(cg, rv, ln)
+        got = keep[starts[s]:starts[s + 1]]
+        assert np.array_equal(got, want), (seed, s, cg, rv, ln)
+        rejected_some += not want.all()
+    assert 20 < rejected_some < 150  # both answers come up
+    # one segment alone is the same answer (the engine's --rejects path)
+    for s in range(0, len(segs), 10):
+        lo, hi = starts[s], starts[s + 1]
+        one = batch.alignment_filter(
+            buf, cigar_off[lo:hi], n_cigar[lo:hi], reverse[lo:hi],
+            lens[lo:hi], np.array([0, hi - lo]))
+        assert np.array_equal(one, keep[lo:hi])
+
+
+def test_alignment_filter_small_and_empty_segments():
+    rng = np.random.default_rng(5)
+    cigars = [[("M", 50)], [("M", 20), ("I", 2), ("M", 28)], [],
+              [("M", 50)], []]
+    buf, cigar_off, n_cigar = cigar_buffer(cigars, rng)
+    rev = np.zeros(5, dtype=np.uint8)
+    lens = np.array([50, 50, 10, 50, 10], dtype=np.int32)
+    # segments: one row; none; an empty CIGAR beside a real one (the empty
+    # CIGAR prefixes everything); an empty CIGAR alone
+    starts = np.array([0, 1, 1, 4, 5])
+    keep = batch.alignment_filter(buf, cigar_off, n_cigar, rev, lens, starts)
+    assert keep.tolist() == [1] + filter_oracle(
+        cigars[1:4], rev[1:4], lens[1:4]).tolist() + [1]
+    assert batch.alignment_filter(
+        buf, cigar_off[:0], n_cigar[:0], rev[:0], lens[:0],
+        np.array([0])).tolist() == []
+    # nonnative dtypes are converted
+    keep2 = batch.alignment_filter(
+        buf, cigar_off.astype(np.int32), n_cigar.astype(np.int64),
+        rev.astype(bool), lens.astype(np.int64), starts.astype(np.int32))
+    assert np.array_equal(keep, keep2)
+    for bad in ([0, 1, 1, 4], [1, 4, 5], [0, 4, 1, 5]):
+        with pytest.raises(ValueError):
+            batch.alignment_filter(buf, cigar_off, n_cigar, rev, lens,
+                                   np.array(bad))
+    with pytest.raises(ValueError):
+        batch.alignment_filter(buf, cigar_off, n_cigar, rev, lens[:4], starts)

@@ -10,8 +10,12 @@ any other CIGAR shape run the classic prepare() unchanged, in stream order
 
 A batch's molecules travel from the prepare to `nb.build_codec_records` as
 columns (`_Molecules`: one array a field, one entry a molecule or a strand),
-so the verdicts, the strand dispatch, the placement, the gates and the
-names / MI / RX of the records are whole-array passes. A Python loop runs
+so the verdicts, the strand dispatch, the gates and the names / MI / RX of
+the records are whole-array passes, and a strand's bases, qualities, depths
+and errors go from the row of the result matrix that holds them to the
+record builder through arrays made once: `nb.codec_place` writes them
+oriented and padded, depths and errors as int32, and `nb.codec_combine` and
+`nb.build_codec_records` read them there. A Python loop runs
 only over the molecules the closed forms do not cover, one at a time and in
 stream order among themselves (`codec.row_molecules`): the one a batch
 boundary cut, a group with a CIGAR that is not one M run, a group whose
@@ -39,6 +43,7 @@ record counter, which names a molecule whose MI value is empty
 (`_EmittedOrder`). Run-report counters, by molecule:
 `codec.molecules` = `.emitted` + `.rejected` (`.rejected.<reason>`),
 `.slow_molecules`, `.row_molecules`, `.strands`, `.single_strands`,
+`.place.strands` / `.place.cells` (what the native placement copied),
 `.combine_cells_device` / `_host`, `.duplex_bases`, `.disagreements`; by
 chunk: `.stage2_batches`, `.stage2_off_thread` (docs/observability.md).
 """
@@ -78,9 +83,10 @@ _BASE_OF_CODE = CODE_TO_BASE[np.minimum(np.arange(256), N_CODE)]
 _COMPLEMENT_OF_CODE = _ASCII_COMPLEMENT[_BASE_OF_CODE]
 
 #: where a strand's consensus lies after the dispatch (`_run`'s strand
-#: column ``src``): a row of the dense batch's result matrices, a row of the
-#: single-read table pass's, or an entry of the materialised list (the
-#: single-read strands of a classic-prepared molecule)
+#: column ``src``, an index into `_finish_batch`'s ``sources``): a row of the
+#: dense batch's result matrices, a row of the single-read table pass's, or,
+#: from `_SRC_ARRAYS` on, a materialised strand of its own (the single-read
+#: strands of a classic-prepared molecule)
 _SRC_SLOT, _SRC_SINGLE, _SRC_ARRAYS = 0, 1, 2
 
 
@@ -92,12 +98,12 @@ def _count_reject(reason, molecules=1):
         METRICS.inc("codec.rejected." + reason, molecules)
 
 
-def _ragged_arange(starts, counts, step=1):
-    """``concatenate([arange(s, s + step * c, step) for s, c in zip(starts,
-    counts)])`` (``step`` 1 or -1) as one repeat and a running offset."""
+def _ragged_arange(starts, counts):
+    """``concatenate([arange(s, s + c) for s, c in zip(starts, counts)])``
+    as one repeat and a running offset."""
     excl = np.cumsum(counts) - counts
-    return np.repeat(starts - step * excl, counts) \
-        + step * np.arange(int(counts.sum()), dtype=np.int64)
+    return np.repeat(starts - excl, counts) \
+        + np.arange(int(counts.sum()), dtype=np.int64)
 
 
 class _Molecules:
@@ -392,7 +398,7 @@ class FastCodecCaller:
         The strands are columns of 2M entries, strand ``2 * i + side`` of
         molecule ``i``: ``cnt`` reads, ``slen`` consensus length, ``b0``
         first pack row; the dispatch fills ``src`` (a ``_SRC_*``) and
-        ``srow`` (the row or entry in that source).
+        ``srow`` (the row in that source).
         """
         from ..ops import oracle
 
@@ -411,7 +417,7 @@ class FastCodecCaller:
         cnt = strands(mols.n_r1, mols.n_r2)
         slen = strands(mols.len1, mols.len2)
         b0 = strands(mols.pk0, mols.pk0 + mols.n_r1)
-        src = np.full(2 * M, _SRC_SLOT, dtype=np.int8)
+        src = np.full(2 * M, _SRC_SLOT, dtype=np.int32)
         srow = np.zeros(2 * M, dtype=np.int64)
         vec = np.repeat(mols.classic < 0, 2)
         sv = np.nonzero(vec & (cnt == 1))[0]  # single-read strands
@@ -448,8 +454,7 @@ class FastCodecCaller:
                     res = oracle.single_read_consensus(
                         job.codes[0][:cl], job.quals[0][:cl], ss.tables,
                         min_q)
-                    src[s], srow[s], slen[s] = _SRC_ARRAYS, len(arrays), \
-                        len(res[0])
+                    src[s], slen[s] = _SRC_ARRAYS + len(arrays), len(res[0])
                     arrays.append(res)
 
         pending = None
@@ -515,7 +520,8 @@ class FastCodecCaller:
                         ss.options.min_consensus_base_quality)
                 slot_mats = (b_all, q_all, d, e)
             return self._finish_batch(mols, src, srow, slen,
-                                      (slot_mats, single_mats, arrays), chunk)
+                                      [slot_mats, single_mats] + arrays,
+                                      chunk)
 
         return [_CodecPending(
             finish, pending.discard if pending is not None else None,
@@ -535,11 +541,11 @@ class FastCodecCaller:
         CODEC_COMBINE chooser and the record counter, which ``chunk``
         publishes to once the gates have said how many molecules it emits.
 
-        A strand's result is a row of one of two sets of result matrices
-        (``src``: the dense batch's (F, L), the single-read table pass's
-        (n_single, stride)); each set places its strands of a side with ONE
-        gather + scatter. The materialised strands of a carry or fallback
-        molecule (one or two a batch) are placed one by one."""
+        A strand's result is row ``srow`` of source ``src`` in ``sources``:
+        the dense batch's (F, L) result matrices, the single-read table
+        pass's (n_single, stride), or one materialised strand of a carry or
+        fallback molecule (one or two a batch). `nb.codec_place` copies a
+        side's strands from there to the padded per-molecule arrays."""
         from .vanilla import I16_MAX
 
         caller = self.caller
@@ -564,43 +570,6 @@ class FastCodecCaller:
             np.cumsum(Ls, out=offs[1:])
             T = int(offs[-1])
 
-            # oriented + padded strands (pad = lowercase n / Q0 / depth 0)
-            b1 = np.full(T, NO_CALL_BASE_LOWER, np.uint8)
-            b2 = np.full(T, NO_CALL_BASE_LOWER, np.uint8)
-            q1 = np.zeros(T, np.uint8)
-            q2 = np.zeros(T, np.uint8)
-            # int32: every value here is pre-capped at I16_MAX, and the
-            # combine's sums stay well under 2^31 — int64 was pure memory
-            # traffic
-            d1 = np.zeros(T, np.int32)
-            d2 = np.zeros(T, np.int32)
-            e1 = np.zeros(T, np.int32)
-            e2 = np.zeros(T, np.int32)
-
-            def place_arr(bases_c, quals, dep, err, table, rc, pad_left, o,
-                          L, b, q, d, e):
-                k = len(bases_c)
-                sl = slice(o + L - k, o + L) if pad_left else slice(o, o + k)
-                step = -1 if rc else 1
-                b[sl] = table[bases_c[::step]]
-                q[sl] = quals[::step]
-                d[sl] = np.minimum(dep[::step], I16_MAX)
-                e[sl] = np.minimum(err[::step], I16_MAX)
-
-            def place_rows(mats, rows, ks, base, table, rc, bt, qt, dt, et):
-                """Strands of one side at fragment positions ``base``, rows
-                ``rows`` (lengths ``ks``) of the result matrices ``mats``:
-                one gather + scatter, reversed where ``rc``."""
-                tgt = _ragged_arange(base, ks)
-                first = rows * mats[0].shape[1]
-                flat = _ragged_arange(first + ks - 1, ks, -1) if rc \
-                    else _ragged_arange(first, ks)
-                b_all, q_all, dmat, emat = (m.reshape(-1) for m in mats)
-                bt[tgt] = table[b_all[flat]]
-                qt[tgt] = q_all[flat]
-                dt[tgt] = np.minimum(dmat[flat], I16_MAX)
-                et[tgt] = np.minimum(emat[flat], I16_MAX)
-
             # The strands land in the RECORD's orientation, not the
             # fragment's (codec.py _finish orients both strands onto the
             # forward fragment, combines, and reverse-complements the result
@@ -610,25 +579,25 @@ class FastCodecCaller:
             # combine, the gates' sums and the quality masks are positionwise
             # or symmetric, so every base is what the two steps would give
             # and the serializer copies nothing around.
-            slot_mats, single_mats, arrays = sources
-            for side, table, out in ((0, _BASE_OF_CODE, (b1, q1, d1, e1)),
-                                     (1, _COMPLEMENT_OF_CODE,
-                                      (b2, q2, d2, e2))):
-                s_src, s_row, s_len = src[side::2], srow[side::2], \
-                    slen[side::2]
-                rc = side == 1
-                base = offs[:-1] + np.where(r1n ^ r2n, Ls - s_len, 0) \
-                    if rc else offs[:-1]
-                for which, mats in ((_SRC_SLOT, slot_mats),
-                                    (_SRC_SINGLE, single_mats)):
-                    jarr = np.nonzero(s_src == which)[0]
-                    if len(jarr):
-                        place_rows(mats, s_row[jarr], s_len[jarr],
-                                   base[jarr], table, rc, *out)
-                for j in np.nonzero(s_src == _SRC_ARRAYS)[0]:
-                    place_arr(*arrays[s_row[j]], table, rc,
-                              bool(rc and r1n[j] != r2n[j]), int(offs[j]),
-                              int(Ls[j]), *out)
+            #
+            # One native ragged copy a side, both matrix sets and the
+            # materialised strands in the one call: it writes the pad too
+            # (lowercase n / Q0 / depth 0 / errors 0), and depths and errors
+            # come out capped at I16_MAX as the int32 the combine and the
+            # record builder read.
+            def place(side, table):
+                base = offs[:-1]
+                if side:
+                    base = base + np.where(r1n ^ r2n, Ls - slen[1::2], 0)
+                return nb.codec_place(
+                    sources, src[side::2], srow[side::2], slen[side::2],
+                    base, offs, table, bool(side), I16_MAX,
+                    NO_CALL_BASE_LOWER)
+
+            b1, q1, d1, e1 = place(0, _BASE_OF_CODE)
+            b2, q2, d2, e2 = place(1, _COMPLEMENT_OF_CODE)
+            METRICS.inc("codec.place.strands", 2 * J)
+            METRICS.inc("codec.place.cells", int(slen.sum()))
 
         # ---- duplex combine, one pass over the concatenated strands:
         # device jit (ops/kernel._codec_combine_jit), native C pass, or
@@ -668,9 +637,13 @@ class FastCodecCaller:
         with _span("engine.codec.gates", rusage=True):
             # per-molecule disagreement thresholds (recoverable rejects)
             def seg_sum(x):
-                cs = np.zeros(T + 1, np.int64)
-                np.cumsum(x, out=cs[1:])
-                return cs[offs[1:]] - cs[offs[:-1]]
+                # one pass and no T-long running sum (a molecule with no
+                # position has no segment to reduce)
+                some = Ls > 0
+                sums = np.zeros(J, np.int64)
+                sums[some] = np.add.reduceat(x, offs[:-1][some],
+                                             dtype=np.int64)
+                return sums
 
             duplex_bases = seg_sum(both)
             disagreements = seg_sum(disag)
@@ -778,24 +751,25 @@ class FastCodecCaller:
             self._name_rx_blob(mols.take(good) if G < len(mols) else mols,
                                chunk)
 
+        # the native builder reads the rows where `nb.codec_place` and the
+        # combine left them, depths and errors as the int32 they were made
+        # in (a copy only of what a route handed over in another form)
         u8 = lambda x: np.ascontiguousarray(x, dtype=np.uint8)
-        # the native builder reads 8-byte depth and error elements (the
-        # combine math upstream runs in int32)
-        i64 = lambda x: np.ascontiguousarray(x, dtype=np.int64)
-        cb, cq, ce = u8(cons[0]), u8(cons[1]), i64(cons[3])
-        b1, q1, a_d, a_e = u8(side_a[0]), u8(side_a[1]), i64(side_a[2]), \
-            i64(side_a[3])
-        b2, q2, b_d, b_e = u8(side_b[0]), u8(side_b[1]), i64(side_b[2]), \
-            i64(side_b[3])
+        i32 = lambda x: np.ascontiguousarray(x, dtype=np.int32)
+        cb, cq, ce = u8(cons[0]), u8(cons[1]), i32(cons[3])
+        b1, q1, a_d, a_e = u8(side_a[0]), u8(side_a[1]), i32(side_a[2]), \
+            i32(side_a[3])
+        b2, q2, b_d, b_e = u8(side_b[0]), u8(side_b[1]), i32(side_b[2]), \
+            i32(side_b[3])
 
         og = offs[:-1][good]
         wire, rec_end = nb.build_codec_records(
             cb.ctypes.data + og, cq.ctypes.data + og,
-            ce.ctypes.data + 8 * og,
+            ce.ctypes.data + 4 * og,
             b1.ctypes.data + og, q1.ctypes.data + og,
-            a_d.ctypes.data + 8 * og, a_e.ctypes.data + 8 * og,
+            a_d.ctypes.data + 4 * og, a_e.ctypes.data + 4 * og,
             b2.ctypes.data + og, q2.ctypes.data + og,
-            b_d.ctypes.data + 8 * og, b_e.ctypes.data + 8 * og,
+            b_d.ctypes.data + 4 * og, b_e.ctypes.data + 4 * og,
             Ls[good], name_addr, name_len, mi_addr, mi_len, rx_addr, rx_len,
             caller.read_group_id.encode(), FLAG_UNMAPPED,
             opts.produce_per_base_tags)

@@ -25,7 +25,7 @@ _lock = threading.Lock()
 _lib = None
 _lib_failed = False
 # must equal fgumi_abi_version() in fgumi_native.cc (stale-.so guard)
-_ABI_VERSION = 18
+_ABI_VERSION = 19
 
 
 def build() -> bool:
@@ -68,6 +68,10 @@ def _declare(lib):
     lib.fgumi_codec_combine.argtypes = [
         p, p, p, p, p, p, p, p, ctypes.c_long, ctypes.c_int, ctypes.c_ubyte,
         ctypes.c_ubyte, ctypes.c_int, p, p, p, p, p, p]
+    lib.fgumi_codec_place.restype = None
+    lib.fgumi_codec_place.argtypes = (
+        [p] * 11 + [ctypes.c_long, p, ctypes.c_int, ctypes.c_int,
+                    ctypes.c_ubyte] + [p] * 4)
     lib.fgumi_bgzf_decompress.restype = ctypes.c_long
     lib.fgumi_bgzf_decompress.argtypes = [
         ctypes.c_void_p, ctypes.c_long, ctypes.c_void_p, ctypes.c_long,

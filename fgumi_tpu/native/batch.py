@@ -794,7 +794,8 @@ def build_codec_records(seq_addr, qual_addr, cons_err_addr,
     codec_caller.rs:1374-1539). All *_addr arrays are raw element addresses
     (int64) into caller-owned arrays that MUST stay referenced for the call;
     seq/qual/strand base+qual rows are uint8, cons_err/depth/error rows are
-    int64, all of length lens[j]. mi_len[j] < 0 skips MI; rx_addr[j] == 0
+    int32 (as `codec_combine` and `codec_place` return them), all of length
+    lens[j]. mi_len[j] < 0 skips MI; rx_addr[j] == 0
     skips RX.
     """
     lib = get_lib()
@@ -957,6 +958,72 @@ def codec_combine(b1, b2, q1, q2, d1, d2, e1, e2, min_phred: int,
         int(no_call_lower), int(i16_max), _addr(cb), _addr(cq), _addr(cd),
         _addr(ce), _addr(both), _addr(disag))
     return cb, cq, cd, ce, both.view(np.bool_), disag.view(np.bool_)
+
+
+def codec_place(sources, sid, rows, ks, base, offs, table, reverse: bool,
+                cap: int, pad_base: int):
+    """One side's CODEC strands from the rows of the result matrices that
+    hold them to the oriented, padded per-molecule arrays, in one ragged
+    copy (fgumi_codec_place): no index array on either side of it.
+
+    ``sources``: a sequence of ``None`` (no strand may name it) or ``(bases
+    u8, quals u8, depths, errors)``, four matrices of one shape (a 1-D
+    array is one row), depths and errors int32 or int64, read as they are.
+    Strand ``j`` is the first ``ks[j]`` elements of row ``rows[j]`` of
+    source ``sid[j]``; it lands at ``base[j]`` inside its molecule's
+    ``offs[j]:offs[j + 1]``, reversed where ``reverse``, its bases through
+    the 256-byte ``table``, its depths and errors capped at ``cap``; the
+    rest of the molecule is the pad (``pad_base``, Q0, depth 0, errors 0).
+    Returns ``(bases u8, quals u8, depths i32, errors i32)`` of
+    ``offs[-1]`` elements, each written once.
+    """
+    lib = get_lib()
+    J = len(sid)
+    sid = np.ascontiguousarray(sid, np.int32)
+    rows, ks, base, offs = (np.ascontiguousarray(a, np.int64)
+                            for a in (rows, ks, base, offs))
+    table = np.ascontiguousarray(table, np.uint8)
+    S = len(sources)
+    addr = np.zeros((4, S), dtype=np.int64)
+    shape = np.zeros((2, S), dtype=np.int64)  # rows, row stride
+    width = np.full(S, 4, dtype=np.int32)
+    keep = []
+    for s, mats in enumerate(sources):
+        if mats is None:
+            continue
+        b, q = (np.atleast_2d(_as_c(m, np.uint8)) for m in mats[:2])
+        wide = np.result_type(mats[2], mats[3]).itemsize > 4
+        d, e = (np.atleast_2d(_as_c(m, np.int64 if wide else np.int32))
+                for m in mats[2:])
+        if not b.shape == q.shape == d.shape == e.shape:
+            raise ValueError(f"codec_place: source {s}'s matrices differ "
+                             "in shape")
+        keep.append((b, q, d, e))
+        addr[:, s] = [m.ctypes.data for m in keep[-1]]
+        shape[:, s] = b.shape
+        width[s] = d.itemsize
+    if len(table) != 256 or len(offs) != J + 1 or not (
+            len(rows) == len(ks) == len(base) == J):
+        raise ValueError("codec_place: column lengths differ")
+    if J and not ((sid >= 0).all() and (sid < S).all()
+                  and (rows >= 0).all() and (rows < shape[0][sid]).all()
+                  and (ks >= 0).all() and (ks <= shape[1][sid]).all()
+                  and (base >= offs[:-1]).all()
+                  and (base + ks <= offs[1:]).all()):
+        raise ValueError("codec_place: a strand lies outside its source "
+                         "or its molecule")
+    T = int(offs[-1])
+    bt = np.empty(T, dtype=np.uint8)
+    qt = np.empty(T, dtype=np.uint8)
+    dt = np.empty(T, dtype=np.int32)
+    et = np.empty(T, dtype=np.int32)
+    lib.fgumi_codec_place(
+        _addr(addr[0]), _addr(addr[1]), _addr(addr[2]), _addr(addr[3]),
+        _addr(shape[1]), _addr(width), _addr(sid), _addr(rows), _addr(ks),
+        _addr(base), _addr(offs), J, _addr(table), int(bool(reverse)),
+        int(cap), int(pad_base), _addr(bt), _addr(qt), _addr(dt), _addr(et))
+    del keep
+    return bt, qt, dt, et
 
 
 def duplex_rx_fast(buf, una_off, una_len, cnt, a_seg, b_seg):

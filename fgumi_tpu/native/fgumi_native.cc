@@ -92,7 +92,7 @@ extern "C" {
 // ABI version for the stale-.so guard in __init__.py: bump whenever any
 // exported signature changes (a symbol probe alone cannot detect an
 // argument-list change in an existing function).
-long fgumi_abi_version() { return 18; }
+long fgumi_abi_version() { return 19; }
 
 // Candidate UMI pairs with hamming(A[i], B[j]) <= d over (n, L)/(m, L) byte
 // matrices, via the d+1-part pigeonhole (umi/assigners.py
@@ -899,9 +899,9 @@ static const uint8_t* iupac_nibble_table() {
 // packed seq + quals, then tags RG:Z, [MI:Z], cD/cM/cE, aD/aM/aE, bD/bM/bE,
 // [ad/bd/ae/be:B,s ac/bc:Z aq/bq:Z], [RX:Z]. Per-record data arrives as raw
 // addresses: seq/qual/strand-base/strand-qual rows are uint8 of length
-// lens[j]; cons_err/strand depth+error rows are int64. mi_len[j] < 0 skips
-// MI; rx_addr[j] == 0 skips RX. Returns total bytes, -2 on an over-long
-// name, -1 on overflow.
+// lens[j]; cons_err/strand depth+error rows are int32, as fgumi_codec_combine
+// and fgumi_codec_place leave them. mi_len[j] < 0 skips MI; rx_addr[j] == 0
+// skips RX. Returns total bytes, -2 on an over-long name, -1 on overflow.
 long fgumi_build_codec_records(
     const int64_t* seq_addr, const int64_t* qual_addr,
     const int64_t* cons_err_addr,
@@ -932,7 +932,7 @@ long fgumi_build_codec_records(
 
     const uint8_t* seq = reinterpret_cast<const uint8_t*>(seq_addr[j]);
     const uint8_t* qual = reinterpret_cast<const uint8_t*>(qual_addr[j]);
-    const int64_t* cerr = reinterpret_cast<const int64_t*>(cons_err_addr[j]);
+    const int32_t* cerr = reinterpret_cast<const int32_t*>(cons_err_addr[j]);
     uint8_t* rec = out + off + 4;
     put_u32(rec + 0, 0xFFFFFFFFu);
     put_u32(rec + 4, 0xFFFFFFFFu);
@@ -967,10 +967,10 @@ long fgumi_build_codec_records(
       *p++ = 0;
     }
 
-    const int64_t* adp = reinterpret_cast<const int64_t*>(a_depth[j]);
-    const int64_t* aer = reinterpret_cast<const int64_t*>(a_err[j]);
-    const int64_t* bdp = reinterpret_cast<const int64_t*>(b_depth[j]);
-    const int64_t* ber = reinterpret_cast<const int64_t*>(b_err[j]);
+    const int32_t* adp = reinterpret_cast<const int32_t*>(a_depth[j]);
+    const int32_t* aer = reinterpret_cast<const int32_t*>(a_err[j]);
+    const int32_t* bdp = reinterpret_cast<const int32_t*>(b_depth[j]);
+    const int32_t* ber = reinterpret_cast<const int32_t*>(b_err[j]);
     auto cap16 = [](int64_t v) -> int64_t { return v < 32767 ? v : 32767; };
 
     // cD/cM over cap(a)+cap(b); cE = sum(cap(cons_err)) / sum(total_depth)
@@ -1001,8 +1001,8 @@ long fgumi_build_codec_records(
     p += 7;
 
     // aD/aM/aE then bD/bM/bE (strand aggregates over capped values)
-    const int64_t* deps[2] = {adp, bdp};
-    const int64_t* errs[2] = {aer, ber};
+    const int32_t* deps[2] = {adp, bdp};
+    const int32_t* errs[2] = {aer, ber};
     const char sc[2] = {'a', 'b'};
     for (int s = 0; s < 2; ++s) {
       int64_t mx = 0, mn = 0, dsum = 0, esum = 0;
@@ -1033,7 +1033,7 @@ long fgumi_build_codec_records(
 
     if (per_base_tags) {
       // ad bd ae be (B,s of capped values), then ac bc (Z), aq bq (Z +33)
-      const int64_t* rows[4] = {adp, bdp, aer, ber};
+      const int32_t* rows[4] = {adp, bdp, aer, ber};
       const char tag0[4] = {'a', 'b', 'a', 'b'};
       const char tag1[4] = {'d', 'd', 'e', 'e'};
       for (int t = 0; t < 4; ++t) {
@@ -3593,6 +3593,76 @@ void fgumi_codec_combine(const uint8_t* b1, const uint8_t* b2,
                                                                : errors);
     both_out[i] = both ? 1 : 0;
     disag_out[i] = (a_wins || b_wins || tie) ? 1 : 0;
+  }
+}
+
+// One side's strands of a CODEC batch, from the rows of the result matrices
+// that hold them to the oriented, padded per-molecule arrays the combine and
+// fgumi_build_codec_records read (fast_codec.py _finish_batch; codec.py
+// _finish orients and pads one molecule at a time). Source s is four
+// row-major matrices with one row stride (bases as result codes u8, quals
+// u8, depths and errors of src_width[s] = 4 or 8 bytes an element). Strand
+// j is the first ks[j] elements of row rows[j] of source sid[j]; it lands at
+// base[j] .. base[j] + ks[j] inside its molecule's offs[j] .. offs[j + 1],
+// reversed where `reverse`, bases through `table` (code -> base, or its
+// complement), depths and errors capped. The rest of the molecule gets the
+// pad (pad_base / Q0 / depth 0 / errors 0), so every output element is
+// written exactly once and the caller allocates without filling.
+void fgumi_codec_place(const int64_t* src_b, const int64_t* src_q,
+                       const int64_t* src_d, const int64_t* src_e,
+                       const int64_t* src_stride, const int32_t* src_width,
+                       const int32_t* sid, const int64_t* rows,
+                       const int64_t* ks, const int64_t* base,
+                       const int64_t* offs, long J, const uint8_t* table,
+                       int reverse, int32_t cap, uint8_t pad_base,
+                       uint8_t* bt, uint8_t* qt, int32_t* dt, int32_t* et) {
+  const bool rc = reverse != 0;
+  auto pad = [&](int64_t lo, int64_t hi) {
+    if (hi <= lo) return;
+    const size_t n = static_cast<size_t>(hi - lo);
+    std::memset(bt + lo, pad_base, n);
+    std::memset(qt + lo, 0, n);
+    std::memset(dt + lo, 0, 4 * n);
+    std::memset(et + lo, 0, 4 * n);
+  };
+  // depths or errors, capped: int32 elements from the dense batch's
+  // matrices, int64 from the single-read table pass, int32 out
+  auto counts = [&](const auto* src, int64_t k, int32_t* dst) {
+    if (rc) {
+      for (int64_t i = 0; i < k; ++i) {
+        const auto v = src[k - 1 - i];
+        dst[i] = v < cap ? static_cast<int32_t>(v) : cap;
+      }
+    } else {
+      for (int64_t i = 0; i < k; ++i)
+        dst[i] = src[i] < cap ? static_cast<int32_t>(src[i]) : cap;
+    }
+  };
+  for (long j = 0; j < J; ++j) {
+    const int64_t k = ks[j], at = base[j];
+    pad(offs[j], at);
+    pad(at + k, offs[j + 1]);
+    if (k <= 0) continue;
+    const int32_t s = sid[j];
+    const int64_t first = rows[j] * src_stride[s];
+    const uint8_t* b = reinterpret_cast<const uint8_t*>(src_b[s]) + first;
+    const uint8_t* q = reinterpret_cast<const uint8_t*>(src_q[s]) + first;
+    if (rc) {
+      for (int64_t i = 0; i < k; ++i) {
+        bt[at + i] = table[b[k - 1 - i]];
+        qt[at + i] = q[k - 1 - i];
+      }
+    } else {
+      for (int64_t i = 0; i < k; ++i) bt[at + i] = table[b[i]];
+      std::memcpy(qt + at, q, static_cast<size_t>(k));
+    }
+    if (src_width[s] == 8) {
+      counts(reinterpret_cast<const int64_t*>(src_d[s]) + first, k, dt + at);
+      counts(reinterpret_cast<const int64_t*>(src_e[s]) + first, k, et + at);
+    } else {
+      counts(reinterpret_cast<const int32_t*>(src_d[s]) + first, k, dt + at);
+      counts(reinterpret_cast<const int32_t*>(src_e[s]) + first, k, et + at);
+    }
   }
 }
 

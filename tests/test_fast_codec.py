@@ -9,6 +9,8 @@ from fgumi_tpu.cli import main
 from fgumi_tpu.io.bam import BamHeader, BamReader, BamWriter, RecordBuilder
 from fgumi_tpu.native import batch as nb
 from fgumi_tpu.simulate import simulate_codec_bam
+from codec_placement import (I16_MAX, PAD, PLACE_CASES, numpy_place,
+                             place_case)
 from record_batches import record_batches
 
 pytestmark = pytest.mark.skipif(not nb.available(),
@@ -736,15 +738,12 @@ def test_rx_on_bytes_equals_consensus_umis_batch(tmp_path, family):
     assert first.get_str(b"RX") == (want or None)
 
 
-def test_clip_overlap_failed_by_mask_equals_per_molecule():
-    """A fragment shorter than one of its strands (`ClipOverlapFailed`; no
-    all-M geometry gives it, so the table is built by hand): the batch
-    engine's mask and summed counts against the classic `_finish`, molecule
-    by molecule, among molecules that pass."""
-    import struct
-
-    from fgumi_tpu.consensus.codec import CodecConsensusCaller, CodecOptions
-    from fgumi_tpu.consensus.fast_codec import FastCodecCaller, _Molecules
+def _clip_overlap_table():
+    """Six hand-built molecules of 40-base strands, three of them in a
+    fragment shorter than a strand (`ClipOverlapFailed`; no all-M geometry
+    gives it): ``(mols, classic, codes_pk, quals_pk)``, the batch engine's
+    columns and the classic engine's molecules over the same pack rows."""
+    from fgumi_tpu.consensus.fast_codec import _Molecules
     from fgumi_tpu.consensus.vanilla import ConsensusJob, R1
 
     rng = np.random.default_rng(12)
@@ -788,7 +787,20 @@ def test_clip_overlap_failed_by_mask_equals_per_molecule():
             "r1_is_negative": r1_neg, "r2_is_negative": not r1_neg,
             "consensus_length": length})
         pk0 += 2 * n
+    return mols, classic, codes_pk, quals_pk
 
+
+def test_clip_overlap_failed_by_mask_equals_per_molecule():
+    """A fragment shorter than one of its strands (`ClipOverlapFailed`; no
+    all-M geometry gives it, so the table is built by hand): the batch
+    engine's mask and summed counts against the classic `_finish`, molecule
+    by molecule, among molecules that pass."""
+    import struct
+
+    from fgumi_tpu.consensus.codec import CodecConsensusCaller, CodecOptions
+    from fgumi_tpu.consensus.fast_codec import FastCodecCaller
+
+    mols, classic, codes_pk, quals_pk = _clip_overlap_table()
     want_caller = CodecConsensusCaller("fgumi", "A", CodecOptions())
     ss = want_caller.ss
     results = ss._run_jobs([j for m in classic
@@ -807,6 +819,182 @@ def test_clip_overlap_failed_by_mask_equals_per_molecule():
     assert wire_of(got) == b"".join(want)
     assert caller.stats.rejection_reasons == {"ClipOverlapFailed": 4 + 2 + 6}
     assert caller.stats == want_caller.stats
+
+
+# --------------------------------------------------------------- placement
+#
+# A strand's bases, qualities, depths and errors go from the row of the
+# result matrix that holds them to the oriented, padded per-molecule arrays
+# in one native ragged copy (`nb.codec_place`; ISSUE 41 / ROADMAP S14). The
+# numpy placement the engine ran before it lives on as the oracle
+# (`codec_placement.numpy_place`).
+
+@pytest.mark.parametrize("reverse", [False, True])
+@pytest.mark.parametrize("case", list(PLACE_CASES))
+def test_codec_place_equals_the_numpy_placement(case, reverse):
+    """Every output byte and every pad byte of the native ragged copy, on
+    memory that held something else before the call."""
+    from fgumi_tpu.consensus.fast_codec import (_BASE_OF_CODE,
+                                                _COMPLEMENT_OF_CODE)
+
+    table = _COMPLEMENT_OF_CODE if reverse else _BASE_OF_CODE
+    args = place_case(case) + (table, reverse, I16_MAX, PAD)
+    want = numpy_place(*args)
+    # dirty the allocator's free lists: `np.empty` must not be relied on
+    junk = [np.full(int(args[5][-1]), 0xAB, np.uint8) for _ in range(4)]
+    del junk
+    got = nb.codec_place(*args)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype
+        assert np.array_equal(g, w)
+    if case == "capped_over_i16_max":
+        assert got[2].max() == I16_MAX and got[3].max() == I16_MAX
+    if case == "molecules_of_length_one":
+        assert (np.diff(args[5]) == 1).all()
+
+
+@pytest.mark.parametrize("fault", ["row", "length", "before", "past",
+                                   "source", "absent_source"])
+def test_codec_place_refuses_a_strand_outside_its_source_or_molecule(fault):
+    sources, sid, rows, ks, base, offs = place_case("both_sources")
+    if fault == "row":
+        rows[5] = 23 if sid[5] == 0 else 31
+    elif fault == "length":
+        ks[5], base[5] = 65, offs[5]
+    elif fault == "before":
+        base[5] -= 1
+    elif fault == "past":
+        base[5] = offs[6] - ks[5] + 1
+    elif fault == "source":
+        sid[5] = 2
+    else:
+        sources[int(sid[5])] = None
+    from fgumi_tpu.consensus.fast_codec import _BASE_OF_CODE
+
+    with pytest.raises(ValueError, match="codec_place"):
+        nb.codec_place(sources, sid, rows, ks, base, offs, _BASE_OF_CODE,
+                       False, I16_MAX, PAD)
+
+
+@pytest.fixture
+def placement_checked(monkeypatch):
+    """Every `nb.codec_place` call of the engine is held to the numpy
+    placement; yields the calls' ``(sid, ks)``."""
+    real = nb.codec_place
+    calls = []
+
+    def checked(sources, sid, rows, ks, *rest):
+        got = real(sources, sid, rows, ks, *rest)
+        want = numpy_place(sources, sid, rows, ks, *rest)
+        assert all(np.array_equal(g, w) and g.dtype == w.dtype
+                   for g, w in zip(got, want))
+        calls.append((np.array(sid), np.array(ks)))
+        return got
+
+    monkeypatch.setattr(nb, "codec_place", checked)
+    return calls
+
+
+def test_clip_overlap_failed_molecules_leave_before_the_placement(
+        placement_checked):
+    """The three failed molecules of the hand table are taken out of the
+    columns before the call: each side places the other three, both sides
+    from the dense batch (two or three reads a strand) or the single-read
+    pass (one), the R2 side left-padded."""
+    from fgumi_tpu.consensus.codec import CodecConsensusCaller, CodecOptions
+    from fgumi_tpu.consensus.fast_codec import FastCodecCaller
+    from fgumi_tpu.observe.metrics import METRICS
+
+    mols, _, codes_pk, quals_pk = _clip_overlap_table()
+    before = METRICS.snapshot()
+    caller = CodecConsensusCaller("fgumi", "A", CodecOptions())
+    wire_of(FastCodecCaller(caller, b"MI")._run(mols, codes_pk, quals_pk))
+    assert [list(sid) for sid, _ in placement_checked] \
+        == [[0, 1, 0], [0, 1, 0]]
+    assert all((ks == 40).all() for _, ks in placement_checked)
+    after = METRICS.snapshot()
+    grew = lambda k: after.get(k, 0) - before.get(k, 0)
+    assert grew("codec.place.strands") == 6
+    assert grew("codec.place.cells") == 6 * 40
+
+
+@pytest.mark.parametrize("n_records", [10 ** 9, 7])
+def test_placement_on_the_mixed_stream(mixed_bam, placement_checked,
+                                       n_records):
+    """The engine's own calls on the mixed stream: strands from the dense
+    batch, from the single-read pass and, for the carried and the
+    soft-clipped molecule, materialised ones; the counters say what the
+    native pass placed."""
+    from fgumi_tpu.consensus.fast_codec import _SRC_ARRAYS
+
+    _, _, counters = _batch_engine(mixed_bam, _codec_options(), n_records)
+    sids = np.concatenate([sid for sid, _ in placement_checked])
+    assert set(np.minimum(sids, _SRC_ARRAYS)) == {0, 1, 2}
+    placed = counters["codec.emitted"] \
+        + counters.get("codec.rejected.HighDuplexDisagreement", 0)
+    assert counters["codec.place.strands"] == 2 * placed == len(sids)
+    assert counters["codec.place.cells"] \
+        == sum(int(ks.sum()) for _, ks in placement_checked)
+
+
+@pytest.mark.parametrize("per_base_tags", [False, True])
+def test_build_codec_records_reads_int32_rows(per_base_tags):
+    """`nb.build_codec_records` on the int32 depth and error rows that
+    `nb.codec_place` and `nb.codec_combine` leave, against the classic
+    `_build_record` a molecule; values past I16_MAX are capped in the tags,
+    an empty MI takes the counter's name, an RX is copied."""
+    import struct
+
+    from fgumi_tpu.consensus.codec import (_SS, CodecConsensusCaller,
+                                           CodecOptions)
+    from fgumi_tpu.io.bam import FLAG_UNMAPPED
+
+    rng = np.random.default_rng(19)
+    Ls = np.array([1, 2, 37, 150, 64, 5], dtype=np.int64)
+    offs = np.zeros(len(Ls) + 1, dtype=np.int64)
+    np.cumsum(Ls, out=offs[1:])
+    T = int(offs[-1])
+    u8 = lambda lo, hi: rng.integers(lo, hi, T).astype(np.uint8)
+    i32 = lambda: rng.integers(0, 2 * I16_MAX, T).astype(np.int32)
+    bases = lambda: np.frombuffer(b"ACGTNn", np.uint8)[rng.integers(0, 6, T)]
+    cb, b1, b2 = bases(), bases(), bases()
+    cq, q1, q2 = u8(0, 94), u8(0, 94), u8(0, 94)
+    ce, d1, e1, d2, e2 = i32(), i32(), i32(), i32(), i32()
+    d1[offs[3]:offs[4]] = 0   # a molecule with no depth at all: rate 0
+    d2[offs[3]:offs[4]] = 0
+    umis = ["7", "", "AAC-GGT", "12", "", "x" * 40]
+    rx = [b"ACGT-TTGA", b"", b"NNNN", b"", b"A", b"ACGT"]
+
+    caller = CodecConsensusCaller(
+        "fgumi", "A", CodecOptions(produce_per_base_tags=per_base_tags))
+    want = []
+    for j, (umi, r) in enumerate(zip(umis, rx)):
+        sl = slice(int(offs[j]), int(offs[j + 1]))
+        rec = caller._build_record(
+            _SS(cb[sl], cq[sl], d1[sl] + d2[sl], ce[sl], 4),
+            _SS(b1[sl], q1[sl], d1[sl], e1[sl], 2),
+            _SS(b2[sl], q2[sl], d2[sl], e2[sl], 2), umi or None, [], [],
+            rx_umis=[r.decode()] if r else [], number=j + 1)
+        want.append(struct.pack("<I", len(rec)) + rec)
+
+    names = [f"fgumi:{umi or j + 1}".encode() for j, umi in enumerate(umis)]
+    blob = np.frombuffer(b"".join(names) + b"".join(rx), dtype=np.uint8)
+    name_len = np.array([len(n) for n in names], dtype=np.int32)
+    name_addr = blob.ctypes.data + np.cumsum(name_len) - name_len
+    mi_len = np.array([len(u) if u else -1 for u in umis], dtype=np.int32)
+    rx_len = np.array([len(r) for r in rx], dtype=np.int32)
+    rx_addr = np.where(rx_len > 0, blob.ctypes.data + int(name_len.sum())
+                       + np.cumsum(rx_len) - rx_len, 0)
+    og = offs[:-1]
+    wire, rec_end = nb.build_codec_records(
+        cb.ctypes.data + og, cq.ctypes.data + og, ce.ctypes.data + 4 * og,
+        b1.ctypes.data + og, q1.ctypes.data + og, d1.ctypes.data + 4 * og,
+        e1.ctypes.data + 4 * og, b2.ctypes.data + og, q2.ctypes.data + og,
+        d2.ctypes.data + 4 * og, e2.ctypes.data + 4 * og, Ls, name_addr,
+        name_len, name_addr + len(b"fgumi:"), mi_len, rx_addr, rx_len,
+        caller.read_group_id.encode(), FLAG_UNMAPPED, per_base_tags)
+    assert wire == b"".join(want)
+    assert list(rec_end) == list(np.cumsum([len(w) for w in want]))
 
 
 # ---------------------------------------------------------------- hand-off

@@ -91,6 +91,17 @@ def test_native_suites_under_asan_ubsan(sanitized_env):
         f"sanitized run passed too few tests (skip fallback?):\n{tail}"
 
 
+def _run_tight(env, script, said):
+    """``script`` in a process that loads the sanitized library: it must
+    exit 0 having printed ``said``, with no sanitizer report."""
+    proc = subprocess.run([sys.executable, "-c", script], cwd=REPO,
+                          capture_output=True, text=True, timeout=300,
+                          env=env)
+    tail = (proc.stdout + "\n" + proc.stderr)[-4000:]
+    assert proc.returncode == 0 and said in proc.stdout, tail
+    assert "ERROR: AddressSanitizer" not in tail
+
+
 # the duplex error recount reads rows where they lie in the batch's packed
 # codes: the last listed row ends on the buffer's last byte, so a read of
 # `stride` bytes a row, or of a row past the list, is out of bounds
@@ -117,12 +128,7 @@ print("ranges ok")
 
 
 def test_depth_errors_ranges_tight_buffer_under_asan(sanitized_env):
-    proc = subprocess.run([sys.executable, "-c", _RANGES_TIGHT], cwd=REPO,
-                          capture_output=True, text=True, timeout=300,
-                          env=sanitized_env)
-    tail = (proc.stdout + "\n" + proc.stderr)[-4000:]
-    assert proc.returncode == 0 and "ranges ok" in proc.stdout, tail
-    assert "ERROR: AddressSanitizer" not in tail
+    _run_tight(sanitized_env, _RANGES_TIGHT, "ranges ok")
 
 
 # the alignment filter decodes CIGAR words where they lie, at odd offsets:
@@ -154,9 +160,31 @@ print("filter ok")
 
 
 def test_alignment_filter_tight_buffer_under_asan(sanitized_env):
-    proc = subprocess.run([sys.executable, "-c", _FILTER_TIGHT], cwd=REPO,
-                          capture_output=True, text=True, timeout=300,
-                          env=sanitized_env)
-    tail = (proc.stdout + "\n" + proc.stderr)[-4000:]
-    assert proc.returncode == 0 and "filter ok" in proc.stdout, tail
-    assert "ERROR: AddressSanitizer" not in tail
+    _run_tight(sanitized_env, _FILTER_TIGHT, "filter ok")
+
+
+# the CODEC placement reads a strand where it lies in a result matrix and
+# writes a molecule where it lies in the outputs: a strand as wide as its
+# matrix in the last row, a materialised strand exactly as long as its
+# arrays, reversed or not, int32 or int64 counts; a read or a write one
+# element past either end is out of bounds
+_PLACE_TIGHT = """
+import sys
+import numpy as np
+sys.path.insert(0, "tests")
+from codec_placement import I16_MAX, PAD, PLACE_CASES, numpy_place, place_case
+from fgumi_tpu.native import batch as nb
+
+assert nb.get_lib() is not None
+table = np.arange(256, dtype=np.uint8)[::-1].copy()
+for case in PLACE_CASES:
+    for reverse in (False, True):
+        args = place_case(case) + (table, reverse, I16_MAX, PAD)
+        got, want = nb.codec_place(*args), numpy_place(*args)
+        assert all(np.array_equal(g, w) for g, w in zip(got, want)), case
+print("place ok")
+"""
+
+
+def test_codec_place_tight_buffers_under_asan(sanitized_env):
+    _run_tight(sanitized_env, _PLACE_TIGHT, "place ok")

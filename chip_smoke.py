@@ -351,9 +351,8 @@ def leg_b(probe):
 def leg_c(probe):
     forced = {"FGUMI_TPU_ROUTE": "device"}
     legs = {
-        # the resident strand-combine route stays on the XLA kernels
         "C-duplex": (["duplex", "-i", w("duplex.bam"), "--min-reads", "1",
-                      "--threads", "4"], "xla"),
+                      "--threads", "4"], "pallas"),
         "C-codec": (["codec", "-i", w("codec.bam"), "--min-reads", "1",
                      "--threads", "4"], "pallas"),
         "C-filter": (["simplex", "-i", w("d.bam"), "--min-reads", "1",

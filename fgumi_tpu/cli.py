@@ -4152,7 +4152,7 @@ def _add_tune(sub):
         "tune",
         help="Measure this host's device/host crossovers on a simulated "
              "workload matrix and write a deployment profile (tuned "
-             "knobs + measured router/chooser priors, loaded via "
+             "knobs + measured router priors, loaded via "
              "--profile/FGUMI_TPU_PROFILE) plus a crossover atlas "
              "(docs/performance-tuning.md \"Deployment profiles\")")
     p.add_argument("-o", "--output", default="deploy_profile.json",
@@ -4246,7 +4246,7 @@ def build_parser():
         "--profile", default=None, metavar="PATH",
         help="load a deployment profile (fgumi-tpu tune output): tuned "
              "knob values fill any FGUMI_TPU_* vars not explicitly set "
-             "(explicit env/flags always win) and measured router/chooser "
+             "(explicit env/flags always win) and measured router "
              "priors seed the adaptive offload machinery so the first "
              "batch routes on the measured side of each crossover "
              "(also FGUMI_TPU_PROFILE; docs/performance-tuning.md)")

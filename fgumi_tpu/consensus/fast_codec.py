@@ -44,7 +44,7 @@ record counter, which names a molecule whose MI value is empty
 `codec.molecules` = `.emitted` + `.rejected` (`.rejected.<reason>`),
 `.slow_molecules`, `.row_molecules`, `.strands`, `.single_strands`,
 `.place.strands` / `.place.cells` (what the native placement copied),
-`.combine_cells_device` / `_host`, `.duplex_bases`, `.disagreements`; by
+`.combine_cells_host`, `.duplex_bases`, `.disagreements`; by
 chunk: `.stage2_batches`, `.stage2_off_thread` (docs/observability.md).
 """
 
@@ -215,8 +215,8 @@ class _CodecPending:
     rest on whichever thread run_stages resolves on: the fetch and unpack,
     the thresholds, stage 2 and the serialization. A chunk dropped
     unresolved (a failed run) hands its dispatch back, so the feeder slot
-    and the resident-byte accounting are not leaked, and publishes no
-    molecules, so a later chunk does not wait for it."""
+    is not leaked, and publishes no molecules, so a later chunk does not
+    wait for it."""
 
     __slots__ = ("_finish", "_discard", "_made_on", "_order", "_serial",
                  "_published")
@@ -258,9 +258,8 @@ class FastCodecCaller:
 
     def __init__(self, caller, tag: bytes = b"MI", mesh=None):
         """`mesh`: optional jax Mesh with (dp, sp) axes — the SS device
-        pass routes through the shard_map-wrapped wire kernels and the
-        concordance combine through the sharded elementwise variant. None
-        or a 1-device mesh = the legacy single-device path, bit for bit."""
+        pass routes through the shard_map-wrapped wire kernels. None or a
+        1-device mesh = the legacy single-device path, bit for bit."""
         self.caller = caller
         # device/host routing is per batch via the adaptive cost model
         # (ops/router.py; FGUMI_TPU_ROUTE / FGUMI_TPU_MAX_INFLIGHT handled
@@ -537,8 +536,8 @@ class FastCodecCaller:
 
         Runs on whichever thread resolves ``chunk``, several batches at
         once on a resolve pool: everything it writes is its own batch's but
-        the tallies (`CodecStats`' locked methods, METRICS), the locked
-        CODEC_COMBINE chooser and the record counter, which ``chunk``
+        the tallies (`CodecStats`' locked methods, METRICS) and the record
+        counter, which ``chunk``
         publishes to once the gates have said how many molecules it emits.
 
         A strand's result is row ``srow`` of source ``src`` in ``sources``:
@@ -599,39 +598,17 @@ class FastCodecCaller:
             METRICS.inc("codec.place.strands", 2 * J)
             METRICS.inc("codec.place.cells", int(slen.sum()))
 
-        # ---- duplex combine, one pass over the concatenated strands:
-        # device jit (ops/kernel._codec_combine_jit), native C pass, or
-        # numpy — all byte-identical (the classic combine_arrays stays the
-        # oracle). The concordance stage routes per batch through the
-        # shared adaptive-stage runner (FGUMI_TPU_CODEC_COMBINE).
-        import os
-
-        kernel = caller.ss.kernel
-        comb_env = os.environ.get("FGUMI_TPU_CODEC_COMBINE",
-                                  "auto").strip().lower()
-
-        def _host_combine():
+        # ---- duplex combine, one pass over the concatenated strands: the
+        # native C pass, or numpy without the library — byte-identical (the
+        # classic combine_arrays stays the oracle)
+        with _span("engine.codec.combine", rusage=True):
             if nb.available():
-                return nb.codec_combine(
+                res = nb.codec_combine(
                     b1, b2, q1, q2, d1, d2, e1, e2, MIN_PHRED, NO_CALL_BASE,
                     NO_CALL_BASE_LOWER, I16_MAX)
-            return combine_arrays(b1, b2, q1, q2, d1, d2, e1, e2)
-
-        with _span("engine.codec.combine", rusage=True) as sp:
-            side = "host"
-            if T > 0 and comb_env != "host" and not kernel.host_mode():
-                from ..ops.kernel import codec_combine_device
-                from ..ops.router import CODEC_COMBINE, run_adaptive_stage
-
-                res, side = run_adaptive_stage(
-                    CODEC_COMBINE, T, comb_env,
-                    lambda: codec_combine_device(b1, b2, q1, q2, d1, d2,
-                                                 e1, e2, mesh=self.mesh),
-                    _host_combine)
             else:
-                res = _host_combine()
-            sp.set(side=side)
-            METRICS.inc("codec.combine_cells_" + side, T)
+                res = combine_arrays(b1, b2, q1, q2, d1, d2, e1, e2)
+            METRICS.inc("codec.combine_cells_host", T)
         cb, cq, cd, ce, both, disag = res
 
         with _span("engine.codec.gates", rusage=True):

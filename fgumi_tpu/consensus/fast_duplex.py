@@ -140,7 +140,7 @@ class _DuplexPending:
     its batch: ordinals were reserved and the fallback molecules called at
     process time, and its tallies go through CallerStats' locked methods.
     A chunk dropped unresolved (a failed run) hands its dispatch back, so
-    the feeder slot and the resident-byte accounting are not leaked."""
+    the feeder slot is not leaked."""
 
     __slots__ = ("_finish", "_discard", "_made_on")
 
@@ -173,9 +173,8 @@ class FastDuplexCaller:
                  mesh=None):
         """`mesh`: optional jax Mesh with (dp, sp) axes — multi-read SS
         segments dispatch through the shard_map-wrapped full-column wire
-        kernels (same mesh compile path as the simplex caller, including
-        the resident fused strand combine). None or a 1-device mesh = the
-        legacy single-device path, bit for bit."""
+        kernels (same mesh compile path as the simplex caller). None or a
+        1-device mesh = the legacy single-device path, bit for bit."""
         self.caller = caller
         self.ss = caller.ss
         self.kernel = caller.ss.kernel
@@ -509,15 +508,10 @@ class FastDuplexCaller:
         slow = {}  # fallback molecule -> its wire bytes, filled below
 
         def finish():
-            tb, tq, d16, e16, ctx = finish_ss()
-            try:
-                return self._stage2(
-                    batch, span, gb, n_paired, slow, live_mol, seg_map,
-                    seg_len, tb, tq, d16, e16, codes, vrows, vstarts, L_max,
-                    ctx)
-            finally:
-                if ctx is not None:
-                    ctx["resident"].release()
+            tb, tq, d16, e16 = finish_ss()
+            return self._stage2(
+                batch, span, gb, n_paired, slow, live_mol, seg_map, seg_len,
+                tb, tq, d16, e16, codes, vrows, vstarts, L_max)
 
         chunk = _DuplexPending(finish, discard)
         # the per-molecule caller carries stats, the ordinal and the slow
@@ -594,18 +588,16 @@ class FastDuplexCaller:
 
         Returns (finish, discard). finish() completes it on the resolving
         thread: thresholded bases/quals and i16-clamped depth/error arrays,
-        (nseg, L_max) each, and the fused strand-combine context (None
-        unless the full-column device route kept stage-1 outputs
-        resident). discard() hands a wire dispatch back when finish() will
-        never run (None, or a no-op on the host route, when there is
-        none)."""
+        (nseg, L_max) each. discard() hands a wire dispatch back when
+        finish() will never run (None, or a no-op on the host route, when
+        there is none)."""
         opts = self.ss.options
         tb = np.zeros((nseg, L_max), dtype=np.uint8)
         tq = np.zeros((nseg, L_max), dtype=np.uint8)
         d16 = np.zeros((nseg, L_max), dtype=np.int32)
         e16 = np.zeros((nseg, L_max), dtype=np.int32)
         if not nseg:
-            return (lambda: (tb, tq, d16, e16, None)), None
+            return (lambda: (tb, tq, d16, e16)), None
 
         single = c1 == 1
         multi = np.nonzero(~single)[0]
@@ -622,12 +614,13 @@ class FastDuplexCaller:
                 d16[single] = np.minimum(d, I16_MAX).astype(np.int32)
                 # errors are zero for single-read consensus
         if not len(multi):
-            return (lambda: (tb, tq, d16, e16, None)), None
+            return (lambda: (tb, tq, d16, e16)), None
         counts_m = c1[multi]
         # vrows is in seg order: the multi-read segs' rows are a mask of it
         rows_m = vrows[np.repeat(~single, c1)]
 
-        def finish_with(w, q_, d, e, ctx):
+        def finish():
+            w, q_, d, e = pending.resolve()
             with _span("resolve.unpack", rusage=True):
                 b_m, q_m = oracle.apply_consensus_thresholds(
                     w, q_, d, opts.min_reads, opts.min_consensus_base_quality)
@@ -635,7 +628,7 @@ class FastDuplexCaller:
                 tq[multi] = q_m
                 d16[multi] = np.minimum(d, I16_MAX).astype(np.int32)
                 e16[multi] = np.minimum(e, I16_MAX).astype(np.int32)
-            return tb, tq, d16, e16, ctx
+            return tb, tq, d16, e16
 
         route = "host"
         if not self.kernel.host_mode():
@@ -648,60 +641,33 @@ class FastDuplexCaller:
                 devices=self.mesh.size if self.mesh is not None else 1)
         # host: the native f64 engine computes the batch at resolve time.
         # Device: the whole multi-seg pileup crosses the link once in the
-        # full-column wire layout; with the resident variant the
-        # thresholded outputs stay on device for the fused strand combine.
-        # One device packs the ragged rows in one native pass (fast.py
+        # full-column wire layout, the kernel simplex runs. One device
+        # packs the ragged rows in one native pass (fast.py
         # _pack_and_dispatch's choice). A > 1-device mesh runs the same
         # kernels shard_map-wrapped (families over dp, read rows over sp
-        # with one psum) from dense rows; the resident arrays then live
-        # sharded along dp and the combine's indices are mapped through
-        # the shard-order gather below.
-        import os
-
-        comb_env = os.environ.get("FGUMI_TPU_DUPLEX_COMBINE",
-                                  "auto").strip().lower()
-        resident = None if comb_env == "host" else \
-            (opts.min_reads, opts.min_consensus_base_quality)
+        # with one psum) from dense rows.
         if self.mesh is not None:
             pending = self.kernel.submit_dense(
                 lambda: (np.ascontiguousarray(codes[rows_m]),
                          np.ascontiguousarray(quals[rows_m])),
-                counts_m, route, mesh=self.mesh, resident_thresholds=resident)
+                counts_m, route, mesh=self.mesh)
         else:
-            pending = self.kernel.submit_ragged(
-                codes, quals, rows_m, L_max, counts_m, route,
-                resident_thresholds=resident)
-
-        def resolve():
-            w, q_, d, e, extras = pending.resolve(want_extras=True)
-            ctx = None
-            if extras["resident"] is not None:
-                seg_to_multi = np.full(nseg, -1, dtype=np.int64)
-                seg_to_multi[multi] = np.arange(len(multi))
-                ctx = {"resident": extras["resident"],
-                       "suspect": extras["suspect"],
-                       "seg_to_multi": seg_to_multi,
-                       "override": comb_env,
-                       # mesh dispatches: multi index -> row of the
-                       # shard-ordered resident arrays
-                       "gather": extras["gather"]}
-            return finish_with(w, q_, d, e, ctx)
-
-        return resolve, pending.discard
+            pending = self.kernel.submit_ragged(codes, quals, rows_m, L_max,
+                                                counts_m, route)
+        return finish, pending.discard
 
     # ---------------------------------------------------------------- stage 2
 
     def _stage2(self, batch, span, gb, n_paired, slow, live_mol, seg_map,
-                seg_len, tb, tq, d16, e16, codes, vrows, vstarts, L_max,
-                combine_ctx=None) -> bytes:
+                seg_len, tb, tq, d16, e16, codes, vrows, vstarts,
+                L_max) -> bytes:
         """Strand combination + serialization, molecule order preserved.
 
         Runs on whichever thread resolves the span's chunk, several spans
-        at once on a resolve pool: everything it writes is its own batch's,
-        but the tallies (CallerStats' locked methods, METRICS) and the
-        locked DUPLEX_COMBINE chooser. ``slow``: the wire bytes of the
-        span's fallback molecules by molecule index, called at process
-        time; they are only interleaved here."""
+        at once on a resolve pool: everything it writes is its own batch's
+        but the tallies (CallerStats' locked methods, METRICS). ``slow``:
+        the wire bytes of the span's fallback molecules by molecule index,
+        called at process time; they are only interleaved here."""
         caller = self.caller
         stats = caller.stats
 
@@ -731,7 +697,7 @@ class FastDuplexCaller:
         if K:
             fast_blob, rec_end = self._serialize_outputs(
                 batch, span, gb, reads, tb, tq, d16, e16, codes, vrows,
-                vstarts, L_max, combine_ctx)
+                vstarts, L_max)
             stats.add_consensus_reads(K)
         if not slow:
             return fast_blob
@@ -750,22 +716,15 @@ class FastDuplexCaller:
         return b"".join(parts)
 
     def _serialize_outputs(self, batch, span, gb, reads, tb, tq, d16, e16,
-                           codes, vrows, vstarts, L_max, combine_ctx=None):
-        """Combine + native-serialize the K fast output reads (order kept).
-
-        The strand combine runs either as numpy (the semantic reference) or
-        as the fused device stage over the stage-1 resident SS arrays
-        (``combine_ctx``; ops/kernel._duplex_combine_jit) — integer-exact
-        twins, chosen per batch by the adaptive cost model. Output rows
-        whose inputs carry an oracle patch (suspect positions) always take
-        the host combine: the resident arrays are pre-patch."""
+                           codes, vrows, vstarts, L_max):
+        """Combine + native-serialize the K fast output reads (order
+        kept)."""
         caller = self.caller
         kinds, aseg, lens = reads.kinds, reads.aseg, reads.lens
         K = len(kinds)
-        with _span("engine.duplex.combine", rusage=True) as sp:
+        with _span("engine.duplex.combine", rusage=True):
             out_b, out_q, out_e = self._combine_outputs(
-                reads, tb, tq, e16, codes, vrows, vstarts, L_max,
-                combine_ctx, sp)
+                reads, tb, tq, e16, codes, vrows, vstarts, L_max)
 
         # serializer strand inputs: 'a' side = dup.ab_consensus (aseg: the
         # alive / AB side, truncated to the combined length), 'b' side =
@@ -803,13 +762,12 @@ class FastDuplexCaller:
         return blob, rec_end
 
     def _combine_outputs(self, reads, tb, tq, e16, codes, vrows, vstarts,
-                         L_max, combine_ctx, sp):
+                         L_max):
         """The K output reads' bases, quals and errors, strand-combined
         or passed through. ``codes`` is the batch's packed rows and
         ``vrows`` the valid ones in seg order (seg s is
         ``vrows[vstarts[s]:vstarts[s + 1]]``): the error recount reads
-        them in place. ``sp`` is the ``engine.duplex.combine`` span this
-        runs in."""
+        them in place."""
         kinds, aseg, bseg, lens = reads.kinds, reads.aseg, reads.bseg, \
             reads.lens
         K = len(kinds)
@@ -819,11 +777,9 @@ class FastDuplexCaller:
         out_q = np.zeros((K, L_max), dtype=np.uint8)
         out_e = np.zeros((K, L_max), dtype=np.int32)
 
-        comb = np.nonzero(kinds == 2)[0]
-
-        def combine_host(sel):
-            """Numpy strand combine for output rows `sel` (the semantic
-            reference the device stage must match bit-for-bit)."""
+        sel = np.nonzero(kinds == 2)[0]  # the strand-combined output rows
+        METRICS.inc("duplex.combine_rows_host", len(sel))
+        if len(sel):
             ca, cb = aseg[sel], bseg[sel]
             a_b = tb[ca].astype(np.int32)
             b_b = tb[cb].astype(np.int32)
@@ -859,67 +815,6 @@ class FastDuplexCaller:
             errs[rb8 == N_CODE] = 0
             errs[~in_len] = 0
             out_e[sel] = np.minimum(errs, I16_MAX)
-
-        done_rows = np.empty(0, dtype=np.int64)
-        side = "host"
-        if len(comb) and combine_ctx is not None:
-            s2m = combine_ctx["seg_to_multi"]
-            ma = s2m[aseg[comb]]
-            mb = s2m[bseg[comb]]
-            eligible = (ma >= 0) & (mb >= 0)  # single-read segs: host-only
-            sus = combine_ctx["suspect"]
-            if sus is not None and eligible.any():
-                # any oracle-patched position on either strand sends the
-                # whole output row to the host combine (resident arrays
-                # are pre-patch; conservative over the full row width)
-                sus_row = sus.any(axis=1)
-                touched = eligible & (sus_row[np.maximum(ma, 0)]
-                                      | sus_row[np.maximum(mb, 0)])
-                METRICS.inc("duplex.suspect_rows", int(touched.sum()))
-                eligible &= ~touched
-            cand = comb[eligible]
-            if len(cand):
-                from ..ops.kernel import duplex_combine_device
-                from ..ops.router import DUPLEX_COMBINE, run_adaptive_stage
-
-                # mesh dispatches keep the resident arrays shard-ordered
-                # on device: remap multi indices through the gather instead
-                # of paying a device-side re-shuffle (single-device: rows
-                # ARE multi order, gather is None)
-                gather = combine_ctx.get("gather")
-                a_rows = s2m[aseg[cand]]
-                b_rows = s2m[bseg[cand]]
-                if gather is not None:
-                    a_rows = gather[a_rows]
-                    b_rows = gather[b_rows]
-
-                def _device_combine():
-                    ob, oq, oe = duplex_combine_device(
-                        combine_ctx["resident"], a_rows, b_rows,
-                        lens[cand])
-                    out_b[cand] = ob
-                    out_q[cand] = oq
-                    out_e[cand] = oe
-
-                _, side = run_adaptive_stage(
-                    DUPLEX_COMBINE, len(cand) * L_max,
-                    combine_ctx.get("override", "auto"),
-                    _device_combine, lambda: combine_host(cand))
-                done_rows = cand
-        on_device = len(done_rows) if side == "device" else 0
-        sp.set(side=side)
-        METRICS.inc("duplex.combine_rows_device", on_device)
-        METRICS.inc("duplex.combine_rows_host", len(comb) - on_device)
-        rest = np.setdiff1d(comb, done_rows)
-        if len(rest):
-            # suspect-touched / single-seg / no-resident rows: always the
-            # host combine (not a chooser sample — the cand subset is the
-            # measured apples-to-apples comparison)
-            combine_host(rest)
-        if combine_ctx is not None:
-            # the fused combine is done with the stage-1 resident arrays:
-            # release their device-byte accounting (ISSUE 11 satellite)
-            combine_ctx["resident"].release()
 
         passthrough = np.nonzero(kinds != 2)[0]
         if len(passthrough):

@@ -638,7 +638,6 @@ def build_report(command: str, argv, started_unix: float, wall_s: float,
                 "fingerprint_mismatch":
                     list(applied["fingerprint_mismatch"]),
                 "seeded_router": bool(applied["seeded_router"]),
-                "seeded_choosers": list(applied["seeded_choosers"]),
             }
     # span aggregate (v9): per-name count / wall / self time of every layer
     # span of this run, whenever spans were live

@@ -267,17 +267,6 @@ class DeviceBreaker:
             self._probe_claimed_at = self._now()
             return True
 
-    def blocked(self) -> bool:
-        """Non-claiming check: True when the device must not be used
-        (open, or half-open with the probe slot taken). Cheap enough for
-        the elementwise combine stages that bypass the router."""
-        if not self.enabled():
-            return False
-        with self._lock:
-            state = self._advance_locked()
-            return state == OPEN or (state == HALF_OPEN
-                                     and self._probe_inflight)
-
     # ------------------------------------------------------------ feeding
 
     def record_success(self):

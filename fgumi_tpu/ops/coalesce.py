@@ -13,7 +13,7 @@ Mechanics
 ---------
 
 - ``ConsensusKernel.device_call_segments_wire`` offers every plain (non-
-  resident, non-filter, non-mesh) wire dispatch to :meth:`DispatchCoalescer.
+  filter, non-mesh) wire dispatch to :meth:`DispatchCoalescer.
   maybe_submit`. While the window is armed, the batch is held for up to
   ``FGUMI_TPU_COALESCE_WINDOW_MS`` (default 2 ms; 0 disables) waiting for
   partners with the same merge key — same kernel variant (``full`` flag,
@@ -677,8 +677,7 @@ class DispatchCoalescer:
     # ------------------------------------------------------------ resolve
 
     def resolve_partner(self, kernel, ticket: CoalescedTicket, codes2d,
-                        quals2d, starts, split_depth: int = 0,
-                        want_extras: bool = False):
+                        quals2d, starts, split_depth: int = 0):
         """One partner's half of resolve_segments_wire: shared fetch,
         per-partner slice, unchanged host completion — or per-partner
         degrade over its own rows on any merged-dispatch failure."""
@@ -717,7 +716,7 @@ class DispatchCoalescer:
                                                starts64, split_depth)
             else:
                 raise failure
-            return out + (K._no_extras(),) if want_extras else out
+            return out
         base = group.seg_bases[ticket.index]
         j = partner.j
         if len(got) == 4:
@@ -728,8 +727,7 @@ class DispatchCoalescer:
             d_sl = e_sl = None
         return kernel._complete_wire_columns(
             qs[base:base + j], wp[base:base + j], d_sl, e_sl,
-            codes2d, quals2d, starts, want_extras=want_extras,
-            slot=partner.slot,
+            codes2d, quals2d, starts, slot=partner.slot,
             partner={"group": group.seq, "index": ticket.index,
                      "partners": len(group.partners)})
 

@@ -1717,7 +1717,7 @@ def _wire_terms(wire, dict_tab):
 
 def _wire_epilogue(wire, seg_ids, dict_tab, ln_error_pre_umi, num_segments):
     """Shared reduction+epilogue of every wire-layout segment kernel:
-    (N, L) wire rows -> (winner, qual, depth, errors, suspect, obs)."""
+    (N, L) wire rows -> (winner, qual, depth, errors, suspect)."""
     one_hot, delta = _wire_terms(wire, dict_tab)
     row_contrib = delta[..., None] * one_hot
     contrib = jax.ops.segment_sum(row_contrib, seg_ids,
@@ -1725,7 +1725,7 @@ def _wire_epilogue(wire, seg_ids, dict_tab, ln_error_pre_umi, num_segments):
                                   indices_are_sorted=True)
     obs = jax.ops.segment_sum(one_hot, seg_ids, num_segments=num_segments,
                               indices_are_sorted=True).astype(jnp.int32)
-    return _call_epilogue(contrib, obs, ln_error_pre_umi) + (obs,)
+    return _call_epilogue(contrib, obs, ln_error_pre_umi)
 
 
 def _packed2_terms(codes_packed, quals, correct_tab, err_tab):
@@ -1757,7 +1757,7 @@ def _packed2_epilogue(codes_packed, quals, seg_ids, correct_tab, err_tab,
                                   indices_are_sorted=True)
     obs = jax.ops.segment_sum(one_hot, seg_ids, num_segments=num_segments,
                               indices_are_sorted=True).astype(jnp.int32)
-    return _call_epilogue(contrib, obs, ln_error_pre_umi) + (obs,)
+    return _call_epilogue(contrib, obs, ln_error_pre_umi)
 
 
 def _wire_split_fn(wire, seg_ids, dict_tab, ln_error_pre_umi,
@@ -1765,7 +1765,7 @@ def _wire_split_fn(wire, seg_ids, dict_tab, ln_error_pre_umi,
     """Ragged-family consensus over the 1-byte wire layout with split packed
     output: (N, L) wire rows -> (out_segments, L) qs + (out_segments, L/4) wp.
     """
-    winner, qual, _depth, _errors, suspect, _obs = _wire_epilogue(
+    winner, qual, _depth, _errors, suspect = _wire_epilogue(
         wire, seg_ids, dict_tab, ln_error_pre_umi, num_segments)
     return _pack_result_split(winner, qual, suspect, out_segments)
 
@@ -1780,7 +1780,7 @@ def _wire_full_fn(wire, seg_ids, dict_tab, ln_error_pre_umi, num_segments,
     crosses the link once, as wire bytes. depth/errors fetch as uint16
     (+4 B/column); callers gate on max family size < 65536 (ROADMAP item 1,
     round 6)."""
-    winner, qual, depth, errors, suspect, _obs = _wire_epilogue(
+    winner, qual, depth, errors, suspect = _wire_epilogue(
         wire, seg_ids, dict_tab, ln_error_pre_umi, num_segments)
     qs, wp = _pack_result_split(winner, qual, suspect, out_segments)
     return (qs, wp, depth[:out_segments].astype(jnp.uint16),
@@ -1804,14 +1804,14 @@ class ResidentHandles:
     (copy_to_host_async over tree leaves) must never start copying these —
     they exist precisely so their bytes never cross the link.
 
-    Accounting (ISSUE 11 satellite): the arrays' device bytes were
-    invisible to every budget — a long duplex run could pin HBM with
-    stage-1 outputs the governor never saw. Construction now registers the
-    byte total with DeviceStats (``device.resident_bytes`` gauge + peak)
-    AND the device feeder's DynamicBudget byte gate, and every consumer
-    calls :meth:`release` when the fused stage has used (or abandoned) the
-    arrays — combine/fetch/degrade paths and the feeder's late-result
-    discard all release, so a wedge cannot leak the accounting."""
+    Accounting (ISSUE 11 satellite): construction registers the byte
+    total with DeviceStats (``device.resident_bytes`` gauge + peak) AND
+    the device feeder's DynamicBudget byte gate, and every consumer calls
+    :meth:`release` when the fused stage has used (or abandoned) the
+    arrays — the fused filter's gather/degrade paths and the feeder's
+    late-result discard all release, so a wedge cannot leak the
+    accounting. Today the one producer is the fused consensus→filter
+    kernel (consensus/device_filter.py)."""
 
     __slots__ = ("arrays", "nbytes", "_released")
 
@@ -1845,52 +1845,19 @@ def _release_residents(result):
             _release_residents(item)
 
 
-def _wire_resident_fn(wire, seg_ids, dict_tab, ln_error_pre_umi, min_reads,
-                      min_qual, num_segments, out_segments):
-    """Full-column wire kernel + device-resident thresholded outputs.
-
-    Beyond the full fetch tuple, returns (tb, tq, obs) sliced to
-    out_segments and kept on device for the fused duplex strand-combine
-    stage (_duplex_combine_jit): tb/tq apply the consensus thresholds
-    (oracle.apply_consensus_thresholds twin — depth < min_reads -> (N, 0),
-    qual < min_qual -> (N, MIN_PHRED)) and obs holds the per-lane
-    observation counts the combine's exact error recount needs. Suspect
-    positions differ from the host's oracle-patched values; the combine
-    resolve recomputes any output row touching one on host."""
-    winner, qual, depth, errors, suspect, obs = _wire_epilogue(
-        wire, seg_ids, dict_tab, ln_error_pre_umi, num_segments)
-    qs, wp = _pack_result_split(winner, qual, suspect, out_segments)
-    w_sl = winner[:out_segments]
-    q_sl = qual[:out_segments]
-    d_sl = depth[:out_segments]
-    low_depth = d_sl < min_reads
-    low_qual = q_sl < min_qual
-    tb = jnp.where(low_depth | low_qual, N_CODE, w_sl).astype(jnp.uint8)
-    tq = jnp.where(low_depth, 0,
-                   jnp.where(low_qual, MIN_PHRED, q_sl)).astype(jnp.uint8)
-    return (qs, wp, d_sl.astype(jnp.uint16),
-            errors[:out_segments].astype(jnp.uint16), tb, tq,
-            obs[:out_segments])
-
-
-_consensus_segments_wire_resident_jit = _lazy_jit(
-    static_argnames=_W_STATIC)(_wire_resident_fn)
-
-
 def _wire_filter_fn(wire, seg_ids, dict_tab, ln_error_pre_umi, min_reads_c,
                     min_qual_c, lens, f_min_reads, f_emin_tab, f_min_base_q,
                     f_per_base, num_segments, out_segments):
     """Fused consensus→filter wire kernel (ISSUE 11 tentpole).
 
     One dispatch computes the full consensus columns, applies the
-    consensus thresholds (apply_consensus_thresholds twin, as in the
-    resident kernel) AND the filter library's simplex per-base masks
-    (mask_bases twin) over them, and reduces everything the read-level
-    verdicts need to a 7-int32 stats row per read — the only thing fetched
-    home by default. The masked output columns (fb/fq), the raw
-    depth/error columns, and the pre-threshold packed winner/qual/suspect
-    words stay DEVICE-RESIDENT for the survivors-only gather
-    (:func:`ConsensusKernel.filter_gather_filtered` /
+    consensus thresholds (apply_consensus_thresholds twin) AND the filter
+    library's simplex per-base masks (mask_bases twin) over them, and
+    reduces everything the read-level verdicts need to a 7-int32 stats row
+    per read — the only thing fetched home by default. The masked output
+    columns (fb/fq), the raw depth/error columns, and the pre-threshold
+    packed winner/qual/suspect words stay DEVICE-RESIDENT for the
+    survivors-only gather (:func:`ConsensusKernel.filter_gather_filtered` /
     :meth:`ConsensusKernel.filter_resolve_suspect_rows`).
 
     Exactness: every per-base decision here is integer arithmetic —
@@ -1899,7 +1866,7 @@ def _wire_filter_fn(wire, seg_ids, dict_tab, ln_error_pre_umi, min_reads_c,
     the device mask can never disagree with ``mask_bases``. Stats columns:
     [max d16, sum d16, sum e16, sum qual, N-after-mask, newly-masked,
     any-suspect] with every reduction restricted to positions < lens."""
-    winner, qual, depth, errors, suspect, _obs = _wire_epilogue(
+    winner, qual, depth, errors, suspect = _wire_epilogue(
         wire, seg_ids, dict_tab, ln_error_pre_umi, num_segments)
     qs, wp = _pack_result_split(winner, qual, suspect, out_segments)
     w = winner[:out_segments]
@@ -1957,153 +1924,13 @@ def _filter_gather_raw_jit(qs, wp, d16, e16, idx, out_rows):
             d16[idx][:out_rows], e16[idx][:out_rows])
 
 
-@_lazy_jit(static_argnames=("out_rows",))
-def _duplex_combine_jit(tb, tq, obs, a_idx, b_idx, lens, out_rows):
-    """Fused duplex strand-combine over stage-1 resident SS arrays.
-
-    Integer-exact twin of the numpy combine in
-    fast_duplex._serialize_outputs (every op is int32 select/clip
-    arithmetic, so device and host agree bit-for-bit): gathers the AB/BA
-    thresholded rows by index, combines base/qual, and recounts the
-    per-position errors against the raw combined base from the resident
-    per-lane observation sums — the SS pileups never re-cross the link;
-    only the (K, L) combined outputs are fetched."""
-    a_b = tb[a_idx].astype(jnp.int32)
-    b_b = tb[b_idx].astype(jnp.int32)
-    a_q = tq[a_idx].astype(jnp.int32)
-    b_q = tq[b_idx].astype(jnp.int32)
-    agree = a_b == b_b
-    a_wins = (~agree) & (a_q > b_q)
-    b_wins = (~agree) & (b_q > a_q)
-    tie = (~agree) & (a_q == b_q)
-    raw_base = jnp.where(agree | a_wins, a_b, b_b)
-    raw_qual = jnp.where(
-        agree, jnp.clip(a_q + b_q, MIN_PHRED, MAX_PHRED),
-        jnp.where(a_wins, jnp.clip(a_q - b_q, MIN_PHRED, MAX_PHRED),
-                  jnp.where(b_wins, jnp.clip(b_q - a_q, MIN_PHRED, MAX_PHRED),
-                            MIN_PHRED)))
-    either_n = (a_b == N_CODE) | (b_b == N_CODE)
-    mask = either_n | (raw_qual == MIN_PHRED) | tie
-    L = tb.shape[1]
-    in_len = jnp.arange(L, dtype=jnp.int32)[None, :] < lens[:, None]
-    out_b = jnp.where(in_len & ~mask, raw_base, N_CODE)
-    out_b = jnp.where(in_len, out_b, 0)
-    out_q = jnp.where(in_len & ~mask, raw_qual, MIN_PHRED)
-    out_q = jnp.where(in_len, out_q, 0)
-    # exact per-base errors vs the pre-mask raw duplex base: per side,
-    # (valid obs) - (obs matching raw_base) == segment_depth_errors_ranges
-    rb_l = jnp.minimum(raw_base, 3)[..., None]
-    errs = jnp.zeros(a_b.shape, dtype=jnp.int32)
-    for idx in (a_idx, b_idx):
-        side = obs[idx]
-        depth = jnp.sum(side, axis=-1)
-        match = jnp.take_along_axis(side, rb_l, axis=-1)[..., 0]
-        errs = errs + (depth - match)
-    errs = jnp.where((raw_base == N_CODE) | ~in_len, 0, errs)
-    return (out_b[:out_rows].astype(jnp.uint8),
-            out_q[:out_rows].astype(jnp.uint8),
-            jnp.minimum(errs, _I16_MAX)[:out_rows].astype(jnp.int32))
-
-
-def _codec_combine_body(ba, bb, qa, qb, da, db, ea, eb):
-    """CODEC concordance/duplex combine math (elementwise int32 select
-    arithmetic end to end) — shared by the single-device jit and the
-    shard_map mesh variant (zero collectives: every output element depends
-    only on its own index)."""
-    from ..constants import NO_CALL_BASE, NO_CALL_BASE_LOWER
-
-    ba = ba.astype(jnp.int32)
-    bb = bb.astype(jnp.int32)
-    qa = qa.astype(jnp.int32)
-    qb = qb.astype(jnp.int32)
-    da = da.astype(jnp.int32)
-    db = db.astype(jnp.int32)
-    ea = ea.astype(jnp.int32)
-    eb = eb.astype(jnp.int32)
-    a_has = (ba != NO_CALL_BASE) & (ba != NO_CALL_BASE_LOWER)
-    b_has = (bb != NO_CALL_BASE) & (bb != NO_CALL_BASE_LOWER)
-    both = a_has & b_has
-    agree = both & (ba == bb)
-    a_wins = both & ~agree & (qa > qb)
-    b_wins = both & ~agree & (qb > qa)
-    tie = both & ~agree & (qa == qb)
-    raw_base = jnp.where(b_wins, bb, ba)
-    raw_qual = jnp.where(
-        agree, jnp.minimum(93, qa + qb),
-        jnp.where(a_wins, jnp.maximum(MIN_PHRED, qa - qb),
-                  jnp.where(b_wins, jnp.maximum(MIN_PHRED, qb - qa),
-                            jnp.where(tie, MIN_PHRED, 0))))
-    q_masked = both & (raw_qual == MIN_PHRED)
-    dup_base = jnp.where(q_masked, NO_CALL_BASE, raw_base)
-    dup_qual = jnp.where(q_masked, MIN_PHRED, raw_qual)
-    cap = lambda x: jnp.minimum(x, _I16_MAX)  # noqa: E731
-    dup_depth = cap(da) + cap(db)
-    chose_a = agree | a_wins | tie
-    dup_err = jnp.where(agree, ea + eb,
-                        jnp.where(chose_a, ea + jnp.maximum(db - eb, 0),
-                                  eb + jnp.maximum(da - ea, 0)))
-    only_a = a_has & ~b_has
-    only_b = b_has & ~a_has
-    a_q2 = qa == MIN_PHRED
-    b_q2 = qb == MIN_PHRED
-    base = jnp.where(
-        both, dup_base,
-        jnp.where(only_a, jnp.where(a_q2, NO_CALL_BASE, ba),
-                  jnp.where(only_b, jnp.where(b_q2, NO_CALL_BASE, bb),
-                            NO_CALL_BASE)))
-    qual = jnp.where(
-        both, dup_qual,
-        jnp.where(only_a & ~a_q2, qa,
-                  jnp.where(only_b & ~b_q2, qb, MIN_PHRED)))
-    depth = jnp.where(both, dup_depth,
-                      jnp.where(only_a, da, jnp.where(only_b, db, 0)))
-    errors = jnp.where(both, dup_err,
-                       jnp.where(only_a, ea,
-                                 jnp.where(only_b, eb, cap(ea + eb))))
-    n_mask = (ba == NO_CALL_BASE) | (bb == NO_CALL_BASE)
-    base = jnp.where(n_mask, NO_CALL_BASE, base)
-    qual = jnp.where(n_mask, MIN_PHRED, qual)
-    return (base.astype(jnp.uint8), qual.astype(jnp.uint8),
-            jnp.minimum(depth, 2 * _I16_MAX).astype(jnp.int32),
-            jnp.minimum(errors, _I16_MAX).astype(jnp.int32),
-            both, (a_wins | b_wins | tie))
-
-
-@_lazy_jit(static_argnames=("out_rows",))
-def _codec_combine_jit(ba, bb, qa, qb, da, db, ea, eb, out_rows):
-    """CODEC concordance/duplex combine as a device stage.
-
-    Integer-exact twin of consensus/codec.combine_arrays over the batch
-    engine's concatenated position arrays; inputs arrive post-oracle, so
-    there is no suspect surface — device output equals the numpy combine
-    bit-for-bit."""
-    out = _codec_combine_body(ba, bb, qa, qb, da, db, ea, eb)
-    return tuple(o[:out_rows] for o in out)
-
-
-@_lazy_jit(static_argnames=("mesh",))
-def _codec_combine_mesh_jit(ba, bb, qa, qb, da, db, ea, eb, mesh):
-    """Mesh variant of the CODEC combine: the position axis shards over
-    every mesh axis with explicit PartitionSpec rules — purely elementwise,
-    so the shard_map body is the single-device body verbatim and the wire
-    cost is one NamedSharding upload slice per device. The host slices the
-    fetched result to the real row count (no static out_rows: a fetch
-    slice would have to respect shard boundaries for no byte win)."""
-    from jax.sharding import PartitionSpec as P
-
-    spec = P(mesh.axis_names)
-    mapped = jax.shard_map(_codec_combine_body, mesh=mesh,
-                           in_specs=(spec,) * 8, out_specs=(spec,) * 6)
-    return mapped(ba, bb, qa, qb, da, db, ea, eb)
-
-
 def _packed2_split_fn(codes_packed, quals, seg_ids, correct_tab,
                       err_tab, ln_error_pre_umi, num_segments,
                       out_segments):
     """1.25 B/position fallback of the wire dispatch (batches with >63
     distinct quals): 2-bit packed codes + sentinel quals, split packed
     output + fetch slice."""
-    winner, qual, _depth, _errors, suspect, _obs = _packed2_epilogue(
+    winner, qual, _depth, _errors, suspect = _packed2_epilogue(
         codes_packed, quals, seg_ids, correct_tab, err_tab,
         ln_error_pre_umi, num_segments)
     return _pack_result_split(winner, qual, suspect, out_segments)
@@ -2113,7 +1940,7 @@ def _packed2_full_fn(codes_packed, quals, seg_ids, correct_tab, err_tab,
                      ln_error_pre_umi, num_segments, out_segments):
     """Full-column variant of the >63-distinct-quals fallback: same
     on-device depth/error counts as the full wire kernel."""
-    winner, qual, depth, errors, suspect, _obs = _packed2_epilogue(
+    winner, qual, depth, errors, suspect = _packed2_epilogue(
         codes_packed, quals, seg_ids, correct_tab, err_tab,
         ln_error_pre_umi, num_segments)
     qs, wp = _pack_result_split(winner, qual, suspect, out_segments)
@@ -2276,7 +2103,7 @@ def _consensus_segments_dp_sp_jit(codes, quals, seg_ids, correct_tab,
 
 def _wire_mesh_local(wire, seg_ids, dict_tab, ln_error_pre_umi, num_local):
     """Per-shard body of the mesh wire kernels: local segment reduction,
-    sp psum combine, shared epilogue. Returns the epilogue tuple + obs."""
+    sp psum combine, shared epilogue."""
     one_hot, delta = _wire_terms(wire, dict_tab)
     row_contrib = delta[..., None] * one_hot
     contrib = jax.ops.segment_sum(row_contrib, seg_ids,
@@ -2286,7 +2113,7 @@ def _wire_mesh_local(wire, seg_ids, dict_tab, ln_error_pre_umi, num_local):
                               indices_are_sorted=True)
     contrib = jax.lax.psum(contrib, "sp")
     obs = jax.lax.psum(obs, "sp").astype(jnp.int32)
-    return _call_epilogue(contrib, obs, ln_error_pre_umi) + (obs,)
+    return _call_epilogue(contrib, obs, ln_error_pre_umi)
 
 
 @_lazy_jit(static_argnames=("num_local", "mesh", "full"))
@@ -2304,7 +2131,7 @@ def _consensus_segments_wire_mesh_jit(wire, seg_ids, dict_tab,
     out = P("dp")
 
     def local(w, s):
-        winner, qual, depth, errors, suspect, _obs = _wire_mesh_local(
+        winner, qual, depth, errors, suspect = _wire_mesh_local(
             w, s, dict_tab, ln_error_pre_umi, num_local)
         qs, wp = _pack_result_split(winner, qual, suspect, num_local)
         if full:
@@ -2314,40 +2141,6 @@ def _consensus_segments_wire_mesh_jit(wire, seg_ids, dict_tab,
 
     mapped = jax.shard_map(local, mesh=mesh, in_specs=(rows, rows),
                            out_specs=(out,) * (4 if full else 2))
-    return mapped(wire, seg_ids)
-
-
-@_lazy_jit(static_argnames=("num_local", "mesh"))
-def _consensus_segments_wire_resident_mesh_jit(wire, seg_ids, dict_tab,
-                                               ln_error_pre_umi, min_reads,
-                                               min_qual, num_local, mesh):
-    """Mesh variant of the resident wire kernel: full-column outputs plus
-    device-resident thresholded (tb, tq) + per-lane obs, all sharded along
-    dp in the shard-ordered (dp * F_loc, ...) layout. The fused duplex
-    combine consumes the resident arrays through the ordinary jit
-    (_duplex_combine_jit) — XLA partitions its gathers over the mesh, the
-    pjit-style half of the compile path (SNIPPETS [1]/[3])."""
-    from jax.sharding import PartitionSpec as P
-
-    rows = P(mesh.axis_names)
-    out = P("dp")
-
-    def local(w, s):
-        winner, qual, depth, errors, suspect, obs = _wire_mesh_local(
-            w, s, dict_tab, ln_error_pre_umi, num_local)
-        qs, wp = _pack_result_split(winner, qual, suspect, num_local)
-        low_depth = depth < min_reads
-        low_qual = qual < min_qual
-        tb = jnp.where(low_depth | low_qual, N_CODE,
-                       winner).astype(jnp.uint8)
-        tq = jnp.where(low_depth, 0,
-                       jnp.where(low_qual, MIN_PHRED,
-                                 qual)).astype(jnp.uint8)
-        return (qs, wp, depth.astype(jnp.uint16),
-                errors.astype(jnp.uint16), tb, tq, obs)
-
-    mapped = jax.shard_map(local, mesh=mesh, in_specs=(rows, rows),
-                           out_specs=(out,) * 7)
     return mapped(wire, seg_ids)
 
 
@@ -2633,11 +2426,6 @@ def _predicted_s():
     return pred[0] if pred else None
 
 
-def _no_extras() -> dict:
-    """``want_extras``' fifth element where nothing stayed on the device."""
-    return {"suspect": None, "resident": None, "gather": None}
-
-
 class PendingSegments:
     """One submitted segment batch between its dispatch and its columns:
     what :meth:`ConsensusKernel.submit_ragged` / ``submit_dense`` return.
@@ -2657,18 +2445,15 @@ class PendingSegments:
         self.quals2d = quals2d
         self.starts = starts
 
-    def resolve(self, want_extras: bool = False):
+    def resolve(self):
         """(winner, qual, depth, errors) (J, L) arrays, exact on every
-        route; ``want_extras`` appends resolve_segments_wire's dict (all
-        None unless a resident dispatch completed on the device)."""
+        route."""
         k = self.kernel
         if self.ticket is HOST_DISPATCH:
-            out = k.resolve_segments(HOST_DISPATCH, self.codes2d,
-                                     self.quals2d, self.starts)
-            return out + (_no_extras(),) if want_extras else out
+            return k.resolve_segments(HOST_DISPATCH, self.codes2d,
+                                      self.quals2d, self.starts)
         return k.resolve_segments_wire(self.ticket, self.codes2d,
-                                       self.quals2d, self.starts,
-                                       want_extras=want_extras)
+                                       self.quals2d, self.starts)
 
     def resolve_filtered(self):
         """The fused-filter resolve (consensus/device_filter.py):
@@ -3020,8 +2805,7 @@ class ConsensusKernel:
         return codes_dev, quals_dev, seg_ids, starts, F_pad, N, None
 
     def submit_ragged(self, codes, quals, rows, L_max: int, counts,
-                      route: str, filter_params=None,
-                      resident_thresholds=None) -> PendingSegments:
+                      route: str, filter_params=None) -> PendingSegments:
         """Turn ragged rows into a dispatched batch (single device).
 
         ``rows`` index the batch's packed (R, L_stride) ``codes``/``quals``
@@ -3034,10 +2818,7 @@ class ConsensusKernel:
         (begin_in_flight), which it must agree with. ``filter_params``:
         device_call_segments_wire's fused consensus→filter selection (the
         caller gates it on every family being under 65536 rows); resolve
-        such a batch with :meth:`PendingSegments.resolve_filtered`.
-        ``resident_thresholds``: that call's (min_reads, min_qual) for the
-        fused duplex combine; dropped where a family of 65536 rows or more
-        rules the full-column kernel out."""
+        such a batch with :meth:`PendingSegments.resolve_filtered`."""
         if route == "host":
             # the native engine eats the batch CONCURRENTLY on the resolve
             # pool, so e2e throughput is device + host, not min of the two.
@@ -3057,14 +2838,13 @@ class ConsensusKernel:
             ticket = self.device_call_segments_wire(
                 codes_dev, quals_dev, seg_ids, F_pad, len(counts),
                 pack_t0=t_pack0, full=full,
-                resident_thresholds=resident_thresholds if full else None,
                 pred_s=_predicted_s(), filter_params=filter_params,
                 prebuilt=prebuilt)
             return PendingSegments(self, ticket, codes_dev[:N],
                                    quals_dev[:N], starts)
 
-    def submit_dense(self, gather, counts, route: str, mesh=None,
-                     resident_thresholds=None) -> PendingSegments:
+    def submit_dense(self, gather, counts, route: str,
+                     mesh=None) -> PendingSegments:
         """Turn dense rows into a dispatched batch (one device or a mesh).
 
         ``gather()`` returns the batch's dense (N, L) ``(codes2d,
@@ -3075,8 +2855,7 @@ class ConsensusKernel:
         layout where ``mesh`` has more than one device: the child span
         ``engine.pack.mesh_layout``, so the parent's self time is the
         caller's copies) under ``engine.pack.gather`` and dispatches
-        through device_call_segments_wire. ``resident_thresholds`` as in
-        :meth:`submit_ragged`."""
+        through device_call_segments_wire."""
         if route == "host":
             with span("engine.host_gather", rusage=True):
                 codes2d, quals2d = gather()
@@ -3099,15 +2878,13 @@ class ConsensusKernel:
                     order = None
             ticket = self.device_call_segments_wire(
                 cd, qd, seg_ids, F_pad, len(counts), pack_t0=t_pack0,
-                full=full,
-                resident_thresholds=resident_thresholds if full else None,
-                pred_s=_predicted_s(), mesh=mesh, mesh_gather=order)
+                full=full, pred_s=_predicted_s(), mesh=mesh,
+                mesh_gather=order)
         return PendingSegments(self, ticket, codes2d, quals2d, starts)
 
     def device_call_segments_wire(self, codes2d_padded, quals2d_padded,
                                   seg_ids, num_segments: int, J: int,
                                   pack_t0: float = None, full: bool = False,
-                                  resident_thresholds=None,
                                   pred_s: float = None, mesh=None,
                                   mesh_gather=None, filter_params=None,
                                   prebuilt=None):
@@ -3130,11 +2907,8 @@ class ConsensusKernel:
         computed on device and fetched as uint16 (+4 B/column), so the
         resolve never re-walks the dense rows — callers must gate on max
         family size < 65536 (the engines do, from their counts arrays).
-        ``resident_thresholds=(min_reads, min_qual)`` additionally keeps
-        thresholded (tb, tq) + per-lane obs device-resident for the fused
-        duplex combine stage (wire layout only; the rare >63-qual fallback
-        ignores it and the combine runs on host). ``pred_s``: the cost
-        model's predicted dispatch seconds, stamped into the timeline.
+        ``pred_s``: the cost model's predicted dispatch seconds, stamped
+        into the timeline.
 
         ``filter_params=(min_reads, min_qual, lens_padded, DeviceFilterParams)``
         selects the fused consensus→filter kernel (ISSUE 11): per-read
@@ -3164,9 +2938,8 @@ class ConsensusKernel:
         if mesh_active:
             return self._dispatch_wire_mesh(
                 codes2d_padded, quals2d_padded, seg_ids, num_segments, J,
-                t_pack0, full, resident_thresholds, pred_s, mesh,
-                mesh_gather)
-        if resident_thresholds is None and filter_params is None:
+                t_pack0, full, pred_s, mesh, mesh_gather)
+        if filter_params is None:
             # cross-job coalescing seam (ops/coalesce.py): while the serve
             # daemon's merge window is armed, compatible plain wire
             # dispatches from concurrent jobs merge into one device launch.
@@ -3187,8 +2960,7 @@ class ConsensusKernel:
                 return merged
         plan = self._wire_dispatch_plan(
             codes2d_padded, quals2d_padded, seg_ids, num_segments, J,
-            full=full, resident_thresholds=resident_thresholds,
-            filter_params=filter_params, prebuilt=prebuilt)
+            full=full, filter_params=filter_params, prebuilt=prebuilt)
         DEVICE_STATS.add_dispatch(segments_flops(
             codes2d_padded.shape[0], codes2d_padded.shape[1], num_segments))
         slot = DEVICE_STATS.begin_in_flight(
@@ -3211,8 +2983,7 @@ class ConsensusKernel:
 
     def _wire_dispatch_plan(self, codes2d_padded, quals2d_padded, seg_ids,
                             num_segments: int, J: int, full: bool = False,
-                            resident_thresholds=None, filter_params=None,
-                            prebuilt=None):
+                            filter_params=None, prebuilt=None):
         """Build — but do not submit — one wire-layout dispatch.
 
         The shared dispatch seam of the solo path and the cross-job
@@ -3243,19 +3014,17 @@ class ConsensusKernel:
         if w is not None:
             wire, dict32 = w
             upload = wire.nbytes + seg_ids.nbytes
-            resident = resident_thresholds is not None
             # ISSUE 19: the hand-tiled Pallas kernel covers the
-            # full-column and fused-filter wire dispatches; resident
-            # (duplex-combine), plain, packed2 and mesh stay XLA. The
-            # backend is pinned at plan-build time so the shape registry
-            # attributes compiles to the kernel that actually runs.
+            # full-column and fused-filter wire dispatches; plain, packed2
+            # and mesh stay XLA. The backend is pinned at plan-build time
+            # so the shape registry attributes compiles to the kernel that
+            # actually runs.
             use_pallas = False
-            if filt or (full and not resident):
+            if filt or full:
                 from . import pallas_kernel as _pk
 
                 use_pallas = _pk.selected_backend() == "pallas"
             kind = (("segwxp" if use_pallas else "segwx") if filt
-                    else "segwr" if resident
                     else (("segwfp" if use_pallas else "segwf") if full
                           else "segw"))
             dims = (wire.shape[0], wire.shape[1], num_segments, out_segments)
@@ -3264,9 +3033,6 @@ class ConsensusKernel:
                 windows = _pk.plan_windows(seg_ids, num_segments)
                 dims += (windows.w_tiles,)
             new = SHAPE_REGISTRY.observe(kind, *dims)
-            if resident:
-                mr, mq = (np.int32(resident_thresholds[0]),
-                          np.int32(resident_thresholds[1]))
             if filt:
                 mr, mq, lens_j, fparams = filter_params
                 lens_pad = np.zeros(out_segments, dtype=np.int32)
@@ -3304,11 +3070,6 @@ class ConsensusKernel:
                         np.int32(1 if fparams.per_base else 0),
                         num_segments, out_segments)
                     return (out[0], ResidentHandles(out[1:]))
-                if resident:
-                    out = _consensus_segments_wire_resident_jit(
-                        wd, sd, dtab, pre, mr, mq, num_segments,
-                        out_segments)
-                    return out[:4] + (ResidentHandles(out[4:]),)
                 fn = (_consensus_segments_wire_full_jit if full
                       else _consensus_segments_wire_jit)
                 return fn(wd, sd, dtab, pre, num_segments, out_segments)
@@ -3339,8 +3100,8 @@ class ConsensusKernel:
                          filt and w is not None)
 
     def _dispatch_wire_mesh(self, codes_g, quals_g, seg_g, F_loc: int,
-                            J: int, t_pack0: float, full: bool,
-                            resident_thresholds, pred_s, mesh, mesh_gather):
+                            J: int, t_pack0: float, full: bool, pred_s,
+                            mesh, mesh_gather):
         """The mesh half of device_call_segments_wire: NamedSharding
         uploads + the shard_map wire kernels (see the caller's docstring).
         Split out so the single-device fast path stays exactly the legacy
@@ -3357,13 +3118,9 @@ class ConsensusKernel:
         if w is not None:
             wire, dict32 = w
             upload = wire.nbytes + seg_g.nbytes
-            resident = resident_thresholds is not None
-            kind = "segwrm" if resident else ("segwfm" if full else "segwm")
             new = SHAPE_REGISTRY.observe(
-                kind, wire.shape[0], wire.shape[1], F_loc, dp, sp)
-            if resident:
-                mr, mq = (np.int32(resident_thresholds[0]),
-                          np.int32(resident_thresholds[1]))
+                "segwfm" if full else "segwm", wire.shape[0], wire.shape[1],
+                F_loc, dp, sp)
 
             def _dispatch(slot):
                 _ensure_jax()
@@ -3375,17 +3132,12 @@ class ConsensusKernel:
                                            sharding=repl_sh)
                 DEVICE_STATS.note_upload(slot, time.monotonic() - t0)
                 DEVICE_STATS.add_kernel_backend(slot, "xla")
-                if resident:
-                    out = _consensus_segments_wire_resident_mesh_jit(
-                        wd, sd, dtab, pre, mr, mq, F_loc, mesh)
-                    return out[:4] + (ResidentHandles(out[4:]),)
                 return _consensus_segments_wire_mesh_jit(
                     wd, sd, dtab, pre, F_loc, mesh, full)
         else:
             with span("engine.pack.wire", layout="packed2"):
                 cp, qsent = pack_codes2(codes_g, quals_g)
             upload = cp.nbytes + qsent.nbytes + seg_g.nbytes
-            tables_dev = self._tables_dev
             new = SHAPE_REGISTRY.observe(
                 "segp2fm" if full else "segp2m", cp.shape[0], cp.shape[1],
                 F_loc, dp, sp)
@@ -3427,17 +3179,13 @@ class ConsensusKernel:
 
     def resolve_segments_wire(self, ticket, codes2d: np.ndarray,
                               quals2d: np.ndarray, starts: np.ndarray,
-                              _split_depth: int = 0,
-                              want_extras: bool = False):
+                              _split_depth: int = 0):
         """Fetch + complete a device_call_segments_wire ticket.
 
         Same contract as resolve_segments: (winner, qual, depth, errors)
         (J, L) arrays, suspects recomputed exactly by the f64 oracle. A
         full-column dispatch carries device-computed depth/errors (no host
-        re-walk of the dense rows); a classic 2-tuple recomputes them here.
-        ``want_extras=True`` appends a 5th element: a dict with the raw
-        ``suspect`` mask and the ``resident`` device handles (both None on
-        any degraded path) for the fused duplex combine stage. A
+        re-walk of the dense rows); a classic 2-tuple recomputes them here. A
         dispatch/fetch failure that survived the feeder's bounded retry
         degrades instead of raising: RESOURCE_EXHAUSTED batches are halved
         and re-dispatched (output order preserved), anything else falls
@@ -3452,18 +3200,15 @@ class ConsensusKernel:
         if isinstance(ticket, CoalescedTicket):
             return COALESCER.resolve_partner(
                 self, ticket, codes2d, quals2d, starts,
-                split_depth=_split_depth, want_extras=want_extras)
+                split_depth=_split_depth)
         t0 = time.monotonic()
         fetched = 0
         failure = None
-        d16 = e16 = resident = None
+        d16 = e16 = None
         deadline = ticket_deadline_s(ticket)
         try:
             with span("resolve.wait", wait=True):
                 dev = ticket.wait(deadline)
-            if isinstance(dev[-1], ResidentHandles):
-                resident = dev[-1]
-                dev = dev[:-1]
             left = None if deadline is None else \
                 max(deadline - (time.monotonic() - t0), 1.0)
             got = _fetch_with_deadline(dev, left)
@@ -3498,11 +3243,6 @@ class ConsensusKernel:
             # only device weather is recoverable; KeyboardInterrupt /
             # SystemExit and INVALID_ARGUMENT-class programming errors
             # propagate (in-flight accounting above already balanced).
-            # A resident handle that made it out before the failure is
-            # dead weight — release its byte accounting now.
-            if resident is not None:
-                resident.release()
-                resident = None
             if isinstance(failure, DeadlineExceeded):
                 out = self._deadline_fallback_segments(failure, codes2d,
                                                        quals2d, starts)
@@ -3511,7 +3251,7 @@ class ConsensusKernel:
             else:
                 out = self._recover_segments(failure, codes2d, quals2d,
                                              starts, _split_depth)
-            return out + (_no_extras(),) if want_extras else out
+            return out
         from .breaker import BREAKER
 
         BREAKER.record_success()
@@ -3519,14 +3259,12 @@ class ConsensusKernel:
         with span("resolve.unpack", rusage=True):
             return self._complete_wire_columns(
                 qs, wp, d16, e16, codes2d, quals2d, starts,
-                want_extras=want_extras, resident=resident,
                 gather=ticket.mesh_gather, devices=ticket.mesh_devices,
                 f_loc=ticket.mesh_f_loc, slot=ticket.slot)
 
     def _complete_wire_columns(self, qs, wp, d16, e16,
                                codes2d: np.ndarray, quals2d: np.ndarray,
-                               starts, want_extras: bool = False,
-                               resident=None, gather=None, devices: int = 1,
+                               starts, gather=None, devices: int = 1,
                                f_loc=None, slot: int = -1, partner=None):
         """Host completion of fetched wire columns: unpack, depth/error
         counts, no-call restore, f64 oracle patch, shadow-audit tap.
@@ -3541,18 +3279,11 @@ class ConsensusKernel:
         if J == 0:
             L = qs.shape[-1]
             z = np.zeros((0, L))
-            out = (z.astype(np.uint8), z.astype(np.uint8),
-                   z.astype(np.int64), z.astype(np.int64))
-            if want_extras:
-                return out + ({"suspect": None, "resident": resident,
-                               "gather": None},)
-            return out
+            return (z.astype(np.uint8), z.astype(np.uint8),
+                    z.astype(np.int64), z.astype(np.int64))
         if gather is not None:
             # mesh dispatch: the fetched global arrays are shard-ordered
-            # (dp * F_loc rows); one host gather restores family order.
-            # The resident handles stay shard-ordered ON DEVICE — the
-            # duplex combine maps its indices through ``gather`` instead
-            # of paying a device-side re-shuffle.
+            # (dp * F_loc rows); one host gather restores family order
             with span("resolve.mesh_gather"):
                 qs = qs[gather]
                 wp = wp[gather]
@@ -3607,22 +3338,7 @@ class ConsensusKernel:
             devices=devices, gather=gather, f_loc=f_loc, slot=slot,
             partner=partner)
         if repaired is not None:
-            winner, qual, depth, errors = repaired
-            if resident is not None:
-                # device-resident columns from the same dispatch are as
-                # untrustworthy as the fetched result: drop them and let
-                # the combine stage take its host path
-                resident.release()
-                resident = None
-            if want_extras:
-                return winner, qual, depth, errors, _no_extras()
-        if want_extras:
-            return winner, qual, depth, errors, {"suspect": suspect,
-                                                 "resident": resident,
-                                                 "gather": gather}
-        if resident is not None:
-            # no consumer is coming for the resident arrays: release
-            resident.release()
+            return repaired
         return winner, qual, depth, errors
 
     # ------------------------------------------- fused consensus→filter
@@ -4076,10 +3792,10 @@ def route_and_call_segments(kernel: "ConsensusKernel", codes2d, quals2d,
     """Route one dense (N, L) segment batch through the adaptive offload
     policy and resolve it synchronously: the host f64 engine or the
     full-column wire kernel (sharded over ``mesh`` when one with > 1 device
-    is passed). Decide -> submit_dense -> resolve for the synchronous
-    callers (fast_codec, the classic vanilla path); the async engines
-    (simplex pending chunks, duplex defer/resident) call the same entries
-    and resolve later."""
+    is passed). Decide -> submit_dense -> resolve for the one synchronous
+    caller, the classic vanilla path; the async engines (simplex pending
+    chunks, duplex's deferred batches, codec) call the same entries and
+    resolve later."""
     from .router import ROUTER
 
     route = "host"
@@ -4089,111 +3805,3 @@ def route_and_call_segments(kernel: "ConsensusKernel", codes2d, quals2d,
             devices=mesh.size if mesh is not None else 1)
     return kernel.submit_dense(lambda: (codes2d, quals2d), counts, route,
                                mesh=mesh).resolve()
-
-
-# ------------------------------------------------------ fused device stages
-
-def duplex_combine_device(resident: "ResidentHandles", a_idx, b_idx, lens):
-    """Fused duplex strand-combine dispatch on stage-1 resident SS arrays.
-
-    a_idx/b_idx index rows of the resident (out_segments, L) arrays; lens
-    are the per-output combined lengths. Returns host
-    (out_b u8, out_q u8, out_e i32) arrays, byte-identical to the numpy
-    combine for rows whose inputs carry no oracle patch (the caller routes
-    suspect-touched rows to the host combine). Upload is just the three
-    index vectors; raises on device failure (caller falls back to host)."""
-    tb, tq, obs = resident.arrays
-    K = len(a_idx)
-    K_pad = SHAPE_REGISTRY.bucket(K, 8)
-    K_out = _pad_out_segments(K, K_pad)
-    ai = np.zeros(K_pad, dtype=np.int32)
-    bi = np.zeros(K_pad, dtype=np.int32)
-    ln = np.zeros(K_pad, dtype=np.int32)
-    ai[:K] = a_idx
-    bi[:K] = b_idx
-    ln[:K] = lens
-    L = int(tb.shape[1])
-    new = SHAPE_REGISTRY.observe("dupcomb", K_pad, L, K_out)
-    DEVICE_STATS.add_dispatch(K_pad * L * 24)
-    slot = DEVICE_STATS.begin_in_flight(ai.nbytes * 3)
-    t0 = time.monotonic()
-    try:
-        def _dispatch():
-            _ensure_jax()
-            return _duplex_combine_jit(tb, tq, obs, ai, bi, ln, K_out)
-
-        # attribute a first-sight-shape compile to the bucket miss, like
-        # every other dispatch site (warm-serve compiles==0 evidence)
-        with SHAPE_REGISTRY.attribute_compiles(new):
-            dev = device_retry_call(_dispatch, "duplex combine")
-        out_b, out_q, out_e = DEVICE_STATS.fetch(dev)
-        fetched = out_b.nbytes + out_q.nbytes + out_e.nbytes
-    except BaseException:
-        fetched = 0
-        raise
-    finally:
-        DEVICE_STATS.end_in_flight(slot, fetched, time.monotonic() - t0)
-    return out_b[:K], out_q[:K], out_e[:K]
-
-
-def codec_combine_device(ba, bb, qa, qb, da, db, ea, eb, mesh=None):
-    """CODEC concordance combine as a device dispatch.
-
-    Same contract as consensus/codec.combine_arrays over the batch
-    engine's concatenated 1-D position arrays (int32-capped inputs);
-    integer-exact vs the numpy version. Raises on device failure — the
-    caller falls back to the host combine. With a > 1-device ``mesh`` the
-    position axis shards over it: aligned padding keeps the global shape
-    evenly divisible, the eight operands upload as NamedSharding slices,
-    and the elementwise shard_map variant runs collective-free."""
-    import math
-
-    T = len(ba)
-    mesh_active = mesh is not None and mesh.size > 1
-    align = math.lcm(16, mesh.size) if mesh_active else 16
-    T_pad = SHAPE_REGISTRY.bucket(T, align)
-    T_out = T_pad if mesh_active else _pad_out_segments(T, T_pad)
-
-    def pad(a, dtype):
-        out = np.zeros(T_pad, dtype=dtype)
-        out[:T] = a
-        return out
-
-    ops = (pad(ba, np.uint8), pad(bb, np.uint8), pad(qa, np.uint8),
-           pad(qb, np.uint8), pad(da, np.int32), pad(db, np.int32),
-           pad(ea, np.int32), pad(eb, np.int32))
-    if mesh_active:
-        new = SHAPE_REGISTRY.observe("codeccombm", T_pad, mesh.size)
-    else:
-        new = SHAPE_REGISTRY.observe("codeccomb", T_pad, T_out)
-    DEVICE_STATS.add_dispatch(T_pad * 40)
-    slot = DEVICE_STATS.begin_in_flight(sum(o.nbytes for o in ops))
-    if mesh_active:
-        DEVICE_STATS.note_mesh(slot, mesh.size,
-                               sum(o.nbytes for o in ops) // mesh.size, 0)
-    t0 = time.monotonic()
-    try:
-        def _dispatch():
-            _ensure_jax()
-            if mesh_active:
-                from jax.sharding import NamedSharding, PartitionSpec as P
-
-                sh = NamedSharding(mesh, P(mesh.axis_names))
-                dev_ops = tuple(jax.device_put(o, sh) for o in ops)
-                return _codec_combine_mesh_jit(*dev_ops, mesh)
-            return _codec_combine_jit(*ops, T_out)
-
-        with SHAPE_REGISTRY.attribute_compiles(new):
-            dev = device_retry_call(_dispatch, "codec combine")
-        got = DEVICE_STATS.fetch(dev)
-        fetched = sum(g.nbytes for g in got)
-    except BaseException:
-        fetched = 0
-        raise
-    finally:
-        DEVICE_STATS.end_in_flight(slot, fetched, time.monotonic() - t0)
-    # .copy(): device_get may hand back read-only buffers and the codec
-    # quality-mask pass writes into cq in place
-    base, qual, depth, errors, both, disag = got
-    return (base[:T].copy(), qual[:T].copy(), depth[:T].copy(),
-            errors[:T].copy(), both[:T].copy(), disag[:T].copy())

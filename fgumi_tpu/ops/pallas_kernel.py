@@ -54,8 +54,9 @@ CPU host runs Mosaic interpret mode — the parity-test path; production
 CPU runs fall back to XLA so tier-1 latency is unchanged. The XLA kernels
 remain the permanent parity oracle. Covered dispatch kinds: the
 full-column wire kernel (``segwfp``) and the fused consensus→filter
-kernel (``segwxp``); resident/duplex, mesh, packed2-fallback, and gather
-dispatches stay XLA.
+kernel (``segwxp``) — simplex, duplex, codec and the chain all dispatch
+``segwfp`` on one device; mesh, packed2-fallback, and gather dispatches
+stay XLA.
 """
 
 import functools

@@ -28,11 +28,6 @@ compile-time constant. This module holds that decision in one place:
   samples), because the static priors below are a guess about hardware
   this process has not seen.
 
-- :class:`AdaptiveChooser` — the same idea for cheap elementwise stages
-  (the duplex strand-combine / CODEC concordance device stages): EWMA of
-  seconds-per-cell on each side, alternate probes until both sides are
-  measured, then pick the predicted winner with a periodic refresh probe.
-
 Env contract (docs/performance-tuning.md):
 
 - ``FGUMI_TPU_ROUTE=device|host|auto`` — force every batch to one side, or
@@ -530,123 +525,5 @@ class OffloadRouter:
             return out
 
 
-class AdaptiveChooser:
-    """Two-sided seconds-per-cell chooser for elementwise device stages.
-
-    Used by the duplex strand-combine and CODEC concordance stages: both
-    sides produce byte-identical output, so the chooser alternates probes
-    until each side has two samples, then picks the predicted winner with
-    a refresh probe every ``FGUMI_TPU_ROUTE_PROBE`` decisions. An env
-    override (passed per call: ``"device"``/``"host"``) always wins."""
-
-    def __init__(self, name: str):
-        self.name = name
-        self._lock = threading.Lock()
-        self._spc = {"device": _Ewma(), "host": _Ewma()}
-        self._streak_side = None
-        self._streak = 0
-
-    def observe(self, side: str, cells: int, seconds: float):
-        if cells > 0 and seconds >= 0:
-            with self._lock:
-                self._spc[side].add(seconds / cells)
-
-    def seed(self, device_s_per_mcell=None, host_s_per_mcell=None) -> bool:
-        """Install measured seconds-per-million-cells priors (profile
-        units match snapshot()). Seeded with samples=2 so the first
-        decide() picks the measured winner instead of alternating; cold
-        sides only, so live daemons keep their learned state."""
-        seeded = False
-        with self._lock:
-            for side, v in (("device", device_s_per_mcell),
-                            ("host", host_s_per_mcell)):
-                if v is not None and self._spc[side].samples == 0:
-                    self._spc[side].seed(v / 1e6, samples=2)
-                    seeded = True
-        return seeded
-
-    def export_state(self):
-        with self._lock:
-            return {side: e.export() for side, e in self._spc.items()}
-
-    def restore_state(self, state: dict) -> bool:
-        if not isinstance(state, dict):
-            return False
-        restored = False
-        with self._lock:
-            for side in ("device", "host"):
-                st = state.get(side)
-                if isinstance(st, dict) and st.get("value") is not None \
-                        and self._spc[side].samples == 0:
-                    self._spc[side].restore(st)
-                    restored = True
-        return restored
-
-    def decide(self, cells: int, override: str = "auto") -> str:
-        from ..observe.metrics import METRICS
-
-        if override in ("device", "host"):
-            METRICS.inc(f"device.route.{self.name}.{override}")
-            return override
-        probe = OffloadRouter._probe_period()
-        with self._lock:
-            d, h = self._spc["device"], self._spc["host"]
-            if d.samples < 2 or h.samples < 2:
-                # alternate until both sides are measured
-                side = "device" if d.samples <= h.samples else "host"
-            else:
-                side = "device" if d.value <= h.value else "host"
-                if probe and self._streak_side == side \
-                        and self._streak >= probe:
-                    side = "host" if side == "device" else "device"
-            if self._streak_side == side:
-                self._streak += 1
-            else:
-                self._streak_side, self._streak = side, 1
-        METRICS.inc(f"device.route.{self.name}.{side}")
-        return side
-
-    def snapshot(self):
-        with self._lock:
-            return {side: {"s_per_mcell": round(e.get(0.0) * 1e6, 6),
-                           "samples": e.samples}
-                    for side, e in self._spc.items()}
-
-
-def run_adaptive_stage(chooser: AdaptiveChooser, cells: int, override: str,
-                       device_fn, host_fn):
-    """Run one elementwise stage on the chooser's preferred side under the
-    shared degrade contract: whichever side runs is timed and fed to its
-    EWMA; a transient/OOM device failure is charged to the device side
-    (including its retry/backoff time — the chooser must learn, not
-    re-try a dead stage every batch), warned once per occurrence, and
-    falls back to ``host_fn``; non-device-weather errors re-raise.
-    Returns (result, side-that-produced-it)."""
-    import time
-
-    from .breaker import BREAKER
-    from .kernel import _is_oom, _is_transient, log
-
-    if cells > 0 and not BREAKER.blocked() \
-            and chooser.decide(cells, override) == "device":
-        t0 = time.monotonic()
-        try:
-            out = device_fn()
-            chooser.observe("device", cells, time.monotonic() - t0)
-            return out, "device"
-        except BaseException as e:  # noqa: BLE001 - classified below
-            if not (_is_oom(e) or _is_transient(e)):
-                raise
-            chooser.observe("device", cells, time.monotonic() - t0)
-            log.warning("%s device stage failed (%s: %s); using the host "
-                        "path", chooser.name, type(e).__name__, e)
-    t0 = time.monotonic()
-    out = host_fn()
-    chooser.observe("host", cells, time.monotonic() - t0)
-    return out, "host"
-
-
 #: process-wide singletons (measured rates are per-process facts)
 ROUTER = OffloadRouter()
-DUPLEX_COMBINE = AdaptiveChooser("duplex_combine")
-CODEC_COMBINE = AdaptiveChooser("codec_combine")

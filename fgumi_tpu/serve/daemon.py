@@ -618,12 +618,9 @@ class JobService:
 
         restored = router_mod.ROUTER.restore_state(
             state.get("router") or {}, source="snapshot")
-        for name, chooser in (("duplex_combine",
-                               router_mod.DUPLEX_COMBINE),
-                              ("codec_combine", router_mod.CODEC_COMBINE)):
-            if chooser.restore_state(
-                    (state.get("choosers") or {}).get(name) or {}):
-                restored = True
+        if "choosers" in state:
+            log.info("serve: routing snapshot %s: choosers ignored (the "
+                     "strand combines run on the host)", path)
         self.routing_state.update(loaded=bool(restored),
                                   saved_unix=state.get("saved_unix"))
         if restored:
@@ -633,8 +630,8 @@ class JobService:
         return restored
 
     def save_routing_state(self):
-        """Snapshot the live routing EWMAs (router incl. keep-rate,
-        choosers, the coalescer's effective window for the record) next
+        """Snapshot the live routing EWMAs (router incl. keep-rate, the
+        coalescer's effective window for the record) next
         to the journal on drain/close; crash-safe via the atomic-rename
         writer. The coalesce window needs no restore of its own — it is
         priced off the router's overhead EWMA, which the snapshot
@@ -651,11 +648,6 @@ class JobService:
             "schema_version": self.ROUTING_STATE_SCHEMA_VERSION,
             "saved_unix": int(time.time()),
             "router": router_mod.ROUTER.export_state(),
-            "choosers": {
-                "duplex_combine":
-                    router_mod.DUPLEX_COMBINE.export_state(),
-                "codec_combine": router_mod.CODEC_COMBINE.export_state(),
-            },
         }
         coal = sys.modules.get("fgumi_tpu.ops.coalesce")
         if coal is not None:

@@ -20,8 +20,7 @@ Outputs:
 - the **deployment profile** (:mod:`.profile`): knobs derived from the
   measured walls (coalesce window from the per-dispatch overhead, feeder
   depth from the wall/overhead ratio, mesh from the visible device
-  count) and priors from the post-sweep router snapshot + an elementwise
-  combine micro-bench for the two AdaptiveChoosers.
+  count) and priors from the post-sweep router snapshot.
 
 ``--replay`` skips the sweep and derives the same artifacts from
 recorded evidence instead: run-report ``device.routing`` sections and/or
@@ -182,43 +181,7 @@ def _crossover_depths(cells):
     return out
 
 
-def _bench_choosers(quick):
-    """Elementwise device-vs-host seconds-per-mcell for the two
-    AdaptiveChooser stages. The duplex/CODEC combines are elementwise
-    select/min kernels over (candidates, L) arrays; this times a
-    representative select+min on each side at a serve-realistic size —
-    a proxy for the real stages, measured, and orders of magnitude
-    better than the cold alternating probe."""
-    import numpy as np
-
-    from ..ops.kernel import _ensure_jax
-
-    jax = _ensure_jax()
-    import jax.numpy as jnp
-
-    n, L = (512, 100) if quick else (4096, 150)
-    cells = n * L
-    a = np.random.default_rng(5).integers(0, 41, size=(n, L),
-                                          dtype=np.uint8)
-    b = np.random.default_rng(6).integers(0, 41, size=(n, L),
-                                          dtype=np.uint8)
-
-    @jax.jit
-    def dev_combine(x, y):
-        return jnp.where(x == y, jnp.minimum(x, y) + 3,
-                         jnp.maximum(x, y) - jnp.minimum(x, y))
-
-    da, db = jnp.asarray(a), jnp.asarray(b)
-    dt_dev = _timeit(lambda: jax.block_until_ready(dev_combine(da, db)))
-    dt_host = _timeit(lambda: np.where(
-        a == b, np.minimum(a, b) + 3,
-        np.maximum(a, b) - np.minimum(a, b)))
-    pair = {"device_s_per_mcell": round(dt_dev / cells * 1e6, 6),
-            "host_s_per_mcell": round(dt_host / cells * 1e6, 6)}
-    return {"duplex_combine": dict(pair), "codec_combine": dict(pair)}
-
-
-def _derive_priors(cells, router_snap, choosers, keep_rates):
+def _derive_priors(cells, router_snap, keep_rates):
     """Profile priors from the post-sweep router snapshot, falling back
     to direct cell timings where a live EWMA never got fed."""
     router = {}
@@ -242,8 +205,6 @@ def _derive_priors(cells, router_snap, choosers, keep_rates):
         router["filter_keep_rate"] = round(
             sum(keep_rates) / len(keep_rates), 4)
     priors = {"router": {k: v for k, v in router.items() if v is not None}}
-    if choosers:
-        priors["choosers"] = choosers
     if cells:
         priors["crossover"] = [
             {"name": c["name"], "winner": c["winner"],
@@ -288,7 +249,7 @@ def _derive_knobs(router_priors, quick):
 
 def run_sweep(quick=False):
     """The in-process measurement pass. Returns (cells, router_snapshot,
-    chooser_priors, keep_rates)."""
+    keep_rates)."""
     import numpy as np
 
     from ..native import batch as nb
@@ -316,8 +277,7 @@ def run_sweep(quick=False):
                  depth, L)
         cells.append(_measure_cell(kernel, host, name, dist, depth, L,
                                    keep, ab, rng, n_rows))
-    return (cells, ROUTER.snapshot(), _bench_choosers(quick),
-            [m[4] for m in matrix])
+    return cells, ROUTER.snapshot(), [m[4] for m in matrix]
 
 
 # ----------------------------------------------------------------- replay
@@ -376,17 +336,14 @@ def run_autotune(profile_path, atlas_path=None, quick=False,
     fp = fingerprint_host(probe_jax=not replay_paths)
     if replay_paths:
         cells, router = derive_from_replay(replay_paths)
-        chooser_priors = {}
-        keep_rates = []
         source = "replay"
         priors = {"router": router}
         if cells:
             priors["crossover"] = cells
         router_snap = dict(router)
     else:
-        cells, router_snap, chooser_priors, keep_rates = run_sweep(quick)
-        priors = _derive_priors(cells, router_snap, chooser_priors,
-                                keep_rates)
+        cells, router_snap, keep_rates = run_sweep(quick)
+        priors = _derive_priors(cells, router_snap, keep_rates)
         source = "autotune"
     knobs = _derive_knobs(priors.get("router", {}), quick)
     profile = {

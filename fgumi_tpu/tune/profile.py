@@ -18,11 +18,11 @@ A profile is one JSON document produced by ``fgumi-tpu tune`` (or its
 - ``priors`` — measured starting points for the adaptive machinery:
   the :class:`~fgumi_tpu.ops.router.OffloadRouter` EWMAs (link rate,
   per-dispatch overhead, dispatch wall, host cells/s, fused-filter
-  keep rate, per-mesh-size overrides) and the
-  :class:`~fgumi_tpu.ops.router.AdaptiveChooser` seconds-per-mcell pairs.
-  Seeding is cold-only — live measurements always win — and stamps
-  ``prior_source="profile"`` into the router snapshot so first-batch
-  routing is attributable in any run report.
+  keep rate, per-mesh-size overrides). A ``choosers`` section from before
+  PR 42 is ignored with one log line. Seeding is cold-only — live
+  measurements always win — and stamps ``prior_source="profile"`` into
+  the router snapshot so first-batch routing is attributable in any run
+  report.
 
 Schema history:
 
@@ -54,7 +54,6 @@ KNOB_ENV = {
 
 _ROUTER_PRIOR_KEYS = ("link_mbps", "overhead_s", "dispatch_wall_s",
                       "host_mcells_per_s", "filter_keep_rate")
-_CHOOSER_NAMES = ("duplex_combine", "codec_combine")
 
 
 class ProfileError(ValueError):
@@ -149,19 +148,6 @@ def _validate_priors(priors):
         for k in ("link_mbps", "overhead_s", "dispatch_wall_s"):
             if mp.get(k) is not None:
                 _check_number(f"priors.router.mesh.{n}.{k}", mp[k], lo=0.0)
-    choosers = priors.get("choosers", {})
-    if not isinstance(choosers, dict):
-        raise _err("priors.choosers", choosers, "wrong type", "an object")
-    for name, cp in choosers.items():
-        if name not in _CHOOSER_NAMES:
-            raise _err("priors.choosers", name, "unknown chooser",
-                       "one of " + ", ".join(_CHOOSER_NAMES))
-        if not isinstance(cp, dict):
-            raise _err(f"priors.choosers.{name}", cp, "wrong type",
-                       "an object")
-        for k in ("device_s_per_mcell", "host_s_per_mcell"):
-            if cp.get(k) is not None:
-                _check_number(f"priors.choosers.{name}.{k}", cp[k], lo=0.0)
     crossover = priors.get("crossover", [])
     if not isinstance(crossover, list):
         raise _err("priors.crossover", crossover, "wrong type",
@@ -308,9 +294,11 @@ def apply_profile(profile, path="<inline>"):
     """Apply a validated profile to this process, once.
 
     Env knobs: filled only when the env var is unset (explicit env/flags
-    win — CLI flags act later and override the env either way). Router /
-    chooser priors: seeded cold-only. Returns the application record
-    (also stored for :func:`stamp_metrics`)."""
+    win — CLI flags act later and override the env either way). Router
+    priors: seeded cold-only. A ``priors.choosers`` section (written
+    before the device strand combines went) is ignored with one log line.
+    Returns the application record (also stored for
+    :func:`stamp_metrics`)."""
     global _APPLIED
     import logging
 
@@ -319,8 +307,7 @@ def apply_profile(profile, path="<inline>"):
         if _APPLIED is not None:
             return _APPLIED
         record = {"path": path, "applied": [], "skipped_explicit": [],
-                  "fingerprint_mismatch": [], "seeded_router": False,
-                  "seeded_choosers": []}
+                  "fingerprint_mismatch": [], "seeded_router": False}
         host_fp = fingerprint_host()
         diffs = fingerprint_mismatches(profile.get("fingerprint", {}),
                                        host_fp)
@@ -348,12 +335,9 @@ def apply_profile(profile, path="<inline>"):
         if _router.ROUTER.seed_priors(priors.get("router") or {},
                                       source="profile"):
             record["seeded_router"] = True
-        for name, chooser in (("duplex_combine", _router.DUPLEX_COMBINE),
-                              ("codec_combine", _router.CODEC_COMBINE)):
-            cp = (priors.get("choosers") or {}).get(name) or {}
-            if chooser.seed(cp.get("device_s_per_mcell"),
-                            cp.get("host_s_per_mcell")):
-                record["seeded_choosers"].append(name)
+        if "choosers" in priors:
+            log.info("profile %s: priors.choosers ignored (the strand "
+                     "combines run on the host)", path)
         _APPLIED = record
     log.info("profile %s: %d knob(s) applied (%s), %d explicit override(s)"
              ", router priors %s", path, len(record["applied"]),

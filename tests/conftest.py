@@ -95,6 +95,3 @@ def _reset_deployment_profile():
         router = sys.modules.get("fgumi_tpu.ops.router")
         if router is not None:
             router.ROUTER.reset()
-            for chooser in (router.DUPLEX_COMBINE, router.CODEC_COMBINE):
-                chooser._spc = {"device": router._Ewma(),
-                                "host": router._Ewma()}

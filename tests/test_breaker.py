@@ -35,7 +35,6 @@ def breaker(clock, monkeypatch):
 def test_starts_closed_and_allows(breaker):
     assert breaker.state == CLOSED
     assert breaker.allow()
-    assert not breaker.blocked()
 
 
 def test_transient_failures_trip_at_threshold(breaker):
@@ -45,7 +44,6 @@ def test_transient_failures_trip_at_threshold(breaker):
     breaker.record_transient_failure()
     assert breaker.state == OPEN
     assert not breaker.allow()
-    assert breaker.blocked()
 
 
 def test_success_resets_closed_score(breaker):
@@ -84,7 +82,6 @@ def test_half_open_single_probe_accounting(breaker, clock):
     # refused until the probe's outcome lands
     assert breaker.allow()
     assert not breaker.allow()
-    assert breaker.blocked()
     breaker.record_success()  # probe 1 of 2
     assert breaker.state == HALF_OPEN
     assert breaker.allow()
@@ -146,7 +143,6 @@ def test_disabled_breaker_never_blocks(breaker, monkeypatch):
     monkeypatch.setenv("FGUMI_TPU_BREAKER", "0")
     breaker.record_deadline_overrun()
     assert breaker.allow()
-    assert not breaker.blocked()
 
 
 def test_metrics_stamped_on_transition(breaker):

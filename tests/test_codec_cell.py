@@ -201,8 +201,7 @@ def test_molecule_counters_add_up(seed, strict, route):
     _config, _reference, data, _path = _cell(seed)
     if not strict:
         assert m["codec.single_strands"] == 2 * (data["sizes"] == 1).sum()
-    cells = m.get("codec.combine_cells_device", 0) \
-        + m.get("codec.combine_cells_host", 0)
+    cells = m["codec.combine_cells_host"]
     assert cells > 0
     if not strict:
         assert cells == data["insert"].sum()
@@ -214,8 +213,8 @@ def test_run_report_names_what_codec_does(route):
     by_name = report["spans"]["by_name"]
     assert [n for n in SPANS[route] if n not in by_name] == []
     assert [n for n in COUNTERS if n not in report["metrics"]] == []
-    side = "device" if route == "fast-device" else "host"
-    assert report["metrics"]["codec.combine_cells_" + side] > 0
+    assert report["metrics"]["codec.combine_cells_host"] > 0
+    assert "codec.combine_cells_device" not in report["metrics"]
     # what is a function of the stream stays on the processing thread
     # (MainThread here) with the pack and the dispatch; the fetch, the
     # thresholds and stage 2 run where the chunk resolves: the resolve

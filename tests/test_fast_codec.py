@@ -1150,12 +1150,8 @@ def _run_codec(monkeypatch, tmp_path, bam, name, route, threads, n_records,
 
 
 def _codec_counters(metrics):
-    """The engine's counters; the combine's cells as one sum, since the
-    chooser places them by what it measures."""
-    c = {k: v for k, v in metrics.items() if k.startswith("codec.")}
-    c["codec.combine_cells"] = c.pop("codec.combine_cells_host", 0) \
-        + c.pop("codec.combine_cells_device", 0)
-    return c
+    """The engine's counters."""
+    return {k: v for k, v in metrics.items() if k.startswith("codec.")}
 
 
 @pytest.fixture(scope="module")

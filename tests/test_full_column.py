@@ -4,8 +4,7 @@ Byte-identity of the full-column wire path (device-computed winner/qual/
 depth/errors per column) against the native f64 host engine across
 simplex/duplex/codec, at bucket-edge shapes and through the >63-distinct-
 qual fallback; forced-route parity (FGUMI_TPU_ROUTE=device|host produce
-identical bytes); fused duplex-combine and CODEC-concordance device stages
-vs their numpy twins; OffloadRouter policy unit tests.
+identical bytes); OffloadRouter policy unit tests.
 """
 
 import os
@@ -21,7 +20,7 @@ from fgumi_tpu.native import batch as nb  # noqa: E402
 from fgumi_tpu.ops import router as R  # noqa: E402
 from fgumi_tpu.ops.host_kernel import HostConsensusEngine  # noqa: E402
 from fgumi_tpu.ops.kernel import (ConsensusKernel, build_wire,  # noqa: E402
-                                  codec_combine_device, pad_segments_gather)
+                                  pad_segments_gather)
 from fgumi_tpu.ops.tables import quality_tables  # noqa: E402
 
 
@@ -107,33 +106,6 @@ def test_full_column_qual_dict_fallback(monkeypatch):
                                   np.asarray(eh, np.int64))
 
 
-def test_codec_combine_device_matches_numpy(monkeypatch):
-    """The CODEC concordance device stage is bit-identical to
-    combine_arrays on adversarial inputs (N bases both cases, ties,
-    single-strand, Q2 floors)."""
-    monkeypatch.setenv("FGUMI_TPU_HOST_ENGINE", "0")
-    from fgumi_tpu.consensus.codec import combine_arrays
-
-    rng = np.random.default_rng(5)
-    T = 1000
-    bases = np.frombuffer(b"ACGTNacgtn", np.uint8)
-    ba = rng.choice(bases, size=T)
-    bb = rng.choice(bases, size=T)
-    qa = rng.integers(0, 94, size=T).astype(np.uint8)
-    qb = rng.integers(0, 94, size=T).astype(np.uint8)
-    qa[rng.random(T) < 0.2] = 2  # MIN_PHRED floors
-    qb[rng.random(T) < 0.2] = 2
-    da = rng.integers(0, 40000, size=T).astype(np.int32)
-    db = rng.integers(0, 40000, size=T).astype(np.int32)
-    ea = rng.integers(0, 33000, size=T).astype(np.int32)
-    eb = rng.integers(0, 33000, size=T).astype(np.int32)
-    ref = combine_arrays(ba, bb, qa, qb, da, db, ea, eb)
-    got = codec_combine_device(ba, bb, qa, qb, da, db, ea, eb)
-    for i, (g, r) in enumerate(zip(got, ref)):
-        np.testing.assert_array_equal(np.asarray(g, np.int64),
-                                      np.asarray(r, np.int64), err_msg=str(i))
-
-
 # --------------------------------------------------------------- CLI parity
 
 def _simulate(tmp_path, what, args):
@@ -178,8 +150,7 @@ def test_forced_routes_byte_identical_simplex(tmp_path):
 @pytest.mark.slow
 @pytest.mark.skipif(not nb.available(), reason="native library required")
 def test_forced_routes_byte_identical_duplex(tmp_path):
-    """Duplex: forced routes AND both strand-combine sides (fused device
-    stage vs numpy) are byte-identical."""
+    """Duplex: the forced routes are byte-identical."""
     sim = _simulate(tmp_path, "duplex-reads",
                     ["--num-molecules", "150", "--reads-per-strand", "3",
                      "--seed", "31"])
@@ -187,20 +158,15 @@ def test_forced_routes_byte_identical_duplex(tmp_path):
         tmp_path, label, "duplex", sim,
         {"FGUMI_TPU_HOST_ENGINE": "0", **env})
         for label, env in (
-            ("device", {"FGUMI_TPU_ROUTE": "device",
-                        "FGUMI_TPU_DUPLEX_COMBINE": "device"}),
-            ("devhost", {"FGUMI_TPU_ROUTE": "device",
-                         "FGUMI_TPU_DUPLEX_COMBINE": "host"}),
+            ("device", {"FGUMI_TPU_ROUTE": "device"}),
             ("host", {"FGUMI_TPU_ROUTE": "host"}))}
     assert outs["device"] == outs["host"]
-    assert outs["device"] == outs["devhost"]
 
 
 @pytest.mark.slow
 @pytest.mark.skipif(not nb.available(), reason="native library required")
 def test_forced_routes_byte_identical_codec(tmp_path):
-    """CODEC: forced routes and the concordance device stage are
-    byte-identical."""
+    """CODEC: the forced routes are byte-identical."""
     sim = _simulate(tmp_path, "codec-reads",
                     ["--num-molecules", "200", "--pairs-per-molecule", "2",
                      "--read-length", "80", "--seed", "37"])
@@ -208,8 +174,7 @@ def test_forced_routes_byte_identical_codec(tmp_path):
         tmp_path, label, "codec", sim,
         {"FGUMI_TPU_HOST_ENGINE": "0", **env})
         for label, env in (
-            ("device", {"FGUMI_TPU_ROUTE": "device",
-                        "FGUMI_TPU_CODEC_COMBINE": "device"}),
+            ("device", {"FGUMI_TPU_ROUTE": "device"}),
             ("host", {"FGUMI_TPU_ROUTE": "host"}))}
     assert outs["device"] == outs["host"]
 
@@ -288,23 +253,3 @@ def test_router_probes_unmeasured_host(monkeypatch):
         r.observe_device(10_000_000, 1_000_000, 0.01, 0.001, 0.011)
     sides = {r.decide(_FakeKernel(), 1000, 1000, 1000) for _ in range(4)}
     assert "host" in sides
-
-
-def test_adaptive_chooser_alternates_then_settles(monkeypatch):
-    monkeypatch.setenv("FGUMI_TPU_ROUTE_PROBE", "0")
-    c = R.AdaptiveChooser("test_chooser")
-    # both sides unmeasured: probes alternate (each decide is followed by
-    # an observe of the chosen side, as the engines do)
-    first = []
-    for _ in range(4):
-        side = c.decide(1000)
-        first.append(side)
-        c.observe(side, 1000, 0.5 if side == "device" else 0.001)
-    assert set(first) == {"device", "host"}
-    for _ in range(3):
-        c.observe("device", 1000, 0.5)
-        c.observe("host", 1000, 0.001)
-    assert c.decide(1000) == "host"
-    assert c.decide(1000, override="device") == "device"
-    snap = c.snapshot()
-    assert snap["host"]["samples"] >= 2

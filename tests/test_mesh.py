@@ -305,7 +305,7 @@ def test_publish_mesh_gauges():
     assert got["device.mesh.devices"] == 8
 
 
-def _cli_mesh_parity(tmp_path, cmd, sim_path, extra_env=()):
+def _cli_mesh_parity(tmp_path, cmd, sim_path):
     """Byte parity of one engine CLI across FGUMI_TPU_MESH settings."""
     import os
 
@@ -314,22 +314,16 @@ def _cli_mesh_parity(tmp_path, cmd, sim_path, extra_env=()):
 
     def run(tag, mesh):
         out = str(tmp_path / f"{cmd}_{tag}.bam")
-        saved = {}
-        env = dict(extra_env)
-        if mesh is not None:
-            env["FGUMI_TPU_MESH"] = mesh
-        for k, v in env.items():
-            saved[k] = os.environ.get(k)
-            os.environ[k] = v
+        saved = os.environ.get("FGUMI_TPU_MESH")
+        os.environ["FGUMI_TPU_MESH"] = mesh
         try:
             assert main([cmd, "-i", sim_path, "-o", out,
                          "--min-reads", "1"]) == 0
         finally:
-            for k, v in saved.items():
-                if v is None:
-                    os.environ.pop(k, None)
-                else:
-                    os.environ[k] = v
+            if saved is None:
+                os.environ.pop("FGUMI_TPU_MESH", None)
+            else:
+                os.environ["FGUMI_TPU_MESH"] = saved
         with BamReader(out) as r:
             return [rec.data for rec in r]
 
@@ -343,10 +337,7 @@ def test_fast_duplex_mesh_byte_parity(tmp_path):
 
     sim = str(tmp_path / "dup.bam")
     simulate_duplex_bam(sim, num_molecules=120, reads_per_strand=3, seed=13)
-    # force the device strand combine so the sharded resident path (and
-    # its gather remap) is exercised, not just priced
-    _cli_mesh_parity(tmp_path, "duplex", sim,
-                     extra_env={"FGUMI_TPU_DUPLEX_COMBINE": "device"})
+    _cli_mesh_parity(tmp_path, "duplex", sim)
 
 
 def test_fast_codec_mesh_byte_parity(tmp_path):
@@ -356,8 +347,41 @@ def test_fast_codec_mesh_byte_parity(tmp_path):
     assert main(["simulate", "codec-reads", "-o", sim, "--num-molecules",
                  "150", "--pairs-per-molecule", "2", "--read-length", "60",
                  "--seed", "13"]) == 0
-    _cli_mesh_parity(tmp_path, "codec", sim,
-                     extra_env={"FGUMI_TPU_CODEC_COMBINE": "device"})
+    _cli_mesh_parity(tmp_path, "codec", sim)
+
+
+@pytest.mark.parametrize("mesh", ["off", "dp4xsp2"])
+def test_duplex_dispatches_the_simplex_plan_kind(tmp_path, monkeypatch,
+                                                 mesh):
+    """Duplex's device batches are the plain full-column wire dispatch
+    simplex makes — ``segwf`` on one device, ``segwfm`` on a mesh — with
+    nothing kept on the device for a strand combine."""
+    from fgumi_tpu.cli import main
+    from fgumi_tpu.ops.datapath import SHAPE_REGISTRY
+    from fgumi_tpu.ops.kernel import DEVICE_STATS
+    from fgumi_tpu.simulate import simulate_duplex_bam, simulate_grouped_bam
+
+    dup, sim = str(tmp_path / "dup.bam"), str(tmp_path / "sim.bam")
+    simulate_duplex_bam(dup, num_molecules=60, reads_per_strand=3, seed=17)
+    simulate_grouped_bam(sim, num_families=60, family_size=4,
+                         read_length=60, error_rate=0.02, seed=17)
+    for k, v in (("FGUMI_TPU_HOST_ENGINE", "0"), ("FGUMI_TPU_ROUTE", "device"),
+                 ("FGUMI_TPU_MESH", mesh)):
+        monkeypatch.setenv(k, v)
+    kinds = []
+    observe = SHAPE_REGISTRY.observe
+    monkeypatch.setattr(SHAPE_REGISTRY, "observe",
+                        lambda kind, *dims: kinds.append(kind)
+                        or observe(kind, *dims))
+    seen = {}
+    for cmd, inp in (("duplex", dup), ("simplex", sim)):
+        kinds.clear()
+        assert main([cmd, "-i", inp, "-o", str(tmp_path / f"{cmd}.out.bam"),
+                     "--min-reads", "1"]) == 0
+        seen[cmd] = set(kinds)
+    want = {"segwf" if mesh == "off" else "segwfm"}
+    assert seen["duplex"] == seen["simplex"] == want
+    assert DEVICE_STATS.resident_bytes == 0
 
 
 def test_fast_simplex_mesh_env_byte_parity(tmp_path):

@@ -433,33 +433,59 @@ def test_bktree_pairs_native():
         assert set(zip(i.tolist(), j.tolist())) == truth
 
 
-def test_codec_combine_matches_numpy_oracle():
+def _codec_combine_inputs(case, rng):
+    """(b1, b2, q1, q2, d1, d2, e1, e2) batches for the codec combine
+    parity test: ``random`` draws 20 batches of edge values (lowercase
+    pads, N masks, Q0/Q2, depths past I16_MAX); ``adversarial`` is one
+    1000-position batch over every base in both cases with uniform
+    qualities (ties), single-strand positions and a fifth of each side
+    floored at Q2 — the input the retired device combine was held to."""
+    if case == "random":
+        letters = np.array([ord(c) for c in "ACGTNn"], dtype=np.uint8)
+        for _ in range(20):
+            n = int(rng.integers(1, 2000))
+            yield (rng.choice(letters, size=n), rng.choice(letters, size=n),
+                   rng.choice([0, 2, 3, 20, 93], size=n).astype(np.uint8),
+                   rng.choice([0, 2, 3, 20, 93], size=n).astype(np.uint8),
+                   rng.integers(0, 70000, size=n).astype(np.int32),
+                   rng.integers(0, 70000, size=n).astype(np.int32),
+                   rng.integers(0, 40000, size=n).astype(np.int32),
+                   rng.integers(0, 40000, size=n).astype(np.int32))
+        return
+    T = 1000
+    bases = np.frombuffer(b"ACGTNacgtn", np.uint8)
+    ba = rng.choice(bases, size=T)
+    bb = rng.choice(bases, size=T)
+    qa = rng.integers(0, 94, size=T).astype(np.uint8)
+    qb = rng.integers(0, 94, size=T).astype(np.uint8)
+    qa[rng.random(T) < 0.2] = 2  # MIN_PHRED floors
+    qb[rng.random(T) < 0.2] = 2
+    yield (ba, bb, qa, qb,
+           rng.integers(0, 40000, size=T).astype(np.int32),
+           rng.integers(0, 40000, size=T).astype(np.int32),
+           rng.integers(0, 33000, size=T).astype(np.int32),
+           rng.integers(0, 33000, size=T).astype(np.int32))
+
+
+@pytest.mark.parametrize("case", ["random", "adversarial"])
+def test_codec_combine_matches_numpy_oracle(case):
     """fgumi_codec_combine must be bit-exact with consensus/codec.py
-    combine_arrays (the classic-path oracle) across adversarial inputs:
-    lowercase pads, N masks, Q0/Q2 edges, and depths past I16_MAX."""
+    combine_arrays (the classic-path oracle) — the one strand combine
+    codec runs."""
     from fgumi_tpu.consensus.codec import combine_arrays
     from fgumi_tpu.constants import (MIN_PHRED, NO_CALL_BASE,
                                      NO_CALL_BASE_LOWER)
     from fgumi_tpu.native import batch as nb
 
     rng = np.random.default_rng(5)
-    letters = np.array([ord(c) for c in "ACGTNn"], dtype=np.uint8)
-    for trial in range(20):
-        n = int(rng.integers(1, 2000))
-        b1 = rng.choice(letters, size=n)
-        b2 = rng.choice(letters, size=n)
-        q1 = rng.choice([0, 2, 3, 20, 93], size=n).astype(np.uint8)
-        q2 = rng.choice([0, 2, 3, 20, 93], size=n).astype(np.uint8)
-        d1 = rng.integers(0, 70000, size=n).astype(np.int32)
-        d2 = rng.integers(0, 70000, size=n).astype(np.int32)
-        e1 = rng.integers(0, 40000, size=n).astype(np.int32)
-        e2 = rng.integers(0, 40000, size=n).astype(np.int32)
-        ref = combine_arrays(b1, b2, q1, q2, d1, d2, e1, e2)
-        got = nb.codec_combine(b1, b2, q1, q2, d1, d2, e1, e2, MIN_PHRED,
-                               NO_CALL_BASE, NO_CALL_BASE_LOWER, 32767)
+    for trial, args in enumerate(_codec_combine_inputs(case, rng)):
+        ref = combine_arrays(*args)
+        got = nb.codec_combine(*args, MIN_PHRED, NO_CALL_BASE,
+                               NO_CALL_BASE_LOWER, 32767)
         for k, (r, g) in enumerate(zip(ref, got)):
-            np.testing.assert_array_equal(np.asarray(r), np.asarray(g),
-                                          err_msg=f"trial {trial} output {k}")
+            np.testing.assert_array_equal(
+                np.asarray(r, np.int64), np.asarray(g, np.int64),
+                err_msg=f"trial {trial} output {k}")
 
 
 def _depth_errors_numpy(codes, rows, winner, lo, hi):

@@ -16,8 +16,7 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from fgumi_tpu.ops.router import (AdaptiveChooser, OffloadRouter,  # noqa: E402
-                                  _Ewma)
+from fgumi_tpu.ops.router import OffloadRouter  # noqa: E402
 from fgumi_tpu.tune import profile as profmod  # noqa: E402
 from fgumi_tpu.tune.profile import (KNOB_ENV, ProfileError,  # noqa: E402
                                     fingerprint_host, load_profile,
@@ -45,8 +44,6 @@ def _isolated_profile_state(monkeypatch):
             os.environ[var] = old
     profmod.reset_applied_for_tests()
     router_mod.ROUTER.reset()
-    for chooser in (router_mod.DUPLEX_COMBINE, router_mod.CODEC_COMBINE):
-        chooser._spc = {"device": _Ewma(), "host": _Ewma()}
 
 
 def _profile(**over):
@@ -62,8 +59,6 @@ def _profile(**over):
                        "dispatch_wall_s": 0.02,
                        "host_mcells_per_s": 50.0,
                        "filter_keep_rate": 0.7},
-            "choosers": {"duplex_combine": {"device_s_per_mcell": 0.001,
-                                            "host_s_per_mcell": 0.004}},
         },
     }
     base.update(over)
@@ -96,7 +91,7 @@ def test_profile_round_trip(tmp_path):
     (lambda p: p["priors"].update(router={"link_mbps": -5}), "link_mbps"),
     (lambda p: p["priors"].update(
         router={"filter_keep_rate": 1.5}), "ceiling"),
-    (lambda p: p["priors"].update(choosers={"nope": {}}), "unknown chooser"),
+    (lambda p: p["priors"].update(crossover={}), "priors.crossover"),
     (lambda p: p["priors"].update(
         router={"mesh": {"0": {}}}), "device count"),
 ])
@@ -248,16 +243,58 @@ def test_seeding_is_cold_only():
     assert r.snapshot()["prior_source"] == "cold"
 
 
-def test_seeded_chooser_picks_winner_first_decide(monkeypatch):
-    monkeypatch.delenv("FGUMI_TPU_ROUTE_PROBE", raising=False)
-    cold = AdaptiveChooser("t_cold")
-    # cold: alternates until both sides have 2 samples
-    assert cold.decide(1000) == "device"
-    seeded = AdaptiveChooser("t_seeded")
-    assert seeded.seed(device_s_per_mcell=4.0, host_s_per_mcell=1.0)
-    assert seeded.decide(1000) == "host"
-    # cold-only
-    assert not seeded.seed(device_s_per_mcell=0.1)
+#: what the chooser priors / chooser state looked like in files written
+#: before the device strand combines went
+_OLD_CHOOSERS = {"duplex_combine": {"device_s_per_mcell": 0.001,
+                                    "host_s_per_mcell": 0.004},
+                 "codec_combine": {"device_s_per_mcell": 0.002}}
+
+
+@pytest.mark.parametrize("kind", ["profile", "routing_state"])
+def test_old_file_with_choosers_loads(tmp_path, caplog, kind):
+    """A profile or a daemon routing-state file carrying a ``choosers``
+    section (outside input, written before the device strand combines
+    were deleted) loads: the section is ignored with one log line naming
+    it, and the router half still seeds."""
+    from fgumi_tpu.ops import router as router_mod
+
+    if kind == "profile":
+        prof = _profile()
+        prof["priors"]["choosers"] = _OLD_CHOOSERS
+        path = tmp_path / "old_profile.json"
+        path.write_text(json.dumps(prof))
+        with caplog.at_level(logging.INFO, logger="fgumi_tpu"):
+            rec = profmod.apply_profile(load_profile(str(path)),
+                                        path=str(path))
+        assert rec["seeded_router"] is True
+    else:
+        from fgumi_tpu.serve.daemon import JobService
+
+        journal = str(tmp_path / "old.journal")
+        with open(journal + ".routing.json", "w") as fh:
+            json.dump({"schema_version":
+                       JobService.ROUTING_STATE_SCHEMA_VERSION,
+                       "saved_unix": 1700000000,
+                       "router": {"host_cps": {"value": 2e8, "samples": 3}},
+                       "choosers": {name: {"device": {"value": v,
+                                                      "samples": 2}}
+                                    for name, v in (("duplex_combine", 1e-9),
+                                                    ("codec_combine", 2e-9))}},
+                      fh)
+        svc = JobService(str(tmp_path / "old.sock"), workers=1,
+                         journal_path=journal)
+        with caplog.at_level(logging.INFO, logger="fgumi_tpu"):
+            svc.start()
+        try:
+            assert svc.routing_state["loaded"] is True
+            assert router_mod.ROUTER.snapshot()["host_mcells_per_s"] \
+                == pytest.approx(200.0)
+        finally:
+            svc.close()
+        router_mod.ROUTER.reset()
+    said = [r.getMessage() for r in caplog.records
+            if "choosers ignored" in r.getMessage()]
+    assert len(said) == 1 and "choosers" in said[0]
 
 
 def test_router_state_round_trip():
@@ -319,7 +356,7 @@ def test_profile_section_rides_run_report():
     assert sec["path"] == "prof.json"
     assert "feeder_depth" in sec["knobs_applied"]
     assert sec["seeded_router"] is True
-    assert sec["seeded_choosers"] == ["duplex_combine"]
+    assert "seeded_choosers" not in sec
 
 
 def test_stamp_metrics_in_current_registry():

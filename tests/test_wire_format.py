@@ -355,8 +355,7 @@ def submit_and_resolve(kernel, entry, codes, quals, counts, route="device",
     else:
         pending = kernel.submit_dense(lambda: (codes, quals), counts, route,
                                       **kwargs)
-    return pending.resolve(want_extras=bool(
-        kwargs.get("resident_thresholds")))
+    return pending.resolve()
 
 
 # every route a submitted batch can take x the entry that takes it there;
@@ -365,8 +364,7 @@ def submit_and_resolve(kernel, entry, codes, quals, counts, route="device",
 _SUBMIT_CASES = [
     ("host", "ragged"), ("host", "dense"),
     ("wire", "ragged"), ("wire", "dense"),
-    ("packed2", "ragged"), ("packed2", "dense"),
-    ("resident", "ragged"), ("resident", "dense"), ("mesh", "dense"),
+    ("packed2", "ragged"), ("packed2", "dense"), ("mesh", "dense"),
 ]
 
 
@@ -374,16 +372,13 @@ _SUBMIT_CASES = [
 def test_submit_resolve_parity(device_kernel, route, entry):
     """submit -> PendingSegments.resolve against the f64 oracle on the
     host route, the XLA wire kernel, the packed2 fallback (> 63 distinct
-    quals), the resident (duplex) kernel and a mesh of the 8 virtual CPU
-    devices."""
+    quals) and a mesh of the 8 virtual CPU devices."""
     rng = np.random.default_rng(13)
     wide = route == "packed2"
     codes, quals, counts, starts = make_ragged(
         rng, J=37, L=32, err=0.15, qlo=2, qhi=88 if wide else 45)
     assert (len(np.unique(quals)) > 63) == wide
     kwargs = {}
-    if route == "resident":
-        kwargs["resident_thresholds"] = (1, 2)
     if route == "mesh":
         import jax
 
@@ -398,24 +393,18 @@ def test_submit_resolve_parity(device_kernel, route, entry):
         route="host" if route == "host" else "device", rng=rng, **kwargs)
     assert DEVICE_STATS.snapshot()["dispatches"] - before == \
         (0 if route == "host" else 1)
-    if route == "resident":
-        extras = out[4]
-        assert extras["resident"] is not None
-        assert extras["suspect"].shape == (len(counts), codes.shape[1])
-        extras["resident"].release()
-    assert_oracle_parity(codes, quals, starts, *out[:4])
+    assert_oracle_parity(codes, quals, starts, *out)
     assert DEVICE_STATS.in_flight_count() == 0
 
 
 @pytest.mark.parametrize("case", ["native", "numpy", "wide_quals"])
-def test_submit_ragged_resident_equals_dense(device_kernel, monkeypatch,
-                                             case):
-    """The duplex request (``resident_thresholds``) through submit_ragged
-    is the one through submit_dense on the same rows: the same kernel on
-    the same shapes, equal columns, suspects and resident arrays. Also
+def test_submit_ragged_full_equals_dense(device_kernel, monkeypatch, case):
+    """The duplex request (the full-column kernel, nothing kept on the
+    device) through submit_ragged is the one through submit_dense on the
+    same rows: the same kernel on the same shapes, equal columns. Also
     where the native pass declines its inputs (numpy packs instead), and
-    with more than 63 distinct quals, where both entries drop the request
-    and dispatch the packed-codes layout."""
+    with more than 63 distinct quals, where both entries dispatch the
+    packed-codes layout."""
     from fgumi_tpu.native import batch as nb
     from fgumi_tpu.ops.datapath import SHAPE_REGISTRY
 
@@ -438,30 +427,17 @@ def test_submit_ragged_resident_equals_dense(device_kernel, monkeypatch,
     for entry in ("dense", "ragged"):
         if entry == "dense":
             pending = device_kernel.submit_dense(
-                lambda: (codes, quals), counts, "device",
-                resident_thresholds=(2, 10))
+                lambda: (codes, quals), counts, "device")
         else:
             pending = device_kernel.submit_ragged(
-                codes_pk, quals_pk, rows, L, counts, "device",
-                resident_thresholds=(2, 10))
-        *cols, extras = pending.resolve(want_extras=True)
-        resident = extras["resident"]
-        assert (resident is None) == wide
-        kept = []
-        if resident is not None:
-            kept = [np.asarray(a) for a in resident.arrays]
-            resident.release()
-        got[entry] = (cols, extras["suspect"], kept)
+                codes_pk, quals_pk, rows, L, counts, "device")
+        got[entry] = pending.resolve()
     assert shapes[0] == shapes[1] and len(shapes) == 2
-    assert shapes[0][0] == ("segp2f" if wide else "segwr")
-    (cols_d, sus_d, kept_d), (cols_r, sus_r, kept_r) = \
-        got["dense"], got["ragged"]
-    for a, b in zip(cols_d + [sus_d] + kept_d, cols_r + [sus_r] + kept_r,
-                    strict=True):
+    assert shapes[0][0] == ("segp2f" if wide else "segwf")
+    for a, b in zip(got["dense"], got["ragged"], strict=True):
         np.testing.assert_array_equal(a, b)
-    assert sus_r.shape == (len(counts), L)
-    assert len(kept_r) == (0 if wide else 3)
-    assert_oracle_parity(codes, quals, starts, *cols_r)
+    assert got["ragged"][0].shape == (len(counts), L)
+    assert_oracle_parity(codes, quals, starts, *got["ragged"])
     assert DEVICE_STATS.in_flight_count() == 0
     assert DEVICE_STATS.resident_bytes == 0
 
@@ -596,7 +572,6 @@ def boom(*a, **kw):
 # break every whole-batch device kernel the engines can route to
 K._consensus_segments_wire_jit = boom
 K._consensus_segments_wire_full_jit = boom
-K._consensus_segments_wire_resident_jit = boom
 K._consensus_segments_packed2_jit = boom
 K._consensus_segments_packed2_full_jit = boom
 from fgumi_tpu.cli import main

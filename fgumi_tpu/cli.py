@@ -931,6 +931,14 @@ def cmd_duplex(args):
                  ocs.bases_disagreeing, ocs.bases_corrected)
     if s.rejected:
         log.info("rejections: %s", dict(sorted(s.rejected.items())))
+    # the caller's account of every input read, in the run report too
+    from .observe.metrics import METRICS
+
+    METRICS.inc("duplex.input_reads", s.input_reads)
+    METRICS.inc("duplex.consensus_reads", n_out)
+    for reason, count in s.rejected.items():
+        METRICS.inc("duplex.reads.rejected." + reason, count)
+    caller.fold_filter_tally()
     if filter_tap is not None:
         _log_filter_stats(filter_tap.stats, "duplex")
     return 0

@@ -28,6 +28,7 @@ from ..constants import MAX_PHRED, MIN_PHRED, N_CODE
 from ..core.overlap import num_bases_extending_past_mate
 from ..io.bam import (FLAG_FIRST, FLAG_LAST, FLAG_PAIRED, FLAG_REVERSE,
                       RawRecord, RecordBuilder)
+from ..observe.metrics import METRICS
 from .simple_umi import consensus_umis
 from .rejects import RejectTracking
 from .vanilla import (CallerStats, I16_MAX, R1, R2, VanillaConsensusCaller,
@@ -217,6 +218,19 @@ class DuplexConsensusCaller(RejectTracking):
         self._init_rejects(track_rejects)
         self._builder = RecordBuilder()
         self._ordinal = 0
+        # the X/Y filter's runs on sets of two reads or more: sets, sets
+        # that kept every read, reads in, reads rejected (plain integers a
+        # molecule, folded into the run report once a job)
+        self.filter_tally = [0, 0, 0, 0]
+
+    def fold_filter_tally(self):
+        """The X/Y filter's tally into the run report's ``duplex.filter.*``
+        counters; nothing when the filter never ran."""
+        tally, self.filter_tally = self.filter_tally, [0, 0, 0, 0]
+        if tally[0]:
+            for name, count in zip(("sets", "sets_kept_all", "reads_in",
+                                    "reads_rejected"), tally):
+                METRICS.inc("duplex.filter." + name, count)
 
     def merged_stats(self) -> CallerStats:
         """Duplex-level stats plus SS-level rejections (e.g. MinorityAlignment
@@ -305,8 +319,18 @@ class DuplexConsensusCaller(RejectTracking):
                     prep_reject([r])
             return out
 
+        minority = 0  # reads the filter set aside: tallied there
+
         def filter_alignment(sources, raws_list):
+            nonlocal minority
             kept = self.ss._filter_by_alignment(sources)
+            if len(sources) >= 2:
+                tally = self.filter_tally
+                tally[0] += 1
+                tally[1] += len(kept) == len(sources)
+                tally[2] += len(sources)
+                tally[3] += len(sources) - len(kept)
+            minority += len(sources) - len(kept)
             if len(kept) < len(sources):
                 kept_idx = {sr.original_idx for sr in kept}
                 prep_reject(raws_list[sr.original_idx] for sr in sources
@@ -339,7 +363,10 @@ class DuplexConsensusCaller(RejectTracking):
             "ba_r1": [y_raws[sr.original_idx] for sr in f_ba_r1],
         }
         return {"base_mi": base_mi, "jobs": jobs, "raws": raws,
-                "n_records": len(a_records) + len(b_records),
+                # what a molecule-level reject counts: its reads but those
+                # already tallied as MinorityAlignment, as the rejects
+                # stream leaves them out (prep_ids)
+                "n_records": len(a_records) + len(b_records) - minority,
                 # molecule-failure rejects: only reads not already rejected
                 # during prep (built only when tracking)
                 "all_records": [r for r in list(a_records) + list(b_records)

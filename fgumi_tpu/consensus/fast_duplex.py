@@ -130,6 +130,23 @@ def output_read_columns(seg_map, seg_len, d16, live_mol, min_total, min_xy,
     return reads, emitted, full, ab_only, ba_only
 
 
+def _count_fallback(sizes, filtered, causes):
+    """A span's fallback set in the run report, a handful of counters a
+    batch: what the need rule sent (``duplex.molecules.filtered``, and their
+    records ``duplex.reads.filtered``) and ``duplex.slow_molecules.<reason>``,
+    each molecule under the first of ``causes`` ((reason, molecule mask or
+    one bool for all)) that holds for it."""
+    if filtered.any():
+        METRICS.inc("duplex.molecules.filtered", int(filtered.sum()))
+        METRICS.inc("duplex.reads.filtered", int(sizes[filtered].sum()))
+    taken = np.zeros(len(sizes), dtype=bool)
+    for reason, sel in causes:
+        first = sel & ~taken
+        if first.any():
+            METRICS.inc("duplex.slow_molecules." + reason, int(first.sum()))
+            taken |= first
+
+
 class _DuplexPending:
     """One span of a duplex batch between its dispatch and its bytes.
 
@@ -306,6 +323,7 @@ class FastDuplexCaller:
         METRICS.inc("duplex.slow_molecules")
         if not corrected:
             METRICS.inc("duplex.molecules")
+            METRICS.inc("duplex.molecules.carried")
         with _span("engine.duplex.slow_molecule", rusage=True):
             if self.overlap_caller is not None and not corrected \
                     and a_records and b_records:
@@ -365,16 +383,17 @@ class FastDuplexCaller:
 
             # molecule-level fallback: FIRST|LAST reads (belong to both X and Y
             # sets) and per-strand downsampling
-            fallback = np.zeros(nG, dtype=bool)
-            fl_both = paired & first & last
-            fallback[g_of_row[fl_both]] = True
+            first_last = np.zeros(nG, dtype=bool)
+            first_last[g_of_row[paired & first & last]] = True
+            fallback = first_last.copy()
             max_rs = self.ss.options.max_reads
-            if self.caller.track_rejects or self.ss.options.methylation_mode:
-                # methylation needs each read's CIGAR/position context for the
-                # reference annotation — the packed batch path strips it, so
-                # every molecule runs the classic per-molecule path (the same
-                # engineering choice as the simplex engine's _vector_ok gate)
-                fallback[:] = True
+            # methylation needs each read's CIGAR/position context for the
+            # reference annotation — the packed batch path strips it, so
+            # every molecule runs the classic per-molecule path (the same
+            # engineering choice as the simplex engine's _vector_ok gate)
+            every = bool(self.caller.track_rejects
+                         or self.ss.options.methylation_mode)
+            fallback |= every
 
             # per-row seg type (AB_R1..BA_R2); fragments and paired-but-neither
             # get -1
@@ -449,15 +468,18 @@ class FastDuplexCaller:
             seg_t = (seg_key & 3).astype(np.int8)
             c1 = np.bincount(seg_of_row, minlength=nseg).astype(np.int64)
             vstarts = np.concatenate(([0], np.cumsum(c1))).astype(np.int64)
+            downsample = np.zeros(nG, dtype=bool)
             if max_rs is not None and nseg and (c1 > max_rs).any():
-                fallback[seg_g[c1 > max_rs]] = True
+                downsample[seg_g[c1 > max_rs]] = True
 
             # alignment-filter analysis per X/Y set of each live molecule:
             # uniform CIGARs over the set's valid rows, with the mixed-strand
             # palindrome rule (fast.py _prepare_groups_vec)
+            filtered = np.zeros(nG, dtype=bool)
             if nseg:
-                self._need_filter_fallback(batch, span, vrows, g_of_row, t,
-                                           fallback, nG)
+                filtered[self._need_filter_fallback(batch, span, vrows,
+                                                    g_of_row, t)] = True
+            fallback |= downsample | filtered
             live_mol &= ~fallback
 
             # rejection tallies for non-fallback molecules
@@ -518,20 +540,27 @@ class FastDuplexCaller:
         # path's kernel calls, none of it safe on two threads: the fallback
         # set (final before the dispatch) goes through it here, in stream
         # order, while the device works on the rest
-        for g in np.nonzero(fallback)[0].tolist():
-            rows = span[gb[g]:gb[g + 1]]
-            sb_g = sb[gb[g]:gb[g + 1]]
-            caller._ordinal = ord0 + g
-            slow[g] = b"".join(self._call_slow_molecule(
-                self._base_mi(batch, int(rows[0])),
-                batch.raw_records(rows[~sb_g]), batch.raw_records(rows[sb_g]),
-                corrected=True))
+        fallen = np.nonzero(fallback)[0]
+        if len(fallen):
+            _count_fallback(sizes, filtered, (
+                ("first_last", first_last), ("all", every),
+                ("downsample", downsample), ("filter", filtered)))
+            with _span("engine.duplex.fallback", rusage=True,
+                       molecules=len(fallen)):
+                for g in fallen.tolist():
+                    rows = span[gb[g]:gb[g + 1]]
+                    sb_g = sb[gb[g]:gb[g + 1]]
+                    caller._ordinal = ord0 + g
+                    slow[g] = b"".join(self._call_slow_molecule(
+                        self._base_mi(batch, int(rows[0])),
+                        batch.raw_records(rows[~sb_g]),
+                        batch.raw_records(rows[sb_g]), corrected=True))
         caller._ordinal = ord0 + nG
         return [chunk]
 
-    def _need_filter_fallback(self, batch, span, vrows, g_of_row, t, fallback,
-                              nG):
-        """Mark molecules whose X or Y set would engage the alignment filter."""
+    def _need_filter_fallback(self, batch, span, vrows, g_of_row, t):
+        """The molecules whose X or Y set would engage the alignment
+        filter."""
         tt = t[vrows]
         setid = np.where((tt == AB_R1) | (tt == BA_R2), 0, 1)
         key = g_of_row[vrows] * 2 + setid
@@ -539,7 +568,7 @@ class FastDuplexCaller:
         srows = vrows[order]
         skey = key[order]
         if not len(skey):
-            return
+            return skey
         sfirst = np.concatenate(([True], skey[1:] != skey[:-1]))
         sstarts = np.append(np.nonzero(sfirst)[0], len(skey))
         set_g = skey[sfirst] >> 1
@@ -577,7 +606,7 @@ class FastDuplexCaller:
             simplified = cigar_utils.simplify(cig)
             if simplified != list(reversed(simplified)):
                 need[s] = True
-        fallback[set_g[need]] = True
+        return set_g[need]
 
     def _ss_consensus(self, codes, quals, vrows, c1, vstarts, nseg, L_max):
         """Start all segs' single-strand consensus: the single-read host

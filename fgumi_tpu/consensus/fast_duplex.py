@@ -9,9 +9,11 @@ native call (fgumi_build_duplex_records).
 Semantics contract: byte-identical output and identical rejection statistics
 to DuplexConsensusCaller.call_groups on the same stream (reference
 duplex_caller.rs:1755-2268; tested in tests/test_fast_duplex.py). Molecules
-the vectorized path cannot express (FIRST|LAST-flagged reads, per-strand
-downsampling, most-common-alignment filtering) fall back to the slow caller
-per molecule, in stream order.
+whose X/Y sets need the most-common-alignment filter stay on the vectorized
+path: the filter is one native pass a span (nb.alignment_filter) whose answer
+is a keep mask. Molecules the vectorized path cannot express (FIRST|LAST-
+flagged reads, per-strand downsampling, rejects tracking, methylation) fall
+back to the slow caller per molecule, in stream order.
 """
 
 import threading
@@ -20,6 +22,7 @@ from typing import NamedTuple
 import numpy as np
 
 from ..constants import MAX_PHRED, MIN_PHRED, N_CODE
+from ..core import cigar as cigar_utils
 from ..io.bam import (FLAG_FIRST, FLAG_LAST, FLAG_MATE_UNMAPPED, FLAG_PAIRED,
                       FLAG_REVERSE, FLAG_SECONDARY, FLAG_SUPPLEMENTARY,
                       FLAG_UNMAPPED)
@@ -27,7 +30,7 @@ from ..native import batch as nb
 from ..observe.metrics import METRICS
 from ..observe.trace import span as _span
 from ..ops import oracle
-from .fast import overlap_correct_span
+from .fast import FastSimplexCaller, _ranges, overlap_correct_span
 from .simple_umi import _ACGTN_UPPER, consensus_umis_batch
 from .vanilla import I16_MAX, R1, R2, _TYPE_FLAGS
 
@@ -130,16 +133,11 @@ def output_read_columns(seg_map, seg_len, d16, live_mol, min_total, min_xy,
     return reads, emitted, full, ab_only, ba_only
 
 
-def _count_fallback(sizes, filtered, causes):
-    """A span's fallback set in the run report, a handful of counters a
-    batch: what the need rule sent (``duplex.molecules.filtered``, and their
-    records ``duplex.reads.filtered``) and ``duplex.slow_molecules.<reason>``,
-    each molecule under the first of ``causes`` ((reason, molecule mask or
-    one bool for all)) that holds for it."""
-    if filtered.any():
-        METRICS.inc("duplex.molecules.filtered", int(filtered.sum()))
-        METRICS.inc("duplex.reads.filtered", int(sizes[filtered].sum()))
-    taken = np.zeros(len(sizes), dtype=bool)
+def _count_fallback(n, causes):
+    """A span's fallback set in the run report: ``duplex.slow_molecules.
+    <reason>``, each of its ``n`` molecules under the first of ``causes``
+    ((reason, molecule mask or one bool for all)) that holds for it."""
+    taken = np.zeros(n, dtype=bool)
     for reason, sel in causes:
         first = sel & ~taken
         if first.any():
@@ -450,8 +448,9 @@ class FastDuplexCaller:
                 rev.astype(np.uint8), clips,
                 self.ss.options.min_input_base_quality, stride)
 
-            # seg construction over valid rows of live molecules (dead
-            # molecules -- failed gates/validation -- need no conversion)
+            # the valid rows of live molecules in seg order (dead molecules
+            # -- failed gates/validation -- need no conversion); per-strand
+            # downsampling is decided on their counts as they come
             live_mol = gate_ok & ~coll & (n_paired > 0) & ~fallback
             valid = (final_len > 0) & (t >= 0) & live_mol[g_of_row]
             er = np.nonzero(valid)[0]
@@ -459,6 +458,41 @@ class FastDuplexCaller:
             order = np.argsort(key, kind="stable")
             vrows = er[order]
             skey = key[order]
+            downsample = np.zeros(nG, dtype=bool)
+            if max_rs is not None and len(skey):
+                seg_key, c1 = np.unique(skey, return_counts=True)
+                downsample[seg_key[c1 > max_rs] >> 2] = True
+                if downsample.any():
+                    fallback |= downsample
+                    live_mol &= ~downsample
+                    on = live_mol[g_of_row[vrows]]
+                    vrows, skey = vrows[on], skey[on]
+
+            # the X/Y most-common-alignment filter on every molecule the
+            # need rule marks, both its sets in one native pass; a rejected
+            # row leaves vrows before the segments are made of them
+            filtered = np.zeros(nG, dtype=bool)
+            n_kept = n_paired  # the paired reads the filter kept
+            if len(vrows):
+                srows, sstarts, set_g, need = self._filter_need(
+                    batch, span, vrows, g_of_row, t)
+                filtered[set_g[need]] = True
+            if filtered.any():
+                METRICS.inc("duplex.molecules.filtered", int(filtered.sum()))
+                METRICS.inc("duplex.reads.filtered",
+                            int(sizes[filtered].sum()))
+                drop = self._filter_sets(batch, span, rev, final_len, srows,
+                                         sstarts, filtered[set_g])
+                if len(drop):
+                    stats.reject("MinorityAlignment", len(drop))
+                    n_kept = n_paired - np.bincount(g_of_row[drop],
+                                                    minlength=nG)
+                    rejected = np.zeros(len(span), dtype=bool)
+                    rejected[drop] = True
+                    on = ~rejected[vrows]
+                    vrows, skey = vrows[on], skey[on]
+
+            # the segments of what is left: every one is a live molecule's
             seg_first = np.concatenate(([True], skey[1:] != skey[:-1])) \
                 if len(skey) else np.empty(0, dtype=bool)
             seg_of_row = (np.cumsum(seg_first) - 1) if len(skey) else skey
@@ -468,19 +502,6 @@ class FastDuplexCaller:
             seg_t = (seg_key & 3).astype(np.int8)
             c1 = np.bincount(seg_of_row, minlength=nseg).astype(np.int64)
             vstarts = np.concatenate(([0], np.cumsum(c1))).astype(np.int64)
-            downsample = np.zeros(nG, dtype=bool)
-            if max_rs is not None and nseg and (c1 > max_rs).any():
-                downsample[seg_g[c1 > max_rs]] = True
-
-            # alignment-filter analysis per X/Y set of each live molecule:
-            # uniform CIGARs over the set's valid rows, with the mixed-strand
-            # palindrome rule (fast.py _prepare_groups_vec)
-            filtered = np.zeros(nG, dtype=bool)
-            if nseg:
-                filtered[self._need_filter_fallback(batch, span, vrows,
-                                                    g_of_row, t)] = True
-            fallback |= downsample | filtered
-            live_mol &= ~fallback
 
             # rejection tallies for non-fallback molecules
             vec = ~fallback
@@ -506,11 +527,11 @@ class FastDuplexCaller:
                     METRICS.inc("duplex.rejected." + reason, int(sel.sum()))
                     METRICS.inc("duplex.rejected", int(sel.sum()))
 
-            # molecule -> seg map for live molecules
+            # molecule -> seg map for live molecules; a seg the filter
+            # emptied is absent, and stage 2 decides the molecule on the
+            # rest (_combine_molecule's arms)
             seg_map = np.full((nG, 4), -1, dtype=np.int64)
-            if nseg:
-                lm = live_mol[seg_g]
-                seg_map[seg_g[lm], seg_t[lm]] = np.nonzero(lm)[0]
+            seg_map[seg_g, seg_t] = np.arange(nseg)
 
             # this span's ordinal range, one a molecule in stream order (the
             # simplex engine's _group_ordinal discipline): the fallback
@@ -532,7 +553,7 @@ class FastDuplexCaller:
         def finish():
             tb, tq, d16, e16 = finish_ss()
             return self._stage2(
-                batch, span, gb, n_paired, slow, live_mol, seg_map, seg_len,
+                batch, span, gb, n_kept, slow, live_mol, seg_map, seg_len,
                 tb, tq, d16, e16, codes, vrows, vstarts, L_max)
 
         chunk = _DuplexPending(finish, discard)
@@ -542,9 +563,8 @@ class FastDuplexCaller:
         # order, while the device works on the rest
         fallen = np.nonzero(fallback)[0]
         if len(fallen):
-            _count_fallback(sizes, filtered, (
-                ("first_last", first_last), ("all", every),
-                ("downsample", downsample), ("filter", filtered)))
+            _count_fallback(nG, (("first_last", first_last), ("all", every),
+                                 ("downsample", downsample)))
             with _span("engine.duplex.fallback", rusage=True,
                        molecules=len(fallen)):
                 for g in fallen.tolist():
@@ -558,17 +578,20 @@ class FastDuplexCaller:
         caller._ordinal = ord0 + nG
         return [chunk]
 
-    def _need_filter_fallback(self, batch, span, vrows, g_of_row, t):
-        """The molecules whose X or Y set would engage the alignment
-        filter."""
+    @staticmethod
+    def _filter_need(batch, span, vrows, g_of_row, t):
+        """The X/Y sets of the valid rows ``vrows`` (seg order) and which
+        of them engage the alignment filter: (the rows in set order, the
+        set bounds, each set's molecule, a need mask a set). A set's rows
+        are AB's seg then BA's, each in record order, as the per-molecule
+        caller pools them (``ab_r1 + ba_r2``, ``ab_r2 + ba_r1``): the
+        filter's stable longest-first sort reads that order."""
         tt = t[vrows]
         setid = np.where((tt == AB_R1) | (tt == BA_R2), 0, 1)
         key = g_of_row[vrows] * 2 + setid
         order = np.argsort(key, kind="stable")
         srows = vrows[order]
         skey = key[order]
-        if not len(skey):
-            return skey
         sfirst = np.concatenate(([True], skey[1:] != skey[:-1]))
         sstarts = np.append(np.nonzero(sfirst)[0], len(skey))
         set_g = skey[sfirst] >> 1
@@ -589,7 +612,7 @@ class FastDuplexCaller:
             # all-single-op-M sets (ragged read lengths) are mutually
             # prefix-compatible after simplify: the alignment filter
             # provably keeps every read, so non-uniform bytes alone do not
-            # require the fallback (fast.py _prepare_groups_vec twin)
+            # engage it (fast.py _prepare_groups_vec twin)
             row_sm = (batch.n_cigar[span[srows]] == 1) \
                 & ((batch.buf[co[span[srows]]] & 0xF) == 0)
             set_sm = np.minimum.reduceat(
@@ -599,14 +622,38 @@ class FastDuplexCaller:
             rec_i = int(span[firsts[s]])
             if batch.n_cigar[rec_i] == 1:
                 continue  # single-op simplified CIGARs are palindromic
-            from ..core import cigar as cigar_utils
-            from .fast import FastSimplexCaller
-
             cig = FastSimplexCaller._decode_cigar(batch, rec_i)
             simplified = cigar_utils.simplify(cig)
             if simplified != list(reversed(simplified)):
                 need[s] = True
-        return set_g[need]
+        return srows, sstarts, set_g, need
+
+    def _filter_sets(self, batch, span, rev, final_len, srows, sstarts, sel):
+        """The most-common-alignment filter over the X/Y sets ``sel`` (a
+        mask over ``_filter_need``'s sets) in one native pass. Returns the
+        span rows it rejects; the caller's ``filter_tally`` takes the runs
+        on sets of two reads or more, as the per-molecule caller counts
+        them."""
+        counts = np.diff(sstarts)[sel]
+        at = _ranges(sstarts[:-1][sel], counts)
+        rows = srows[at]
+        recs = span[rows]
+        with _span("process.prep.align_filter", rusage=True,
+                   sets=len(counts)):
+            keep = nb.alignment_filter(
+                batch.buf, batch.cigar_off[recs], batch.n_cigar[recs],
+                rev[rows].astype(np.uint8), final_len[rows],
+                np.concatenate(([0], np.cumsum(counts))))
+        lost = keep == 0
+        # a set of one read keeps it: every set that lost one counts
+        n_two = int((counts >= 2).sum())
+        set_of = np.repeat(np.arange(len(counts)), counts)
+        tally = self.caller.filter_tally
+        tally[0] += n_two
+        tally[1] += n_two - len(np.unique(set_of[lost]))
+        tally[2] += int(counts[counts >= 2].sum())
+        tally[3] += int(lost.sum())
+        return rows[lost]
 
     def _ss_consensus(self, codes, quals, vrows, c1, vstarts, nseg, L_max):
         """Start all segs' single-strand consensus: the single-read host
@@ -687,16 +734,18 @@ class FastDuplexCaller:
 
     # ---------------------------------------------------------------- stage 2
 
-    def _stage2(self, batch, span, gb, n_paired, slow, live_mol, seg_map,
+    def _stage2(self, batch, span, gb, n_kept, slow, live_mol, seg_map,
                 seg_len, tb, tq, d16, e16, codes, vrows, vstarts,
                 L_max) -> bytes:
         """Strand combination + serialization, molecule order preserved.
 
         Runs on whichever thread resolves the span's chunk, several spans
         at once on a resolve pool: everything it writes is its own batch's
-        but the tallies (CallerStats' locked methods, METRICS). ``slow``:
-        the wire bytes of the span's fallback molecules by molecule index,
-        called at process time; they are only interleaved here."""
+        but the tallies (CallerStats' locked methods, METRICS). ``n_kept``:
+        each molecule's paired reads but the alignment filter's minority.
+        ``slow``: the wire bytes of the span's fallback molecules by
+        molecule index, called at process time; they are only interleaved
+        here."""
         caller = self.caller
         stats = caller.stats
 
@@ -706,10 +755,11 @@ class FastDuplexCaller:
                 caller.min_xy, caller.min_yx)
 
             # InsufficientReads for live-but-unemitted molecules (the
-            # fallthrough reject in _combine_molecule, duplex.py:361-363)
+            # fallthrough reject in _combine_molecule, duplex.py:435-437):
+            # their paired reads but the filter's minority, already counted
             dead = live_mol & ~emitted
             if dead.any():
-                stats.reject("InsufficientReads", int(n_paired[dead].sum()))
+                stats.reject("InsufficientReads", int(n_kept[dead].sum()))
 
             # what became of this span's molecules (run report `duplex.*`;
             # the fallback set is counted where the slow caller takes it)

@@ -34,6 +34,7 @@ try:
 finally:
     sys.path.remove(BENCH)
 
+from fgumi_tpu.cli import main as cli_main  # noqa: E402
 from fgumi_tpu.io.bam import BamHeader, BamWriter  # noqa: E402
 from fgumi_tpu.native import batch as nb  # noqa: E402
 from fgumi_tpu.observe import trace  # noqa: E402
@@ -57,7 +58,8 @@ ENGINES = {
 _WORK = tempfile.TemporaryDirectory(prefix="aligned_duplex_cell_")
 layout = traffic.kind_module("aligned_duplex_bam")
 
-REASONS = ("filter", "first_last", "downsample", "all")
+#: what still takes the per-molecule caller, by first cause
+REASONS = ("first_last", "downsample", "all")
 FILTER = ("duplex.filter.sets", "duplex.filter.sets_kept_all",
           "duplex.filter.reads_in", "duplex.filter.reads_rejected")
 READERS = ("duplex.fallback_s_per_mread", "duplex.filter_read_share",
@@ -94,12 +96,14 @@ def _expected(seed, rates, dtype=np.float64, alignment_filter=True,
 
 def _cli(path, out, config, engine, extra=()):
     """(record bytes, header lines, run report) of the configuration's
-    command on ``path``."""
+    command on ``path``; its span events are left in ``out + ".trace.json"``
+    (``_trace_events``)."""
     env, args = ENGINES[engine]
     report = out + ".report.json"
     argv = [a.format(in0=path, out=out) for a in config["command"]]
     subprocess.run(
-        [sys.executable, "-m", "fgumi_tpu", "--run-report", report] + argv
+        [sys.executable, "-m", "fgumi_tpu", "--run-report", report,
+         "--trace", out + ".trace.json"] + argv
         + args + list(extra), check=True, cwd=_WORK.name,
         env={**os.environ, **env, "PYTHONPATH": REPO, "JAX_PLATFORMS": "cpu",
              "XLA_FLAGS": ""})
@@ -110,11 +114,24 @@ def _cli(path, out, config, engine, extra=()):
         return payload[start:], lines, json.load(f)
 
 
+def _out(seed, rates, engine):
+    return os.path.join(_WORK.name, f"{engine}{rates}{seed}.bam")
+
+
 @functools.lru_cache(maxsize=None)
 def _run(seed, rates, engine):
     config, _module, _data, path = _cell(seed, rates)
-    return _cli(path, os.path.join(_WORK.name, f"{engine}{rates}{seed}.bam"),
-                config, engine)
+    return _cli(path, _out(seed, rates, engine), config, engine)
+
+
+def _trace_events(out, name):
+    """The complete events named ``name`` in the trace of the run that
+    wrote ``out``."""
+    with open(out + ".trace.json") as f:
+        events = json.load(f)
+    if isinstance(events, dict):
+        events = events["traceEvents"]
+    return [e for e in events if e.get("ph") == "X" and e["name"] == name]
 
 
 RUNS = [(seed, rates, engine) for engine in ENGINES for rates in RATES
@@ -326,17 +343,18 @@ def test_counters_add_up(seed, rates):
     assert sum(m.get("duplex.slow_molecules." + r, 0) for r in REASONS) \
         + carried == m["duplex.slow_molecules"]
     mol, need, runs = _need_and_filter(d)
-    # the need rule: every molecule it sends but the carried last one
+    # the need rule: every molecule it sends but the carried last one, to
+    # the native filter; the per-molecule caller takes the carried one only
     last = n_mol - 1
     sent = need.copy()
     sent[last] = False
-    assert m.get("duplex.molecules.filtered", 0) == sent.sum() \
-        == m.get("duplex.slow_molecules.filter", 0) \
-        == m["duplex.slow_molecules"] - carried
+    assert m.get("duplex.molecules.filtered", 0) == sent.sum()
+    assert m.get("duplex.slow_molecules.filter", 0) == 0
+    assert m["duplex.slow_molecules"] == carried
     sizes = np.bincount(mol, minlength=n_mol)
     assert m.get("duplex.reads.filtered", 0) == 2 * sizes[sent].sum()
-    # the filter's runs: over every molecule on the per-molecule caller,
-    # the carried one too
+    # the filter's runs: over every molecule the need rule sends, and the
+    # carried one on the per-molecule caller
     slow = np.flatnonzero(sent)
     slow = np.append(slow, last)
     got = [m.get(name, 0) for name in FILTER]
@@ -354,22 +372,31 @@ def test_counters_add_up(seed, rates):
 
 @pytest.mark.parametrize("seed,rates", WITH_CIGARS)
 def test_run_report_names_the_fallback_loop(seed, rates):
+    """The need rule's molecules take the native filter, not the
+    per-molecule loop: no ``engine.duplex.fallback``, and one
+    ``process.prep.align_filter`` a batch round the one native call, a
+    child of ``process.prep`` on its thread, with the sets it filtered."""
     _got, _header, report = _run(seed, rates, "host")
     by_name = report["spans"]["by_name"]
     m = report["metrics"]
-    loop, one = by_name["engine.duplex.fallback"], \
-        by_name["engine.duplex.slow_molecule"]
-    # one span a batch round the loop, not one a molecule: the per-molecule
-    # spans of the span's fallback set are below it, the carried one not
-    assert loop["count"] == 1 < m["duplex.molecules.filtered"]
-    assert one["count"] == m["duplex.slow_molecules"]
-    assert "utime_s" in loop
-    assert 0 < loop["wall_s"] - loop["self_s"] <= loop["wall_s"]
+    assert "engine.duplex.fallback" not in by_name
+    # the carried molecule is the one the per-molecule caller takes
+    assert by_name["engine.duplex.slow_molecule"]["count"] \
+        == m["duplex.slow_molecules"] == 1
+    prep, filt = by_name["process.prep"], by_name["process.prep.align_filter"]
+    assert filt["count"] == 1 < m["duplex.molecules.filtered"]
+    assert "utime_s" in filt
+    assert 0 < filt["wall_s"] <= prep["wall_s"] - prep["self_s"] + 1e-5
     holders = {t for t, rec in report["threads"].items()
-               if "engine.duplex.fallback" in rec["self_s"]}
+               if "process.prep.align_filter" in rec["self_s"]}
     assert holders and holders == {
         t for t, rec in report["threads"].items()
         if "process.prep" in rec["self_s"]}
+    # both sets of each molecule it sends (a read pair gives each set one
+    # read, so neither is empty)
+    (event,) = _trace_events(_out(seed, rates, "host"),
+                             "process.prep.align_filter")
+    assert event["args"]["sets"] == 2 * m["duplex.molecules.filtered"]
 
 
 def test_the_clean_input_opens_no_fallback_span():
@@ -385,6 +412,7 @@ def test_the_clean_input_opens_no_fallback_span():
                                  config, "host")
     m = report["metrics"]
     assert "engine.duplex.fallback" not in report["spans"]["by_name"]
+    assert "process.prep.align_filter" not in report["spans"]["by_name"]
     assert [k for k in m if k.startswith(("duplex.molecules.filtered",
                                           "duplex.reads.filtered",
                                           "duplex.slow_molecules."))] == []
@@ -440,6 +468,10 @@ def test_the_readers(name):
     d = _cell(SEEDS[0], "cell")[2]
     value = _reader(name).read({"reports": [report], "traced_jobs": 1,
                                 "reads_per_job": d["n_reads"]})
+    if name == "duplex.fallback_s_per_mread":
+        # no molecule of the cell's input is in a fallback set: no loop
+        assert value is None
+        return
     assert value > 0
     if name == "duplex.filter_read_share":
         assert 30 < value < 70
@@ -543,8 +575,11 @@ def _hand(molecules, seed=3):
 
 
 M, INS, DEL = "100M", "40M2I58M", "50M1D50M"
+#: X and Y of a molecule that loses all of BA's reads to them
+BA_LOSES = [([(M, M)] * 3, [(INS, INS)] * 2)]
 HAND = {
-    # name: (molecules, MinorityAlignment, InsufficientReads, records)
+    # name: (molecules, MinorityAlignment, InsufficientReads, records[,
+    # min-reads]); the configuration's min-reads where none is given
     "AB-R1 is filtered with BA-R2, not alone": (
         # AB's two forward reads agree with each other, but X pools them with
         # BA's three plain ones: they are the minority and go, AB-R1 is left
@@ -563,15 +598,32 @@ HAND = {
         1, 0, 2),
     "a lone strand is filtered too, then passes through": (
         [([(M, M), (INS, M), (INS, M)], [])], 1, 0, 2),
+    "Y empties BA-R1: three of four left, rejected net of the minority": (
+        # X keeps every read; Y's three plain AB reads outnumber BA's two
+        [([(M, M)] * 3, [(M, INS)] * 2)], 2, 8, 0),
+    "BA loses every read: AB passes through where YX may be 0": (
+        BA_LOSES, 4, 0, 2),
+    "BA loses every read: rejected where YX must be 1": (
+        BA_LOSES, 4, 6, 0, ["1", "1", "1"]),
+    "a tie: AB's first read founds the earlier group, the smaller wins": (
+        # X in its order: AB's D, AB's I, BA's I, BA's D; two groups of two,
+        # the first founded by AB's first read, and the smaller CIGAR (I)
+        # takes the tie from it: AB-R1 and BA-R2 keep one read each
+        [([(DEL, M), ("50M1I49M", M)], [("50M1I49M", M), (DEL, M)])],
+        2, 0, 2),
 }
 
 
 @pytest.mark.parametrize("name", HAND)
 def test_the_xy_filter_on_hand_made_molecules(name):
-    molecules, minority, insufficient, records = HAND[name]
+    molecules, minority, insufficient, records, *min_reads = HAND[name]
     d = _hand(molecules)
     config = _cell(SEEDS[0], "cell")[0]
-    flat, n_records, tallies = rda.duplex(d, _opts(config))
+    opts, extra = _opts(config), []
+    if min_reads:
+        opts["min_reads"] = [int(v) for v in min_reads[0]]
+        extra = ["--min-reads", *min_reads[0]]
+    flat, n_records, tallies = rda.duplex(d, opts)
     assert n_records == records
     assert tallies == {k: v for k, v in (
         ("MinorityAlignment", minority), ("InsufficientReads", insufficient),
@@ -579,36 +631,35 @@ def test_the_xy_filter_on_hand_made_molecules(name):
     # the program, on the same molecules written as the cell's layout, plus
     # a plain molecule behind them for the batch's open tail
     d = _hand(molecules + [([(M, M)], [(M, M)])])
-    want, n_want, _t = rda.duplex(d, _opts(config))
+    want, n_want, _t = rda.duplex(d, opts)
     (path,) = layout.write(d, os.path.join(
         _WORK.name, f"hand{list(HAND).index(name)}"), 1)
     for engine in ENGINES:
         got, _header, report = _cli(path, path + engine + ".out.bam", config,
-                                    engine)
+                                    engine, extra)
         assert got == np.ascontiguousarray(want).tobytes()
         m = report["metrics"]
         assert m.get("duplex.reads.rejected.MinorityAlignment", 0) == minority
         assert m.get("duplex.reads.rejected.InsufficientReads", 0) \
             == insufficient
         assert m["duplex.molecules.filtered"] == len(molecules)
+        # on the native filter: the carried plain molecule alone is called
+        # one at a time
+        assert m["duplex.slow_molecules"] == 1
+        assert m["duplex.consensus_reads"] == n_want
 
 
-def test_a_mixed_strand_set_that_is_no_palindrome():
-    """A one-strand molecule whose AB-R1 reads lie on both strands with one
-    CIGAR that reads otherwise from its end: the bytes are uniform, but the
-    need rule sends it (the palindrome check), and the filter keeps the read
-    whose CIGAR, in read orientation, is the smaller."""
+def _mixed_strand_bam(path, molecules):
+    """A BAM of one-strand molecules ``(MI, R1 flags)``, two read pairs
+    each, every read ``70M2D30M``: a molecule whose R1 flags differ in
+    their reverse bit has X and Y sets on both strands."""
     cigar = _parse("70M2D30M")
-    reads = [(100, ra.simplify(cigar)), (100, ra.simplify(cigar)[::-1])]
-    assert ra.most_common_alignment(reads) == [1]
     header = BamHeader(
         text="@HD\tVN:1.6\tSO:unsorted\tGO:query\n@SQ\tSN:c1\tLN:100000\n"
              "@RG\tID:A\tSM:s\n", ref_names=["c1"], ref_lengths=[100000])
     rng = np.random.default_rng(5)
-    path = os.path.join(_WORK.name, "mixed.bam")
     with BamWriter(path, header) as w:
-        for mi, r1_flags in ((b"0/A", (0x41, 0x41 | 0x10)),
-                             (b"1/A", (0x41, 0x41))):
+        for mi, r1_flags in molecules:
             for r, flag1 in enumerate(r1_flags):
                 for flag in (flag1, 0x81 | (flag1 & 0x10) ^ 0x10):
                     w.write_record_bytes(_build_mapped_record(
@@ -617,6 +668,19 @@ def test_a_mixed_strand_set_that_is_no_palindrome():
                         np.full(100, 35, np.uint8), 0, 1300, 0,
                         [(b"MI", "Z", mi), (b"RG", "Z", b"A"),
                          (b"RX", "Z", b"ACGT-TTGA")]))
+    return cigar
+
+
+def test_a_mixed_strand_set_that_is_no_palindrome():
+    """A one-strand molecule whose AB-R1 reads lie on both strands with one
+    CIGAR that reads otherwise from its end: the bytes are uniform, but the
+    need rule sends it (the palindrome check), and the filter keeps the read
+    whose CIGAR, in read orientation, is the smaller."""
+    path = os.path.join(_WORK.name, "mixed.bam")
+    cigar = _mixed_strand_bam(path, ((b"0/A", (0x41, 0x41 | 0x10)),
+                                     (b"1/A", (0x41, 0x41))))
+    reads = [(100, ra.simplify(cigar)), (100, ra.simplify(cigar)[::-1])]
+    assert ra.most_common_alignment(reads) == [1]
     config = _cell(SEEDS[0], "cell")[0]
     got, _header, report = _cli(path, path + ".out.bam", config, "host")
     classic, _h, report_c = _cli(path, path + ".classic.bam", config, "host",
@@ -630,3 +694,59 @@ def test_a_mixed_strand_set_that_is_no_palindrome():
         == m["duplex.reads.rejected.MinorityAlignment"] \
         == report_c["metrics"]["duplex.reads.rejected.MinorityAlignment"]
     assert m["duplex.consensus_reads"] == 4
+
+
+@pytest.mark.parametrize("engine", ENGINES)
+def test_mixed_strand_sets_on_both_strands_and_engines(engine):
+    """Mixed-strand sets that are no palindrome, on an AB-only and a
+    BA-only molecule, filtered on the arrays by both engines: the records
+    and the account of the per-molecule caller (``--classic``), and no
+    molecule of theirs on that caller."""
+    path = os.path.join(_WORK.name, "mixed2.bam")
+    _mixed_strand_bam(path, ((b"0/A", (0x41, 0x41 | 0x10)),
+                             (b"1/B", (0x41 | 0x10, 0x41)),
+                             (b"2/A", (0x41, 0x41))))
+    config = _cell(SEEDS[0], "cell")[0]
+    got, _header, report = _cli(path, f"{path}.{engine}.bam", config, engine)
+    classic, _h, report_c = _cli(path, path + ".classic.bam", config, "host",
+                                 ["--classic"])
+    assert got == classic
+    m, mc = report["metrics"], report_c["metrics"]
+    assert m["duplex.molecules.filtered"] == 2
+    assert m["duplex.slow_molecules"] == 1  # the carried 2/A
+    for key in [k for k in mc if k.startswith(("duplex.reads.rejected.",
+                                               "duplex.filter."))] \
+            + ["duplex.input_reads", "duplex.consensus_reads"]:
+        assert m.get(key) == mc[key], key
+    assert mc["duplex.reads.rejected.MinorityAlignment"] == 4
+    assert mc["duplex.consensus_reads"] == 6
+
+
+@pytest.mark.parametrize("cell,calls", [("duplex-c3.panel", 0), (CELL, 1)])
+def test_the_native_filter_is_called_once_a_batch_at_most(cell, calls,
+                                                          monkeypatch):
+    """``panel``'s input (one ``M`` run a read) makes no call of
+    ``nb.alignment_filter``; the aligned cell's input, one batch, makes one,
+    and its records are the reference's."""
+    _bench, _entry, config, _module, params = harness.load_cell(cell)
+    params["num_families"] = FAMILIES
+    (path,) = traffic.write_inputs(
+        traffic.generate(params, SEEDS[0]),
+        os.path.join(_WORK.name, f"calls{calls}"))
+    made = []
+    real = nb.alignment_filter
+
+    def counted(*args):
+        made.append(len(args[-1]) - 1)
+        return real(*args)
+
+    monkeypatch.setattr(nb, "alignment_filter", counted)
+    monkeypatch.setenv("FGUMI_TPU_ROUTE", "host")
+    out = os.path.join(_WORK.name, f"calls{calls}.bam")
+    assert cli_main([a.format(in0=path, out=out)
+                     for a in config["command"]]) == 0
+    assert len(made) == calls
+    if cell == CELL:
+        payload = bamio.read_bgzf(out)
+        _text, start = bamio.split_bam(payload)
+        assert payload[start:] == _expected(SEEDS[0], "cell")[0]

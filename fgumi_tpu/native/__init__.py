@@ -25,7 +25,7 @@ _lock = threading.Lock()
 _lib = None
 _lib_failed = False
 # must equal fgumi_abi_version() in fgumi_native.cc (stale-.so guard)
-_ABI_VERSION = 19
+_ABI_VERSION = 20
 
 
 def build() -> bool:
@@ -89,6 +89,9 @@ def _declare(lib):
         p, p, ctypes.c_long]
     lib.fgumi_adjacency_bfs.restype = None
     lib.fgumi_adjacency_bfs.argtypes = [p, p, p, ctypes.c_long, p]
+    lib.fgumi_within_bits_lists.restype = ctypes.c_long
+    lib.fgumi_within_bits_lists.argtypes = [
+        p, ctypes.c_long, ctypes.c_long, ctypes.c_long, p, p]
     lib.fgumi_bgzf_compress_block.restype = ctypes.c_long
     lib.fgumi_bgzf_compress_block.argtypes = [
         ctypes.c_char_p, ctypes.c_long, ctypes.c_int, ctypes.c_char_p,

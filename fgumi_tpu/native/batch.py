@@ -381,6 +381,27 @@ def adjacency_bfs(nbr_flat: np.ndarray, nbr_start: np.ndarray,
     return root_of
 
 
+def within_bits_lists(bits: np.ndarray, n: int, m: int):
+    """Neighbour lists from the device Hamming kernel's packed answer
+    (fgumi_within_bits_lists): ``bits`` is ``(rows, row_bytes)`` uint8, high
+    bit first, and only rows < n and columns < m are read. Returns
+    ``(nbr_flat int64, nbr_start int64[n + 1])``, ``adjacency_bfs``'s input:
+    row i's ascending j != i whose bit is set."""
+    lib = get_lib()
+    bits = np.ascontiguousarray(bits, np.uint8)
+    if bits.ndim != 2 or bits.shape[0] < n or 8 * bits.shape[1] < m:
+        raise ValueError(f"within_bits_lists: {bits.shape} bits cannot hold "
+                         f"{n} rows of {m} columns")
+    nbr_start = np.empty(n + 1, dtype=np.int64)
+    total = lib.fgumi_within_bits_lists(_addr(bits), bits.shape[1], n, m,
+                                        _addr(nbr_start), None)
+    nbr_flat = np.empty(total, dtype=np.int64)
+    if total:
+        lib.fgumi_within_bits_lists(_addr(bits), bits.shape[1], n, m,
+                                    _addr(nbr_start), _addr(nbr_flat))
+    return nbr_flat, nbr_start
+
+
 def segment_depth_errors(codes2d: np.ndarray, winner: np.ndarray,
                          starts: np.ndarray):
     """Per-segment depth/error counts: (J, L) int32 pair.

@@ -92,7 +92,7 @@ extern "C" {
 // ABI version for the stale-.so guard in __init__.py: bump whenever any
 // exported signature changes (a symbol probe alone cannot detect an
 // argument-list change in an existing function).
-long fgumi_abi_version() { return 19; }
+long fgumi_abi_version() { return 20; }
 
 // Candidate UMI pairs with hamming(A[i], B[j]) <= d over (n, L)/(m, L) byte
 // matrices, via the d+1-part pigeonhole (umi/assigners.py
@@ -353,6 +353,74 @@ void fgumi_adjacency_bfs(const int64_t* nbr_flat, const int64_t* nbr_start,
       }
     }
   }
+}
+
+// The adjacency BFS's neighbour lists straight from the device Hamming
+// kernel's packed answer (umi/assigners.py _device_within_bits): `bits` is
+// (rows, row_bytes) uint8, the bit of column j in row i set when the pair is
+// within `edits`, high bit first as jnp.packbits writes it. Only the real
+// corner is read: rows < n, columns < m (the last byte masked), so the
+// padding never enters a list. Row i's list is the ascending j != i whose
+// bit is set. Two passes: with nbr_flat null, count each row by popcount
+// into nbr_start[0..n] and return the total; with nbr_flat given (total
+// entries, nbr_start from the first pass), fill the lists. All-zero words
+// are skipped in both.
+long fgumi_within_bits_lists(const uint8_t* bits, long row_bytes, long n,
+                             long m, int64_t* nbr_start, int64_t* nbr_flat) {
+  const long full = m >> 3;  // whole bytes of real columns
+  const uint8_t tail_mask =
+      static_cast<uint8_t>(0xFF00u >> static_cast<unsigned>(m & 7));
+  auto load_be = [](const uint8_t* p) {
+    uint64_t w;
+    std::memcpy(&w, p, 8);
+#if __BYTE_ORDER__ == __ORDER_LITTLE_ENDIAN__
+    w = __builtin_bswap64(w);
+#endif
+    return w;  // column 0 of the eight bytes in the top bit
+  };
+  if (nbr_flat == nullptr) {
+    nbr_start[0] = 0;
+    for (long i = 0; i < n; ++i) {
+      const uint8_t* row = bits + i * row_bytes;
+      long c = 0;
+      long b = 0;
+      for (; b + 8 <= full; b += 8) {
+        uint64_t w;
+        std::memcpy(&w, row + b, 8);
+        if (w != 0) c += __builtin_popcountll(w);
+      }
+      for (; b < full; ++b) c += __builtin_popcount(row[b]);
+      if (m & 7) c += __builtin_popcount(row[full] & tail_mask);
+      if (i < m && ((row[i >> 3] >> (7 - (i & 7))) & 1)) --c;  // the diagonal
+      nbr_start[i + 1] = nbr_start[i] + c;
+    }
+    return static_cast<long>(nbr_start[n]);
+  }
+  for (long i = 0; i < n; ++i) {
+    const uint8_t* row = bits + i * row_bytes;
+    int64_t* out = nbr_flat + nbr_start[i];
+    auto emit_word = [&](uint64_t w, long col0) {
+      while (w != 0) {
+        const int lz = __builtin_clzll(w);
+        const long j = col0 + lz;
+        if (j != i) *out++ = j;
+        w &= ~(uint64_t{1} << (63 - lz));
+      }
+    };
+    long b = 0;
+    for (; b + 8 <= full; b += 8) {
+      const uint64_t w = load_be(row + b);
+      if (w != 0) emit_word(w, b * 8);
+    }
+    for (; b < full; ++b) {
+      if (row[b] != 0) emit_word(static_cast<uint64_t>(row[b]) << 56, b * 8);
+    }
+    if (m & 7) {
+      const uint8_t last = row[full] & tail_mask;
+      if (last != 0) emit_word(static_cast<uint64_t>(last) << 56, full * 8);
+    }
+  }
+  return static_cast<long>(nbr_start[n]);
 }
 
 // Decompress a whole (possibly multi-member) plain-gzip buffer with

@@ -31,9 +31,11 @@ Spans and counters (live under ``--trace`` / ``--run-report``; children of
 ``group.assign``): ``group.assign.umis``, ``group.assign.graph`` (attribute
 ``route``; on the device route ``group.hamming.upload``, ``group.hamming.dispatch``
 and ``device.fetch`` below it), ``group.assign.threshold`` (the host's compare with
-``edits``, or the device's bits unpacked), ``group.assign.bfs``,
-``group.assign.ids``; counters ``group.graph.<route>``, ``group.hamming.*``,
-``group.neighbor_pairs``, ``group.unique_umis`` (docs/observability.md).
+``edits``; on the device route the one native pass from the packed bits to the
+BFS's neighbour lists, ``nb.within_bits_lists``), ``group.assign.bfs``,
+``group.assign.ids``; counters ``group.graph.<route>``, ``group.graph.bits_lists``,
+``group.hamming.*``, ``group.neighbor_pairs``, ``group.unique_umis``
+(docs/observability.md).
 """
 
 from collections import deque
@@ -155,14 +157,20 @@ class NeighborGraph:
     """Match-graph adjacency: neighbors(i) -> ascending indices j != i.
 
     Dense mode wraps a boolean within-matrix (small groups); sparse mode
-    holds per-node neighbor lists from pigeonhole candidate generation."""
+    holds per-node neighbor lists from pigeonhole candidate generation; flat
+    mode holds the native BFS's ``(nbr_flat, nbr_start)`` as the device
+    route's native pass made them."""
 
-    def __init__(self, n, within=None, lists=None):
+    def __init__(self, n, within=None, lists=None, flat=None):
         self.n = n
         self._within = within
         self._lists = lists
+        self._flat = flat
 
     def neighbors(self, i: int) -> np.ndarray:
+        if self._flat is not None:
+            nbr_flat, nbr_start = self._flat
+            return nbr_flat[nbr_start[i]:nbr_start[i + 1]]
         if self._within is not None:
             row = np.nonzero(self._within[i])[0]
             return row[row != i]
@@ -171,6 +179,8 @@ class NeighborGraph:
     def flat(self):
         """(nbr_flat, nbr_start) arrays for the native BFS: neighbors of i
         are nbr_flat[nbr_start[i]:nbr_start[i+1]], ascending."""
+        if self._flat is not None:
+            return self._flat
         with _sized_span(self.n, "group.assign.threshold", rusage=True):
             lists = (self._lists if self._lists is not None
                      else [self.neighbors(i) for i in range(self.n)])
@@ -194,25 +204,22 @@ def build_neighbor_graph(mat: np.ndarray, max_mismatches: int,
         route = "device" if on_device else "dense_host"
         if n > 1:  # the routes sum to the sub-groups of two or more uniques
             METRICS.inc("group.graph." + route)
-        within = None
         passes = [(mat, mat)] + ([(rev_mat, mat)] if rev_mat is not None
                                  else [])
+        if on_device:
+            return _device_graph(n, passes, max_mismatches)
+        within = None
         for a, b in passes:
-            with _sized_span(n, "group.assign.graph", rusage=not on_device,
+            with _sized_span(n, "group.assign.graph", rusage=True,
                              route=route, uniques=n):
-                found = (_device_within_bits(a, b, max_mismatches)
-                         if on_device else pairwise_distances(a, b))
+                found = pairwise_distances(a, b)
             with _sized_span(n, "group.assign.threshold", rusage=True):
-                found = (_unpack_within(found, n, n) if on_device
-                         else found <= max_mismatches)
+                found = found <= max_mismatches
                 if within is None:
                     within = found
                 else:
                     within |= found
-        if tracing_enabled():  # a pass over n x n: only when it is read
-            METRICS.inc("group.neighbor_pairs",
-                        (int(np.count_nonzero(within)) - n) // 2)
-        return NeighborGraph(n, within=within)
+        return _dense_graph(n, within)
     from ..native import batch as nb
 
     METRICS.inc("group.graph.sparse_native")
@@ -398,7 +405,8 @@ def _device_within_bits(mat_a: np.ndarray, mat_b: np.ndarray,
                         edits: int) -> np.ndarray:
     """hamming(mat_a[i], mat_b[j]) <= edits for every pair of the padded
     matrices, as the device packs it: ``(n_pad, ceil(m_pad / 8))`` uint8, the
-    high bit first (``_unpack_within`` undoes it). The real ``n x m`` corner is
+    high bit first (``nb.within_bits_lists`` reads it, ``_unpack_within``
+    undoes it). The real ``n x m`` corner is
     not cut out on the device: every (n, m) would be a shape and a compile,
     and at a bit a pair the padding is a few MB."""
     dist = _get_dist_jit()
@@ -427,8 +435,41 @@ def _device_within_bits(mat_a: np.ndarray, mat_b: np.ndarray,
 
 def _unpack_within(bits: np.ndarray, n: int, m: int) -> np.ndarray:
     """The real ``(n, m)`` corner of ``_device_within_bits``' answer as the
-    boolean matrix ``NeighborGraph`` takes."""
+    boolean matrix ``NeighborGraph`` takes (the device route without the
+    native library)."""
     return np.unpackbits(bits[:n], axis=1, count=m).view(np.bool_)
+
+
+def _device_graph(n: int, passes, edits: int) -> NeighborGraph:
+    """The device route's graph: one dispatch a pass, the passes' packed
+    answers OR-ed (``paired``'s reversed UMIs against the forward ones), then
+    one native pass from the bits to the native BFS's neighbour lists, with
+    no ``(n, n)`` matrix between. Without the native library the bits are
+    unpacked into the dense matrix instead."""
+    from ..native import batch as nb
+
+    found = []
+    for a, b in passes:
+        with _sized_span(n, "group.assign.graph", route="device", uniques=n):
+            found.append(_device_within_bits(a, b, edits))
+    with _sized_span(n, "group.assign.threshold", rusage=True):
+        bits = found[0] if len(found) == 1 else np.bitwise_or(*found)
+        if nb.available():
+            nbr_flat, nbr_start = nb.within_bits_lists(bits, n, n)
+            if n > 1:  # as ``group.graph.device`` counts
+                METRICS.inc("group.graph.bits_lists")
+            METRICS.inc("group.neighbor_pairs", len(nbr_flat) // 2)
+            return NeighborGraph(n, flat=(nbr_flat, nbr_start))
+        within = _unpack_within(bits, n, n)
+    return _dense_graph(n, within)
+
+
+def _dense_graph(n: int, within: np.ndarray) -> NeighborGraph:
+    """A graph over the boolean within-matrix, its diagonal set."""
+    if tracing_enabled():  # a pass over n x n: only when it is read
+        METRICS.inc("group.neighbor_pairs",
+                    (int(np.count_nonzero(within)) - n) // 2)
+    return NeighborGraph(n, within=within)
 
 
 def _assert_uniform_length(lengths) -> None:

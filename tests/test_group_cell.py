@@ -49,7 +49,11 @@ MOLECULES = 2050
 #: route -> extra arguments. ``indexed`` sends every graph of 600 uniques or
 #: more to the native pigeonhole pass through the existing flag
 ROUTES = {"default": [], "indexed": ["--index-threshold", "600"],
-          "one-thread": ["--threads", "1"], "classic": ["--classic"]}
+          "one-thread": ["--threads", "1"], "classic": ["--classic"],
+          "no-native": []}
+#: route -> environment. ``no-native`` runs without the native library, so
+#: the device route unpacks its bits into the boolean matrix
+ENVS = {"no-native": {"FGUMI_TPU_NO_NATIVE": "1"}}
 _WORK = tempfile.TemporaryDirectory(prefix="group_cell_")
 
 NEW_SPANS = ("group.assign.umis", "group.assign.graph",
@@ -101,7 +105,7 @@ def _run(seed, route):
         [sys.executable, "-m", "fgumi_tpu", "--run-report", report] + argv
         + ROUTES[route], check=True, cwd=_WORK.name,
         env={**os.environ, "PYTHONPATH": REPO, "JAX_PLATFORMS": "cpu",
-             "XLA_FLAGS": ""})
+             "XLA_FLAGS": "", **ENVS.get(route, {})})
     payload = bamio.read_bgzf(out)
     text, start = bamio.split_bam(payload)
     lines = [ln for ln in text.splitlines() if not ln.startswith("@PG")]
@@ -110,7 +114,8 @@ def _run(seed, route):
 
 
 RUNS = [(seed, route) for route in ("default", "indexed") for seed in SEEDS] \
-    + [(SEEDS[0], "one-thread"), (SEEDS[2], "classic")]
+    + [(SEEDS[0], "one-thread"), (SEEDS[2], "classic"),
+       (SEEDS[1], "no-native")]
 
 
 @pytest.mark.parametrize("seed,route", RUNS)
@@ -203,9 +208,12 @@ def test_counters_add_up(seed, route):
         assert m["group.hamming.rows"] ** 2 >= 4 * m["group.hamming.cells"]
         assert report["device"]["bytes_fetched"] \
             == m["group.hamming.bytes_fetched"]
+        # every device graph's lists come from the native pass over its bits
+        assert m["group.graph.bits_lists"] == by_route["device"]
     else:
         assert by_route["sparse_native"] >= 2 and by_route["device"] == 0
         assert [n for n in HAMMING if n in m] == []
+        assert "group.graph.bits_lists" not in m
     assert m["group.neighbor_pairs"] > 0
 
 
@@ -213,6 +221,31 @@ def test_every_route_finds_the_same_neighbours():
     pairs = {route: _run(SEEDS[0], route)[2]["metrics"]
              ["group.neighbor_pairs"] for route in ("default", "indexed")}
     assert pairs["default"] == pairs["indexed"]
+
+
+@pytest.mark.parametrize("seed", SEEDS)
+def test_the_bits_lists_find_the_native_routes_pairs(seed):
+    """The lists the native pass makes from the device's bits hold the
+    pairs the native pigeonhole route finds, on every seed."""
+    default = _run(seed, "default")[2]["metrics"]
+    indexed = _run(seed, "indexed")[2]["metrics"]
+    assert default["group.graph.bits_lists"] \
+        == default["group.graph.device"] >= 1
+    assert default["group.neighbor_pairs"] == indexed["group.neighbor_pairs"]
+
+
+def test_without_the_native_library_the_device_route_unpacks_the_bits():
+    """The fallback takes the same routes and dispatches, builds no list
+    from the bits natively, and finds the same pairs (its records are held
+    to the reference by ``test_cli_writes_the_reference_records``)."""
+    fallback = _run(SEEDS[1], "no-native")[2]["metrics"]
+    default = _run(SEEDS[1], "default")[2]["metrics"]
+    assert "group.graph.bits_lists" not in fallback
+    for name in ("group.graph.device", "group.graph.dense_host",
+                 "group.hamming.dispatches", "group.hamming.cells",
+                 "group.neighbor_pairs"):
+        assert fallback[name] == default[name], name
+    assert fallback["group.graph.device"] >= 1
 
 
 @pytest.mark.parametrize("route", ["default", "indexed"])

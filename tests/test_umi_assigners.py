@@ -144,11 +144,38 @@ def _host_within(a, b, edits):
     return (a[:, None, :] != b[None, :, :]).sum(axis=2) <= edits
 
 
+def _lists_of(within):
+    """``NeighborGraph.flat``'s two arrays from a boolean matrix: each row's
+    ascending set columns with the diagonal left out."""
+    rows = [np.flatnonzero(r) for r in within]
+    rows = [r[r != i] for i, r in enumerate(rows)]
+    start = np.zeros(len(rows) + 1, dtype=np.int64)
+    np.cumsum([len(r) for r in rows], out=start[1:])
+    flat = np.concatenate(rows) if rows else np.empty(0, np.int64)
+    return flat.astype(np.int64), start
+
+
+def _assert_same_lists(got, want):
+    assert got[0].dtype == got[1].dtype == np.int64
+    np.testing.assert_array_equal(got[1], want[1])
+    np.testing.assert_array_equal(got[0], want[0])
+
+
+def _assert_same_graph(got, want):
+    assert got.n == want.n
+    for i in range(want.n):
+        np.testing.assert_array_equal(got.neighbors(i), want.neighbors(i))
+    _assert_same_lists(got.flat(), want.flat())
+
+
 @pytest.mark.parametrize("edits", [0, 1, 2, 3])
 def test_device_pairwise_path(edits, monkeypatch):
     """The device route, forced through the module threshold, builds the
-    graph the host route builds, for the single and the dual UMI's bytes."""
+    graph the host route builds, for the single and the dual UMI's bytes:
+    neighbour lists made by the native pass from the packed bits, with no
+    boolean matrix."""
     import fgumi_tpu.umi.assigners as A
+    from fgumi_tpu.native import batch as nb
     from fgumi_tpu.observe.metrics import METRICS
 
     rng = np.random.default_rng(1)
@@ -156,11 +183,15 @@ def test_device_pairwise_path(edits, monkeypatch):
         mat = np.concatenate(_near_copies(rng, alphabet, 32, 32, 8))
         host = A.build_neighbor_graph(mat, edits)
         before = METRICS.get("group.hamming.dispatches", 0)
+        lists = METRICS.get("group.graph.bits_lists", 0)
         with monkeypatch.context() as mp:
             mp.setattr(A, "DEVICE_THRESHOLD", 1)
             dev = A.build_neighbor_graph(mat, edits)
         assert METRICS.get("group.hamming.dispatches", 0) == before + 1
-        np.testing.assert_array_equal(dev._within, host._within)
+        if nb.available():
+            assert dev._within is None
+            assert METRICS.get("group.graph.bits_lists", 0) == lists + 1
+        _assert_same_graph(dev, host)
         np.testing.assert_array_equal(host._within,
                                       _host_within(mat, mat, edits))
 
@@ -232,14 +263,17 @@ def test_assigners_identical_across_threshold(monkeypatch):
 
 @pytest.mark.parametrize("edits", [0, 1, 2, 3])
 @pytest.mark.parametrize("n,m", [(1500, 1500), (2049, 130), (1023, 4097),
-                                 (5, 3)])
+                                 (5, 3), (1031, 77), (6, 1029)])
 def test_device_pairwise_parity_at_scale(n, m, edits):
     """The padded device path's bits are the numpy host path's
     ``!= ... sum <= edits`` exactly (VERDICT r3 item 6: huge-position-group
     parity), at non-pow2 sizes, asymmetric (a, b) shapes and a padded side
     under one packed byte, over every byte a strategy puts into a UMI
     matrix: upper-case ACGT, and the ``paired`` strategy's ``-`` between
-    the halves and ``AA:`` / ``BB:`` orientation prefixes."""
+    the halves and ``AA:`` / ``BB:`` orientation prefixes. The native pass
+    reads the same bits into the host matrix's rows: columns under ``m``
+    only, the diagonal left out."""
+    from fgumi_tpu.native import batch as nb
     from fgumi_tpu.umi import assigners as A
 
     rng = np.random.default_rng(3)
@@ -251,6 +285,99 @@ def test_device_pairwise_parity_at_scale(n, m, edits):
     host = _host_within(a, b, edits)
     assert dev.dtype == np.bool_ and np.array_equal(host, dev)
     assert host.any() and not host.all()
+    if nb.available():
+        _assert_same_lists(nb.within_bits_lists(bits, n, m), _lists_of(host))
+
+
+def test_bits_lists_of_the_paired_passes_are_the_union():
+    """``paired``'s two passes OR-ed in their packed form give the lists of
+    the union of the two host matrices."""
+    from fgumi_tpu.native import batch as nb
+    from fgumi_tpu.umi import assigners as A
+
+    if not nb.available():
+        pytest.skip("native library unavailable")
+    umis = sorted(set(_dual_umis(np.random.default_rng(8), 600, 3000)))
+    mat = _umi_matrix(umis)
+    rev = _umi_matrix([PairedUmiAssigner._reverse(u) for u in umis])
+    n = len(umis)
+    fwd_bits = A._device_within_bits(mat, mat, 1)
+    rev_bits = A._device_within_bits(rev, mat, 1)
+    host = _host_within(mat, mat, 1) | _host_within(rev, mat, 1)
+    assert (host & ~_host_within(mat, mat, 1)).any()  # the reversal adds
+    _assert_same_lists(nb.within_bits_lists(fwd_bits | rev_bits, n, n),
+                       _lists_of(host))
+
+
+@pytest.mark.parametrize("n,m,rows,row_bytes", [
+    (1, 1, 1, 1), (1, 9, 4, 2), (0, 0, 1, 1), (20, 20, 32, 4),
+    (130, 70, 256, 32)])
+def test_bits_lists_of_empty_rows(n, m, rows, row_bytes):
+    """No set bit in the real corner, or only the diagonal, gives empty
+    lists; bits set in the padding outside it never enter one."""
+    from fgumi_tpu.native import batch as nb
+
+    if not nb.available():
+        pytest.skip("native library unavailable")
+    bits = np.zeros((rows, row_bytes), dtype=np.uint8)
+    empty = (np.empty(0, np.int64), np.zeros(n + 1, np.int64))
+    _assert_same_lists(nb.within_bits_lists(bits, n, m), empty)
+    for i in range(min(n, m)):  # the diagonal alone
+        bits[i, i // 8] |= 0x80 >> (i % 8)
+    bits[n:] = 0xFF  # pad rows
+    if m % 8:
+        bits[:, m // 8] |= 0xFF >> (m % 8)  # pad columns in the last byte
+    bits[:, -(-m // 8):] = 0xFF  # pad bytes
+    _assert_same_lists(nb.within_bits_lists(bits, n, m), empty)
+
+
+def test_one_unique_on_the_device_route_has_no_neighbours():
+    from fgumi_tpu.native import batch as nb
+    from fgumi_tpu.umi import assigners as A
+
+    mat = _umi_matrix(["ACGTACGT"])
+    bits = A._device_within_bits(mat, mat, 1)
+    assert bits.shape == (1, 1) and bits[0, 0] == 0x80  # itself, within 0
+    if nb.available():
+        _assert_same_lists(nb.within_bits_lists(bits, 1, 1),
+                           (np.empty(0, np.int64), np.zeros(2, np.int64)))
+
+
+def test_bits_lists_refuses_bits_too_small():
+    from fgumi_tpu.native import batch as nb
+
+    if not nb.available():
+        pytest.skip("native library unavailable")
+    with pytest.raises(ValueError):
+        nb.within_bits_lists(np.zeros((4, 1), np.uint8), 5, 8)
+    with pytest.raises(ValueError):
+        nb.within_bits_lists(np.zeros((4, 1), np.uint8), 4, 9)
+
+
+@pytest.mark.parametrize("paired", [False, True])
+def test_device_route_without_the_native_library(paired, monkeypatch):
+    """Without the native library the device route unpacks the bits into
+    the boolean matrix, as it did before the native pass, and builds the
+    graph the native pass builds."""
+    from fgumi_tpu.native import batch as nb
+    from fgumi_tpu.observe.metrics import METRICS
+    from fgumi_tpu.umi import assigners as A
+
+    if not nb.available():
+        pytest.skip("native library unavailable")
+    umis = sorted(set(_dual_umis(np.random.default_rng(9), 300, 2000)))
+    mat = _umi_matrix(umis)
+    rev = (_umi_matrix([PairedUmiAssigner._reverse(u) for u in umis])
+           if paired else None)
+    monkeypatch.setattr(A, "DEVICE_THRESHOLD", 16)
+    native = A.build_neighbor_graph(mat, 1, rev_mat=rev)
+    assert native._within is None
+    lists = METRICS.get("group.graph.bits_lists", 0)
+    monkeypatch.setattr(nb, "available", lambda: False)
+    fallback = A.build_neighbor_graph(mat, 1, rev_mat=rev)
+    assert fallback._within is not None
+    assert METRICS.get("group.graph.bits_lists", 0) == lists
+    _assert_same_graph(fallback, native)
 
 
 def test_the_alphabet_is_what_the_strategies_write():

@@ -1253,6 +1253,14 @@ def cmd_codec(args):
              s.total_input_reads / dt if dt else 0)
     if s.rejection_reasons:
         log.info("rejections: %s", dict(sorted(s.rejection_reasons.items())))
+    # the caller's account of every input read, in the run report too
+    from .observe.metrics import METRICS
+
+    METRICS.inc("codec.input_reads", s.total_input_reads)
+    METRICS.inc("codec.consensus_reads", n_out)
+    for reason, count in s.rejection_reasons.items():
+        METRICS.inc("codec.reads.rejected." + reason, count)
+    s.fold_filter_tally()
     if s.consensus_duplex_bases_emitted:
         log.info("duplex disagreement rate: %.6f (%d/%d)",
                  s.duplex_disagreement_rate(), s.duplex_disagreement_base_count,

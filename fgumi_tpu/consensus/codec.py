@@ -42,6 +42,7 @@ from ..core.overlap import (is_primary_fr_pair,
 from ..io.bam import (FLAG_FIRST, FLAG_PAIRED, FLAG_REVERSE, FLAG_SECONDARY,
                       FLAG_SUPPLEMENTARY, FLAG_UNMAPPED, RawRecord,
                       RecordBuilder)
+from ..observe.metrics import METRICS
 from ..ops.kernel import ConsensusKernel
 from .simple_umi import consensus_umis
 from .vanilla import (I16_MAX, R1, SourceRead, VanillaConsensusCaller,
@@ -84,6 +85,11 @@ class CodecOptions:
     seed: int = 42
 
 
+#: what `CodecStats.filter_tally` counts: sets, sets that kept every read,
+#: reads in, reads rejected (``MinorityAlignment``)
+FILTER_TALLY = ("sets", "sets_kept_all", "reads_in", "reads_rejected")
+
+
 @dataclass
 class CodecStats:
     """CodecConsensusStats analog (codec_caller.rs:214-259).
@@ -101,6 +107,12 @@ class CodecStats:
     consensus_duplex_bases_emitted: int = 0
     duplex_disagreement_base_count: int = 0
     rejection_reasons: dict = field(default_factory=dict)
+    #: the most-common-alignment filter's runs on sets of two reads or more
+    #: (`FILTER_TALLY`), plain integers on the processing thread; where it
+    #: runs is the engine's choice (the batch engine runs it only in the
+    #: per-molecule ``prepare()``), so engines compare equal without it
+    filter_tally: list = field(default_factory=lambda: [0, 0, 0, 0],
+                               compare=False)
     lock: threading.Lock = field(default_factory=threading.Lock, repr=False,
                                  compare=False)
 
@@ -115,6 +127,14 @@ class CodecStats:
         with self.lock:
             for name, count in counts.items():
                 setattr(self, name, getattr(self, name) + count)
+
+    def fold_filter_tally(self):
+        """The filter's tally into the run report's ``codec.filter.*``
+        counters; nothing when the filter never ran."""
+        tally, self.filter_tally = self.filter_tally, [0, 0, 0, 0]
+        if tally[0]:
+            for name, count in zip(FILTER_TALLY, tally):
+                METRICS.inc("codec.filter." + name, count)
 
     def duplex_disagreement_rate(self) -> float:
         if self.consensus_duplex_bases_emitted:
@@ -296,6 +316,11 @@ class CodecConsensusCaller:
         indexed.sort(key=lambda t: -t[1])
         keep = set(cigar_utils.select_most_common_alignment_group(indexed))
         rejected = len(infos) - len(keep)
+        tally = self.stats.filter_tally
+        tally[0] += 1
+        tally[1] += not rejected
+        tally[2] += len(infos)
+        tally[3] += rejected
         if rejected:
             self.stats.reject("MinorityAlignment", rejected)
         return [info for i, info in enumerate(infos) if i in keep]

@@ -42,7 +42,10 @@ for two things chunks share: the caller's `CodecStats` (locked) and its
 record counter, which names a molecule whose MI value is empty
 (`_EmittedOrder`). Run-report counters, by molecule:
 `codec.molecules` = `.emitted` + `.rejected` (`.rejected.<reason>`),
-`.slow_molecules`, `.row_molecules`, `.strands`, `.single_strands`,
+`.slow_molecules` = `.slow_molecules.carried` + `.cigar` (why the classic
+``prepare()`` took it), `.molecules.cigar` / `.reads.cigar` (the input's
+molecules with a paired primary that is not one M run, and their reads),
+`.row_molecules`, `.strands`, `.single_strands`,
 `.place.strands` / `.place.cells` (what the native placement copied),
 `.combine_cells_host`, `.duplex_bases`, `.disagreements`; by
 chunk: `.stage2_batches`, `.stage2_off_thread` (docs/observability.md).
@@ -96,6 +99,18 @@ def _count_reject(reason, molecules=1):
     if molecules:
         METRICS.inc("codec.rejected", molecules)
         METRICS.inc("codec.rejected." + reason, molecules)
+
+
+def _not_one_m_run(rec):
+    """A paired primary record whose CIGAR is not one ``M`` run over the
+    whole read: `FastCodecCaller._prepare_span`'s ``m_only`` rule, a
+    record."""
+    flag = rec.flag
+    if not flag & FLAG_PAIRED or flag & (FLAG_SECONDARY | FLAG_SUPPLEMENTARY):
+        return False
+    cigar = rec.cigar()
+    return not (len(cigar) == 1 and cigar[0][0] == "M"
+                and cigar[0][1] == rec.l_seq > 0)
 
 
 def _ragged_arange(starts, counts):
@@ -318,7 +333,7 @@ class FastCodecCaller:
             if (not merge_carry) or final or n_total >= 2:
                 mi, recs = self._carry
                 self._carry = None
-                mol = self._prepare_slow(recs, mi)
+                mol = self._prepare_slow(recs, mi, "carried")
                 if mol is not None:
                     lead.append(mol)
 
@@ -345,19 +360,24 @@ class FastCodecCaller:
             return []
         mi, recs = self._carry
         self._carry = None
-        mol = self._prepare_slow(recs, mi)
+        mol = self._prepare_slow(recs, mi, "carried")
         return self._run(_Molecules(1, [mol]) if mol is not None
                          else _Molecules(0))
 
-    def _prepare_slow(self, records, mi, counted=False):
+    def _prepare_slow(self, records, mi, cause):
         """One molecule through the classic ``prepare()`` (the semantic
-        reference): the one a batch boundary cut, or (``counted``: a group of
-        the vectorized span, already in ``codec.molecules``) one whose CIGAR
-        shape the closed forms do not cover."""
+        reference), counted under ``codec.slow_molecules.<cause>``:
+        ``carried``, the one a batch boundary cut, or ``cigar``, a group of
+        the vectorized span (already in ``codec.molecules`` and
+        ``.molecules.cigar``) with a CIGAR the closed forms do not cover."""
         METRICS.inc("codec.slow_molecules")
+        METRICS.inc("codec.slow_molecules." + cause)
         METRICS.inc("codec.row_molecules")
-        if not counted:
+        if cause == "carried":
             METRICS.inc("codec.molecules")
+            if any(_not_one_m_run(rec) for rec in records):
+                METRICS.inc("codec.molecules.cigar")
+                METRICS.inc("codec.reads.cigar", len(records))
         before = self._prepare_rejects()
         with _span("engine.codec.slow_molecule", rusage=True):
             mol = self.caller.prepare(records, umi=mi)
@@ -935,6 +955,10 @@ class FastCodecCaller:
             & ((fl & (FLAG_SECONDARY | FLAG_SUPPLEMENTARY)) == 0)
         g_of_row = np.repeat(np.arange(nG), np.diff(gb))
         grp_ok = np.logical_and.reduceat(m_only | ~paired_primary, gb[:-1])
+        n_cigar = nG - int(grp_ok.sum())
+        if n_cigar:  # the input's molecules with a CIGAR, and their reads
+            METRICS.inc("codec.molecules.cigar", n_cigar)
+            METRICS.inc("codec.reads.cigar", int(np.diff(gb)[~grp_ok].sum()))
 
         # phases 1-2 (primary-pair formation by name + clip closed forms)
         # run once over the whole eligible span, then phases 3-4 (overlap
@@ -1005,7 +1029,7 @@ class FastCodecCaller:
                 # downsample RNG stream must see molecules in input order
                 mi = batch.tag_bytes(self.tag, int(rows[0])).decode()
                 mol = self._prepare_slow(batch.raw_records(rows), mi,
-                                         counted=True)
+                                         "cigar")
                 if mol is not None:
                     mols.set_classic(nL + g, mol)
                     alive[nL + g] = True

@@ -509,7 +509,8 @@ def test_the_configuration_file_and_the_benchmark_agree():
     for metric in new:
         assert metric["workloads"] == [CELL]
         assert metric["moves"] == "reads_per_s"
-    assert bench["workloads"][-1]["name"] == CELL
+    # appended after the eight cells that came before it
+    assert [w["name"] for w in bench["workloads"]].index(CELL) == 8
 
 
 def test_the_reference_imports_nothing_of_the_program():

@@ -109,8 +109,9 @@ class CodecStats:
     rejection_reasons: dict = field(default_factory=dict)
     #: the most-common-alignment filter's runs on sets of two reads or more
     #: (`FILTER_TALLY`), plain integers on the processing thread; where it
-    #: runs is the engine's choice (the batch engine runs it only in the
-    #: per-molecule ``prepare()``), so engines compare equal without it
+    #: runs is the engine's choice (the batch engine runs it on the
+    #: molecules with a CIGAR other than one M run and in the per-molecule
+    #: ``prepare()``), so engines compare equal without it
     filter_tally: list = field(default_factory=lambda: [0, 0, 0, 0],
                                compare=False)
     lock: threading.Lock = field(default_factory=threading.Lock, repr=False,

@@ -1,12 +1,18 @@
 """Vectorized CODEC host prep over RecordBatch inputs.
 
 Replaces CodecConsensusCaller.prepare()'s record-level work (phases 1-5 of
-codec_caller.rs:589-836) with batch arrays for the dominant CODEC shape —
-every paired primary a single-op M CIGAR — where clip amounts, adjusted
-positions, overlap geometry, and the phase checks are closed-form
-arithmetic and the SourceRead conversion is one native pack. Molecules with
-any other CIGAR shape run the classic prepare() unchanged, in stream order
-(sharing the caller's stats and downsample RNG stream).
+codec_caller.rs:589-836) with batch arrays. Every group of a batch is paired
+by read name in one pass. Where every paired primary is a single-op M CIGAR,
+clip amounts, adjusted positions, overlap geometry and the phase checks are
+closed-form arithmetic; any other CIGAR shape (soft clips, indels, hard
+clips) goes through one native pass a batch (`nb.codec_cigar_prepare`: the
+mate clip from the mate's own CIGAR, the clipped CIGAR, codec's per-strand
+most-common-alignment filter on it, the longest strands by clipped reference
+length and the phase check), and both join one pack layout whose SourceRead
+conversion is one native pack. Only a molecule a batch boundary cuts, a
+group whose read names collide in the hash and a group that downsamples
+leave the arrays, in stream order (sharing the caller's stats and downsample
+RNG stream): a group with a CIGAR through the classic prepare() unchanged.
 
 A batch's molecules travel from the prepare to `nb.build_codec_records` as
 columns (`_Molecules`: one array a field, one entry a molecule or a strand),
@@ -16,11 +22,11 @@ and errors go from the row of the result matrix that holds them to the
 record builder through arrays made once: `nb.codec_place` writes them
 oriented and padded, depths and errors as int32, and `nb.codec_combine` and
 `nb.build_codec_records` read them there. A Python loop runs
-only over the molecules the closed forms do not cover, one at a time and in
+only over the molecules the array passes do not cover, one at a time and in
 stream order among themselves (`codec.row_molecules`): the one a batch
-boundary cut, a group with a CIGAR that is not one M run, a group whose
-read names collide in the hash, a group that downsamples; and over every
-emitted molecule under `--cell-tag`, which needs raw records.
+boundary cut, a group whose read names collide in the hash, a group that
+downsamples; and over every emitted molecule under `--cell-tag`, which needs
+raw records.
 
 Stage 2 (the SS device pass, geometry finish, combine/masks, record build)
 IS the classic caller's `_run_jobs` + `_finish`, so outputs are identical
@@ -31,11 +37,12 @@ dispatch (`_CodecPending`, the shape of fast_duplex's `_DuplexPending`); its
 `resolve()` does the rest on whichever thread `run_stages` resolves on (the
 resolve workers at ``--threads 4``, the writer at 2-3, the caller inline at
 0-1). On the thread that calls `process_batch`: the spans `process.decode`,
-`.group`, `.prep`, `engine.codec.slow_molecule`, `.single`, `.gather` and
-the pack and dispatch; what is a function of the stream and not of the
-batch (the carry, the classic ``prepare()`` of a carried or fallback
-molecule, ``codec.molecules`` / ``.strands`` and the prepare-phase
-rejects). Where the chunk resolves: `resolve.wait`, `device.fetch`,
+`.group`, `.prep` (and inside it `process.prep.codec_cigar`, the native
+pass), `engine.codec.slow_molecule`, `.single`, `.gather` and the pack and
+dispatch; what is a function of the stream and not of the batch (the carry,
+the classic ``prepare()`` of a carried or fallback molecule,
+``codec.molecules`` / ``.strands``, the prepare-phase rejects and the
+filter's tally). Where the chunk resolves: `resolve.wait`, `device.fetch`,
 `resolve.unpack` (the thresholds), `engine.codec.place`, `.combine`,
 `.gates` and `resolve.serialize`. Stage 2 is a function of its batch but
 for two things chunks share: the caller's `CodecStats` (locked) and its
@@ -43,8 +50,10 @@ record counter, which names a molecule whose MI value is empty
 (`_EmittedOrder`). Run-report counters, by molecule:
 `codec.molecules` = `.emitted` + `.rejected` (`.rejected.<reason>`),
 `.slow_molecules` = `.slow_molecules.carried` + `.cigar` (why the classic
-``prepare()`` took it), `.molecules.cigar` / `.reads.cigar` (the input's
-molecules with a paired primary that is not one M run, and their reads),
+``prepare()`` took it: the carry, or a group with a CIGAR that collided or
+downsampled), `.molecules.cigar` / `.reads.cigar` (the input's molecules
+with a paired primary that is not one M run, and their reads),
+`.molecules.cigar_vec` (those the native pass prepared),
 `.row_molecules`, `.strands`, `.single_strands`,
 `.place.strands` / `.place.cells` (what the native placement copied),
 `.combine_cells_host`, `.duplex_bases`, `.disagreements`; by
@@ -369,7 +378,8 @@ class FastCodecCaller:
         reference), counted under ``codec.slow_molecules.<cause>``:
         ``carried``, the one a batch boundary cut, or ``cigar``, a group of
         the vectorized span (already in ``codec.molecules`` and
-        ``.molecules.cigar``) with a CIGAR the closed forms do not cover."""
+        ``.molecules.cigar``) with a CIGAR other than one M run whose names
+        collide in the hash or whose pairs downsample."""
         METRICS.inc("codec.slow_molecules")
         METRICS.inc("codec.slow_molecules." + cause)
         METRICS.inc("codec.row_molecules")
@@ -928,11 +938,15 @@ class FastCodecCaller:
     def _prepare_span(self, batch, bounds, g0, g1, lead=()):
         """Vectorized prepare for complete groups [g0, g1), after the
         classic-prepared molecules ``lead``: the molecules as columns
-        (`_Molecules`) and their pack arrays. The groups the closed forms
-        cover fill their columns in whole-array passes and their verdicts
-        reject by mask; the others (a CIGAR that is not one M run, names
-        that collide in the hash, a downsample) are prepared one at a time,
-        in stream order among themselves."""
+        (`_Molecules`) and their pack arrays. Every group is paired by name
+        in one pass (`_pair_span`); a group whose paired primaries are each
+        one M run takes the closed forms (`_geometry_vec`), any other one
+        native pass (`_prepare_cigar`: the mate clip, the clipped CIGAR, the
+        per-strand filter, the geometry and the phase check), and their
+        verdicts reject by mask. The rest (names that collide in the hash, a
+        downsample) are prepared one at a time, in stream order among
+        themselves: a group with a CIGAR through the classic
+        ``prepare()``."""
         caller = self.caller
         st = caller.stats
         buf = batch.buf
@@ -960,13 +974,18 @@ class FastCodecCaller:
             METRICS.inc("codec.molecules.cigar", n_cigar)
             METRICS.inc("codec.reads.cigar", int(np.diff(gb)[~grp_ok].sum()))
 
-        # phases 1-2 (primary-pair formation by name + clip closed forms)
-        # run once over the whole eligible span, then phases 3-4 (overlap
-        # geometry + verdicts) as one array pass (_geometry_vec);
-        # hash-collision groups fall back to the per-molecule python
-        # pairing, downsampled groups to the per-molecule geometry
-        pair_of_group, py_groups, geom = self._pair_span(
-            batch, span, g_of_row, grp_ok, fl, paired_primary)
+        # phases 1-2 (primary-pair formation by name) run once over the
+        # whole span, then phases 3-4 as one array pass for the all-M groups
+        # (_geometry_vec) and one native pass for the others
+        # (_prepare_cigar); all-M groups whose names collide in the hash
+        # fall back to the per-molecule pairing, downsampled ones to the
+        # per-molecule geometry, and such groups with a CIGAR to the classic
+        # prepare()
+        pair_of_group, py_groups, by_row, geom, cig = self._pair_span(
+            batch, span, g_of_row, grp_ok, m_only, fl, paired_primary)
+        n_cigar_vec = n_cigar - int((by_row & ~grp_ok).sum())
+        if n_cigar_vec:
+            METRICS.inc("codec.molecules.cigar_vec", n_cigar_vec)
 
         nL = len(lead)
         mols = _Molecules(nL + nG, lead, batch)
@@ -975,14 +994,11 @@ class FastCodecCaller:
         alive = np.zeros(nL + nG, dtype=bool)
         alive[:nL] = True
         # a group with no verdict yet: not in the geometry, not an exception
-        unpaired = grp_ok.copy()
-        by_row = ~grp_ok  # groups prepared one at a time
-        if py_groups:
-            by_row[np.fromiter(py_groups, np.int64, len(py_groups))] = True
-            unpaired &= ~by_row
+        unpaired = ~by_row
 
-        # bulk pack layout of the geometry-ok groups occupies [0, pk_base);
-        # per-molecule fallbacks append after it
+        # bulk pack layout of the geometry-ok groups, then of the CIGAR
+        # groups', occupies [0, pk_base); per-molecule fallbacks append
+        # after it
         pk_base = 0
         if geom is not None:
             pk_base = len(geom["pack0"])
@@ -1012,6 +1028,17 @@ class FastCodecCaller:
                 if n_hit:
                     st.reject(reason, 2 * int(n_g[hit].sum()))
                     _count_reject(reason, n_hit)
+        if cig is not None:
+            unpaired[cig["gid"]] = False
+            k = nL + cig["gid"][cig["ok"]]
+            alive[k] = True
+            n_r1, n_r2 = cig["n_r1"], cig["n_r2"]
+            mols.pk0[k] = pk_base + np.cumsum(n_r1 + n_r2) - (n_r1 + n_r2)
+            mols.n_r1[k], mols.n_r2[k] = n_r1, n_r2
+            mols.len1[k], mols.len2[k] = cig["len1"], cig["len2"]
+            mols.r1_neg[k], mols.r2_neg[k] = cig["r1_neg"], cig["r2_neg"]
+            mols.length[k] = cig["length"]
+            pk_base += len(cig["rows"])
         n_unpaired = int(unpaired.sum())
         if n_unpaired:
             # no surviving FR pair (the reads were counted in _pair_span)
@@ -1050,11 +1077,14 @@ class FastCodecCaller:
 
         codes_pk = quals_pk = None
         if pk_base or pack_rows:
-            rows_arr, clips_arr = np.asarray(pack_rows, dtype=np.int64), \
-                np.asarray(pack_clips, dtype=np.int64)
-            if pk_base:
-                rows_arr = np.concatenate([geom["pack0"], rows_arr])
-                clips_arr = np.concatenate([geom["clips0"], clips_arr])
+            parts = [(np.asarray(pack_rows, dtype=np.int64),
+                      np.asarray(pack_clips, dtype=np.int64))]
+            if cig is not None:
+                parts.insert(0, (cig["rows"], cig["clips"]))
+            if geom is not None:
+                parts.insert(0, (geom["pack0"], geom["clips0"]))
+            rows_arr = np.concatenate([rows for rows, _clips in parts])
+            clips_arr = np.concatenate([clips for _rows, clips in parts])
             row_len = batch.l_seq[rows_arr]
             stride = max(-(-int(row_len.max()) // 32) * 32, 32)
             rev = ((flag[rows_arr] & FLAG_REVERSE) != 0).astype(np.uint8)
@@ -1068,33 +1098,34 @@ class FastCodecCaller:
             mols = mols.take(np.nonzero(alive)[0])
         return mols, codes_pk, quals_pk
 
-    def _pair_span(self, batch, span, g_of_row, grp_ok, fl_span, pp_span):
-        """Phases 1-2 for every eligible group in one pass: primary FR
-        pairing by read name (FNV hash buckets, byte-verified) plus the
-        clip/adjusted-position closed forms, all as span-wide array math.
-        fl_span / pp_span are the caller's per-span flag values and
-        paired-primary mask (shared, not recomputed).
+    def _pair_span(self, batch, span, g_of_row, grp_ok, m_only, fl_span,
+                   pp_span):
+        """Phases 1-2 for every group of the span in one pass: primary FR
+        pairing by read name (FNV hash buckets, byte-verified), then, for
+        the groups whose paired primaries are each one M run (``grp_ok``),
+        the clip/adjusted-position closed forms and `_geometry_vec`, and for
+        the others `_prepare_cigar`, all as span-wide passes. m_only /
+        fl_span / pp_span are the caller's per-span one-M-run mask, flag
+        values and paired-primary mask (shared, not recomputed).
 
         Returns ({local_g: per-pair arrays}, {local_g needing the python
-        pairing}). The second set holds groups where two distinct names
-        share a hash (byte-verify failed) — their stats are untouched here
-        so the per-molecule path recounts them exactly.
+        pairing}, by_row, geometry, CIGAR groups). The second set holds
+        groups where two distinct names share a hash (byte-verify failed);
+        ``by_row`` marks them and the groups with a CIGAR that downsample
+        (the classic ``prepare()`` takes those whole, in stream order, for
+        the shared RNG stream): their stats are untouched here so the
+        per-molecule path recounts them exactly.
         """
         st = self.caller.stats
+        opts = self.caller.options
         flag = batch.flag
         l_seq = batch.l_seq
         pos = batch.pos
         buf = batch.buf
-        elig = grp_ok[g_of_row]
-        rows = span[elig]
-        g_of = g_of_row[elig]
-        if len(rows) == 0:
-            return {}, set(), None
-
-        paired = (fl_span[elig] & FLAG_PAIRED) != 0
-        ppm = pp_span[elig]
-        pr = rows[ppm]
-        pg = g_of[ppm]
+        nG = len(grp_ok)
+        paired = (fl_span & FLAG_PAIRED) != 0
+        pr = span[pp_span]
+        pg = g_of_row[pp_span]
 
         # name buckets within each group (classic by_name first-appearance
         # dict, fast_codec _prepare_molecule_vec phase 2)
@@ -1113,33 +1144,17 @@ class FastCodecCaller:
         head = np.repeat(b_start, b_size)
         same = nb.ranges_equal(buf, sno, snl, sno[head], snl[head])
         py_groups = set(int(g) for g in np.unique(sg[same == 0]))
-
-        ok_mask = np.ones(len(b_start), dtype=bool)
-        if py_groups:
-            bg_all = sg[b_start]
-            ok_mask = ~np.isin(bg_all, np.fromiter(py_groups, dtype=sg.dtype,
-                                                   count=len(py_groups)))
-        # stats for the groups resolved here (python-fallback groups excluded)
-        if py_groups:
-            keep_rows = ~np.isin(g_of, np.fromiter(py_groups, dtype=g_of.dtype,
-                                                   count=len(py_groups)))
-            st.total_input_reads += int(keep_rows.sum())
-            frag = int((~paired[keep_rows]).sum())
-        else:
-            st.total_input_reads += len(rows)
-            frag = int((~paired).sum())
-        if frag:
-            st.reject("FragmentRead", frag)
+        by_row = np.zeros(nG, dtype=bool)
+        by_row[list(py_groups)] = True
+        ok_mask = ~by_row[sg[b_start]]
 
         two = ok_mask & (b_size == 2)
-        odd_total = int(b_size[ok_mask & ~two].sum())
-
         ia = sp[b_start[two]]
         ib = sp[b_start[two] + 1]
         bg = sg[b_start[two]]
         first_orig = so[b_start[two]]  # classic bucket order: name appearance
 
-        # is_primary_fr_pair, vectorized (overlap.py:96-156 for all-M rows)
+        # is_primary_fr_pair, vectorized (overlap.py:96-156)
         fa, fb = flag[ia], flag[ib]
         ok = ((fa | fb) & (FLAG_UNMAPPED | FLAG_MATE_UNMAPPED)) == 0
         ok &= batch.ref_id[ia] == batch.ref_id[ib]
@@ -1152,19 +1167,49 @@ class FastCodecCaller:
         start = pos[r].astype(np.int64) + 1
         mate_start = batch.next_pos[r].astype(np.int64) + 1
         rrev = (rf & FLAG_REVERSE) != 0
-        end = start + np.maximum(l_seq[r].astype(np.int64) - 1, 0)
+        # the reverse record's reference length: one M run's is its length,
+        # any other CIGAR's is read from its words (`nb.ref_spans`' end is
+        # pos + max(length, 1), which gives the same end below)
+        ref_len = l_seq[r].astype(np.int64)
+        walk = ~m_only[r - span[0]]
+        if walk.any():
+            rw = r[walk]
+            ref_len[walk] = nb.ref_spans(buf, batch.cigar_off[rw],
+                                         batch.n_cigar[rw], pos[rw]) \
+                - pos[rw].astype(np.int64)
+        end = start + np.maximum(ref_len - 1, 0)
         pos5 = np.where(rrev, mate_start, start)
         neg5 = np.where(rrev, end, start + batch.tlen[r].astype(np.int64))
         ok &= pos5 < neg5
 
-        n_failed = int((~ok).sum())
+        max_pairs = opts.max_reads_per_strand
+        if max_pairs is not None:
+            n_pairs = np.bincount(bg[ok], minlength=nG)
+            by_row |= ~grp_ok & (n_pairs > max_pairs) \
+                & (n_pairs >= opts.min_reads_per_strand)
+        # stats for the groups resolved here (by_row groups excluded)
+        counted = ~by_row[g_of_row]
+        st.total_input_reads += int(counted.sum())
+        frag = int((~paired & counted).sum())
+        if frag:
+            st.reject("FragmentRead", frag)
+        odd_total = int(b_size[ok_mask & ~two & ~by_row[sg[b_start]]].sum())
+        n_failed = int((~ok & ~by_row[bg]).sum())
         if odd_total or n_failed:
             st.reject("NotPrimaryFrPair", odd_total + 2 * n_failed)
 
-        ia, ib, bg, first_orig = ia[ok], ib[ok], bg[ok], first_orig[ok]
+        take = ok & ~by_row[bg]
+        ia, ib, bg, first_orig = ia[take], ib[take], bg[take], \
+            first_orig[take]
         a_first = (flag[ia] & FLAG_FIRST) != 0
         r1 = np.where(a_first, ia, ib)
         r2 = np.where(a_first, ib, ia)
+        # classic pair order within a group = first appearance of the name
+        po = np.lexsort((first_orig, bg))
+        r1, r2, bg = r1[po], r2[po], bg[po]
+        mo = grp_ok[bg]
+        cig = self._prepare_cigar(batch, r1[~mo], r2[~mo], bg[~mo])
+        r1, r2, bg = r1[mo], r2[mo], bg[mo]
 
         # clip_vs closed forms, both directions (all-M geometry)
         def clips(ra, rb):
@@ -1190,11 +1235,7 @@ class FastCodecCaller:
         c1, rev1, flen1, adj1 = info(r1, clips(r1, r2))
         c2, rev2, flen2, adj2 = info(r2, clips(r2, r1))
 
-        # classic pair order within a group = first appearance of the name
-        po = np.lexsort((first_orig, bg))
-        arrs = (r1[po], c1[po], rev1[po], flen1[po], adj1[po],
-                r2[po], c2[po], rev2[po], flen2[po], adj2[po])
-        bg = bg[po]
+        arrs = (r1, c1, rev1, flen1, adj1, r2, c2, rev2, flen2, adj2)
         geom = self._geometry_vec(arrs, bg)
         # per-group pair tuples only for the groups that still take the
         # per-molecule path (downsampling consumes the shared RNG stream);
@@ -1204,7 +1245,62 @@ class FastCodecCaller:
             starts, ends, gid = geom["starts"], geom["ends"], geom["gid"]
             for k in np.nonzero(geom["downs"])[0]:
                 out[int(gid[k])] = tuple(a[starts[k]:ends[k]] for a in arrs)
-        return out, py_groups, geom
+        return out, py_groups, by_row, geom, cig
+
+    def _prepare_cigar(self, batch, r1, r2, bg):
+        """Phases 2-4 of the groups with a CIGAR other than one M run, from
+        their FR pairs ``r1`` / ``r2`` (batch rows, in prepare()'s order
+        within each group ``bg``): one native pass
+        (`nb.codec_cigar_prepare`: the mate clip from the mate's own CIGAR,
+        the clipped CIGAR and position, the per-strand filter, the longest
+        strands by clipped reference length, the overlap and the phase
+        check), then the verdicts' rejects, in prepare()'s reasons, and the
+        filter's tally. None without pairs; else the groups ``gid``, which
+        kept (``ok``), the kept ones' columns, and their kept reads as pack
+        ``rows`` with their ``clips``: a molecule's R1s, then its R2s, in
+        pair order."""
+        P = len(bg)
+        if P == 0:
+            return None
+        caller = self.caller
+        st, opts = caller.stats, caller.options
+        starts = np.nonzero(np.concatenate(([True], bg[1:] != bg[:-1])))[0]
+        bounds = np.append(starts, P)
+        reads = np.concatenate([r1, r2])
+        with _span("process.prep.codec_cigar", rusage=True,
+                   molecules=len(starts)):
+            res = nb.codec_cigar_prepare(
+                batch.buf, batch.cigar_off[reads], batch.n_cigar[reads],
+                batch.flag[reads], batch.pos[reads], batch.l_seq[reads],
+                bounds, opts.min_reads_per_strand, opts.min_duplex_length)
+        tally = res["tally"]
+        for i, count in enumerate(tally.tolist()):
+            st.filter_tally[i] += count
+        if tally[3]:
+            st.reject("MinorityAlignment", int(tally[3]))
+        verdict = res["verdict"]
+        n_r1 = res["n_kept1"].astype(np.int64)
+        n_r2 = res["n_kept2"].astype(np.int64)
+        for code, reason in ((1, "InsufficientReads"),
+                             (2, "InsufficientOverlap"),
+                             (3, "IndelErrorBetweenStrands")):
+            hit = verdict == code
+            n_hit = int(hit.sum())
+            if n_hit:
+                st.reject(reason, int((n_r1 + n_r2)[hit].sum()))
+                _count_reject(reason, n_hit)
+        ok = verdict == 0
+        seg = np.repeat(np.arange(len(starts)), np.diff(bounds))
+        keep = (res["keep"] != 0) & np.tile(ok[seg], 2)
+        order = np.argsort(np.concatenate([2 * seg, 2 * seg + 1])[keep],
+                           kind="stable")
+        rev = (batch.flag[reads] & FLAG_REVERSE) != 0
+        return {"gid": bg[starts], "ok": ok, "n_r1": n_r1[ok],
+                "n_r2": n_r2[ok], "len1": res["len1"][ok],
+                "len2": res["len2"][ok], "r1_neg": rev[res["longest1"][ok]],
+                "r2_neg": rev[res["longest2"][ok]],
+                "length": res["length"][ok], "rows": reads[keep][order],
+                "clips": res["clip"][keep][order].astype(np.int64)}
 
     def _geometry_vec(self, arrs, bg):
         """Phases 3-4 for EVERY paired group in one array pass: the

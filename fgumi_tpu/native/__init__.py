@@ -25,7 +25,7 @@ _lock = threading.Lock()
 _lib = None
 _lib_failed = False
 # must equal fgumi_abi_version() in fgumi_native.cc (stale-.so guard)
-_ABI_VERSION = 20
+_ABI_VERSION = 21
 
 
 def build() -> bool:
@@ -125,6 +125,9 @@ def _declare(lib):
     lib.fgumi_mate_clips.argtypes = [p] * 11 + [ctypes.c_long, p]
     lib.fgumi_alignment_filter.restype = None
     lib.fgumi_alignment_filter.argtypes = [p] * 6 + [ctypes.c_long, p]
+    lib.fgumi_codec_cigar_prepare.restype = None
+    lib.fgumi_codec_cigar_prepare.argtypes = (
+        [p] * 6 + [ctypes.c_long, p] + [ctypes.c_long] * 3 + [p] * 17)
     lib.fgumi_overlap_correct_pairs.restype = None
     lib.fgumi_overlap_correct_pairs.argtypes = [
         p, p, p, ctypes.c_long, ctypes.c_int, ctypes.c_int, p]

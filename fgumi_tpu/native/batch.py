@@ -177,6 +177,69 @@ def alignment_filter(buf: np.ndarray, cigar_off: np.ndarray,
     return keep
 
 
+def codec_cigar_prepare(buf: np.ndarray, cigar_off, n_cigar, flag, pos,
+                        l_seq, seg_starts, min_reads: int,
+                        min_duplex_length: int) -> dict:
+    """Codec's prepare (consensus/codec.py prepare(), phases 2-4) for the FR
+    pairs of whole molecules: the mate clip, the clipped CIGAR and position,
+    the per-strand most-common-alignment filter and the overlap geometry
+    with its phase check.
+
+    The read arrays hold 2P entries, the P pairs' R1s and then their R2s in
+    the same order; seg_starts (int64[n_seg + 1]) bounds each molecule's
+    pairs. Per read: ``clip``, ``clipped_len``, ``adj_pos``, ``ref_len``,
+    ``keep`` and the clipped CIGAR, ``words[word_lo:word_lo + word_n]`` (BAM
+    words). Per molecule: ``verdict`` (0 kept, 1 InsufficientReads, 2
+    InsufficientOverlap, 3 IndelErrorBetweenStrands), ``n_kept1`` /
+    ``n_kept2``, ``len1`` / ``len2``, ``longest1`` / ``longest2`` (the read
+    index of each strand's longest kept read by clipped reference length, -1
+    where the verdict came before phase 4), ``length``; and
+    ``tally``, the filter's sets, sets kept whole, reads in and reads
+    rejected (`consensus/codec.py FILTER_TALLY`).
+    """
+    lib = get_lib()
+    n = len(cigar_off)
+    seg_starts = np.ascontiguousarray(seg_starts, np.int64)
+    if not (n % 2 == 0 and len(n_cigar) == len(flag) == len(pos)
+            == len(l_seq) == n and len(seg_starts) >= 1
+            and seg_starts[0] == 0 and seg_starts[-1] == n // 2
+            and (np.diff(seg_starts) >= 0).all()):
+        raise ValueError("codec_cigar_prepare: seg_starts must run from 0 to "
+                         "the pair count without a step back, over read "
+                         "arrays of one even length")
+    # converted arrays must stay referenced until the foreign call returns
+    cigar_off = np.ascontiguousarray(cigar_off, np.int64)
+    n_cigar, flag, pos, l_seq = (np.ascontiguousarray(a, np.int32)
+                                 for a in (n_cigar, flag, pos, l_seq))
+    n_seg = len(seg_starts) - 1
+    out = {"words": np.empty(int(n_cigar.sum()) + n, dtype=np.uint32),
+           "word_lo": np.empty(n, dtype=np.int64),
+           "word_n": np.empty(n, dtype=np.int32),
+           "clip": np.empty(n, dtype=np.int32),
+           "clipped_len": np.empty(n, dtype=np.int32),
+           "adj_pos": np.empty(n, dtype=np.int64),
+           "ref_len": np.empty(n, dtype=np.int64),
+           "keep": np.empty(n, dtype=np.uint8),
+           "verdict": np.empty(n_seg, dtype=np.int8),
+           "n_kept1": np.empty(n_seg, dtype=np.int32),
+           "n_kept2": np.empty(n_seg, dtype=np.int32),
+           "len1": np.empty(n_seg, dtype=np.int32),
+           "len2": np.empty(n_seg, dtype=np.int32),
+           "longest1": np.empty(n_seg, dtype=np.int64),
+           "longest2": np.empty(n_seg, dtype=np.int64),
+           "length": np.empty(n_seg, dtype=np.int64),
+           "tally": np.zeros(4, dtype=np.int64)}
+    lib.fgumi_codec_cigar_prepare(
+        _addr(buf), _addr(cigar_off), _addr(n_cigar), _addr(flag),
+        _addr(pos), _addr(l_seq), n // 2, _addr(seg_starts), n_seg,
+        min_reads, min_duplex_length,
+        *(_addr(out[k]) for k in (
+            "words", "word_lo", "word_n", "clip", "clipped_len", "adj_pos",
+            "ref_len", "keep", "verdict", "n_kept1", "n_kept2", "len1",
+            "len2", "longest1", "longest2", "length", "tally")))
+    return out
+
+
 def build_consensus_records(code_addr, qual_addr, depth_addr, err_addr, lens,
                             flags, prefix: bytes, mi_addr, mi_len,
                             rx_addr, rx_len, rg: bytes,

@@ -92,7 +92,7 @@ extern "C" {
 // ABI version for the stale-.so guard in __init__.py: bump whenever any
 // exported signature changes (a symbol probe alone cannot detect an
 // argument-list change in an existing function).
-long fgumi_abi_version() { return 20; }
+long fgumi_abi_version() { return 21; }
 
 // Candidate UMI pairs with hamming(A[i], B[j]) <= d over (n, L)/(m, L) byte
 // matrices, via the d+1-part pigeonhole (umi/assigners.py
@@ -1965,17 +1965,14 @@ int64_t bases_extending_past_mate(const CigarView& c, int32_t flag, int32_t pos,
   return v > 0 ? v : 0;
 }
 
-// The most-common-alignment filter's CIGAR of one read, an element a
+// A CIGAR as the most-common-alignment filter compares it, an element a
 // uint64 `length << 4 | op` (so that comparing two elements compares the
 // length first and the BAM op code second: core/cigar.py compare): the
 // CIGAR words decoded where they lie, simplified (S = X H become M, equal
 // neighbours merged: core/cigar.py simplify), read from its end for a
-// reverse-strand read, then cut to `query_len` query bases with
-// truncate_to_query_length's exact loop (stop at the top once the query is
-// used up; a non-query op before that is kept whole; no re-merge). Appends
-// to `out`, returns the number of elements.
-int32_t filter_cigar(const CigarView& c, bool reverse, int64_t query_len,
-                     std::vector<uint64_t>* out) {
+// reverse-strand read. Appends to `out`, returns the number of elements.
+int32_t simplify_cigar(const CigarView& c, bool reverse,
+                       std::vector<uint64_t>* out) {
   const size_t base = out->size();
   for (int32_t k = 0; k < c.n; ++k) {
     const int32_t i = reverse ? c.n - 1 - k : k;
@@ -1988,6 +1985,18 @@ int32_t filter_cigar(const CigarView& c, bool reverse, int64_t query_len,
       out->push_back(len << 4 | op);
     }
   }
+  return static_cast<int32_t>(out->size() - base);
+}
+
+// The filter's CIGAR of one read in simplex and duplex: `simplify_cigar`,
+// then cut to `query_len` query bases with truncate_to_query_length's exact
+// loop (stop at the top once the query is used up; a non-query op before
+// that is kept whole; no re-merge). Appends to `out`, returns the number of
+// elements.
+int32_t filter_cigar(const CigarView& c, bool reverse, int64_t query_len,
+                     std::vector<uint64_t>* out) {
+  const size_t base = out->size();
+  simplify_cigar(c, reverse, out);
   uint64_t remaining = query_len > 0 ? static_cast<uint64_t>(query_len) : 0;
   size_t k = base;
   for (; k < out->size() && remaining != 0; ++k) {
@@ -2024,6 +2033,161 @@ inline bool filter_cigar_less(const uint64_t* a, int32_t na, const uint64_t* b,
     if (a[i] != b[i]) return a[i] < b[i];
   }
   return na < nb;
+}
+
+// A compatibility group of `select_alignment_group`.
+struct FilterGroup {
+  int32_t founder;  // position in the set's length order
+  int64_t reads;
+};
+
+// select_most_common_alignment_group (fgbio filterToMostCommonAlignment,
+// vanilla_caller.rs:50-122) over one set of n reads, read i's CIGAR the
+// elements e[lo[i], lo[i] + cnt[i]): the reads are taken longest `len`
+// first (stable); each joins EVERY group whose CIGAR its own prefixes
+// (fgbio's quirk) or founds one; most reads win, a tie goes to the smaller
+// CIGAR, a full tie to the earlier group. keep[i] = 1 for the reads of the
+// winning group, else 0. `order` and `groups` are scratch. Simplex's and
+// duplex's filter (`fgumi_alignment_filter`) and codec's
+// (`fgumi_codec_cigar_prepare`) differ only in the elements they build.
+void select_alignment_group(const uint64_t* e, const int64_t* lo,
+                            const int32_t* cnt, const int32_t* len, int32_t n,
+                            std::vector<int32_t>* order,
+                            std::vector<FilterGroup>* groups, uint8_t* keep) {
+  order->resize(n);
+  for (int32_t i = 0; i < n; ++i) (*order)[i] = i;
+  std::stable_sort(order->begin(), order->end(),
+                   [len](int32_t a, int32_t b) { return len[a] > len[b]; });
+  const int32_t* ord = order->data();
+  groups->clear();
+  for (int32_t p = 0; p < n; ++p) {
+    const int32_t i = ord[p];
+    bool found = false;
+    for (FilterGroup& g : *groups) {
+      const int32_t f = ord[g.founder];
+      if (filter_is_prefix(e + lo[i], cnt[i], e + lo[f], cnt[f])) {
+        ++g.reads;
+        found = true;
+      }
+    }
+    if (!found) groups->push_back(FilterGroup{p, 1});
+  }
+  if (groups->size() == 1) {  // one group: every read is in it
+    std::memset(keep, 1, static_cast<size_t>(n));
+    return;
+  }
+  const FilterGroup* best = &(*groups)[0];
+  for (size_t g = 1; g < groups->size(); ++g) {
+    const int32_t f = ord[(*groups)[g].founder], bf = ord[best->founder];
+    if ((*groups)[g].reads > best->reads ||
+        ((*groups)[g].reads == best->reads &&
+         filter_cigar_less(e + lo[f], cnt[f], e + lo[bf], cnt[bf]))) {
+      best = &(*groups)[g];
+    }
+  }
+  // the winner's reads: its founder and every later read that prefixes it
+  const int32_t bf = ord[best->founder];
+  for (int32_t p = 0; p < n; ++p) {
+    const int32_t i = ord[p];
+    keep[i] = p == best->founder ||
+              (p > best->founder &&
+               filter_is_prefix(e + lo[i], cnt[i], e + lo[bf], cnt[bf]));
+  }
+}
+
+// core/cigar.py clip_cigar (clip_cigar_ops_raw,
+// crates/fgumi-raw-bam/src/cigar.rs:404-446): `clip` query bases
+// hard-clipped from the start (`from_start`) or the end of `c`, written to
+// `out` as BAM words (room for c.n + 1); returns the number written and
+// sets *ref_clipped to the reference bases the clip took from the start (0
+// for an end clip). The H and then S at that end absorb the clip first (S
+// upgraded to H); the rest clips into the alignment, splitting an op,
+// swallowing an insertion that crosses the clip point whole, and skipping
+// deletions that abut it. Built from the clipped end inward, then turned
+// round for an end clip.
+int32_t clip_cigar(const CigarView& c, int64_t clip, bool from_start,
+                   uint32_t* out, int64_t* ref_clipped) {
+  *ref_clipped = 0;
+  const int32_t n = c.n;
+  if (clip == 0 || n == 0) {
+    for (int32_t i = 0; i < n; ++i) out[i] = read_u32(c.p + 4 * i);
+    return n;
+  }
+  auto at = [n, from_start](int32_t k) { return from_start ? k : n - 1 - k; };
+  auto word = [](int64_t len, uint32_t op) {
+    return static_cast<uint32_t>(len << 4) | op;
+  };
+  int64_t hard = 0, soft = 0;
+  int32_t k = 0;
+  for (; k < n && c.op(at(k)) == 5; ++k) hard += c.len(at(k));
+  for (; k < n && c.op(at(k)) == 4; ++k) soft += c.len(at(k));
+  int32_t m = 1;  // out[0] is the hard clip, written last
+  if (clip <= hard + soft) {
+    // soft clips upgraded to hard, the alignment untouched (cigar.rs:669-745)
+    const int64_t upgrade = std::min(soft, std::max<int64_t>(clip - hard, 0));
+    out[0] = word(hard + upgrade, 5);
+    if (soft > upgrade) out[m++] = word(soft - upgrade, 4);
+  } else {
+    const int64_t aclip = clip - hard - soft;
+    int64_t read_clipped = 0, ref_c = 0;
+    bool split = false;
+    for (; k < n; ++k) {
+      const uint32_t op = c.op(at(k));
+      const int64_t len = c.len(at(k));
+      if (read_clipped == aclip && !split && op == 2) {
+        ref_c += len;  // a deletion that abuts the clip point
+        continue;
+      }
+      if (read_clipped >= aclip) break;
+      const bool is_read = op == 0 || op == 1 || op == 7 || op == 8;  // MI=X
+      const bool is_ref = op_consumes_ref(op);
+      if (is_read && len > aclip - read_clipped) {
+        if (op == 1) {
+          read_clipped += len;  // an insertion across the clip point, whole
+        } else {
+          const int64_t take = aclip - read_clipped;
+          read_clipped += take;
+          if (is_ref) ref_c += take;
+          out[m++] = word(len - take, op);
+          split = true;
+        }
+      } else {
+        if (is_read) read_clipped += len;
+        if (is_ref) ref_c += len;
+      }
+    }
+    out[0] = word(hard + soft + read_clipped, 5);
+    if (from_start) *ref_clipped = ref_c;
+  }
+  for (; k < n; ++k) out[m++] = read_u32(c.p + 4 * at(k));
+  if (!from_start) std::reverse(out, out + m);
+  return m;
+}
+
+// core/cigar.py read_pos_at_ref_pos (read_pos_at_ref_pos_raw,
+// crates/fgumi-raw-bam/src/cigar.rs:461-506): the 1-based query position at
+// 1-based `ref_pos` of an alignment starting at `start`, 0 for none: outside
+// the alignment, or inside a deletion unless `last_if_deleted`, which takes
+// the last query position before it (1 at the read's start).
+int64_t read_pos_at_ref_pos(const CigarView& c, int64_t start,
+                            int64_t ref_pos, bool last_if_deleted) {
+  if (ref_pos < start) return 0;
+  int64_t ref_off = 0, query_off = 0;
+  for (int32_t i = 0; i < c.n; ++i) {
+    const uint32_t op = c.op(i);
+    const int64_t len = c.len(i);
+    if (op_consumes_ref(op)) {
+      const int64_t op_start = start + ref_off;
+      if (op_start <= ref_pos && ref_pos <= op_start + len - 1) {
+        if (op_consumes_query(op)) return query_off + (ref_pos - op_start) + 1;
+        if (last_if_deleted) return query_off > 0 ? query_off : 1;
+        return 0;
+      }
+      ref_off += len;
+    }
+    if (op_consumes_query(op)) query_off += len;
+  }
+  return 0;
 }
 
 // Size of a fixed-width aux value type, or 0 when variable/unknown.
@@ -2258,14 +2422,10 @@ void fgumi_alignment_filter(const uint8_t* buf, const int64_t* cigar_off,
                             const int32_t* final_len,
                             const int64_t* seg_starts, long n_seg,
                             uint8_t* keep) {
-  struct Group {
-    int32_t founder;  // position in the segment's length order
-    int64_t reads;
-  };
-  std::vector<uint64_t> elems;      // the segment's CIGARs, row after row
-  std::vector<int64_t> lo;          // row -> first element
-  std::vector<int32_t> cnt, order;  // row -> elements; the length order
-  std::vector<Group> groups;
+  std::vector<uint64_t> elems;  // the segment's CIGARs, row after row
+  std::vector<int64_t> lo;      // row -> first element
+  std::vector<int32_t> cnt, order;
+  std::vector<FilterGroup> groups;
   for (long s = 0; s < n_seg; ++s) {
     const int64_t r0 = seg_starts[s];
     const int32_t n = static_cast<int32_t>(seg_starts[s + 1] - r0);
@@ -2275,50 +2435,161 @@ void fgumi_alignment_filter(const uint8_t* buf, const int64_t* cigar_off,
     elems.clear();
     lo.resize(n);
     cnt.resize(n);
-    order.resize(n);
     for (int32_t i = 0; i < n; ++i) {
       const int64_t r = r0 + i;
       lo[i] = static_cast<int64_t>(elems.size());
       cnt[i] = filter_cigar(CigarView{buf + cigar_off[r], n_cigar[r]},
                             reverse[r] != 0, final_len[r], &elems);
-      order[i] = i;
     }
-    const int32_t* flen = final_len + r0;
-    std::stable_sort(order.begin(), order.end(),
-                     [flen](int32_t a, int32_t b) { return flen[a] > flen[b]; });
-    const uint64_t* e = elems.data();
-    groups.clear();
-    for (int32_t p = 0; p < n; ++p) {
-      const int32_t i = order[p];
-      bool found = false;
-      for (Group& g : groups) {
-        const int32_t f = order[g.founder];
-        if (filter_is_prefix(e + lo[i], cnt[i], e + lo[f], cnt[f])) {
-          ++g.reads;
-          found = true;
-        }
+    select_alignment_group(elems.data(), lo.data(), cnt.data(),
+                           final_len + r0, n, &order, &groups, keep + r0);
+  }
+}
+
+// Codec's prepare, phases 2-4 of consensus/codec.py prepare()
+// (codec_caller.rs:647-794), for the FR pairs of n_seg molecules at once.
+// Read i < n_pairs is pair i's R1, read n_pairs + i its R2 (each the
+// other's mate); segment s is pairs [seg_starts[s], seg_starts[s + 1]), a
+// molecule's pairs in prepare()'s order. Per read: `clip` (the bases past
+// the mate's soft-clip boundary, taken from the mate's own CIGAR:
+// core/overlap.py num_bases_extending_past_mate_vs_mate), the clipped CIGAR
+// `words[word_lo[i], + word_n[i])` (`clip_cigar` from the start of a
+// reverse read, the end of a forward one), `clipped_len`, `adj_pos` (1-based,
+// moved by the reference a start clip took), `ref_len` (of the clipped
+// CIGAR) and `keep` (the per-strand filter's mask). `words` holds the sum of
+// (n_cigar + 1). Per molecule: `verdict` (0 kept; 1 InsufficientReads, before
+// the filter or after it; 2 InsufficientOverlap; 3 IndelErrorBetweenStrands),
+// the reads each strand keeps (`n_kept1`, `n_kept2`; a molecule that fails
+// before the filter keeps its pairs), the longest kept clipped length of
+// each (`len1`, `len2`), the longest kept read of each by clipped reference
+// length, the first of equals (`longest1`, `longest2`: a read index, -1
+// before phase 4) and the consensus `length`. The filter runs on a
+// strand of two reads or more, on its clipped CIGARs simplified (H too
+// becomes M) and strand-oriented, untruncated, the reads taken longest
+// `clipped_len` first; `tally` adds its sets, the sets that kept every
+// read, its reads and those it rejected.
+void fgumi_codec_cigar_prepare(
+    const uint8_t* buf, const int64_t* cigar_off, const int32_t* n_cigar,
+    const int32_t* flag, const int32_t* pos, const int32_t* l_seq,
+    long n_pairs, const int64_t* seg_starts, long n_seg, long min_reads,
+    long min_duplex_length, uint32_t* words, int64_t* word_lo,
+    int32_t* word_n, int32_t* clip, int32_t* clipped_len, int64_t* adj_pos,
+    int64_t* ref_len, uint8_t* keep, int8_t* verdict, int32_t* n_kept1,
+    int32_t* n_kept2, int32_t* len1, int32_t* len2, int64_t* longest1,
+    int64_t* longest2, int64_t* length, int64_t* tally) {
+  const long n_reads = 2 * n_pairs;
+  int64_t w = 0;
+  for (long i = 0; i < n_reads; ++i) {
+    const long j = i < n_pairs ? i + n_pairs : i - n_pairs;
+    const CigarView c{buf + cigar_off[i], n_cigar[i]};
+    const CigarView cm{buf + cigar_off[j], n_cigar[j]};
+    const int64_t mate_start = pos[j] + 1 - cigar_leading_soft(cm);
+    const int64_t mate_end =
+        pos[j] + cigar_ref_len(cm) + cigar_trailing_soft(cm);
+    const int64_t cl =
+        bases_extending_past_mate(c, flag[i], pos[i], mate_start, mate_end);
+    const bool rev = flag[i] & kFlagReverse;
+    int64_t ref_clipped = 0;
+    word_lo[i] = w;
+    word_n[i] = clip_cigar(c, cl, rev, words + w, &ref_clipped);
+    clip[i] = static_cast<int32_t>(cl);
+    clipped_len[i] = static_cast<int32_t>(l_seq[i] > cl ? l_seq[i] - cl : 0);
+    adj_pos[i] = static_cast<int64_t>(pos[i]) + 1 + ref_clipped;
+    ref_len[i] = cigar_ref_len(
+        CigarView{reinterpret_cast<const uint8_t*>(words + w), word_n[i]});
+    keep[i] = 1;
+    w += word_n[i];
+  }
+  auto view = [words, word_lo, word_n](long i) {
+    return CigarView{reinterpret_cast<const uint8_t*>(words + word_lo[i]),
+                     word_n[i]};
+  };
+  std::vector<uint64_t> elems;
+  std::vector<int64_t> lo;
+  std::vector<int32_t> cnt, order;
+  std::vector<FilterGroup> groups;
+  for (long s = 0; s < n_seg; ++s) {
+    const int64_t p0 = seg_starts[s];
+    const int32_t n = static_cast<int32_t>(seg_starts[s + 1] - p0);
+    verdict[s] = 0;
+    n_kept1[s] = n_kept2[s] = n;
+    len1[s] = len2[s] = 0;
+    longest1[s] = longest2[s] = -1;
+    length[s] = 0;
+    if (n < min_reads) {
+      verdict[s] = 1;
+      continue;
+    }
+    // phase 3: the per-strand filter, R1s then R2s
+    int32_t* kept[2] = {&n_kept1[s], &n_kept2[s]};
+    for (int side = 0; side < 2; ++side) {
+      const int64_t r0 = side * n_pairs + p0;
+      if (n < 2) continue;
+      elems.clear();
+      lo.resize(n);
+      cnt.resize(n);
+      for (int32_t k = 0; k < n; ++k) {
+        lo[k] = static_cast<int64_t>(elems.size());
+        cnt[k] = simplify_cigar(view(r0 + k), flag[r0 + k] & kFlagReverse,
+                                &elems);
       }
-      if (!found) groups.push_back(Group{p, 1});
+      select_alignment_group(elems.data(), lo.data(), cnt.data(),
+                             clipped_len + r0, n, &order, &groups, keep + r0);
+      int32_t held = 0;
+      for (int32_t k = 0; k < n; ++k) held += keep[r0 + k];
+      *kept[side] = held;
+      tally[0] += 1;
+      tally[1] += held == n;
+      tally[2] += n;
+      tally[3] += n - held;
     }
-    if (groups.size() == 1) continue;  // one group: every read is in it
-    const Group* best = &groups[0];
-    for (size_t g = 1; g < groups.size(); ++g) {
-      const int32_t f = order[groups[g].founder], bf = order[best->founder];
-      if (groups[g].reads > best->reads ||
-          (groups[g].reads == best->reads &&
-           filter_cigar_less(e + lo[f], cnt[f], e + lo[bf], cnt[bf]))) {
-        best = &groups[g];
+    if (n_kept1[s] < min_reads || n_kept2[s] < min_reads) {
+      verdict[s] = 1;
+      continue;
+    }
+    // phase 4: the longest kept read of each strand by clipped reference
+    // length (the first of equals) and their lengths
+    int64_t best[2] = {-1, -1};
+    int32_t longest[2] = {0, 0};
+    for (int side = 0; side < 2; ++side) {
+      const int64_t r0 = side * n_pairs + p0;
+      for (int32_t k = 0; k < n; ++k) {
+        const int64_t r = r0 + k;
+        if (!keep[r]) continue;
+        if (best[side] < 0 || ref_len[r] > ref_len[best[side]]) best[side] = r;
+        if (clipped_len[r] > longest[side]) longest[side] = clipped_len[r];
       }
     }
-    // the winner's reads: its founder and every later read that prefixes it
-    const int32_t bf = order[best->founder];
-    for (int32_t p = 0; p < n; ++p) {
-      const int32_t i = order[p];
-      keep[r0 + i] =
-          p == best->founder ||
-          (p > best->founder &&
-           filter_is_prefix(e + lo[i], cnt[i], e + lo[bf], cnt[bf]));
+    len1[s] = longest[0];
+    len2[s] = longest[1];
+    const int64_t l1 = longest1[s] = best[0], l2 = longest2[s] = best[1];
+    const bool r1_neg = flag[l1] & kFlagReverse;
+    const int64_t lp = r1_neg ? l2 : l1, ln = r1_neg ? l1 : l2;
+    const int64_t overlap_start = adj_pos[ln];
+    const int64_t pos_end =
+        adj_pos[lp] + (ref_len[lp] - 1 > 0 ? ref_len[lp] - 1 : 0);
+    if (pos_end - overlap_start + 1 < min_duplex_length) {
+      verdict[s] = 2;
+      continue;
     }
+    // the phase check: equal read offsets at both ends of the overlap
+    auto rp = [&](int64_t r, int64_t at, bool last) {
+      return read_pos_at_ref_pos(view(r), adj_pos[r], at, last);
+    };
+    const int64_t r1s = rp(l1, overlap_start, true);
+    const int64_t r2s = rp(l2, overlap_start, true);
+    const int64_t r1e = rp(l1, pos_end, true);
+    const int64_t r2e = rp(l2, pos_end, true);
+    if (!r1s || !r2s || !r1e || !r2e || r1s - r2s != r1e - r2e) {
+      verdict[s] = 3;
+      continue;
+    }
+    const int64_t p = rp(lp, pos_end, false), q = rp(ln, pos_end, false);
+    if (!p || !q) {
+      verdict[s] = 3;
+      continue;
+    }
+    length[s] = p + clipped_len[ln] - q;
   }
 }
 

@@ -41,3 +41,40 @@ def filter_oracle(cigars, reverse, lens):
     entries.sort(key=lambda t: -t[1])
     kept = set(cigar_utils.select_most_common_alignment_group(entries))
     return np.array([i in kept for i in range(len(cigars))], dtype=np.uint8)
+
+
+def random_segment(rng):
+    """One segment: (cigars, reverse flags, final lengths). Rows are drawn
+    round a few template CIGARs so that prefix groups, ties in length and
+    ties in group size all come up; some rows are random outright."""
+    n = int(rng.integers(1, 61))
+
+    def random_cigar():
+        return [(OPS[int(rng.integers(0, 9))], int(rng.integers(1, 40)))
+                for _ in range(int(rng.integers(1, 7)))]
+
+    templates = [random_cigar() for _ in range(int(rng.integers(1, 4)))]
+    mixed = rng.random() < 0.5
+    strand = bool(rng.integers(0, 2))
+    few_lens = rng.random() < 0.5
+    cigars, reverse, lens = [], [], []
+    for _ in range(n):
+        u = rng.random()
+        if u < 0.15:
+            cig = random_cigar()
+        else:
+            cig = list(templates[int(rng.integers(0, len(templates)))])
+            if u < 0.4:  # the same ops, one length moved
+                k = int(rng.integers(0, len(cig)))
+                cig[k] = (cig[k][0],
+                          max(1, cig[k][1] + int(rng.integers(-2, 3))))
+            elif u < 0.5:  # an op more, or one fewer
+                cig = cig[:-1] or cig if rng.random() < 0.5 \
+                    else cig + random_cigar()[:1]
+        query = sum(ln for op, ln in cig if op in "MIS=XH")
+        cigars.append(cig)
+        reverse.append(bool(rng.integers(0, 2)) if mixed else strand)
+        hi = max(query, 1)
+        lens.append(int(rng.choice([hi, max(hi - 3, 1), max(hi // 2, 1)]))
+                    if few_lens else int(rng.integers(1, hi + 1)))
+    return cigars, reverse, lens

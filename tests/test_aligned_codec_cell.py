@@ -303,7 +303,8 @@ def test_the_account_is_the_references(seed, rates, engine):
         assert filt == [tallies["filter"][name] for name in rca.FILTER]
         return
     # the batch engine: molecules by reason, the per-molecule prepare's
-    # molecules by cause, and the filter where that prepare runs it
+    # molecules by cause, the CIGAR molecules the native pass prepared, and
+    # the filter where a molecule with a CIGAR or the carried one runs it
     assert {k[len("codec.rejected."):]: v for k, v in m.items()
             if k.startswith("codec.rejected.")} == tallies["molecules"]
     cigar = _cigar_molecules(d)
@@ -312,17 +313,36 @@ def test_the_account_is_the_references(seed, rates, engine):
     assert m.get("codec.reads.cigar", 0) == 2 * sizes[cigar].sum()
     carried = m["codec.slow_molecules.carried"]
     assert carried == 1  # one batch: its last molecule
-    assert m["codec.slow_molecules"] == carried \
-        + m.get("codec.slow_molecules.cigar", 0)
-    slow = cigar.copy()
-    slow[-1] = True  # the carried molecule
-    assert m.get("codec.slow_molecules.cigar", 0) == cigar[:-1].sum()
-    assert filt == list(tallies["filter_by_molecule"][slow].sum(axis=0))
+    assert m["codec.slow_molecules"] == carried
+    assert "codec.slow_molecules.cigar" not in m
+    assert m.get("codec.molecules.cigar_vec", 0) == cigar[:-1].sum()
+    filtered = cigar.copy()
+    filtered[-1] = True  # the carried molecule
+    assert filt == list(tallies["filter_by_molecule"][filtered].sum(axis=0))
     if rates == "none":
         assert "codec.molecules.cigar" not in m
         assert filt[0] == filt[1]
     else:
         assert 0 < filt[1] < filt[0]
+
+
+@pytest.mark.parametrize("seed", SEEDS)
+def test_downsampled_cigar_molecules_take_the_classic_prepare(seed):
+    """With ``--max-reads 1`` every molecule of two pairs or more draws its
+    pair from the shared RNG stream, in input order: such a molecule with a
+    CIGAR takes the classic ``prepare()``, one at a time, the others stay
+    on the arrays, and the records are the classic engine's."""
+    config, _module, d, path = _cell(seed, "cell")
+    runs = {engine: _cli(path, os.path.join(_WORK.name,
+                                            f"max1{engine}{seed}.bam"),
+                         config, engine, ["--max-reads", "1"])
+            for engine in ENGINES}
+    assert runs["fast"][0] == runs["classic"][0]
+    m = runs["fast"][2]["metrics"]
+    cigar = _cigar_molecules(d)[:-1]  # the last molecule is carried
+    many = d["sizes"][:-1] > 1
+    assert m["codec.slow_molecules.cigar"] == (cigar & many).sum() > 0
+    assert m["codec.molecules.cigar_vec"] == (cigar & ~many).sum() > 0
 
 
 def test_the_clean_input_counts_no_cigar():
@@ -599,6 +619,8 @@ def test_the_rules_on_hand_made_molecules(name):
                 if k.startswith("codec.reads.rejected.")} == reads
         assert m["codec.consensus_reads"] == n_want
         if engine == "fast":  # the molecule with a CIGAR, then the carried
-            assert m["codec.slow_molecules.cigar"] \
+            assert m["codec.molecules.cigar_vec"] \
                 == m["codec.molecules.cigar"] == 1
-            assert m["codec.slow_molecules.carried"] == 1
+            assert "codec.slow_molecules.cigar" not in m
+            assert m["codec.slow_molecules"] \
+                == m["codec.slow_molecules.carried"] == 1

@@ -25,9 +25,12 @@ SRC = os.path.join(REPO, "fgumi_tpu", "native", "fgumi_native.cc")
 
 # the suites that exercise every native entry point with real data
 # (test_host_engine drives fgumi_consensus_segments, the f64 engine, with
-# adversarial pileups — Q0 NaN flows, depth tables, saturation boundary)
+# adversarial pileups — Q0 NaN flows, depth tables, saturation boundary;
+# test_codec_cigar_native drives fgumi_codec_cigar_prepare with random
+# CIGARs, hard clips and one-read strands)
 SANITIZED_SUITES = ["tests/test_native.py", "tests/test_native_batch.py",
-                    "tests/test_host_engine.py"]
+                    "tests/test_host_engine.py",
+                    "tests/test_codec_cigar_native.py"]
 
 
 def _runtime(name):

@@ -3274,12 +3274,19 @@ def _pipeline_fused(args):
     gauge_token = _hb.register_gauge(_running_stages)
     t00 = time.monotonic()
     threads = []
+    # the job's open phase ends as the stage threads start, its close
+    # begins where they are joined (this thread runs no pipeline itself)
+    from .observe.process import job_here
+
+    job = job_here()
     try:
         for name, _ in stages:
             t = spawn_thread(runner, args=(name,),
                              name=f"fgumi-chain-{name}")
             threads.append(t)
             t.start()
+        if job is not None:
+            job.begin(None)
         try:
             for t in threads:
                 while t.is_alive():
@@ -3295,6 +3302,8 @@ def _pipeline_fused(args):
                 t.join(timeout=10)
             raise
     finally:
+        if job is not None:
+            job.begin("close")
         _hb.unregister_gauge(gauge_token)
         for c in chans:
             c.fold_metrics()
@@ -4480,6 +4489,14 @@ def main(argv=None):
     from .observe import process as _process
 
     _process.note_main()
+    # every top-level invocation leaves an entry of its phases in the
+    # process record, armed or not; a nested stage of a chained command
+    # (depth > 0) is part of the outer one's
+    with _process.job_record(nested=_main_depth.get() > 0):
+        return _main(argv)
+
+
+def _main(argv):
     parser = build_parser()
     args = parser.parse_args(argv)
     from .observe.logs import setup_logging
@@ -4623,6 +4640,12 @@ def _main_scoped(args, argv):
                 parent_span_id=scope.parent_span_id,
                 process_label=(f"backend {scope.job_id}" if scope.job_id
                                else None))
+    # the job's open phase: from here to the pipeline's first item
+    from .observe.process import current_job
+
+    job = current_job()
+    if job is not None:
+        job.begin("open")
     if hb_s > 0:
         from .observe.heartbeat import Heartbeat
 
@@ -4642,6 +4665,8 @@ def _main_scoped(args, argv):
         raise
     finally:
         _main_depth.reset(token)
+        if job is not None:
+            job.begin("report")  # what follows is the report's making
         if hb is not None:
             hb.stop()
         if tracer is not None:

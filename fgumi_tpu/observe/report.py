@@ -89,7 +89,21 @@ import time
 #: job's two ends; only when a run report was asked for). A ``spans``
 #: record no longer lists the names of its threads: ``threads.*.self_s``
 #: says where a span ran, and how long there.
-SCHEMA_VERSION = 10
+#: v11: ``process.jobs``, in every report: one entry a finished
+#: top-level invocation of the process, armed or not, in the order they
+#: ended (``at_s`` since process start at ``cli.main``'s entry, ``wall_s``
+#: from there, and the seconds of its phases ``before_arm_s``, ``open_s``,
+#: ``fill_s``, ``drain_s``, ``close_s``, ``report_s``: observe/process.py
+#: ``JobClock``; their sum is at most ``wall_s``), capped at 128 with
+#: ``jobs_dropped`` beyond; the phases ``open`` to ``close`` are also spans
+#: ``job.*`` of the command thread, and a resolve worker's wait for its
+#: next output is the wait span ``queue.worker_get``. The
+#: ``latency_decomposition`` names the job's phases (``open_s``,
+#: ``fill_s``, ``drain_s``, ``close_s`` net of ``commit_s``), so a CLI
+#: job's ``host_complete_s`` holds the pipeline's run. A ``rusage=True``
+#: span records ``utime_s``, ``stime_s`` and ``minflt`` (``majflt``,
+#: ``nvcsw``, ``nivcsw`` went: nothing read them).
+SCHEMA_VERSION = 11
 
 
 def _device_stats():
@@ -160,9 +174,12 @@ _OPTIONAL = {
 #: ``total_s`` (any subset; what was measurable for this run).
 _DECOMP_COMPONENTS = (
     "client_to_balancer_s", "balancer_to_admit_s", "client_to_admit_s",
-    "queue_s", "coalesce_hold_s", "device_s", "commit_s",
-    "host_complete_s",
+    "queue_s", "open_s", "fill_s", "drain_s", "close_s", "coalesce_hold_s",
+    "device_s", "commit_s", "host_complete_s",
 )
+#: the in-process components left out where they read 0 (never armed)
+_DECOMP_OMIT_ZERO = frozenset(("open_s", "fill_s", "drain_s", "close_s",
+                               "coalesce_hold_s", "device_s", "commit_s"))
 
 #: Required numeric fields of one ``latency`` summary entry, in the order
 #: the quantile-monotonicity check walks them.
@@ -280,14 +297,48 @@ def _validate_process(proc: dict, errors: list):
     compiles = proc.get("compiles")
     if not isinstance(compiles, list):
         errors.append("process.compiles is not a list")
+    else:
+        for rec in compiles:
+            if not (isinstance(rec, dict)
+                    and rec.get("kind") in ("compile", "cache_load")
+                    and _is_number(rec.get("s"))
+                    and _is_number(rec.get("at_s"))):
+                errors.append(f"process.compiles entry {rec!r} is not "
+                              "{kind, s, at_s}")
+    _validate_jobs(proc, errors)
+
+
+def _validate_jobs(proc: dict, errors: list):
+    """``process.jobs`` (v11): entries of exactly the record's numbers, in
+    the order they ended, none of whose phases sum past its wall."""
+    from .process import PHASES, job_end
+
+    jobs = proc.get("jobs")
+    if not isinstance(jobs, list):
+        errors.append("process.jobs is not a list")
         return
-    for rec in compiles:
-        if not (isinstance(rec, dict)
-                and rec.get("kind") in ("compile", "cache_load")
-                and _is_number(rec.get("s"))
-                and _is_number(rec.get("at_s"))):
-            errors.append(f"process.compiles entry {rec!r} is not "
-                          "{kind, s, at_s}")
+    phase_fields = [p + "_s" for p in PHASES]
+    fields = ["at_s", "wall_s"] + phase_fields
+    end = None
+    for rec in jobs:
+        if not (isinstance(rec, dict) and set(rec) == set(fields)
+                and all(_is_number(v) and v >= 0 for v in rec.values())):
+            errors.append(f"process.jobs entry {rec!r} is not "
+                          f"{{{', '.join(fields)}}} of numbers >= 0")
+            continue
+        phases = sum(rec[f] for f in phase_fields)
+        if phases > rec["wall_s"] + 1e-5:
+            errors.append(f"process.jobs entry at_s {rec['at_s']}: its "
+                          f"phases sum to {phases:.6f}, past its wall_s "
+                          f"{rec['wall_s']:.6f}")
+        if end is not None and job_end(rec) < end:
+            errors.append(f"process.jobs entry at_s {rec['at_s']} ended "
+                          "before the entry ahead of it")
+        end = job_end(rec)
+    if "jobs_dropped" in proc and not (
+            isinstance(proc["jobs_dropped"], int)
+            and proc["jobs_dropped"] > 0):
+        errors.append("process.jobs_dropped is not a count > 0")
 
 
 def validate_report(obj) -> list:
@@ -423,9 +474,11 @@ def _latency_decomposition(latency: dict, wall_s: float, scope) -> dict:
     go? Hop legs come from the propagated wall-clock timestamps on the
     telemetry scope (client_sent / balancer_recv / balancer_sent /
     admitted / started — a fleet-routed job has all five, a direct submit
-    three, a plain CLI run none); in-process components are histogram sums
-    (coalesce hold, device wall, output commit); ``host_complete_s`` is
-    the residual. ``total_s`` spans client send to now when the client
+    three, a plain CLI run none); in-process components are the job's
+    phases on its command thread (v11: open, fill, drain, and close net of
+    the commit) and histogram sums (coalesce hold, device wall, output
+    commit); ``host_complete_s`` is the residual, for a CLI job the
+    pipeline's run. ``total_s`` spans client send to now when the client
     stamped its send time, else the command wall.
 
     Components are CAPPED in order so they can never sum past ``total_s``
@@ -454,10 +507,21 @@ def _latency_decomposition(latency: dict, wall_s: float, scope) -> dict:
         measured.append(("client_to_admit_s", ad - cs))
     if ad and st:
         measured.append(("queue_s", st - ad))
+    # the job's phases on its command thread (observe/process.py), the
+    # output's commit taken out of the close it happens in
+    from .process import current_job
+
+    job = current_job()
+    commit = hist_sum("io.commit_s")
+    if job is not None:
+        sec = job.seconds
+        measured += [("open_s", sec["open"]), ("fill_s", sec["fill"]),
+                     ("drain_s", sec["drain"]),
+                     ("close_s", sec["close"] - commit)]
     measured.append(("coalesce_hold_s",
                      hist_sum("device.coalesce.window_wait_s")))
     measured.append(("device_s", hist_sum("device.dispatch.wall_s")))
-    measured.append(("commit_s", hist_sum("io.commit_s")))
+    measured.append(("commit_s", commit))
     if not hops and not any(v > 0 for _, v in measured):
         return None
     total = (time.time() - cs) if cs else float(wall_s)
@@ -467,7 +531,7 @@ def _latency_decomposition(latency: dict, wall_s: float, scope) -> dict:
     spent = 0.0
     for name, v in measured:
         v = min(max(float(v), 0.0), max(total - spent, 0.0))
-        if v <= 0 and name in ("coalesce_hold_s", "device_s", "commit_s"):
+        if v <= 0 and name in _DECOMP_OMIT_ZERO:
             continue  # component never armed this run: omit, not zero
         out[name] = round(v, 6)
         spent += v

@@ -188,8 +188,7 @@ except ImportError:  # pragma: no cover - non-POSIX
 
 #: ``getrusage`` fields a ``rusage=True`` span records, as report keys
 _RUSAGE_FIELDS = (("utime_s", "ru_utime"), ("stime_s", "ru_stime"),
-                  ("minflt", "ru_minflt"), ("majflt", "ru_majflt"),
-                  ("nvcsw", "ru_nvcsw"), ("nivcsw", "ru_nivcsw"))
+                  ("minflt", "ru_minflt"))
 #: those of them a thread's record keeps, summed over its root spans
 _THREAD_RUSAGE = ("utime_s", "stime_s")
 _THREAD_RUSAGE_AT = tuple(i for i, (key, _f) in enumerate(_RUSAGE_FIELDS)

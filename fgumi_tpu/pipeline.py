@@ -263,11 +263,17 @@ def run_stages(source_iter, process_fn, sink_fn, threads: int = 0,
         if resolve_fn is not None:
             resolve_fn = _traced_stage("pipeline.resolve", resolve_fn)
         sink_fn = _traced_stage("pipeline.sink", sink_fn)
+    # the job's phases, where this is the command thread of a top-level
+    # invocation still opening (observe/process.py JobClock): the first
+    # item ends its open phase, the last begins its drain
+    from .observe.process import job_here
+
+    job = job_here()
     try:
         return _run_stages_impl(
             source_iter, process_fn, sink_fn, threads, queue_items, stats,
             watchdog_interval, resolve_fn, max_bytes, item_bytes,
-            deadlock_recover, resolve_workers)
+            deadlock_recover, resolve_workers, job)
     finally:
         # fold per-stage timings into the metrics registry on every exit
         # path (success AND failure) so the run report can always answer
@@ -275,11 +281,13 @@ def run_stages(source_iter, process_fn, sink_fn, threads: int = 0,
         from .observe.metrics import record_stage_times
 
         record_stage_times(stats)
+        if job is not None:
+            job.begin("close")
 
 
 def _run_stages_impl(source_iter, process_fn, sink_fn, threads, queue_items,
                      stats, watchdog_interval, resolve_fn, max_bytes,
-                     item_bytes, deadlock_recover, resolve_workers):
+                     item_bytes, deadlock_recover, resolve_workers, job):
     from .utils import faults
 
     if faults.armed("pipeline.process"):
@@ -309,6 +317,10 @@ def _run_stages_impl(source_iter, process_fn, sink_fn, threads, queue_items,
                     "FGUMI_TPU_INLINE_FLIGHT", "2")), 1)
             except ValueError:
                 max_pend = 2
+        if job is not None:
+            # this thread reads every item and resolves every output itself:
+            # the job has no fill and no drain to wait through
+            job.begin(None)
         if max_pend == 1:
             t_last = time.monotonic()
             for item in source_iter:
@@ -428,10 +440,14 @@ def _run_stages_impl(source_iter, process_fn, sink_fn, threads, queue_items,
     # serial order with a holdback map (bounded by in-flight = q_out depth +
     # n_workers, so memory stays bounded by queue_items)
     q_done = queue.Queue() if n_workers else None
+    from .observe.trace import span
 
     def worker(widx):
         while True:
-            got = q_out.get()
+            # the wait for the next output, the last one included: with
+            # it a worker's root spans cover its whole life in the pool
+            with span("queue.worker_get", wait=True):
+                got = q_out.get()
             if got is _DONE:
                 q_done.put(_DONE)
                 return
@@ -524,13 +540,19 @@ def _run_stages_impl(source_iter, process_fn, sink_fn, threads, queue_items,
     for t in wts:
         t.start()
     serial = 0
-    from .observe.trace import span
-
+    filling = job is not None
     try:
         while True:
             t0 = time.monotonic()
-            with span("pipeline.wait_in", wait=True):
+            if filling:
+                # the job's fill: its first item, as the reader makes it
+                job.begin("fill", wait=True)
                 item = q_in.get()
+                job.begin(None)
+                filling = False
+            else:
+                with span("pipeline.wait_in", wait=True):
+                    item = q_in.get()
             now = time.monotonic()
             stats.add_blocked("process", now - t0)
             if item is _DONE:
@@ -555,6 +577,8 @@ def _run_stages_impl(source_iter, process_fn, sink_fn, threads, queue_items,
             if writer_exc:
                 raise writer_exc[0]
     finally:
+        if job is not None:
+            job.begin("drain", wait=True)  # the stage threads' last items
         for _ in range(max(n_workers, 1)):
             q_out.put(_DONE)
         for t in wts:

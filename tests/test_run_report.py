@@ -77,18 +77,18 @@ def test_report_matches_golden_shape(clean_registries):
     trace.stop_trace()
     agg = trace.arm_spans()
     agg.record("engine.pack", 0.25, 0.125, 0.0625,
-               rusage=[0.125, 0.0625, 4096, 0, 2, 1],
+               rusage=[0.125, 0.0625, 4096],
                counts={"staging_reuses": 1}, thread="fgumi-process")
     agg.record("engine.pack", 0.75, 0.5, 0.0, thread="fgumi-process")
     agg.record("chain.get", 0.0625, 0.0625, 0.0625, thread="fgumi-process")
     # the thread's root spans: work with its clock's deltas, and a wait
     agg.record("pipeline.process", 1.5, 0.4375, 0.125,
                thread="fgumi-process", root=True,
-               root_rusage=[0.75, 0.25, 8192, 0, 3, 5])
+               root_rusage=[0.75, 0.25, 8192])
     agg.record("pipeline.wait_in", 0.5, 0.5, 0.5, thread="fgumi-process",
                root=True)
     agg.record("pipeline.resolve", 0.25, 0.25, 0.0, thread="fgumi-worker-0",
-               root=True, root_rusage=[0.0, 0.125, 0, 0, 1, 0])
+               root=True, root_rusage=[0.0, 0.125, 0])
     try:
         report = build_report(
             "dedup", ["dedup", "-i", "in.bam", "-o", "out.bam"],
@@ -103,7 +103,9 @@ def test_report_matches_golden_shape(clean_registries):
     # above, its shape pinned here
     proc = report["process"]
     assert proc["start_unix"] > 0 and isinstance(proc["compiles"], list)
-    report["process"] = {"start_unix": 0.0, "spans": {}, "compiles": []}
+    assert isinstance(proc["jobs"], list)
+    report["process"] = {"start_unix": 0.0, "spans": {}, "compiles": [],
+                         "jobs": []}
     assert "alloc" not in report  # only an invocation with --run-report
     # this process imported the kernel module, so the report names a
     # platform — which one depends on whether an earlier test started jax
@@ -237,12 +239,12 @@ def test_validate_spans_section(spans, problem):
 
 
 @pytest.mark.parametrize("process, problem", [
-    ({"start_unix": 1.0, "spans": {}, "compiles": []}, None),
+    ({"start_unix": 1.0, "spans": {}, "compiles": [], "jobs": []}, None),
     ({"start_unix": 1.0, "first_main_s": 0.5,
       "spans": {"startup.jax_import": {"s": 2.0, "at_s": 0.25}},
       "compiles": [{"kind": "cache_load", "s": 0.5, "at_s": 9.0,
                     "shape": "segwfp:1x2", "fun": "jit(fn)"}],
-      "compiles_dropped": 2}, None),
+      "compiles_dropped": 2, "jobs": []}, None),
     ({"spans": {}, "compiles": []}, "start_unix"),
     ({"start_unix": 1.0, "first_main_s": "x", "spans": {}, "compiles": []},
      "first_main_s"),

@@ -70,7 +70,7 @@ def _threads_with(report, root):
 def test_each_stage_thread_has_its_wrapper_as_root(grouped_bam, tmp_path,
                                                    threads, stage_threads):
     report = _simplex(grouped_bam, tmp_path, threads)
-    assert report["schema_version"] == SCHEMA_VERSION == 10
+    assert report["schema_version"] == SCHEMA_VERSION == 11
     assert validate_report(report) == []
     for wrapper in WRAPPERS:
         assert _threads_with(report, wrapper) == stage_threads[wrapper]
@@ -514,4 +514,4 @@ def test_validate_threads_and_alloc_sections(extra, problem):
 
 def test_schema_9_report_is_refused():
     errs = validate_report(_minimal(schema_version=9))
-    assert errs == ["schema_version 9 != 10"]
+    assert errs == ["schema_version 9 != 11"]

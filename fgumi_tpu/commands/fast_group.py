@@ -3,10 +3,13 @@
 The group-command analog of consensus/fast.py: template formation, position
 keys, filtering, and MI-tag record rewriting happen in whole-batch array
 passes (native ops from fgumi_tpu.native.batch); only the per-position-group
-UMI assignment (strings + the strategy assigner) remains Python, matching
-the reference's split where assigners are the algorithmic core
+UMI assignment (the strategy assigner) remains Python, matching the
+reference's split where assigners are the algorithmic core
 (/root/reference/src/lib/commands/group.rs:505-560) and everything around
-them is raw-byte plumbing.
+them is raw-byte plumbing. An orientation sub-group the adjacency assigner
+can take as 2-bit integer codes (``AdjacencyUmiAssigner.assign_codes``)
+goes from the batch's bytes to int64 molecule ids and their MI values with
+no Python string; every other one hands the assigner its strings.
 
 Semantics contract: byte-identical output records, identical filter metrics
 and family-size histograms to commands/group.py::run_group on the same
@@ -26,6 +29,7 @@ from ..io.bam import (FLAG_FIRST, FLAG_LAST, FLAG_MATE_UNMAPPED, FLAG_PAIRED,
 from ..native import batch as nb
 from ..observe.metrics import METRICS
 from ..observe.trace import spanned
+from ..umi.assigners import molecule_ids, molecule_kinds, render_mis, umi_codes
 from .group import (FilterMetrics, append_mi_tag, assign_group, extract_umi,
                     filter_template, pair_orientation)
 
@@ -46,13 +50,95 @@ class _ArrSeg:
     """Carried-group segment backed by a retained RecordBatch: templates
     were filtered/tallied at batch time; closure only assigns + rewrites."""
 
-    __slots__ = ("batch", "umis", "okeys", "out_rows")
+    __slots__ = ("batch", "umis", "out_rows")
 
-    def __init__(self, batch, umis, okeys, out_rows):
+    def __init__(self, batch, umis, out_rows):
         self.batch = batch
-        self.umis = umis        # list[str], kept templates in order
-        self.okeys = okeys      # list[orientation key | None]
+        self.umis = umis          # _Umis of the kept templates, in order
         self.out_rows = out_rows  # (rows_flat int64[], counts int64[])
+
+
+class _Umis:
+    """Kept templates' UMIs in stream order, as the assigner takes them.
+
+    ``parts`` hold them in order: each a (buf, offs, lens) triple of the
+    representative reads' UMI bytes in a batch buffer, or a list of the
+    strings assign_group extracts (a carried python Template's, and the
+    ``paired`` strategy's prefixed ones). ``ori`` is the orientation
+    sub-group key a template, r1 forward * 2 + r2 forward (None for
+    ``paired``). Strings and 2-bit codes are made on first use, so a
+    sub-group the codes take makes no string."""
+
+    __slots__ = ("parts", "ori", "n", "_strings", "_codes")
+
+    def __init__(self, parts, ori, n):
+        self.parts = parts
+        self.ori = ori
+        self.n = n
+        self._strings = None
+        self._codes = None
+
+    @classmethod
+    def join(cls, runs):
+        ori = (None if runs[0].ori is None
+               else np.concatenate([u.ori for u in runs]))
+        return cls([p for u in runs for p in u.parts], ori,
+                   sum(u.n for u in runs))
+
+    def strings(self):
+        """The upper-cased strings, a list."""
+        if self._strings is None:
+            out = [p if isinstance(p, list) else _decode_umis(*p)
+                   for p in self.parts]
+            self._strings = (out[0] if len(out) == 1
+                             else [u for part in out for u in part])
+        return self._strings
+
+    def codes(self, min_len):
+        """(codes uint64, length int64) a template: the 2-bit code of the
+        UMI the strings' ``--min-umi-length`` truncation keeps, and its
+        length, -1 where it has no code (see ``_span_codes``)."""
+        if self._codes is None:
+            pieces = [_span_codes(*(_string_spans(p) if isinstance(p, list)
+                                    else p), min_len) for p in self.parts]
+            self._codes = (pieces[0] if len(pieces) == 1 else
+                           tuple(map(np.concatenate, zip(*pieces))))
+        return self._codes
+
+
+def _decode_umis(buf, offs, lens):
+    """Upper-cased strings of ASCII UMI byte spans: one gather, one decode."""
+    blob, boff = nb.concat_spans([buf], np.zeros(len(offs), np.int32), offs,
+                                 lens)
+    s = blob.tobytes().upper().decode()
+    bo = boff.tolist()
+    return [s[bo[i]:bo[i + 1]] for i in range(len(offs))]
+
+
+def _string_spans(strings):
+    """(buf, offs, lens) of the UTF-8 bytes of strings."""
+    raw = [u.encode() for u in strings]
+    lens = np.fromiter(map(len, raw), np.int64, len(raw))
+    offs = np.zeros(len(raw), dtype=np.int64)
+    np.cumsum(lens[:-1], out=offs[1:])
+    return np.frombuffer(b"".join(raw) or b"\0", dtype=np.uint8), offs, lens
+
+
+def _span_codes(buf, offs, lens, min_len):
+    """(codes uint64, length int64) of UMI byte spans, truncated to
+    ``min_len`` bases where that is set: length -1 where a span has no
+    code, which is where its bytes leave ACGTacgt or ``CODE_MAX_LEN``, where
+    the spans differ in length, or where one is shorter than ``min_len``
+    (the strings' truncation then raises)."""
+    n = len(offs)
+    length = int(lens[0]) if min_len is None else min_len
+    uncoded = lens != length if min_len is None else lens < length
+    if length < 1 or uncoded.any():
+        return np.zeros(n, dtype=np.uint64), np.full(n, -1, dtype=np.int64)
+    blob, _ = nb.concat_spans([buf], np.zeros(n, np.int32), offs,
+                              np.full(n, length, dtype=np.int32))
+    codes, valid = umi_codes(blob.reshape(n, length))
+    return codes, np.where(valid, length, -1)
 
 
 class FastGrouper:
@@ -83,7 +169,8 @@ class FastGrouper:
         self.position_group_sizes = {}
         self.records_out = 0
         # run-report counters, folded into METRICS once, by flush()
-        self._counts = {"position_groups": 0, "subgroups": 0, "templates": 0}
+        self._counts = {"position_groups": 0, "subgroups": 0, "templates": 0,
+                        "subgroups.coded": 0, "templates.coded": 0}
         self._ids_folded = 0
         self._carry = []        # python Templates of the open position group
         self._carry_key = None  # their read_info_key
@@ -160,9 +247,7 @@ class FastGrouper:
         segs, self._carry, self._carry_key = self._carry, [], None
         if not segs:
             return []
-        # per-template entries in stream order: (umi, okey, emitter info)
-        umis = []
-        okeys = []
+        runs = []       # the kept templates' _Umis, a segment each
         emit_plan = []  # per seg: ("arr", seg) | ("py", kept templates)
         m = self.metrics
         for seg in segs:
@@ -175,29 +260,20 @@ class FastGrouper:
                             no_umi=self.no_umi,
                             allow_unmapped=self.allow_unmapped, metrics=m)]
                 m.accepted += sum(len(t.primary_records()) for t in kept)
-                for t in kept:
-                    if self.no_umi:
-                        umis.append("")
-                    else:
-                        umis.append(extract_umi(t, self.umi_tag,
-                                                self.assigner))
-                    okeys.append(pair_orientation(t)
-                                 if self.assigner.split_by_orientation()
-                                 else None)
+                if kept:
+                    runs.append(self._python_umis(kept))
                 emit_plan.append(("py", kept))
             else:
-                umis.extend(seg.umis)
-                okeys.extend(seg.okeys)
+                runs.append(seg.umis)
                 emit_plan.append(("arr", seg))
-        total = len(umis)
-        if total == 0:
+        if not runs:
             return []
+        total = sum(u.n for u in runs)
 
         # orientation subgrouping + truncation + assignment (assign_group)
-        from ..umi.assigners import render_mis_array
-
-        rendered = render_mis_array(self._assign_umis(umis, okeys))
-        self._tally_family_sizes(rendered)
+        ids, kinds = self._assign_umis(_Umis.join(runs))
+        self._tally_family_sizes(ids, kinds)
+        rendered = render_mis(ids, kinds)
         self.position_group_sizes[total] = \
             self.position_group_sizes.get(total, 0) + 1
 
@@ -218,7 +294,7 @@ class FastGrouper:
             else:
                 seg = plan[1]
                 rows_flat, counts = seg.out_rows
-                k = len(seg.umis)
+                k = seg.umis.n
                 # one repeat expands template values to record values
                 values = np.repeat(rendered[pos:pos + k],
                                    np.asarray(counts, dtype=np.int64))
@@ -226,6 +302,16 @@ class FastGrouper:
                 out.extend(self._flush_pending(seg.batch, rows_flat,
                                                values))
         return out
+
+    def _python_umis(self, kept):
+        """_Umis of carried python Templates: assign_group's strings."""
+        strings = ([""] * len(kept) if self.no_umi else
+                   [extract_umi(t, self.umi_tag, self.assigner) for t in kept])
+        ori = None
+        if self.assigner.split_by_orientation():
+            ori = np.array([2 * r1 + r2 for r1, r2 in map(pair_orientation,
+                                                          kept)], np.int8)
+        return _Umis([strings], ori, len(kept))
 
     def flush(self):
         """End of stream: resolve the held template and close the open group."""
@@ -328,8 +414,8 @@ class FastGrouper:
     @spanned("group.defer", rusage=True)
     def _defer_templates(self, batch, tbounds, ts):
         """Append templates of the open group to the carry: filter + tally
-        now (vectorized), carry only the kept templates' UMI strings and
-        output rows; non-ASCII-UMI templates carry as python Templates,
+        now (vectorized), carry only the kept templates' UMIs and output
+        rows; non-ASCII-UMI templates carry as python Templates,
         interleaved in stream order (MI numbering is order-sensitive)."""
         if not len(ts):
             return
@@ -353,13 +439,13 @@ class FastGrouper:
             if not run:
                 return
             kept_t = np.asarray(run, dtype=np.int64)
-            umis, okeys = self._umi_strings(batch, kept_t)
             picks = np.stack([self._fr_of[kept_t], self._r1_of[kept_t],
                               self._r2_of[kept_t]], axis=1)
             rows_flat = picks.ravel()
             rows_flat = rows_flat[rows_flat >= 0]
             counts = (picks >= 0).sum(axis=1)
-            self._carry.append(_ArrSeg(batch, umis, okeys,
+            self._carry.append(_ArrSeg(batch,
+                                       self._template_umis(batch, kept_t),
                                        (rows_flat, counts)))
 
         run = []
@@ -382,44 +468,33 @@ class FastGrouper:
             self._fc_batch = batch
         return self._fc
 
-    def _umi_strings(self, batch, kept_t):
-        """(umis, okeys) for kept templates: the strings assign_group would
-        hand the assigner (uppercased; paired-prefix applied), plus the
-        orientation subgroup key (None for the paired strategy)."""
-        assigner = self.assigner
-        uo, ul, _ = batch.tag_locs_str(self.umi_tag)
-        buf = batch.buf
-        flag = batch.flag
-
-        # representative row per kept template (r1 > fragment > r2) and one
-        # blob gather + single upper/decode for every UMI string — the
-        # per-template slice/tobytes/decode/upper loop here was ~20% of
-        # group wall time
+    def _template_umis(self, batch, kept_t):
+        """_Umis of kept templates, read from each one's representative row
+        (r1 > fragment > r2): the spans of their UMI bytes in the batch
+        buffer and the orientation keys; for ``paired`` the prefixed
+        strings assign_group would hand the assigner."""
         kt = np.asarray(kept_t, dtype=np.int64)
         r1s, r2s, frs = self._r1_of[kt], self._r2_of[kt], self._fr_of[kt]
         rep = np.where(r1s >= 0, r1s, np.where(frs >= 0, frs, r2s))
+        uo, ul, _ = batch.tag_locs_str(self.umi_tag)
         offs = uo[rep]
         lens = np.where(offs >= 0, ul[rep], 0).astype(np.int64)
-        if self.no_umi:
-            all_umis = [""] * len(kt)
-        else:
-            from ..native import batch as _nb
+        part = [""] * len(kt) if self.no_umi else (batch.buf, offs, lens)
+        if self.assigner.split_by_orientation():
+            flag = batch.flag
+            fwd1 = (r1s < 0) | ((flag[np.maximum(r1s, 0)] & FLAG_REVERSE) == 0)
+            fwd2 = (r2s < 0) | ((flag[np.maximum(r2s, 0)] & FLAG_REVERSE) == 0)
+            return _Umis([part], (fwd1.astype(np.int8) << 1) | fwd2, len(kt))
+        return _Umis([self._paired_umis(batch, kt, _decode_umis(*part))],
+                     None, len(kt))
 
-            blob, boff = _nb.concat_spans(
-                [buf], np.zeros(len(kt), np.int32), offs, lens)
-            s = blob.tobytes().upper().decode()
-            bo = boff.tolist()
-            all_umis = [s[bo[i]:bo[i + 1]] for i in range(len(kt))]
-
-        if assigner.split_by_orientation():
-            ok1 = (r1s < 0) | ((flag[np.maximum(r1s, 0)] & FLAG_REVERSE) == 0)
-            ok2 = (r2s < 0) | ((flag[np.maximum(r2s, 0)] & FLAG_REVERSE) == 0)
-            okeys = list(zip(ok1.tolist(), ok2.tolist()))
-            return all_umis, okeys
+    def _paired_umis(self, batch, kept_t, all_umis):
+        """The ``paired`` strategy's strings: each template's two UMI halves
+        with the prefixes of its reads' genomic order."""
+        flag = batch.flag
         umis = []
-        okeys = []
         u5 = self._u5_cache(batch)
-        lo_p, hi_p = assigner.lower_prefix, assigner.higher_prefix
+        lo_p, hi_p = self.assigner.lower_prefix, self.assigner.higher_prefix
         for i, t in enumerate(kept_t):
             umi = all_umis[i]
             parts = umi.split("-")
@@ -441,8 +516,7 @@ class FastGrouper:
                 umis.append(f"{lo_p}:{parts[0]}-{hi_p}:{parts[1]}")
             else:
                 umis.append(f"{hi_p}:{parts[0]}-{lo_p}:{parts[1]}")
-            okeys.append(None)
-        return umis, okeys
+        return umis
 
     def _materialize(self, batch, tbounds, t):
         return classify(batch.raw_records(
@@ -668,28 +742,29 @@ class FastGrouper:
         # accumulated fast-group output, emitted in one vectorized pass:
         # assignment stays per group (the algorithm is per position group)
         # but rendering, family tallies, and row/value expansion run ONCE
-        # over the whole accumulation (render_mis_array) — the per-template
-        # render/encode/append loop was ~0.25 s/run of pure Python
-        acc_mols = []  # MoleculeIds, template order across fast groups
-        acc_kept = []  # kept template-index arrays
+        # over the whole accumulation
+        acc_ids = []    # int64 molecule ids, template order across groups
+        acc_kinds = []  # their kind bytes (None: every id Single)
+        acc_kept = []   # kept template-index arrays
 
         def flush_fast():
-            if not acc_mols:
+            if not acc_ids:
                 return []
-            from ..umi.assigners import render_mis_array
-
-            rend = render_mis_array(acc_mols)
+            ids = np.concatenate(acc_ids)
+            kinds = (None if acc_kinds[0] is None
+                     else np.concatenate(acc_kinds))
+            kept_all = np.concatenate(acc_kept)
+            acc_ids.clear()
+            acc_kinds.clear()
+            acc_kept.clear()
             # MI values are globally unique per family (the deterministic
             # counter), so one tally covers every group in the accumulation
-            self._tally_family_sizes(rend)
-            kept_all = np.concatenate(acc_kept)
-            acc_mols.clear()
-            acc_kept.clear()
+            self._tally_family_sizes(ids, kinds)
             sels = np.stack([self._fr_of[kept_all], self._r1_of[kept_all],
                              self._r2_of[kept_all]], axis=1)
             valid = sels >= 0
             rows = sels[valid]
-            values = np.repeat(rend, valid.sum(axis=1))
+            values = np.repeat(render_mis(ids, kinds), valid.sum(axis=1))
             return self._flush_pending(batch, rows, values)
 
         for gi in range(len(gb) - 1):
@@ -716,23 +791,28 @@ class FastGrouper:
                 continue
             m.accepted += int(g_sizes[g_cat == _ACCEPT].sum())
 
-            mols = self._assign_light(batch, kept_t)
-            self.position_group_sizes[len(mols)] = \
-                self.position_group_sizes.get(len(mols), 0) + 1
-            acc_mols.extend(mols)
+            ids, kinds = self._assign_light(batch, kept_t)
+            self.position_group_sizes[len(ids)] = \
+                self.position_group_sizes.get(len(ids), 0) + 1
+            acc_ids.append(ids)
+            acc_kinds.append(kinds)
             acc_kept.append(kept_t)
 
         out.extend(flush_fast())
         return out
 
-    def _tally_family_sizes(self, rendered):
-        """Family multiplicities from rendered MI values: two unique passes
-        (vectorized Counter-of-Counter). Safe across position groups — MI
-        values are globally unique per family."""
-        _, fam_counts = np.unique(rendered, return_counts=True)
-        for size, cnt in zip(*np.unique(fam_counts, return_counts=True)):
+    def _tally_family_sizes(self, ids, kinds):
+        """Family multiplicities from molecule ids (and kinds: a paired
+        id's /A and /B are two families) with two bincounts. Safe across
+        position groups — the ids are globally unique per family, and those
+        of one flush span few values."""
+        key = ids - ids.min()
+        if kinds is not None:
+            key = 2 * key + (kinds == ord("B"))
+        hist = np.bincount(np.bincount(key))
+        for size in np.nonzero(hist[1:])[0] + 1:
             self.family_sizes[int(size)] = \
-                self.family_sizes.get(int(size), 0) + int(cnt)
+                self.family_sizes.get(int(size), 0) + int(hist[size])
 
     @spanned("group.rewrite", rusage=True)
     def _flush_pending(self, batch, rows, values):
@@ -756,43 +836,53 @@ class FastGrouper:
 
     def _assign_light(self, batch, kept_t):
         """UMI extraction + strategy assignment for one group's kept
-        templates; returns MoleculeIds in template order."""
-        umis, okeys = self._umi_strings(batch, kept_t)
-        return self._assign_umis(umis, okeys)
+        templates: (ids, kinds) in template order, as _assign_umis."""
+        return self._assign_umis(self._template_umis(batch, kept_t))
 
     @spanned("group.assign", rusage=True)
-    def _assign_umis(self, umis, okeys):
-        """assign_group's subgroup/truncate/assign tail over prepared UMI
-        strings; returns MoleculeIds in entry order."""
+    def _assign_umis(self, umis):
+        """assign_group's subgroup/truncate/assign tail over one position
+        group's _Umis: molecule ids, int64 in entry order, and their kinds
+        (None where every id is Single, as every split strategy's are; the
+        ``paired`` strategy's kind bytes otherwise)."""
         assigner = self.assigner
-        if not assigner.split_by_orientation():
-            self._count_group(len(umis), 1)
-            return assigner.assign(self._truncate(umis))
-        # okeys are (r1_positive, r2_positive) bool pairs over (possibly)
-        # hundreds of thousands of templates: one numpy unique+argsort beats
-        # a per-template dict walk. Encoding the pair as r1*2+r2 preserves
-        # tuple lexicographic order (False < True), so the subgroup
-        # assignment order matches the scalar sorted(subgroups.items())
-        ok_arr = np.asarray(okeys, dtype=bool)
-        inv_raw = (ok_arr[:, 0].astype(np.int8) << 1) | ok_arr[:, 1]
-        uniq_ok, inv_ok = np.unique(inv_raw, return_inverse=True)
-        self._count_group(len(umis), len(uniq_ok))
-        mids = [None] * len(umis)
+        if umis.ori is None:
+            self._count_group(umis.n, 1)
+            mols = assigner.assign(self._truncate(umis.strings()))
+            return molecule_ids(mols), molecule_kinds(mols)
+        # the orientation key r1*2+r2 sorts as the (r1, r2) bool tuples do
+        # (False < True), so np.unique's order is the scalar path's
+        # sorted(subgroups.items()) order
+        uniq_ok, inv_ok = np.unique(umis.ori, return_inverse=True)
+        self._count_group(umis.n, len(uniq_ok))
         if len(uniq_ok) == 1:
-            sub = umis if self.no_umi else self._truncate(umis)
-            for i, mi in enumerate(assigner.assign(sub)):
-                mids[i] = mi
-            return mids
+            return self._assign_subgroup(umis, None), None
+        ids = np.empty(umis.n, dtype=np.int64)
         order = np.argsort(inv_ok, kind="stable")
         bounds = np.searchsorted(inv_ok[order], np.arange(len(uniq_ok) + 1))
         for g in range(len(uniq_ok)):
             idxs = order[bounds[g]:bounds[g + 1]]
-            sub = [umis[i] for i in idxs]
-            if not self.no_umi:
-                sub = self._truncate(sub)
-            for i, mi in zip(idxs, assigner.assign(sub)):
-                mids[int(i)] = mi
-        return mids
+            ids[idxs] = self._assign_subgroup(umis, idxs)
+        return ids, None
+
+    def _assign_subgroup(self, umis, idxs):
+        """One orientation sub-group's molecule ids (``idxs`` None: the
+        whole group): over the 2-bit codes where the assigner takes them,
+        else over the strings, whose checks raise as they always have."""
+        whole = slice(None) if idxs is None else idxs
+        n = umis.n if idxs is None else len(idxs)
+        ids = self.assigner.assign_codes(
+            n, lambda: [a[whole] for a in umis.codes(self.min_umi_length)])
+        if ids is not None:
+            self._counts["subgroups.coded"] += 1
+            self._counts["templates.coded"] += n
+            return ids
+        sub = umis.strings()
+        if idxs is not None:
+            sub = [sub[i] for i in idxs]
+        if not self.no_umi:
+            sub = self._truncate(sub)
+        return molecule_ids(self.assigner.assign(sub))
 
     def _truncate(self, umis):
         if self.min_umi_length is None:
@@ -928,7 +1018,7 @@ class FastDedup(FastGrouper):
 
     def _process_groups(self, batch, tbounds, keys, gb):
         from .dedup import (PICARD_MAX_SCORE_PER_READ, PICARD_MIN_BASE_QUALITY,
-                            PICARD_QC_FAIL_DISCOUNT, _family_key)
+                            PICARD_QC_FAIL_DISCOUNT)
         from ..io.bam import FLAG_DUPLICATE
 
         dm = self.dmetrics
@@ -1005,11 +1095,12 @@ class FastDedup(FastGrouper):
 
             is_dup = {}
             if len(kept_t):
-                mids = self._assign_light(batch, kept_t)
-                # family grouping by (mi.id, mi.kind), name-ordered within
+                # MI values, one a template: a family is one value (an id,
+                # or a paired id's /A or /B), name-ordered within
+                mis = render_mis(*self._assign_light(batch, kept_t)).tolist()
                 fams = {}
                 for k, t in enumerate(kept_t):
-                    fams.setdefault(_family_key(mids[k]), []).append((k, t))
+                    fams.setdefault(mis[k], []).append((k, t))
                 for fam in fams.values():
                     fam.sort(key=lambda kt: batch.buf[
                         name_off[tbounds[kt[1]]]:
@@ -1048,12 +1139,10 @@ class FastDedup(FastGrouper):
                         else:
                             dm.unique_templates += 1
 
-                mi_strs = {int(t): mids[k].render()
-                           for k, t in enumerate(kept_t)}
-                for t in kept_t:
+                for k, t in enumerate(kept_t):
                     t = int(t)
                     dup = is_dup[t]
-                    mi_b = mi_strs[t].encode()
+                    mi_b = mis[k]
                     rows = self._template_rows(batch, tbounds, t)
                     self._count_rows(rows, dup, flag, tc_off)
                     if self.remove_duplicates and dup:

@@ -68,32 +68,38 @@ class MoleculeId:
 NONE_ID = MoleculeId("")
 
 
-def render_mis_array(mols) -> np.ndarray:
-    """Vectorized MoleculeId.render over a list: one S-dtype numpy array
-    (itemsize covers the longest value; consumers read true lengths via
-    np.char.str_len). Replaces 100k+ per-object render()/encode() calls in
-    the group emission path with three array passes.
+def molecule_ids(mols) -> np.ndarray:
+    """The ``id`` of each MoleculeId of a list, as int64."""
+    return np.fromiter((mo.id for mo in mols), np.int64, len(mols))
 
-    Assigners return ONE MoleculeId object per molecule (repeated by
-    reference across its templates), so the attribute reads run on the
-    identity-deduped uniques and the full-size result is a single gather."""
-    n = len(mols)
-    obj = np.fromiter(map(id, mols), np.int64, n)
-    uniq, first, inverse = np.unique(obj, return_index=True,
-                                     return_inverse=True)
-    umols = [mols[int(i)] for i in first]
-    m = len(umols)
-    ids = np.fromiter((mo.id for mo in umols), np.int64, m)
-    kinds = np.fromiter((ord(mo.kind) if mo.kind else 0 for mo in umols),
-                        np.uint8, m)
-    s = ids.astype("S20")
-    out = np.where(kinds == 0, np.bytes_(b""), s)
+
+def molecule_kinds(mols) -> np.ndarray:
+    """The ``kind`` of each MoleculeId of a list as its byte (0 for none)."""
+    return np.fromiter((ord(mo.kind) if mo.kind else 0 for mo in mols),
+                       np.uint8, len(mols))
+
+
+def render_mis(ids: np.ndarray, kinds: np.ndarray = None) -> np.ndarray:
+    """``MoleculeId.render`` over arrays: one S-dtype array (its itemsize
+    covers the longest value; consumers read true lengths via
+    np.char.str_len). ``kinds`` is None where every id is Single.
+
+    The ids come from one run of a running counter, as a flush's do, so
+    they span few values: each value of the span is rendered once and the
+    full-size result is one gather."""
+    if not len(ids):
+        return np.empty(0, "S1")
+    lo = int(ids.min())
+    rendered = np.arange(lo, int(ids.max()) + 1).astype("S20")[ids - lo]
+    if kinds is None:
+        return rendered
+    out = np.where(kinds == 0, np.bytes_(b""), rendered)
     ab = (kinds == ord("A")) | (kinds == ord("B"))
     if ab.any():
         suffix = np.where(kinds == ord("A"), np.bytes_(b"/A"),
                           np.bytes_(b"/B"))
-        out = np.where(ab, np.char.add(s, suffix), out)
-    return out[inverse]
+        out = np.where(ab, np.char.add(rendered, suffix), out)
+    return out
 
 
 _VALID_SET = frozenset("ACGTacgt")
@@ -114,6 +120,38 @@ def _is_encodable(umi: str) -> bool:
 def _umi_matrix(umis) -> np.ndarray:
     """(N, L) uint8 byte matrix of equal-length strings."""
     return np.frombuffer("".join(umis).encode(), dtype=np.uint8).reshape(len(umis), -1)
+
+
+# A UMI as a 2-bit integer code: A=0 C=1 G=2 T=3 after case folding, the
+# first base most significant; 4 marks a byte no code holds. For UMIs of one
+# length over ACGT, code order is string order.
+_CODE_OF = np.full(256, 4, dtype=np.uint8)
+for _code, _base in enumerate(b"ACGT"):
+    _CODE_OF[_base] = _CODE_OF[_base | 0x20] = _code
+_BASE_OF = np.frombuffer(b"ACGT", dtype=np.uint8)
+# The longest UMI a uint64 code holds.
+CODE_MAX_LEN = 32
+
+
+def umi_codes(mat: np.ndarray):
+    """(codes uint64, valid bool) of the UMIs in the rows of an (n, L) byte
+    matrix: a row is valid when L <= ``CODE_MAX_LEN`` and every byte is one
+    of ACGTacgt, and only a valid row's code means anything."""
+    n, length = mat.shape
+    codes = np.zeros(n, dtype=np.uint64)
+    if length > CODE_MAX_LEN:
+        return codes, np.zeros(n, dtype=bool)
+    vals = _CODE_OF[mat]
+    for j in range(length):
+        codes = (codes << np.uint64(2)) | (vals[:, j] & 3)
+    return codes, (vals < 4).all(axis=1)
+
+
+def _code_matrix(codes: np.ndarray, length: int) -> np.ndarray:
+    """The (n, length) byte matrix ``_umi_matrix`` makes of the upper-case
+    strings of ``length``-base codes."""
+    shifts = np.arange(2 * (length - 1), -1, -2, dtype=np.uint64)
+    return _BASE_OF[(codes[:, None] >> shifts) & np.uint64(3)]
 
 
 # Above this many unique UMIs, dense all-pairs matrices become untenable
@@ -514,7 +552,16 @@ def _with_invalid_fallback(umis, resolve, counter):
     return out
 
 
-class IdentityUmiAssigner:
+class _StringsOnly:
+    """An assigner that reads UMIs as strings alone."""
+
+    def assign_codes(self, n, coded):
+        """None: ``assign`` takes the strings (see
+        ``AdjacencyUmiAssigner.assign_codes``)."""
+        return None
+
+
+class IdentityUmiAssigner(_StringsOnly):
     """Exact-match grouping; IDs assigned over sorted unique uppercased UMIs."""
 
     def __init__(self):
@@ -531,7 +578,7 @@ class IdentityUmiAssigner:
         return [mapping[c] for c in canon]
 
 
-class SimpleErrorUmiAssigner:
+class SimpleErrorUmiAssigner(_StringsOnly):
     """Transitive single-linkage clustering within ``max_mismatches`` (edit strategy)."""
 
     def __init__(self, max_mismatches: int = 1):
@@ -645,25 +692,31 @@ class AdjacencyUmiAssigner:
     def split_by_orientation(self) -> bool:
         return True
 
-    def _assign_uniques(self, unique, counts):
-        """Molecule ids for (-count, string)-sorted valid unique UMIs.
-
-        Returns a list of MoleculeIds aligned with `unique`; id minting
-        order (roots in BFS-root order) is the shared contract of both the
-        scalar and vectorized assign paths."""
-        self.counter.uniques += len(unique)
-        if len(unique) == 1:
-            return [MoleculeId("S", self.counter.next_id())]
-        n = len(unique)
-        with _sized_span(n, "group.assign.umis", rusage=True):
-            mat = _umi_matrix(unique)
+    def _mint_ids(self, mat, counts):
+        """Molecule ids, int64, of (-count, string)-sorted valid unique UMIs
+        given as their (n, L) byte matrix: each root of the directed BFS
+        takes the counter's next id, in root order, and every unique its
+        root's. The scalar, vectorised and coded paths all mint here."""
+        n = len(mat)
+        self.counter.uniques += n
+        if n == 1:
+            return np.array([self.counter.next_id()], dtype=np.int64)
         graph = build_neighbor_graph(mat, self.max_mismatches)
         with _sized_span(n, "group.assign.bfs", rusage=True):
-            roots, root_of = _adjacency_bfs(unique, counts, graph)
+            roots, root_of = _adjacency_bfs(mat, counts, graph)
         with _sized_span(n, "group.assign.ids", rusage=True):
-            root_ids = {r: MoleculeId("S", self.counter.next_id())
-                        for r in roots}
-            return [root_ids[int(root_of[i])] for i in range(len(unique))]
+            # roots ascend (each is the first index of its component)
+            ids = self.counter.value + np.searchsorted(roots, root_of)
+            self.counter.value += len(roots)
+            return ids
+
+    def _assign_uniques(self, unique, counts):
+        """MoleculeIds aligned with (-count, string)-sorted valid unique
+        UMIs (see ``_mint_ids``)."""
+        with _sized_span(len(unique), "group.assign.umis", rusage=True):
+            mat = _umi_matrix(unique)
+        return [MoleculeId("S", i)
+                for i in self._mint_ids(mat, counts).tolist()]
 
     def assign(self, raw_umis):
         if not raw_umis:
@@ -687,6 +740,31 @@ class AdjacencyUmiAssigner:
         with _sized_span(len(raw_umis), "group.assign.ids", rusage=True):
             return _with_invalid_fallback(
                 upper, lambda _i, u: umi_to_id.get(u), self.counter)
+
+    def assign_codes(self, n, coded):
+        """``assign`` for a sub-group of ``n`` UMIs given as 2-bit codes:
+        ``coded()`` returns (codes uint64, length int64) a UMI as
+        ``umi_codes`` makes them, with length -1 where a UMI has no code.
+        Returns molecule ids, int64 a UMI, with no string; or None where
+        the codes may not stand in for the strings, which ``assign`` then
+        takes: under ``_VEC_THRESHOLD`` UMIs, or UMIs not all coded at one
+        length. For UMIs of one length over ACGT code order is string
+        order, so the uniques sort by (-count, string) as ``assign``'s do,
+        the graph gets the same byte matrix and the roots mint the same
+        ids in the same order."""
+        if n < self._VEC_THRESHOLD:
+            return None
+        with span("group.assign.umis", rusage=True):
+            codes, length = coded()
+            if length[0] < 1 or (length != length[0]).any():
+                return None
+            uniq, inverse, counts = np.unique(codes, return_inverse=True,
+                                              return_counts=True)
+            order = np.argsort(-counts, kind="stable")
+            mat = _code_matrix(uniq[order], int(length[0]))
+        ids = np.empty(len(uniq), dtype=np.int64)
+        ids[order] = self._mint_ids(mat, counts[order])
+        return ids[inverse]
 
     def _assign_vectorized(self, raw_umis):
         """Large-group assign: numpy passes over the input, Python per
@@ -726,7 +804,7 @@ class AdjacencyUmiAssigner:
             return list(mids_u[inverse])
 
 
-class PairedUmiAssigner:
+class PairedUmiAssigner(_StringsOnly):
     """Dual-UMI (duplex) strategy: A-B and B-A group together with /A-/B strand ids."""
 
     def __init__(self, max_mismatches: int = 1):

@@ -46,6 +46,9 @@ SEEDS = [11, 2147483659, 3000000019]
 #: host route) and three of 50 (the Python BFS under 512 uniques)
 GROUPS = [[1, 1300], [2, 300], [3, 50]]
 MOLECULES = 2050
+#: times the loci of ``GROUPS`` in the input whose run report names the
+#: thread that paces the job
+PACING_LOCI = 4
 #: route -> extra arguments. ``indexed`` sends every graph of 600 uniques or
 #: more to the native pigeonhole pass through the existing flag
 ROUTES = {"default": [], "indexed": ["--index-threshold", "600"],
@@ -70,17 +73,18 @@ HAMMING = ("group.hamming.dispatches", "group.hamming.rows",
 
 
 @functools.lru_cache(maxsize=None)
-def _cell(seed):
+def _cell(seed, loci=1):
     """(configuration, reference module, input arrays, input path). A
     quarter of the templates are F2R1, so a locus has two orientation
-    sub-groups; the last seed's input has a few UMIs with an ``N``."""
+    sub-groups; the last seed's input has a few UMIs with an ``N``.
+    ``loci`` multiplies the loci of every size in ``GROUPS``."""
     _bench, _cell, config, reference, params = harness.load_cell(CELL)
-    params.update(groups=GROUPS, num_families=MOLECULES,
-                  r1_reverse_share=0.25)
+    params.update(groups=[[n * loci, size] for n, size in GROUPS],
+                  num_families=MOLECULES * loci, r1_reverse_share=0.25)
     data = traffic.generate(params, seed)
     if seed == SEEDS[2]:
         data["umi_t"][::997, 3] = traffic.N_CODE
-    prefix = os.path.join(_WORK.name, f"in{seed}")
+    prefix = os.path.join(_WORK.name, f"in{seed}x{loci}")
     (path,) = traffic.write_inputs(data, prefix)
     return config, reference, data, path
 
@@ -94,11 +98,11 @@ def _expected(seed, edits=1):
 
 
 @functools.lru_cache(maxsize=None)
-def _run(seed, route):
+def _run(seed, route, loci=1):
     """(record bytes, header lines, run report) of the configuration's
     command."""
-    config, _reference, _data, path = _cell(seed)
-    out = os.path.join(_WORK.name, f"{route}{seed}.bam")
+    config, _reference, _data, path = _cell(seed, loci)
+    out = os.path.join(_WORK.name, f"{route}{seed}x{loci}.bam")
     report = out + ".report.json"
     argv = [a.format(in0=path, out=out) for a in config["command"]]
     subprocess.run(
@@ -275,6 +279,9 @@ def test_run_report_names_what_group_does(route):
             == report["metrics"]["group.hamming.dispatches"]
         graph = by_name["group.assign.graph"]
         assert graph["wall_s"] - graph["self_s"] >= fetch["wall_s"] - 1e-6
+    # group's work paces the job once it outweighs the job's fixed opening
+    # (job.open), as it does with four times the loci
+    report = _run(SEEDS[0], route, loci=PACING_LOCI)[2]
     assert report["threads_pacing"]["role"] in ("group.assign",
                                                 "pipeline.process")
 
